@@ -116,5 +116,7 @@ def run(iters=2 * SAVE_EVERY, warmup=10, feed=lambda *_: None):
 
 
 if __name__ == "__main__":
+    from mxnet_tpu.compile_cache import place_jax_cache
+    place_jax_cache()
     import json
     print(json.dumps(run()))

@@ -22,7 +22,10 @@ test_checkpoint's crash subprocess), twice against one cache dir:
   compile_cache_bytes      bytes on disk after both legs
   compile_cache_mode       'serialize' or 'builtin' (backend fallback)
 
-JAX's builtin persistent cache is disabled for both children so the
+  compile_backend          always 'cpu': the children are pinned to the
+                           host (the parent may hold the chip)
+
+JAX's builtin persistent cache is switched off for both children so the
 comparison isolates THIS cache.
 """
 import json
@@ -126,9 +129,11 @@ def _run_child(prefix, cache_dir, timeout_s=900):
     env = dict(os.environ)
     env["MXNET_COMPILE_CACHE"] = cache_dir
     env.setdefault("MXNET_COMPILE_CACHE_SIZE_MB", "512")
-    # isolate the measurement from jax's own persistent cache (the test
-    # harness enables it process-wide)
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    # the parent may hold the chip, and a chip belongs to one process
+    env["JAX_PLATFORMS"] = "cpu"
+    # isolate the measurement from jax's own persistent cache (every
+    # entry point places one): switched off, its directory left alone
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     res = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--child", prefix],
         env=env, capture_output=True, text=True, timeout=timeout_s)
@@ -170,6 +175,7 @@ def run(feed=lambda *_: None):
             "compile_cache_hit_rate": round(hit_rate, 4),
             "compile_cache_bytes": warm["disk_bytes"],
             "compile_cache_mode": warm["mode"],
+            "compile_backend": "cpu",
         }
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
@@ -179,6 +185,8 @@ def main():
     if len(sys.argv) > 1 and sys.argv[1] == "--child":
         child_main(sys.argv[2])
         return
+    from mxnet_tpu.compile_cache import place_jax_cache
+    place_jax_cache()          # children inherit the exported choice
     print(json.dumps(run()), flush=True)
 
 

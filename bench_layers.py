@@ -123,6 +123,8 @@ def main():
     ap.add_argument("--batch", type=int, default=256)
     ap.add_argument("--iters", type=int, default=8)
     args = ap.parse_args()
+    from mxnet_tpu.compile_cache import place_jax_cache
+    place_jax_cache()
     layout = os.environ.get("MXNET_CONV_LAYOUT", "NCHW").upper()
 
     cfgs = conv_configs(args.batch)

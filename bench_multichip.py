@@ -10,16 +10,17 @@ ServeEngine's closed-loop throughput:
                                  conv head tensor-parallel over tp
   multichip_serve_tp_qps         closed-loop QPS of a tp=2-sharded
                                  ServeEngine (8 client threads)
-  multichip_backend              'native' when the parent process sees
-                                 >= 8 real devices, else 'host_cpu'
-                                 (XLA_FLAGS forced 8 host devices — the
-                                 tier-1 topology; efficiencies on a
-                                 shared-core host measure the GSPMD
-                                 path's overhead, not chip scaling)
+  multichip_backend              always 'cpu': every datapoint is a
+                                 child on 8 forced host devices (the
+                                 tier-1 topology).  A chip belongs to
+                                 one process, so a child can never have
+                                 it; efficiencies on a shared-core host
+                                 measure the GSPMD path's overhead, not
+                                 chip scaling (that is one process over
+                                 a host's chips — chip_smoke.py --chips)
 
-ISSUE 18 (mxnet_tpu.dist) adds the multi-PROCESS legs — always on the
-host-CPU backend (two local processes cannot share one TPU, and the
-gloo process-boundary overhead is what the leg measures):
+ISSUE 18 (mxnet_tpu.dist) adds the multi-PROCESS legs (the gloo
+process-boundary overhead is what they measure):
 
   dist_scaling_eff_2proc         img/s(2 processes x 1 dev, dp=2 mesh
                                  across the process boundary) / img/s
@@ -36,8 +37,8 @@ gloo process-boundary overhead is what the leg measures):
                                  acceptance bar ("within 5% of hand")
 
 Each datapoint runs in a FRESH subprocess (same pattern as
-bench_compile.py): the mesh is a process-level property of the backend,
-and forcing the host platform must not poison the parent's real device.
+bench_compile.py) whose env pins JAX_PLATFORMS=cpu: the mesh is a
+process-level property of the backend, and the parent may hold the chip.
 The 2-process leg goes through ``tools/launch.py --launcher local`` —
 the exact rendezvous a real fleet uses.
 """
@@ -104,12 +105,7 @@ def _train_child(mesh_spec):
     X = rng.rand(batch, *IMG_SHAPE).astype(np.float32)
     y = rng.randint(0, CLASSES, batch).astype(np.float32)
     it = mx.io.NDArrayIter(X, y, batch_size=batch)
-    # every leg must run on the SAME backend the mesh legs use: on an
-    # accelerator host the 1-device baseline trains on chip 0, not on
-    # the host CPU (a CPU baseline would make the efficiency ratio
-    # compare TPU against CPU throughput)
-    ctx = mx.cpu(0) if jax.default_backend() == "cpu" else mx.tpu(0)
-    mod = mx.mod.Module(_cnn(), context=ctx)
+    mod = mx.mod.Module(_cnn(), context=mx.cpu(0))
     mod.bind(it.provide_data, it.provide_label, mesh=mesh,
              sharding=sharding)
     mod.init_params(mx.init.Xavier())
@@ -367,22 +363,11 @@ def _shard_child(model, mode):
         {"step_ms": step_ms, "model": model, "mode": mode}), flush=True)
 
 
-def _child_env(force_host):
-    env = dict(os.environ)
-    if force_host:
-        env["JAX_PLATFORMS"] = "cpu"
-        flags = env.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            env["XLA_FLAGS"] = (
-                flags + " --xla_force_host_platform_device_count=8").strip()
-    return env
-
-
-def _dist_env(ndev=None):
-    """Env for the multi-process legs: always host CPU (two local
-    processes cannot share one TPU; the process boundary is the thing
-    measured), with an EXACT forced device count when asked — the
-    parent's own XLA_FLAGS never leak into a worker that must see 1."""
+def _cpu_env(ndev=None):
+    """Env for every child: pinned to the host CPU (the parent may hold
+    the chip, and a chip belongs to one process), with an EXACT forced
+    device count when asked — the parent's own XLA_FLAGS never leak into
+    a worker that must see 1."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)
@@ -392,11 +377,10 @@ def _dist_env(ndev=None):
     return env
 
 
-def _run_child(args, force_host, timeout_s=600, env=None):
+def _run_child(args, env, timeout_s=600):
     res = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--child"] + args,
-        env=env if env is not None else _child_env(force_host),
-        capture_output=True, text=True, timeout=timeout_s)
+        env=env, capture_output=True, text=True, timeout=timeout_s)
     if res.returncode != 0:
         raise RuntimeError("bench_multichip child %s failed: %s"
                            % (args, res.stderr[-1200:]))
@@ -415,7 +399,7 @@ def _dist_leg():
             "%s %s --child dist_train"
             % (sys.executable, os.path.abspath(__file__))]
     res = subprocess.run(args, capture_output=True, text=True,
-                         timeout=600, env=_dist_env(), cwd=root)
+                         timeout=600, env=_cpu_env(), cwd=root)
     if res.returncode != 0:
         raise RuntimeError("dist_train workers failed: %s"
                            % (res.stderr[-1200:] or res.stdout[-1200:]))
@@ -424,7 +408,7 @@ def _dist_leg():
             re.findall(r"BENCH_MULTICHIP_CHILD (\{[^{}\n]*\})",
                        res.stdout)]
     two = next(d for d in docs if d.get("rank") == 0)
-    ref = _run_child(["dist_ref"], True, env=_dist_env(2))
+    ref = _run_child(["dist_ref"], _cpu_env(2))
     eff = two["img_s"] / ref["img_s"] if ref["img_s"] else None
     return {"dist_img_s_2proc": round(two["img_s"], 1),
             "dist_img_s_1proc_2dev": round(ref["img_s"], 1),
@@ -432,7 +416,7 @@ def _dist_leg():
 
 
 def _fleet_leg():
-    doc = _run_child(["fleet"], True, env=_dist_env())
+    doc = _run_child(["fleet"], _cpu_env())
     if doc.get("rc") not in (0, None) or not doc.get("restarts"):
         raise RuntimeError("fleet leg did not recover: %r" % doc)
     return {"dist_host_recovery_s": round(float(doc["last_recovery_s"]),
@@ -444,14 +428,14 @@ def _shard_leg(feed):
     is the WORST model's ratio (<= 1.05 = within 5% of hand)."""
     import tempfile
     store = tempfile.mkdtemp(prefix="bench_shard_store_")
-    env8 = _dist_env(8)
+    env8 = _cpu_env(8)
     out = {}
     fracs = []
     for model in ("cnn", "lstm"):
         feed("shardsearch-" + model)
-        hand = _run_child(["shard", model, "hand"], True, env=dict(env8))
-        auto = _run_child(["shard", model, "auto"], True,
-                          env=dict(env8, MXNET_AUTOTUNE_DIR=store))
+        hand = _run_child(["shard", model, "hand"], dict(env8))
+        auto = _run_child(["shard", model, "auto"],
+                          dict(env8, MXNET_AUTOTUNE_DIR=store))
         out["shardsearch_%s_hand_step_ms" % model] = \
             round(hand["step_ms"], 2)
         out["shardsearch_%s_auto_step_ms" % model] = \
@@ -463,37 +447,21 @@ def _shard_leg(feed):
 
 def run(feed=lambda *_: None):
     """Returns the multichip_* metrics dict.  ``feed`` is the watchdog
-    heartbeat."""
-    import jax
-    force_host = jax.device_count() < 8
-    backend = "host_cpu" if force_host else "native"
-
+    heartbeat.  Touches no JAX in this process; a child that fails
+    raises."""
+    env8 = _cpu_env(8)
     feed("multichip-1dev")
-    try:
-        one = _run_child(["train", ""], force_host)
-    except Exception as e:
-        if force_host:
-            raise
-        # a backend that admits ONE process (local libtpu exclusivity —
-        # the parent bench already holds the chips) kills every child at
-        # init; fall back to the forced-host topology rather than
-        # silently emitting no multichip metrics at all
-        sys.stderr.write("bench_multichip: native children failed (%s); "
-                         "falling back to 8 forced host-CPU devices\n"
-                         % str(e)[-300:])
-        force_host = True
-        backend = "host_cpu_fallback"
-        one = _run_child(["train", ""], force_host)
+    one = _run_child(["train", ""], env8)
     feed("multichip-dp8")
-    dp8 = _run_child(["train", "dp=8"], force_host)
+    dp8 = _run_child(["train", "dp=8"], env8)
     feed("multichip-dp4tp2")
-    dp4tp2 = _run_child(["train", "dp=4,tp=2"], force_host)
+    dp4tp2 = _run_child(["train", "dp=4,tp=2"], env8)
     feed("multichip-serve-tp")
-    serve = _run_child(["serve"], force_host)
+    serve = _run_child(["serve"], env8)
 
     base = one["img_s"]
     out = {
-        "multichip_backend": backend,
+        "multichip_backend": "cpu",
         "multichip_img_s_1dev": round(base, 1),
         "multichip_img_s_dp8": round(dp8["img_s"], 1),
         "multichip_img_s_dp4tp2": round(dp4tp2["img_s"], 1),
@@ -505,18 +473,11 @@ def run(feed=lambda *_: None):
         # the acceptance key names it serve_tp_qps; publish both
         "serve_tp_qps": round(serve["qps"], 1),
     }
-    # ISSUE 18 multi-process legs — guarded individually: a flaky
-    # rendezvous must not take the in-process metrics down with it (the
-    # gate's MISSING row still flags the lost leg)
     for name, leg in (("dist-2proc", _dist_leg),
                       ("dist-fleet", _fleet_leg),
                       ("shardsearch", lambda: _shard_leg(feed))):
         feed(name)
-        try:
-            out.update(leg())
-        except Exception as e:
-            sys.stderr.write("bench_multichip: %s leg failed (%s)\n"
-                             % (name, str(e)[-400:]))
+        out.update(leg())
     return out
 
 
@@ -535,6 +496,8 @@ def main():
         else:
             _serve_child()
         return
+    from mxnet_tpu.compile_cache import place_jax_cache
+    place_jax_cache()          # children inherit the exported choice
     print(json.dumps(run()), flush=True)
 
 

@@ -257,4 +257,6 @@ def run(feed=lambda *_: None):
 
 
 if __name__ == "__main__":
+    from mxnet_tpu.compile_cache import place_jax_cache
+    place_jax_cache()
     print(json.dumps(run(), indent=1))

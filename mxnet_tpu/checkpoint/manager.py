@@ -115,15 +115,14 @@ def _barrier(name: str, timeout_ms: int = 120000) -> None:
     by peer", then the coordination service takes the job down).  The
     coordination-service barrier is a plain gRPC rendezvous — no device
     programs — so the writer thread can block on it freely."""
-    try:
-        from jax._src import distributed
-        client = distributed.global_state.client
-    except Exception:
-        client = None
+    # jax 0.9.0 has no public handle on the coordination client
+    from jax._src import distributed
+    client = distributed.global_state.client
     if client is not None:
         client.wait_at_barrier(name, timeout_in_ms=int(timeout_ms))
         return
-    from jax.experimental import multihost_utils as mhu   # fallback
+    # no coordination service (jax.distributed not initialized)
+    from jax.experimental import multihost_utils as mhu
     mhu.sync_global_devices(name)
 
 

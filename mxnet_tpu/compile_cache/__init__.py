@@ -11,8 +11,9 @@ This subsystem kills that cold start on three legs:
    the lowered program + jax/jaxlib versions + backend + topology +
    compile flags.  Atomic publish, checksum-verified reads, LRU size
    bound, warn-and-recompile on any malformed entry, and a fallback to
-   JAX's builtin persistent cache on backends without PJRT executable
-   serialization.  Enable with ``MXNET_COMPILE_CACHE=<dir>`` (size bound
+   JAX's builtin persistent cache (placed by ``place_jax_cache``,
+   `jaxcache.py`) on backends without PJRT executable serialization.
+   Enable with ``MXNET_COMPILE_CACHE=<dir>`` (size bound
    ``MXNET_COMPILE_CACHE_SIZE_MB``, default 2048).
 
 2. **Parallel AOT warmup** (`warmup.py`): ``parallel_warm`` compiles a
@@ -26,9 +27,13 @@ This subsystem kills that cold start on three legs:
 """
 from .cached import (CachedFunction, CompileCache, cached_jit, configure,
                      get_cache, reset)
+from .jaxcache import (CompileCounter, count_backend_compiles,
+                       jax_cache_dir, place_jax_cache)
 from .stats import CompileStats, get_stats
 from .warmup import WarmupError, default_warmup_threads, parallel_warm
 
-__all__ = ["CachedFunction", "CompileCache", "CompileStats", "WarmupError",
-           "cached_jit", "configure", "default_warmup_threads", "get_cache",
-           "get_stats", "parallel_warm", "reset"]
+__all__ = ["CachedFunction", "CompileCache", "CompileCounter", "CompileStats",
+           "WarmupError", "cached_jit", "configure",
+           "count_backend_compiles", "default_warmup_threads", "get_cache",
+           "get_stats", "jax_cache_dir", "parallel_warm", "place_jax_cache",
+           "reset"]

@@ -29,7 +29,6 @@ the entry, warns once, and compiles fresh.
 from __future__ import annotations
 
 import contextlib
-import os
 import threading
 import time
 from typing import Any, Dict, Optional, Sequence, Tuple
@@ -74,22 +73,14 @@ def _fresh_compile_ctx():
     the duration (refcounted — overlapping warmup-pool compiles share
     one window) with ``reset_cache()`` dropping the memo on the way in
     AND out; an unrelated compile racing the window merely skips the
-    jax cache once.  If these internals move, degrade to a plain
-    compile — verify-on-store still rejects a poisoned blob."""
+    jax cache once."""
     global _nocache_depth, _nocache_prev
     import jax
-    try:
-        from jax._src import compilation_cache as jax_cc
-    except Exception:
-        yield
-        return
+    from jax.experimental.compilation_cache import compilation_cache as jax_cc
     with _nocache_lock:
         if _nocache_depth == 0:
             _nocache_prev = bool(jax.config.jax_enable_compilation_cache)
-            try:
-                jax_cc.reset_cache()
-            except Exception:
-                pass
+            jax_cc.reset_cache()
             jax.config.update("jax_enable_compilation_cache", False)
         _nocache_depth += 1
     try:
@@ -100,10 +91,7 @@ def _fresh_compile_ctx():
             if _nocache_depth == 0:
                 jax.config.update("jax_enable_compilation_cache",
                                   _nocache_prev)
-                try:
-                    jax_cc.reset_cache()
-                except Exception:
-                    pass
+                jax_cc.reset_cache()
 
 
 # -- leaf plumbing -----------------------------------------------------------
@@ -158,20 +146,26 @@ def _sharding_recipe(s):
 
 def _placement_extras(args) -> str:
     """Ordered device placement of every argument leaf — the part of a
-    program's identity its HLO text does not carry."""
+    program's identity its HLO text does not carry.  The platform rides
+    along: device ids repeat across platforms, and the same StableHLO
+    placed on cpu(0) and on tpu(0) of one process are two programs."""
     import jax
     parts = []
     for x in jax.tree_util.tree_flatten(args)[0]:
         sh = getattr(x, "sharding", None)
-        parts.append(None if sh is None else _sharding_recipe(sh))
+        parts.append(None if sh is None else
+                     (next(iter(sh.device_set)).platform,
+                      _sharding_recipe(sh)))
     return repr(parts)
 
 
-def _recipe_to_sharding(r):
-    import jax
+def _recipe_to_sharding(r, by_id):
+    """``by_id``: the executable's OWN client's devices.  Device ids
+    repeat across platforms (TFRT_CPU_0 and TPU_0 are both id 0), so a
+    cpu-context program in a TPU process must not resolve its recipe
+    against the default backend."""
     from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
                               SingleDeviceSharding)
-    by_id = {d.id: d for d in jax.devices()}
     if r[0] == "dev":
         return SingleDeviceSharding(by_id[r[1]])
     if r[0] == "named":
@@ -359,9 +353,12 @@ class CompileCache:
                 client = jax.local_devices(backend=platform)[0].client
             else:
                 client = jax.devices()[0].client
-            loaded = client.deserialize_executable(blob, None)
-            shardings = [_recipe_to_sharding(r) for r in meta["shardings"]]
-            out_shardings = [_recipe_to_sharding(r)
+            by_id = {d.id: d for d in client.devices()}
+            loaded = client.deserialize_executable(
+                blob, [by_id[i] for i in meta["devices"]])
+            shardings = [_recipe_to_sharding(r, by_id)
+                         for r in meta["shardings"]]
+            out_shardings = [_recipe_to_sharding(r, by_id)
                              for r in meta["out_shardings"]]
             entry = _CachedExecutable(
                 loaded, meta["out_tree"], meta["kept"], meta["avals"],
@@ -450,6 +447,7 @@ class CompileCache:
             # through the TPU client)
             client = getattr(rex, "client", None) or jax.devices()[0].client
             platform = client.platform
+            devices = list(rex.local_devices())
             blob = client.serialize_executable(rex)
         except Exception as e:
             self._serialize_unavailable(e)
@@ -461,7 +459,7 @@ class CompileCache:
         # will never load, and publishing it would cost every later
         # process a failed deserialize
         try:
-            client.deserialize_executable(blob, None)
+            client.deserialize_executable(blob, devices)
         except Exception as e:
             warn_once(
                 "blob-verify",
@@ -473,6 +471,7 @@ class CompileCache:
         import jaxlib
         meta = {"name": name, "kept": kept, "avals": avals,
                 "shardings": recipes, "platform": platform,
+                "devices": [int(d.id) for d in devices],
                 "out_tree": out_tree, "out_avals": out_avals,
                 "out_shardings": out_recipes,
                 "jax": (jax.__version__, jaxlib.__version__)}
@@ -486,37 +485,20 @@ class CompileCache:
 
     # -- builtin-cache fallback --------------------------------------------
     def _serialize_unavailable(self, exc) -> None:
-        """PJRT executable serialization missing on this backend: keep
-        persistence by enabling JAX's own compilation cache into a
-        subdirectory (unless the user already configured one)."""
+        """PJRT executable serialization missing on this backend: this
+        cache stands down (every program bypasses from here on) and
+        JAX's own persistent compilation cache, wherever the entry point
+        placed it (``place_jax_cache``), is what persists compiles."""
         if self.mode != "serialize":
             return
         self.mode = "builtin"
         import jax
-        msg = ("PJRT executable serialization unavailable on this "
-               "backend (%s: %s); " % (type(exc).__name__, exc))
-        try:
-            already = jax.config.jax_compilation_cache_dir
-        except AttributeError:
-            already = None
-        if already:
-            warn_once("serialize-unavailable", msg +
-                      "JAX's persistent compilation cache at %r stays "
-                      "in charge" % already)
-            return
-        sub = os.path.join(self.store.directory, "jax_builtin")
-        try:
-            os.makedirs(sub, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", sub)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.0)
-            warn_once("serialize-unavailable", msg +
-                      "falling back to JAX's persistent compilation "
-                      "cache in %r" % sub)
-        except Exception as e:
-            warn_once("serialize-unavailable", msg +
-                      "and the builtin-cache fallback failed too (%s); "
-                      "running uncached" % e)
+        warn_once("serialize-unavailable",
+                  "PJRT executable serialization unavailable on this "
+                  "backend (%s: %s); JAX's persistent compilation cache "
+                  "at %r stays in charge"
+                  % (type(exc).__name__, exc,
+                     jax.config.jax_compilation_cache_dir))
 
     def describe(self) -> dict:
         return {"directory": self.store.directory, "mode": self.mode,

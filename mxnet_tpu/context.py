@@ -14,6 +14,8 @@ from typing import Optional
 
 import jax
 
+from .base import MXNetError
+
 __all__ = ["Context", "cpu", "gpu", "tpu", "cpu_pinned", "current_context"]
 
 
@@ -23,7 +25,7 @@ class Context:
     Mirrors reference Context semantics: usable as a with-statement scope
     (python/mxnet/context.py), hashable, comparable.  ``gpu`` is accepted for
     script compatibility (north star: train_imagenet.py --gpus -> --tpus) and
-    resolves to a TPU device when no GPU platform exists.
+    resolves to the accelerator the process has.
     """
 
     # reference include/mxnet/base.h:93-99 device type enum
@@ -70,18 +72,28 @@ class Context:
     def jax_device(self) -> jax.Device:
         """Resolve this context to a concrete jax.Device.
 
-        cpu -> host platform device[device_id] (fake-device trick supported);
-        tpu/gpu -> accelerator device[device_id], falling back to cpu when no
-        accelerator platform is present (so tests run anywhere).
+        cpu -> host platform device[device_id] (fake-device trick: ids
+        wrap over the host devices that exist); tpu/gpu -> local
+        accelerator device[device_id], and an MXNetError when the
+        process has no accelerator or fewer than ``device_id + 1`` — a
+        run that asked for the chip never lands on the host silently.
         """
         dt = self.device_type
         if dt in ("cpu", "cpu_pinned"):
             devs = jax.local_devices(backend="cpu")
             return devs[self.device_id % len(devs)]
-        # tpu / gpu: prefer the default (accelerator) backend; local devices
-        # only — in multi-process runs jax.devices() includes remote chips
+        # local devices only — in multi-process runs jax.devices()
+        # includes remote chips
         devs = jax.local_devices()
-        return devs[self.device_id % len(devs)]
+        if devs[0].platform == "cpu":
+            raise MXNetError(
+                "%s asks for an accelerator but this process has none: "
+                "jax.local_devices() = %s" % (self, devs))
+        if not 0 <= self.device_id < len(devs):
+            raise MXNetError(
+                "%s is out of range: this process has %d %s device(s): %s"
+                % (self, len(devs), devs[0].platform, devs))
+        return devs[self.device_id]
 
     @property
     def platform(self) -> str:
@@ -119,7 +131,4 @@ def current_context() -> Context:
 
 
 def _has_accelerator() -> bool:
-    try:
-        return jax.devices()[0].platform != "cpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.local_devices()[0].platform != "cpu"

@@ -90,16 +90,6 @@ def initialize(coordinator_address: str, num_processes: int,
     except RuntimeError as e:
         if "already" not in str(e):
             raise
-    except TypeError:
-        # older jax without initialization_timeout
-        try:
-            jax.distributed.initialize(
-                coordinator_address=coordinator_address,
-                num_processes=int(num_processes),
-                process_id=int(process_id))
-        except RuntimeError as e:
-            if "already" not in str(e):
-                raise
     _initialized = True
 
 

@@ -771,7 +771,10 @@ class FusedTrainStep:
                      # symbol json, same as the embed specs
                      repr(sorted((n, sp.describe())
                                  for n, sp in self.moe_blocks.items())),
-                     repr([int(d.id) for d in self.mesh.devices.ravel()]),
+                     # platform with the ids: cpu(0) and tpu(0) are both
+                     # device id 0 in a process that has a chip
+                     repr([(d.platform, int(d.id))
+                           for d in self.mesh.devices.ravel()]),
                      repr(self.train_names), repr(self.fixed_names),
                      repr(sorted(self.label_shapes.items()))):
             h.update(str(part).encode())

@@ -23,11 +23,11 @@ Plus ``_fused_elemwise``: an arbitrary chain of single-input elementwise
 ops (activations, scalar arithmetic, unary math) collapsed into one node
 carrying the serialized step list — ``ElementwiseFusePass``'s target.
 
-Escape hatch: on TPU the FullyConnected epilogues can dispatch to a
-Pallas kernel (``pallas_kernels.fused_fc_epilogue``) for shapes XLA
-schedules poorly; off-TPU the hook returns None and the jnp body runs,
-so CPU tier-1 numerics are exactly the unfused graph's.  Knob:
-``MXNET_FUSE_PALLAS`` (default on where the kernel is available).
+Escape hatch: a FullyConnected epilogue lowered for a TPU dispatches to
+a Pallas kernel (``pallas_kernels.fused_fc_epilogue``) for shapes XLA
+schedules poorly; lowered for any other platform the jnp body runs, so
+CPU tier-1 numerics are exactly the unfused graph's.  Knob:
+``MXNET_FUSE_PALLAS`` (default on).
 
 Inference-only, like ``ops.quantized``: the fusion passes run on the
 serving pipeline and these ops define no bespoke gradient story.
@@ -127,15 +127,20 @@ class FusedFullyConnectedOp(OpDef):
         x = inputs[0].reshape(inputs[0].shape[0], -1)
         w = inputs[1]
         b = None if p.no_bias else inputs[2]
+
+        def body(x, w, b):
+            out = jnp.dot(x, w.T)
+            if b is not None:
+                out = out + b
+            return _requantize(apply_act(out, p.act_type), p.out_scale)
+
         if _pallas_wanted():
             from .pallas_kernels import fused_fc_epilogue
-            out = fused_fc_epilogue(x, w, b, p.act_type, p.out_scale)
+            out = fused_fc_epilogue(x, w, b, p.act_type, p.out_scale,
+                                    dense=body)
             if out is not None:
                 return [out]
-        out = jnp.dot(x, w.T)
-        if b is not None:
-            out = out + b
-        return [_requantize(apply_act(out, p.act_type), p.out_scale)]
+        return [body(x, w, b)]
 
 
 @register_op("_fused_Convolution", hint="fused_convolution")

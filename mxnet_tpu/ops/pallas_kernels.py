@@ -7,14 +7,18 @@ users write their own through mxnet_tpu.rtc.
 flash_attention: blockwise attention with online softmax, MXU-shaped tiles
 (q blocks x k blocks of 128, fp32 accumulators in VMEM), causal masking via
 block skipping; ragged lengths are padded up to the tile grid and masked.
-Falls back to the dense jnp reference off-TPU; tests run the kernel in
-interpret mode for numerical parity.
 
 paged_attention: attention through a paged KV cache (serve.paged) — the
 per-slot page table rides scalar prefetch and indexes the block pool
 directly from the BlockSpec index map, so each grid step streams one
 physical KV block; online softmax accumulates across the page walk in
-VMEM scratch.  Off-TPU the engine takes the dense gather reference.
+VMEM scratch.
+
+Every kernel ships beside a dense jnp twin.  Which of the two runs is
+decided when the program is LOWERED, from the platform it is compiled
+for (``_kernel_on_tpu``): Mosaic on a TPU, the twin anywhere else.
+Tier-1 runs the kernels with ``interpret=True``; tests/tpu/
+test_pallas_tpu.py holds the compiled-vs-twin checks from the chip.
 """
 from __future__ import annotations
 
@@ -41,6 +45,19 @@ __all__ = ["flash_attention", "paged_attention", "correlation",
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
+
+
+def _kernel_on_tpu(kernel_fn, dense_fn, interpret: bool, *args):
+    """``kernel_fn(*args)`` where this computation is lowered for a TPU
+    (or anywhere under ``interpret=True``), ``dense_fn(*args)`` on every
+    other platform.  The platform is the one the program is compiled
+    for, not the process's default backend: a ``mx.cpu()`` program in a
+    TPU process never reaches Mosaic, and a TPU program never silently
+    takes the dense path.  ``dense_fn=None`` means the kernel
+    unconditionally (the parity checks)."""
+    if interpret or dense_fn is None:
+        return kernel_fn(*args)
+    return lax.platform_dependent(*args, tpu=kernel_fn, default=dense_fn)
 
 
 def _searched(family: str, *args):
@@ -122,18 +139,18 @@ def flash_attention(q, k, v, causal: bool = False, block_q=None,
                     block_k=None, interpret: bool = False):
     """Blockwise attention.  q, k, v: (B, T, H, D) -> (B, T, H, D).
 
-    Uses the Pallas kernel on TPU (or with interpret=True anywhere);
-    falls back to dense attention otherwise.  ``block_q``/``block_k``
-    default to the kernel search's persisted winner for this shape
-    class when ``MXNET_KERNEL_SEARCH=1`` (every winner was
-    bitwise-parity-gated before persistence), else 128; an explicit
-    argument always wins.
+    The Pallas kernel where the program is lowered for a TPU (or with
+    interpret=True anywhere); dense attention elsewhere.
+    ``block_q``/``block_k`` default to the kernel search's persisted
+    winner for this shape class when ``MXNET_KERNEL_SEARCH=1`` (every
+    winner was bitwise-parity-gated before persistence), else 128; an
+    explicit argument always wins.
     """
+    from ..parallel.ring import attention_reference
+    dense = functools.partial(attention_reference, causal=causal)
+    if not HAS_PALLAS:
+        return dense(q, k, v)
     b, t, h, d = q.shape
-    on_tpu = jax.default_backend() == "tpu"
-    if not HAS_PALLAS or (not on_tpu and not interpret):
-        from ..parallel.ring import attention_reference
-        return attention_reference(q, k, v, causal=causal)
     if block_q is None or block_k is None:
         win = _searched("flash", t, d, causal, q.dtype) or {}
         block_q = int(win.get("block_q", 128)) if block_q is None \
@@ -148,32 +165,36 @@ def flash_attention(q, k, v, causal: bool = False, block_q=None,
     block_q = min(block_q, _round_up(t, 8))
     block_k = min(block_k, _round_up(t, 8))
     tp = _round_up(t, block_q * block_k // math.gcd(block_q, block_k))
-    if tp != t:
-        pad = [(0, 0), (0, tp - t), (0, 0), (0, 0)]
-        q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
-    scale = 1.0 / math.sqrt(d)
-    # (B, T, H, D) -> (B*H, T, D)
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, tp, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h, tp, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, tp, d)
-
     kernel = functools.partial(_flash_kernel, block_q=block_q,
-                               block_k=block_k, causal=causal, scale=scale,
-                               seq_len=tp, true_len=t)
-    out = pl.pallas_call(
-        kernel,
-        grid=(b * h, tp // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, i: (bh, i, 0)),
-            pl.BlockSpec((1, tp, d), lambda bh, i: (bh, 0, 0)),
-            pl.BlockSpec((1, tp, d), lambda bh, i: (bh, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda bh, i: (bh, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, tp, d), q.dtype),
-        interpret=interpret,
-    )(qf, kf, vf)
-    out = out.reshape(b, h, tp, d).transpose(0, 2, 1, 3)
-    return out[:, :t] if tp != t else out
+                               block_k=block_k, causal=causal,
+                               scale=1.0 / math.sqrt(d), seq_len=tp,
+                               true_len=t)
+
+    def run(q, k, v):
+        if tp != t:
+            pad = [(0, 0), (0, tp - t), (0, 0), (0, 0)]
+            q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
+        # (B, T, H, D) -> (B*H, T, D)
+        qf = q.transpose(0, 2, 1, 3).reshape(b * h, tp, d)
+        kf = k.transpose(0, 2, 1, 3).reshape(b * h, tp, d)
+        vf = v.transpose(0, 2, 1, 3).reshape(b * h, tp, d)
+        out = pl.pallas_call(
+            kernel,
+            grid=(b * h, tp // block_q),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), lambda bh, i: (bh, i, 0)),
+                pl.BlockSpec((1, tp, d), lambda bh, i: (bh, 0, 0)),
+                pl.BlockSpec((1, tp, d), lambda bh, i: (bh, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, block_q, d),
+                                   lambda bh, i: (bh, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((b * h, tp, d), q.dtype),
+            interpret=interpret,
+        )(qf, kf, vf)
+        out = out.reshape(b, h, tp, d).transpose(0, 2, 1, 3)
+        return out[:, :t] if tp != t else out
+
+    return _kernel_on_tpu(run, dense, interpret, q, k, v)
 
 
 def _paged_attention_dense(q, k_pool, v_pool, pages, lengths, q_pos,
@@ -240,7 +261,7 @@ def _paged_kernel(pages_ref, len_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     k_pos = b_i * block_tokens + lax.broadcasted_iota(jnp.int32, s.shape, 2)
     mask = k_pos < len_ref[s_i]
     if causal:
-        mask = mask & (k_pos <= pos_ref[s_i][None, :, None])
+        mask = mask & (k_pos <= pos_ref[0][None])
     s = jnp.where(mask, s, -jnp.inf)
     m_prev = m_s[...]
     new_m = jnp.maximum(m_prev, jnp.max(s, axis=-1))
@@ -266,43 +287,45 @@ def paged_attention(q, k_pool, v_pool, pages, lengths, q_pos=None,
     C = 1 for plain decode, the prefill chunk / speculative verify
     width otherwise.
 
-    Uses the Pallas page-walk kernel on TPU (or with ``interpret=True``
-    anywhere): the page table rides scalar prefetch, so each grid step
-    DMAs exactly one physical block from the pool — context length
-    costs bandwidth, not a materialized gather.  Falls back to the
-    dense gather reference off-TPU, keeping CPU tier-1 numerics
-    identical to the engine's reference path.
+    The Pallas page-walk kernel where the program is lowered for a TPU
+    (or with ``interpret=True`` anywhere): the page table rides scalar
+    prefetch, so each grid step DMAs exactly one physical block from the
+    pool — context length costs bandwidth, not a materialized gather.
+    The dense gather reference on every other platform, keeping CPU
+    tier-1 numerics identical to the engine's reference path.
     """
     s_, c, h, d = q.shape
     if q_pos is None:
         q_pos = lengths[:, None] - c + jnp.arange(c, dtype=jnp.int32)[None]
-    on_tpu = jax.default_backend() == "tpu"
-    if not HAS_PALLAS or (not on_tpu and not interpret):
-        return _paged_attention_dense(q, k_pool, v_pool, pages, lengths,
-                                      q_pos, causal=causal)
+    dense = functools.partial(_paged_attention_dense, causal=causal)
+    if not HAS_PALLAS:
+        return dense(q, k_pool, v_pool, pages, lengths, q_pos)
     # the kernel's blocking is fixed by the pool's page size, so the
     # searched axis is WHICH program: a persisted "dense" winner means
     # the gather reference beat the page walk on this backend/class
     win = _searched("paged", k_pool.shape[1], d, causal, q.dtype)
     if win is not None and win.get("impl") == "dense":
-        return _paged_attention_dense(q, k_pool, v_pool, pages, lengths,
-                                      q_pos, causal=causal)
+        return dense(q, k_pool, v_pool, pages, lengths, q_pos)
     from jax.experimental.pallas import tpu as pltpu
     n, bt = k_pool.shape[0], k_pool.shape[1]
     b = pages.shape[1]
-    scale = 1.0 / math.sqrt(d)
     kernel = functools.partial(_paged_kernel, block_tokens=bt,
-                               causal=causal, scale=scale)
+                               causal=causal, scale=1.0 / math.sqrt(d))
 
-    def _page(sl, bl, pages_ref, _len, _pos):
+    def _page(sl, bl, pages_ref, _len):
         # sentinel / unassigned entries clamp to a real block — their
         # keys sit past `lengths` and are masked in the kernel
         return (jnp.minimum(pages_ref[sl, bl], n - 1), 0, 0, 0)
 
+    # pages and lengths ride scalar prefetch (SMEM: the index map and
+    # the kernel read them one scalar at a time); q_pos is read as a
+    # vector, so it is a VMEM input laid out (C, 1) — C on the sublanes,
+    # like the score tile it masks
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=2,
         grid=(s_, b),
         in_specs=[
+            pl.BlockSpec((1, c, 1), lambda sl, bl, *_: (sl, 0, 0)),
             pl.BlockSpec((1, c, h, d), lambda sl, bl, *_: (sl, 0, 0, 0)),
             pl.BlockSpec((1, bt, h, d), _page),
             pl.BlockSpec((1, bt, h, d), _page),
@@ -315,12 +338,17 @@ def paged_attention(q, k_pool, v_pool, pages, lengths, q_pos=None,
             pltpu.VMEM((h, c, d), jnp.float32),
         ],
     )
-    return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_, c, h, d), q.dtype),
-        interpret=interpret,
-    )(pages.astype(jnp.int32), lengths.astype(jnp.int32),
-      q_pos.astype(jnp.int32), q, k_pool, v_pool)
+
+    def run(q, k_pool, v_pool, pages, lengths, q_pos):
+        return pl.pallas_call(
+            kernel, grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((s_, c, h, d), q.dtype),
+            interpret=interpret,
+        )(pages.astype(jnp.int32), lengths.astype(jnp.int32),
+          q_pos.astype(jnp.int32)[..., None], q, k_pool, v_pool)
+
+    return _kernel_on_tpu(run, dense, interpret, q, k_pool, v_pool, pages,
+                          lengths, q_pos)
 
 
 def _fc_epilogue_kernel(x_ref, w_ref, b_ref, o_ref, *, act_type, out_scale):
@@ -330,7 +358,7 @@ def _fc_epilogue_kernel(x_ref, w_ref, b_ref, o_ref, *, act_type, out_scale):
     x = x_ref[...].astype(jnp.float32)                 # (M, K)
     w = w_ref[...].astype(jnp.float32)                 # (block_n, K)
     acc = jnp.dot(x, w.T, preferred_element_type=jnp.float32)
-    acc = acc + b_ref[...][None, :]
+    acc = acc + b_ref[...]                             # (1, block_n)
     if act_type == "relu":
         acc = jnp.maximum(acc, 0.0)
     elif act_type == "sigmoid":
@@ -345,17 +373,18 @@ def _fc_epilogue_kernel(x_ref, w_ref, b_ref, o_ref, *, act_type, out_scale):
 
 
 def fused_fc_epilogue(x, w, b, act_type: str, out_scale=None,
-                      block_n=None, interpret: bool = False):
+                      block_n=None, interpret: bool = False, dense=None):
     """FullyConnected epilogue kernel: x (M, K) · w (N, K)ᵀ + b, fused
     activation, optional int8 requantize (``out_scale``).  Returns the
     (M, N) result — f32, or int8 when ``out_scale`` is set — or None
-    when the Pallas path is unavailable/ineligible (off-TPU without
-    ``interpret``, odd shapes, unknown act): the caller falls back to
-    the jnp body, which keeps CPU tier-1 numerics identical to the
-    unfused graph.  ``block_n`` defaults to the kernel search's
-    persisted winner under ``MXNET_KERNEL_SEARCH=1``, else 128."""
-    on_tpu = jax.default_backend() == "tpu"
-    if not HAS_PALLAS or (not on_tpu and not interpret):
+    when the shape or activation is ineligible, and the caller runs its
+    own jnp body.  ``dense(x, w, b)`` is that body: it runs instead of
+    the kernel wherever the program is not lowered for a TPU, so CPU
+    tier-1 numerics stay identical to the unfused graph; without it the
+    kernel runs unconditionally.  ``block_n`` defaults to the kernel
+    search's persisted winner under ``MXNET_KERNEL_SEARCH=1``, else
+    128."""
+    if not HAS_PALLAS:
         return None
     if act_type not in ("none", "relu", "sigmoid", "tanh", "softrelu"):
         return None
@@ -366,28 +395,35 @@ def fused_fc_epilogue(x, w, b, act_type: str, out_scale=None,
                         x.dtype) or {}
         block_n = int(win.get("block_n", 128))
     # MXU lane/sublane alignment: K and N on the 128 lanes; M must fill
-    # the output tile's sublanes (8 for f32, 32 for an int8 result)
+    # the output tile's sublanes (8 for f32, 32 for an int8 result) —
+    # the interpreter alone takes any M
     min_m = 32 if out_scale is not None else 8
-    if n % block_n or k % 128 or (on_tpu and m % min_m):
+    if n % block_n or k % 128 or (not interpret and m % min_m):
         return None
-    if b is None:
-        b = jnp.zeros((n,), jnp.float32)
     out_dtype = jnp.int8 if out_scale is not None else x.dtype
     kernel = functools.partial(
         _fc_epilogue_kernel, act_type=act_type,
         out_scale=None if out_scale is None else float(out_scale))
-    return pl.pallas_call(
-        kernel,
-        grid=(n // block_n,),
-        in_specs=[
-            pl.BlockSpec((m, k), lambda i: (0, 0)),
-            pl.BlockSpec((block_n, k), lambda i: (i, 0)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((m, block_n), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        interpret=interpret,
-    )(x, w, b)
+
+    def run(x, w, b):
+        if b is None:
+            b = jnp.zeros((n,), jnp.float32)
+        # the bias rides as (1, N): a 1-D operand's XLA layout does not
+        # tile the way a (block_n,) Mosaic block would
+        return pl.pallas_call(
+            kernel,
+            grid=(n // block_n,),
+            in_specs=[
+                pl.BlockSpec((m, k), lambda i: (0, 0)),
+                pl.BlockSpec((block_n, k), lambda i: (i, 0)),
+                pl.BlockSpec((1, block_n), lambda i: (0, i)),
+            ],
+            out_specs=pl.BlockSpec((m, block_n), lambda i: (0, i)),
+            out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+            interpret=interpret,
+        )(x, w, b.reshape(1, n))
+
+    return _kernel_on_tpu(run, dense, interpret, x, w, b)
 
 
 def _correlation_kernel(a_ref, b_ref, o_ref, *, d2, stride2, base, hh, ww,
@@ -415,14 +451,16 @@ def _correlation_kernel(a_ref, b_ref, o_ref, *, d2, stride2, base, hh, ww,
 
 
 def correlation(a, b, max_displacement: int, stride2: int = 1,
-                is_multiply: bool = True, interpret: bool = False):
+                is_multiply: bool = True, interpret: bool = False,
+                dense=None):
     """FlowNet correlation (reference correlation.cu) for the
     kernel_size=1 / stride1=1 / pad=max_displacement configuration.
     a, b: (N, C, H, W) -> (N, D2*D2, H, W) with D2 = 2*(m//stride2)+1.
-    Returns None when the Pallas path is unavailable (caller falls back
-    to the lax lowering)."""
-    on_tpu = jax.default_backend() == "tpu"
-    if not HAS_PALLAS or (not on_tpu and not interpret):
+    Returns None when the window is ineligible (caller falls back to
+    its lax lowering).  ``dense(a, b)`` is that lowering: it runs
+    instead of the kernel wherever the program is not lowered for a
+    TPU; without it the kernel runs unconditionally."""
+    if not HAS_PALLAS:
         return None
     n, c, h, w = a.shape
     m = max_displacement
@@ -430,19 +468,24 @@ def correlation(a, b, max_displacement: int, stride2: int = 1,
     d2 = 2 * ng + 1
     if d2 * d2 > 169:   # static unroll bound: fall back for huge windows
         return None
-    bp = jnp.pad(b, [(0, 0), (0, 0), (m, m), (m, m)])
     kernel = functools.partial(
         _correlation_kernel, d2=d2, stride2=stride2, base=m - ng * stride2,
         hh=h, ww=w, is_multiply=is_multiply, norm=float(c))
-    return pl.pallas_call(
-        kernel,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, c, h, w), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, c, h + 2 * m, w + 2 * m),
-                         lambda i: (i, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, d2 * d2, h, w), lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, d2 * d2, h, w), a.dtype),
-        interpret=interpret,
-    )(a, bp)
+
+    def run(a, b):
+        bp = jnp.pad(b, [(0, 0), (0, 0), (m, m), (m, m)])
+        return pl.pallas_call(
+            kernel,
+            grid=(n,),
+            in_specs=[
+                pl.BlockSpec((1, c, h, w), lambda i: (i, 0, 0, 0)),
+                pl.BlockSpec((1, c, h + 2 * m, w + 2 * m),
+                             lambda i: (i, 0, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, d2 * d2, h, w),
+                                   lambda i: (i, 0, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct((n, d2 * d2, h, w), a.dtype),
+            interpret=interpret,
+        )(a, bp)
+
+    return _kernel_on_tpu(run, dense, interpret, a, b)

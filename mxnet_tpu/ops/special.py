@@ -163,41 +163,47 @@ class CorrelationOp(OpDef):
         a, b = inputs
         n, c, h, w = a.shape
         ph, pw, kr, br, oh, ow, ng, d2 = self._geom(p, a.shape)
+        def lax_path(a, b):
+            pad = [(0, 0), (0, 0), (p.pad_size, p.pad_size),
+                   (p.pad_size, p.pad_size)]
+            ap = jnp.pad(a, pad)
+            bp = jnp.pad(b, pad)
+            outs = []
+            ksz = p.kernel_size
+            norm = float(c * ksz * ksz)
+            for dy in range(-ng, ng + 1):
+                for dx in range(-ng, ng + 1):
+                    sy, sx = dy * p.stride2, dx * p.stride2
+                    shifted = jnp.roll(bp, shift=(-sy, -sx), axis=(2, 3))
+                    if p.is_multiply:
+                        prod = ap * shifted
+                    else:
+                        prod = jnp.abs(ap - shifted)
+                    # sum over channel and kernel window
+                    summed = jnp.sum(prod, axis=1, keepdims=True)
+                    if ksz > 1:
+                        summed = lax.reduce_window(
+                            summed, 0.0, lax.add, (1, 1, ksz, ksz),
+                            (1, 1, 1, 1),
+                            [(0, 0), (0, 0), (kr, kr), (kr, kr)])
+                    # sample output grid starting at border br with stride1
+                    sl = summed[:, :, br:br + oh * p.stride1:p.stride1,
+                                br:br + ow * p.stride1:p.stride1]
+                    outs.append(sl / norm)
+            return jnp.concatenate(outs, axis=1)
+
         # Pallas fast path (the reference's hand-written correlation.cu
-        # equivalent): one VMEM-resident displacement loop instead of
-        # d2*d2 HBM passes. Covers the FlowNet configuration.
+        # equivalent) where the program is lowered for a TPU: one
+        # VMEM-resident displacement loop instead of d2*d2 HBM passes.
+        # Covers the FlowNet configuration.
         if (p.kernel_size == 1 and p.stride1 == 1
                 and p.pad_size == p.max_displacement
                 and not getattr(ctx, "is_train", False)):
             # inference only: pallas_call has no reverse-mode rule, so
-            # training must take the differentiable lax lowering below
+            # training must take the differentiable lax lowering
             from .pallas_kernels import correlation as _pallas_corr
             out = _pallas_corr(a, b, p.max_displacement, p.stride2,
-                               p.is_multiply)
+                               p.is_multiply, dense=lax_path)
             if out is not None:
                 return [out]
-        pad = [(0, 0), (0, 0), (p.pad_size, p.pad_size), (p.pad_size, p.pad_size)]
-        ap = jnp.pad(a, pad)
-        bp = jnp.pad(b, pad)
-        outs = []
-        ksz = p.kernel_size
-        norm = float(c * ksz * ksz)
-        for dy in range(-ng, ng + 1):
-            for dx in range(-ng, ng + 1):
-                sy, sx = dy * p.stride2, dx * p.stride2
-                shifted = jnp.roll(bp, shift=(-sy, -sx), axis=(2, 3))
-                if p.is_multiply:
-                    prod = ap * shifted
-                else:
-                    prod = jnp.abs(ap - shifted)
-                # sum over channel and kernel window
-                summed = jnp.sum(prod, axis=1, keepdims=True)
-                if ksz > 1:
-                    summed = lax.reduce_window(
-                        summed, 0.0, lax.add, (1, 1, ksz, ksz), (1, 1, 1, 1),
-                        [(0, 0), (0, 0), (kr, kr), (kr, kr)])
-                # sample output grid starting at border br with stride1
-                sl = summed[:, :, br:br + oh * p.stride1:p.stride1,
-                            br:br + ow * p.stride1:p.stride1]
-                outs.append(sl / norm)
-        return [jnp.concatenate(outs, axis=1)]
+        return [lax_path(a, b)]

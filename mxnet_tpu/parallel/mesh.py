@@ -183,17 +183,9 @@ def replicated(mesh: Mesh) -> NamedSharding:
 
 
 def shard_map_norep(fn, mesh, in_specs, out_specs):
-    """shard_map with replication checking off, across jax versions (the
-    kwarg was renamed check_rep -> check_vma; one shim for every caller —
-    ring attention and the pipeline both need unchecked outputs that are
-    made replicated by explicit collectives)."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-    except TypeError:  # older spelling
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+    """shard_map with replication checking off (one spelling for every
+    caller — ring attention and the pipeline both need unchecked outputs
+    that are made replicated by explicit collectives)."""
+    from jax import shard_map
+    return shard_map(fn, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)

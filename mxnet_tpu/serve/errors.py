@@ -1,4 +1,4 @@
-"""Serving error taxonomy.
+"""Serving error hierarchy.
 
 Every failure a client can see maps to one concrete subclass of
 :class:`ServeError` (itself an :class:`~mxnet_tpu.base.MXNetError`), so

@@ -30,8 +30,9 @@ drain discipline of decode.py and swaps the state story:
   tokens roll back by moving length counters, their stale KV rows are
   simply overwritten later;
 * **attention** — the Pallas page-walk kernel
-  (:func:`mxnet_tpu.ops.pallas_kernels.paged_attention`) on TPU, the
-  dense gather reference off-TPU.  The reference reorders pool rows
+  (:func:`mxnet_tpu.ops.pallas_kernels.paged_attention`) where the step
+  is lowered for a TPU, the dense gather reference on any other
+  platform.  The reference reorders pool rows
   into logical order before one fixed-shape reduction, so dense-stripe
   (``paged=False``) and scattered page tables produce bitwise-identical
   logits — the parity baseline the tests pin.
@@ -166,9 +167,9 @@ class PagedDecodeEngine:
         shares the pool's allocator and page table with its own K/V
         view.
     use_pallas : bool, optional
-        Force the Pallas paged-attention kernel on/off; default
-        ``MXNET_PAGED_PALLAS`` (auto: kernel on TPU, dense reference
-        elsewhere).
+        False pins the dense gather reference everywhere; True (the
+        ``MXNET_PAGED_PALLAS`` default) runs the Pallas kernel where
+        the step is lowered for a TPU and the reference elsewhere.
     """
 
     def __init__(self, params: Dict, cfg: LMConfig, *,
@@ -186,7 +187,6 @@ class PagedDecodeEngine:
                  eos_id: Optional[int] = None,
                  use_pallas: Optional[bool] = None,
                  name: str = "paged", warmup: bool = True):
-        import jax
         import jax.numpy as jnp
 
         from ...compile_cache import cached_jit
@@ -247,10 +247,8 @@ class PagedDecodeEngine:
         self._pool.add_view("target", cfg.layers, cfg.heads, cfg.head_dim)
         self._params = {k: jnp.asarray(v) for k, v in params.items()}
 
-        on_tpu = jax.default_backend() == "tpu"
         if use_pallas is None:
-            use_pallas = on_tpu and bool(
-                get_env("MXNET_PAGED_PALLAS", 1, int))
+            use_pallas = bool(get_env("MXNET_PAGED_PALLAS", 1, int))
         self._use_kernel = bool(use_pallas)
         self._step_jit = cached_jit(
             functools.partial(_paged_step, cfg=cfg,

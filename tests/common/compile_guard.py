@@ -1,67 +1,25 @@
 """Shared steady-state recompile guard.
 
-``count_backend_compiles()`` counts REAL XLA backend compilations via
-jax's monitoring events (``/jax/core/compile/backend_compile_duration``
-fires once per backend compile; cache hits — ours or jax's builtin
-persistent cache — do not fire it).  ``assert_no_compiles()`` turns "a
-retrace in the steady loop" from a silent 10x regression into a tier-1
-test failure: test_serve's no-compiles-in-the-serving-loop assertion,
+``count_backend_compiles()`` (mxnet_tpu.compile_cache) counts the
+programs that reached JAX's backend-compile stage via the public
+``jax.monitoring`` events.  ``assert_no_compiles()`` turns "a retrace in
+the steady loop" from a silent 10x regression into a tier-1 test
+failure: test_serve's no-compiles-in-the-serving-loop assertion,
 generalized for fit / superstep / score / serve loops.
 """
 import contextlib
 
-from jax import monitoring as _monitoring
-import jax._src.monitoring as _monitoring_impl
-
-BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
-
-class CompileCounter:
-    """Counts backend compiles between start() and stop()."""
-
-    def __init__(self):
-        self.count = 0
-        self._active = False
-
-    def _listener(self, event, duration_secs, **kwargs):
-        del duration_secs, kwargs
-        if event == BACKEND_COMPILE_EVENT:
-            self.count += 1
-
-    def start(self):
-        if not self._active:
-            _monitoring.register_event_duration_secs_listener(self._listener)
-            self._active = True
-        return self
-
-    def stop(self):
-        if self._active:
-            _monitoring_impl._unregister_event_duration_listener_by_callback(
-                self._listener)
-            self._active = False
-        return self.count
-
-
-@contextlib.contextmanager
-def count_backend_compiles():
-    """-> CompileCounter; ``counter.count`` holds the XLA backend
-    compiles that happened inside the block."""
-    counter = CompileCounter().start()
-    try:
-        yield counter
-    finally:
-        counter.stop()
+from mxnet_tpu.compile_cache import (  # noqa: F401  (re-exported)
+    CompileCounter, count_backend_compiles)
 
 
 @contextlib.contextmanager
 def assert_no_compiles(what="steady-state loop"):
     """Fail the test if ANY XLA backend compilation happens inside the
     block: every program the block runs must already have been built."""
-    counter = CompileCounter().start()
-    try:
+    with count_backend_compiles() as counter:
         yield counter
-    finally:
-        n = counter.stop()
+    n = counter.count
     assert n == 0, (
         "%s triggered %d XLA compile(s); every program must be built "
         "before the steady loop (a retrace here is a silent 10x "

@@ -1,15 +1,15 @@
 """tools/bench_gate.py: the bench-trajectory regression gate.
 
-Tier-1 contracts from ISSUE 8: the gate exits 0 on the repo's real
-checked-in BENCH trajectory (r02's clock artifact, r03's wedged round
-and r01's pre-fused configuration are skipped as incomparable, not
-counted as regressions), exits nonzero when a synthetic newest round
-regresses a gated metric past the threshold, and treats a silently
-dropped bench leg as a failure too.
+Tier-1 contracts from ISSUE 8, on a synthetic trajectory written into
+tmp_path (no chip record is checked in): the gate exits 0 when the
+incomparable rounds — a probe outside the physical band, a nonzero rc,
+a round that predates the path label — are skipped rather than counted
+as regressions, exits nonzero when the newest round regresses a gated
+metric past the threshold, and treats a silently dropped bench leg as a
+failure too.
 """
 import json
 import os
-import shutil
 import subprocess
 import sys
 
@@ -21,46 +21,62 @@ GATE = os.path.join(REPO, "tools", "bench_gate.py")
 sys.path.insert(0, os.path.join(REPO, "tools"))
 import bench_gate  # noqa: E402
 
+_HEAD = {"metric": "resnet50_train_throughput_per_chip",
+         "unit": "images/sec"}
+_GOOD = dict(_HEAD, path="module_api_fused", value=1000.0, vs_baseline=7.8,
+             mfu=0.30, hfu=0.31, peak_tflops=90.0,
+             lstm_tokens_per_sec=1.0e6, lstm_mfu=0.20,
+             lstm_h1024_tokens_per_sec=4.0e5, lstm_h1024_mfu=0.70)
+# (rc, parsed) per round: r01 predates the path label, r02's probe is
+# outside the physical band, r03 lost the device, r04/r05 are comparable
+_ROUNDS = {
+    1: (0, dict(_HEAD, value=239000.0, vs_baseline=1867.0)),
+    2: (0, dict(_HEAD, path="module_api_fused", value=331000.0,
+                vs_baseline=2586.0, mfu=0.06, peak_tflops=66500.8)),
+    3: (2, dict(_HEAD, value=0.0, vs_baseline=0.0,
+                error="device watchdog timeout")),
+    4: (0, _GOOD),
+    5: (0, dict(_GOOD, value=1100.0, vs_baseline=8.6, mfu=0.33,
+                io_host_cores=1, io_jpeg_img_s=650.0)),
+}
+
 
 def _run(args, cwd):
     return subprocess.run([sys.executable, GATE] + args, cwd=str(cwd),
                           capture_output=True, text=True, timeout=60)
 
 
-def _real_bench_files():
-    return sorted(f for f in os.listdir(REPO)
-                  if f.startswith("BENCH_r") and f.endswith(".json"))
-
-
-def test_gate_passes_on_real_trajectory():
-    res = _run([], REPO)
-    assert res.returncode == 0, res.stdout + res.stderr
-    assert "bench_gate: OK" in res.stdout
-    # the known artifacts are skipped with a reason, not gated
-    assert "BENCH_r02.json (clock-suspect" in res.stdout
-    assert "BENCH_r03.json (rc=2)" in res.stdout
+def _write_round(directory, n, rc, parsed):
+    with open(str(directory / ("BENCH_r%02d.json" % n)), "w") as f:
+        json.dump({"n": n, "rc": rc, "parsed": parsed}, f)
 
 
 @pytest.fixture()
 def trajectory(tmp_path):
-    """The real BENCH files copied somewhere writable."""
-    for f in _real_bench_files():
-        shutil.copy(os.path.join(REPO, f), tmp_path / f)
+    """Five synthetic rounds, three of them incomparable."""
+    for n, (rc, parsed) in _ROUNDS.items():
+        _write_round(tmp_path, n, rc, parsed)
     return tmp_path
 
 
+def test_gate_passes_and_skips_incomparable_rounds(trajectory):
+    res = _run([], trajectory)
+    assert res.returncode == 0, res.stdout + res.stderr
+    assert "bench_gate: OK" in res.stdout
+    # the incomparable rounds are skipped with a reason, not gated
+    assert "BENCH_r02.json (clock-suspect" in res.stdout
+    assert "BENCH_r03.json (rc=2)" in res.stdout
+    assert "BENCH_r01.json (different bench configuration" in res.stdout
+
+
 def _synthetic_round(tmp_path, n=9, scale=None, drop=None):
-    files = _real_bench_files()
-    with open(os.path.join(REPO, files[-1])) as f:
-        doc = json.load(f)
-    parsed = doc["parsed"]
+    parsed = dict(_ROUNDS[max(_ROUNDS)][1])
     if scale:
         for k, s in scale.items():
             parsed[k] = parsed[k] * s
     for k in drop or ():
         parsed.pop(k, None)
-    with open(str(tmp_path / ("BENCH_r%02d.json" % n)), "w") as f:
-        json.dump({"n": n, "rc": 0, "parsed": parsed}, f)
+    _write_round(tmp_path, n, 0, parsed)
 
 
 def test_gate_fails_on_synthetic_regression(trajectory):
@@ -173,16 +189,16 @@ def test_invalid_newest_run_is_an_error(tmp_path):
     assert "not gateable" in res.stderr + res.stdout
 
 
-def test_metrics_typo_fails_with_clear_message():
-    res = _run(["--metrics", "no_such_metric"], REPO)
+def test_metrics_typo_fails_with_clear_message(trajectory):
+    res = _run(["--metrics", "no_such_metric"], trajectory)
     assert res.returncode == 1
     assert "present in no run" in res.stdout
 
 
-def test_gate_api_rows_shape():
-    runs = bench_gate.load_runs(REPO, "BENCH_r*.json")
+def test_gate_api_rows_shape(trajectory):
+    runs = bench_gate.load_runs(str(trajectory), "BENCH_r*.json")
     rows, regressions, newest, priors = bench_gate.gate(runs, threshold=10.0)
-    assert newest.name == _real_bench_files()[-1]
+    assert newest.name == "BENCH_r%02d.json" % max(_ROUNDS)
     assert not regressions
     keys = {r[0] for r in rows}
     assert "value" in keys and "peak_tflops" not in keys

@@ -418,17 +418,35 @@ def test_pallas_fc_epilogue_interpret_parity():
                   - refq.astype(np.int32)).max() <= 1
 
 
-def test_pallas_fc_epilogue_cpu_falls_back():
-    """Off-TPU without interpret the hook must return None so the op's
-    jnp body runs — CPU tier-1 numerics stay the unfused graph's."""
+def test_pallas_fc_epilogue_cpu_lowers_the_dense_body():
+    """Lowered for the CPU, the hook runs the caller's jnp body — no
+    Mosaic call in the program, bitwise the body's result — so CPU
+    tier-1 numerics stay the unfused graph's.  (The choice is made per
+    lowering platform, not from the process's default backend.)"""
     from mxnet_tpu.ops.pallas_kernels import fused_fc_epilogue
     import jax
     import jax.numpy as jnp
-    if jax.default_backend() == "tpu":
-        pytest.skip("TPU host: the kernel path is live here")
-    x = jnp.zeros((8, 128), jnp.float32)
-    w = jnp.zeros((128, 128), jnp.float32)
-    assert fused_fc_epilogue(x, w, None, "relu") is None
+    rng = np.random.RandomState(0)
+    x = jnp.asarray(rng.randn(8, 128).astype(np.float32))
+    w = jnp.asarray(rng.randn(128, 128).astype(np.float32))
+
+    def body(x, w, b):
+        return jax.nn.relu(jnp.dot(x, w.T))
+
+    def hooked(x, w):
+        return fused_fc_epilogue(x, w, None, "relu", dense=body)
+
+    cpu = jax.local_devices(backend="cpu")[0]
+    x, w = jax.device_put(x, cpu), jax.device_put(w, cpu)
+    # lint: allow(raw-jit) — one-off lowering inspection
+    lowered = jax.jit(hooked).lower(x, w)
+    assert "tpu_custom_call" not in lowered.as_text()
+    assert np.array_equal(np.asarray(hooked(x, w)),
+                          np.asarray(body(x, w, None)))
+    # the same trace lowered for a TPU carries the Mosaic kernel
+    # lint: allow(raw-jit) — one-off lowering inspection
+    tpu = jax.jit(hooked).trace(x, w).lower(lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in tpu.as_text()
 
 
 # ---------------------------------------------------------------------------

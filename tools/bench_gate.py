@@ -18,14 +18,12 @@ silently losing a bench leg is itself a regression).
 
 Comparability filters (the trajectory contains known artifacts):
 
-* runs with nonzero ``rc`` or no parsed metrics are skipped (r03's
+* runs with nonzero ``rc`` or no parsed metrics are skipped (a
   wedged-device round);
 * runs whose headline ``metric``/``unit``/``path`` differ from the
-  newest run's are skipped (r01 predates the fused path label);
+  newest run's are skipped (a round that predates the path label);
 * runs whose ``peak_tflops`` probe sits outside the physically sane
-  band are skipped (r02's 66,500 "TF/s" clock artifact — same band as
-  bench.clock_is_suspect, duplicated here so the gate never imports
-  jax).
+  band are skipped (a 66,500 "TF/s" probe is no chip's).
 
 Config keys (``io_host_cores``, ``peak_tflops``, ...) are excluded from
 gating by default; ``--metrics`` gives an explicit allowlist instead,
@@ -41,8 +39,9 @@ import re
 import sys
 from typing import Dict, List, Optional
 
-# mirror of bench.PEAK_SANE_TFLOPS (bench.py imports jax at module
-# level; the gate must stay importable anywhere)
+# no single chip probes below 10 or above 1000 TF/s; a round whose probe
+# did is not comparable (left for ROADMAP D8 with the other old-round
+# filters)
 PEAK_SANE_TFLOPS = (10.0, 1000.0)
 
 # keys that describe the run rather than measure it — never gated unless
