@@ -538,86 +538,129 @@ class BaseModule:
                             ckpt_save(epoch, nbatch)
                     return False
 
+                def step_span(count):
+                    """``fit:step``: one iteration of the loop, from
+                    before its pull to after advance().  Its children
+                    (fit:feed_next, fit:forward_backward, fit:update,
+                    fit:update_metric, fit:batch_end) lie inside it and
+                    do not overlap, so the step minus its children is
+                    the loop's own bookkeeping."""
+                    return _trace.span("fit:step", cat="train",
+                                       step=global_step, epoch=epoch,
+                                       nbatch=nbatch, count=count)
+
+                def pull(data_iter):
+                    """next(data_iter) under ``fit:feed_next``; None at
+                    the epoch's end (that pull records end=True)."""
+                    with _trace.span("fit:feed_next", cat="train") as sp:
+                        try:
+                            return next(data_iter)
+                        except StopIteration:
+                            sp.args = {"end": True}
+                            return None
+
                 def train_one(data_batch, allow_ckpt=True, ckpt_from=None):
                     """The reference per-batch body (the K=1 path)."""
                     if monitor is not None:
                         monitor.tic()
-                    self.forward_backward(data_batch)
-                    self.update()
-                    self.update_metric(eval_metric, data_batch.label)
+                    with _trace.span("fit:forward_backward", cat="train"):
+                        self.forward_backward(data_batch)
+                    with _trace.span("fit:update", cat="train"):
+                        self.update()
+                    with _trace.span("fit:update_metric", cat="train"):
+                        self.update_metric(eval_metric, data_batch.label)
                     if monitor is not None:
                         monitor.toc_print()
-                    fire_batch_end(nbatch, locals())
+                    with _trace.span("fit:batch_end", cat="train"):
+                        fire_batch_end(nbatch, locals())
                     return advance(1, allow_ckpt=allow_ckpt,
                                    ckpt_from=ckpt_from)
 
+                data_iter = iter(train_data)
                 if use_super:
                     # pull K batches (or one prefetch-assembled megabatch)
                     # per iteration and run them as ONE dispatch; a partial
                     # tail or a mid-training fallback (hparams mutated,
-                    # fusion disabled) trains per-batch instead
-                    data_iter = iter(train_data)
+                    # fusion disabled) trains per-batch instead.  One
+                    # fit:step spans the whole iteration; its count is the
+                    # number of batches it trained.
                     while not preempted:
-                        mega, pulled = None, []
-                        while len(pulled) < k_super:
-                            try:
-                                b = next(data_iter)
-                            except StopIteration:
+                        with step_span(k_super) as step:
+                            mega, pulled = None, []
+                            while len(pulled) < k_super:
+                                b = pull(data_iter)
+                                if b is None:
+                                    break
+                                if getattr(b, "megabatch", 0) > 1:
+                                    mega = b
+                                    break
+                                pulled.append(b)
+                            if mega is None and not pulled:
+                                step.cancel()
                                 break
-                            if getattr(b, "megabatch", 0) > 1:
-                                mega = b
-                                break
-                            pulled.append(b)
-                        if mega is None and not pulled:
-                            break
-                        if pulled and (mega is not None
-                                       or len(pulled) < k_super):
-                            # plain batches that cannot form a full K — the
-                            # epoch tail, or stragglers ahead of an arriving
-                            # megabatch: train them per-batch, never drop.
-                            # They were all pulled from the iterator up
-                            # front, so a feed cursor already counts them —
-                            # defer saves to the group's end like the
-                            # unstacked-fallback below.
-                            start_step = global_step
-                            for i, b in enumerate(pulled):
-                                last = i == len(pulled) - 1
-                                if train_one(b, allow_ckpt=last,
-                                             ckpt_from=(start_step if last
-                                                        else None)):
+                            step.args["count"] = len(pulled) + (
+                                mega.megabatch if mega is not None else 0)
+                            if pulled and (mega is not None
+                                           or len(pulled) < k_super):
+                                # plain batches that cannot form a full K —
+                                # the epoch tail, or stragglers ahead of an
+                                # arriving megabatch: train them per-batch,
+                                # never drop.  They were all pulled from the
+                                # iterator up front, so a feed cursor already
+                                # counts them — defer saves to the group's
+                                # end like the unstacked-fallback below.
+                                start_step = global_step
+                                for i, b in enumerate(pulled):
+                                    last = i == len(pulled) - 1
+                                    if train_one(b, allow_ckpt=last,
+                                                 ckpt_from=(start_step if last
+                                                            else None)):
+                                        return
+                                pulled = []
+                            group = mega if mega is not None else pulled
+                            if not group:
+                                continue
+                            count = mega.megabatch if mega is not None \
+                                else len(pulled)
+                            if self.superstep_train(group, eval_metric):
+                                with _trace.span("fit:batch_end",
+                                                 cat="train"):
+                                    fire_batch_end(nbatch + count - 1,
+                                                   locals())
+                                if advance(count):
                                     return
-                            pulled = []
-                        group = mega if mega is not None else pulled
-                        if not group:
-                            continue
-                        count = mega.megabatch if mega is not None \
-                            else len(pulled)
-                        if self.superstep_train(group, eval_metric):
-                            fire_batch_end(nbatch + count - 1, locals())
-                            if advance(count):
-                                return
-                        else:
-                            # superstep refused (fused path gone / hparams
-                            # changed): K=1 fallback.  For an unstacked
-                            # megabatch the feed cursor already counted ALL
-                            # K batches, so a save fired mid-group would
-                            # resume past never-trained data — defer
-                            # preemption/save checks to the group's end (an
-                            # exact boundary again), re-basing the crossing
-                            # test so no save_every multiple is skipped.
-                            singles = mega.unstack() if mega is not None \
-                                else pulled
-                            start_step = global_step
-                            for i, b in enumerate(singles):
-                                last = i == len(singles) - 1
-                                if train_one(b, allow_ckpt=last,
-                                             ckpt_from=(start_step if last
-                                                        else None)):
-                                    return
+                            else:
+                                # superstep refused (fused path gone /
+                                # hparams changed): K=1 fallback.  For an
+                                # unstacked megabatch the feed cursor already
+                                # counted ALL K batches, so a save fired
+                                # mid-group would resume past never-trained
+                                # data — defer preemption/save checks to the
+                                # group's end (an exact boundary again),
+                                # re-basing the crossing test so no
+                                # save_every multiple is skipped.
+                                singles = mega.unstack() if mega is not None \
+                                    else pulled
+                                start_step = global_step
+                                for i, b in enumerate(singles):
+                                    last = i == len(singles) - 1
+                                    if train_one(b, allow_ckpt=last,
+                                                 ckpt_from=(start_step if last
+                                                            else None)):
+                                        return
                 else:
-                    for data_batch in train_data:
-                        if train_one(data_batch):
-                            return
+                    while True:
+                        with step_span(1) as step:
+                            data_batch = pull(data_iter)
+                            if data_batch is None:
+                                step.cancel()
+                                break
+                            bucket_key = getattr(data_batch, "bucket_key",
+                                                 None)
+                            if bucket_key is not None:
+                                step.args["bucket_key"] = bucket_key
+                            if train_one(data_batch):
+                                return
                 if preempted:
                     return
 

@@ -11,6 +11,7 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from ..base import MXNetError
+from .. import trace as _trace
 from ..context import Context, cpu
 from ..ndarray import NDArray, zeros as nd_zeros, concatenate as nd_concatenate
 from ..executor_manager import (_split_input_slice, _load_data, _load_label)
@@ -145,6 +146,7 @@ class DataParallelExecutorGroup:
             weight = sum(w.copyto(cpu())._get() for w in block) / len(block)
             aux_params[name] = NDArray(weight).astype(block[0].dtype)
 
+    @_trace.span("executor:forward", cat="train")
     def forward(self, data_batch, is_train=None):
         _load_data(data_batch, self.data_arrays)
         if is_train is None:
@@ -154,6 +156,7 @@ class DataParallelExecutorGroup:
         for exe in self.execs:
             exe.forward(is_train=is_train)
 
+    @_trace.span("executor:backward", cat="train")
     def backward(self, out_grads=None):
         assert self.for_training, "re-bind with for_training=True to run backward"
         for i, exe in enumerate(self.execs):

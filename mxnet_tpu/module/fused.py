@@ -25,6 +25,7 @@ MXNET_FUSED_TRAIN=0.
 """
 from __future__ import annotations
 
+import time
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -253,9 +254,9 @@ class FusedTrainStep:
         self._step = None
         self._fwd = None
         self._lr_cache = None
-        # multichip observability: per-step dispatch vs (sampled) device
-        # time, plus XLA cost analysis + collective counts once an AOT
-        # compile ran — surfaced via mx.profiler.multichip_report()
+        # multichip observability: per-step dispatch time (and, from
+        # superstep windows, device time), plus XLA cost analysis +
+        # collective counts once an AOT compile ran — surfaced via mx.profiler.multichip_report()
         self.multichip_stats = None
         if len(self.mesh.devices.ravel()) > 1:
             from .. import profiler as _prof
@@ -880,52 +881,23 @@ class FusedTrainStep:
         return self._dispatch(state, batch, self._lr_cache[1], base_key)
 
     def _dispatch(self, state, batch, lr, base_key):
-        """Run the step program, feeding the multichip counters and the
-        span recorder: host dispatch time every step, full device step
-        wall on a sampled subset (one sync every sample_every steps —
-        the async pipeline stays intact between samples)."""
+        """Enqueue the step program under its span (also a profiler
+        annotation, so the launch sits beside the device operations in
+        an xplane).  On a mesh the host dispatch time feeds the
+        multichip counters; nothing here waits for the device."""
         stats = self.multichip_stats
-        import time as _time
-        if stats is None:
-            if not _trace.enabled():
-                return self._step(state, batch, lr, base_key)
-            t0 = _time.perf_counter()
+        first = stats is not None and stats.steps == 0
+        # the first mesh dispatch blocks through trace+compile on a cold
+        # cache: its own span and counter, not the steady average
+        with _trace.span("fused:first_step(compile)" if first
+                         else "fused:dispatch", cat="train"):
+            t0 = time.perf_counter()
             out = self._step(state, batch, lr, base_key)
-            _trace.complete("fused:dispatch", t0,
-                            _time.perf_counter() - t0, cat="train")
-            return out
-        first = stats.steps == 0
-        sample = not first and stats.should_sample()
-        if sample:
-            # drain the async backlog BEFORE timing, or the sampled
-            # wait charges up to sample_every queued steps' device time
-            # to this one step (the input state is the previous step's
-            # output — ready means the queue is empty)
-            jax.block_until_ready(
-                next(iter(state["params"].values()), state["t"]))
-        t0 = _time.perf_counter()
-        out = self._step(state, batch, lr, base_key)
-        dt = _time.perf_counter() - t0
+            dt = time.perf_counter() - t0
         if first:
-            # blocks through trace+compile on a cold cache: its own
-            # counter, not the steady dispatch average
             stats.note_first(dt)
-            _trace.complete("fused:first_step(compile)", t0, dt,
-                            cat="train")
-        else:
+        elif stats is not None:
             stats.add_step(dt)
-            _trace.complete("fused:dispatch", t0, dt, cat="train")
-        if sample:
-            t1 = _time.perf_counter()
-            leaf = next(iter(out[0]["params"].values()), out[0]["t"])
-            jax.block_until_ready(leaf)
-            wait = _time.perf_counter() - t1
-            stats.add_wait(wait)
-            # the sampled device-wall: the one span that shows real
-            # device compute in a timeline otherwise full of async
-            # dispatches
-            _trace.complete("fused:device_wait(sampled)", t1, wait,
-                            cat="train")
         return out
 
     def gather_update_leaf(self, x):

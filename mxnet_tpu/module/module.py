@@ -1067,16 +1067,18 @@ class Module(BaseModule):
                 self._fused_pending = None
                 self._fused_eval_local = False
                 return
-        if self._update_on_kvstore:
-            _update_params_on_kvstore(self._exec_group.param_arrays,
-                                      self._exec_group.grad_arrays,
-                                      self._kvstore)
-        else:
-            _update_params(self._exec_group.param_arrays,
-                           self._exec_group.grad_arrays,
-                           updater=self._updater,
-                           num_device=len(self._context),
-                           kvstore=self._kvstore)
+        with _trace.span("optimizer:update_params", cat="train",
+                         arrays=len(self._exec_group.param_arrays)):
+            if self._update_on_kvstore:
+                _update_params_on_kvstore(self._exec_group.param_arrays,
+                                          self._exec_group.grad_arrays,
+                                          self._kvstore)
+            else:
+                _update_params(self._exec_group.param_arrays,
+                               self._exec_group.grad_arrays,
+                               updater=self._updater,
+                               num_device=len(self._context),
+                               kvstore=self._kvstore)
         self._grads_pending = False
 
     def _fused_live(self):

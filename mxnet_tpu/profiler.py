@@ -18,7 +18,6 @@ MXSetProfilerState) so ported scripts work unchanged.
 """
 from __future__ import annotations
 
-import contextlib
 import os
 import threading
 import weakref
@@ -281,9 +280,10 @@ def superstep_report_str() -> str:
 #
 #   dispatch_s          host time enqueueing the step program (async
 #                       backends return before compute ends)
-#   sampled_device_s    full step wall measured by block_until_ready on
-#                       a sampled subset (1 in sample_every steps — the
-#                       async pipeline stays intact between samples)
+#   sampled_device_s    device wall of superstep windows: the metric
+#                       drain waits there anyway, so no sync is added
+#                       (a K=1 step is never blocked to be timed; read
+#                       its device time from a profiler trace)
 #   flops/bytes         XLA cost analysis of the AOT-compiled step —
 #                       PER DEVICE (SPMD cost analysis reports one
 #                       partition's work)
@@ -351,14 +351,13 @@ class MultichipStats:
     note above).  ``axes`` is the mesh's ((name, size), ...) tuple;
     ``spec_axes`` the axes any per-param sharding spec references."""
 
-    def __init__(self, name: str, axes, spec_axes=(), sample_every: int = 16):
+    def __init__(self, name: str, axes, spec_axes=()):
         self.name = name
         self.axes = tuple((str(a), int(s)) for a, s in axes)
         self.spec_axes = tuple(spec_axes)
         self.devices = 1
         for _, s in self.axes:
             self.devices *= s
-        self.sample_every = max(1, int(sample_every))
         self.steps = 0
         self.dispatch_s = 0.0
         self.first_step_s = 0.0
@@ -378,17 +377,6 @@ class MultichipStats:
         dispatch_s_per_step forever, so it gets its own counter."""
         self.steps += 1
         self.first_step_s = dispatch_s
-
-    def should_sample(self) -> bool:
-        """Checked BEFORE add_step: true on the 2nd, (N+2)th, ... call
-        — never the first, whose wall is compile time (the caller
-        skips it; sample_every=1 samples every step after it)."""
-        return self.sample_every == 1 \
-            or self.steps % self.sample_every == 1
-
-    def add_wait(self, device_s: float) -> None:
-        self.sampled_steps += 1
-        self.sampled_device_s += device_s
 
     def add_superstep(self, k: int, dispatch_s: float,
                       wait_s: float = 0.0) -> None:
@@ -832,15 +820,12 @@ def unified_report_str() -> str:
     return "\n\n".join(parts)
 
 
-@contextlib.contextmanager
 def scope(name: str):
     """Named region visible in BOTH trace timelines: the span runtime's
     Chrome/Perfetto dump (mxnet_tpu.trace) and, while
-    profiler_set_state("run") holds an xprof trace open, jax's
-    TraceAnnotation.  Also usable around host-side work like data
-    loading.  API unchanged from the seed."""
-    import jax
+    profiler_set_state("run") holds an xprof trace open, the profile's
+    host plane (every mxnet_tpu.trace span is a TraceAnnotation).  Also
+    usable around host-side work like data loading.  API unchanged from
+    the seed."""
     from . import trace as _trace
-    with jax.profiler.TraceAnnotation(name):
-        with _trace.span(name, cat="scope"):
-            yield
+    return _trace.span(name, cat="scope")

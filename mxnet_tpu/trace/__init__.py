@@ -23,7 +23,11 @@ cap), and monotonic (perf_counter_ns — the same CLOCK_MONOTONIC
 timeline across forked processes).  Overhead with tracing on is ~a
 microsecond per span; ``MXNET_TRACE=0`` reduces ``complete``/
 ``instant``/``async_*`` call sites to one predicate check (a disabled
-``span`` still costs its two clock reads, nothing more).
+``span`` still costs its two clock reads, nothing more).  Every enabled
+``span`` is also a ``jax.profiler.TraceAnnotation`` of the same name:
+while a profiler session is open it lands in the ``.xplane.pb`` host
+plane beside the device operations, on their clock (``complete`` cannot:
+an interval already measured cannot be annotated afterwards).
 
 Env knobs: ``MXNET_TRACE`` (default 1), ``MXNET_TRACE_BUF_EVENTS``
 (ring capacity per thread, default 65536), ``MXNET_TRACE_JOURNAL`` /
@@ -101,10 +105,27 @@ def reset(buf_events: Optional[int] = None) -> None:
 
 
 # -- recording ------------------------------------------------------------
-class _Span:
-    """Context manager AND decorator for one named span."""
+# jax.profiler.TraceAnnotation, bound on the first span recorded while
+# tracing is enabled: never at ``import mxnet_tpu.trace`` and never under
+# MXNET_TRACE=0.  Idle (no profiler session) it costs ~0.3 us a span.
+_annotation = None
 
-    __slots__ = ("name", "cat", "args", "_t0")
+
+def _load_annotation():
+    global _annotation
+    from jax.profiler import TraceAnnotation
+    _annotation = TraceAnnotation
+    return TraceAnnotation
+
+
+class _Span:
+    """Context manager AND decorator for one named span.  While tracing
+    is enabled the span is also a ``jax.profiler.TraceAnnotation`` of
+    the same name, so an open profiler session (``mx.profiler.
+    profiler_set_state("run")``) shows it in the ``.xplane.pb`` host
+    plane on the device trace's own clock."""
+
+    __slots__ = ("name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, name: str, cat: str, args):
         self.name = name
@@ -112,12 +133,25 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        if _enabled:
+            self._ann = (_annotation or _load_annotation())(self.name)
+            self._ann.__enter__()
+        else:
+            self._ann = None
         self._t0 = time.perf_counter_ns()
         return self
 
+    def cancel(self):
+        """Record nothing at exit (``fit`` opens its step span before
+        the pull that may end the epoch); an open annotation still
+        closes."""
+        self._t0 = None
+
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
-        if _enabled:
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if _enabled and self._t0 is not None:
             _recorder.add("X", self.name, self.cat, self._t0,
                           t1 - self._t0, None, self.args)
         return False
@@ -129,12 +163,8 @@ class _Span:
         def wrapped(*a, **kw):
             if not _enabled:
                 return fn(*a, **kw)
-            t0 = time.perf_counter_ns()
-            try:
+            with _Span(name, cat, args):
                 return fn(*a, **kw)
-            finally:
-                _recorder.add("X", name, cat, t0,
-                              time.perf_counter_ns() - t0, None, args)
         return wrapped
 
 
