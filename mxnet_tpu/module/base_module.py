@@ -29,6 +29,34 @@ def _fire_callbacks(callbacks, param):
         cb(param)
 
 
+def _hold_labels(labels):
+    """Labels of a step whose metric runs after the iterator's next
+    ``next()``: an iterator may refill its label buffers in place (the
+    DataIter contract allows it), and that must not shift the deferred
+    step's labels.  A device array is immutable, so it is held as it is,
+    with no read back to the host; a host array is copied (labels are
+    small; the outputs stay in flight)."""
+    from ..ndarray import NDArray
+
+    def hold(x):
+        if x is None:
+            return None
+        if isinstance(x, NDArray):
+            a = x._get()
+            return NDArray(a.copy() if isinstance(a, np.ndarray) else a)
+        return np.array(x, copy=True)
+    return [hold(x) for x in (labels or [])]
+
+
+def _inspects_outputs(callbacks):
+    """Whether a batch-end callback declares ``inspects_outputs = True``:
+    it reads the module's outputs or parameters and must run while its
+    own batch's are current, so nothing may be dispatched before it."""
+    cbs = callbacks if isinstance(callbacks, list) \
+        else ([callbacks] if callbacks else [])
+    return any(getattr(cb, "inspects_outputs", False) for cb in cbs)
+
+
 class BaseModule:
     """Abstract module (reference base_module.py:41)."""
 
@@ -47,11 +75,12 @@ class BaseModule:
         self.forward(data_batch, is_train=True)
         self.backward()
 
-    def _eval_outputs_async(self):
-        """Hook for score()'s dispatch/metric overlap: return the last
-        eval forward's outputs with their D2H transfers started async,
-        or None to keep the synchronous per-batch order (the default —
-        Module overrides on the fused path)."""
+    def _outputs_in_flight(self):
+        """Hook for the dispatch/metric overlap of fit() and score():
+        the outputs of the step (or eval forward) just dispatched, as
+        device arrays still in flight with their D2H copies started, or
+        None to keep the synchronous per-batch order (the default —
+        Module overrides on the one-process fused path, off the host)."""
         return None
 
     def _wire_eval_augment(self, eval_data):
@@ -100,20 +129,7 @@ class BaseModule:
                                           eval_metric=eval_metric,
                                           locals=loc))
 
-        def snap_labels(labels):
-            # the deferred drain outlives the iterator's next(); an
-            # iterator that refills its label buffers in place (allowed
-            # by the DataIter contract) must not shift the deferred
-            # batch's labels — snapshot them now (labels are tiny; the
-            # big output arrays stay in flight)
-            def snap(x):
-                if x is None:
-                    return None
-                return np.array(x.asnumpy() if hasattr(x, "asnumpy")
-                                else x, copy=True)
-            return [snap(x) for x in (labels or [])]
-
-        pending = None   # (label snapshot, outputs-in-flight, nbatch, locals)
+        pending = None   # (held labels, outputs in flight, nbatch, locals)
 
         def drain(p):
             labels, outs, nb, loc = p
@@ -124,16 +140,13 @@ class BaseModule:
         # the same contract fit() honors) must run while ITS batch's
         # outputs are still current — deferral would hand it the next
         # batch's forward
-        cbs = batch_end_callback if isinstance(batch_end_callback, list) \
-            else ([batch_end_callback] if batch_end_callback else [])
-        defer_ok = not any(getattr(cb, "inspects_outputs", False)
-                           for cb in cbs)
+        defer_ok = not _inspects_outputs(batch_end_callback)
 
         for nbatch, eval_batch in enumerate(eval_data):
             if num_batch is not None and nbatch == num_batch:
                 break
             self.forward(eval_batch, is_train=False)
-            outs = self._eval_outputs_async() if defer_ok else None
+            outs = self._outputs_in_flight() if defer_ok else None
             if outs is None:
                 # synchronous path (classic exec group, worker-local
                 # multi-process eval): drain any deferred batch first so
@@ -152,7 +165,7 @@ class BaseModule:
                 # alive until score() returns (O(batches) device memory)
                 loc = dict(locals())
                 loc.pop("pending", None)
-                pending = (snap_labels(eval_batch.label), outs, nbatch,
+                pending = (_hold_labels(eval_batch.label), outs, nbatch,
                            loc)
         if pending is not None:
             drain(pending)
@@ -214,6 +227,31 @@ class BaseModule:
             checkpoint=None, checkpoint_every=None, resume=False,
             superstep=None, mesh=None, sharding=None, autotune=None):
         """Train (reference base_module.py:273-393).
+
+        **When the metric and the callbacks of a step run.**  Where the
+        step's outputs are device arrays still in flight (Module's fused
+        step in one process on an accelerator; the CPU backend's arrays
+        are the host's own memory), the per-batch loop is pipelined by
+        one step: step N+1 is enqueued first, then ``eval_metric`` is
+        updated with step N's outputs and labels (held meanwhile) and
+        the ``batch_end_callback``s fire for step N, so the host's share
+        runs beside the device instead of after it.  Programs,
+        arithmetic, metric totals, callback order and arguments
+        (``nbatch``, ``eval_metric``, ``locals['data_batch']``) are
+        those of the serial loop.  What differs: at step N's callback
+        the module's parameters and ``get_outputs()`` are already step
+        N+1's, and a change the callback makes to the optimizer reaches
+        step N+2.  A callback that reads outputs or parameters, or
+        changes training state for the very next step, declares
+        ``inspects_outputs = True``, and the loop stays serial, as it
+        does for the classic executor path (``BucketingModule``, fusion
+        off), multi-process training, a ``monitor`` and ``mx.cpu()``.
+        A step that a checkpoint or a preemption follows, and the
+        epoch's last, are finished before anything else is enqueued: a
+        checkpoint at global step S holds the parameters after S
+        updates and the metric through S.  If the next pull or dispatch
+        raises, the outstanding step's callbacks fire before the
+        exception leaves.
 
         ``mesh``/``sharding``: first-class multichip training.  ``mesh``
         is a named device mesh (``parallel.make_mesh([("dp", 4),
@@ -412,6 +450,11 @@ class BaseModule:
                                      k_super, blocker)
                     use_super = False
 
+            # the K=1 loop runs a step's metric and callbacks one step
+            # late only where nothing needs them between two dispatches
+            pipeline_ok = monitor is None and \
+                not _inspects_outputs(batch_end_callback)
+
             if prefetch_to_device and hasattr(self, "prefetch_to_device"):
                 # wrap AFTER init_optimizer so the fused step's batch sharding
                 # exists and staged batches land directly in its input layout;
@@ -538,16 +581,17 @@ class BaseModule:
                             ckpt_save(epoch, nbatch)
                     return False
 
-                def step_span(count):
+                def step_span(count, ahead=0):
                     """``fit:step``: one iteration of the loop, from
                     before its pull to after advance().  Its children
                     (fit:feed_next, fit:forward_backward, fit:update,
                     fit:update_metric, fit:batch_end) lie inside it and
                     do not overlap, so the step minus its children is
-                    the loop's own bookkeeping."""
+                    the loop's own bookkeeping.  ``ahead``: steps
+                    dispatched and not yet counted by advance()."""
                     return _trace.span("fit:step", cat="train",
-                                       step=global_step, epoch=epoch,
-                                       nbatch=nbatch, count=count)
+                                       step=global_step + ahead, epoch=epoch,
+                                       nbatch=nbatch + ahead, count=count)
 
                 def pull(data_iter):
                     """next(data_iter) under ``fit:feed_next``; None at
@@ -559,22 +603,52 @@ class BaseModule:
                             sp.args = {"end": True}
                             return None
 
-                def train_one(data_batch, allow_ckpt=True, ckpt_from=None):
-                    """The reference per-batch body (the K=1 path)."""
+                def dispatch(data_batch):
+                    """Hand one step to the device: forward, backward
+                    and update."""
                     if monitor is not None:
                         monitor.tic()
                     with _trace.span("fit:forward_backward", cat="train"):
                         self.forward_backward(data_batch)
                     with _trace.span("fit:update", cat="train"):
                         self.update()
-                    with _trace.span("fit:update_metric", cat="train"):
-                        self.update_metric(eval_metric, data_batch.label)
+
+                def finish(data_batch, held=None, lag=0, allow_ckpt=True,
+                           ckpt_from=None):
+                    """The host's share of the step dispatch() enqueued:
+                    its metric, its callbacks, advance().  ``held`` is
+                    (labels, outputs) of a step that is no longer the
+                    module's latest; ``lag`` the number of steps
+                    dispatched after this one before its metric ran."""
+                    with _trace.span("fit:update_metric", cat="train",
+                                     for_step=global_step, lag=lag):
+                        if held is None:
+                            self.update_metric(eval_metric, data_batch.label)
+                        else:
+                            eval_metric.update(*held)
                     if monitor is not None:
                         monitor.toc_print()
-                    with _trace.span("fit:batch_end", cat="train"):
-                        fire_batch_end(nbatch, locals())
+                    with _trace.span("fit:batch_end", cat="train",
+                                     for_step=global_step, lag=lag):
+                        fire_batch_end(nbatch, {"data_batch": data_batch})
                     return advance(1, allow_ckpt=allow_ckpt,
                                    ckpt_from=ckpt_from)
+
+                def train_one(data_batch, allow_ckpt=True, ckpt_from=None):
+                    """The reference per-batch body, serial."""
+                    dispatch(data_batch)
+                    return finish(data_batch, allow_ckpt=allow_ckpt,
+                                  ckpt_from=ckpt_from)
+
+                def state_must_be_exact():
+                    """Whether the step just dispatched ends at a point
+                    where the module must hold exactly that many steps:
+                    a checkpoint's cadence, or a preemption to answer."""
+                    if ckpt_mgr is None:
+                        return False
+                    every = ckpt_mgr.save_every_steps
+                    return ckpt_mgr.preempted or bool(
+                        every and (global_step + 1) % every == 0)
 
                 data_iter = iter(train_data)
                 if use_super:
@@ -649,18 +723,69 @@ class BaseModule:
                                                             else None)):
                                         return
                 else:
-                    while True:
-                        with step_span(1) as step:
-                            data_batch = pull(data_iter)
-                            if data_batch is None:
-                                step.cancel()
-                                break
-                            bucket_key = getattr(data_batch, "bucket_key",
-                                                 None)
-                            if bucket_key is not None:
-                                step.args["bucket_key"] = bucket_key
-                            if train_one(data_batch):
-                                return
+                    # the K=1 loop, software-pipelined by one step where
+                    # the step's outputs are device arrays in flight:
+                    # step N's metric and callbacks run after step N+1
+                    # is enqueued, on N's outputs and labels, held
+                    # meanwhile.  Without such outputs (classic executor,
+                    # multi-process, the CPU backend, monitor, an
+                    # inspects_outputs callback) nothing is held and the
+                    # loop is serial.
+                    outstanding = None    # (data_batch, (labels, outputs))
+                    deferred = drained_early = 0
+
+                    def drain(lag=0):
+                        """finish() of the outstanding step.  Never a
+                        checkpoint: the next pull or dispatch has been
+                        made, so the feed's cursor and the parameters
+                        are a step past the one finished here."""
+                        nonlocal outstanding, deferred, drained_early
+                        prev, outstanding = outstanding, None
+                        if lag:
+                            deferred += 1
+                        else:
+                            drained_early += 1
+                        finish(*prev, lag=lag, allow_ckpt=False)
+
+                    try:
+                        while True:
+                            with step_span(1, ahead=int(
+                                    outstanding is not None)) as step:
+                                data_batch = pull(data_iter)
+                                if data_batch is None:
+                                    step.cancel()
+                                    break
+                                bucket_key = getattr(data_batch,
+                                                     "bucket_key", None)
+                                if bucket_key is not None:
+                                    step.args["bucket_key"] = bucket_key
+                                dispatch(data_batch)
+                                outs = self._outputs_in_flight() \
+                                    if pipeline_ok else None
+                                if outstanding is not None:
+                                    drain(lag=1)
+                                if outs is not None and \
+                                        not state_must_be_exact():
+                                    outstanding = (data_batch, (_hold_labels(
+                                        data_batch.label), outs))
+                                    continue
+                                drained_early += int(outs is not None)
+                                if finish(data_batch):
+                                    return
+                        if outstanding is not None:
+                            # the epoch's last step; a preemption is
+                            # answered at the next epoch's first step
+                            drain()
+                    except BaseException:
+                        # the pull or the dispatch failed: the step before
+                        # it was trained, so its callbacks still fire
+                        if outstanding is not None:
+                            drain()
+                        raise
+                    finally:
+                        _trace.counter("fit:deferred", cat="train",
+                                       steps=deferred,
+                                       drained_early=drained_early)
                 if preempted:
                     return
 

@@ -23,6 +23,14 @@ from .fused import FusedTrainStep
 __all__ = ["Module"]
 
 
+def _lives_on_host(array):
+    """Whether a jax array's buffers are the host's own memory (the CPU
+    backend).  Nothing is then in flight to the host that the next
+    dispatch could run under, and fit() and score() keep their serial
+    order; on an accelerator they overlap (_outputs_in_flight)."""
+    return all(d.platform == "cpu" for d in array.devices())
+
+
 class Module(BaseModule):
     """Module over a Symbol (reference module.py:18)."""
 
@@ -1140,24 +1148,27 @@ class Module(BaseModule):
             return
         self._exec_group.update_metric(eval_metric, labels)
 
-    def _eval_outputs_async(self):
-        """score()'s overlap hook: the last eval forward's outputs with
-        their device->host copies STARTED but not awaited, so the next
-        batch's dispatch runs under the transfer and the metric update
-        (which blocks) happens a batch later.  None on the classic /
-        worker-local paths — those keep the synchronous order."""
+    def _outputs_in_flight(self):
+        """The overlap hook of fit() and score(): the outputs of the
+        fused step (or eval forward) just dispatched, as device arrays
+        with their device->host copies STARTED but not awaited, so the
+        next dispatch runs under the transfer and the metric update
+        (which blocks) happens a batch later.  None where the outputs
+        are not in flight — the classic path (fusion off, a monitor),
+        worker-local eval, multi-process training (host_outputs reads
+        this worker's rows synchronously), arrays of the CPU backend
+        (they are the host's own memory: nothing travels) — and the
+        caller keeps the synchronous order."""
         if self._fused is None or self._fused_eval_local or \
-                self._fused_outputs is None:
+                self._fused_outputs is None or self._fused._multiprocess():
             return None
         outs = list(self._fused_outputs)
+        if _lives_on_host(outs[0]._get()):
+            return None
         for o in outs:
-            a = o._get()
-            start = getattr(a, "copy_to_host_async", None)
+            start = getattr(o._get(), "copy_to_host_async", None)
             if callable(start):
-                try:
-                    start()
-                except Exception:
-                    pass
+                start()
         return outs
 
     def install_monitor(self, mon):

@@ -3,7 +3,8 @@
 ``BaseModule.fit`` records one ``fit:step`` per iteration of its loop
 with, inside it and not overlapping, ``fit:feed_next``,
 ``fit:forward_backward``, ``fit:update``, ``fit:update_metric`` and
-``fit:batch_end``; the fused path nests ``fused:dispatch`` in
+``fit:batch_end`` (the last two of the step before where the loop is
+pipelined: tests/test_fit_pipeline.py); the fused path nests ``fused:dispatch`` in
 ``fit:update``, the classic path ``executor:forward``/``backward`` in
 ``fit:forward_backward`` and ``optimizer:update_params`` in
 ``fit:update``.  Every ``mx.trace.span`` is also a
@@ -120,14 +121,58 @@ def _assert_children_in_order(step, kids):
     assert sum(k["dur"] for k in kids) <= step["dur"] + 0.01
 
 
-def test_module_fit_records_one_step_per_batch_with_its_children():
-    _fit_module()
+def _serial(param):
+    """A callback that forces the serial loop."""
+
+
+_serial.inspects_outputs = True
+LOOPS = pytest.mark.parametrize("pipelined", [True, False],
+                                ids=["pipelined", "serial"])
+
+
+def _host_share(step):
+    """The ``fit:update_metric`` and ``fit:batch_end`` of global step
+    ``step``, wherever they lie."""
+    return [e for e in sorted(trace.span_events(names=CHILDREN[3:]),
+                              key=lambda e: e["ts"])
+            if e["args"]["for_step"] == step]
+
+
+@pytest.fixture
+def loop(pipelined, monkeypatch):
+    """The loop an accelerator gets (the CPU backend's arrays are the
+    host's own memory, and the loop stays serial for them), or the
+    serial one the way a user forces it."""
+    from mxnet_tpu.module import module
+    monkeypatch.setattr(module, "_lives_on_host", lambda array: False)
+    return None if pipelined else _serial
+
+
+@LOOPS
+def test_module_fit_records_one_step_per_batch_with_its_children(pipelined,
+                                                                 loop):
+    _fit_module(batch_end_callback=loop)
     tree = _tree(CHILDREN)
     assert len(tree) == 4
     for i, (step, kids) in enumerate(tree):
         assert step["args"] == {"step": i, "epoch": 0, "nbatch": i,
                                 "count": 1}
+        if pipelined and i == 0:
+            # the epoch's first step has no step before it to finish
+            assert [k["name"] for k in kids] == CHILDREN[:3]
+            continue
         _assert_children_in_order(step, kids)
+        # the metric and the callbacks are those of the step before
+        # where the loop is pipelined, this step's own where it is not
+        assert [k["args"] for k in kids[3:]] == \
+            [{"for_step": i - int(pipelined), "lag": int(pipelined)}] * 2
+        assert kids[3:] == _host_share(i - int(pipelined))
+    # the epoch's drain: the last step's share, after the last fit:step
+    last, (metric, batch_end) = tree[-1][0], _host_share(3)
+    assert [metric["name"], batch_end["name"]] == CHILDREN[3:]
+    assert metric["args"] == batch_end["args"] == {"for_step": 3, "lag": 0}
+    assert _end(metric) <= batch_end["ts"] + 0.01
+    assert (metric["ts"] >= _end(last)) == pipelined
 
 
 def test_fused_dispatch_lies_inside_fit_update():
@@ -142,8 +187,9 @@ def test_fused_dispatch_lies_inside_fit_update():
                                         "optimizer:update_params"])
 
 
-def test_epoch_ending_pull_records_no_step():
-    _fit_module()
+@LOOPS
+def test_epoch_ending_pull_records_no_step(pipelined, loop):
+    _fit_module(batch_end_callback=loop)
     pulls = trace.span_events(names=["fit:feed_next"])
     assert len(pulls) == 5
     assert [(p.get("args") or {}).get("end") for p in pulls] == \
@@ -151,6 +197,13 @@ def test_epoch_ending_pull_records_no_step():
     steps = trace.span_events(names=["fit:step"])
     assert len(steps) == 4
     assert not any(_inside(pulls[-1], s) for s in steps)
+    # pipelined, the epoch's drain follows the pull that ended the epoch
+    # and precedes the epoch's own span's end
+    (drain,) = [e for e in trace.span_events(names=["fit:update_metric"])
+                if e["args"]["for_step"] == 3]
+    assert (drain["ts"] >= _end(pulls[-1])) == pipelined
+    (epoch,) = trace.span_events(names=["fit:epoch"])
+    assert _end(drain) <= _end(epoch) + 0.01
 
 
 def test_bucketing_fit_records_the_classic_step():

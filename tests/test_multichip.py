@@ -279,8 +279,10 @@ def test_multichip_report_structure():
 
 
 def test_multichip_crosslink_from_superstep_report():
-    _fit(mesh=[("dp", 8)], superstep=2)
+    # the superstep registry holds its modules weakly: keep this one
+    mod, _ = _fit(mesh=[("dp", 8)], superstep=2)
     assert "multichip_report_str" in mx.profiler.superstep_report_str()
+    assert mod._fused is not None
 
 
 # -- tp-sharded ServeEngine --------------------------------------------------
