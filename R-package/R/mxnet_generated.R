@@ -43,6 +43,15 @@ mx.symbol.Cast <- function(...) {
   mx.symbol.internal.create('Cast', list(...))
 }
 
+#' Causal multi-head self-attention over ``(B, T, H, Dh)`` query,
+#' @param scale scale parameter (default 0.0)
+#' @param layer layer parameter (default -1)
+#' @param name string, node name (auto when omitted)
+#' @return MXSymbol
+mx.symbol.CausalSelfAttention <- function(...) {
+  mx.symbol.internal.create('CausalSelfAttention', list(...))
+}
+
 #' reference concat-inl.h (num_args variable inputs, dim param).
 #' @param num_args num_args parameter (required)
 #' @param dim dim parameter (default 1)
@@ -255,6 +264,14 @@ mx.symbol.Pooling <- function(...) {
   mx.symbol.internal.create('Pooling', list(...))
 }
 
+#' Root-mean-square LayerNorm over the last axis (Zhang & Sennrich
+#' @param eps eps parameter (default 1e-05)
+#' @param name string, node name (auto when omitted)
+#' @return MXSymbol
+mx.symbol.RMSNorm <- function(...) {
+  mx.symbol.internal.create('RMSNorm', list(...))
+}
+
 #' Multi-layer unidirectional recurrent block over lax.scan.
 #' @param state_size state_size parameter (required)
 #' @param num_layers num_layers parameter (required)
@@ -284,6 +301,14 @@ mx.symbol.ROIPooling <- function(...) {
 #' @return MXSymbol
 mx.symbol.Reshape <- function(...) {
   mx.symbol.internal.create('Reshape', list(...))
+}
+
+#' Rotary position embedding of ``(B, T, H, Dh)`` (Su et al. 2021),
+#' @param theta theta parameter (default 10000.0)
+#' @param name string, node name (auto when omitted)
+#' @return MXSymbol
+mx.symbol.RotaryEmbedding <- function(...) {
+  mx.symbol.internal.create('RotaryEmbedding', list(...))
 }
 
 #' reference svm_output-inl.h: hinge-loss gradient layer.
@@ -325,6 +350,13 @@ mx.symbol.Softmax <- function(...) {
 #' @return MXSymbol
 mx.symbol.SoftmaxActivation <- function(...) {
   mx.symbol.internal.create('SoftmaxActivation', list(...))
+}
+
+#' Per-token softmax cross-entropy that emits the LOSS: logits
+#' @param name string, node name (auto when omitted)
+#' @return MXSymbol
+mx.symbol.SoftmaxCELoss <- function(...) {
+  mx.symbol.internal.create('SoftmaxCELoss', list(...))
 }
 
 #' reference softmax_output-inl.h:342.
@@ -677,6 +709,14 @@ mx.nd.Cast <- function(..., ctx = mx.cpu()) {
   mx.nd.internal.invoke('Cast', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
 }
 
+#' Imperative CausalSelfAttention: 3 NDArray input(s), 1 scalar(s)
+#' @return MXNDArray
+mx.nd.CausalSelfAttention <- function(..., ctx = mx.cpu()) {
+  a <- list(...)
+  is.nd <- vapply(a, function(x) inherits(x, 'MXNDArray'), logical(1))
+  mx.nd.internal.invoke('CausalSelfAttention', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
+}
+
 #' Imperative Concat: 1 NDArray input(s), 0 scalar(s)
 #' @return MXNDArray
 mx.nd.Concat <- function(..., ctx = mx.cpu()) {
@@ -821,6 +861,14 @@ mx.nd.Pooling <- function(..., ctx = mx.cpu()) {
   mx.nd.internal.invoke('Pooling', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
 }
 
+#' Imperative RMSNorm: 2 NDArray input(s), 1 scalar(s)
+#' @return MXNDArray
+mx.nd.RMSNorm <- function(..., ctx = mx.cpu()) {
+  a <- list(...)
+  is.nd <- vapply(a, function(x) inherits(x, 'MXNDArray'), logical(1))
+  mx.nd.internal.invoke('RMSNorm', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
+}
+
 #' Imperative RNN: 1 NDArray input(s), 1 scalar(s)
 #' @return MXNDArray
 mx.nd.RNN <- function(..., ctx = mx.cpu()) {
@@ -843,6 +891,14 @@ mx.nd.Reshape <- function(..., ctx = mx.cpu()) {
   a <- list(...)
   is.nd <- vapply(a, function(x) inherits(x, 'MXNDArray'), logical(1))
   mx.nd.internal.invoke('Reshape', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
+}
+
+#' Imperative RotaryEmbedding: 1 NDArray input(s), 1 scalar(s)
+#' @return MXNDArray
+mx.nd.RotaryEmbedding <- function(..., ctx = mx.cpu()) {
+  a <- list(...)
+  is.nd <- vapply(a, function(x) inherits(x, 'MXNDArray'), logical(1))
+  mx.nd.internal.invoke('RotaryEmbedding', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
 }
 
 #' Imperative SVMOutput: 2 NDArray input(s), 2 scalar(s)
@@ -875,6 +931,14 @@ mx.nd.SoftmaxActivation <- function(..., ctx = mx.cpu()) {
   a <- list(...)
   is.nd <- vapply(a, function(x) inherits(x, 'MXNDArray'), logical(1))
   mx.nd.internal.invoke('SoftmaxActivation', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
+}
+
+#' Imperative SoftmaxCELoss: 2 NDArray input(s), 0 scalar(s)
+#' @return MXNDArray
+mx.nd.SoftmaxCELoss <- function(..., ctx = mx.cpu()) {
+  a <- list(...)
+  is.nd <- vapply(a, function(x) inherits(x, 'MXNDArray'), logical(1))
+  mx.nd.internal.invoke('SoftmaxCELoss', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
 }
 
 #' Imperative SoftmaxOutput: 2 NDArray input(s), 2 scalar(s)

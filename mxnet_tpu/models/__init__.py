@@ -14,6 +14,7 @@ from .lstm import (lstm_unroll, lstm_unroll_scan, lstm_cell,
 from .dcgan import make_generator, make_discriminator
 from .fcn import get_fcn32s, get_fcn16s, get_fcn8s
 from .rcnn import get_fast_rcnn, get_rpn
+from .olmoe import olmoe_lm
 from .gru import gru_unroll, gru_cell, rnn_unroll, rnn_cell, GRUState, \
     GRUParam, RNNState, RNNParam
 
@@ -23,6 +24,6 @@ __all__ = ["get_mlp", "get_lenet", "get_resnet", "get_resnet50",
            "lstm_unroll", "lstm_unroll_scan", "lstm_cell", "LSTMState",
            "LSTMParam",
            "make_generator", "make_discriminator", "get_fcn32s", "get_fcn16s", "get_fcn8s",
-           "get_fast_rcnn", "get_rpn", "gru_unroll", "gru_cell",
+           "get_fast_rcnn", "get_rpn", "olmoe_lm", "gru_unroll", "gru_cell",
            "rnn_unroll", "rnn_cell", "GRUState", "GRUParam", "RNNState",
            "RNNParam"]

@@ -626,6 +626,7 @@ class BaseModule:
                             self.update_metric(eval_metric, data_batch.label)
                         else:
                             eval_metric.update(*held)
+                    self._note_train_outputs(held[1] if held else None)
                     if monitor is not None:
                         monitor.toc_print()
                     with _trace.span("fit:batch_end", cat="train",
@@ -926,6 +927,14 @@ class BaseModule:
 
     def update_metric(self, eval_metric, labels):
         raise NotImplementedError()
+
+    def _note_train_outputs(self, outputs=None):
+        """fit()'s hook after a training step's metric update (the
+        step's outputs have been waited for), outside
+        ``fit:update_metric``: ``outputs`` are that step's (held) outputs,
+        None where they are still the module's latest.  A module that
+        derives counters from its outputs overrides it, under a span of
+        its own."""
 
     def bind(self, data_shapes, label_shapes=None, for_training=True,
              inputs_need_grad=False, force_rebind=False, shared_module=None,

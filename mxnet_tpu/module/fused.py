@@ -202,12 +202,14 @@ class FusedTrainStep:
             1, get_env("MXNET_EMBED_STATS_EVERY", 1, int))
         self._embed_stats_n = 0
         # routed-MoE blocks: graph-side detection registers the stats
-        # consumer (per-expert traffic lands here from bench/serve
-        # samplers — routing is data-dependent, so there is nothing to
-        # sample host-side per step) and stamps each block's routing
-        # geometry into the program descriptor
-        from ..moe.detect import find_moe_blocks
+        # consumer and stamps each block's routing geometry into the
+        # program descriptor.  Routing is data-dependent: per-expert
+        # traffic reaches the stats from the step's own outputs where
+        # the symbol carries the blocks' load head (note_outputs),
+        # else from bench/serve samplers
+        from ..moe.detect import find_load_heads, find_moe_blocks
         self.moe_blocks = find_moe_blocks(symbol)
+        self.moe_load_heads = find_load_heads(symbol)
         self.moe_stats = None
         if self.moe_blocks:
             from ..moe.stats import MoeStats
@@ -600,6 +602,22 @@ class FusedTrainStep:
             names = lead if isinstance(lead, tuple) else (lead,)
             return P("dp") if "dp" in names else P()
         return P("dp") if (o.ndim >= 1 and o.shape[0] == rows) else P()
+
+    def note_outputs(self, outs) -> None:
+        """Feed ``MoeStats`` and the ``moe:load`` trace counter (one
+        sample a block: max, mean, empty experts, routed, dropped) from
+        one step's outputs, given as the metric gets them.  One host
+        read of the ``(blocks, E + 1)`` load head, which the metric
+        update before this call already waited for."""
+        idx, blocks = self.moe_load_heads
+        for block, row in zip(blocks, outs[idx].asnumpy()):
+            counts, dropped = row[:-1], float(row[-1])
+            self.moe_stats.note_counts(block, counts, dropped)
+            _trace.counter("moe:load", cat="moe", track=block,
+                           max=float(counts.max()),
+                           mean=float(counts.mean()),
+                           empty=int((counts == 0).sum()),
+                           routed=float(counts.sum()), dropped=dropped)
 
     # -- compiled programs ---------------------------------------------------
     def _make_step_fn(self):

@@ -1148,6 +1148,19 @@ class Module(BaseModule):
             return
         self._exec_group.update_metric(eval_metric, labels)
 
+    def _note_train_outputs(self, outputs=None):
+        """Routed-expert load of the step just scored, from its load
+        head (``FusedTrainStep.note_outputs``), under a span of its own,
+        ``fit:moe_load``: nothing, and no span, where the fused step is
+        off or the symbol carries no such head."""
+        fused = self._fused
+        if fused is None or not fused.moe_load_heads \
+                or not self._fused_live():
+            return
+        with _trace.span("fit:moe_load", cat="train"):
+            fused.note_outputs(self.get_outputs() if outputs is None
+                               else outputs)
+
     def _outputs_in_flight(self):
         """The overlap hook of fit() and score(): the outputs of the
         fused step (or eval forward) just dispatched, as device arrays

@@ -14,7 +14,8 @@ Layers of the subsystem:
                 position-in-expert bucketing, load-balance aux loss
 * ``dispatch``  capacity-bucketed dispatch/combine as pure-jnp
                 primitives (THE scatter choke point — see the
-                ``moe-raw-scatter`` lint rule)
+                ``moe-raw-scatter`` lint rule), and the drop-free
+                layout: rows sorted by expert, grouped matmuls
 * ``layer``     ``MoEFeedForward`` symbol block over the
                 ``_moe_dispatch`` / ``_moe_expert_ffn`` /
                 ``_moe_combine`` ops, ``with_aux_loss`` head attach
@@ -34,13 +35,14 @@ dispatch/combine resharding as collectives — visible in
 from .router import resolve_capacity, route
 from .dispatch import dispatch, combine
 from .layer import (MoEFeedForward, aux_loss_symbols, count_symbols,
-                    hit_symbols, with_aux_loss)
-from .detect import MoEBlockSpec, find_moe_blocks
+                    dropped_symbols, hit_symbols, with_aux_loss,
+                    with_load_heads)
+from .detect import MoEBlockSpec, find_load_heads, find_moe_blocks
 from .stats import MoeStats
 
 __all__ = [
     "resolve_capacity", "route", "dispatch", "combine",
     "MoEFeedForward", "aux_loss_symbols", "count_symbols",
-    "hit_symbols", "with_aux_loss",
-    "MoEBlockSpec", "find_moe_blocks", "MoeStats",
+    "dropped_symbols", "hit_symbols", "with_aux_loss", "with_load_heads",
+    "MoEBlockSpec", "find_load_heads", "find_moe_blocks", "MoeStats",
 ]

@@ -13,7 +13,7 @@ from __future__ import annotations
 
 from typing import Dict
 
-__all__ = ["MoEBlockSpec", "find_moe_blocks"]
+__all__ = ["MoEBlockSpec", "find_load_heads", "find_moe_blocks"]
 
 
 class MoEBlockSpec:
@@ -55,3 +55,39 @@ def find_moe_blocks(symbol) -> Dict[str, MoEBlockSpec]:
             node.name, p.num_experts, p.k, p.capacity_factor,
             p.renormalize)
     return out
+
+
+def find_load_heads(symbol):
+    """``(i, [dispatch node names])``: output ``i`` of ``symbol`` is the
+    ``(blocks, E + 1)`` load head that ``moe.layer.with_load_heads``
+    groups on, and the names are its rows' blocks.  None where the
+    symbol has no such head."""
+    from .layer import _COUNTS_IDX, _DROPPED_IDX
+
+    def op_name(node):
+        return None if node.is_variable else getattr(node.op, "name", "")
+
+    def block_of(node):
+        """The dispatch node of one row, Reshape(Concat(counts,
+        dropped)), else None."""
+        if op_name(node) != "Reshape":
+            return None
+        cat = node.inputs[0][0]
+        if op_name(cat) != "Concat" or len(cat.inputs) != 2:
+            return None
+        (a, i), (b, j) = cat.inputs
+        if a is b and op_name(a) == "_moe_dispatch" \
+                and (i, j) == (_COUNTS_IDX, _DROPPED_IDX):
+            return a.name
+        return None
+
+    for i, (node, _) in enumerate(symbol._heads):
+        if op_name(node) != "BlockGrad":
+            continue
+        node = node.inputs[0][0]
+        rows = [node] if op_name(node) == "Reshape" else \
+            [n for n, _ in node.inputs] if op_name(node) == "Concat" else []
+        blocks = [block_of(r) for r in rows]
+        if blocks and all(blocks):
+            return i, blocks
+    return None

@@ -13,6 +13,14 @@ the expert tensors are sharded over an ``ep``/``tp`` axis (layer.py's
 between the token layout and the expert layout — the all-to-all family
 in ``multichip_report()``'s collective census.
 
+The drop-free layout (``capacity_factor <= 0``) has no buffer to
+scatter into: ``sort_rows`` gathers the ``T*k`` (token, choice) rows in
+expert order, ``grouped_matmul`` multiplies each expert's run of rows by
+that expert's matrix, ``combine_sorted`` gathers them back.  Forward AND
+backward are gathers through the plan's permutation and its inverse
+(each a ``custom_vjp``): the autodiff transpose of a gather is a
+scatter-add, which a TPU executes row by row.
+
 When called eagerly (serving probes, bench, tests) the primitives emit
 ``moe:dispatch`` / ``moe:combine`` trace spans plus a per-call
 ``moe:expert_occupancy`` counter; under a jit trace they stay silent —
@@ -25,7 +33,8 @@ import jax.numpy as jnp
 
 from .. import trace
 
-__all__ = ["dispatch", "combine"]
+__all__ = ["dispatch", "combine", "sort_rows", "combine_sorted",
+           "grouped_matmul"]
 
 
 def _eager(*xs) -> bool:
@@ -89,3 +98,79 @@ def combine(expert_out, slot, weight, num_experts: int, capacity: int):
     with trace.span("moe:combine", cat="moe", tokens=int(T),
                     experts=E, capacity=C):
         return jax.block_until_ready(impl())
+
+
+# -- the drop-free (sorted) layout -------------------------------------------
+
+@jax.custom_vjp
+def _sort_rows(x, order, slot):
+    k = slot.shape[1]
+    return jnp.take(x, order // k, axis=0)
+
+
+def _sort_rows_fwd(x, order, slot):
+    return _sort_rows(x, order, slot), slot
+
+
+def _sort_rows_bwd(slot, g):
+    T, k = slot.shape
+    # token t's gradient: the sum of its k sorted rows' gradients
+    dx = jnp.take(g, slot.reshape(T * k), axis=0).reshape(T, k, -1)
+    return dx.sum(axis=1).astype(g.dtype), None, None
+
+
+_sort_rows.defvjp(_sort_rows_fwd, _sort_rows_bwd)
+
+
+def sort_rows(x, order, slot):
+    """``(T, D)`` tokens -> the ``(T*k, D)`` rows of a ``SortedPlan``:
+    row ``r`` is token ``order[r] // k``."""
+    return _sort_rows(x, order, slot)
+
+
+@jax.custom_vjp
+def _combine_sorted(rows, slot, weight):
+    T, k = slot.shape
+    picked = jnp.take(rows, slot.reshape(T * k), axis=0).reshape(T, k, -1)
+    return (picked * weight[..., None].astype(rows.dtype)).sum(axis=1)
+
+
+def _combine_sorted_fwd(rows, slot, weight):
+    return _combine_sorted(rows, slot, weight), (rows, slot, weight)
+
+
+def _combine_sorted_bwd(res, g):
+    rows, slot, weight = res
+    T, k = slot.shape
+    flat = slot.reshape(T * k)
+    order = jnp.argsort(flat)                # the permutation's inverse
+    w_sorted = jnp.take(weight.reshape(T * k), order)
+    d_rows = jnp.take(g, order // k, axis=0) \
+        * w_sorted[:, None].astype(g.dtype)
+    picked = jnp.take(rows, flat, axis=0).reshape(T, k, -1)
+    d_weight = (picked.astype(jnp.float32)
+                * g[:, None, :].astype(jnp.float32)).sum(axis=-1)
+    return d_rows.astype(rows.dtype), None, d_weight.astype(weight.dtype)
+
+
+_combine_sorted.defvjp(_combine_sorted_fwd, _combine_sorted_bwd)
+
+
+def combine_sorted(rows, slot, weight):
+    """``(T*k, O)`` expert outputs in sorted order -> ``(T, O)``: each
+    token's k rows, weighted by its gate values and summed."""
+    return _combine_sorted(rows, slot, weight)
+
+
+def grouped_matmul(rows, w, group_sizes):
+    """``rows`` (M, K) in expert order x stacked ``w`` (E, K, N) -> (M, N):
+    rows ``sum(group_sizes[:e]) .. + group_sizes[e]`` meet ``w[e]``.
+    Exactly M rows of work whatever the routing: an expert that got no
+    token costs nothing, one that got them all takes them all."""
+    # bfloat16 products are exact at any precision, and XLA:TPU's ragged
+    # dot refuses bfloat16 operands under a "highest" default ("Bad lhs
+    # type", jax 0.9.0): name the precision they run at anyway
+    precision = jax.lax.Precision.DEFAULT \
+        if rows.dtype == jnp.bfloat16 else None
+    return jax.lax.ragged_dot(rows, w, group_sizes.astype(jnp.int32),
+                              precision=precision)

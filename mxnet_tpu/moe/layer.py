@@ -23,12 +23,14 @@ from ..base import get_env
 from .. import symbol as _sym
 
 __all__ = ["MoEFeedForward", "aux_loss_symbols", "count_symbols",
-           "hit_symbols", "with_aux_loss"]
+           "hit_symbols", "dropped_symbols", "with_aux_loss",
+           "with_load_heads"]
 
 # _moe_dispatch output indices (ops/moe.py list_outputs)
 _AUX_IDX = 3
 _COUNTS_IDX = 4
 _HITS_IDX = 5
+_DROPPED_IDX = 6
 
 
 def MoEFeedForward(data, num_hidden: int, num_experts: int, k: int = 2,
@@ -36,24 +38,32 @@ def MoEFeedForward(data, num_hidden: int, num_experts: int, k: int = 2,
                    name: str = "moe", act_type: str = "relu",
                    renormalize: bool = False, output_dim: int = 0,
                    no_bias: bool = False,
-                   expert_axis: Optional[str] = None):
+                   expert_axis: Optional[str] = None,
+                   gated: bool = False, layer: Optional[int] = None):
     """Build one routed MoE feed-forward block over ``data`` (T, D).
 
     ``capacity_factor`` None reads ``MXNET_MOE_CAPACITY_FACTOR``
-    (default 0 = no dropping); ``expert_axis`` names the mesh axis the
-    stacked expert weights shard over (None = replicated).  Returns the
+    (default 0 = no token-choice dropped: the ``T*k`` rows are sorted
+    by expert and the experts are grouped matmuls over exactly those
+    rows; ``> 0`` buckets to a capacity and drops the overflow);
+    ``expert_axis`` names the mesh axis the stacked expert weights
+    shard over (None = replicated).  ``gated`` adds the stacked
+    ``i2h_gate`` projection: ``(act(x Wg) * (x W1)) W2``, SwiGLU with
+    ``act_type="silu"``.  ``layer`` is the block's index in its model,
+    for the trace scopes (``moe_experts.l<layer>``).  Returns the
     combined output symbol; recover the aux-loss / counts heads with
     ``aux_loss_symbols`` / ``count_symbols`` or attach them in one move
     with ``with_aux_loss``.
     """
     if capacity_factor is None:
         capacity_factor = get_env("MXNET_MOE_CAPACITY_FACTOR", 0.0, float)
+    scope = {} if layer is None else {"layer": int(layer)}
     logits = _sym.FullyConnected(data, num_hidden=num_experts,
                                  no_bias=True, name=name + "_gate")
     disp = _sym._moe_dispatch(data, logits, num_experts=num_experts,
                               k=k, capacity_factor=capacity_factor,
                               renormalize=renormalize,
-                              name=name + "_dispatch")
+                              name=name + "_dispatch", **scope)
 
     def expert_var(suffix, spec):
         attr = {"__sharding__": spec} if expert_axis else None
@@ -61,17 +71,18 @@ def MoEFeedForward(data, num_hidden: int, num_experts: int, k: int = 2,
 
     row3 = "%s,None,None" % expert_axis
     row2 = "%s,None" % expert_axis
-    args = [disp[0], expert_var("i2h_weight", row3)]
-    if not no_bias:
-        args.append(expert_var("i2h_bias", row2))
-    args.append(expert_var("h2o_weight", row3))
-    if not no_bias:
-        args.append(expert_var("h2o_bias", row2))
+    args = [disp[0]]
+    for stem in (["i2h_gate"] if gated else []) + ["i2h", "h2o"]:
+        args.append(expert_var(stem + "_weight", row3))
+        if not no_bias:
+            args.append(expert_var(stem + "_bias", row2))
+    args.append(disp[_COUNTS_IDX])
     ffn = _sym._moe_expert_ffn(*args, num_hidden=num_hidden,
                                output_dim=output_dim, act_type=act_type,
-                               no_bias=no_bias, name=name + "_experts")
+                               no_bias=no_bias, gated=gated,
+                               name=name + "_experts", **scope)
     return _sym._moe_combine(ffn, disp[1], disp[2],
-                             name=name + "_combine")
+                             name=name + "_combine", **scope)
 
 
 def _dispatch_heads(symbol, out_idx: int) -> List:
@@ -102,6 +113,31 @@ def hit_symbols(symbol) -> List:
     ``moe_hits`` state variable — ``DecodeEngine(moe_hits_state=...)``
     then samples the running histogram into ``moe_report()``."""
     return _dispatch_heads(symbol, _HITS_IDX)
+
+
+def dropped_symbols(symbol) -> List:
+    """The ``(1,)`` dropped-token-choices head of every MoE block
+    (stop-gradient; 0 for a block that does not drop)."""
+    return _dispatch_heads(symbol, _DROPPED_IDX)
+
+
+def with_load_heads(net):
+    """Group ONE head, ``moe_load``, onto ``net`` behind ``BlockGrad``:
+    the ``(blocks, E + 1)`` stack of every MoE block's ``counts`` and
+    its ``dropped`` count, in ``find_moe_blocks`` order.  It travels with
+    the step's outputs, and ``Module.fit`` feeds ``MoeStats`` and the
+    ``moe:load`` trace counter from it with one host read a step
+    whatever the depth, and no device sync of its own
+    (``FusedTrainStep.note_outputs``).  The blocks must agree on the
+    number of experts.  Returns ``net`` unchanged when the graph has no
+    MoE blocks."""
+    rows = [_sym.Reshape(_sym.Concat(counts, dropped, dim=0), shape=(1, -1))
+            for counts, dropped in zip(count_symbols(net),
+                                       dropped_symbols(net))]
+    if not rows:
+        return net
+    load = rows[0] if len(rows) == 1 else _sym.Concat(*rows, dim=0)
+    return _sym.Group([net, _sym.BlockGrad(load, name="moe_load")])
 
 
 def with_aux_loss(net, grad_scale: Optional[float] = None):

@@ -18,23 +18,31 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["resolve_capacity", "route", "RoutingPlan"]
+__all__ = ["drop_free", "resolve_capacity", "route", "route_sorted",
+           "RoutingPlan", "SortedPlan"]
+
+
+def drop_free(capacity_factor) -> bool:
+    """``capacity_factor <= 0`` (or None): no token-choice is dropped."""
+    return capacity_factor is None or capacity_factor <= 0
 
 
 def resolve_capacity(capacity_factor: float, n_tokens: int,
                      num_experts: int, k: int) -> int:
-    """Static per-expert bucket size for a routing geometry.
+    """Static per-expert bucket size for a routing geometry:
+    ``C = ceil(cf * n_tokens * k / num_experts)`` — the perfectly-
+    balanced load times the slack factor — clamped to ``[1, n_tokens]``.
+    Mirrors ``embed.sparse.resolve_cap``.
 
-    ``capacity_factor <= 0`` means no dropping: the bucket holds the
-    worst case (every token lands on the same expert), i.e. ``C =
-    n_tokens``.  Otherwise ``C = ceil(cf * n_tokens * k / num_experts)``
-    — the perfectly-balanced load times the slack factor — clamped to
-    ``[1, n_tokens]``.  Mirrors ``embed.sparse.resolve_cap``.
+    ``capacity_factor <= 0`` means no dropping, and that has no bucket:
+    the ops take the sorted layout (``route_sorted``, exactly
+    ``n_tokens * k`` rows).  Asking for its capacity is an error.
     """
+    if drop_free(capacity_factor):
+        raise ValueError("capacity_factor <= 0 drops nothing and has no "
+                         "bucket; route it with route_sorted")
     n_tokens = int(n_tokens)
     worst = max(1, n_tokens)
-    if capacity_factor is None or capacity_factor <= 0:
-        return worst
     cap = int(math.ceil(float(capacity_factor) * n_tokens * int(k)
                         / float(max(1, int(num_experts)))))
     return max(1, min(worst, cap))
@@ -110,3 +118,53 @@ def route(logits, k: int, capacity: int,
                        hits=jax.lax.stop_gradient(hits),
                        aux=aux,
                        dropped=jax.lax.stop_gradient(dropped))
+
+
+class SortedPlan(NamedTuple):
+    """The drop-free routing plan: the ``T*k`` (token, choice) pairs
+    sorted by expert, nothing bucketed and nothing dropped.
+
+    ``order``   (T*k,) int32: sorted row ``r`` holds pair ``order[r]``
+                (token ``order[r] // k``), experts ascending, pairs of
+                one expert in token order
+    ``slot``    (T, k) int32: the sorted row of each pair (``order``'s
+                inverse)
+    ``weight``  (T, k) f32 combine weights (the top-k gate values)
+    ``counts``  (E,) f32 pairs per expert = the sorted layout's group
+                sizes; sums to ``T*k``
+    ``hits``    (T, E) f32 per-token assignment one-hots
+    ``aux``     () f32 load-balance loss, as ``RoutingPlan.aux``
+    ``dropped`` () f32, always 0
+    """
+    order: jax.Array
+    slot: jax.Array
+    weight: jax.Array
+    counts: jax.Array
+    hits: jax.Array
+    aux: jax.Array
+    dropped: jax.Array
+
+
+def route_sorted(logits, k: int, renormalize: bool = False) -> SortedPlan:
+    """Route ``(T, E)`` gate logits without a capacity: softmax over all
+    experts, top-k, and a stable sort of the ``T*k`` pairs by expert."""
+    T, E = logits.shape
+    k = int(k)
+    gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    gate_k, expert_k = jax.lax.top_k(gates, k)            # (T, k)
+    if renormalize:
+        gate_k = gate_k / jnp.maximum(
+            gate_k.sum(axis=-1, keepdims=True), jnp.float32(1e-9))
+    order = jnp.argsort(expert_k.reshape(T * k), stable=True)
+    slot = jnp.argsort(order).reshape(T, k)
+    hits = (expert_k[..., None] == jnp.arange(E)).sum(
+        axis=1).astype(jnp.float32)                        # (T, E)
+    counts = hits.sum(axis=0)
+    me = gates.mean(axis=0)
+    ce = counts / jnp.float32(max(1, T * k))
+    aux = (me * ce).sum() * jnp.float32(E)
+    return SortedPlan(order=order.astype(jnp.int32),
+                      slot=slot.astype(jnp.int32), weight=gate_k,
+                      counts=jax.lax.stop_gradient(counts),
+                      hits=jax.lax.stop_gradient(hits), aux=aux,
+                      dropped=jnp.zeros((), jnp.float32))

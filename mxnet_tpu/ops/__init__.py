@@ -13,6 +13,7 @@ from . import rnn     # noqa: F401  (registers the fused scan-based RNN)
 from . import quantized  # noqa: F401 (registers q/dq + int8 matmul/conv)
 from . import fused   # noqa: F401  (registers the epilogue-fused op family)
 from . import moe     # noqa: F401  (registers the routed-MoE dispatch family)
+from . import transformer  # noqa: F401 (registers RMSNorm/RoPE/attention/loss head)
 
 __all__ = ["OpDef", "OpContext", "Param", "register_op", "register_simple_op",
            "get_op", "list_ops"]

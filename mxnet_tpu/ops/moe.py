@@ -10,7 +10,21 @@ fused train step and the decode engine compile each geometry once.
 ``_moe_dispatch`` is multi-output: the ``(E, C, D)`` buffer plus the
 combine weights/slots, the load-balance aux loss (wrap it in
 ``MakeLoss`` to train the router — ``moe.layer.with_aux_loss``), and
-the per-expert accepted counts (stop-gradient; a metric/stats head).
+the per-expert accepted counts and the number of dropped token-choices
+(stop-gradient; metric/stats heads).
+
+Two layouts, chosen by the dispatch node's ``capacity_factor`` alone.
+``> 0``: capacity buckets, ``dispatched`` is ``(E, C, D)`` and
+over-capacity choices drop.  ``<= 0``: nothing drops and nothing is
+bucketed; ``dispatched`` is the ``(T*k, D)`` rows sorted by expert,
+``slot`` the sorted row of each (token, choice), ``counts`` the group
+sizes, and ``_moe_expert_ffn`` is three grouped matmuls over exactly
+``T*k`` rows.  ``_moe_expert_ffn`` and ``_moe_combine`` tell the two by
+the rank of their data, so a pass that re-pins a dispatch node's
+capacity (``MoEServeParityPass``) need touch nothing else.
+
+Each body runs under a ``jax.named_scope`` (``moe_route``,
+``moe_experts``, ``moe_combine``, with ``.l<layer>``).
 """
 from __future__ import annotations
 
@@ -19,37 +33,51 @@ import numpy as np
 import jax.numpy as jnp
 
 from ..base import MXNetError
+from ..moe.router import drop_free
 from .registry import OpDef, Param, register_op
 
-_ACTS = ["relu", "tanh", "sigmoid", "softrelu", "identity"]
+_ACTS = ["relu", "tanh", "sigmoid", "softrelu", "identity", "silu"]
 
 
 def _act(name):
     import jax
     return {"relu": jax.nn.relu, "tanh": jnp.tanh,
             "sigmoid": jax.nn.sigmoid, "softrelu": jax.nn.softplus,
-            "identity": lambda x: x}[name]
+            "identity": lambda x: x, "silu": jax.nn.silu}[name]
+
+
+# _moe_dispatch's output "counts" (list_outputs)
+_COUNTS_OUT = 4
+
+
+def _scope(kind, p):
+    from .transformer import layer_scope
+    return layer_scope(kind, p.layer)
 
 
 @register_op("_moe_dispatch", hint="moe_dispatch")
 class MoEDispatchOp(OpDef):
-    """Route ``data`` (T, D) by ``logits`` (T, E) into the capacity-
-    bucketed expert buffer (E, C, D).  C is static:
-    ``moe.router.resolve_capacity(capacity_factor, T, E, k)``;
-    ``capacity_factor <= 0`` means no dropping (C = T).  Overflowed
-    token-choices fold to the out-of-range sentinel slot ``E*C`` and
-    drop on the scatter — an expert's rows are never corrupted
-    (``moe.dispatch``, the scatter choke point)."""
+    """Route ``data`` (T, D) by ``logits`` (T, E).  ``capacity_factor
+    > 0``: into the capacity-bucketed expert buffer (E, C, D), C static
+    (``moe.router.resolve_capacity(capacity_factor, T, E, k)``);
+    overflowed token-choices fold to the out-of-range sentinel slot
+    ``E*C`` and drop on the scatter — an expert's rows are never
+    corrupted (``moe.dispatch``, the scatter choke point).
+    ``capacity_factor <= 0``: no token-choice is dropped; ``dispatched``
+    is the (T*k, D) rows sorted by expert (``moe.router.route_sorted``)
+    and ``counts`` their group sizes."""
     params = [Param("num_experts", int, required=True),
               Param("k", int, default=2),
               Param("capacity_factor", float, default=0.0),
-              Param("renormalize", bool, default=False)]
+              Param("renormalize", bool, default=False),
+              Param("layer", int, default=-1)]
 
     def list_arguments(self, p):
         return ["data", "logits"]
 
     def list_outputs(self, p):
-        return ["dispatched", "weight", "slot", "aux", "counts", "hits"]
+        return ["dispatched", "weight", "slot", "aux", "counts", "hits",
+                "dropped"]
 
     def _cap(self, p, T):
         from ..moe.router import resolve_capacity
@@ -58,7 +86,7 @@ class MoEDispatchOp(OpDef):
     def infer_shape(self, p, in_shapes):
         d, lg = in_shapes
         if d is None:
-            return in_shapes, [None] * 6, []
+            return in_shapes, [None] * 7, []
         if len(d) != 2:
             raise MXNetError("_moe_dispatch: data must be (tokens, dim), "
                              "got %r" % (d,))
@@ -69,81 +97,148 @@ class MoEDispatchOp(OpDef):
         if lg is not None and tuple(lg) != (T, E):
             raise MXNetError("_moe_dispatch: logits must be (%d, %d), "
                              "got %r" % (T, E, lg))
-        C = self._cap(p, T)
+        if drop_free(p.capacity_factor):
+            buf = (T * k, D)
+        else:
+            buf = (E, self._cap(p, T), D)
         return [d, (T, E)], \
-            [(E, C, D), (T, k), (T, k), (1,), (E,), (T, E)], []
+            [buf, (T, k), (T, k), (1,), (E,), (T, E), (1,)], []
 
     def infer_type(self, p, in_types):
         t = in_types[0] if in_types[0] is not None else np.dtype(np.float32)
         f32 = np.dtype(np.float32)
         return [t, f32], \
-            [t, f32, np.dtype(np.int32), f32, f32, f32], []
+            [t, f32, np.dtype(np.int32), f32, f32, f32, f32], []
 
     def forward(self, p, inputs, aux, ctx):
-        from ..moe.dispatch import dispatch as _dispatch
-        from ..moe.router import route as _route
+        from ..moe.dispatch import dispatch as _dispatch, sort_rows
+        from ..moe.router import route as _route, route_sorted
         x, logits = inputs
         T = x.shape[0]
-        C = self._cap(p, T)
-        plan = _route(logits, p.k, C, renormalize=p.renormalize)
-        buf = _dispatch(x, plan.slot, p.num_experts, C)
-        return [buf, plan.weight, plan.slot,
-                plan.aux.reshape(1), plan.counts, plan.hits]
+        with _scope("moe_route", p):
+            if drop_free(p.capacity_factor):
+                plan = route_sorted(logits, p.k, renormalize=p.renormalize)
+                buf = sort_rows(x, plan.order, plan.slot)
+            else:
+                C = self._cap(p, T)
+                plan = _route(logits, p.k, C, renormalize=p.renormalize)
+                buf = _dispatch(x, plan.slot, p.num_experts, C)
+            return [buf, plan.weight, plan.slot, plan.aux.reshape(1),
+                    plan.counts, plan.hits, plan.dropped.reshape(1)]
 
 
 @register_op("_moe_expert_ffn", hint="moe_experts")
 class MoEExpertFFNOp(OpDef):
-    """Per-expert 2-layer FFN over the dispatched buffer: for each
-    expert ``e``, ``act(x[e] @ w1[e] + b1[e]) @ w2[e] + b2[e]`` as two
-    batched einsums — the stacked weights (E, D, H)/(E, H, O) are what
-    an ``ep``-axis ``__sharding__`` attr shards row-wise, exactly like
-    a row-sharded embedding table."""
+    """Per-expert 2-layer FFN.  Plain: ``act(x @ w1[e] + b1[e]) @ w2[e]
+    + b2[e]``; ``gated``: ``(act(x @ wg[e] + bg[e]) * (x @ w1[e] +
+    b1[e])) @ w2[e] + b2[e]`` (SwiGLU with ``act_type="silu"``).  Over
+    the (E, C, D) buffer it is batched einsums; over the (T*k, D) sorted
+    rows it is grouped matmuls (``moe.dispatch.grouped_matmul``) whose
+    group sizes are ``counts``.  The stacked weights (E, D, H)/(E, H, O)
+    are what an ``ep``-axis ``__sharding__`` attr shards row-wise,
+    exactly like a row-sharded embedding table."""
     params = [Param("num_hidden", int, required=True),
               Param("output_dim", int, default=0),
               Param("act_type", str, default="relu", enum=_ACTS),
-              Param("no_bias", bool, default=False)]
+              Param("no_bias", bool, default=False),
+              Param("gated", bool, default=False),
+              Param("layer", int, default=-1)]
 
     def list_arguments(self, p):
         # *_weight / *_bias suffixes keep auto-created variables on the
         # initializer's name-pattern dispatch (the RNN op's convention)
-        if p.no_bias:
-            return ["data", "i2h_weight", "h2o_weight"]
-        return ["data", "i2h_weight", "i2h_bias", "h2o_weight", "h2o_bias"]
+        names = ["data"]
+        for stem in (["i2h_gate"] if p.gated else []) + ["i2h", "h2o"]:
+            names.append(stem + "_weight")
+            if not p.no_bias:
+                names.append(stem + "_bias")
+        return names + ["counts"]
+
+    def implied_inputs(self, p, given):
+        # counts are the dispatch node's: a caller that gives data alone,
+        # and a graph saved before counts was an input, get them from
+        # the node data comes from
+        if "counts" in given or "data" not in given:
+            return {}
+        src, out = given["data"]
+        if src.is_variable or src.op.name != "_moe_dispatch" or out != 0:
+            raise MXNetError(
+                "_moe_expert_ffn: data is not a _moe_dispatch node's "
+                "first output, so give counts (the group sizes of sorted "
+                "rows; unused over (experts, capacity, dim) buckets)")
+        return {"counts": (src, _COUNTS_OUT)}
 
     def infer_shape(self, p, in_shapes):
-        d = in_shapes[0]
+        d, cnt = in_shapes[0], in_shapes[-1]
         if d is None:
             return in_shapes, [None], []
-        if len(d) != 3:
+        if len(d) == 3:
+            E, D = d[0], d[2]
+        elif len(d) == 2 and cnt is not None:
+            E, D = cnt[0], d[1]
+        elif len(d) == 2:
+            return in_shapes, [None], []
+        else:
             raise MXNetError("_moe_expert_ffn: data must be (experts, "
-                             "capacity, dim), got %r" % (d,))
-        E, C, D = d
+                             "capacity, dim) or sorted (rows, dim), got %r"
+                             % (d,))
         H = p.num_hidden
         O = p.output_dim or D
-        if p.no_bias:
-            shapes = [d, (E, D, H), (E, H, O)]
-        else:
-            shapes = [d, (E, D, H), (E, H), (E, H, O), (E, O)]
-        return shapes, [(E, C, O)], []
+        shapes = [d]
+        for w, b in ([((E, D, H), (E, H))] if p.gated else []) \
+                + [((E, D, H), (E, H)), ((E, H, O), (E, O))]:
+            shapes.append(w)
+            if not p.no_bias:
+                shapes.append(b)
+        return shapes + [(E,)], [tuple(d[:-1]) + (O,)], []
+
+    def infer_type(self, p, in_types):
+        t = in_types[0] if in_types[0] is not None else np.dtype(np.float32)
+        n = len(self.list_arguments(p))
+        return [t] * (n - 1) + [np.dtype(np.float32)], [t], []
 
     def forward(self, p, inputs, aux, ctx):
-        act = _act(p.act_type)
+        x, counts = inputs[0], inputs[-1]
+        layers = list(inputs[1:-1])
         if p.no_bias:
-            x, w1, w2 = inputs
-            h = act(jnp.einsum("ecd,edh->ech", x, w1))
-            return [jnp.einsum("ech,eho->eco", h, w2)]
-        x, w1, b1, w2, b2 = inputs
-        h = act(jnp.einsum("ecd,edh->ech", x, w1) + b1[:, None, :])
-        return [jnp.einsum("ech,eho->eco", h, w2) + b2[:, None, :]]
+            layers = [(w, None) for w in layers]
+        else:
+            layers = list(zip(layers[0::2], layers[1::2]))
+        if x.ndim == 3:
+            def linear(h, w, b):
+                out = jnp.einsum("ecd,edh->ech", h, w)
+                return out if b is None else out + b[:, None, :]
+        else:
+            from ..moe.dispatch import grouped_matmul
+            sizes = counts.astype(jnp.int32)
+            E = counts.shape[0]
+            expert_of_row = None if p.no_bias else jnp.repeat(
+                jnp.arange(E), sizes, total_repeat_length=x.shape[0])
+
+            def linear(h, w, b):
+                out = grouped_matmul(h, w, sizes)
+                return out if b is None else out + jnp.take(
+                    b, expert_of_row, axis=0)
+        act = _act(p.act_type)
+        with _scope("moe_experts", p):
+            if p.gated:
+                (wg, bg), (w1, b1), (w2, b2) = layers
+                h = act(linear(x, wg, bg)) * linear(x, w1, b1)
+            else:
+                (w1, b1), (w2, b2) = layers
+                h = act(linear(x, w1, b1))
+            return [linear(h, w2, b2)]
 
 
 @register_op("_moe_combine", hint="moe_combine")
 class MoECombineOp(OpDef):
-    """Gather expert outputs (E, C, O) back to token order (T, O),
-    weighted by the routing plan's combine weights.  The sentinel slot
-    reads zero (clip-gather + explicit mask in ``moe.dispatch.combine``)
-    so dropped tokens contribute exactly nothing."""
-    params = []
+    """Gather expert outputs back to token order (T, O), weighted by
+    the routing plan's combine weights: from the (E, C, O) buffer, where
+    the sentinel slot reads zero (clip-gather + explicit mask in
+    ``moe.dispatch.combine``) so dropped tokens contribute exactly
+    nothing, or from the (T*k, O) sorted rows, where nothing was
+    dropped."""
+    params = [Param("layer", int, default=-1)]
 
     def list_arguments(self, p):
         return ["data", "weight", "slot"]
@@ -152,19 +247,22 @@ class MoECombineOp(OpDef):
         d, w, s = in_shapes
         if d is None or (w is None and s is None):
             return in_shapes, [None], []
-        if len(d) != 3:
+        if len(d) not in (2, 3):
             raise MXNetError("_moe_combine: data must be (experts, "
-                             "capacity, dim), got %r" % (d,))
+                             "capacity, dim) or sorted (rows, dim), got %r"
+                             % (d,))
         tk = w if w is not None else s
-        E, C, O = d
-        return [d, tuple(tk), tuple(tk)], [(tk[0], O)], []
+        return [d, tuple(tk), tuple(tk)], [(tk[0], d[-1])], []
 
     def infer_type(self, p, in_types):
         t = in_types[0] if in_types[0] is not None else np.dtype(np.float32)
         return [t, np.dtype(np.float32), np.dtype(np.int32)], [t], []
 
     def forward(self, p, inputs, aux, ctx):
-        from ..moe.dispatch import combine as _combine
+        from ..moe.dispatch import combine as _combine, combine_sorted
         x, weight, slot = inputs
-        E, C = x.shape[0], x.shape[1]
-        return [_combine(x, slot, weight, E, C)]
+        with _scope("moe_combine", p):
+            if x.ndim == 2:
+                return [combine_sorted(x, slot, weight)]
+            E, C = x.shape[0], x.shape[1]
+            return [_combine(x, slot, weight, E, C)]

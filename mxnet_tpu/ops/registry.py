@@ -171,6 +171,13 @@ class OpDef:
     def list_outputs(self, p) -> List[str]:
         return ["output"]
 
+    def implied_inputs(self, p, given: Dict[str, Any]) -> Dict[str, Any]:
+        """Arguments the op wires itself from those ``given`` (name ->
+        ``(node, output index)``): asked by the symbol constructor
+        before it auto-creates a variable for a missing argument, and by
+        ``load_json`` for a graph saved before the op gained an input."""
+        return {}
+
     def list_auxiliary_states(self, p) -> List[str]:
         return []
 

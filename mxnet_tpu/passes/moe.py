@@ -6,9 +6,14 @@ next epoch.  At serve time there is no next epoch — a dropped token is
 a corrupted response, and which tokens drop depends on what else is in
 the batch (slot composition under continuous batching), so the same
 request can answer differently run to run.  This pass rewrites every
-``_moe_dispatch`` node to ``capacity_factor=0`` (bucket = worst case,
-nothing folds to the sentinel), making routed serving bitwise parity
-with the dense-gather reference — the contract ``bench_moe``'s
+``_moe_dispatch`` node to ``capacity_factor=0``: no bucket and no
+sentinel, the ``T*k`` token-choices sorted by expert and run as grouped
+matmuls (``moe.router.route_sorted``).  The expert and combine nodes
+follow the rank of the dispatch node's output and every expert node
+already takes the dispatch node's ``counts``, so only the dispatch node
+is rewritten.  Routed serving then answers as the dense-gather reference
+does — the same terms, summed in the grouped matmul's order, so equal to
+rounding and not bit for bit — the contract ``bench_moe``'s
 ``moe_serve_tok_s`` leg asserts.
 
 On by default for serving pipelines; ``MXNET_MOE_SERVE_EXACT=0`` keeps
@@ -53,7 +58,8 @@ class MoEServeParityPass(Pass):
             new = _make_node(
                 "_moe_dispatch", node.name,
                 {"num_experts": p.num_experts, "k": p.k,
-                 "capacity_factor": 0.0, "renormalize": p.renormalize},
+                 "capacity_factor": 0.0, "renormalize": p.renormalize,
+                 "layer": p.layer},
                 new_inputs, attrs=node.attrs)
             rewritten.append(node.name)
             return [(new, i) for i in range(node.num_outputs())]

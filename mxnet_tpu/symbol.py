@@ -496,6 +496,13 @@ def load_json(json_str: str) -> Symbol:
             op = get_op(jn["op"])
             params = op.parse_params(jn.get("param", {}))
             inputs = [(nodes[i], x) for (i, x) in jn["inputs"]]
+            if type(op).implied_inputs is not OpDef.implied_inputs:
+                # saved before the op gained its trailing input(s)?
+                arg_names = op.list_arguments(params)
+                implied = op.implied_inputs(
+                    params, dict(zip(arg_names, inputs)))
+                inputs += [implied[an] for an in arg_names[len(inputs):]
+                           if an in implied]
             nodes.append(_Node(op, jn["name"], params=params,
                                attrs=jn.get("attr", {}), inputs=inputs))
     heads = [(nodes[i], x) for (i, x) in data["heads"]]
@@ -532,12 +539,16 @@ def _create(op_name: str, input_syms: Sequence[Symbol], name: Optional[str] = No
     attr = AttrScope.current().get(attr)
     name = NameManager.current().get(name, op.hint)
     inputs: List[Tuple[_Node, int]] = []
+    for s in inputs_by_name.values():
+        if len(s._heads) != 1:
+            raise MXNetError("cannot use grouped symbol as input")
+    implied = op.implied_inputs(
+        p, {an: s._heads[0] for an, s in inputs_by_name.items()})
     for an in arg_names:
         if an in inputs_by_name:
-            s = inputs_by_name[an]
-            if len(s._heads) != 1:
-                raise MXNetError("cannot use grouped symbol as input")
-            inputs.append(s._heads[0])
+            inputs.append(inputs_by_name[an]._heads[0])
+        elif an in implied:
+            inputs.append(implied[an])
         else:
             # auto-create missing argument variable, e.g. fc1_weight;
             # inherits scope attrs (ctx_group etc.) like the reference

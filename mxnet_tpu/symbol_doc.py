@@ -26,6 +26,63 @@ def get_output_shape(sym, **input_shapes):
     return SymbolDoc.get_output_shape(sym, **input_shapes)
 
 
+# -- the transformer block's ops (ops/transformer.py, ops/moe.py) ------------
+
+class RMSNormDoc(SymbolDoc):
+    """``data / sqrt(mean(data**2, last axis) + eps) * gamma``; the
+    statistics in float32 whatever the data's dtype.  ``gamma`` has the
+    last axis' length and initializes to 1 (the ``*gamma`` name rule).
+
+    >>> x = mx.sym.RMSNorm(mx.sym.Variable("data"), eps=1e-5, name="n")
+    >>> SymbolDoc.get_output_shape(x, data=(8, 128, 2048))
+    {'n_output': (8, 128, 2048)}
+    """
+
+
+class RotaryEmbeddingDoc(SymbolDoc):
+    """Rotary position embedding of ``(batch, seq, heads, head_dim)`` at
+    positions ``0..seq-1``: dimension ``i`` pairs with ``i + head_dim/2``
+    (the half-split of the llama/olmoe modelling code), angle
+    ``pos * theta**(-2i/head_dim)``."""
+
+
+class CausalSelfAttentionDoc(SymbolDoc):
+    """``softmax(q k^T * scale + causal mask) v`` per head over
+    ``(batch, seq, heads, head_dim)`` query, key, value; ``scale`` 0
+    means ``head_dim**-0.5``.  The softmax runs in float32 and the
+    ``(batch, heads, seq, seq)`` scores are never held whole: queries
+    go in blocks whose scores are recomputed in the backward pass.
+
+    >>> q, k, v = (mx.sym.Variable(n) for n in ("q", "k", "v"))
+    >>> a = mx.sym.CausalSelfAttention(q, k, v, name="attn")
+    >>> SymbolDoc.get_output_shape(a, q=(4, 4096, 16, 128))
+    {'attn_output': (4, 4096, 16, 128)}
+    """
+
+
+class SoftmaxCELossDoc(SymbolDoc):
+    """Per-row cross-entropy of ``(rows, classes)`` logits against
+    integer labels ``(rows,)``: the LOSS, float32 ``(rows,)``, not the
+    probabilities ``SoftmaxOutput`` returns, so a metric reads ``rows``
+    numbers a step.  Differentiable; train on it through ``MakeLoss``:
+
+    >>> loss = mx.sym.SoftmaxCELoss(logits, labels)
+    >>> head = mx.sym.MakeLoss(loss, normalization="batch")   # mean CE
+    >>> metric = mx.metric.OutputMean(0)
+    """
+
+
+class _moe_expert_ffnDoc(SymbolDoc):
+    """Per-expert feed-forward over what ``_moe_dispatch`` emits: the
+    ``(E, C, D)`` capacity buckets (batched einsums) or, when the
+    dispatch node's ``capacity_factor <= 0``, the ``(T*k, D)`` rows
+    sorted by expert (grouped matmuls whose group sizes are
+    ``counts``).  ``gated=True`` is ``(act(x Wg) * (x W1)) W2``: SwiGLU
+    with ``act_type="silu"``.  ``counts`` is the last input; left out,
+    it is taken from the dispatch node that feeds ``data``.  Built by
+    ``mx.moe.MoEFeedForward``."""
+
+
 def build_doc(func_name: str, desc: str, arg_names, arg_types, arg_descs,
               key_var_num_args: str = "", ret_type: str = "Symbol"):
     """Assemble a numpy-style docstring from registry metadata (reference

@@ -54,6 +54,7 @@ __all__ = ["span", "complete", "instant", "counter", "async_begin",
            "set_enabled", "dump_trace", "add_spill_dir", "spill_dirs",
            "configure_spill", "flush_spill", "label_process",
            "event_count", "drop_count", "span_events", "instant_events",
+           "counter_events",
            "trace_report",
            "reset", "maybe_journal_step", "write_journal_line",
            "journal_path", "journal_every", "reset_journal"]
@@ -196,15 +197,17 @@ def instant(name: str, cat: str = "host", **attrs) -> None:
                   attrs or None)
 
 
-def counter(name: str, cat: str = "host", **values) -> None:
+def counter(name: str, cat: str = "host", track=None, **values) -> None:
     """Record a Chrome counter sample (``ph: "C"``): each kwarg is one
     series, rendered by Perfetto as a stacked counter track.  The decode
     engine samples its slot occupancy here every step
     (``serve:decode_slots``), so the timeline shows batch fill as a
-    graph alongside the step spans instead of one number in a report."""
+    graph alongside the step spans instead of one number in a report.
+    ``track`` (the event's ``id``) keeps samples of one name apart:
+    ``moe:load`` has one track per routed block."""
     if not _enabled:
         return
-    _recorder.add("C", name, cat, time.perf_counter_ns(), 0, None,
+    _recorder.add("C", name, cat, time.perf_counter_ns(), 0, track,
                   values or None)
 
 
@@ -301,6 +304,17 @@ def span_events(names=None, since_ns: Optional[int] = None,
             continue
         out.append(e)
     return out
+
+
+def counter_events(names=None, since_ns: Optional[int] = None) -> List[Dict]:
+    """Matching counter-sample dicts (``ph: "C"``) from this process's
+    rings, oldest first per thread — the read side of :func:`counter`;
+    a sample's series are its ``args``, its track its ``id``."""
+    name_set = set(names) if names is not None else None
+    return [e for e in _recorder.snapshot()
+            if e.get("ph") == "C"
+            and (name_set is None or e["name"] in name_set)
+            and (since_ns is None or e["ts"] * 1000.0 >= since_ns)]
 
 
 def instant_events(names=None, cat: Optional[str] = None,

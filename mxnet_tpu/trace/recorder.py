@@ -263,7 +263,7 @@ def chrome_event(ev, pid: int, tid: int) -> Dict:
          "pid": pid, "tid": tid}
     if ph == "X":
         d["dur"] = dur_ns / 1000.0
-    elif ph in ("b", "n", "e"):
+    elif ph in ("b", "n", "e") or (ph == "C" and async_id is not None):
         d["id"] = async_id
     elif ph == "i":
         d["s"] = "t"        # instant scope: thread

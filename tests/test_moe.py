@@ -4,7 +4,9 @@ Acceptance battery:
 
 * routed forward at capacity=INF is BITWISE identical to the dense
   gather reference (every token through every expert, same einsum
-  shapes, same k-term weighted sum);
+  shapes, same k-term weighted sum); with capacity_factor <= 0 the ops
+  take the sorted drop-free layout (T*k rows, no bucket: its numerics
+  and its memory are tests/test_olmoe.py's);
 * capacity dropping is sentinel-fold clean: over-capacity slots fold to
   the out-of-range sentinel, read zero on combine, and never corrupt an
   expert row — an expert that accepts no traffic keeps bitwise-frozen
@@ -20,6 +22,7 @@ Acceptance battery:
 * MoEServeParityPass pins serve-time capacity to no-drop, and
   DecodeEngine samples per-slot routing state into moe_report().
 """
+import json
 import os
 import signal
 import subprocess
@@ -85,8 +88,9 @@ def _fit(mesh=None, superstep=None, cf=0.0, expert_axis=None,
 # -- routing math ------------------------------------------------------------
 
 def test_resolve_capacity():
-    assert resolve_capacity(0.0, 64, 4, 2) == 64      # no dropping
-    assert resolve_capacity(None, 64, 4, 2) == 64
+    for no_drop in (0.0, None, -1.0):                 # sorted rows, no bucket
+        with pytest.raises(ValueError, match="route_sorted"):
+            resolve_capacity(no_drop, 64, 4, 2)
     assert resolve_capacity(1.0, 64, 4, 2) == 32      # cf*T*k/E
     assert resolve_capacity(1.25, 256, 8, 2) == 80
     assert resolve_capacity(0.01, 64, 4, 2) == 1      # floor
@@ -152,6 +156,50 @@ def test_capacity_drop_is_sentinel_fold():
     gone = (slot == E * C).all(axis=1)
     assert gone.any() or True
     assert np.all(back[gone] == 0.0)
+
+
+@pytest.mark.parametrize("cf,layout", [(0.0, "sorted"), (-1.0, "sorted"),
+                                       (1.0, "buckets"), (0.5, "buckets")])
+def test_dispatch_layout_follows_capacity_factor(cf, layout):
+    """capacity_factor <= 0 drops nothing and buckets nothing: the
+    dispatch node emits the T*k rows sorted by expert, never the
+    (E, T, D) worst-case buffer it once did; > 0 keeps the (E, C, D)
+    buckets.  The expert and combine nodes follow the rank."""
+    T, D = 64, 6
+    net = MoEFeedForward(mx.sym.Variable("data"), num_hidden=HID,
+                         num_experts=E, k=K, capacity_factor=cf, name="moe")
+    inter = net.get_internals()
+    shapes = dict(zip(inter.list_outputs(),
+                      inter.infer_shape(data=(T, D))[1]))
+    if layout == "sorted":
+        assert shapes["moe_dispatch_dispatched"] == (T * K, D)
+        assert shapes["moe_experts_output"] == (T * K, D)
+    else:
+        C = resolve_capacity(cf, T, E, K)
+        assert shapes["moe_dispatch_dispatched"] == (E, C, D)
+        assert shapes["moe_experts_output"] == (E, C, D)
+    assert shapes["moe_dispatch_counts"] == (E,)
+    assert shapes["moe_dispatch_dropped"] == (1,)
+    assert shapes["moe_combine_output"] == (T, D)
+    # the plan: every choice has its own sorted row, or a bucket slot
+    rng = np.random.RandomState(8)
+    x = mx.nd.array(rng.randn(T, D).astype(np.float32))
+    exe = inter.simple_bind(mx.cpu(), data=(T, D), grad_req="null")
+    exe.arg_dict["data"][:] = x
+    for name, arr in exe.arg_dict.items():
+        if name != "data":
+            arr[:] = rng.randn(*arr.shape).astype(np.float32) * 0.3
+    exe.forward(is_train=False)
+    outs = dict(zip(inter.list_outputs(), exe.outputs))
+    slot = outs["moe_dispatch_slot"].asnumpy()
+    counts = outs["moe_dispatch_counts"].asnumpy()
+    dropped = float(outs["moe_dispatch_dropped"].asnumpy()[0])
+    assert counts.sum() + dropped == T * K
+    if layout == "sorted":
+        assert dropped == 0.0
+        assert sorted(slot.reshape(-1)) == list(range(T * K))
+        rows = outs["moe_dispatch_dispatched"].asnumpy()
+        assert np.array_equal(rows[slot[:, 0]], x.asnumpy())
 
 
 # -- untouched-expert freeze through a real train step -----------------------
@@ -371,6 +419,86 @@ def _decode_params(seed=4):
             "dmoe_experts_h2o_bias": np.zeros((E, SV_EMB), np.float32),
             "out_weight": g(SV_VOCAB, SV_EMB),
             "out_bias": np.zeros(SV_VOCAB, np.float32)}
+
+
+def _symbol_json_saved_before_counts(no_bias, cf):
+    """``MoEFeedForward(..., name="moe").tojson()`` as the commit before
+    the sorted layout wrote it: the expert node has 3 or 5 inputs (no
+    ``counts``), no node has ``gated`` / ``layer``."""
+    def var(name):
+        return {"op": "null", "name": name, "attr": {}, "inputs": []}
+
+    nodes = [var("data"), var("moe_gate_weight"),
+             {"op": "FullyConnected", "name": "moe_gate",
+              "param": {"num_hidden": str(E), "no_bias": "True"},
+              "attr": {}, "inputs": [[0, 0], [1, 0]]},
+             {"op": "_moe_dispatch", "name": "moe_dispatch",
+              "param": {"num_experts": str(E), "k": str(K),
+                        "capacity_factor": str(cf),
+                        "renormalize": "False"},
+              "attr": {}, "inputs": [[0, 0], [2, 0]]}]
+    weights = ["moe_experts_" + n for n in
+               (["i2h_weight", "h2o_weight"] if no_bias else
+                ["i2h_weight", "i2h_bias", "h2o_weight", "h2o_bias"])]
+    nodes += [var(n) for n in weights]
+    ffn = len(nodes)
+    nodes.append({"op": "_moe_expert_ffn", "name": "moe_experts",
+                  "param": {"num_hidden": str(HID), "output_dim": "0",
+                            "act_type": "relu", "no_bias": str(no_bias)},
+                  "attr": {},
+                  "inputs": [[3, 0]] + [[4 + i, 0]
+                                        for i in range(len(weights))]})
+    nodes.append({"op": "_moe_combine", "name": "moe_combine", "param": {},
+                  "attr": {}, "inputs": [[ffn, 0], [3, 1], [3, 2]]})
+    return json.dumps({
+        "nodes": nodes, "heads": [[ffn + 1, 0]],
+        "arg_nodes": [i for i, n in enumerate(nodes) if n["op"] == "null"],
+        "attrs": {"mxnet_tpu_version": 1}})
+
+
+@pytest.mark.parametrize("no_bias,cf", [(False, 0.0), (True, 0.0),
+                                        (False, 1.25), (True, 0.5)])
+def test_a_symbol_saved_before_counts_was_an_input_still_runs(no_bias, cf):
+    """The expert node gained a trailing ``counts`` input with the
+    sorted layout.  A graph saved before has none: ``load_json`` takes
+    them from the dispatch node that feeds the expert node's data, so
+    the old file binds with the arguments it always had and answers as
+    today's builder does."""
+    old = mx.sym.load_json(_symbol_json_saved_before_counts(no_bias, cf))
+    new = MoEFeedForward(mx.sym.Variable("data"), num_hidden=HID,
+                         num_experts=E, k=K, capacity_factor=cf,
+                         no_bias=no_bias, name="moe")
+    assert old.list_arguments() == new.list_arguments()
+    assert not any("counts" in a for a in old.list_arguments())
+    T, D = 32, 6
+    rng = np.random.RandomState(11)
+    outs = []
+    for net in (old, new):
+        exe = net.simple_bind(mx.cpu(), data=(T, D), grad_req="null")
+        for name in net.list_arguments():
+            exe.arg_dict[name][:] = np.random.RandomState(
+                len(name)).randn(*exe.arg_dict[name].shape) * 0.3
+        exe.forward(is_train=False)
+        outs.append(exe.outputs[0].asnumpy())
+    assert outs[0].shape == (T, D) and np.abs(outs[0]).sum() > 0
+    assert np.array_equal(outs[0], outs[1])
+    # saved again it is today's graph, counts wired
+    again = json.loads(old.tojson())
+    ffn = next(n for n in again["nodes"] if n["op"] == "_moe_expert_ffn")
+    assert len(ffn["inputs"]) == (4 if no_bias else 6)
+
+
+def test_expert_node_on_other_data_must_be_given_counts():
+    """No silent ``<name>_counts`` variable: data that is not a dispatch
+    node's output needs counts spelled out."""
+    with pytest.raises(mx.base.MXNetError, match="give counts"):
+        mx.sym._moe_expert_ffn(mx.sym.Variable("buf"), num_hidden=HID,
+                               no_bias=True, name="ffn")
+    net = mx.sym._moe_expert_ffn(mx.sym.Variable("buf"),
+                                 counts=mx.sym.Variable("sizes"),
+                                 num_hidden=HID, no_bias=True, name="ffn")
+    assert net.list_arguments() == ["buf", "ffn_i2h_weight",
+                                    "ffn_h2o_weight", "sizes"]
 
 
 def test_serve_parity_pass_pins_capacity(monkeypatch):

@@ -1,0 +1,235 @@
+"""OLMoE-1B-7B at its published widths on the chip (one layer, as the
+``olmoe-1b-7b`` configuration is cut), against the plain reference
+``benchmark/reference/olmoe-1b-7b.py`` computed on the same chip.
+
+    MXNET_TPU_TESTS=1 python -m pytest tests/tpu/test_olmoe_tpu.py -s -q
+
+One test, six phases that each release what they held (the chip holds
+one 0.47 G-parameter module at a time): the logits of the last 256
+positions of two seeded sequences through a plain executor in float32;
+the reference's loss, logits and gradients (and the same with its
+weights rounded to float8); one SGD step of the fused
+train step in float32 and one in bfloat16 (the gradient read back as
+``(w - w') / lr``); and the configuration's own Adam step in bfloat16,
+where the token embedding takes the lazy sparse path.  The numbers go to
+``chiprun_out/olmoe_parity.json`` after every phase, before anything is
+asserted.
+tests/tpu/conftest.py pins "highest" matmul precision, so float32 is
+float32 on both sides; bfloat16 operands are exact under it.
+"""
+import gc
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+
+from _mirror import tpu_gate
+
+pytestmark = [tpu_gate()]
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+NAMED = ["l0_moe_gate_weight", "l0_moe_experts_i2h_weight",
+         "l0_q_proj_weight", "embed_weight"]
+LAST = 256
+SGD_LR = 1024.0
+# float32 against float32 on the same chip: the order of the sums only
+# (tests/test_olmoe.py reads 2e-6..3e-5 on the CPU at tiny widths; 4096
+# keys and 2048-wide sums make it a few times that)
+F32_LOGIT_ATOL_SHARE = 2e-4      # of the largest |logit|
+F32_LOSS_RTOL = 1e-5
+F32_GRAD_RTOL = 1e-3
+# bfloat16 compute against the float32 reference: 8 bits of mantissa a
+# rounding, through ~10 roundings a layer; a token near the top-8's edge
+# changes expert, which moves the router's and the experts' gradients
+BF16_LOSS_RTOL = 2e-3
+BF16_GRAD_RTOL = {"l0_moe_gate_weight": 0.5, "l0_moe_experts_i2h_weight":
+                  0.2, "l0_q_proj_weight": 0.2, "embed_weight": 0.2}
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+def _module_step(net, params, tokens, labels, optimizer, opt_params,
+                 compute_dtype):
+    """One step of the fused train step on the chip.  -> (mean CE, aux,
+    counts, {name: after - before for NAMED}, sparse embeds engaged)."""
+    import mxnet_tpu as mx
+    if compute_dtype:
+        os.environ["MXNET_COMPUTE_DTYPE"] = compute_dtype
+    else:
+        os.environ.pop("MXNET_COMPUTE_DTYPE", None)
+    try:
+        mod = mx.mod.Module(net, context=mx.tpu(0))
+        mod.bind(data_shapes=[("data", tokens.shape)],
+                 label_shapes=[("softmax_label", labels.shape)])
+        mod.init_params(arg_params={k: mx.nd.array(v)
+                                    for k, v in params.items()},
+                        aux_params={})
+        gc.collect()
+        mod.init_optimizer(optimizer=optimizer,
+                           optimizer_params=dict(opt_params))
+        assert mod._fused is not None
+        sparse = sorted(mod._fused.sparse_embeds)
+        batch = mx.io.DataBatch(data=[mx.nd.array(tokens)],
+                                label=[mx.nd.array(labels)], pad=0)
+        mod.forward_backward(batch)
+        mod.update()
+        outs = [o.asnumpy() for o in mod.get_outputs()]
+        after = mod.get_params()[0]
+        delta = {n: after[n].asnumpy() - params[n] for n in NAMED}
+        del mod, after, batch
+    finally:
+        os.environ.pop("MXNET_COMPUTE_DTYPE", None)
+    gc.collect()
+    # one layer: the loss head, its aux head, the (1, E + 1) load head
+    return (float(outs[0].mean()), float(outs[1][0]), outs[2][0, :-1], delta,
+            sparse)
+
+
+def test_published_width_step_matches_reference():
+    import jax
+    import mxnet_tpu as mx
+    from mxnet_tpu.models import olmoe_lm
+    sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+    import manifest
+    ref = manifest.load_module("reference", "olmoe-1b-7b")
+    gen = manifest.load_module("generators", "token_packed")
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "olmoe-1b-7b.json")) as f:
+        cfg = json.load(f)
+    kw = cfg["model"]["kwargs"]
+    seq, vocab = kw["seq_len"], kw["vocab_size"]
+    net = olmoe_lm(**kw)
+    shapes = dict(zip(net.list_arguments(), net.infer_shape(
+        data=(1, seq), softmax_label=(1, seq))[0]))
+    rng = np.random.RandomState(26)
+    params = {n: (np.ones(s, np.float32) if n.endswith("gamma") else
+                  (0.02 * rng.standard_normal(s)).astype(np.float32))
+              for n, s in shapes.items()
+              if n not in ("data", "softmax_label")}
+    stream = gen.markov_stream(np.random.RandomState(2600000026 % 2 ** 32),
+                               2 * seq + 1, vocab, 0.85, 1.2, 600.0)
+    tokens = stream[:-1].reshape(2, seq)
+    labels = stream[1:].reshape(2, seq)
+    report = {"device": jax.devices()[0].device_kind,
+              "params_M": sum(v.size for v in params.values()) / 1e6}
+    out_dir = os.path.join(ROOT, "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+
+    def save():
+        with open(os.path.join(out_dir, "olmoe_parity.json"), "w") as f:
+            json.dump(report, f, indent=1)
+        print("\nOLMOE_PARITY " + json.dumps(report), flush=True)
+
+    # A. float32 logits of the last LAST positions, two sequences
+    logits_sym = net.get_internals()["lm_head_output"]
+    exe = logits_sym.simple_bind(mx.tpu(0), data=(1, seq), grad_req="null")
+    for name, val in params.items():
+        exe.arg_dict[name][:] = val
+    got_logits = []
+    for s in range(2):
+        exe.arg_dict["data"][:] = tokens[s:s + 1]
+        exe.forward(is_train=False)
+        got_logits.append(exe.outputs[0].asnumpy()[-LAST:])
+    del exe
+    gc.collect()
+
+    # B. the reference, on this chip, float32 at highest precision
+    want_logits = []
+    for s in range(2):
+        out = ref.loss_and_grads(cfg, params, tokens[s:s + 1],
+                                 labels[s:s + 1], NAMED)
+        want_logits.append(np.asarray(out["logits"][-LAST:]))
+        if s == 0:
+            want = {"loss": out["loss"], "aux": out["aux"][0],
+                    "counts": np.asarray(out["counts"][0]),
+                    "grads": {n: np.asarray(out["grads"][n])
+                              for n in NAMED}}
+            adam = {n: np.asarray(ref.adam_first_step(
+                out["grads"][n], cfg["optimizer"]["params"]))
+                for n in NAMED}
+        del out
+        gc.collect()
+    # B2. the same reference with its weights rounded to float8 (e4m3,
+    # the nearest format under the configuration's bfloat16; the
+    # arithmetic stays float32, so this reads low): what the limits of
+    # the configuration's reference check have to refuse
+    import jax.numpy as jnp
+    coarse = {n: np.asarray(jnp.asarray(v).astype(jnp.float8_e4m3fn)
+                            .astype(jnp.float32))
+              for n, v in params.items()}
+    out = ref.loss_and_grads(cfg, coarse, tokens[:1], labels[:1], NAMED)
+    report["reference_fp8_weights"] = {
+        "loss": out["loss"], "reference_loss": want["loss"],
+        "loss_rel_err": abs(out["loss"] - want["loss"]) / want["loss"],
+        "adam_update_rel_err": {n: _rel(ref.adam_first_step(
+            out["grads"][n], cfg["optimizer"]["params"]), adam[n])
+            for n in NAMED}}
+    del out, coarse
+    gc.collect()
+    peak = max(np.abs(w).max() for w in want_logits)
+    report["logits_f32"] = {
+        "max_abs_err": [float(np.abs(g - w).max())
+                        for g, w in zip(got_logits, want_logits)],
+        "max_abs_logit": float(peak)}
+    save()
+
+    # C, D. one SGD step of the fused train step: float32, bfloat16
+    sgd = {"learning_rate": SGD_LR, "momentum": 0.0, "wd": 0.0,
+           "rescale_grad": 1.0}
+    for tag, dtype in (("f32", None), ("bf16", "bfloat16")):
+        loss, aux, counts, delta, _ = _module_step(
+            net, params, tokens[:1], labels[:1], "sgd", sgd, dtype)
+        report["step_" + tag] = {
+            "loss": loss, "reference_loss": want["loss"],
+            "aux": aux, "reference_aux": want["aux"],
+            "counts_equal": bool(np.array_equal(counts, want["counts"])),
+            "choices_moved": float(np.abs(counts - want["counts"]).sum()
+                                   / 2),
+            "grad_rel_err": {n: _rel(-delta[n] / SGD_LR, want["grads"][n])
+                             for n in NAMED}}
+        save()
+
+    # E. the configuration's Adam step in bfloat16: what the cell's own
+    # reference check compares
+    loss, _, _, delta, sparse = _module_step(
+        net, params, tokens[:1], labels[:1], cfg["optimizer"]["name"],
+        cfg["optimizer"]["params"], "bfloat16")
+    touched = np.zeros(vocab, bool)
+    touched[np.unique(tokens[0])] = True
+    emb, emb_ref = delta["embed_weight"], adam["embed_weight"]
+    report["adam_bf16"] = {
+        "loss": loss, "sparse_embeds": sparse,
+        "update_rel_err": {n: _rel(delta[n], adam[n]) for n in NAMED},
+        "embed_rows_touched": int(touched.sum()),
+        "embed_untouched_max_abs_update": float(np.abs(emb[~touched]).max()),
+        "reference_untouched_max_abs_update":
+            float(np.abs(emb_ref[~touched]).max()),
+        "embed_touched_rel_err": _rel(emb[touched], emb_ref[touched]),
+        "sign_agreement": {n: float(np.mean(np.sign(delta[n])
+                                            == np.sign(adam[n])))
+                           for n in NAMED}}
+
+    save()
+
+    for err in report["logits_f32"]["max_abs_err"]:
+        assert err <= F32_LOGIT_ATOL_SHARE * peak
+    f32, bf16 = report["step_f32"], report["step_bf16"]
+    assert abs(f32["loss"] - want["loss"]) <= F32_LOSS_RTOL * want["loss"]
+    assert f32["counts_equal"]
+    assert max(f32["grad_rel_err"].values()) <= F32_GRAD_RTOL, f32
+    assert abs(bf16["loss"] - want["loss"]) <= BF16_LOSS_RTOL * want["loss"]
+    for n, bound in BF16_GRAD_RTOL.items():
+        assert bf16["grad_rel_err"][n] <= bound, (n, bf16)
+    # the float32 bound is one bfloat16 compute does not meet
+    assert max(bf16["grad_rel_err"].values()) > F32_GRAD_RTOL
+    assert sparse == ["embed_weight"]
+    # rows the batch did not touch: the lazy sparse update leaves them,
+    # and so does dense Adam's first step (zero gradient, zero state)
+    assert report["adam_bf16"]["embed_untouched_max_abs_update"] == 0.0
+    assert report["adam_bf16"]["reference_untouched_max_abs_update"] == 0.0
