@@ -8,10 +8,16 @@ run in float32 whatever the compute dtype: the RMS, the attention
 softmax and the loss's log-sum-exp.
 
 Attention never materializes the ``(B, H, T, T)`` scores: the op calls
-ONE inner function, ``causal_attention``, which walks the queries in
-blocks under ``lax.map`` and recomputes each block's scores in the
-backward pass (``jax.checkpoint``), so its memory is one block's scores
-and a kernel can replace the function later.
+ONE inner function, ``causal_attention``, blockwise softmax attention
+that recomputes the scores in the backward pass.  It has two lowerings.
+The plain blocks walk the queries under ``lax.map`` of a
+``jax.checkpoint``ed body and run on every platform.  Where the program
+is lowered for a TPU and the inputs are ones the kernel takes (bfloat16,
+``Dh`` a multiple of 128, ``T`` whole tiles), JAX's own Pallas splash
+attention under a causal mask (online softmax in VMEM, tiles above the
+diagonal skipped, a fused backward kernel) runs instead.  The code
+chooses from what it sees, no option does; ``attn:lowering`` records
+the choice.
 
 The bodies of ``CausalSelfAttention`` and ``SoftmaxCELoss`` run under a
 ``jax.named_scope`` (``attn.l<layer>``, ``lm_loss``) so a device trace
@@ -19,12 +25,16 @@ can tell the block's parts apart.
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import trace
 from ..base import MXNetError
+from .pallas_kernels import _kernel_on_tpu
 from .registry import OpDef, Param, register_op
 
 __all__ = ["causal_attention", "rms_norm", "rotary_embedding"]
@@ -32,6 +42,11 @@ __all__ = ["causal_attention", "rms_norm", "rotary_embedding"]
 # queries per block of causal_attention: one block's float32 scores are
 # B*H*ATTN_BLOCK_Q*T*4 bytes (0.5 GB at B=4, H=16, T=4096)
 ATTN_BLOCK_Q = 512
+# the TPU kernel's tiling, forward and backward: queries and keys per
+# tile, and keys per matmul inside a tile (the fastest of 38 settings of
+# two library kernels on a v5e at (4, 4096, 16, 128): PERF.md, PR 27)
+ATTN_KERNEL_BLOCK = 1024
+ATTN_KERNEL_SLICE = 512
 
 
 def layer_scope(kind: str, layer):
@@ -70,9 +85,96 @@ def rotary_embedding(x, theta: float):
 
 def causal_attention(q, k, v, scale: float):
     """Causal multi-head self-attention of ``(B, T, H, Dh)`` q, k, v ->
-    ``(B, T, H, Dh)``; softmax in float32.  Queries go in blocks of
-    ATTN_BLOCK_Q; a block's scores live only inside its (checkpointed)
-    body, in the forward and again in the backward pass."""
+    ``(B, T, H, Dh)``; scores, softmax and accumulation in float32.
+
+    One algorithm, two lowerings.  Inputs the flash-attention kernel
+    takes (``_kernel_takes``) run it where the program is LOWERED for a
+    TPU and the plain blocks on any other platform; every other input
+    runs the plain blocks everywhere.  Each trace records which, as the
+    counter ``attn:lowering``: ``kernel`` 1 means this op's TPU lowering
+    is the kernel (the lowered text of a CPU program holds the plain
+    blocks all the same), ``plain`` 1 the plain blocks on every platform;
+    the track names dtype and shape."""
+    kernel = _kernel_takes(q, k, v)
+    trace.counter("attn:lowering", cat="ops",
+                  track="%s%s" % (q.dtype.name, list(q.shape)),
+                  kernel=int(kernel), plain=int(not kernel))
+    if not kernel:
+        return _plain_attention(q, k, v, scale)
+    return _kernel_on_tpu(
+        lambda q, k, v: _flash_attention(q, k, v, scale),
+        lambda q, k, v: _plain_attention(q, k, v, scale), False, q, k, v)
+
+
+def _kernel_tiles(t: int):
+    """(keys and queries a tile, keys a matmul) for sequences of ``t``."""
+    tile = min(ATTN_KERNEL_BLOCK, t)
+    return tile, min(ATTN_KERNEL_SLICE, tile)
+
+
+def _kernel_takes(q, k, v) -> bool:
+    """What the TPU kernel's tiling accepts: the configuration's compute
+    dtype (float32 keeps the plain blocks its chip parity was measured
+    on), heads of whole 128-lane rows, sequences of whole tiles and
+    tiles of whole slices."""
+    t, dh = q.shape[1], q.shape[3]
+    tile, piece = _kernel_tiles(t)
+    return (all(x.dtype == jnp.bfloat16 for x in (q, k, v))
+            and dh % 128 == 0 and t % 128 == 0
+            and t % tile == 0 and tile % piece == 0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _flash_attention(q, k, v, scale: float):
+    """The TPU lowering: ``jax.experimental.pallas.ops.tpu.
+    splash_attention`` under a causal mask (online softmax in VMEM, key
+    tiles above the diagonal never visited, one fused backward kernel
+    that recomputes the scores tile by tile), tiled by
+    ``_kernel_tiles``.  The kernel takes one sequence as
+    ``(H, T, Dh)`` and has no scale of its own: the queries are scaled
+    first (one more bfloat16 rounding of q), the batch is a ``vmap``, and
+    three transposes go in and one out, with theirs in the backward
+    pass."""
+    return _flash_fwd(q, k, v, scale)[0]
+
+
+def _flash_fwd(q, k, v, scale):
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
+    t, h = q.shape[1], q.shape[2]
+    tile, piece = _kernel_tiles(t)
+    sizes = sk.BlockSizes(
+        block_q=tile, block_kv=tile, block_kv_compute=piece,
+        block_q_dkv=tile, block_kv_dkv=tile, block_kv_dkv_compute=piece,
+        use_fused_bwd_kernel=True)
+    attend = sk.make_splash_mha_single_device(
+        sm.MultiHeadMask([sm.CausalMask((t, t))] * h), block_sizes=sizes)
+
+    def kernel(q, k, v):
+        out = jax.vmap(attend)(*(x.transpose(0, 2, 1, 3)
+                                 for x in (q * scale, k, v)))
+        return out.transpose(0, 2, 1, 3)
+
+    # bfloat16 products are exact at any precision, and Mosaic refuses
+    # bfloat16 operands under a "highest" default ("Bad lhs type"), so
+    # both passes are traced at the default one, whatever the caller's
+    with jax.default_matmul_precision("default"):
+        return jax.vjp(kernel, q, k, v)
+
+
+def _flash_bwd(scale, kernel_vjp, g):
+    with jax.default_matmul_precision("default"):
+        return kernel_vjp(g)
+
+
+_flash_attention.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _plain_attention(q, k, v, scale: float):
+    """The lowering of every platform, and the kernel's parity twin:
+    queries in blocks of ATTN_BLOCK_Q; a block's scores live only inside
+    its (checkpointed) body, in the forward and again in the backward
+    pass."""
     b, t, h, dh = q.shape
     bq = min(ATTN_BLOCK_Q, t)
     nb = -(-t // bq)
@@ -143,7 +245,15 @@ class CausalSelfAttentionOp(OpDef):
     """Causal multi-head self-attention over ``(B, T, H, Dh)`` query,
     key and value: ``softmax(q k^T * scale + causal mask) v`` per head,
     softmax in float32, scores never materialized whole.  ``scale`` 0
-    means ``Dh**-0.5``; ``layer`` names the trace scope."""
+    means ``Dh**-0.5``; ``layer`` names the trace scope.
+
+    Which lowering runs is ``causal_attention``'s choice, from the
+    platform the program is lowered for and the inputs: bfloat16 with
+    ``Dh % 128 == 0`` and ``T`` a multiple of 128 and of its tile
+    (``min(1024, T)``, itself whole slices of 512), lowered for a TPU,
+    is JAX's Pallas splash-attention kernel; float32, any other shape
+    and every other platform are the plain query blocks.  The counter
+    ``attn:lowering`` records it per bind."""
     params = [Param("scale", float, default=0.0),
               Param("layer", int, default=-1)]
 

@@ -3,6 +3,7 @@ block's ops against jnp and numeric gradients, the drop-free routed
 experts against the plain reference's dense loop, and the whole model
 (logits, loss, every gradient) against ``benchmark/reference/
 olmoe-1b-7b.py`` in float32, at a tolerance bfloat16 compute fails."""
+import functools
 import os
 import sys
 import time
@@ -108,6 +109,80 @@ def test_attention_scores_are_never_materialized():
         q, k, v, 0.35).sum(), argnums=(0, 1, 2))
     sizes = _jaxpr_sizes(jax.make_jaxpr(fn)(q, q, q).jaxpr)
     assert max(sizes) <= b * h * tf_ops.ATTN_BLOCK_Q * t
+
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+@pytest.mark.parametrize("dtype,shape,platform,kernel_inputs", [
+    (BF16, (1, 256, 2, 128), "tpu", True),
+    (BF16, (1, 2048, 2, 128), "tpu", True),
+    (BF16, (1, 256, 2, 128), "cpu", True),
+    (F32, (1, 256, 2, 128), "tpu", False),
+    (BF16, (1, 1536, 2, 128), "tpu", False),
+    (BF16, (1, 768, 2, 128), "tpu", False),
+    (BF16, (1, 200, 2, 128), "tpu", False),
+    (BF16, (1, 256, 2, 64), "tpu", False),
+], ids=["bf16-tpu", "bf16-2-tiles-tpu", "bf16-cpu", "float32-tpu",
+        "seq-not-whole-tiles-tpu", "tile-not-whole-slices-tpu",
+        "seq-not-128s-tpu", "head-64-tpu"])
+def test_attention_lowering_is_chosen_from_platform_and_inputs(
+        dtype, shape, platform, kernel_inputs):
+    """bfloat16 at shapes the kernel's tiling takes, lowered for a TPU,
+    is the Mosaic kernel, forward and the fused backward one; float32, a
+    sequence that is not whole tiles, a 64-wide head, and ANY CPU
+    lowering are the plain blocks.  Read off the text lowered for the
+    platform (no chip, no libtpu) and off ``attn:lowering``, which says
+    what the op's TPU lowering is."""
+    x = jax.ShapeDtypeStruct(shape, dtype)
+    fn = jax.jit(jax.grad(lambda q, k, v: tf_ops.causal_attention(
+        q, k, v, 0.3).astype(F32).sum(), argnums=(0, 1, 2)))
+    mark = time.perf_counter_ns()
+    text = jax.export.export(fn, platforms=[platform])(x, x, x).mlir_module()
+    want = 2 if kernel_inputs and platform == "tpu" else 0
+    assert text.count("tpu_custom_call") == want
+    events = mx.trace.counter_events(["attn:lowering"], since_ns=mark)
+    assert len(events) == 1, "once a trace of the op"
+    assert events[0]["args"] == {"kernel": int(kernel_inputs),
+                                 "plain": int(not kernel_inputs)}
+    assert events[0]["id"] == "%s%s" % (np.dtype(dtype).name, list(shape))
+
+
+def test_kernel_lowering_matches_dense_attention_interpreted(monkeypatch):
+    """The library kernel the TPU lowering runs, interpreted on the CPU
+    at tiles of 128 (so blocks above the diagonal are skipped and the
+    online softmax spans two tiles): output and the three input
+    gradients against dense float32 attention of the same bfloat16
+    inputs, inside bfloat16's rounding; the future does not leak."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk)
+    monkeypatch.setattr(sk, "make_splash_mha_single_device",
+                        functools.partial(sk.make_splash_mha_single_device,
+                                          interpret=True))
+    monkeypatch.setattr(tf_ops, "ATTN_KERNEL_BLOCK", 128)
+    rng = np.random.RandomState(5)
+    q, k, v = (jnp.asarray(rng.randn(2, 256, 2, 128), BF16)
+               for _ in range(3))
+    w = jnp.asarray(rng.randn(2, 256, 2, 128), F32)
+    assert tf_ops._kernel_takes(q, k, v)
+    scale = 128 ** -0.5
+
+    def run(fn, *args):
+        out, vjp = jax.vjp(lambda *a: fn(*a, scale).astype(F32), *args)
+        return [np.asarray(x, np.float32) for x in (out,) + vjp(w)]
+
+    got = run(tf_ops._flash_attention, q, k, v)
+    k2, v2 = k.at[:, -1].add(1.0), v.at[:, -1].add(-1.0)
+    moved = np.asarray(tf_ops._flash_attention(q, k2, v2, scale), np.float32)
+    # the parity twin on the same values in float32 is the dense result
+    want = run(tf_ops._plain_attention, *(x.astype(F32) for x in (q, k, v)))
+    assert np.allclose(want[0], _dense_attention(
+        *(np.asarray(x, np.float32) for x in (q, k, v))), atol=1e-5)
+    for g, r in zip(got, want):
+        # bfloat16 probabilities and outputs: 2**-8 a rounding
+        assert np.abs(g - r).max() <= 0.02 * np.abs(r).max()
+    assert np.array_equal(moved[:, :-1], got[0][:, :-1])
+    assert not np.array_equal(moved[:, -1], got[0][:, -1])
 
 
 def test_silu_and_the_gated_product_inside_the_expert_op():

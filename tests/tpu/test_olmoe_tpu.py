@@ -21,6 +21,7 @@ import gc
 import json
 import os
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -233,3 +234,89 @@ def test_published_width_step_matches_reference():
     # and so does dense Adam's first step (zero gradient, zero state)
     assert report["adam_bf16"]["embed_untouched_max_abs_update"] == 0.0
     assert report["adam_bf16"]["reference_untouched_max_abs_update"] == 0.0
+
+
+# the kernel against the plain blocks, both bfloat16 on the same chip:
+# each side rounds its probabilities and its results to 8 bits of
+# mantissa (2**-8 = 0.0039 a rounding), so two correct results differ by
+# a few roundings of the largest element (0.0036-0.0085 measured, PR 27)
+ATTN_MAX_ERR_SHARE = 0.02        # of the largest |element| of the plain side
+ATTN_L2_ERR = 0.01               # of the plain side's norm
+
+
+def test_attention_kernel_matches_plain_blocks_at_the_cell_shape():
+    """``causal_attention`` at the cell's ``(4, 4096, 16, 128)`` bfloat16
+    compiles to the Mosaic kernel on the chip; its output and its three
+    input gradients agree with the plain blocks', and the last key and
+    value move only the last query's output."""
+    import jax
+    import jax.numpy as jnp
+    import mxnet_tpu as mx
+    from mxnet_tpu.ops import transformer as tf_ops
+    shape, scale = (4, 4096, 16, 128), 128 ** -0.5
+    rng = np.random.RandomState(27)
+    q, k, v = (jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+               for _ in range(3))
+    w = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    def both_passes(attend):
+        def run(q, k, v):
+            out, vjp = jax.vjp(lambda *a: attend(*a, scale), q, k, v)
+            return (out,) + vjp(w.astype(out.dtype))
+        return jax.jit(run)
+
+    mark = time.perf_counter_ns()
+    kernel = both_passes(tf_ops.causal_attention)
+    plain = both_passes(tf_ops._plain_attention)
+    assert "tpu_custom_call" in kernel.lower(q, k, v).compile().as_text()
+    assert "tpu_custom_call" not in plain.lower(q, k, v).compile().as_text()
+    event = mx.trace.counter_events(["attn:lowering"], since_ns=mark)[-1]
+    assert event["args"] == {"kernel": 1, "plain": 0}
+    assert event["id"] == "bfloat16[4, 4096, 16, 128]"
+    got = [np.asarray(x, np.float32) for x in kernel(q, k, v)]
+    want = [np.asarray(x, np.float32) for x in plain(q, k, v)]
+    report = {"max_err_share": [], "l2_err": []}
+    for g, r in zip(got, want):
+        report["max_err_share"].append(
+            float(np.abs(g - r).max() / np.abs(r).max()))
+        report["l2_err"].append(_rel(g, r))
+    print("\nATTN_KERNEL_PARITY " + json.dumps(report), flush=True)
+    assert max(report["max_err_share"]) <= ATTN_MAX_ERR_SHARE, report
+    assert max(report["l2_err"]) <= ATTN_L2_ERR, report
+    # the future does not leak
+    moved = np.asarray(jax.jit(tf_ops.causal_attention, static_argnums=3)(
+        q, k.at[:, -1].add(1.0), v.at[:, -1].add(-1.0), scale), np.float32)
+    assert np.array_equal(moved[:, :-1], got[0][:, :-1])
+    assert not np.array_equal(moved[:, -1], got[0][:, -1])
+
+
+def test_the_cell_s_bound_module_runs_the_kernel():
+    """The cell's module (the configuration's model, bfloat16 compute,
+    4 sequences of 4096) traces its attention op once, and the op hands
+    the chip the kernel: ``attn:lowering`` reads ``kernel``."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.models import olmoe_lm
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "olmoe-1b-7b.json")) as f:
+        cfg = json.load(f)
+    kw = cfg["model"]["kwargs"]
+    seq, vocab = kw["seq_len"], kw["vocab_size"]
+    net = olmoe_lm(**kw)
+    shapes = dict(zip(net.list_arguments(), net.infer_shape(
+        data=(4, seq), softmax_label=(4, seq))[0]))
+    rng = np.random.RandomState(27)
+    params = {n: (np.ones(s, np.float32) if n.endswith("gamma") else
+                  (0.02 * rng.standard_normal(s)).astype(np.float32))
+              for n, s in shapes.items()
+              if n not in ("data", "softmax_label")}
+    tokens = rng.randint(0, vocab, (4, seq + 1))
+    mark = time.perf_counter_ns()
+    loss = _module_step(net, params, tokens[:, :-1], tokens[:, 1:],
+                        cfg["optimizer"]["name"], cfg["optimizer"]["params"],
+                        cfg["compute_dtype"])[0]
+    assert np.isfinite(loss)
+    events = mx.trace.counter_events(["attn:lowering"], since_ns=mark)
+    assert events, "the step traced no attention op"
+    for e in events:
+        assert e["args"] == {"kernel": 1, "plain": 0}, e
+        assert e["id"] == "bfloat16[4, 4096, 16, 128]", e
