@@ -226,17 +226,6 @@ def _feed_watchdog(phase=None):
 # each module's ``run(feed=)`` returns its metrics dict and documents them
 _LATER_LEGS = (
     ("io", "bench_io"),              # RecordIO -> JPEG decode -> device_put
-    ("ckpt", "bench_ckpt"),          # async save / restore / steps-per-s tax
-    ("serve", "bench_serve"),        # micro-batcher, int8, decode, mux, router
-    ("fusion", "bench_fusion"),      # fused-vs-unfused serve step, autotune
-    ("embed", "bench_embed"),        # deduped sparse embedding update + serve
-    ("compile", "bench_compile"),    # cold vs warm-cache start (CPU children)
-    ("multichip", "bench_multichip"),  # mesh scaling (CPU children)
-    ("faults", "bench_faults"),      # crash-and-resume, failover, chaos cost
-    ("llm", "bench_llm"),            # paged KV-cache decode engine
-    ("online", "bench_online"),      # serve -> capture -> fine-tune -> promote
-    ("moe", "bench_moe"),            # routed MoE step + decode
-    ("tune", "bench_tune"),          # joint autotuner + kernel search
 )
 
 

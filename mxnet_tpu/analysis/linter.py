@@ -547,9 +547,9 @@ def _rule_donated_aliasing(ctx: _Ctx) -> Iterable[Finding]:
     jnp.copy: on CPU device_put can zero-copy ALIAS the host buffer, and
     state built in init/restore paths is donated every step — XLA then
     scribbles over memory numpy still owns (PR 2's nondeterministic
-    resume corruption; bit again in PR 7 review round 2 in
-    DPTrainStep.init/GPipeTrainStep.init).  Freshly-created jnp.*
-    arrays are exempt (nothing on host aliases them)."""
+    resume corruption; bit again in PR 7 review round 2 in two train
+    steps' init).  Freshly-created jnp.* arrays are exempt (nothing on
+    host aliases them)."""
     for node in ast.walk(ctx.tree):
         if not (isinstance(node, ast.Call)
                 and _dotted(node.func) == "jax.device_put"):

@@ -473,8 +473,7 @@ class FusedTrainStep:
             # dedup-ratio instrumentation on the HOST ids (microseconds
             # on an int batch vs a multi-ms step), sampled every
             # MXNET_EMBED_STATS_EVERY batches — the number
-            # mx.profiler.embed_report() and bench_embed's
-            # embed_dedup_ratio leg surface
+            # mx.profiler.embed_report() surfaces
             self._embed_stats_n += 1
             if self._embed_stats_n % self._embed_stats_every == 0:
                 by_name = dict(zip(self.data_names, data_batch.data))

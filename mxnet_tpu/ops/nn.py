@@ -107,28 +107,12 @@ class ConvolutionOp(OpDef):
 
     def forward(self, p, inputs, aux, ctx):
         x, w = inputs[0], inputs[1]
-        from ..base import get_env
-        if get_env("MXNET_CONV_LAYOUT", "NCHW").upper() == "NHWC":
-            # channels-last lowering experiment (docs/perf.md records the
-            # measurement): the API stays NCHW; the op transposes at its
-            # boundary and XLA cancels back-to-back transposes through
-            # the elementwise/BN ops between convs
-            out = lax.conv_general_dilated(
-                jnp.transpose(x, (0, 2, 3, 1)),
-                jnp.transpose(w, (2, 3, 1, 0)),
-                window_strides=tuple(p.stride),
-                padding=[(p.pad[0], p.pad[0]), (p.pad[1], p.pad[1])],
-                rhs_dilation=tuple(p.dilate),
-                dimension_numbers=("NHWC", "HWIO", "NHWC"),
-                feature_group_count=p.num_group)
-            out = jnp.transpose(out, (0, 3, 1, 2))
-        else:
-            out = lax.conv_general_dilated(
-                x, w, window_strides=tuple(p.stride),
-                padding=[(p.pad[0], p.pad[0]), (p.pad[1], p.pad[1])],
-                rhs_dilation=tuple(p.dilate),
-                dimension_numbers=("NCHW", "OIHW", "NCHW"),
-                feature_group_count=p.num_group)
+        out = lax.conv_general_dilated(
+            x, w, window_strides=tuple(p.stride),
+            padding=[(p.pad[0], p.pad[0]), (p.pad[1], p.pad[1])],
+            rhs_dilation=tuple(p.dilate),
+            dimension_numbers=("NCHW", "OIHW", "NCHW"),
+            feature_group_count=p.num_group)
         if not p.no_bias:
             out = out + inputs[2][None, :, None, None]
         return [out]

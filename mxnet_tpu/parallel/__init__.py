@@ -1,4 +1,4 @@
-"""Parallelism: meshes, sharded training steps, collectives.
+"""Parallelism: meshes, the pipeline schedule, collectives.
 
 TPU-native replacement for the reference's kvstore/ps-lite distribution stack
 (SURVEY §2.4, §5.8): data parallel = GSPMD batch sharding + XLA all-reduce
@@ -9,11 +9,9 @@ from .mesh import (make_mesh, parse_mesh_spec, mesh_from_env,
                    normalize_spec, spec_axes, validate_spec,
                    sharding_attrs, dp_sharding, replicated,
                    Mesh, NamedSharding, PartitionSpec)
-from .data_parallel import DPTrainStep
-from .pipeline import GPipeTrainStep, pipeline_apply
+from .pipeline import pipeline_apply
 
 __all__ = ["make_mesh", "parse_mesh_spec", "mesh_from_env",
            "normalize_spec", "spec_axes", "validate_spec",
            "sharding_attrs", "dp_sharding", "replicated",
-           "Mesh", "NamedSharding", "PartitionSpec", "DPTrainStep",
-           "GPipeTrainStep", "pipeline_apply"]
+           "Mesh", "NamedSharding", "PartitionSpec", "pipeline_apply"]

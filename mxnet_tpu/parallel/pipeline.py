@@ -26,11 +26,11 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from .mesh import shard_map_norep
 
-__all__ = ["pipeline_apply", "GPipeTrainStep"]
+__all__ = ["pipeline_apply"]
 
 
 def pipeline_apply(stage_fn: Callable, mesh: Mesh, stacked_params, micros,
@@ -87,72 +87,3 @@ def pipeline_apply(stage_fn: Callable, mesh: Mesh, stacked_params, micros,
     sharded = shard_map_norep(run, mesh, in_specs=(P(axis), P()),
                               out_specs=P())
     return sharded(stacked_params, micros)
-
-
-class GPipeTrainStep:
-    """Microbatched pipeline training step over a ``pp`` mesh axis.
-
-    model: S x stage_fn(stage_params_i, h) -> h  (pipelined stack)
-           loss_fn(tail_params, h, label) -> scalar loss (replicated
-           tail; put any non-pipelined encoder/embedding inside stage 0's
-           parameters or precompute it into the input batch)
-
-    Gradients flow back through the pipeline via autodiff (reverse
-    ppermute hops); the optimizer update (SGD) runs replicated — the
-    same update-on-every-stage model the fused data-parallel step uses.
-    """
-
-    def __init__(self, stage_fn, loss_fn, mesh: Mesh, num_micro: int,
-                 learning_rate: float = 0.1, axis: str = "pp"):
-        self.stage_fn = stage_fn
-        self.loss_fn = loss_fn
-        self.mesh = mesh
-        self.num_micro = num_micro
-        self.lr = learning_rate
-        self.axis = axis
-        self._step = None
-
-    def init(self, stacked_params, tail_params):
-        # jnp.copy: the state is donated every step and device_put may
-        # zero-copy alias the caller's host buffers (see
-        # DPTrainStep.init)
-        spec = NamedSharding(self.mesh, P(self.axis))
-        rep = NamedSharding(self.mesh, P())
-        stacked = jax.tree_util.tree_map(
-            lambda a: jnp.copy(jax.device_put(jnp.asarray(a), spec)),
-            stacked_params)
-        tail = jax.tree_util.tree_map(
-            lambda a: jnp.copy(jax.device_put(jnp.asarray(a), rep)),
-            tail_params)
-        return {"stages": stacked, "tail": tail}
-
-    def _build(self):
-        mesh, axis, M = self.mesh, self.axis, self.num_micro
-        stage_fn, loss_fn, lr = self.stage_fn, self.loss_fn, self.lr
-
-        def loss_of(params, data, labels):
-            # data: (B, ...) -> microbatches (M, B/M, ...)
-            micros = data.reshape((M, data.shape[0] // M) + data.shape[1:])
-            outs = pipeline_apply(stage_fn, mesh, params["stages"], micros,
-                                  axis)
-            h = outs.reshape(data.shape[0], *outs.shape[2:])
-            return loss_fn(params["tail"], h, labels)
-
-        def step(params, data, labels):
-            loss, grads = jax.value_and_grad(loss_of)(params, data, labels)
-            new = jax.tree_util.tree_map(lambda w, g: w - lr * g,
-                                         params, grads)
-            return new, loss
-
-        from ..compile_cache import cached_jit
-        return cached_jit(step, name="parallel:pipeline_step",
-                          donate_argnums=(0,))
-
-    def __call__(self, params, data, labels):
-        if len(data) % self.num_micro:
-            raise ValueError(
-                "batch size %d must be divisible by num_micro=%d"
-                % (len(data), self.num_micro))
-        if self._step is None:
-            self._step = self._build()
-        return self._step(params, jnp.asarray(data), jnp.asarray(labels))

@@ -13,8 +13,7 @@ follow the rank of the dispatch node's output and every expert node
 already takes the dispatch node's ``counts``, so only the dispatch node
 is rewritten.  Routed serving then answers as the dense-gather reference
 does — the same terms, summed in the grouped matmul's order, so equal to
-rounding and not bit for bit — the contract ``bench_moe``'s
-``moe_serve_tok_s`` leg asserts.
+rounding and not bit for bit.
 
 On by default for serving pipelines; ``MXNET_MOE_SERVE_EXACT=0`` keeps
 the training capacity (a latency experiment, not a serving

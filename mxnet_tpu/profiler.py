@@ -571,7 +571,7 @@ def serve_report_str() -> str:
 # EmbeddingTable, a device_embed kvstore) registers its EmbedStats at
 # construction, weakly like the rest; embed_report() shows per-table
 # lookup/update counts and the measured dedup ratio on the live id
-# distribution — the number bench_embed's embed_dedup_ratio leg holds.
+# distribution.
 _embed_registry = _Registry("embed", "(no live embedding tables)")
 
 

@@ -87,75 +87,30 @@ def test_model_parallel_matches_single_device():
         assert np.allclose(a, b, atol=1e-6)
 
 
-def test_mesh_dp_train_step():
-    """GSPMD fused data-parallel step over an 8-device cpu mesh."""
-    import jax
-    assert len(jax.devices()) >= 8
-    np.random.seed(0)
-    mx.random.seed(0)
-
-    data = mx.sym.Variable("data")
-    net = mx.sym.FullyConnected(data, num_hidden=16, name="fc1")
-    net = mx.sym.Activation(net, act_type="relu")
-    net = mx.sym.FullyConnected(net, num_hidden=4, name="fc2")
-    net = mx.sym.SoftmaxOutput(net, name="softmax")
-
-    mesh = mx.parallel.make_mesh([("dp", 8)])
-    step = mx.parallel.DPTrainStep(net, mesh, learning_rate=0.5,
-                                   momentum=0.9, weight_decay=0.0)
-    rng = np.random.RandomState(0)
-    arg_params = {
-        "fc1_weight": rng.randn(16, 10).astype(np.float32) * 0.1,
-        "fc1_bias": np.zeros(16, np.float32),
-        "fc2_weight": rng.randn(4, 16).astype(np.float32) * 0.1,
-        "fc2_bias": np.zeros(4, np.float32),
-    }
-    state = step.init(arg_params, {})
-    centers = rng.randn(4, 10) * 3
-    losses = []
-    for it in range(30):
-        ys = rng.randint(4, size=64)
-        X = centers[ys] + rng.randn(64, 10) * 0.5
-        batch = step.shard_batch({"data": X.astype(np.float32),
-                                  "softmax_label": ys.astype(np.float32)})
-        state, outs = step(state, batch)
-        probs = np.asarray(outs[0])
-        acc = (probs.argmax(axis=1) == ys).mean()
-        losses.append(acc)
-    assert np.mean(losses[-5:]) > 0.9, losses
-
-
-def test_mesh_dp_train_step_bf16():
-    """bf16 compute + f32 master weights converges (mixed precision)."""
-    import jax.numpy as jnp
-    np.random.seed(0)
+def test_mesh_dp_train_step_bf16(monkeypatch):
+    """What resnet50-dp4-b512 runs, at toy size: Module.fit over a dp=4
+    mesh with bf16 compute learns, and the master weights stay f32."""
+    monkeypatch.setenv("MXNET_COMPUTE_DTYPE", "bfloat16")
     mx.random.seed(0)
     data = mx.sym.Variable("data")
     net = mx.sym.FullyConnected(data, num_hidden=16, name="fc1")
     net = mx.sym.Activation(net, act_type="relu")
     net = mx.sym.FullyConnected(net, num_hidden=4, name="fc2")
     net = mx.sym.SoftmaxOutput(net, name="softmax")
-    mesh = mx.parallel.make_mesh([("dp", 4)])
-    step = mx.parallel.DPTrainStep(net, mesh, learning_rate=0.5,
-                                   momentum=0.9, weight_decay=0.0,
-                                   compute_dtype=jnp.bfloat16)
     rng = np.random.RandomState(0)
-    arg_params = {
-        "fc1_weight": rng.randn(16, 10).astype(np.float32) * 0.1,
-        "fc1_bias": np.zeros(16, np.float32),
-        "fc2_weight": rng.randn(4, 16).astype(np.float32) * 0.1,
-        "fc2_bias": np.zeros(4, np.float32),
-    }
-    state = step.init(arg_params, {})
     centers = rng.randn(4, 10) * 3
-    accs = []
-    for _ in range(25):
-        ys = rng.randint(4, size=64)
-        X = centers[ys] + rng.randn(64, 10) * 0.5
-        batch = step.shard_batch({"data": X.astype(np.float32),
-                                  "softmax_label": ys.astype(np.float32)})
-        state, outs = step(state, batch)
-        accs.append((np.asarray(outs[0].astype(jnp.float32)).argmax(axis=1)
-                     == ys).mean())
-    assert state["params"]["fc1_weight"].dtype == np.float32  # master stays f32
-    assert np.mean(accs[-5:]) > 0.9, accs
+    ys = rng.randint(4, size=64 * 25)
+    X = (centers[ys] + rng.randn(len(ys), 10) * 0.5).astype(np.float32)
+    it = mx.io.NDArrayIter(X, ys.astype(np.float32), batch_size=64)
+    mod = mx.mod.Module(net, context=mx.cpu(0))
+    mod.fit(it, num_epoch=1, initializer=mx.init.Xavier(),
+            optimizer_params={"learning_rate": 0.5, "momentum": 0.9},
+            mesh="dp=4")
+    fused = mod._fused
+    assert fused is not None and fused.named_mesh
+    assert dict(fused.mesh.shape) == {"dp": 4}
+    assert str(fused.compute_dtype) == "bfloat16"
+    arg_params, _ = mod.get_params()
+    for name, arr in arg_params.items():
+        assert arr.dtype == np.float32, name     # master stays f32
+    assert dict(mod.score(it, "acc"))["accuracy"] > 0.9

@@ -1,7 +1,7 @@
-"""mxnet_tpu.parallel under tier-1: mesh construction, the standalone
-sharded train steps (DPTrainStep, GPipeTrainStep), and sequence
-parallelism (ring / Ulysses attention) on the 8 forced host devices —
-previously only the out-of-band MULTICHIP dryrun exercised any of this.
+"""mxnet_tpu.parallel under tier-1: mesh construction, the pipeline
+schedule (pipeline_apply), and sequence parallelism (ring / Ulysses
+attention) on the 8 forced host devices.  Mesh training itself is
+Module.fit(mesh=): tests/test_multichip.py.
 """
 import numpy as np
 import pytest
@@ -83,59 +83,7 @@ def test_dp_sharding_and_replicated():
     assert tuple(parallel.replicated(mesh).spec) == ()
 
 
-# -- DPTrainStep -------------------------------------------------------------
-
-def _mlp_sym():
-    data = mx.sym.Variable("data")
-    h = mx.sym.Activation(
-        mx.sym.FullyConnected(data, num_hidden=8, name="fc1"),
-        act_type="relu")
-    return mx.sym.SoftmaxOutput(
-        mx.sym.FullyConnected(h, num_hidden=2, name="fc2"), name="softmax")
-
-
-def _mlp_params(rng):
-    return {
-        "fc1_weight": rng.randn(8, 6).astype(np.float32) * 0.1,
-        "fc1_bias": np.zeros(8, np.float32),
-        "fc2_weight": rng.randn(2, 8).astype(np.float32) * 0.1,
-        "fc2_bias": np.zeros(2, np.float32),
-    }
-
-
-def _dp_train(mesh, param_specs=None, steps=4):
-    rng = np.random.RandomState(3)
-    step = parallel.DPTrainStep(_mlp_sym(), mesh,
-                                learning_rate=0.5, momentum=0.9,
-                                weight_decay=0.0,
-                                param_specs=param_specs)
-    state = step.init(_mlp_params(rng), {})
-    key = jax.random.PRNGKey(0)
-    for i in range(steps):
-        X = rng.randn(16, 6).astype(np.float32)
-        y = (X.sum(axis=1) > 0).astype(np.float32)
-        batch = step.shard_batch({"data": X, "softmax_label": y})
-        state, outs = step(state, batch, rng=key)
-    return {k: np.asarray(v) for k, v in state["params"].items()}
-
-
-def test_dp_train_step_dp8_matches_single():
-    p8 = _dp_train(parallel.make_mesh([("dp", 8)]))
-    p1 = _dp_train(parallel.make_mesh([("dp", 1)], devices=jax.devices()[:1]))
-    for k in p1:
-        assert np.abs(p1[k] - p8[k]).max() < 1e-4, k
-        assert np.isfinite(p8[k]).all()
-
-
-def test_dp_train_step_param_specs_tp():
-    mesh = parallel.make_mesh([("dp", 4), ("tp", 2)])
-    pt = _dp_train(mesh, param_specs={"fc1_weight": P("tp", None)})
-    p1 = _dp_train(parallel.make_mesh([("dp", 1)], devices=jax.devices()[:1]))
-    for k in p1:
-        assert np.abs(p1[k] - pt[k]).max() < 1e-4, k
-
-
-# -- GPipeTrainStep ----------------------------------------------------------
+# -- pipeline_apply ----------------------------------------------------------
 
 def _stage_fn(p, x):
     return jnp.tanh(x @ p["w"] + p["b"])
@@ -166,39 +114,6 @@ def test_pipeline_apply_stage_count_mismatch():
     with pytest.raises(ValueError):
         parallel.pipeline_apply(_stage_fn, mesh,
                                 params, jnp.zeros((8, 2, 4)))
-
-
-def test_gpipe_train_step_loss_decreases():
-    S, M, B, D = 4, 4, 8, 8
-    mesh = parallel.make_mesh([("pp", S)])
-    rng = np.random.RandomState(1)
-
-    def loss_fn(tail, h, labels):
-        logits = h @ tail["w"]
-        return jnp.mean((logits[:, 0] - labels) ** 2)
-
-    step = parallel.GPipeTrainStep(_stage_fn, loss_fn, mesh, num_micro=M,
-                                   learning_rate=0.1)
-    params = step.init(
-        {"w": rng.randn(S, D, D).astype(np.float32) * 0.3,
-         "b": np.zeros((S, D), np.float32)},
-        {"w": rng.randn(D, 1).astype(np.float32) * 0.3})
-    X = rng.randn(B * M, D).astype(np.float32)
-    y = (X.sum(axis=1) > 0).astype(np.float32)
-    losses = []
-    for _ in range(8):
-        params, loss = step(params, X, y)
-        losses.append(float(loss))
-    assert losses[-1] < losses[0]
-    assert np.isfinite(losses).all()
-
-
-def test_gpipe_batch_not_divisible():
-    mesh = parallel.make_mesh([("pp", 4)])
-    step = parallel.GPipeTrainStep(_stage_fn, lambda t, h, l: jnp.sum(h),
-                                   mesh, num_micro=4)
-    with pytest.raises(ValueError):
-        step(None, np.zeros((6, 8), np.float32), np.zeros(6, np.float32))
 
 
 # -- ring / Ulysses attention ------------------------------------------------

@@ -58,7 +58,7 @@ DEFAULT_IGNORE = {
 # (merged with --lower-is-better): latencies, padding waste, and the
 # quantized-serving accuracy delta (ISSUE 9: a growing top-1 delta is a
 # quantization-quality regression even when its qps improves).  ISSUE 11
-# adds the fused/unfused serve-step latencies (bench_fusion.py) — their
+# adds the fused/unfused serve-step latencies — their
 # RATIO (fused_step_speedup) gates higher-is-better like every speedup.
 DEFAULT_LOWER_IS_BETTER = {
     "serve_p50_ms", "serve_p99_ms", "serve_pad_waste_frac",

@@ -1,11 +1,10 @@
-"""Per-HLO cost-analysis + layout A/B for the fused ResNet-50 train step.
+"""Per-HLO cost-analysis for the fused ResNet-50 train step.
 
 Answers "where do the executed FLOPs go?" with XLA's own cost analysis of
 the exact executable the bench times (bench.py drives the same
 Module->fused path).  Usage:
 
-    python tools/profile_resnet.py [--batch 256] [--layout NCHW|NHWC]
-                                   [--time] [--hlo-top 25]
+    python tools/profile_resnet.py [--batch 256] [--time] [--hlo-top 25]
 
 With --time, measures steady-state img/s exactly like bench.run().
 Reference workload: example/image-classification/train_imagenet.py
@@ -83,13 +82,10 @@ def build(batch):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--batch", type=int, default=256)
-    ap.add_argument("--layout", default=None, choices=["NCHW", "NHWC"])
     ap.add_argument("--time", action="store_true")
     ap.add_argument("--iters", type=int, default=30)
     ap.add_argument("--hlo-top", type=int, default=25)
     args = ap.parse_args()
-    if args.layout:
-        os.environ["MXNET_CONV_LAYOUT"] = args.layout
     os.environ.setdefault("MXNET_COMPUTE_DTYPE", "bfloat16")
 
     mod, staged = build(args.batch)
@@ -146,9 +142,8 @@ def main():
         jax.block_until_ready(next(iter(mod._fused_state["params"].values())))
         dt = time.perf_counter() - t0
         rate = args.batch * args.iters / dt
-        print("layout=%s batch=%d  %.1f img/s  (%.1f ms/step)"
-              % (os.environ.get("MXNET_CONV_LAYOUT", "NCHW"), args.batch,
-                 rate, dt / args.iters * 1e3))
+        print("batch=%d  %.1f img/s  (%.1f ms/step)"
+              % (args.batch, rate, dt / args.iters * 1e3))
 
 
 if __name__ == "__main__":
