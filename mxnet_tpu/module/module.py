@@ -12,7 +12,7 @@ from .. import trace as _trace
 from ..base import MXNetError, get_env
 from ..context import Context, cpu, current_context
 from ..initializer import Uniform
-from ..ndarray import NDArray, zeros as nd_zeros
+from ..ndarray import NDArray, _lives_on_host, zeros as nd_zeros
 from .. import optimizer as opt_mod
 from ..model import (_create_kvstore, _initialize_kvstore, _param_idx2name,
                      _update_params, _update_params_on_kvstore)
@@ -21,14 +21,6 @@ from .executor_group import DataParallelExecutorGroup
 from .fused import FusedTrainStep
 
 __all__ = ["Module"]
-
-
-def _lives_on_host(array):
-    """Whether a jax array's buffers are the host's own memory (the CPU
-    backend).  Nothing is then in flight to the host that the next
-    dispatch could run under, and fit() and score() keep their serial
-    order; on an accelerator they overlap (_outputs_in_flight)."""
-    return all(d.platform == "cpu" for d in array.devices())
 
 
 class Module(BaseModule):
@@ -1179,9 +1171,7 @@ class Module(BaseModule):
         if _lives_on_host(outs[0]._get()):
             return None
         for o in outs:
-            start = getattr(o._get(), "copy_to_host_async", None)
-            if callable(start):
-                start()
+            o._start_host_copy()
         return outs
 
     def install_monitor(self, mon):

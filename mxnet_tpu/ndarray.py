@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from .base import MXNetError, numeric_types
 from .context import Context, cpu, current_context
 from . import engine as _engine
+from . import trace as _trace
 
 __all__ = [
     "NDArray", "array", "empty", "zeros", "ones", "full", "arange",
@@ -79,6 +80,56 @@ def _ctx_of(jarr) -> Context:
     return Context("tpu", dev.id)
 
 
+def _lives_on_host(array) -> bool:
+    """Whether a jax array's buffers are the host's own memory (the CPU
+    backend): nothing then travels to the host, what jax hands out is a
+    view of the live buffer, and a host read is one copy of it."""
+    return all(d.platform == "cpu" for d in array.devices())
+
+
+# host reads of this many bytes or more leave an ``ndarray:asnumpy``
+# sample; under it, scalars and metric heads would fill the trace ring
+_COUNTED_READ_BYTES = 1 << 20
+
+
+def _read_to_host(x, started: bool):
+    """``(array, route)``: the value of ``x`` as a numpy array that
+    only the caller holds, and how it got there.
+
+    ``direct``: one device->host transfer, and the array it filled is
+    the result.  jax keeps whatever it fetches as a read-only twin of
+    the device array, on the ``jax.Array`` wrapper and in the transfer
+    state below it, and ``np.array`` of that is a second pass over the
+    same bytes on the host (a page fault a page: four fifths of a
+    1.6 GB read on a v5e host).  Both caches belong to the wrapper, not to the device
+    buffers, so the fetch goes through a wrapper of its own over the
+    same buffers; with that dropped the fetched array has no other
+    holder and is made writable in place.  An array in shards is
+    assembled by jax into one ``np.empty`` the same way.
+
+    ``cached``: the host value is already there (somebody read ``x``
+    before) or on its way (``started``: ``NDArray._start_host_copy``),
+    so it is copied and nothing travels twice.  ``copied``: the CPU
+    backend, where the "fetch" is a view of the device's own memory and
+    the copy is the only copy; and whatever jax cannot hand over whole
+    (a host array, an array with shards in other processes)."""
+    if not isinstance(x, jax.Array) or isinstance(x, jax.core.Tracer) \
+            or not x.is_fully_addressable:
+        return np.array(x), "copied"
+    if started or getattr(x, "_npy_value", None) is not None:
+        return np.array(x), "cached"
+    if _lives_on_host(x):
+        return np.array(x), "copied"
+    own = jax.make_array_from_single_device_arrays(
+        x.shape, x.sharding, [s.data for s in x.addressable_shards])
+    host = np.asarray(own)
+    del own
+    if not host.flags.owndata:
+        return np.array(host), "copied"
+    host.flags.writeable = True
+    return host, "direct"
+
+
 def _as_jax(value, dtype=None):
     if isinstance(value, NDArray):
         return value._get()
@@ -90,13 +141,14 @@ def _as_jax(value, dtype=None):
 class NDArray:
     """Multi-dimensional array with async dispatch and mutable semantics."""
 
-    __slots__ = ("_data", "_base", "_spec", "writable")
+    __slots__ = ("_data", "_base", "_spec", "writable", "_host_copy")
 
     def __init__(self, data=None, base: "NDArray" = None, spec=None, writable=True):
         self._data = data          # jax.Array when owner, None when view
         self._base = base          # owner NDArray when this is a view
         self._spec = spec          # ("slice", start, stop) | ("at", i) | ("reshape", shape)
         self.writable = writable
+        self._host_copy = None     # the jax.Array whose host copy _start_host_copy began
 
     # -- chunk access -------------------------------------------------------
     def _root(self) -> "NDArray":
@@ -151,6 +203,7 @@ class NDArray:
                 except Exception:
                     pass
             self._data = _engine.track(new)
+            self._host_copy = None
             return
         parent = self._base._get()
         kind = self._spec[0]
@@ -173,6 +226,7 @@ class NDArray:
         subsequent ``set_input``/``set_params`` write stays sharded."""
         root = self._root()
         root._data = _engine.track(jax.device_put(root._get(), sharding))
+        root._host_copy = None
         return self
 
     # -- basic properties ---------------------------------------------------
@@ -216,8 +270,42 @@ class NDArray:
     wait_to_write = wait_to_read
 
     def asnumpy(self) -> np.ndarray:
-        """Copy to host numpy array — THE sync point (SURVEY §3.6)."""
-        return np.array(self._get())
+        """The value as a host numpy array — THE sync point (SURVEY §3.6).
+
+        Blocks until the value is ready.  The result is writable and
+        the caller's own: independent of this array, of its views and
+        of every other ``asnumpy()`` result.  It costs one device->host
+        transfer and no further pass over the bytes on the host
+        (``_read_to_host``); on the CPU backend, where the device's
+        memory is the host's, it is one copy of that memory.  The bytes
+        arrive in the device's dimension order and the strides say
+        which: C-contiguous from the CPU backend and wherever the
+        device holds the array row-major; XLA:TPU holds some shapes
+        otherwise (a float32 ``(N, 10000)`` is column-major on a v5e)
+        and those come back Fortran-ordered, since the first chip run
+        (``np.ascontiguousarray`` where the order matters: it is a
+        transposing pass over the whole array).  Reads of 1 MiB or more
+        leave a sample of the ``ndarray:asnumpy`` trace counter:
+        ``bytes`` and the route, ``direct`` / ``copied`` / ``cached``."""
+        x = self._get()
+        host, route = _read_to_host(x, x is self._host_copy)
+        if host.nbytes >= _COUNTED_READ_BYTES:
+            _trace.counter("ndarray:asnumpy", cat="ndarray",
+                           bytes=host.nbytes, direct=int(route == "direct"),
+                           copied=int(route == "copied"),
+                           cached=int(route == "cached"))
+        return host
+
+    def _start_host_copy(self):
+        """Start this array's device->host copy and return at once; the
+        ``asnumpy()`` that follows waits for it and copies what landed
+        (route ``cached``) where it would have fetched.  For a caller
+        that dispatches more work before it reads: ``fit`` and ``score``
+        on the fused step (``Module._outputs_in_flight``)."""
+        x = self._get()
+        if isinstance(x, jax.Array):
+            x.copy_to_host_async()
+            self._host_copy = x
 
     def asscalar(self):
         if self.size != 1:
@@ -363,6 +451,7 @@ class NDArray:
         self._base = None
         self._spec = None
         self.writable = True
+        self._host_copy = None
         self._data = jnp.asarray(state["data"])
 
     def broadcast_to(self, shape) -> "NDArray":

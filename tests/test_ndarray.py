@@ -303,3 +303,168 @@ def test_save_load_uri_schemes(tmp_path):
     with pytest.raises(Exception) as e:
         mx.nd.save("bogus-scheme://bucket/x.nd", {"a": mx.nd.ones((2,))})
     assert "bogus-scheme" in str(e.value) or "protocol" in str(e.value)
+
+
+# -- asnumpy: one transfer into memory the caller owns ----------------------
+
+def _on_host(nd):
+    from mxnet_tpu.ndarray import _lives_on_host
+    return _lives_on_host(nd._get())
+
+
+def _asnumpy_samples():
+    """(bytes, route) of every ``ndarray:asnumpy`` sample in the ring."""
+    return [(e["args"]["bytes"],
+             [r for r in ("direct", "copied", "cached") if e["args"][r]])
+            for e in mx.trace.counter_events(names=["ndarray:asnumpy"])]
+
+
+def _make_view(kind, dtype):
+    """(NDArray, its value) for one way an NDArray can hold a value."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    value = (np.arange(16 * 6) % 251).reshape(16, 6).astype(dtype)
+    base = mx.nd.array(value, dtype=dtype)
+    if kind == "base":
+        return base, value
+    if kind == "slice":
+        return base[3:11], value[3:11]
+    if kind == "at":
+        return base[5], value[5]
+    if kind == "reshape":
+        return base.reshape((6, 16)), value.reshape(6, 16)
+    if kind == "zero_d":
+        return mx.nd.array(value[2, 3], dtype=dtype), value[2, 3]
+    assert kind == "sharded"
+    devices = jax.devices()
+    mesh = Mesh(np.array(devices if 16 % len(devices) == 0 else devices[:1]),
+                ("dp",))
+    base._place(NamedSharding(mesh, P("dp")))
+    assert len(base._get().sharding.device_set) == len(mesh.devices)
+    return base, value
+
+
+@pytest.mark.parametrize("kind", ["base", "slice", "at", "reshape",
+                                  "zero_d", "sharded"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int32", "uint8"])
+def test_asnumpy_result_is_the_callers(dtype, kind):
+    """Writable, its own memory, C-contiguous, equal to the value; a
+    write to it reaches neither the NDArray, nor another asnumpy()
+    result, nor a later device computation on the same array."""
+    import ml_dtypes
+    dtype = np.dtype(getattr(ml_dtypes, dtype, dtype))
+    nd, value = _make_view(kind, dtype)
+    first = nd.asnumpy()
+    assert isinstance(first, np.ndarray)
+    assert first.dtype == dtype and first.shape == np.shape(value)
+    assert first.flags.writeable and first.flags.owndata
+    # the bytes come in the device's dimension order: row-major on the
+    # CPU backend, the compiler's choice on an accelerator
+    assert first.flags.c_contiguous or not _on_host(nd)
+    assert same(first, value)
+    second = nd.asnumpy()
+    assert second is not first and not np.shares_memory(first, second)
+    first[...] = 7
+    assert same(second, value)
+    assert same(nd.asnumpy(), value)
+    one = np.asarray(1, dtype)
+    assert same((nd + 1).asnumpy(), np.asarray(value + one, dtype))
+    second[...] = 9
+    assert same(nd.copy().asnumpy(), value)
+
+
+def test_asnumpy_started_copy_is_served_without_a_fetch(monkeypatch):
+    """_start_host_copy, then asnumpy: the value comes from the copy
+    already made (route ``cached``), nothing is fetched again, and the
+    result is still writable and nobody else's."""
+    from mxnet_tpu import ndarray as nd_mod
+
+    def no_fetch(*a, **k):
+        raise AssertionError("a second device->host fetch was started")
+
+    value = np.random.uniform(-1, 1, (512, 1024)).astype(np.float32)   # 2 MiB
+    nd = mx.nd.array(value)
+    nd._start_host_copy()
+    # whatever the platform: a started copy must not reach the fetch
+    with monkeypatch.context() as m:
+        m.setattr(nd_mod.jax, "make_array_from_single_device_arrays",
+                  no_fetch)
+        mx.trace.reset()
+        first = nd.asnumpy()
+        second = nd.asnumpy()
+        assert _asnumpy_samples() == [(value.nbytes, ["cached"])] * 2
+        assert first.flags.writeable and first.flags.owndata
+        assert not np.shares_memory(first, second)
+        first[...] = 0
+        assert same(second, value) and same(nd.asnumpy(), value)
+    # a write replaces the device value: the started copy is stale
+    nd[:] = 1
+    assert nd._host_copy is None
+    assert same(nd.asnumpy(), np.ones_like(value))
+
+
+def test_asnumpy_counter_route_and_bytes():
+    """One sample a read of 1 MiB or more, with its bytes and the one
+    route taken; smaller reads are silent."""
+    big = mx.nd.zeros((1 << 18,))            # 1 MiB of float32
+    small = mx.nd.zeros(((1 << 18) - 1,))
+    expect = "copied" if _on_host(big) else "direct"
+    mx.trace.reset()
+    small.asnumpy()
+    mx.nd.zeros((3,)).asnumpy()
+    assert _asnumpy_samples() == []
+    big.asnumpy()
+    big[0:1 << 17].asnumpy()                 # a view of 512 KiB: silent
+    big.reshape((512, 512)).asnumpy()
+    assert _asnumpy_samples() == [(1 << 20, [expect])] * 2
+    ev = mx.trace.counter_events(names=["ndarray:asnumpy"])[0]
+    assert ev["cat"] == "ndarray"
+
+
+def test_asnumpy_fetch_path_takes_ownership_or_copies(monkeypatch):
+    """The path an accelerator's arrays take, driven here by hiding the
+    platform: an array in shards is assembled into memory of the
+    result's own (``direct``); a single CPU buffer comes back as a view
+    of the device's memory, which is not ours to hand out (``copied``).
+    Either way the device array keeps no host twin."""
+    import jax
+    from mxnet_tpu import ndarray as nd_mod
+    monkeypatch.setattr(nd_mod, "_lives_on_host", lambda array: False)
+    if len(jax.devices()) == 1:
+        pytest.skip("needs the CPU mesh: one device has no shards")
+    sharded, value = _make_view("sharded", np.dtype("float32"))
+    host, route = nd_mod._read_to_host(sharded._get(), False)
+    assert route == "direct" and host.flags.writeable and host.flags.owndata
+    assert same(host, value)
+    assert sharded._get()._npy_value is None
+    host[...] = 0
+    assert same(sharded.asnumpy(), value)
+    single, value = _make_view("base", np.dtype("float32"))
+    host, route = nd_mod._read_to_host(single._get(), False)
+    assert route == "copied" and host.flags.writeable and host.flags.owndata
+    host[...] = 0
+    assert same(single.asnumpy(), value)
+    assert single._get()._npy_value is None
+
+
+def test_custom_metric_feval_may_write_into_pred():
+    """A numpy feval that works in place on what it is handed (the
+    reference's metrics do) sees its own array: the outputs are intact
+    and a second update reads the same values."""
+    def feval(label, pred):
+        pred -= pred.max(axis=1, keepdims=True)    # in place
+        np.exp(pred, out=pred)
+        pred /= pred.sum(axis=1, keepdims=True)
+        label[...] = 0                             # and the label too
+        return float(pred[:, 0].sum()), pred.shape[0]
+
+    logits = np.random.uniform(-1, 1, (8, 5)).astype(np.float32)
+    pred = mx.nd.array(logits)
+    label = mx.nd.array(np.arange(8) % 5)
+    metric = mx.metric.CustomMetric(feval)
+    metric.update([label], [pred])
+    once = metric.get()[1]
+    metric.update([label], [pred])
+    assert abs(metric.get()[1] - once) < 1e-6
+    assert same(pred.asnumpy(), logits)
+    assert same(label.asnumpy(), np.arange(8) % 5)
