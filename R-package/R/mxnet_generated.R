@@ -10,7 +10,7 @@
 # ---- symbol operators (registry) ----
 
 #' reference activation-inl.h:182.
-#' @param act_type act_type parameter (required; one of relu, sigmoid, tanh, softrelu)
+#' @param act_type act_type parameter (required; one of relu, sigmoid, tanh, softrelu, silu)
 #' @param name string, node name (auto when omitted)
 #' @return MXSymbol
 mx.symbol.Activation <- function(...) {
@@ -43,7 +43,15 @@ mx.symbol.Cast <- function(...) {
   mx.symbol.internal.create('Cast', list(...))
 }
 
-#' Causal multi-head self-attention over ``(B, T, H, Dh)`` query,
+#' Depthwise causal convolution over time of ``(B, T, C)``: one
+#' @param kernel kernel parameter (default 4)
+#' @param name string, node name (auto when omitted)
+#' @return MXSymbol
+mx.symbol.CausalConv1D <- function(...) {
+  mx.symbol.internal.create('CausalConv1D', list(...))
+}
+
+#' Causal multi-head self-attention over ``(B, T, H, Dh)`` query
 #' @param scale scale parameter (default 0.0)
 #' @param layer layer parameter (default -1)
 #' @param name string, node name (auto when omitted)
@@ -186,6 +194,14 @@ mx.symbol.FullyConnected <- function(...) {
 #' @return MXSymbol
 mx.symbol.IdentityAttachKLSparseReg <- function(...) {
   mx.symbol.internal.create('IdentityAttachKLSparseReg', list(...))
+}
+
+#' Kimi Delta Attention over ``(B, T, H, Dk)`` query and key, ``(B,
+#' @param layer layer parameter (default -1)
+#' @param name string, node name (auto when omitted)
+#' @return MXSymbol
+mx.symbol.KimiDeltaAttention <- function(...) {
+  mx.symbol.internal.create('KimiDeltaAttention', list(...))
 }
 
 #' reference l2_normalization-inl.h: per-instance L2 normalize.
@@ -709,6 +725,14 @@ mx.nd.Cast <- function(..., ctx = mx.cpu()) {
   mx.nd.internal.invoke('Cast', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
 }
 
+#' Imperative CausalConv1D: 2 NDArray input(s), 0 scalar(s)
+#' @return MXNDArray
+mx.nd.CausalConv1D <- function(..., ctx = mx.cpu()) {
+  a <- list(...)
+  is.nd <- vapply(a, function(x) inherits(x, 'MXNDArray'), logical(1))
+  mx.nd.internal.invoke('CausalConv1D', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
+}
+
 #' Imperative CausalSelfAttention: 3 NDArray input(s), 1 scalar(s)
 #' @return MXNDArray
 mx.nd.CausalSelfAttention <- function(..., ctx = mx.cpu()) {
@@ -795,6 +819,14 @@ mx.nd.FullyConnected <- function(..., ctx = mx.cpu()) {
   a <- list(...)
   is.nd <- vapply(a, function(x) inherits(x, 'MXNDArray'), logical(1))
   mx.nd.internal.invoke('FullyConnected', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
+}
+
+#' Imperative KimiDeltaAttention: 7 NDArray input(s), 0 scalar(s)
+#' @return MXNDArray
+mx.nd.KimiDeltaAttention <- function(..., ctx = mx.cpu()) {
+  a <- list(...)
+  is.nd <- vapply(a, function(x) inherits(x, 'MXNDArray'), logical(1))
+  mx.nd.internal.invoke('KimiDeltaAttention', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
 }
 
 #' Imperative L2Normalization: 1 NDArray input(s), 1 scalar(s)
@@ -1157,7 +1189,7 @@ mx.nd.moe_combine <- function(..., ctx = mx.cpu()) {
   mx.nd.internal.invoke('_moe_combine', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
 }
 
-#' Imperative _moe_dispatch: 1 NDArray input(s), 1 scalar(s)
+#' Imperative _moe_dispatch: 1 NDArray input(s), 3 scalar(s)
 #' @return MXNDArray
 mx.nd.moe_dispatch <- function(..., ctx = mx.cpu()) {
   a <- list(...)

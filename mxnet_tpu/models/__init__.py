@@ -15,6 +15,7 @@ from .dcgan import make_generator, make_discriminator
 from .fcn import get_fcn32s, get_fcn16s, get_fcn8s
 from .rcnn import get_fast_rcnn, get_rpn
 from .olmoe import olmoe_lm
+from .kimi_linear import kimi_linear_lm  # noqa: F401
 from .gru import gru_unroll, gru_cell, rnn_unroll, rnn_cell, GRUState, \
     GRUParam, RNNState, RNNParam
 

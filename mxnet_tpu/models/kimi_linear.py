@@ -1,0 +1,140 @@
+"""Kimi Linear: a pre-norm decoder whose mixers differ by layer.
+
+"Kimi Linear: An Expressive, Efficient Attention Architecture"
+(arXiv:2510.26692; ``model_type`` ``kimi_linear``): token embedding ->
+L x [x + Mixer_l(RMSNorm(x)), x + MLP_l(RMSNorm(x))] -> RMSNorm ->
+untied vocabulary head.  Layers are counted from 1, as the published
+config counts them.
+
+``Mixer_l`` is Kimi Delta Attention (``sym.KimiDeltaAttention``: the
+gated delta rule with a per-channel decay) unless ``l`` is in
+``full_attn_layers``, where it is latent attention (MLA) with no
+positional embedding.  KDA: q, k, v projections, each through a
+depthwise causal convolution of ``conv_kernel`` taps and SiLU; a
+low-rank decay gate (``hidden -> kda_head_dim -> heads * kda_head_dim``)
+and a per-head write gate; the output through a per-head RMSNorm gated
+by ``sigmoid`` of a second low-rank projection (the one bias of the
+model is on its up-projection), then ``o_proj``.  MLA: queries of
+``qk_nope_dim + qk_rope_dim`` a head; keys and values decompressed from
+one ``kv_lora_rank`` latent (RMSNorm'ed) plus one ``qk_rope_dim`` key
+part shared by all heads; values of ``v_head_dim``; no rotary embedding
+on either part (``mla_use_nope``).
+
+``MLP_l`` is a SwiGLU of ``dense_width`` for the first ``dense_layers``
+layers and after them the routed expert layer: ``sigmoid`` router over
+``num_experts``, top ``experts_per_tok`` by score plus a selection bias
+(an aux state, no gradient), weights renormalized over the chosen and
+multiplied by ``routed_scale``, and one shared expert.  ``experts_held``
+> 0 builds one expert-parallel rank's share (``MoEFeedForward``):
+experts ``first_expert ..`` only, the router still ``num_experts`` wide.
+
+The symbol trains through ``Module.fit`` as it stands: inputs ``data``
+and ``softmax_label``, both ``(batch, seq_len)`` token ids; outputs the
+per-token loss head and the expert blocks' ``moe_load`` head.  The loss
+head normalizes its own gradient, so ``rescale_grad`` is 1; there is no
+load-balance loss (the selection bias balances).
+"""
+from .. import symbol as sym
+from ..moe.layer import MoEFeedForward, with_load_heads
+
+
+def kimi_linear_lm(num_layers, hidden_size, full_attn_layers, dense_layers,
+                   kda_heads, kda_head_dim, conv_kernel, mla_heads,
+                   kv_lora_rank, qk_nope_dim, qk_rope_dim, v_head_dim,
+                   dense_width, num_experts, experts_per_tok, expert_width,
+                   shared_width, routed_scale, vocab_size, seq_len,
+                   experts_held=0, first_expert=0, bias_rate=1e-3,
+                   rms_eps=1e-5):
+    """The training symbol; see the module docstring.  Each KDA core is
+    marked ``force_mirroring``: its float32 chunk products (0.6 GB a
+    layer at 4096 tokens of the published widths) are computed again in
+    the backward pass instead of kept."""
+    full_attn_layers = set(full_attn_layers)
+    kda_width = kda_heads * kda_head_dim
+
+    def norm(x, name):
+        return sym.RMSNorm(x, eps=rms_eps, name=name)
+
+    def proj(x, name, width, bias=False):
+        return sym.FullyConnected(x, num_hidden=width, no_bias=not bias,
+                                  name=name)
+
+    def silu(x):
+        return sym.Activation(x, act_type="silu")
+
+    def kda(h, pre, l):
+        def conv(x, name):
+            x = sym.Reshape(x, shape=(-1, seq_len, kda_width))
+            x = silu(sym.CausalConv1D(x, kernel=conv_kernel, name=name))
+            return sym.Reshape(x, shape=(-1, seq_len, kda_heads,
+                                         kda_head_dim))
+
+        q, k, v = (conv(proj(h, pre + s + "_proj", kda_width),
+                        pre + s + "_conv") for s in "qkv")
+        decay = proj(proj(h, pre + "f_down", kda_head_dim),
+                     pre + "f_up", kda_width)
+        decay = sym.Reshape(decay, shape=(-1, seq_len, kda_heads,
+                                          kda_head_dim))
+        beta = sym.Reshape(proj(h, pre + "beta_proj", kda_heads),
+                           shape=(-1, seq_len, kda_heads))
+        o = sym.KimiDeltaAttention(q, k, v, decay, beta, layer=l,
+                                   name=pre + "kda",
+                                   attr={"force_mirroring": "true"})
+        o = norm(sym.Reshape(o, shape=(-1, kda_head_dim)), pre + "o_norm")
+        gate = proj(proj(h, pre + "g_down", kda_head_dim),
+                    pre + "g_up", kda_width, bias=True)
+        gate = sym.Activation(sym.Reshape(gate, shape=(-1, kda_head_dim)),
+                              act_type="sigmoid")
+        return proj(sym.Reshape(o * gate, shape=(-1, kda_width)),
+                    pre + "o_proj", hidden_size)
+
+    def mla(h, pre, l):
+        qk_dim = qk_nope_dim + qk_rope_dim
+        q = sym.Reshape(proj(h, pre + "q_proj", mla_heads * qk_dim),
+                        shape=(-1, seq_len, mla_heads, qk_dim))
+        kv_a = proj(h, pre + "kv_a_proj", kv_lora_rank + qk_rope_dim)
+        latent = norm(sym.slice_axis(kv_a, axis=1, begin=0,
+                                     end=kv_lora_rank), pre + "kv_a_norm")
+        k_shared = sym.Reshape(
+            sym.slice_axis(kv_a, axis=1, begin=kv_lora_rank,
+                           end=kv_lora_rank + qk_rope_dim),
+            shape=(-1, seq_len, 1, qk_rope_dim))
+        kv = sym.Reshape(
+            proj(latent, pre + "kv_b_proj",
+                 mla_heads * (qk_nope_dim + v_head_dim)),
+            shape=(-1, seq_len, mla_heads, qk_nope_dim + v_head_dim))
+        k = sym.Concat(
+            sym.slice_axis(kv, axis=3, begin=0, end=qk_nope_dim),
+            sym.broadcast_axis(k_shared, axis=2, size=mla_heads), dim=3)
+        v = sym.slice_axis(kv, axis=3, begin=qk_nope_dim,
+                           end=qk_nope_dim + v_head_dim)
+        a = sym.CausalSelfAttention(q, k, v, layer=l, name=pre + "attn")
+        return proj(sym.Reshape(a, shape=(-1, mla_heads * v_head_dim)),
+                    pre + "o_proj", hidden_size)
+
+    x = sym.Embedding(sym.Variable("data"), input_dim=vocab_size,
+                      output_dim=hidden_size, name="embed")
+    x = sym.Reshape(x, shape=(-1, hidden_size))           # (B*T, D)
+    for l in range(1, num_layers + 1):
+        pre = "l%d_" % l
+        h = norm(x, pre + "mixer_norm")
+        x = x + (mla if l in full_attn_layers else kda)(h, pre, l)
+        h = norm(x, pre + "ffn_norm")
+        if l <= dense_layers:
+            x = x + proj(silu(proj(h, pre + "gate_proj", dense_width))
+                         * proj(h, pre + "up_proj", dense_width),
+                         pre + "down_proj", hidden_size)
+        else:
+            x = x + MoEFeedForward(
+                h, num_hidden=expert_width, num_experts=num_experts,
+                k=experts_per_tok, capacity_factor=0.0, name=pre + "moe",
+                act_type="silu", gated=True, no_bias=True, layer=l,
+                renormalize=True, score="sigmoid", scale=routed_scale,
+                bias_rate=bias_rate, shared_hidden=shared_width,
+                output_dim=hidden_size, experts_held=experts_held,
+                first_expert=first_expert)
+    logits = proj(norm(x, "final_norm"), "lm_head", vocab_size)
+    label = sym.Reshape(sym.Variable("softmax_label"), shape=(-1,))
+    loss = sym.SoftmaxCELoss(logits, label, name="lm_loss")
+    return with_load_heads(
+        sym.MakeLoss(loss, normalization="batch", name="lm"))

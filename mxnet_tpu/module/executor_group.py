@@ -132,6 +132,17 @@ class DataParallelExecutorGroup:
             [e.aux_dict[name] for e in self.execs]
             for name in self.aux_names]
 
+    def release(self):
+        """Drop the executors and every array they hold (arguments,
+        gradients, aux states, outputs).  The shapes stay, so
+        ``bind_exec(self.data_shapes, self.label_shapes)`` binds them
+        anew, zeroed.  For the owner whose live weights are elsewhere:
+        ``Module`` on the fused train step."""
+        self.execs = []
+        self.data_arrays = self.label_arrays = None
+        self.param_arrays = self.grad_arrays = self.aux_arrays = None
+        self.input_grad_arrays = None
+
     def set_params(self, arg_params, aux_params):
         for exe in self.execs:
             exe.copy_params_from(arg_params, aux_params)

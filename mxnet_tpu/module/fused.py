@@ -604,10 +604,12 @@ class FusedTrainStep:
 
     def note_outputs(self, outs) -> None:
         """Feed ``MoeStats`` and the ``moe:load`` trace counter (one
-        sample a block: max, mean, empty experts, routed, dropped) from
-        one step's outputs, given as the metric gets them.  One host
-        read of the ``(blocks, E + 1)`` load head, which the metric
-        update before this call already waited for."""
+        sample a block: max, mean, empty experts, routed, held, dropped)
+        from one step's outputs, given as the metric gets them.
+        ``held`` counts the routed choices that fell on experts this
+        rank holds (all of them where it holds all).  One host read of
+        the ``(blocks, E + 1)`` load head, which the metric update
+        before this call already waited for."""
         idx, blocks = self.moe_load_heads
         for block, row in zip(blocks, outs[idx].asnumpy()):
             counts, dropped = row[:-1], float(row[-1])
@@ -616,7 +618,10 @@ class FusedTrainStep:
                            max=float(counts.max()),
                            mean=float(counts.mean()),
                            empty=int((counts == 0).sum()),
-                           routed=float(counts.sum()), dropped=dropped)
+                           routed=float(counts.sum()),
+                           held=float(counts[self.moe_blocks[block].held]
+                                      .sum()),
+                           dropped=dropped)
 
     # -- compiled programs ---------------------------------------------------
     def _make_step_fn(self):

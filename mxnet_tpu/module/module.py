@@ -177,19 +177,40 @@ class Module(BaseModule):
 
         self.params_initialized = True
         self._params_dirty = False
-        self._exec_group.set_params(self._arg_params, self._aux_params)
         # host params changed: any fused device state is stale
         self._fused_state = None
+        if not self._restore_exec_arrays():
+            self._exec_group.set_params(self._arg_params, self._aux_params)
         self._fused_pending = None
         self._fused_outputs = None
         self._discard_speculation()
+
+    def _restore_exec_arrays(self) -> bool:
+        """Bind the executor group again where ``_fused_ensure_state``
+        released it, and fill it from ``_arg_params`` / ``_aux_params``;
+        True where it did."""
+        eg = self._exec_group
+        if eg is None or eg.execs:
+            return False
+        eg.bind_exec(eg.data_shapes, eg.label_shapes)
+        eg.set_params(self._arg_params, self._aux_params)
+        return True
+
+    def _drop_fused_state(self):
+        """The fused state goes; ``_arg_params`` / ``_aux_params`` are
+        current.  Where that state held the only device copy of the
+        weights, the executor group holds them again."""
+        self._fused_state = None
+        self._restore_exec_arrays()
 
     def _sync_params_from_devices(self):
         if self._fused is not None and self._fused_state is not None:
             # the fused state, not the exec group, holds the live params
             self._fused.read_params(self._fused_state, self._arg_params,
                                     self._aux_params)
-            self._exec_group.set_params(self._arg_params, self._aux_params)
+            if self._exec_group.execs:
+                self._exec_group.set_params(self._arg_params,
+                                            self._aux_params)
         else:
             self._exec_group.get_params(self._arg_params, self._aux_params)
         self._params_dirty = False
@@ -342,6 +363,8 @@ class Module(BaseModule):
         self._discard_speculation()
         self._data_shapes = list(data_shapes)
         self._label_shapes = list(label_shapes) if label_shapes else None
+        # the fused state holds the weights: the new group keeps shapes only
+        released = not self._exec_group.execs
         self._exec_group = DataParallelExecutorGroup(
             self._symbol, self._context, self._work_load_list,
             self._data_shapes, self._label_shapes, self._param_names,
@@ -351,7 +374,9 @@ class Module(BaseModule):
             no_slice_names=getattr(self, "_no_slice_names", ()))
         if self._fused is not None:
             self._fused.label_shapes = dict(self._label_shapes or [])
-        if self.params_initialized:
+        if released:
+            self._exec_group.release()
+        elif self.params_initialized:
             self._exec_group.set_params(self._arg_params, self._aux_params)
 
     # -- optimizer ------------------------------------------------------------
@@ -370,6 +395,8 @@ class Module(BaseModule):
             # kvstore is re-seeded and _setup_fused drops that state, or
             # training silently reverts to the last-synced values
             self._sync_params_from_devices()
+        # the kvstore below is seeded from the executor group's arrays
+        self._drop_fused_state()
 
         (kvstore, update_on_kvstore) = _create_kvstore(
             kvstore, len(self._context), self._arg_params)
@@ -452,7 +479,7 @@ class Module(BaseModule):
             # live fused state that holds the only copy of trained params
             self._sync_params_from_devices()
         self._fused = None
-        self._fused_state = None
+        self._drop_fused_state()
         self._fused_pending = None
         self._fused_outputs = None
         self._superstep_progs = {}
@@ -602,6 +629,7 @@ class Module(BaseModule):
         pend = self._fused_pending
         if self._fused_state is not None:
             self._sync_params_from_devices()
+            self._restore_exec_arrays()
             if self._update_on_kvstore and self._kvstore is not None:
                 _initialize_kvstore(kvstore=self._kvstore,
                                     param_arrays=self._exec_group.param_arrays,
@@ -682,6 +710,15 @@ class Module(BaseModule):
         if self._fused_state is None:
             if self._params_dirty:
                 self._sync_params_from_devices()
+            if not self._fused._multiprocess():
+                # from here on the fused state holds the live weights and
+                # the step's own gradients; the executor group's argument
+                # and gradient arrays (8 bytes a parameter) would only
+                # lie beside them unread, so they go before the state is
+                # built and come back when it is dropped
+                # (_drop_fused_state).  Multi-process eval runs the
+                # executor group every epoch and keeps them.
+                self._exec_group.release()
             self._fused_state = self._fused.init_state(self._arg_params,
                                                        self._aux_params)
             self._fused_t = 0

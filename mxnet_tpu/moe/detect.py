@@ -20,20 +20,25 @@ class MoEBlockSpec:
     """One routed block: its name and static routing geometry."""
 
     __slots__ = ("name", "num_experts", "k", "capacity_factor",
-                 "renormalize")
+                 "renormalize", "held")
 
     def __init__(self, name: str, num_experts: int, k: int,
-                 capacity_factor: float, renormalize: bool):
+                 capacity_factor: float, renormalize: bool,
+                 experts_held: int = 0, first_expert: int = 0):
         self.name = name
         self.num_experts = int(num_experts)
         self.k = int(k)
         self.capacity_factor = float(capacity_factor)
         self.renormalize = bool(renormalize)
+        # the experts this rank holds, as a slice of the E routed over
+        self.held = slice(int(first_expert), int(first_expert)
+                          + int(experts_held or num_experts))
 
     def describe(self):
         """Stable tuple for compile-cache fast keys."""
         return (self.name, self.num_experts, self.k,
-                self.capacity_factor, self.renormalize)
+                self.capacity_factor, self.renormalize,
+                self.held.start, self.held.stop)
 
     def __repr__(self):
         return ("MoEBlockSpec(name=%r, E=%d, k=%d, cf=%g, renorm=%r)"
@@ -53,7 +58,7 @@ def find_moe_blocks(symbol) -> Dict[str, MoEBlockSpec]:
         p = node.params
         out[node.name] = MoEBlockSpec(
             node.name, p.num_experts, p.k, p.capacity_factor,
-            p.renormalize)
+            p.renormalize, p.experts_held, p.first_expert)
     return out
 
 

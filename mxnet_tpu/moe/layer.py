@@ -39,7 +39,10 @@ def MoEFeedForward(data, num_hidden: int, num_experts: int, k: int = 2,
                    renormalize: bool = False, output_dim: int = 0,
                    no_bias: bool = False,
                    expert_axis: Optional[str] = None,
-                   gated: bool = False, layer: Optional[int] = None):
+                   gated: bool = False, layer: Optional[int] = None,
+                   score: str = "softmax", scale: float = 1.0,
+                   bias_rate: float = 0.0, shared_hidden: int = 0,
+                   experts_held: int = 0, first_expert: int = 0):
     """Build one routed MoE feed-forward block over ``data`` (T, D).
 
     ``capacity_factor`` None reads ``MXNET_MOE_CAPACITY_FACTOR``
@@ -50,7 +53,23 @@ def MoEFeedForward(data, num_hidden: int, num_experts: int, k: int = 2,
     shard over (None = replicated).  ``gated`` adds the stacked
     ``i2h_gate`` projection: ``(act(x Wg) * (x W1)) W2``, SwiGLU with
     ``act_type="silu"``.  ``layer`` is the block's index in its model,
-    for the trace scopes (``moe_experts.l<layer>``).  Returns the
+    for the trace scopes (``moe_experts.l<layer>``).
+
+    The drop-free layout also takes a DeepSeek-V3 style router: ``score``
+    ``"sigmoid"`` scores each logit by itself, ``scale`` multiplies the
+    (``renormalize``d) weights, ``bias_rate`` > 0 adds the selection
+    bias, an aux state of the dispatch node that enters the choice only
+    and moves by ``bias_rate * sign(mean load - load)`` a training step;
+    ``shared_hidden`` > 0 adds one shared expert of that width (three
+    ``FullyConnected``, in the experts' gated or plain form) to every
+    token's output.  ``experts_held`` > 0 makes this one expert-parallel
+    rank's share: the router, its weights' renormalization and the load
+    head stay ``num_experts`` wide, the stacked weights hold experts
+    ``first_expert .. first_expert + experts_held - 1`` only, and rows
+    that chose another expert are left out of the grouped matmuls and
+    of the output (no code stands in for the other ranks or their
+    exchange; the shares of all ranks, with the shared expert once, sum
+    to the whole layer's output).  Returns the
     combined output symbol; recover the aux-loss / counts heads with
     ``aux_loss_symbols`` / ``count_symbols`` or attach them in one move
     with ``with_aux_loss``.
@@ -60,10 +79,14 @@ def MoEFeedForward(data, num_hidden: int, num_experts: int, k: int = 2,
     scope = {} if layer is None else {"layer": int(layer)}
     logits = _sym.FullyConnected(data, num_hidden=num_experts,
                                  no_bias=True, name=name + "_gate")
+    share = {"experts_held": int(experts_held),
+             "first_expert": int(first_expert)}
     disp = _sym._moe_dispatch(data, logits, num_experts=num_experts,
                               k=k, capacity_factor=capacity_factor,
-                              renormalize=renormalize,
-                              name=name + "_dispatch", **scope)
+                              renormalize=renormalize, score=score,
+                              scale=float(scale),
+                              bias_rate=float(bias_rate),
+                              name=name + "_dispatch", **scope, **share)
 
     def expert_var(suffix, spec):
         attr = {"__sharding__": spec} if expert_axis else None
@@ -80,9 +103,27 @@ def MoEFeedForward(data, num_hidden: int, num_experts: int, k: int = 2,
     ffn = _sym._moe_expert_ffn(*args, num_hidden=num_hidden,
                                output_dim=output_dim, act_type=act_type,
                                no_bias=no_bias, gated=gated,
-                               name=name + "_experts", **scope)
-    return _sym._moe_combine(ffn, disp[1], disp[2],
-                             name=name + "_combine", **scope)
+                               name=name + "_experts", **scope, **share)
+    out = _sym._moe_combine(ffn, disp[1], disp[2],
+                            name=name + "_combine", **scope)
+    if not shared_hidden:
+        return out
+    if not output_dim:
+        raise ValueError("MoEFeedForward: a shared expert needs output_dim "
+                         "(the model width its last projection returns to)")
+
+    def fc(x, stem, width):
+        return _sym.FullyConnected(x, num_hidden=width, no_bias=no_bias,
+                                   name="%s_shared_%s" % (name, stem))
+
+    def act(x):
+        return x if act_type == "identity" else \
+            _sym.Activation(x, act_type=act_type)
+
+    hidden = fc(data, "i2h", shared_hidden)
+    hidden = act(fc(data, "i2h_gate", shared_hidden)) * hidden if gated \
+        else act(hidden)
+    return out + fc(hidden, "h2o", output_dim)
 
 
 def _dispatch_heads(symbol, out_idx: int) -> List:

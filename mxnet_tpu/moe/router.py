@@ -18,8 +18,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["drop_free", "resolve_capacity", "route", "route_sorted",
-           "RoutingPlan", "SortedPlan"]
+__all__ = ["drop_free", "moved_select_bias", "resolve_capacity", "route",
+           "route_sorted", "RoutingPlan", "SortedPlan"]
 
 
 def drop_free(capacity_factor) -> bool:
@@ -126,7 +126,8 @@ class SortedPlan(NamedTuple):
 
     ``order``   (T*k,) int32: sorted row ``r`` holds pair ``order[r]``
                 (token ``order[r] // k``), experts ascending, pairs of
-                one expert in token order
+                one expert in token order (with ``held``: the held
+                experts' pairs first, the absent experts' behind them)
     ``slot``    (T, k) int32: the sorted row of each pair (``order``'s
                 inverse)
     ``weight``  (T, k) f32 combine weights (the top-k gate values)
@@ -145,17 +146,47 @@ class SortedPlan(NamedTuple):
     dropped: jax.Array
 
 
-def route_sorted(logits, k: int, renormalize: bool = False) -> SortedPlan:
-    """Route ``(T, E)`` gate logits without a capacity: softmax over all
-    experts, top-k, and a stable sort of the ``T*k`` pairs by expert."""
+def route_sorted(logits, k: int, renormalize: bool = False,
+                 score: str = "softmax", scale: float = 1.0,
+                 select_bias=None, held=None) -> SortedPlan:
+    """Route ``(T, E)`` gate logits without a capacity: scores over all
+    experts (``score``: ``softmax``, or ``sigmoid`` of each logit), top-k,
+    and a stable sort of the ``T*k`` pairs by expert.
+
+    ``select_bias`` ``(E,)`` is added to the scores for the CHOICE only:
+    the weights are the chosen experts' own scores, and the bias gets no
+    gradient.  ``renormalize`` divides a token's k weights by their sum
+    and ``scale`` multiplies them, over all k chosen whether held here or
+    not.  ``held = (first, n)`` says this rank holds experts ``first ..
+    first + n - 1``: pairs that chose one of them are sorted first, by
+    expert, and the others behind them with weight 0, so the held rows
+    are ``0 .. counts[first:first + n].sum() - 1`` and what the absent
+    experts would have added is left out.  ``counts`` and ``hits`` stay
+    ``E`` wide: a choice of an absent expert is routed, not dropped."""
     T, E = logits.shape
     k = int(k)
-    gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    gate_k, expert_k = jax.lax.top_k(gates, k)            # (T, k)
+    x = logits.astype(jnp.float32)
+    gates = jax.nn.sigmoid(x) if score == "sigmoid" \
+        else jax.nn.softmax(x, axis=-1)
+    if select_bias is None:
+        gate_k, expert_k = jax.lax.top_k(gates, k)        # (T, k)
+    else:
+        _, expert_k = jax.lax.top_k(
+            gates + jax.lax.stop_gradient(select_bias.astype(jnp.float32)),
+            k)
+        gate_k = jnp.take_along_axis(gates, expert_k, axis=-1)
     if renormalize:
         gate_k = gate_k / jnp.maximum(
             gate_k.sum(axis=-1, keepdims=True), jnp.float32(1e-9))
-    order = jnp.argsort(expert_k.reshape(T * k), stable=True)
+    if scale != 1.0:
+        gate_k = gate_k * jnp.float32(scale)
+    sort_key = expert_k
+    if held is not None:
+        first, n = held
+        here = (expert_k >= first) & (expert_k < first + n)
+        sort_key = jnp.where(here, expert_k - first, n)
+        gate_k = jnp.where(here, gate_k, jnp.float32(0.0))
+    order = jnp.argsort(sort_key.reshape(T * k), stable=True)
     slot = jnp.argsort(order).reshape(T, k)
     hits = (expert_k[..., None] == jnp.arange(E)).sum(
         axis=1).astype(jnp.float32)                        # (T, E)
@@ -168,3 +199,10 @@ def route_sorted(logits, k: int, renormalize: bool = False) -> SortedPlan:
                       counts=jax.lax.stop_gradient(counts),
                       hits=jax.lax.stop_gradient(hits), aux=aux,
                       dropped=jnp.zeros((), jnp.float32))
+
+
+def moved_select_bias(bias, counts, rate: float):
+    """One step of the selection bias (DeepSeek-V3's auxiliary-loss-free
+    balancing): ``b_e += rate * sign(mean load - load_e)``."""
+    counts = counts.astype(jnp.float32)
+    return bias + jnp.float32(rate) * jnp.sign(counts.mean() - counts)

@@ -40,10 +40,8 @@ _ACTS = ["relu", "tanh", "sigmoid", "softrelu", "identity", "silu"]
 
 
 def _act(name):
-    import jax
-    return {"relu": jax.nn.relu, "tanh": jnp.tanh,
-            "sigmoid": jax.nn.sigmoid, "softrelu": jax.nn.softplus,
-            "identity": lambda x: x, "silu": jax.nn.silu}[name]
+    from .nn import ACTIVATIONS
+    return (lambda x: x) if name == "identity" else ACTIVATIONS[name]
 
 
 # _moe_dispatch's output "counts" (list_outputs)
@@ -65,15 +63,32 @@ class MoEDispatchOp(OpDef):
     corrupted (``moe.dispatch``, the scatter choke point).
     ``capacity_factor <= 0``: no token-choice is dropped; ``dispatched``
     is the (T*k, D) rows sorted by expert (``moe.router.route_sorted``)
-    and ``counts`` their group sizes."""
+    and ``counts`` their group sizes.  In that layout the scores may be
+    each logit's ``sigmoid`` (``score``), the weights multiplied by
+    ``scale``, and ``bias_rate > 0`` adds the aux state ``select_bias``
+    (E,): it joins the scores for the choice only and moves by
+    ``bias_rate * sign(mean load - load)`` a training step.
+    ``experts_held`` > 0 says this rank holds experts ``first_expert ..
+    first_expert + experts_held - 1`` of the E routed over: their rows
+    come first, the absent experts' behind them with weight 0, and
+    ``counts`` stays E wide."""
     params = [Param("num_experts", int, required=True),
               Param("k", int, default=2),
               Param("capacity_factor", float, default=0.0),
               Param("renormalize", bool, default=False),
-              Param("layer", int, default=-1)]
+              Param("layer", int, default=-1),
+              Param("score", str, default="softmax",
+                    enum=["softmax", "sigmoid"]),
+              Param("scale", float, default=1.0),
+              Param("bias_rate", float, default=0.0),
+              Param("experts_held", int, default=0),
+              Param("first_expert", int, default=0)]
 
     def list_arguments(self, p):
         return ["data", "logits"]
+
+    def list_auxiliary_states(self, p):
+        return ["select_bias"] if p.bias_rate > 0 else []
 
     def list_outputs(self, p):
         return ["dispatched", "weight", "slot", "aux", "counts", "hits",
@@ -101,30 +116,55 @@ class MoEDispatchOp(OpDef):
             buf = (T * k, D)
         else:
             buf = (E, self._cap(p, T), D)
+            if p.score != "softmax" or p.scale != 1.0 or p.bias_rate > 0 \
+                    or p.experts_held:
+                raise MXNetError(
+                    "_moe_dispatch: score, scale, bias_rate and "
+                    "experts_held belong to the drop-free layout "
+                    "(capacity_factor <= 0)")
+        if p.experts_held and not \
+                0 <= p.first_expert <= E - p.experts_held:
+            raise MXNetError("_moe_dispatch: experts %d..%d are not among "
+                             "the %d routed" % (
+                                 p.first_expert,
+                                 p.first_expert + p.experts_held - 1, E))
         return [d, (T, E)], \
-            [buf, (T, k), (T, k), (1,), (E,), (T, E), (1,)], []
+            [buf, (T, k), (T, k), (1,), (E,), (T, E), (1,)], \
+            [(E,)] * len(self.list_auxiliary_states(p))
 
     def infer_type(self, p, in_types):
         t = in_types[0] if in_types[0] is not None else np.dtype(np.float32)
         f32 = np.dtype(np.float32)
         return [t, f32], \
-            [t, f32, np.dtype(np.int32), f32, f32, f32, f32], []
+            [t, f32, np.dtype(np.int32), f32, f32, f32, f32], \
+            [f32] * len(self.list_auxiliary_states(p))
 
     def forward(self, p, inputs, aux, ctx):
         from ..moe.dispatch import dispatch as _dispatch, sort_rows
-        from ..moe.router import route as _route, route_sorted
+        from ..moe.router import (moved_select_bias, route as _route,
+                                  route_sorted)
         x, logits = inputs
         T = x.shape[0]
         with _scope("moe_route", p):
             if drop_free(p.capacity_factor):
-                plan = route_sorted(logits, p.k, renormalize=p.renormalize)
+                plan = route_sorted(
+                    logits, p.k, renormalize=p.renormalize, score=p.score,
+                    scale=p.scale, select_bias=aux[0] if aux else None,
+                    held=(p.first_expert, p.experts_held)
+                    if p.experts_held else None)
                 buf = sort_rows(x, plan.order, plan.slot)
             else:
                 C = self._cap(p, T)
                 plan = _route(logits, p.k, C, renormalize=p.renormalize)
                 buf = _dispatch(x, plan.slot, p.num_experts, C)
-            return [buf, plan.weight, plan.slot, plan.aux.reshape(1),
+            outs = [buf, plan.weight, plan.slot, plan.aux.reshape(1),
                     plan.counts, plan.hits, plan.dropped.reshape(1)]
+            if not aux:
+                return outs
+            # the selection bias is a state of the op, as BatchNorm's
+            # moving mean is: it moves in training steps only
+            return outs, [moved_select_bias(aux[0], plan.counts, p.bias_rate)
+                          if ctx.is_train else aux[0]]
 
 
 @register_op("_moe_expert_ffn", hint="moe_experts")
@@ -136,13 +176,18 @@ class MoEExpertFFNOp(OpDef):
     rows it is grouped matmuls (``moe.dispatch.grouped_matmul``) whose
     group sizes are ``counts``.  The stacked weights (E, D, H)/(E, H, O)
     are what an ``ep``-axis ``__sharding__`` attr shards row-wise,
-    exactly like a row-sharded embedding table."""
+    exactly like a row-sharded embedding table.  ``experts_held`` > 0:
+    the stacked weights hold that many experts, ``first_expert`` on, of
+    the ``counts`` routed over (sorted rows only), and the rows behind
+    their groups come out zero."""
     params = [Param("num_hidden", int, required=True),
               Param("output_dim", int, default=0),
               Param("act_type", str, default="relu", enum=_ACTS),
               Param("no_bias", bool, default=False),
               Param("gated", bool, default=False),
-              Param("layer", int, default=-1)]
+              Param("layer", int, default=-1),
+              Param("experts_held", int, default=0),
+              Param("first_expert", int, default=0)]
 
     def list_arguments(self, p):
         # *_weight / *_bias suffixes keep auto-created variables on the
@@ -175,7 +220,7 @@ class MoEExpertFFNOp(OpDef):
         if len(d) == 3:
             E, D = d[0], d[2]
         elif len(d) == 2 and cnt is not None:
-            E, D = cnt[0], d[1]
+            E, D = p.experts_held or cnt[0], d[1]
         elif len(d) == 2:
             return in_shapes, [None], []
         else:
@@ -190,7 +235,8 @@ class MoEExpertFFNOp(OpDef):
             shapes.append(w)
             if not p.no_bias:
                 shapes.append(b)
-        return shapes + [(E,)], [tuple(d[:-1]) + (O,)], []
+        return shapes + [cnt if cnt is not None else (E,)], \
+            [tuple(d[:-1]) + (O,)], []
 
     def infer_type(self, p, in_types):
         t = in_types[0] if in_types[0] is not None else np.dtype(np.float32)
@@ -211,14 +257,31 @@ class MoEExpertFFNOp(OpDef):
         else:
             from ..moe.dispatch import grouped_matmul
             sizes = counts.astype(jnp.int32)
-            E = counts.shape[0]
+            mine = None
+            if p.experts_held:
+                # the dispatch node sorted this rank's rows first: the
+                # groups are its experts', the rows behind them no one's
+                sizes = sizes[p.first_expert:p.first_expert
+                              + p.experts_held]
+                mine = (jnp.arange(x.shape[0]) < sizes.sum())[:, None]
+            E = sizes.shape[0]
+
+            def own(rows):
+                """Rows that belong to no group read exactly zero, in
+                this pass and (the select's transpose) in the backward
+                one: a grouped matmul leaves them unwritten, which on a
+                TPU is whatever the buffer held."""
+                return rows if mine is None else jnp.where(
+                    mine, rows, jnp.zeros((), rows.dtype))
+
+            x = own(x)
             expert_of_row = None if p.no_bias else jnp.repeat(
                 jnp.arange(E), sizes, total_repeat_length=x.shape[0])
 
             def linear(h, w, b):
                 out = grouped_matmul(h, w, sizes)
-                return out if b is None else out + jnp.take(
-                    b, expert_of_row, axis=0)
+                return own(out if b is None else out + jnp.take(
+                    b, expert_of_row, axis=0))
         act = _act(p.act_type)
         with _scope("moe_experts", p):
             if p.gated:

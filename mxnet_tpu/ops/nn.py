@@ -30,23 +30,19 @@ def _conv_out(x, k, s, p, d=1):
     return (x + 2 * p - eff) // s + 1
 
 
+# act_type -> function, for Activation and the routed experts (ops/moe.py)
+ACTIVATIONS = {"relu": jax.nn.relu, "sigmoid": jax.nn.sigmoid,
+               "tanh": jnp.tanh, "softrelu": jax.nn.softplus,
+               "silu": jax.nn.silu}
+
+
 @register_op("Activation", hint="activation")
 class ActivationOp(OpDef):
     """reference activation-inl.h:182."""
-    params = [Param("act_type", str, required=True,
-                    enum=["relu", "sigmoid", "tanh", "softrelu"])]
+    params = [Param("act_type", str, required=True, enum=list(ACTIVATIONS))]
 
     def forward(self, p, inputs, aux, ctx):
-        x = inputs[0]
-        if p.act_type == "relu":
-            return [jax.nn.relu(x)]
-        if p.act_type == "sigmoid":
-            return [jax.nn.sigmoid(x)]
-        if p.act_type == "tanh":
-            return [jnp.tanh(x)]
-        if p.act_type == "softrelu":
-            return [jax.nn.softplus(x)]
-        raise MXNetError("unknown act_type %s" % p.act_type)
+        return [ACTIVATIONS[p.act_type](inputs[0])]
 
 
 @register_op("FullyConnected", hint="fullyconnected")

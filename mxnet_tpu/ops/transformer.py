@@ -84,8 +84,10 @@ def rotary_embedding(x, theta: float):
 
 
 def causal_attention(q, k, v, scale: float):
-    """Causal multi-head self-attention of ``(B, T, H, Dh)`` q, k, v ->
-    ``(B, T, H, Dh)``; scores, softmax and accumulation in float32.
+    """Causal multi-head self-attention of ``(B, T, H, Dh)`` q and k and
+    ``(B, T, H, Dv)`` v -> ``(B, T, H, Dv)`` (the value heads may be
+    narrower or wider than the query's, as in latent attention); scores,
+    softmax and accumulation in float32.
 
     One algorithm, two lowerings.  Inputs the flash-attention kernel
     takes (``_kernel_takes``) run it where the program is LOWERED for a
@@ -97,7 +99,9 @@ def causal_attention(q, k, v, scale: float):
     the track names dtype and shape."""
     kernel = _kernel_takes(q, k, v)
     trace.counter("attn:lowering", cat="ops",
-                  track="%s%s" % (q.dtype.name, list(q.shape)),
+                  track="%s%s%s" % (q.dtype.name, list(q.shape),
+                                    "" if v.shape[3] == q.shape[3]
+                                    else "x%d" % v.shape[3]),
                   kernel=int(kernel), plain=int(not kernel))
     if not kernel:
         return _plain_attention(q, k, v, scale)
@@ -115,12 +119,13 @@ def _kernel_tiles(t: int):
 def _kernel_takes(q, k, v) -> bool:
     """What the TPU kernel's tiling accepts: the configuration's compute
     dtype (float32 keeps the plain blocks its chip parity was measured
-    on), heads of whole 128-lane rows, sequences of whole tiles and
-    tiles of whole slices."""
-    t, dh = q.shape[1], q.shape[3]
+    on), value heads of whole 128-lane rows, query/key heads of at least
+    one (``_flash_fwd`` pads 192 to 256 with zeros, which no score
+    sees), sequences of whole tiles and tiles of whole slices."""
+    t, dh, dv = q.shape[1], q.shape[3], v.shape[3]
     tile, piece = _kernel_tiles(t)
     return (all(x.dtype == jnp.bfloat16 for x in (q, k, v))
-            and dh % 128 == 0 and t % 128 == 0
+            and dh >= 128 and dv % 128 == 0 and t % 128 == 0
             and t % tile == 0 and tile % piece == 0)
 
 
@@ -150,9 +155,13 @@ def _flash_fwd(q, k, v, scale):
     attend = sk.make_splash_mha_single_device(
         sm.MultiHeadMask([sm.CausalMask((t, t))] * h), block_sizes=sizes)
 
+    lanes = -q.shape[3] % 128        # query/key heads to whole 128 lanes
+
     def kernel(q, k, v):
+        q, k = (jnp.pad(x, ((0, 0),) * 3 + ((0, lanes),)) if lanes else x
+                for x in (q * scale, k))
         out = jax.vmap(attend)(*(x.transpose(0, 2, 1, 3)
-                                 for x in (q * scale, k, v)))
+                                 for x in (q, k, v)))
         return out.transpose(0, 2, 1, 3)
 
     # bfloat16 products are exact at any precision, and Mosaic refuses
@@ -200,7 +209,7 @@ def _plain_attention(q, k, v, scale: float):
         out = one_block((jnp.int32(0), blocks[0]))[None]
     else:
         out = lax.map(one_block, (jnp.arange(nb, dtype=jnp.int32), blocks))
-    out = out.transpose(1, 0, 2, 3, 4).reshape(b, nb * bq, h, dh)
+    out = out.transpose(1, 0, 2, 3, 4).reshape(b, nb * bq, h, v.shape[3])
     return out[:, :t] if pad else out
 
 
@@ -242,18 +251,21 @@ class RotaryEmbeddingOp(OpDef):
 
 @register_op("CausalSelfAttention", hint="attention")
 class CausalSelfAttentionOp(OpDef):
-    """Causal multi-head self-attention over ``(B, T, H, Dh)`` query,
-    key and value: ``softmax(q k^T * scale + causal mask) v`` per head,
-    softmax in float32, scores never materialized whole.  ``scale`` 0
-    means ``Dh**-0.5``; ``layer`` names the trace scope.
+    """Causal multi-head self-attention over ``(B, T, H, Dh)`` query
+    and key and ``(B, T, H, Dv)`` value (``Dv`` may differ from ``Dh``:
+    latent attention's 192 against 128): ``softmax(q k^T * scale +
+    causal mask) v`` per head -> ``(B, T, H, Dv)``, softmax in float32,
+    scores never materialized whole.  ``scale`` 0 means ``Dh**-0.5``;
+    ``layer`` names the trace scope.
 
     Which lowering runs is ``causal_attention``'s choice, from the
     platform the program is lowered for and the inputs: bfloat16 with
-    ``Dh % 128 == 0`` and ``T`` a multiple of 128 and of its tile
-    (``min(1024, T)``, itself whole slices of 512), lowered for a TPU,
-    is JAX's Pallas splash-attention kernel; float32, any other shape
-    and every other platform are the plain query blocks.  The counter
-    ``attn:lowering`` records it per bind."""
+    ``Dv % 128 == 0``, ``Dh >= 128`` (padded with zeros to whole 128
+    lanes inside the kernel's wrapper) and ``T`` a multiple of 128 and
+    of its tile (``min(1024, T)``, itself whole slices of 512), lowered
+    for a TPU, is JAX's Pallas splash-attention kernel; float32, any
+    other shape and every other platform are the plain query blocks.
+    The counter ``attn:lowering`` records it per bind."""
     params = [Param("scale", float, default=0.0),
               Param("layer", int, default=-1)]
 
@@ -261,17 +273,24 @@ class CausalSelfAttentionOp(OpDef):
         return ["query", "key", "value"]
 
     def infer_shape(self, p, in_shapes):
-        d = next((s for s in in_shapes if s is not None), None)
-        if d is None:
+        q, k, v = in_shapes
+        if q is None and k is None and v is None:
             return in_shapes, [None], []
-        if len(d) != 4:
-            raise MXNetError("CausalSelfAttention: inputs must be (batch, "
-                             "seq, heads, head_dim), got %r" % (d,))
-        for s in in_shapes:
-            if s is not None and tuple(s) != tuple(d):
-                raise MXNetError("CausalSelfAttention: query, key and value "
-                                 "shapes differ: %r" % (in_shapes,))
-        return [d, d, d], [d], []
+        # a shape that is not given is query's (or key's, or value's)
+        like = q if q is not None else k if k is not None else v
+        q, k, v = (like if s is None else s for s in (q, k, v))
+        for name, s in (("query", q), ("key", k), ("value", v)):
+            if len(s) != 4:
+                raise MXNetError("CausalSelfAttention: %s must be (batch, "
+                                 "seq, heads, head_dim), got %r" % (name, s))
+        if tuple(k) != tuple(q):
+            raise MXNetError("CausalSelfAttention: key %r differs from "
+                             "query %r" % (tuple(k), tuple(q)))
+        if tuple(v[:3]) != tuple(q[:3]):
+            raise MXNetError("CausalSelfAttention: value %r differs from "
+                             "query %r before the head size"
+                             % (tuple(v), tuple(q)))
+        return [q, k, v], [v], []
 
     def forward(self, p, inputs, aux, ctx):
         q, k, v = inputs

@@ -1,0 +1,515 @@
+"""Kimi Linear through the Symbol graph (ISSUE 31, tier-1): the chunked
+gated delta rule against the token recurrence, latent attention's
+unequal head sizes, the sigmoid router with its selection bias, one
+expert-parallel rank's share against the uncut layer, the whole tiny
+model (loss, every gradient, Adam's first step, the bias's first move)
+against ``benchmark/reference/kimi-linear-48b-a3b.py`` in float32, and
+what ``Module`` holds and hands back while the fused step is live."""
+import os
+import sys
+import time
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "common"))
+sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+
+import jax                                                # noqa: E402
+import jax.numpy as jnp                                   # noqa: E402
+
+import mxnet_tpu as mx                                    # noqa: E402
+from mxnet_tpu.models import kimi_linear_lm               # noqa: E402
+from mxnet_tpu.moe import MoEFeedForward                  # noqa: E402
+from mxnet_tpu.moe.router import route_sorted             # noqa: E402
+from mxnet_tpu.ops import linear_attention as kda_ops     # noqa: E402
+from mxnet_tpu.ops import transformer as tf_ops           # noqa: E402
+from check_utils import check_symbolic_forward            # noqa: E402
+
+import manifest                                           # noqa: E402
+
+REF = manifest.load_module("reference", "kimi-linear-48b-a3b")
+
+TINY = dict(num_layers=5, hidden_size=32, full_attn_layers=[4, 8],
+            dense_layers=1, kda_heads=2, kda_head_dim=8, conv_kernel=4,
+            mla_heads=2, kv_lora_rank=16, qk_nope_dim=8, qk_rope_dim=4,
+            v_head_dim=8, dense_width=64, num_experts=16, experts_per_tok=4,
+            expert_width=24, shared_width=24, routed_scale=2.446,
+            vocab_size=50, seq_len=72, experts_held=4, first_expert=4,
+            bias_rate=1e-3, rms_eps=1e-5)
+BATCH = 2
+ADAM = {"learning_rate": 1e-3, "beta1": 0.9, "beta2": 0.95,
+        "epsilon": 1e-8, "wd": 0.0, "rescale_grad": 1.0}
+
+
+def _unit(x):
+    return x / np.linalg.norm(x, axis=-1, keepdims=True)
+
+
+# -- the ops -----------------------------------------------------------------
+
+@pytest.mark.parametrize("lo,hi", [(-0.01, -1e-4), (-40.0, -5.0),
+                                   (-3.0, -0.01)],
+                         ids=["decay-near-1", "decay-near-0", "mixed"])
+def test_chunked_delta_rule_matches_the_token_recurrence(lo, hi):
+    """Forward and all five gradients, T = 150 (two whole chunks of 64
+    and a tail of 22), log-decay from nearly none to exp(-40) a token."""
+    rng = np.random.RandomState(0)
+    b, t, h, dk, dv = 2, 150, 3, 16, 8
+    q, k = (jnp.asarray(_unit(rng.randn(b, t, h, dk)), jnp.float32)
+            for _ in range(2))
+    v = jnp.asarray(rng.randn(b, t, h, dv), jnp.float32)
+    g = jnp.asarray(rng.uniform(lo, hi, (b, t, h, dk)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0.05, 0.99, (b, t, h)), jnp.float32)
+    w = jnp.cos(jnp.arange(dv, dtype=jnp.float32))
+    scale = dk ** -0.5
+
+    def run(fn):
+        return jax.value_and_grad(
+            lambda *a: (fn(*a) * w).sum(), argnums=(0, 1, 2, 3, 4))(
+                q, k, v, g, beta)
+
+    with jax.default_matmul_precision("highest"):
+        got = kda_ops.gated_delta_rule(q, k, v, g, beta, scale)
+        want = REF.delta_rule(q, k, v, g, beta)
+        assert np.abs(np.asarray(got - want)).max() \
+            <= 1e-5 * np.abs(np.asarray(want)).max()
+        (_, grads), (_, ref_grads) = run(
+            lambda *a: kda_ops.gated_delta_rule(*a, scale)), \
+            run(REF.delta_rule)
+    for x, y in zip(grads, ref_grads):
+        assert np.abs(np.asarray(x - y)).max() \
+            <= 2e-4 * np.abs(np.asarray(y)).max()
+
+
+def test_kda_op_gates_scope_and_counter():
+    """The op against the reference's pieces (L2 norm, both gates), and
+    its ``kda:lowering`` sample."""
+    rng = np.random.RandomState(1)
+    b, t, h, d = 1, 70, 2, 8
+    shapes = {"query": (b, t, h, d), "key": (b, t, h, d),
+              "value": (b, t, h, d), "decay": (b, t, h, d),
+              "beta": (b, t, h), "a_log_bias": (h,), "dt_bias": (h * d,)}
+    vals = {n: rng.randn(*s).astype(np.float32) for n, s in shapes.items()}
+    sym = mx.sym.KimiDeltaAttention(*[mx.sym.Variable(n) for n in shapes],
+                                    layer=3)
+    assert sym.infer_shape(query=(b, t, h, d), value=(b, t, h, d))[1] \
+        == [(b, t, h, d)]
+    x = {n: jnp.asarray(v) for n, v in vals.items()}
+    g = -jnp.exp(x["a_log_bias"])[:, None] * jax.nn.softplus(
+        x["decay"] + x["dt_bias"].reshape(h, d))
+    want = REF.delta_rule(REF.l2norm(x["query"]), REF.l2norm(x["key"]),
+                          x["value"], g, jax.nn.sigmoid(x["beta"]))
+    was = mx.trace.enabled()
+    mx.trace.set_enabled(True)
+    try:
+        mark = time.perf_counter_ns()
+        check_symbolic_forward(sym, vals, [np.asarray(want)], 1e-4)
+        events = mx.trace.counter_events(["kda:lowering"], since_ns=mark)
+    finally:
+        mx.trace.set_enabled(was)
+    assert events and events[0]["args"] == {"chunked": 1, "chunk": 64}
+    assert events[0]["id"] == "float32%s" % [b, t, h, d]
+    op = mx.ops.get_op("KimiDeltaAttention")
+    text = jax.jit(lambda *a: op.forward(
+        op.parse_params({"layer": 3}), list(a), [], None)[0]).lower(
+            *x.values()).as_text(debug_info=True)
+    assert "kda.l3" in text
+
+
+def test_causal_conv_and_silu():
+    rng = np.random.RandomState(2)
+    x = rng.randn(2, 9, 5).astype(np.float32)
+    w = rng.randn(5, 4).astype(np.float32)
+    want = np.zeros_like(x)
+    for t in range(9):
+        for j in range(4):
+            if t - 3 + j >= 0:
+                want[:, t] += w[:, j] * x[:, t - 3 + j]
+    sym = mx.sym.CausalConv1D(mx.sym.Variable("data"),
+                              mx.sym.Variable("weight"), kernel=4)
+    assert sym.infer_shape(data=x.shape)[0][1] == (5, 4)
+    check_symbolic_forward(sym, {"data": x, "weight": w}, [want], 1e-5)
+    assert np.allclose(np.asarray(REF.causal_conv(jnp.asarray(x),
+                                                  jnp.asarray(w))), want,
+                       atol=1e-5)
+    silu = mx.sym.Activation(mx.sym.Variable("data"), act_type="silu")
+    check_symbolic_forward(silu, {"data": x}, [x / (1 + np.exp(-x))], 1e-5)
+
+
+def test_attention_takes_value_heads_of_another_size():
+    """Latent attention's shapes: q and k of 12 a head, v of 8, against
+    dense per-head attention; which input differed is named."""
+    rng = np.random.RandomState(3)
+    b, t, h = 2, 40, 3
+    q, k = (rng.randn(b, t, h, 12).astype(np.float32) for _ in range(2))
+    v = rng.randn(b, t, h, 8).astype(np.float32)
+    sym = mx.sym.CausalSelfAttention(*[mx.sym.Variable(n) for n in "qkv"],
+                                     scale=0.3)
+    assert sym.infer_shape(q=q.shape, k=k.shape, v=v.shape)[1] == [v.shape]
+    s = np.einsum("bqhd,bkhd->bhqk", q, k) * 0.3
+    s = np.where(np.tril(np.ones((t, t), bool)), s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    want = np.einsum("bhqk,bkhd->bqhd", p / p.sum(-1, keepdims=True), v)
+    check_symbolic_forward(sym, {"q": q, "k": k, "v": v}, [want], 1e-4)
+    with pytest.raises(mx.MXNetError, match="key .* differs from query"):
+        sym.infer_shape(q=q.shape, k=(b, t, h, 8), v=v.shape)
+    with pytest.raises(mx.MXNetError, match="value .* differs from query"):
+        sym.infer_shape(q=q.shape, k=k.shape, v=(b, t + 1, h, 8))
+
+
+@pytest.mark.parametrize("dqk,kernel", [(192, True), (128, True),
+                                        (96, False)],
+                         ids=["192-padded", "128", "96-plain"])
+def test_latent_attention_lowering_on_a_tpu(dqk, kernel):
+    """bfloat16 q, k of 192 a head against v of 128, lowered for a TPU,
+    is the splash kernel (q and k padded to 256 inside its wrapper);
+    under 128 it is the plain blocks.  ``attn:lowering`` names v's size
+    where it differs."""
+    q = jax.ShapeDtypeStruct((1, 256, 2, dqk), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 256, 2, 128), jnp.bfloat16)
+    fn = jax.jit(jax.grad(lambda q, k, v: tf_ops.causal_attention(
+        q, k, v, 0.07).astype(jnp.float32).sum(), argnums=(0, 1, 2)))
+    was = mx.trace.enabled()
+    mx.trace.set_enabled(True)
+    try:
+        mark = time.perf_counter_ns()
+        text = jax.export.export(fn, platforms=["tpu"])(q, q, v) \
+            .mlir_module()
+        events = mx.trace.counter_events(["attn:lowering"], since_ns=mark)
+    finally:
+        mx.trace.set_enabled(was)
+    assert text.count("tpu_custom_call") == (2 if kernel else 0)
+    assert events[0]["args"] == {"kernel": int(kernel),
+                                 "plain": int(not kernel)}
+    assert events[0]["id"] == "bfloat16%s%s" % (
+        [1, 256, 2, dqk], "" if dqk == 128 else "x128")
+
+
+# -- the router and the share -------------------------------------------------
+
+def test_sigmoid_router_bias_moves_the_choice_and_not_the_weights():
+    rng = np.random.RandomState(4)
+    T, E, k = 24, 16, 4
+    logits = jnp.asarray(rng.randn(T, E), jnp.float32)
+    s = np.asarray(jax.nn.sigmoid(logits))
+
+    def want(bias):
+        top = np.argsort(-(s + bias), axis=-1, kind="stable")[:, :k]
+        picked = np.take_along_axis(s, top, -1)
+        return top, 2.446 * picked / picked.sum(-1, keepdims=True)
+
+    plain = route_sorted(logits, k, renormalize=True, score="sigmoid",
+                         scale=2.446, select_bias=jnp.zeros(E))
+    top0, w0 = want(np.zeros(E))
+    assert np.allclose(np.asarray(plain.weight), w0, rtol=1e-5)
+    assert np.array_equal(np.asarray(plain.counts),
+                          np.bincount(top0.ravel(), minlength=E))
+    # a bias large enough to pull expert 3 into every token's choice
+    bias = np.zeros(E, np.float32)
+    bias[3] = 2.0
+    moved = route_sorted(logits, k, renormalize=True, score="sigmoid",
+                         scale=2.446, select_bias=jnp.asarray(bias))
+    top1, w1 = want(bias)
+    assert np.asarray(moved.counts)[3] == T > np.asarray(plain.counts)[3]
+    # ... and the weights are the chosen experts' own scores, bias-free
+    assert np.allclose(np.asarray(moved.weight), w1, rtol=1e-5)
+    assert np.allclose(np.asarray(moved.weight).sum(-1), 2.446, rtol=1e-5)
+    grad = jax.grad(lambda b: route_sorted(
+        logits, k, renormalize=True, score="sigmoid",
+        select_bias=b).weight.sum())(jnp.asarray(bias))
+    assert not np.asarray(grad).any()
+
+
+def _share_block(E, k, H, D, held, first):
+    return MoEFeedForward(mx.sym.Variable("data"), num_hidden=H,
+                          num_experts=E, k=k, capacity_factor=0.0,
+                          name="moe", act_type="silu", gated=True,
+                          no_bias=True, renormalize=True, score="sigmoid",
+                          scale=2.446, bias_rate=1e-3, shared_hidden=H,
+                          output_dim=D, experts_held=held,
+                          first_expert=first)
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """E = 16 experts over 4 ranks of 4: each rank's output (its held
+    experts' part plus the shared expert), summed, with the shared
+    expert counted once, is the reference's layer with all 16 held; and
+    each rank's output is the reference given the same share."""
+    rng = np.random.RandomState(5)
+    T, D, H, E, k, held = 40, 12, 10, 16, 4, 4
+    x = rng.randn(T, D).astype(np.float32)
+    full = {"moe_gate_weight": rng.randn(E, D),
+            "moe_experts_i2h_gate_weight": 0.5 * rng.randn(E, D, H),
+            "moe_experts_i2h_weight": 0.5 * rng.randn(E, D, H),
+            "moe_experts_h2o_weight": 0.5 * rng.randn(E, H, D),
+            "moe_shared_i2h_gate_weight": 0.5 * rng.randn(H, D),
+            "moe_shared_i2h_weight": 0.5 * rng.randn(H, D),
+            "moe_shared_h2o_weight": 0.5 * rng.randn(D, H)}
+    full = {n: v.astype(np.float32) for n, v in full.items()}
+    bias = (0.3 * rng.randn(E)).astype(np.float32)
+    m = {"num_experts": E, "experts_per_tok": k, "routed_scale": 2.446}
+    p = {n: jnp.asarray(v) for n, v in full.items()}
+    p["moe_dispatch_select_bias"] = jnp.asarray(bias)
+    with jax.default_matmul_precision("highest"):
+        whole, counts = REF.moe(p, "", jnp.asarray(x), m)
+        shared = REF.swiglu(jnp.asarray(x), *(
+            p["moe_shared_%s_weight" % n]
+            for n in ("i2h_gate", "i2h", "h2o")))
+    total = np.zeros((T, D), np.float32)
+    for first in range(0, E, held):
+        mine = {n: (v[first:first + held] if "experts" in n else v)
+                for n, v in full.items()}
+        net = _share_block(E, k, H, D, held, first)
+        exe = net.simple_bind(mx.cpu(), data=(T, D), grad_req="null")
+        exe.arg_dict["data"][:] = x
+        for n, v in mine.items():
+            exe.arg_dict[n][:] = v
+        exe.aux_dict["moe_dispatch_select_bias"][:] = bias
+        exe.forward(is_train=False)
+        out = exe.outputs[0].asnumpy()
+        with jax.default_matmul_precision("highest"):
+            want, _ = REF.moe(
+                {**{n: jnp.asarray(v) for n, v in mine.items()},
+                 "moe_dispatch_select_bias": jnp.asarray(bias)}, "",
+                jnp.asarray(x), dict(m, experts_held=held,
+                                     first_expert=first))
+        assert np.abs(out - np.asarray(want)).max() \
+            <= 1e-4 * np.abs(np.asarray(want)).max()
+        # evaluation does not move the bias
+        assert np.array_equal(
+            exe.aux_dict["moe_dispatch_select_bias"].asnumpy(), bias)
+        total += out - np.asarray(shared)
+    total += np.asarray(shared)
+    assert np.asarray(counts).sum() == T * k
+    assert np.abs(total - np.asarray(whole)).max() \
+        <= 1e-4 * np.abs(np.asarray(whole)).max()
+
+
+# -- the whole model -----------------------------------------------------------
+
+def _tiny(seed, **over):
+    kwargs = dict(TINY, **over)
+    net = kimi_linear_lm(**kwargs)
+    arg_shapes, _, _ = net.infer_shape(
+        data=(BATCH, kwargs["seq_len"]),
+        softmax_label=(BATCH, kwargs["seq_len"]))
+    rng = np.random.RandomState(seed)
+    params = {}
+    for name, shape in zip(net.list_arguments(), arg_shapes):
+        if name in ("data", "softmax_label"):
+            continue
+        if name.endswith("gamma"):
+            params[name] = (1 + 0.1 * rng.randn(*shape)).astype(np.float32)
+        elif name.endswith("a_log_bias"):
+            params[name] = rng.uniform(-1, 1, shape).astype(np.float32)
+        else:
+            # wide enough that routing, gates and attention are not flat
+            params[name] = (0.2 * rng.randn(*shape)).astype(np.float32)
+    tokens = rng.randint(0, kwargs["vocab_size"],
+                         (BATCH, kwargs["seq_len"])).astype(np.int32)
+    return net, kwargs, params, tokens, np.roll(tokens, -1, axis=1)
+
+
+def _bound(net, params, tokens, labels, optimizer, optimizer_params):
+    mod = mx.mod.Module(net, context=mx.cpu(0))
+    mod.bind(data_shapes=[("data", tokens.shape)],
+             label_shapes=[("softmax_label", labels.shape)])
+    mod.init_params(mx.init.Zero(), arg_params={
+        k: mx.nd.array(v) for k, v in params.items()}, allow_missing=True)
+    mod.init_optimizer(optimizer=optimizer,
+                       optimizer_params=optimizer_params)
+    assert mod._fused is not None
+    return mod, mx.io.DataBatch(data=[mx.nd.array(tokens)],
+                                label=[mx.nd.array(labels)], pad=0)
+
+
+def _rel(got, want):
+    want = np.asarray(want)
+    return float(np.linalg.norm(got - want)
+                 / max(float(np.linalg.norm(want)), 1e-30))
+
+
+def test_model_matches_reference_gradients_adam_step_and_bias_move(
+        monkeypatch):
+    monkeypatch.delenv("MXNET_COMPUTE_DTYPE", raising=False)
+    net, kwargs, params, tokens, labels = _tiny(seed=7)
+    cfg = {"model": {"kwargs": kwargs}}
+    ref = REF.loss_and_grads(cfg, params, tokens, labels)
+
+    # every gradient, through one SGD step of the fused train step
+    lr = 0.125
+    mod, batch = _bound(net, params, tokens, labels, "sgd", {
+        "learning_rate": lr, "momentum": 0.0, "wd": 0.0,
+        "rescale_grad": 1.0})
+    mod.forward_backward(batch)
+    mod.update()
+    outs = [o.asnumpy() for o in mod.get_outputs()]
+    assert abs(float(outs[0].mean()) - ref["loss"]) <= 1e-5 * ref["loss"]
+    blocks = ["l%d_moe_dispatch" % l for l in (2, 3, 4, 5)]
+    for row, block in zip(outs[-1], blocks):
+        assert np.array_equal(row[:-1], np.asarray(ref["counts"][block]))
+        assert row[-1] == 0
+    after, aux = mod.get_params()
+    errors = {k: _rel((params[k] - after[k].asnumpy()) / lr, ref["grads"][k])
+              for k in params}
+    assert set(errors) == set(ref["grads"])
+    assert max(errors.values()) <= 2e-4, errors
+
+    # the configuration's optimizer: Adam's first step and the bias
+    names = ["l1_q_proj_weight", "l2_kda_a_log_bias", "l3_kda_dt_bias",
+             "l4_kv_b_proj_weight", "l5_moe_gate_weight",
+             "l3_moe_experts_i2h_weight", "embed_weight"]
+    want = REF.reference_step(cfg, params, {"data": tokens},
+                              {"softmax_label": labels}, ADAM, names)
+    mod, batch = _bound(net, params, tokens, labels, "adam", dict(ADAM))
+    mod.forward_backward(batch)
+    mod.update()
+    after, aux = mod.get_params()
+    for name in names:
+        got = after[name].asnumpy() - params[name]
+        # an element whose gradient is ~0 may flip sign: Adam's first
+        # step is lr * sign(g); such elements are a sliver of the norm
+        assert _rel(got, want["updates"][name]) <= 0.02, name
+    for block in blocks:
+        moved = aux[block + "_select_bias"].asnumpy()
+        assert np.allclose(moved, want["bias_moves"][block], atol=1e-9)
+        assert np.allclose(np.abs(moved)[moved != 0], 1e-3)
+
+
+def test_reference_flops_count_the_held_share():
+    """At the published widths, depth 5, 8 of 256 experts, 20 480
+    vocabulary rows: the held share of the routed experts is a quarter
+    of an expert a token."""
+    kw = dict(num_layers=5, hidden_size=2304, full_attn_layers=[4, 8],
+              dense_layers=1, kda_heads=32, kda_head_dim=128,
+              conv_kernel=4, mla_heads=32, kv_lora_rank=512,
+              qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128,
+              dense_width=9216, num_experts=256, experts_per_tok=8,
+              expert_width=1024, shared_width=1024, vocab_size=20480,
+              seq_len=4096, experts_held=8)
+    per_token = REF.train_flops_per_sample({"model": {"kwargs": kw}})
+    D, W = 2304, 4096
+    kda = 8 * D * W + 4 * (D * 128 + 128 * W) + 2 * D * 32 + 24 * W \
+        + 6 * 32 * 128 * 128
+    mla = 2 * D * 32 * 192 + 2 * D * 576 + 2 * 512 * 32 * 256 \
+        + 2 * W * D + 4096 * 32 * 320
+    sparse = 2 * D * 256 + 6 * D * 1024 + 0.25 * 6 * D * 1024
+    assert per_token == 3 * (4 * kda + mla + 6 * D * 9216 + 4 * sparse
+                             + 2 * D * 20480)
+    whole = REF.train_flops_per_sample(
+        {"model": {"kwargs": dict(kw, experts_held=0)}})
+    assert whole - per_token == 3 * 4 * 7.75 * 6 * D * 1024
+
+
+# -- what Module holds while the fused step is live ----------------------------
+
+def test_fused_module_holds_no_executor_arrays_and_hands_weights_back(
+        tmp_path):
+    """Once the fused state exists the executor group's argument and
+    gradient arrays are gone; ``get_params``, a checkpoint round trip
+    and ``_disable_fused`` still see the trained weights, and the
+    classic path goes on from them."""
+    net, kwargs, params, tokens, labels = _tiny(seed=9, num_layers=2)
+    mod, batch = _bound(net, params, tokens, labels, "adam", dict(ADAM))
+    assert mod._exec_group.execs          # bound, nothing trained yet
+    for _ in range(2):
+        mod.forward_backward(batch)
+        mod.update()
+    assert mod._fused_state is not None
+    assert mod._exec_group.execs == [] \
+        and mod._exec_group.param_arrays is None
+    trained = {k: np.asarray(v) for k, v in
+               mod._fused_state["params"].items()}
+    assert any(np.abs(trained[k] - params[k]).max() > 0 for k in params)
+    got, aux = mod.get_params()
+    assert all(np.array_equal(got[k].asnumpy(), trained[k])
+               for k in trained)
+    assert mod._exec_group.execs == []    # reading does not bind
+    # evaluation on the live weights, still without executor arrays
+    mod.forward(batch, is_train=False)
+    assert np.isfinite(mod.get_outputs()[0].asnumpy()).all()
+    assert mod._exec_group.execs == []
+
+    prefix = str(tmp_path / "kimi")
+    mod.save_checkpoint(prefix, 1)
+    _, args, auxs = mx.model.load_checkpoint(prefix, 1)
+    assert all(np.array_equal(args[k].asnumpy(), trained[k])
+               for k in trained)
+    assert all(np.array_equal(auxs[k].asnumpy(), aux[k].asnumpy())
+               for k in aux)
+    fresh = mx.mod.Module(net, context=mx.cpu(0))
+    fresh.bind(data_shapes=[("data", tokens.shape)],
+               label_shapes=[("softmax_label", labels.shape)])
+    fresh.init_params(mx.init.Zero())
+    fresh.init_optimizer(optimizer="adam", optimizer_params=dict(ADAM))
+    mx.checkpoint.restore_module(
+        mx.checkpoint.CheckpointManager(prefix + "-ckpt"), fresh)
+    assert all(np.array_equal(np.asarray(fresh._fused_state["params"][k]),
+                              trained[k]) for k in trained)
+
+    # both modules take the same third step: one fused, one classic
+    fresh.forward_backward(batch)
+    fresh.update()
+    mod._disable_fused("test")
+    assert mod._fused is None and mod._exec_group.execs
+    for name, block in zip(mod._param_names, mod._exec_group.param_arrays):
+        assert np.array_equal(block[0].asnumpy(), trained[name])
+    mod.forward_backward(batch)
+    mod.update()
+    classic = mod.get_params()[0]
+    fused = fresh.get_params()[0]
+    for k in trained:
+        assert np.abs(classic[k].asnumpy() - fused[k].asnumpy()).max() \
+            <= 1e-5 + 1e-3 * np.abs(fused[k].asnumpy() - trained[k]).max()
+
+
+def test_set_params_and_a_new_optimizer_bind_the_executor_arrays_again():
+    net, kwargs, params, tokens, labels = _tiny(seed=10, num_layers=2)
+    mod, batch = _bound(net, params, tokens, labels, "adam", dict(ADAM))
+    mod.forward_backward(batch)
+    mod.update()
+    assert mod._exec_group.execs == []
+    trained, aux = mod.get_params()
+    trained = {k: v.asnumpy() for k, v in trained.items()}
+    mod.init_optimizer(optimizer="sgd", force_init=True, optimizer_params={
+        "learning_rate": 0.01, "rescale_grad": 1.0})
+    assert mod._fused_state is None and mod._exec_group.execs
+    for name, block in zip(mod._param_names, mod._exec_group.param_arrays):
+        assert np.array_equal(block[0].asnumpy(), trained[name])
+    mod.forward_backward(batch)
+    mod.update()
+    assert mod._exec_group.execs == []
+    mod.set_params({k: mx.nd.array(v) for k, v in params.items()}, aux)
+    assert mod._fused_state is None and mod._exec_group.execs
+    for name, block in zip(mod._param_names, mod._exec_group.param_arrays):
+        assert np.array_equal(block[0].asnumpy(), params[name])
+
+
+def test_fit_feeds_the_held_share_to_moe_load():
+    net, kwargs, params, tokens, labels = _tiny(seed=3)
+    rng = np.random.RandomState(0)
+    X = rng.randint(0, kwargs["vocab_size"],
+                    (8, kwargs["seq_len"])).astype(np.int32)
+    it = mx.io.NDArrayIter(X, np.roll(X, -1, 1), batch_size=BATCH)
+    was = mx.trace.enabled()
+    mx.trace.set_enabled(True)
+    try:
+        since = time.perf_counter_ns()
+        mod = mx.mod.Module(net, context=mx.cpu(0))
+        mod.fit(it, num_epoch=1, eval_metric=mx.metric.OutputMean(0),
+                optimizer="adam", initializer=mx.init.Normal(0.02),
+                optimizer_params=dict(ADAM))
+        events = mx.trace.counter_events(["moe:load"], since_ns=since)
+    finally:
+        mx.trace.set_enabled(was)
+    blocks = ["l%d_moe_dispatch" % l for l in (2, 3, 4, 5)]
+    assert mod._fused.moe_load_heads[1] == blocks
+    assert len(events) == 4 * len(blocks)
+    routed = BATCH * kwargs["seq_len"] * kwargs["experts_per_tok"]
+    for e in events:
+        assert e["args"]["routed"] == routed and e["args"]["dropped"] == 0
+        assert 0 < e["args"]["held"] < routed
+    assert [n for n in mod._aux_names] == [b + "_select_bias"
+                                           for b in blocks]
