@@ -45,10 +45,12 @@ def kimi_linear_lm(num_layers, hidden_size, full_attn_layers, dense_layers,
                    shared_width, routed_scale, vocab_size, seq_len,
                    experts_held=0, first_expert=0, bias_rate=1e-3,
                    rms_eps=1e-5):
-    """The training symbol; see the module docstring.  Each KDA core is
-    marked ``force_mirroring``: its float32 chunk products (0.6 GB a
-    layer at 4096 tokens of the published widths) are computed again in
-    the backward pass instead of kept."""
+    """The training symbol; see the module docstring.  No KDA core is
+    marked ``force_mirroring``: where the op's kernels run (heads of 128,
+    whole chunks, a TPU) its backward pass keeps the op's inputs and the
+    chunks' entry states, and the backward kernel computes the float32
+    chunk products again itself (0.6 GB a layer at 4096 tokens of the
+    published widths, were they kept)."""
     full_attn_layers = set(full_attn_layers)
     kda_width = kda_heads * kda_head_dim
 
@@ -78,8 +80,7 @@ def kimi_linear_lm(num_layers, hidden_size, full_attn_layers, dense_layers,
         beta = sym.Reshape(proj(h, pre + "beta_proj", kda_heads),
                            shape=(-1, seq_len, kda_heads))
         o = sym.KimiDeltaAttention(q, k, v, decay, beta, layer=l,
-                                   name=pre + "kda",
-                                   attr={"force_mirroring": "true"})
+                                   name=pre + "kda")
         o = norm(sym.Reshape(o, shape=(-1, kda_head_dim)), pre + "o_norm")
         gate = proj(proj(h, pre + "g_down", kda_head_dim),
                     pre + "g_up", kda_width, bias=True)

@@ -19,11 +19,44 @@ exp(-G_j)`` across more than the chunk's decay allows: blocks of
 <= 1), and a block against itself is computed channel by channel.  So a
 decay of ``exp(-40)`` a token is as safe as one of 1.
 
-One lowering, plain ``lax`` / ``jnp``, differentiable by autodiff; the
-counter ``kda:lowering`` records it per traced op as ``attn:lowering``
-does for attention, and the body runs under ``kda.l<layer>``.
+The op's body, ``kimi_delta_attention`` (normalize q and k, the two
+gates, the rule), is one algorithm with two lowerings, chosen from what
+the code observes as ``causal_attention`` chooses.  The plain ``lax`` /
+``jnp`` chunks above, differentiable by autodiff, run on every platform
+and are the parity oracle.  Where the program is LOWERED for a TPU and
+the inputs are ones the kernels take (``_kernel_takes``: whole chunks,
+heads of 128), two Pallas kernels run the rule instead,
+``kda_chunk_fwd`` and ``kda_chunk_bwd``, over a grid of (batch, a few
+heads, chunk): the heads' states and the chunk's float32 products stay
+in VMEM, the state is carried along the sequential chunk axis in
+scratch, and the backward kernel computes the chunk algebra again from
+q, k, v, g, beta and the chunk's entry state, which the forward kernel
+writes out (``f32[B, H, N, Dv, Dk]``).  Inside the kernels ``exp(G_i -
+G_j)`` is kept below 1 by halving: at level ``l`` a block of ``2 << l``
+tokens takes the running sum at the end of its first half as the
+reference point of its lower-left quarter, so every pair ``j < i`` is
+formed once, by a matmul, at the level where it first falls into two
+halves; the unit triangular system is inverted by the same halving (``T
+<- T - T X T``).  Pairs less than ``KDA_SUB`` apart, the inverse, its
+products with the right-hand sides and the running sums keep float32's
+digits whatever the matmul precision in force (three bfloat16 passes),
+as the plain chunks form them in float32; every other product follows
+the precision in force, as the plain chunks' matmuls do.
+
+The lowering differentiates itself (``jax.custom_vjp`` around the op's
+body, which keeps the op's inputs and the entry states and nothing
+else) through two module-level ``jax.jit`` functions of arrays and
+static sizes only, so a process traces each kernel once and a program
+holds each once, whatever the number of layers and modules that call
+them: the counter ``kda:kernel_trace`` (``fwd`` / ``bwd``) fires from
+inside their bodies and so counts traces, not calls.  The counter
+``kda:lowering`` records the choice per traced op (``kernel`` /
+``plain``) as ``attn:lowering`` does for attention, and the op's body
+runs under ``kda.l<layer>``.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -31,16 +64,20 @@ from jax import lax
 
 from .. import trace
 from ..base import MXNetError
+from .pallas_kernels import _kernel_on_tpu, pl
 from .registry import OpDef, Param, register_op
 from .transformer import layer_scope
 
-__all__ = ["causal_conv1d", "gated_delta_rule", "kda_gates"]
+__all__ = ["causal_conv1d", "gated_delta_rule", "kda_gates",
+           "kimi_delta_attention"]
 
 # tokens a chunk: one triangular system and one step of the state's scan
 KDA_CHUNK = 64
 # tokens a block inside a chunk: its own (KDA_SUB, KDA_SUB, Dk) products
 # are formed channel by channel, every other block by a matmul
 KDA_SUB = 16
+# the one head size the kernels take: a row of lanes, a square state
+KDA_KERNEL_DIM = 128
 
 
 def _l2norm(x, eps=1e-6):
@@ -100,16 +137,14 @@ def gated_delta_rule(q, k, v, g, beta, scale: float, chunk: int = KDA_CHUNK):
     and g (float32 log-decay), ``(B, T, H, Dv)`` v and ``(B, T, H)``
     beta, from a zero state -> ``(B, T, H, Dv)`` in v's dtype.  Chunked;
     T need not be a multiple of the chunk (the tail is padded with
-    tokens that write nothing)."""
+    tokens that write nothing).  The plain chunks: the lowering of every
+    platform, and the kernels' parity twin."""
     b, t, h, dk = q.shape
     dv = v.shape[-1]
     chunk = min(chunk, t)
     n = -(-t // chunk)
     pad = n * chunk - t
     f32 = jnp.float32
-    trace.counter("kda:lowering", cat="ops",
-                  track="%s%s" % (v.dtype.name, list(q.shape)),
-                  chunked=1, chunk=chunk)
 
     def blocks(x):                      # (B, T, H, ..) -> (N, B, H, C, ..)
         x = x.astype(f32)
@@ -144,6 +179,453 @@ def gated_delta_rule(q, k, v, g, beta, scale: float, chunk: int = KDA_CHUNK):
     _, o = lax.scan(one_chunk, jnp.zeros((b, h, dk, dv), f32), xs)
     o = jnp.moveaxis(o, 0, 1).transpose(0, 1, 3, 2, 4)    # (B, N, C, H, Dv)
     return o.reshape(b, n * chunk, h, dv)[:, :t].astype(out_dtype)
+
+
+# -- the TPU lowering ---------------------------------------------------------
+
+# heads a grid step: their chains of small dependent matmuls interleave
+KDA_KERNEL_HEADS = 4
+_NN = (((2,), (1,)), ((0,), (0,)))    # a @ b, a batch of heads
+_NT = (((2,), (2,)), ((0,), (0,)))    # a @ b.T
+_TN = (((1,), (1,)), ((0,), (0,)))    # a.T @ b
+
+
+def _bf16_parts(x, n):
+    """x as a sum of n bfloat16 arrays, each the bfloat16 of what the
+    ones before it left: two hold 16 of float32's 24 digits, three all."""
+    parts = []
+    for _ in range(n):
+        parts.append(x.astype(jnp.bfloat16))
+        x = x - parts[-1].astype(jnp.float32)
+    return parts
+
+
+def _one_pass(a, b, dims):
+    return lax.dot_general(a, b, dims, precision=lax.Precision.DEFAULT,
+                           preferred_element_type=jnp.float32)
+
+
+def _dot(a, b, dims, exact=False):
+    """A batch of float32 products on the MXU: at the precision in force
+    (one bfloat16 pass at the default one), or ``exact``, to 2**-16 of
+    each product whatever is in force, by three passes over the
+    operands' bfloat16 halves."""
+    if not exact:
+        return lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+    (ah, al), (bh, bl) = _bf16_parts(a, 2), _bf16_parts(b, 2)
+    return _one_pass(ah, bh, dims) + (_one_pass(ah, bl, dims)
+                                      + _one_pass(al, bh, dims))
+
+
+def _sum_rows(ones, x):
+    """``ones @ x`` for a ``(C, C)`` matrix of zeros and ones, exactly:
+    the ones are whole in bfloat16, so three passes over x's three
+    bfloat16 parts lose nothing."""
+    ones = jnp.broadcast_to(ones.astype(jnp.bfloat16),
+                            x.shape[:1] + ones.shape)
+    return sum(_one_pass(ones, part, _NN) for part in _bf16_parts(x, 3))
+
+
+def _level(l, q, k, g, G, scale, row):
+    """Level ``l`` of the halving (blocks of ``2 << l`` tokens, halves of
+    ``s = 1 << l``): rows of a block's second half decayed from the
+    block's reference point, the running sum at the end of its first
+    half, and rows of the first half decayed up to it; both factors are
+    <= 1.  -> k and q times the first, k times the second, the factors."""
+    from jax.experimental.pallas import tpu as pltpu
+    c = q.shape[1]
+    s = 1 << l
+    second = ((row >> l) & 1) == 1                       # (C, 1)
+    if l == 0:
+        x = jnp.where(second, g, 0.0)
+    elif l == 1:
+        at = row & 3
+        x = jnp.where(at == 0, pltpu.roll(g, c - 1, 1), 0.0) \
+            + jnp.where(at >= 2, g, 0.0) \
+            + jnp.where(at == 3, pltpu.roll(g, 1, 1), 0.0)
+    else:
+        ref = jnp.concatenate(
+            [jnp.broadcast_to(G[:, lo + s - 1:lo + s], g.shape[:1]
+                              + (2 * s,) + g.shape[2:])
+             for lo in range(0, c, 2 * s)], 1)
+        x = jnp.where(second, G - ref, ref - G)
+    f = jnp.exp(jnp.minimum(x, 0.0))
+    left, right = jnp.where(second, f, 0.0), jnp.where(second, 0.0, f)
+    return k * left, q * left * scale, k * right, left, right
+
+
+def _pair_masks(c):
+    """-> the row and column indices ``(C, 1)`` / ``(1, C)`` and, a
+    level, the ``(C, C)`` mask of pairs whose row lies in the second and
+    whose column in the first half of one block of that level."""
+    row = lax.broadcasted_iota(jnp.int32, (c, 1), 0)
+    col = lax.broadcasted_iota(jnp.int32, (1, c), 1)
+    return row, col, [((row >> (l + 1)) == (col >> (l + 1)))
+                      & (((row >> l) & 1) == 1) & (((col >> l) & 1) == 0)
+                      for l in range(c.bit_length() - 1)]
+
+
+# levels whose pairs are less than KDA_SUB apart: exact whatever the
+# precision in force, as the plain chunks form them channel by channel
+_FINE_LEVELS = KDA_SUB.bit_length() - 1
+
+
+def _chunk_forward(q, k, v, g, beta, St, scale):
+    """One chunk of a few heads, everything float32: q, k, g ``(H, C,
+    Dk)``, v ``(H, C, Dv)``, beta ``(H, C, 1)``, the entry states
+    transposed ``(H, Dv, Dk)`` -> the chunk's products by name.  ``A``
+    and ``Bs`` are the scores of ``_chunk_scores`` (``Bs`` times
+    ``scale``), ``T`` the inverse of ``I + beta A``, ``u`` the
+    corrections, ``o`` the output and ``S1t`` the exit states."""
+    c = q.shape[1]
+    row, col, pairs = _pair_masks(c)
+    G = _sum_rows(row >= col, g)                 # running sum of g
+
+    A = Bs = jnp.zeros((c, c), jnp.float32)
+    for l, pair in enumerate(pairs):
+        kl, ql, kr, _, _ = _level(l, q, k, g, G, scale, row)
+        both = _dot(jnp.concatenate([kl, ql], 1), kr, _NT,
+                    exact=l < _FINE_LEVELS)
+        A = A + jnp.where(pair, both[:, :c], 0.0)
+        Bs = Bs + jnp.where(pair, both[:, c:], 0.0)
+    Bs = Bs + jnp.where(row == col, jnp.sum(q * k, axis=2, keepdims=True)
+                        * scale, 0.0)
+
+    M = beta * A
+    T = jnp.where(row == col, 1.0, 0.0) - jnp.where(pairs[0], M, 0.0)
+    for pair in pairs[1:]:          # blocks of 2s from blocks of s
+        X = jnp.where(pair, M, 0.0)
+        T = T - _dot(T, _dot(X, T, _NN, True), _NN, True)
+    decayed = jnp.exp(G)
+    Gc = G[:, c - 1:c]
+    to_end = jnp.exp(Gc - G)
+    kd = k * decayed
+    qd = q * decayed * scale
+    kc = k * to_end
+    ec = jnp.exp(Gc)                                    # (H, 1, Dk)
+    r = v - _dot(kd, St, _NT)
+    u = _dot(T, beta * r, _NN, True)
+    o = _dot(qd, St, _NT) + _dot(Bs, u, _NN)
+    S1t = St * ec + _dot(u, kc, _TN)
+    return dict(G=G, A=A, Bs=Bs, T=T, kd=kd, qd=qd, kc=kc, ec=ec, r=r, u=u,
+                o=o, S1t=S1t, decayed=decayed, to_end=to_end, row=row,
+                col=col, pairs=pairs)
+
+
+def _heads(ref, d):
+    """A ``(C, H * D)`` block as ``(H, C, D)`` float32."""
+    return jnp.stack([ref[:, i * d:(i + 1) * d].astype(jnp.float32)
+                      for i in range(ref.shape[1] // d)])
+
+
+def _put_heads(ref, x):
+    d = x.shape[2]
+    for i in range(x.shape[0]):
+        ref[:, i * d:(i + 1) * d] = x[i].astype(ref.dtype)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, state, *,
+                scale):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    d = state.shape[-1]
+    St = state[...]
+    s_ref[...] = St
+    c = _chunk_forward(_heads(q_ref, d), _heads(k_ref, d), _heads(v_ref, d),
+                       _heads(g_ref, d), b_ref[...], St, scale)
+    _put_heads(o_ref, c["o"])
+    state[...] = c["S1t"]
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref, dq_ref,
+                dk_ref, dv_ref, dg_ref, db_ref, dstate, *, scale):
+    """The chunks in reverse: the chunk algebra again from the inputs and
+    the entry states, then its transpose; ``dstate`` carries the states'
+    cotangent (transposed) to the chunk before."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate[...] = jnp.zeros_like(dstate)
+
+    d = dstate.shape[-1]
+    q, k, g = _heads(q_ref, d), _heads(k_ref, d), _heads(g_ref, d)
+    beta, St, dS1t, do = b_ref[...], s_ref[...], dstate[...], _heads(do_ref, d)
+    c = _chunk_forward(q, k, _heads(v_ref, d), g, beta, St, scale)
+    n = q.shape[1]
+    row, col = c["row"], c["col"]
+    u, T, kd, qd, kc = c["u"], c["T"], c["kd"], c["qd"], c["kc"]
+
+    dqd = _dot(do, St, _NN)
+    dBs = jnp.where(row >= col, _dot(do, u, _NT), 0.0)
+    du = _dot(c["Bs"], do, _TN) + _dot(kc, dS1t, _NT)
+    dkc = _dot(u, dS1t, _NN)
+    dGc = jnp.sum(dS1t * St, axis=1, keepdims=True) * c["ec"] \
+        + jnp.sum(dkc * kc, axis=1, keepdims=True)
+    dy = _dot(T, du, _TN, True)
+    dM = jnp.where(row > col, -_dot(dy, u, _NT), 0.0)
+    dA = beta * dM
+    dr = beta * dy
+    db_ref[...] = jnp.sum(dM * c["A"], axis=2, keepdims=True) \
+        + jnp.sum(dy * c["r"], axis=2, keepdims=True)
+    _put_heads(dv_ref, dr)
+    dkd = -_dot(dr, St, _NN)
+    dstate[...] = _dot(do, qd, _TN) + dS1t * c["ec"] - _dot(dr, kd, _TN)
+
+    diag = jnp.sum(jnp.where(row == col, dBs, 0.0), axis=2,
+                   keepdims=True) * scale
+    dq = dqd * c["decayed"] * scale + diag * k
+    dk = dkd * c["decayed"] + dkc * c["to_end"] + diag * q
+    dG = dkd * kd + dqd * qd - dkc * kc + jnp.where(row == n - 1, dGc, 0.0)
+    for l, pair in enumerate(c["pairs"]):
+        exact = l < _FINE_LEVELS
+        kl, ql, kr, left, right = _level(l, q, k, g, c["G"], scale, row)
+        dAB = jnp.concatenate([jnp.where(pair, dA, 0.0),
+                               jnp.where(pair, dBs, 0.0)], 1)
+        dleft = _dot(dAB, kr, _NN, exact)               # (H, 2C, Dk)
+        dkl, dql = dleft[:, :n], dleft[:, n:]
+        dkr = _dot(dAB, jnp.concatenate([kl, ql], 1), _TN, exact)
+        dq = dq + dql * left * scale
+        dk = dk + dkl * left + dkr * right
+        moved = dkl * kl + dql * ql - dkr * kr
+        if not exact:
+            # a block's rows move G by amounts that add up to nothing but
+            # for what the products at the precision in force lost; the
+            # reference row takes that, as autodiff of the plain chunks
+            # gives it to theirs, or the running sum below would carry
+            # it to every row before the block
+            s = 1 << l
+            moved = moved - _sum_rows(
+                row == ((col >> (l + 1)) << (l + 1)) + s - 1, moved)
+        dG = dG + moved
+    _put_heads(dq_ref, dq)
+    _put_heads(dk_ref, dk)
+    # g's cotangent is the running sum of G's from the chunk's end
+    _put_heads(dg_ref, _sum_rows(row <= col, dG))
+
+
+def _kernel_specs(b, t, h, d, flip):
+    """The grid over (batch, heads, chunk) and the blocks of one step:
+    ``(C, heads * D)`` of a ``(B, T, H * D)`` array, ``(heads, C, 1)`` of
+    beta's ``(B, H, T, 1)``, ``(heads, D, D)`` states of ``(B, H, N, D,
+    D)``.  ``flip`` walks the chunks from the last."""
+    from jax.experimental.pallas import tpu as pltpu
+    chunk, n = KDA_CHUNK, t // KDA_CHUNK
+    heads = next(x for x in range(min(KDA_KERNEL_HEADS, h), 0, -1)
+                 if h % x == 0)
+    at = (lambda i: n - 1 - i) if flip else (lambda i: i)
+    return dict(
+        grid=(b, h // heads, n),
+        seq=pl.BlockSpec((None, chunk, heads * d),
+                         lambda i, j, m: (i, at(m), j)),
+        col=pl.BlockSpec((None, heads, chunk, 1),
+                         lambda i, j, m: (i, j, at(m), 0)),
+        state=pl.BlockSpec((None, heads, None, d, d),
+                           lambda i, j, m: (i, j, at(m), 0, 0)),
+        scratch=[pltpu.VMEM((heads, d, d), jnp.float32)],
+        # the unrolled levels of a few heads spill more than the 16 MiB
+        # a kernel is given unasked; a v5e core has 128 MiB
+        params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+    )
+
+
+# lint: allow(raw-jit) — never dispatched on its own: a jit inside the step
+# program, there so that every call site shares one traced jaxpr and one
+# lowered function; the step that holds it goes through the cache
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _kda_fwd(q, k, v, g, beta, *, scale, interpret):
+    """``kda_chunk_fwd`` over ``(B, T, H * D)`` q, k, g (float32) and v,
+    ``(B, H, T, 1)`` beta -> the output in v's layout and dtype and every
+    chunk's entry state ``(B, H, N, D, D)``, transposed.  Module-level
+    and free of per-call objects: traced once a process."""
+    b, t, hd = q.shape
+    h = beta.shape[1]
+    d = hd // h
+    trace.counter("kda:kernel_trace", cat="ops",
+                  track="%s%s" % (v.dtype.name, [b, t, h, d]), fwd=1, bwd=0)
+    sp = _kernel_specs(b, t, h, d, flip=False)
+    # lint: allow(raw-pallas-call) — one lowering of this op, a pair with
+    # its own vjp: ops/pallas_kernels holds forward kernels behind the
+    # kernel search's bitwise gate, which a pair chosen by platform and
+    # held to the plain chunks by tolerance (tests/test_kimi_linear.py,
+    # tests/tpu) cannot ride
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale),
+        grid=sp["grid"],
+        in_specs=[sp["seq"]] * 4 + [sp["col"]],
+        out_specs=[sp["seq"], sp["state"]],
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct((b, h, t // KDA_CHUNK, d, d),
+                                        jnp.float32)],
+        scratch_shapes=sp["scratch"], compiler_params=sp["params"],
+        interpret=interpret, name="kda_chunk_fwd",
+    )(q, k, v, g, beta)
+
+
+# lint: allow(raw-jit) — as _kda_fwd
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _kda_bwd(q, k, v, g, beta, states, do, *, scale, interpret):
+    """``kda_chunk_bwd``: the cotangents of q, k, v, g and beta from the
+    forward kernel's inputs, its entry states and the output's cotangent,
+    all in ``_kda_fwd``'s layouts.  Traced once a process."""
+    b, t, hd = q.shape
+    h = beta.shape[1]
+    d = hd // h
+    trace.counter("kda:kernel_trace", cat="ops",
+                  track="%s%s" % (v.dtype.name, [b, t, h, d]), fwd=0, bwd=1)
+    sp = _kernel_specs(b, t, h, d, flip=True)
+    f32 = jax.ShapeDtypeStruct(q.shape, jnp.float32)
+    # lint: allow(raw-pallas-call) — as _kda_fwd
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale),
+        grid=sp["grid"],
+        in_specs=[sp["seq"]] * 4 + [sp["col"], sp["state"], sp["seq"]],
+        out_specs=[sp["seq"]] * 4 + [sp["col"]],
+        out_shape=[f32, f32, jax.ShapeDtypeStruct(v.shape, v.dtype), f32,
+                   jax.ShapeDtypeStruct(beta.shape, jnp.float32)],
+        scratch_shapes=sp["scratch"], compiler_params=sp["params"],
+        interpret=interpret, name="kda_chunk_bwd",
+    )(q, k, v, g, beta, states, do)
+
+
+def _kernel_layout(q, k, v, g, beta):
+    """``(B, T, H, D)`` -> ``(B, T, H * D)`` (free), q, k, g in float32;
+    beta ``(B, T, H)`` -> ``(B, H, T, 1)``."""
+    b, t = q.shape[:2]
+    f32 = jnp.float32
+    return (q.astype(f32).reshape(b, t, -1), k.astype(f32).reshape(b, t, -1),
+            v.reshape(b, t, -1), g.astype(f32).reshape(b, t, -1),
+            beta.astype(f32).transpose(0, 2, 1)[..., None])
+
+
+def _kernel_rule(q, k, v, g, beta, scale: float, interpret: bool = False):
+    """``gated_delta_rule`` by ``kda_chunk_fwd`` for inputs
+    ``_kernel_takes`` accepts -> the output and every chunk's entry
+    state, which ``_kernel_rule_vjp`` wants back."""
+    o, states = _kda_fwd(*_kernel_layout(q, k, v, g, beta), scale=scale,
+                         interpret=interpret)
+    return o.reshape(v.shape), states
+
+
+def _kernel_rule_vjp(q, k, v, g, beta, states, do, scale: float,
+                     interpret: bool = False):
+    """The cotangents of ``_kernel_rule``'s five inputs for the output's
+    cotangent ``do``, by ``kda_chunk_bwd``."""
+    b, t = q.shape[:2]
+    dq, dk, dv, dg, db = _kda_bwd(
+        *_kernel_layout(q, k, v, g, beta), states, do.reshape(b, t, -1),
+        scale=scale, interpret=interpret)
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
+            dg.reshape(g.shape), db[..., 0].transpose(0, 2, 1))
+
+
+def _normalized_and_gated(q, k, decay, beta, a_log, dt_bias):
+    """What the op does before the rule, all float32: q and k
+    L2-normalized a head, the log-decay and the write gate."""
+    return (_l2norm(q), _l2norm(k)) + kda_gates(decay, beta, a_log, dt_bias)
+
+
+def _scale(q) -> float:
+    return float(q.shape[-1]) ** -0.5
+
+
+def _plain_attention(q, k, v, decay, beta, a_log, dt_bias):
+    """The op's body on every platform: the plain chunks."""
+    q, k, g, beta = _normalized_and_gated(q, k, decay, beta, a_log, dt_bias)
+    return gated_delta_rule(q, k, v, g, beta, _scale(q))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _two_lowerings(q, k, v, decay, beta, a_log, dt_bias, interpret: bool):
+    """The op's body for inputs the kernels take: the kernels where the
+    program is lowered for a TPU, the plain chunks elsewhere
+    (``_kernel_on_tpu``), in the forward and in the backward pass.  The
+    backward pass keeps the op's own inputs and, from the kernels, the
+    chunks' entry states (134 MB a layer at 4096 tokens of 32 heads) and
+    computes everything else again: the normalized q and k and the gates
+    (0.27 GB a layer in float32, one elementwise pass), the chunk
+    products inside the backward kernel; the plain chunks are computed
+    again whole.  The choice lies inside the two rules, so that neither
+    lowering is differentiated through the choice."""
+    return _two_lowerings_fwd(q, k, v, decay, beta, a_log, dt_bias,
+                              interpret)[0]
+
+
+def _two_lowerings_fwd(q, k, v, decay, beta, a_log, dt_bias, interpret):
+    args = (q, k, v, decay, beta, a_log, dt_bias)
+
+    def kernels(*args):
+        qn, kn, g, b = _normalized_and_gated(*args[:2], *args[3:])
+        return _kernel_rule(qn, kn, args[2], g, b, _scale(qn), interpret)
+
+    def plain(*args):
+        b, t, h, d = args[0].shape
+        return _plain_attention(*args), jnp.zeros(
+            (b, h, t // KDA_CHUNK, d, d), jnp.float32)
+
+    o, states = _kernel_on_tpu(kernels, plain, interpret, *args)
+    return o, (args, states)
+
+
+def _two_lowerings_bwd(interpret, res, do):
+    # as jax.checkpoint ties what it computes again to the cotangent's
+    # arrival: without it XLA is free to form every layer's float32 q, k
+    # and g at the start of the backward pass and hold them
+    (args, states), do = lax.optimization_barrier((res, do))
+
+    def kernels(do, states, *args):
+        v = args[2]
+        (qn, kn, g, b), before = jax.vjp(_normalized_and_gated, *args[:2],
+                                         *args[3:])
+        dqn, dkn, dv, dg, db = _kernel_rule_vjp(qn, kn, v, g, b, states, do,
+                                                _scale(qn), interpret)
+        dq, dk, ddecay, dbeta, da_log, ddt_bias = before((dqn, dkn, dg, db))
+        return dq, dk, dv, ddecay, dbeta, da_log, ddt_bias
+
+    def plain(do, states, *args):
+        return jax.vjp(_plain_attention, *args)[1](do)
+
+    return _kernel_on_tpu(kernels, plain, interpret, do, states, *args)
+
+
+_two_lowerings.defvjp(_two_lowerings_fwd, _two_lowerings_bwd)
+
+
+def kimi_delta_attention(q, k, v, decay, beta, a_log, dt_bias,
+                         interpret: bool = False):
+    """Kimi Delta Attention of ``KimiDeltaAttentionOp``'s seven inputs:
+    normalize, gate, ``gated_delta_rule``.
+
+    One algorithm, two lowerings.  Inputs the kernels take
+    (``_kernel_takes``) run them where the program is LOWERED for a TPU
+    (anywhere under ``interpret``, the Pallas interpreter: the tests) and
+    the plain chunks on any other platform; every other input runs the
+    plain chunks everywhere.  Each trace records which, as the counter
+    ``kda:lowering``: ``kernel`` 1 means this op's TPU lowering is the
+    kernels (the lowered text of a CPU program holds the plain chunks all
+    the same), ``plain`` 1 the plain chunks on every platform; the track
+    names dtype and shape."""
+    kernel = _kernel_takes(q, v)
+    trace.counter("kda:lowering", cat="ops",
+                  track="%s%s" % (v.dtype.name, list(q.shape)),
+                  chunked=1, chunk=min(KDA_CHUNK, q.shape[1]),
+                  kernel=int(kernel), plain=int(not kernel))
+    args = (q, k, v, decay, beta, a_log, dt_bias)
+    if not kernel:
+        return _plain_attention(*args)
+    return _two_lowerings(*args, interpret)
+
+
+def _kernel_takes(q, v) -> bool:
+    """What the kernels' tiling accepts: sequences of whole chunks and
+    key and value heads of one 128-lane row (the state is one 128 x 128
+    tile); v in bfloat16 or float32."""
+    return (q.shape[1] % KDA_CHUNK == 0
+            and q.shape[3] == v.shape[3] == KDA_KERNEL_DIM
+            and v.dtype in (jnp.bfloat16, jnp.float32))
 
 
 def causal_conv1d(x, w):
@@ -208,8 +690,5 @@ class KimiDeltaAttentionOp(OpDef):
         return [q, q, v, q, (b, t, h), (h,), (h * dk,)], [v], []
 
     def forward(self, p, inputs, aux, ctx):
-        q, k, v, decay, beta, a_log, dt_bias = inputs
         with layer_scope("kda", p.layer):
-            g, beta = kda_gates(decay, beta, a_log, dt_bias)
-            return [gated_delta_rule(_l2norm(q), _l2norm(k), v, g, beta,
-                                     float(q.shape[-1]) ** -0.5)]
+            return [kimi_delta_attention(*inputs)]

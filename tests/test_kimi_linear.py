@@ -6,6 +6,7 @@ model (loss, every gradient, Adam's first step, the bias's first move)
 against ``benchmark/reference/kimi-linear-48b-a3b.py`` in float32, and
 what ``Module`` holds and hands back while the fused step is live."""
 import os
+import re
 import sys
 import time
 
@@ -109,13 +110,206 @@ def test_kda_op_gates_scope_and_counter():
         events = mx.trace.counter_events(["kda:lowering"], since_ns=mark)
     finally:
         mx.trace.set_enabled(was)
-    assert events and events[0]["args"] == {"chunked": 1, "chunk": 64}
+    # heads of 8 are not the kernels': the plain chunks on every platform
+    assert events and events[0]["args"] == {"chunked": 1, "chunk": 64,
+                                            "kernel": 0, "plain": 1}
     assert events[0]["id"] == "float32%s" % [b, t, h, d]
     op = mx.ops.get_op("KimiDeltaAttention")
     text = jax.jit(lambda *a: op.forward(
         op.parse_params({"layer": 3}), list(a), [], None)[0]).lower(
             *x.values()).as_text(debug_info=True)
     assert "kda.l3" in text
+
+
+
+# -- the two lowerings of the delta rule (ISSUE 33) ---------------------------
+
+def _kda_inputs(t, lo, hi, heads=2, seed=0, dk=128, dv=128):
+    rng = np.random.RandomState(seed)
+    q, k = (jnp.asarray(_unit(rng.randn(1, t, heads, dk)), jnp.float32)
+            for _ in range(2))
+    v = jnp.asarray(rng.randn(1, t, heads, dv), jnp.float32)
+    decay = jnp.asarray(rng.uniform(lo, hi, (1, t, heads, dk)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0.05, 0.99, (1, t, heads)), jnp.float32)
+    return q, k, v, decay, beta
+
+
+@pytest.mark.parametrize("t", [128, 320], ids=["t128", "t320"])
+@pytest.mark.parametrize("lo,hi", [(-0.01, -1e-4), (-3.0, -0.01),
+                                   (-40.0, -5.0), (-40.0, -1e-4)],
+                         ids=["decay-near-1", "mixed", "decay-near-0",
+                              "wide"])
+def test_kernels_match_the_plain_chunks(lo, hi, t):
+    """``kda_chunk_fwd`` / ``kda_chunk_bwd`` in Pallas interpret mode
+    against the plain chunks at HIGHEST precision: the output and the
+    cotangents of q, k, v, g and beta, heads of 128, two chunks and five
+    (the carried state and its cotangent cross four boundaries)."""
+    args = _kda_inputs(t, lo, hi)
+    w = jnp.asarray(np.random.RandomState(1).randn(*args[2].shape),
+                    jnp.float32)
+    scale = 128 ** -0.5
+    with jax.default_matmul_precision("highest"):
+        got, states = kda_ops._kernel_rule(*args, scale, interpret=True)
+        grads = kda_ops._kernel_rule_vjp(*args, states, w, scale,
+                                         interpret=True)
+        want, vjp = jax.vjp(
+            lambda *a: kda_ops.gated_delta_rule(*a, scale), *args)
+        plain_grads = vjp(w)
+    assert np.abs(np.asarray(got - want)).max() \
+        <= 1e-4 * np.abs(np.asarray(want)).max()
+    for x, y in zip(grads, plain_grads):
+        assert np.abs(np.asarray(x - y)).max() \
+            <= 5e-4 * np.abs(np.asarray(y)).max()
+
+
+def test_kernel_lowering_carries_the_gradient_through_the_gates():
+    """The op's body under its kernel lowering against the plain one:
+    the output and all seven gradients (q and k through the L2 norm,
+    ``decay``, ``a_log`` and ``dt_bias`` through ``kda_gates`` and the
+    kernels' ``dg``, beta through the sigmoid), bfloat16 values."""
+    rng = np.random.RandomState(2)
+    q, k, v, decay, beta = _kda_inputs(128, -2.0, 2.0, seed=2)
+    q, k = 3.0 * q, 0.5 * k
+    v = v.astype(jnp.bfloat16)
+    a_log = jnp.asarray(rng.uniform(-1, 1, (2,)), jnp.float32)
+    dt_bias = jnp.asarray(rng.uniform(-1, 1, (2 * 128,)), jnp.float32)
+    args = (q, k, v, decay, 4.0 * beta - 2.0, a_log, dt_bias)
+
+    def run(fn):
+        return jax.value_and_grad(
+            lambda *a: jnp.square(fn(*a).astype(jnp.float32)).sum(),
+            argnums=tuple(range(7)))(*args)
+
+    with jax.default_matmul_precision("highest"):
+        (got, grads), (want, plain_grads) = run(
+            lambda *a: kda_ops.kimi_delta_attention(*a, interpret=True)), \
+            run(kda_ops._plain_attention)
+    assert abs(float(got) - float(want)) <= 1e-3 * float(want)
+    for x, y in zip(grads, plain_grads):
+        x, y = (np.asarray(z, np.float32) for z in (x, y))
+        assert np.abs(x - y).max() <= 4e-3 * np.abs(y).max()
+
+
+@pytest.mark.parametrize("t,dk,dv,dtype,kernel", [
+    (128, 128, 128, "bfloat16", True), (128, 128, 128, "float32", True),
+    (96, 128, 128, "bfloat16", False), (128, 64, 128, "bfloat16", False),
+    (128, 128, 64, "bfloat16", False), (128, 128, 128, "float16", False),
+    (32, 128, 128, "bfloat16", False)],
+    ids=["takes-bf16", "takes-f32", "ragged-t", "dk-64", "dv-64", "f16",
+         "short"])
+def test_delta_rule_lowering_is_chosen_from_shape_and_dtype(t, dk, dv, dtype,
+                                                            kernel):
+    """What the kernels do not take runs the plain chunks on every
+    platform, and ``kda:lowering`` says so; what they take is the kernel
+    pair in a program lowered for a TPU and the plain chunks in one for
+    the CPU."""
+    q, k, v, decay, beta = _kda_inputs(t, -1.0, 1.0, heads=1, dk=dk, dv=dv)
+    args = (q, k, v.astype(dtype), decay, beta, jnp.zeros((1,)),
+            jnp.zeros((dk,)))
+    was = mx.trace.enabled()
+    mx.trace.set_enabled(True)
+    try:
+        mark = time.perf_counter_ns()
+        fn = jax.jit(kda_ops.kimi_delta_attention)
+        tpu = jax.export.export(fn, platforms=["tpu"])(*args).mlir_module()
+        cpu = fn.lower(*args).as_text()
+        events = mx.trace.counter_events(["kda:lowering"], since_ns=mark)
+    finally:
+        mx.trace.set_enabled(was)
+    assert events[0]["args"] == {"chunked": 1, "chunk": min(64, t),
+                                 "kernel": int(kernel),
+                                 "plain": int(not kernel)}
+    assert events[0]["id"] == "%s%s" % (dtype, [1, t, 1, dk])
+    assert ("tpu_custom_call" in tpu) == kernel
+    assert ("kda_chunk_fwd" in tpu) == kernel
+    assert "tpu_custom_call" not in cpu
+    if not kernel:
+        qn, kn, g, b = kda_ops._normalized_and_gated(q, k, decay, beta,
+                                                     *args[5:])
+        want = REF.delta_rule(qn, kn, args[2].astype(jnp.float32), g, b)
+        got = fn(*args).astype(jnp.float32)
+        assert np.abs(np.asarray(got - want)).max() \
+            <= 2e-2 * np.abs(np.asarray(want)).max()
+
+
+def test_the_tpu_program_traces_each_kernel_once():
+    """The same count on the path the chip takes (no interpreter: the
+    choice by ``lax.platform_dependent``, two ops under ``jax.grad``,
+    exported for a TPU): one ``fwd`` and one ``bwd`` trace, one function
+    each with two call sites.  With the ``custom_vjp`` inside the choice
+    the forward function was traced twice, once for the branch and once
+    for its forward rule."""
+    q, k, v, decay, beta = _kda_inputs(256, -1.0, 1.0, heads=1)
+    args = (q, k, v.astype(jnp.bfloat16), decay, beta, jnp.zeros((1,)),
+            jnp.zeros((128,)))
+
+    def loss(*a):
+        once = kda_ops.kimi_delta_attention(*a)
+        twice = kda_ops.kimi_delta_attention(a[0], a[1], once, *a[3:])
+        return jnp.square(twice.astype(jnp.float32)).sum()
+
+    was = mx.trace.enabled()
+    mx.trace.set_enabled(True)
+    try:
+        mark = time.perf_counter_ns()
+        text = jax.export.export(
+            jax.jit(jax.grad(loss, argnums=tuple(range(7)))),
+            platforms=["tpu"])(*args).mlir_module()
+        traces = mx.trace.counter_events(["kda:kernel_trace"], since_ns=mark)
+    finally:
+        mx.trace.set_enabled(was)
+    assert [e["args"] for e in traces] == [{"fwd": 1, "bwd": 0},
+                                           {"fwd": 0, "bwd": 1}]
+    for fn in ("_kda_fwd", "_kda_bwd"):
+        assert len(re.findall(r"func\.func private @%s\b" % fn, text)) == 1
+        assert len(re.findall(r"call @%s\b" % fn, text)) == 2, fn
+    assert "triangular_solve" not in text and "stablehlo.while" not in text
+
+
+def test_kernels_are_traced_once_a_process_and_lowered_once_a_program(
+        monkeypatch):
+    """Two ``Module``s of the same shapes (as the harness's reference
+    check and ``fit`` are), four KDA layers each, heads of 128, the
+    kernels through interpret mode: ``kda:kernel_trace`` counts one
+    ``fwd`` and one ``bwd`` for the process, and the step's lowered text
+    holds each kernel's function once with four call sites."""
+    monkeypatch.delenv("MXNET_COMPUTE_DTYPE", raising=False)
+    body = kda_ops.kimi_delta_attention
+    monkeypatch.setattr(kda_ops, "kimi_delta_attention",
+                        lambda *a: body(*a, interpret=True))
+    # shapes no other test of this process has traced the kernels at
+    over = dict(kda_heads=1, kda_head_dim=128, seq_len=192,
+                full_attn_layers=[4])
+    was = mx.trace.enabled()
+    mx.trace.set_enabled(True)
+    try:
+        mark = time.perf_counter_ns()
+        texts = []
+        for seed in (11, 12):
+            net, kwargs, params, tokens, labels = _tiny(seed, **over)
+            mod, batch = _bound(net, params, tokens, labels, "adam",
+                                dict(ADAM))
+            mod.forward_backward(batch)
+            mod.update()
+            assert np.isfinite(mod.get_outputs()[0].asnumpy()).all()
+            fused = mod._fused
+            texts.append(fused._step._jit.lower(
+                mod._fused_state, fused.make_batch(batch),
+                jnp.asarray(ADAM["learning_rate"], jnp.float32),
+                mod._fused_key).as_text())
+        traces = mx.trace.counter_events(["kda:kernel_trace"], since_ns=mark)
+        choices = mx.trace.counter_events(["kda:lowering"], since_ns=mark)
+    finally:
+        mx.trace.set_enabled(was)
+    assert [e["args"] for e in traces] == [{"fwd": 1, "bwd": 0},
+                                           {"fwd": 0, "bwd": 1}]
+    assert len(choices) >= 8 and all(
+        e["args"]["kernel"] == 1 for e in choices)
+    for text in texts:
+        for fn in ("_kda_fwd", "_kda_bwd"):
+            assert len(re.findall(r"func\.func private @%s\b" % fn,
+                                  text)) == 1, fn
+            assert len(re.findall(r"call @%s\b" % fn, text)) == 4, fn
 
 
 def test_causal_conv_and_silu():

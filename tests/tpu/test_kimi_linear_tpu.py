@@ -16,6 +16,14 @@ reference at one sequence of 1024 (float32 activations of 4096 tokens do
 not fit beside the state).  The numbers go to
 ``chiprun_out/kimi_parity.json`` after every phase, before anything is
 asserted.
+
+A second test (ISSUE 33) holds ``gated_delta_rule``'s two lowerings
+against each other at the published shape ``(1, 4096, 32, 128)``: the
+Pallas kernels at the default matmul precision may be no further from
+the plain chunks at HIGHEST than the plain chunks at the default
+precision are, in the output and in all five gradients, in four decay
+bands; the numbers and both lowerings' times go to
+``chiprun_out/kda_kernel_parity.json``.
 """
 import gc
 import json
@@ -182,3 +190,95 @@ def test_published_width_step_matches_reference():
     f32 = report["adam_f32_t1024"]
     assert f32["loss_rel_err"] <= 1e-4
     assert max(f32["update_rel_err"].values()) <= 0.1, f32
+
+
+BANDS = {"near-1": (-0.01, -1e-4), "mixed": (-3.0, -0.01),
+         "near-0": (-40.0, -5.0), "wide": (-40.0, -1e-4)}
+
+
+def test_kda_kernels_against_the_plain_chunks_at_the_published_shape():
+    import time
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import linear_attention as kda
+    b, t, h, d = 1, 4096, 32, 128
+    scale = d ** -0.5
+    names = ("o", "dq", "dk", "dv", "dg", "dbeta")
+
+    @jax.jit
+    def kernel(q, k, v, g, beta, w):
+        o, states = kda._kernel_rule(q, k, v, g, beta, scale)
+        return (o,) + kda._kernel_rule_vjp(q, k, v, g, beta, states,
+                                           w.astype(o.dtype), scale)
+
+    @jax.jit
+    def plain(q, k, v, g, beta, w):
+        o, vjp = jax.vjp(lambda *a: kda.gated_delta_rule(*a, scale),
+                         q, k, v, g, beta)
+        return (o,) + vjp(w.astype(o.dtype))
+
+    forward = {"kernel": jax.jit(
+        lambda *a: kda._kernel_rule(*a, scale)[0]),
+        "plain": jax.jit(lambda *a: kda.gated_delta_rule(*a, scale))}
+    report = {"device": jax.devices()[0].device_kind, "shape": [b, t, h, d],
+              "bands": {}}
+
+    def rels(got, want):
+        return {n: _rel(x, y) for n, x, y in zip(names, got, want)}
+
+    for band, (lo, hi) in BANDS.items():
+        rng = np.random.RandomState(33)
+        q, k = (rng.standard_normal((b, t, h, d)).astype(np.float32)
+                for _ in range(2))
+        q, k = (jnp.asarray(x / np.linalg.norm(x, axis=-1, keepdims=True))
+                for x in (q, k))
+        v = jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32)
+        g = jnp.asarray(rng.uniform(lo, hi, (b, t, h, d)), jnp.float32)
+        beta = jnp.asarray(rng.uniform(0.05, 0.99, (b, t, h)), jnp.float32)
+        w = jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32)
+        args = (q, k, v, g, beta, w)
+        with jax.default_matmul_precision("highest"):
+            want = plain(*args)
+            exact = rels(kernel(*args), want)
+        with jax.default_matmul_precision("default"):
+            got = kernel(*args)
+            report["bands"][band] = {
+                "kernel_vs_plain_highest": exact,
+                "kernel_default_vs_plain_highest": rels(got, want),
+                "plain_default_vs_plain_highest": rels(plain(*args), want),
+                "finite": bool(all(np.isfinite(np.asarray(x, np.float32))
+                                   .all() for x in got))}
+        del want, got
+
+    # times at the configuration's dtypes and precision, a layer
+    v16 = v.astype(jnp.bfloat16)
+    args = (q, k, v16, g, beta, w)
+
+    def ms(fn, *a):
+        jax.block_until_ready(fn(*a))
+        t0 = time.perf_counter()
+        for _ in range(5):
+            out = fn(*a)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / 5 * 1e3
+
+    with jax.default_matmul_precision("default"):
+        report["ms_a_layer"] = {
+            "kernel_forward": ms(forward["kernel"], *args[:5]),
+            "plain_forward": ms(forward["plain"], *args[:5]),
+            "kernel_forward_backward": ms(kernel, *args),
+            "plain_forward_backward": ms(plain, *args)}
+    out_dir = os.path.join(ROOT, "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, "kda_kernel_parity.json"), "w") as f:
+        json.dump(report, f, indent=1)
+    print("\nKDA_KERNEL_PARITY " + json.dumps(report), flush=True)
+
+    for band, r in report["bands"].items():
+        assert r["finite"], band
+        for n in names:
+            assert r["kernel_vs_plain_highest"][n] <= 2e-4, (band, n, r)
+            # no further off than the plain chunks at this precision are
+            assert r["kernel_default_vs_plain_highest"][n] <= \
+                1.05 * r["plain_default_vs_plain_highest"][n] + 1e-5, \
+                (band, n, r)
