@@ -20,6 +20,10 @@ CELL = "tiny-kimi"
 REAL_CELL = "kimi-linear-48b-a3b-train-4k"
 CONFIG = "kimi-linear-48b-a3b"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+# a step of the tiny model takes ~40 ms alone and ~1 s beside two dozen
+# busy processes on eight cores: the window holds some steps either way,
+# and no assertion below asks for more than one
+WINDOW_S = 4.0
 
 
 @pytest.fixture(scope="module")
@@ -68,14 +72,19 @@ def test_the_kimi_cell_runs_through_the_driver_and_is_correct(copy):
     lines = []
     rng = mx.random.get_key_data(), np.random.get_state()
     try:
-        result = driver.run(cell, [mx.cpu(0)], 3100000031, 2.0, False,
+        result = driver.run(cell, [mx.cpu(0)], 3100000031, WINDOW_S, False,
                             time.perf_counter(), FAKE_PEAKS, lines.append)
     finally:
         mx.random.set_key_data(rng[0])
         np.random.set_state(rng[1])
     assert result["correct"] is True, lines
-    assert result["failed"] == 0 and result["attempted"] > 6
     obs = result["_obs"]
+    # in terms of the steps completed, never of how many a loaded machine
+    # fits into the window: every batch drawn became a step, the warm-up's
+    # three before the window and the rest inside it
+    assert result["failed"] == 0 and obs["steps_in_window"] >= 1
+    assert result["attempted"] == \
+        cell.traffic["warmup_steps"] + obs["steps_in_window"]
     assert set(result["_e2e"]) == {"train_tok_per_s", "setup_s"}
     assert obs["compile"]["in_window"] == 0
     assert result["_e2e"]["train_tok_per_s"] * obs["window_s"] == \
@@ -100,39 +109,53 @@ def test_the_kimi_cell_runs_through_the_driver_and_is_correct(copy):
                  "peak_hbm_gib.tok", "compiles_in_window"):
         assert name in got, sorted(got)
     # every per-layer entry the cell is listed under has a reader the
-    # run can feed, but the three that read a trace
-    listed = {m["name"] for m in cell.per_layer}
-    assert listed - set(got) <= {
-        "device_step_ms.tok", "mfu.tok", "device_idle_share.tok",
-        "dispatch_ms_p50.tok", "fit_step_ms_p50.tok",
-        "metric_wait_ms_p50.tok", "loop_other_ms_p50.tok",
-        "feed_next_ms_p50.tok", "enqueue_ms_p50.tok"}
+    # run can feed, but those that read a device trace or the spans of
+    # a traced run (by their source: later entries join the cell's lists)
+    untraced = {m["name"] for m in cell.per_layer
+                if m["source"] not in ("device_trace", "program_span")}
+    assert untraced <= set(got), sorted(untraced - set(got))
 
 
-def test_the_cells_entries_are_appended_and_agree_with_the_reader():
-    doc = manifest.Manifest().doc
-    assert [w["name"] for w in doc["workloads"]].index(REAL_CELL) == 4
+def check_the_cells_own_entries(doc):
+    """``doc`` holds the cell, the entry it came with as its reader has
+    it, and the cell on the lists it shares with the OLMoE cell.  By
+    name and by membership, never by a position, a length or "the only
+    member": later cells and entries are appended to the same lists
+    (``test_cellbench_rehearsal.py`` runs this against such copies).
+    That nothing which was there moved is the driver's check and
+    ``test_what_was_there_did_not_move``'s."""
     cell = next(w for w in doc["workloads"] if w["name"] == REAL_CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         (CONFIG, "packed-4k-b1", 1)
-    assert sum(w["chips"] == 4 for w in doc["workloads"]) == 1
-    entry = doc["per_layer"][-1]
+    entries = [m for m in doc["per_layer"]
+               if m["name"] == "moe_held_rows_share"]
+    assert len(entries) == 1
+    entry = dict(entries[0])
     reader = manifest.load_module("layer_metrics", "moe_held_rows_share")
+    assert REAL_CELL in entry.pop("workloads")
     assert entry == {"name": "moe_held_rows_share", "unit": reader.UNIT,
                      "better": reader.BETTER, "source": reader.SOURCE,
-                     "layer": reader.LAYER, "moves": "train_tok_per_s",
-                     "workloads": [REAL_CELL]}
+                     "layer": reader.LAYER, "moves": "train_tok_per_s"}
     listed = {m["name"] for m in doc["per_layer"] + doc["end_to_end"]
               if REAL_CELL in m.get("workloads", [])}
     olmoe = {m["name"] for m in doc["per_layer"] + doc["end_to_end"]
              if "olmoe-1b-7b-train-4k" in m.get("workloads", [])}
-    # every list the OLMoE cell is on but its two kernels' rooflines,
-    # whose work functions are that cell's
-    assert olmoe - listed == {"attn_roofline", "moe_gmm_roofline"}
-    assert listed - olmoe == {"moe_held_rows_share"}
-    for m in doc["per_layer"] + doc["end_to_end"]:
-        if REAL_CELL in m.get("workloads", []):
-            assert m["workloads"][-1] == REAL_CELL
+    # every list the OLMoE cell is on but its kernels' rooflines, whose
+    # work functions are that cell's
+    missing = olmoe - listed
+    assert {"attn_roofline", "moe_gmm_roofline"} <= missing
+    assert all(name.endswith("_roofline") for name in missing), missing
+    # and of its own the one it came with; whatever joined since has a
+    # reader file
+    own = listed - olmoe
+    assert "moe_held_rows_share" in own
+    for name in own:
+        assert os.path.isfile(os.path.join(
+            util.BENCH, "layer_metrics", name.split(".", 1)[0] + ".py")), name
+
+
+def test_the_cells_entries_are_appended_and_agree_with_the_reader():
+    check_the_cells_own_entries(manifest.Manifest().doc)
 
 
 def test_the_held_share_reader_with_and_without_the_counter():

@@ -127,6 +127,19 @@ def test_per_layer_entries_agree_with_their_readers(doc):
             "PERF.md's list of layers lacks %r" % layer
 
 
+# what holds of any BENCHMARK.json, later additions at its lists' ends
+# included: test_cellbench_rehearsal.py finds these by name and runs
+# them against copies with such additions
+check_top_level_keys_and_limits = test_top_level_keys_and_limits
+check_entries_have_just_the_contracts_keys = \
+    test_entries_have_just_the_contracts_keys
+check_cells_and_configs_line_up = test_cells_and_configs_line_up
+check_a_per_layer_metric_is_listed_only_where_its_moved_metric_is = \
+    test_a_per_layer_metric_is_listed_only_where_its_moved_metric_is
+check_per_layer_entries_agree_with_their_readers = \
+    test_per_layer_entries_agree_with_their_readers
+
+
 def test_no_cell_name_in_harness_code(doc):
     """The harness is driven by data: no cell, configuration, traffic or
     metric name appears in its code."""

@@ -1,7 +1,8 @@
-"""``benchmark/kernel_rooflines.py`` and its two readers: the operations
-and bytes against sums written out by hand at OLMoE-1B-7B's published
-sizes, and the share on reductions made by hand.  A CPU run has no trace:
-there the readers return None, never a number."""
+"""``benchmark/kernel_rooflines.py`` and its four readers: the operations
+and bytes against sums written out by hand at OLMoE-1B-7B's and
+Kimi-Linear-48B-A3B's published sizes, and the share on reductions made
+by hand.  A CPU run has no trace: there the readers return None, never a
+number."""
 import pytest
 
 import cellbench_util as util  # noqa: F401
@@ -10,7 +11,9 @@ import manifest
 
 V5E = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 READERS = {"attn_roofline": ("Pallas kernels", "splash_mha"),
-           "moe_gmm_roofline": ("routed experts", "ragged-dot")}
+           "moe_gmm_roofline": ("routed experts", "ragged-dot"),
+           "mla_attn_roofline": ("Pallas kernels", "splash_mha"),
+           "kda_roofline": ("linear attention", "kda_chunk")}
 
 
 @pytest.fixture(scope="module")
@@ -135,3 +138,161 @@ def test_the_grouped_matmul_reader_on_a_reduction_made_by_hand(olmoe):
     value, _ = manifest.load_module(
         "layer_metrics", "moe_gmm_roofline").read(_obs(olmoe, ops))
     assert value == pytest.approx(125.58, rel=1e-4)
+
+
+# -- the Kimi cell's two kernels ---------------------------------------------
+
+@pytest.fixture(scope="module")
+def kimi():
+    cell = manifest.Manifest().cell("kimi-linear-48b-a3b-train-4k")
+    return cell.config, cell.traffic
+
+
+def test_latent_attention_at_kimis_published_sizes(kimi):
+    """B 1, H 32, T 4096, q and k 128 + 64 = 192 wide, v 128, bfloat16;
+    of the five layers built one (layer 4) mixes by latent attention."""
+    ops, nbytes = kr.latent_attention_work(*kimi)
+    pairs = 32 * 4096 * 4096            # 2 x the causal half, every head
+    assert pairs == 536_870_912
+    forward = pairs * (192 + 128)       # Q K^T at 192, P V at 128
+    backward = pairs * (3 * 192 + 2 * 128)
+    assert (forward, backward) == (171_798_691_840, 446_676_598_784)
+    assert ops == forward + backward == 618_475_290_624
+    # q, k, dq, dk of 4096 x 32 x 192 and v, o, dv, do of 4096 x 32 x 128,
+    # 2 B an element; the 256 lanes the kernel pads q and k to count nowhere
+    assert nbytes == 2 * 4096 * 32 * (4 * 192 + 4 * 128) == 335_544_320
+    least, bound = kr.roofline_time((ops, nbytes), V5E)
+    assert bound == "compute"
+    assert least == pytest.approx(3.13947e-3, rel=1e-5)
+    assert nbytes / V5E["hbm_bytes_per_s"] == pytest.approx(0.4097e-3,
+                                                            rel=1e-3)
+
+
+def test_the_kda_chunks_at_kimis_published_sizes(kimi):
+    """B 1, H 32 heads of 128, T 4096 in chunks of 64, bfloat16; of the
+    five layers built four (1, 2, 3, 5) are KDA."""
+    ops, nbytes = kr.kda_chunk_work(*kimi)
+    full = 2 * 64 * 128 * 128           # a chunk against a (128, 128) state
+    half = 64 * 64 * 128                # the triangle of a (64, 64) product
+    assert (full, half) == (2_097_152, 524_288)
+    # scores of k and of q, read by k, system, read by q, intra-chunk
+    # output, write
+    forward = half + half + full + half + full + half + full
+    assert forward == 3 * full + 4 * half == 8_388_608
+    # the chunk again (two scores, the read, the system), then the
+    # transposes: reads 2, write 2, state 2, intra-chunk 2 half, system
+    # 2 half, scores 4 half
+    backward = (2 * half + full + half) + 6 * full + 8 * half
+    assert backward == 7 * full + 11 * half == 20_447_232
+    chunk_heads = 32 * (4096 // 64)
+    assert chunk_heads == 2048
+    assert ops == 4 * chunk_heads * (forward + backward) == 236_223_201_280
+    seq = 4096 * 32 * 128               # elements of q, k, v, o or the decay
+    beta = 4 * 4096 * 32
+    states = 4 * chunk_heads * 128 * 128
+    assert (seq, beta, states) == (16_777_216, 524_288, 134_217_728)
+    # forward: q, k, v, o at 2 B, the decay at 4 B, beta, the states out
+    fwd = 4 * 2 * seq + 4 * seq + beta + states
+    # backward: q, k, v, do at 2 B, the decay, beta, the states in; dq,
+    # dk, dv at 2 B, the decay's and beta's gradients at 4 B out
+    bwd = (4 * 2 * seq + 4 * seq + beta + states) \
+        + (3 * 2 * seq + 4 * seq + beta)
+    assert (fwd, bwd) == (336_068_608, 504_365_056)
+    assert nbytes == 4 * (fwd + bwd) == 3_361_734_656
+    least, bound = kr.roofline_time((ops, nbytes), V5E)
+    assert bound == "memory"
+    assert least == pytest.approx(4.10468e-3, rel=1e-5)
+    assert ops / V5E["bf16_flops_per_s"] == pytest.approx(1.1991e-3,
+                                                          rel=1e-4)
+
+
+@pytest.mark.parametrize("heads, head_dim, seq_len, batch",
+                         [(16, 128, 4096, 4), (20, 256, 8192, 1)])
+def test_latent_attention_with_equal_heads_is_causal_attention(
+        olmoe, heads, head_dim, seq_len, batch):
+    """q, k and v of one size and as many key/value heads as query heads:
+    the two functions count the same work."""
+    config, traffic = olmoe
+    traffic = dict(traffic, batch_per_chip=batch)
+    config = dict(config, num_attention_heads=heads,
+                  num_key_value_heads=heads, head_dim=head_dim,
+                  hidden_size=heads * head_dim, input={"seq_len": seq_len},
+                  qk_nope_head_dim=head_dim - 64, qk_rope_head_dim=64,
+                  v_head_dim=head_dim)
+    assert kr.latent_attention_work(config, traffic) == \
+        kr.causal_attention_work(config, traffic)
+
+
+@pytest.mark.parametrize("change, mla_layers, kda_layers", [
+    ({}, 1, 4),
+    ({"num_hidden_layers": 27}, 7, 20),         # as published
+    ({"num_hidden_layers": 3}, 0, 3),
+    ({"num_hidden_layers": 5, "num_nextn_predict_layers": 1}, 2, 4),
+], ids=["as-run", "published-depth", "no-mla-built", "one-more-predicted"])
+def test_the_layers_counted_are_the_mixers_built(kimi, change, mla_layers,
+                                                 kda_layers):
+    config, traffic = kimi
+    one = dict(config, num_hidden_layers=4, name="anything",
+               linear_attn_config=dict(config["linear_attn_config"],
+                                       full_attn_layers=[4], kda_layers=[1]))
+    changed = dict(config, **change)
+    for work, layers in ((kr.latent_attention_work, mla_layers),
+                         (kr.kda_chunk_work, kda_layers)):
+        a, b = work(one, traffic), work(changed, traffic)
+        assert b == (layers * a[0], layers * a[1])
+
+
+def test_the_new_work_follows_the_sizes_not_a_name(kimi):
+    config, traffic = kimi
+    # a configuration without the list of mixers: every layer is latent
+    # attention, and the one predicted token's module has one more
+    plain = {k: v for k, v in config.items() if k != "linear_attn_config"}
+    a = kr.latent_attention_work(config, traffic)
+    b = kr.latent_attention_work(dict(plain, num_nextn_predict_layers=1,
+                                      name="anything"), traffic)
+    assert b == (6 * a[0], 6 * a[1])
+    for work in (kr.latent_attention_work, kr.kda_chunk_work):
+        a = work(config, traffic)
+        wide = work(config, dict(traffic, batch_per_chip=2))
+        assert wide == (2 * a[0], 2 * a[1])
+    # float32 compute: every tensor of latent attention doubles; of the
+    # KDA kernels' q, k, v, o and their gradients only (11 of them a
+    # layer), the decay, beta and the states are float32 either way
+    f32 = dict(config, compute_dtype="float32")
+    assert kr.latent_attention_work(f32, traffic) == \
+        (618_475_290_624, 2 * 335_544_320)
+    assert kr.kda_chunk_work(f32, traffic) == \
+        (236_223_201_280, 3_361_734_656 + 4 * 11 * 2 * 16_777_216)
+    # a sequence of half a chunk is one chunk of 32 tokens a head
+    short = kr.kda_chunk_work(dict(config, input={"seq_len": 32}), traffic)
+    assert short[0] == 4 * 32 * (10 * 2 * 32 * 128 * 128
+                                 + 15 * 32 * 32 * 128)
+
+
+KIMI_TRACE = {
+    # PERF.md section 5 (the builder's chip run, PR 33): 17 traced steps
+    "mla_attn_roofline": (
+        {"splash_mha_fwd_residuals custom-call bf16[1,32,4096,256]": 1.78e-3,
+         "splash_mha_dkv_no_residuals.1 custom-call bf16[4,32,4096,256]":
+         3.95e-3}, 5.73, 3.13947, "compute"),
+    "kda_roofline": (
+        dict([("kda_chunk_fwd.%d custom-call f32[1,32,64,128,128]" % i,
+               2.48e-3) for i in range(4, 8)]
+             + [("kda_chunk_bwd.%d custom-call f32[1,4096,4096]" % i,
+                 4.48e-3) for i in range(4, 8)]), 27.84, 4.10468, "memory"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KIMI_TRACE))
+def test_a_kimi_reader_on_a_reduction_made_by_hand(name, kimi):
+    a_step, kernel_ms, roofline_ms, bound = KIMI_TRACE[name]
+    ops = {op: 17 * s for op, s in a_step.items()}
+    ops["fusion.370 fusion bf16[4096,2304]"] = 17 * 2.61e-3
+    ops["ragged-dot-none.3 custom-call bf16[32768,2304]"] = 17 * 0.5e-3
+    value, extra = manifest.load_module("layer_metrics", name).read(
+        _obs(kimi, ops, steps=17))
+    assert extra["kernel_ms"] == pytest.approx(kernel_ms)
+    assert extra["roofline_ms"] == pytest.approx(roofline_ms, rel=1e-5)
+    assert value == pytest.approx(100 * roofline_ms / kernel_ms, rel=1e-5)
+    assert (extra["bound"], extra["steps"]) == (bound, 17)
+    assert 0.0 < value < 100.0
