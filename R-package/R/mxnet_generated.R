@@ -369,6 +369,8 @@ mx.symbol.SoftmaxActivation <- function(...) {
 }
 
 #' Per-token softmax cross-entropy that emits the LOSS: logits
+#' @param use_ignore use_ignore parameter (default False)
+#' @param ignore_label ignore_label parameter (default -1)
 #' @param name string, node name (auto when omitted)
 #' @return MXSymbol
 mx.symbol.SoftmaxCELoss <- function(...) {

@@ -34,6 +34,7 @@ from .base import MXNetError, get_env
 from .context import Context, cpu, current_context
 from .ndarray import NDArray, zeros as nd_zeros, array as nd_array
 from .ops.registry import OpContext
+from .ops.transformer import node_scope
 from . import random as _random
 from .symbol import Symbol, _topo, _Node
 
@@ -112,11 +113,13 @@ class _GraphProgram:
 
             mirror = (self.do_mirror
                       or node.attrs.get("force_mirroring", "").lower() == "true")
-            if mirror and not aux_names:
-                outs = jax.checkpoint(
-                    lambda *i: node.op.forward(node.params, list(i), [], opctx))(*ins)
-            else:
-                outs = run()
+            with node_scope(node.attrs.get("__scope__")):
+                if mirror and not aux_names:
+                    outs = jax.checkpoint(
+                        lambda *i: node.op.forward(node.params, list(i), [],
+                                                   opctx))(*ins)
+                else:
+                    outs = run()
             if isinstance(outs, tuple):
                 outs, aux_out = outs
                 for a, v in zip(aux_names, aux_out):

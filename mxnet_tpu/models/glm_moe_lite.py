@@ -1,0 +1,134 @@
+"""GLM-4.7-Flash (``model_type`` ``glm4_moe_lite``): a DeepSeek-V3-shaped
+pre-norm decoder (arXiv:2412.19437) with a multi-token-prediction module.
+
+Token embedding -> L x [x + MLA(RMSNorm(x)), x + MLP_l(RMSNorm(x))] ->
+RMSNorm -> untied vocabulary head.  Layers are counted from 0, as the
+published config counts them.
+
+The mixer of every layer is latent attention (``latent_attention``, the
+assembly this package's decoders share) with the queries compressed
+through a normed low-rank pair (``q_lora_rank``) and the rope part of
+every query head and the one shared key part rotated at ``rope_theta``.
+``MLP_l`` is a SwiGLU of ``dense_width`` for the first ``dense_layers``
+layers and after them the routed expert layer: ``sigmoid`` router over
+``num_experts``, top ``experts_per_tok`` by score plus a selection bias
+(an aux state, no gradient; no group limit), weights renormalized over
+the chosen and multiplied by ``routed_scale``, and one shared expert.
+``experts_held`` > 0 builds one expert-parallel rank's share
+(``MoEFeedForward``): experts ``first_expert ..`` only, the router still
+``num_experts`` wide.
+
+``nextn_layers`` prediction modules follow (DeepSeek-V3 section 2.2;
+this builder takes 0 or 1).  With ``x`` the trunk's last residual state
+(before its final norm) and ``t`` the tokens, position ``i`` of the
+module reads ``[RMSNorm(Emb(t_{i+1})) ; RMSNorm(x_i)] W_eh`` (``2 D ->
+D``), runs one more block of the kind above with its own weights, and
+predicts ``t_{i+2}`` through an RMSNorm of its own and THE TRUNK'S head;
+``Emb`` is THE TRUNK'S embedding: ``embed_weight`` and
+``lm_head_weight`` are each used twice in the graph, and their gradients
+are the sums of both uses.  ``t_{i+1}`` is ``softmax_label`` and the
+target is ``softmax_label`` moved one place; the last position of each
+sequence has no target (its label is ``SoftmaxCELoss``'s
+``ignore_label``) and is outside the second loss and its normalization.
+
+The symbol trains through ``Module.fit`` as it stands: inputs ``data``
+and ``softmax_label``, both ``(batch, seq_len)`` token ids.  Outputs, by
+name: ``lm_output`` the per-token loss head (first, where the metric
+reads it), ``mtp_output`` the module's per-token loss head at
+``mtp_weight`` (absent with no module), ``moe_load_output`` the expert
+blocks' load head.  Both loss heads normalize their own gradients, so
+``rescale_grad`` is 1; there is no load-balance loss.
+
+Device scopes (``__scope__`` attributes, ``ops.transformer.node_scope``):
+``mla_q.l<i>``, ``mla_kv.l<i>``, ``rope.l<i>`` beside the ops' own
+``attn.l<i>`` and ``moe_*.l<i>``; the module's under ``mtp.``
+(``mtp.eh_proj``, ``mtp.mla_q``, ``mtp.attn``, ``mtp.moe_experts``,
+``mtp.lm_loss``, ...).
+"""
+from .. import symbol as sym
+from ..moe.layer import MoEFeedForward, with_load_heads
+from .latent_attention import latent_attention, scoped
+
+
+def glm_moe_lite_lm(num_layers, hidden_size, dense_layers, heads,
+                    q_lora_rank, kv_lora_rank, qk_nope_dim, qk_rope_dim,
+                    v_head_dim, rope_theta, dense_width, num_experts,
+                    experts_per_tok, expert_width, shared_width,
+                    routed_scale, vocab_size, seq_len, nextn_layers=1,
+                    mtp_weight=0.3, experts_held=0, first_expert=0,
+                    bias_rate=1e-3, rms_eps=1e-5):
+    """The training symbol; see the module docstring."""
+    if nextn_layers not in (0, 1):
+        raise ValueError("glm_moe_lite_lm builds 0 or 1 prediction "
+                         "modules, not %r" % (nextn_layers,))
+
+    def norm(x, name):
+        return sym.RMSNorm(x, eps=rms_eps, name=name)
+
+    def proj(x, name, width):
+        return sym.FullyConnected(x, num_hidden=width, no_bias=True,
+                                  name=name)
+
+    def block(x, pre, layer, dense, scope):
+        """One decoder block; ``layer`` is the ops' trace index (-1:
+        none), ``scope`` the prefix of its device scopes."""
+        x = x + latent_attention(
+            norm(x, pre + "mixer_norm"), pre, seq_len, hidden_size, heads,
+            kv_lora_rank, qk_nope_dim, qk_rope_dim, v_head_dim, rms_eps,
+            layer=layer, q_lora_rank=q_lora_rank, rope_theta=rope_theta,
+            scope=scope)
+        h = norm(x, pre + "ffn_norm")
+        if dense:
+            gate = sym.Activation(proj(h, pre + "gate_proj", dense_width),
+                                  act_type="silu")
+            return x + proj(gate * proj(h, pre + "up_proj", dense_width),
+                            pre + "down_proj", hidden_size)
+        with scoped(scope):
+            return x + MoEFeedForward(
+                h, num_hidden=expert_width, num_experts=num_experts,
+                k=experts_per_tok, capacity_factor=0.0, name=pre + "moe",
+                act_type="silu", gated=True, no_bias=True,
+                layer=None if layer < 0 else layer, renormalize=True,
+                score="sigmoid", scale=routed_scale, bias_rate=bias_rate,
+                shared_hidden=shared_width, output_dim=hidden_size,
+                experts_held=experts_held, first_expert=first_expert)
+
+    def head(x, label, pre, name, **loss):
+        """(B*T, D) residual state -> the per-token loss head ``name``
+        through the shared ``lm_head_weight``."""
+        logits = sym.FullyConnected(norm(x, pre + "final_norm"),
+                                    weight=lm_head, num_hidden=vocab_size,
+                                    no_bias=True, name=pre + "lm_head")
+        return sym.SoftmaxCELoss(logits, sym.Reshape(label, shape=(-1,)),
+                                 name=name, **loss)
+
+    def embed(tokens, name):
+        x = sym.Embedding(tokens, weight=embed_weight, input_dim=vocab_size,
+                          output_dim=hidden_size, name=name)
+        return sym.Reshape(x, shape=(-1, hidden_size))     # (B*T, D)
+
+    embed_weight = sym.Variable("embed_weight")
+    lm_head = sym.Variable("lm_head_weight")
+    label = sym.Variable("softmax_label")
+    x = embed(sym.Variable("data"), "embed")
+    for l in range(num_layers):
+        x = block(x, "l%d_" % l, l, l < dense_layers, "")
+    heads_out = [sym.MakeLoss(head(x, label, "", "lm_loss"),
+                              normalization="batch", name="lm")]
+    if nextn_layers:
+        with scoped("mtp.", "eh_proj"):
+            u = proj(sym.Concat(norm(embed(label, "mtp_embed"), "mtp_enorm"),
+                                norm(x, "mtp_hnorm"), dim=1),
+                     "mtp_eh_proj", hidden_size)
+        u = block(u, "mtp_", -1, False, "mtp.")
+        # the target of position i is the token after its label: the
+        # labels moved one place, the sequence's last position left out
+        last = sym.slice_axis(label, axis=1, begin=0, end=1) * 0 - 1
+        target = sym.Concat(sym.slice_axis(label, axis=1, begin=1,
+                                           end=seq_len), last, dim=1)
+        with scoped("mtp."):
+            loss = head(u, target, "mtp_", "mtp_loss", use_ignore=True,
+                        ignore_label=-1)
+        heads_out.append(sym.MakeLoss(loss, grad_scale=float(mtp_weight),
+                                      normalization="valid", name="mtp"))
+    return with_load_heads(sym.Group(heads_out))

@@ -18,7 +18,9 @@ model is on its up-projection), then ``o_proj``.  MLA: queries of
 ``qk_nope_dim + qk_rope_dim`` a head; keys and values decompressed from
 one ``kv_lora_rank`` latent (RMSNorm'ed) plus one ``qk_rope_dim`` key
 part shared by all heads; values of ``v_head_dim``; no rotary embedding
-on either part (``mla_use_nope``).
+on either part (``mla_use_nope``): ``latent_attention``, the assembly
+this package's decoders share, without its query compression and its
+rotation.
 
 ``MLP_l`` is a SwiGLU of ``dense_width`` for the first ``dense_layers``
 layers and after them the routed expert layer: ``sigmoid`` router over
@@ -36,6 +38,7 @@ load-balance loss (the selection bias balances).
 """
 from .. import symbol as sym
 from ..moe.layer import MoEFeedForward, with_load_heads
+from .latent_attention import latent_attention
 
 
 def kimi_linear_lm(num_layers, hidden_size, full_attn_layers, dense_layers,
@@ -90,28 +93,9 @@ def kimi_linear_lm(num_layers, hidden_size, full_attn_layers, dense_layers,
                     pre + "o_proj", hidden_size)
 
     def mla(h, pre, l):
-        qk_dim = qk_nope_dim + qk_rope_dim
-        q = sym.Reshape(proj(h, pre + "q_proj", mla_heads * qk_dim),
-                        shape=(-1, seq_len, mla_heads, qk_dim))
-        kv_a = proj(h, pre + "kv_a_proj", kv_lora_rank + qk_rope_dim)
-        latent = norm(sym.slice_axis(kv_a, axis=1, begin=0,
-                                     end=kv_lora_rank), pre + "kv_a_norm")
-        k_shared = sym.Reshape(
-            sym.slice_axis(kv_a, axis=1, begin=kv_lora_rank,
-                           end=kv_lora_rank + qk_rope_dim),
-            shape=(-1, seq_len, 1, qk_rope_dim))
-        kv = sym.Reshape(
-            proj(latent, pre + "kv_b_proj",
-                 mla_heads * (qk_nope_dim + v_head_dim)),
-            shape=(-1, seq_len, mla_heads, qk_nope_dim + v_head_dim))
-        k = sym.Concat(
-            sym.slice_axis(kv, axis=3, begin=0, end=qk_nope_dim),
-            sym.broadcast_axis(k_shared, axis=2, size=mla_heads), dim=3)
-        v = sym.slice_axis(kv, axis=3, begin=qk_nope_dim,
-                           end=qk_nope_dim + v_head_dim)
-        a = sym.CausalSelfAttention(q, k, v, layer=l, name=pre + "attn")
-        return proj(sym.Reshape(a, shape=(-1, mla_heads * v_head_dim)),
-                    pre + "o_proj", hidden_size)
+        return latent_attention(
+            h, pre, seq_len, hidden_size, mla_heads, kv_lora_rank,
+            qk_nope_dim, qk_rope_dim, v_head_dim, rms_eps, layer=l)
 
     x = sym.Embedding(sym.Variable("data"), input_dim=vocab_size,
                       output_dim=hidden_size, name="embed")

@@ -1,0 +1,97 @@
+"""Multi-head latent attention (MLA, DeepSeek-V2 arXiv:2405.04434) as
+symbols: ONE assembly for every decoder of this package that mixes by it
+(``kimi_linear``: plain queries, no positions; ``glm_moe_lite``:
+compressed queries, rotary on the rope parts).
+
+Keys and values are decompressed from one ``kv_lora_rank`` latent
+(RMSNorm'ed) a token plus one ``qk_rope_dim`` key part shared by all
+heads; queries are ``qk_nope_dim + qk_rope_dim`` a head, values
+``v_head_dim``.  ``q_lora_rank`` > 0 compresses the queries through a
+normed low-rank pair (``q_a_proj`` -> ``q_a_norm`` -> ``q_b_proj``), 0
+projects them in one step (``q_proj``).  ``rope_theta`` > 0 rotates the
+rope part of every query head and the shared key part at positions
+``0..T-1`` (``RotaryEmbedding``: half-split pairing, all of the part's
+lanes), 0 leaves both plain projections.  The softmax scale is
+``(qk_nope_dim + qk_rope_dim) ** -0.5`` (``CausalSelfAttention``'s own).
+"""
+import contextlib
+
+from .. import symbol as sym
+from ..attribute import AttrScope
+
+
+def scoped(prefix, kind=None, layer=-1):
+    """The ``__scope__`` attribute scope (``ops.transformer.node_scope``)
+    of one block part, for the device trace.  A part made of plain ops is
+    named ``prefix + kind`` (``.l<layer>`` behind it where the block has
+    an index): ``mla_q.l3``, ``mtp.eh_proj``.  With no ``kind`` the
+    part's ops name their own scope (attention, the expert layer, the
+    loss) and take ``prefix`` alone, before it: ``mtp.`` gives
+    ``mtp.attn``.  ``prefix`` None (or nothing to say): no attribute."""
+    if prefix is None or (kind is None and not prefix):
+        return contextlib.nullcontext()
+    if kind is None:
+        return AttrScope(__scope__=prefix)
+    return AttrScope(__scope__=prefix + kind
+                     + ("" if layer < 0 else ".l%d" % layer))
+
+
+def latent_attention(h, pre, seq_len, hidden_size, heads, kv_lora_rank,
+                     qk_nope_dim, qk_rope_dim, v_head_dim, rms_eps,
+                     layer=-1, q_lora_rank=0, rope_theta=0.0, scope=None):
+    """``h`` ``(B*T, hidden)`` -> ``(B*T, hidden)``; parameters are named
+    ``pre`` + ``q_proj`` (or ``q_a_proj``, ``q_a_norm``, ``q_b_proj``),
+    ``kv_a_proj``, ``kv_a_norm``, ``kv_b_proj``, ``o_proj``.  ``layer``
+    names the attention op's trace scope.  ``scope`` is the prefix of
+    the device scopes of the parts made of plain ops (``scoped``:
+    ``mla_q``, ``mla_kv``, ``rope``; ``""`` for a trunk's block,
+    ``"mtp."`` inside a prediction module); None sets no attribute and
+    leaves the symbol as it was before scopes."""
+    def within(kind=None):
+        return scoped(scope, kind, layer)
+
+    def norm(x, name):
+        return sym.RMSNorm(x, eps=rms_eps, name=name)
+
+    def proj(x, name, width):
+        return sym.FullyConnected(x, num_hidden=width, no_bias=True,
+                                  name=name)
+
+    def rotate(x):
+        with within("rope"):
+            return sym.RotaryEmbedding(x, theta=rope_theta)
+
+    qk_dim = qk_nope_dim + qk_rope_dim
+    with within("mla_q"):
+        q = proj(norm(proj(h, pre + "q_a_proj", q_lora_rank),
+                      pre + "q_a_norm"), pre + "q_b_proj", heads * qk_dim) \
+            if q_lora_rank else proj(h, pre + "q_proj", heads * qk_dim)
+        q = sym.Reshape(q, shape=(-1, seq_len, heads, qk_dim))
+    if rope_theta:
+        q = sym.Concat(
+            sym.slice_axis(q, axis=3, begin=0, end=qk_nope_dim),
+            rotate(sym.slice_axis(q, axis=3, begin=qk_nope_dim, end=qk_dim)),
+            dim=3)
+    with within("mla_kv"):
+        kv_a = proj(h, pre + "kv_a_proj", kv_lora_rank + qk_rope_dim)
+        latent = norm(sym.slice_axis(kv_a, axis=1, begin=0,
+                                     end=kv_lora_rank), pre + "kv_a_norm")
+        k_shared = sym.Reshape(
+            sym.slice_axis(kv_a, axis=1, begin=kv_lora_rank,
+                           end=kv_lora_rank + qk_rope_dim),
+            shape=(-1, seq_len, 1, qk_rope_dim))
+        kv = sym.Reshape(
+            proj(latent, pre + "kv_b_proj",
+                 heads * (qk_nope_dim + v_head_dim)),
+            shape=(-1, seq_len, heads, qk_nope_dim + v_head_dim))
+        if rope_theta:
+            k_shared = rotate(k_shared)     # its own scope, inside this
+        k = sym.Concat(
+            sym.slice_axis(kv, axis=3, begin=0, end=qk_nope_dim),
+            sym.broadcast_axis(k_shared, axis=2, size=heads), dim=3)
+        v = sym.slice_axis(kv, axis=3, begin=qk_nope_dim,
+                           end=qk_nope_dim + v_head_dim)
+    with within():
+        a = sym.CausalSelfAttention(q, k, v, layer=layer, name=pre + "attn")
+    return proj(sym.Reshape(a, shape=(-1, heads * v_head_dim)),
+                pre + "o_proj", hidden_size)

@@ -55,6 +55,30 @@ def _hparams_undeclared(cls):
     return fh is None or not issubclass(fh, fu)
 
 
+def find_prediction_heads(symbol):
+    """``(main, extra, weight, valid_thresh)`` where ``symbol`` has two
+    per-token loss heads, ``MakeLoss`` over ``SoftmaxCELoss``: outputs
+    ``main`` (the first such head) and ``extra`` (the second: a
+    multi-token-prediction module's), found by what they are and not by
+    where they stand; ``weight`` is the second's ``grad_scale``, and
+    ``valid_thresh`` its threshold where it normalizes over the rows
+    above one (``normalization="valid"``: the positions that have a
+    target), else None.  None for a symbol with fewer than two."""
+    found = []
+    for i, (node, _) in enumerate(symbol._heads):
+        if node.is_variable or getattr(node.op, "name", "") != "MakeLoss":
+            continue
+        inner = node.inputs[0][0]
+        if not inner.is_variable \
+                and getattr(inner.op, "name", "") == "SoftmaxCELoss":
+            found.append((i, node.params))
+    if len(found) < 2:
+        return None
+    (main, _), (extra, p) = found[:2]
+    return (main, extra, float(p.grad_scale),
+            float(p.valid_thresh) if p.normalization == "valid" else None)
+
+
 class FusedTrainStep:
     """One donated XLA program per (shapes, dtypes): fwd+bwd+reduce+update.
 
@@ -210,6 +234,9 @@ class FusedTrainStep:
         from ..moe.detect import find_load_heads, find_moe_blocks
         self.moe_blocks = find_moe_blocks(symbol)
         self.moe_load_heads = find_load_heads(symbol)
+        # a second per-token loss head (a multi-token-prediction module):
+        # its mean reaches the trace from the step's outputs too
+        self.prediction_heads = find_prediction_heads(symbol)
         self.moe_stats = None
         if self.moe_blocks:
             from ..moe.stats import MoeStats
@@ -622,6 +649,23 @@ class FusedTrainStep:
                            held=float(counts[self.moe_blocks[block].held]
                                       .sum()),
                            dropped=dropped)
+
+    def note_prediction_loss(self, outs) -> None:
+        """Feed the ``mtp:loss`` trace counter, one sample a step, from
+        the step's outputs as the metric gets them: ``main`` the mean of
+        the first per-token loss head, ``mtp`` the second head's mean
+        over the positions that have a target (as its ``MakeLoss``
+        normalizes), ``weight`` its ``grad_scale``.  Two host reads of
+        ``(rows,)`` outputs the metric update before this call already
+        waited for."""
+        main, extra, weight, thresh = self.prediction_heads
+        second = outs[extra].asnumpy()
+        if thresh is not None:
+            second = second[second > thresh]
+        _trace.counter("mtp:loss", cat="train",
+                       main=float(outs[main].asnumpy().mean()),
+                       mtp=float(second.mean()) if second.size else 0.0,
+                       weight=weight)
 
     # -- compiled programs ---------------------------------------------------
     def _make_step_fn(self):

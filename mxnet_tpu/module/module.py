@@ -1180,15 +1180,21 @@ class Module(BaseModule):
     def _note_train_outputs(self, outputs=None):
         """Routed-expert load of the step just scored, from its load
         head (``FusedTrainStep.note_outputs``), under a span of its own,
-        ``fit:moe_load``: nothing, and no span, where the fused step is
+        ``fit:moe_load``, and the mean of a second per-token loss head
+        beside the first's (``note_prediction_loss``) under
+        ``fit:mtp_loss``: nothing, and no span, where the fused step is
         off or the symbol carries no such head."""
         fused = self._fused
-        if fused is None or not fused.moe_load_heads \
-                or not self._fused_live():
+        if fused is None or not self._fused_live() \
+                or not (fused.moe_load_heads or fused.prediction_heads):
             return
-        with _trace.span("fit:moe_load", cat="train"):
-            fused.note_outputs(self.get_outputs() if outputs is None
-                               else outputs)
+        outs = self.get_outputs() if outputs is None else outputs
+        if fused.moe_load_heads:
+            with _trace.span("fit:moe_load", cat="train"):
+                fused.note_outputs(outs)
+        if fused.prediction_heads and _trace.enabled():
+            with _trace.span("fit:mtp_loss", cat="train"):
+                fused.note_prediction_loss(outs)
 
     def _outputs_in_flight(self):
         """The overlap hook of fit() and score(): the outputs of the

@@ -21,11 +21,16 @@ the choice.
 
 The bodies of ``CausalSelfAttention`` and ``SoftmaxCELoss`` run under a
 ``jax.named_scope`` (``attn.l<layer>``, ``lm_loss``) so a device trace
-can tell the block's parts apart.
+can tell the block's parts apart.  A builder names the parts that are
+made of plain ops through the symbol attribute ``__scope__``
+(``node_scope``): ``mla_q.l3`` around a projection, ``mtp.`` before the
+scopes the ops of a prediction module name themselves.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
 
 import numpy as np
 import jax
@@ -49,11 +54,38 @@ ATTN_KERNEL_BLOCK = 1024
 ATTN_KERNEL_SLICE = 512
 
 
+_scope = threading.local()         # .prefix: what node_scope("x.") set
+
+
 def layer_scope(kind: str, layer):
     """``jax.named_scope`` of one block part: ``attn.l3``, ``moe_experts.l0``
-    (no suffix where the builder gave no layer index)."""
-    return jax.named_scope(kind if layer is None or layer < 0
-                           else "%s.l%d" % (kind, layer))
+    (no suffix where the builder gave no layer index), behind the prefix
+    of the ``node_scope`` it runs in, if any: ``mtp.attn``."""
+    name = kind if layer is None or layer < 0 else "%s.l%d" % (kind, layer)
+    return jax.named_scope(getattr(_scope, "prefix", "") + name)
+
+
+@contextlib.contextmanager
+def node_scope(name):
+    """What the executor enters around a node whose symbol carries the
+    attribute ``__scope__`` (``mx.AttrScope(__scope__=...)`` in a model
+    builder), so that a device trace can tell apart block parts that are
+    made of plain ops: ``mla_q.l3`` is the ``jax.named_scope`` of the
+    node's operations; a name that ends in ``.`` (``mtp.``) is put before
+    the scopes the node's op names itself (``layer_scope``).  Nothing
+    for a node without the attribute."""
+    if not name:
+        yield
+    elif name.endswith("."):
+        was = getattr(_scope, "prefix", "")
+        _scope.prefix = name
+        try:
+            yield
+        finally:
+            _scope.prefix = was
+    else:
+        with jax.named_scope(name):
+            yield
 
 
 def rms_norm(x, gamma, eps: float):
@@ -111,7 +143,11 @@ def causal_attention(q, k, v, scale: float):
 
 
 def _kernel_tiles(t: int):
-    """(keys and queries a tile, keys a matmul) for sequences of ``t``."""
+    """(keys and queries a tile, keys a matmul) for sequences of ``t``,
+    whatever the head sizes: at 256-lane q, k AND v (20 heads, the widest
+    this repo runs) both kernels still compile for a v5e inside Mosaic's
+    default scoped VMEM (16 MiB of the chip's 128), so no rule by head
+    size is needed (PERF.md, PR 35)."""
     tile = min(ATTN_KERNEL_BLOCK, t)
     return tile, min(ATTN_KERNEL_SLICE, tile)
 
@@ -119,9 +155,10 @@ def _kernel_tiles(t: int):
 def _kernel_takes(q, k, v) -> bool:
     """What the TPU kernel's tiling accepts: the configuration's compute
     dtype (float32 keeps the plain blocks its chip parity was measured
-    on), value heads of whole 128-lane rows, query/key heads of at least
-    one (``_flash_fwd`` pads 192 to 256 with zeros, which no score
-    sees), sequences of whole tiles and tiles of whole slices."""
+    on), value heads of whole 128-lane rows (128 against queries of 192,
+    or 256 against 256), query/key heads of at least one (``_flash_fwd``
+    pads 192 to 256 with zeros, which no score sees, and pads nothing at
+    256), sequences of whole tiles and tiles of whole slices."""
     t, dh, dv = q.shape[1], q.shape[3], v.shape[3]
     tile, piece = _kernel_tiles(t)
     return (all(x.dtype == jnp.bfloat16 for x in (q, k, v))
@@ -253,7 +290,9 @@ class RotaryEmbeddingOp(OpDef):
 class CausalSelfAttentionOp(OpDef):
     """Causal multi-head self-attention over ``(B, T, H, Dh)`` query
     and key and ``(B, T, H, Dv)`` value (``Dv`` may differ from ``Dh``:
-    latent attention's 192 against 128): ``softmax(q k^T * scale +
+    latent attention's 192 against 128, or equal it at 256 against 256
+    where the value heads are as wide as the nope and rope parts
+    together): ``softmax(q k^T * scale +
     causal mask) v`` per head -> ``(B, T, H, Dv)``, softmax in float32,
     scores never materialized whole.  ``scale`` 0 means ``Dh**-0.5``;
     ``layer`` names the trace scope.
@@ -262,7 +301,8 @@ class CausalSelfAttentionOp(OpDef):
     platform the program is lowered for and the inputs: bfloat16 with
     ``Dv % 128 == 0``, ``Dh >= 128`` (padded with zeros to whole 128
     lanes inside the kernel's wrapper) and ``T`` a multiple of 128 and
-    of its tile (``min(1024, T)``, itself whole slices of 512), lowered
+    of its tile (``min(1024, T)``, itself whole slices of 512, at every
+    head size: 256 / 256 fits the kernels' VMEM at that tile), lowered
     for a TPU, is JAX's Pallas splash-attention kernel; float32, any
     other shape and every other platform are the plain query blocks.
     The counter ``attn:lowering`` records it per bind."""
@@ -299,12 +339,17 @@ class CausalSelfAttentionOp(OpDef):
             return [causal_attention(q, k, v, scale)]
 
 
-def _softmax_ce(logits, label):
-    """Per-row ``logsumexp(logits) - logits[label]`` in float32."""
+def _softmax_ce(logits, label, ignore=None):
+    """Per-row ``logsumexp(logits) - logits[label]`` in float32; a row
+    whose label is ``ignore`` gives exactly 0 and takes no gradient."""
     x = logits.astype(jnp.float32)
     idx = lax.stop_gradient(label).astype(jnp.int32)
+    left_out = None if ignore is None else idx == ignore
+    if left_out is not None:
+        idx = jnp.where(left_out, 0, idx)
     picked = jnp.take_along_axis(x, idx[:, None], axis=-1)[:, 0]
-    return jax.nn.logsumexp(x, axis=-1) - picked
+    loss = jax.nn.logsumexp(x, axis=-1) - picked
+    return loss if left_out is None else jnp.where(left_out, 0.0, loss)
 
 
 @register_op("SoftmaxCELoss", hint="softmaxceloss")
@@ -314,7 +359,13 @@ class SoftmaxCELossOp(OpDef):
     log-sum-exp in float32.  Differentiable (its gradient is
     ``(softmax - onehot) * head``); wrap it in ``MakeLoss`` to train on
     it.  Where ``SoftmaxOutput`` hands the metric ``(N, V)``
-    probabilities, this hands it N numbers."""
+    probabilities, this hands it N numbers.  With ``use_ignore`` a row
+    whose label is ``ignore_label`` (a position that has no target) reads
+    exactly 0 and sends no gradient to its logits (``SoftmaxOutput``'s
+    pair of parameters); ``MakeLoss(normalization="valid")`` then
+    normalizes over the rows that have one."""
+    params = [Param("use_ignore", bool, default=False),
+              Param("ignore_label", int, default=-1)]
 
     def list_arguments(self, p):
         return ["data", "label"]
@@ -334,5 +385,6 @@ class SoftmaxCELossOp(OpDef):
         return [t, lt], [np.dtype(np.float32)], []
 
     def forward(self, p, inputs, aux, ctx):
-        with jax.named_scope("lm_loss"):
-            return [_softmax_ce(inputs[0], inputs[1])]
+        with layer_scope("lm_loss", None):
+            return [_softmax_ce(inputs[0], inputs[1],
+                                p.ignore_label if p.use_ignore else None)]

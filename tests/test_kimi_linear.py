@@ -416,23 +416,29 @@ def test_sigmoid_router_bias_moves_the_choice_and_not_the_weights():
     assert not np.asarray(grad).any()
 
 
-def _share_block(E, k, H, D, held, first):
+def _share_block(E, k, H, D, held, first, scale):
     return MoEFeedForward(mx.sym.Variable("data"), num_hidden=H,
                           num_experts=E, k=k, capacity_factor=0.0,
                           name="moe", act_type="silu", gated=True,
                           no_bias=True, renormalize=True, score="sigmoid",
-                          scale=2.446, bias_rate=1e-3, shared_hidden=H,
+                          scale=scale, bias_rate=1e-3, shared_hidden=H,
                           output_dim=D, experts_held=held,
                           first_expert=first)
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
-    """E = 16 experts over 4 ranks of 4: each rank's output (its held
-    experts' part plus the shared expert), summed, with the shared
-    expert counted once, is the reference's layer with all 16 held; and
+@pytest.mark.parametrize("config,E,k,held,scale", [
+    ("kimi-linear-48b-a3b", 16, 4, 4, 2.446),
+    ("glm-4.7-flash", 16, 2, 2, 1.8)],
+    ids=["kimi-4-ranks-of-4", "glm-8-ranks-of-2"])
+def test_the_shares_add_up_to_the_uncut_layer(config, E, k, held, scale):
+    """E = 16 experts over 4 ranks of 4 (8 ranks of 2, as the GLM
+    configuration's eight): each rank's output (its held experts' part
+    plus the shared expert), summed, with the shared expert counted
+    once, is that configuration's reference layer with all 16 held; and
     each rank's output is the reference given the same share."""
+    REF = manifest.load_module("reference", config)
     rng = np.random.RandomState(5)
-    T, D, H, E, k, held = 40, 12, 10, 16, 4, 4
+    T, D, H = 40, 12, 10
     x = rng.randn(T, D).astype(np.float32)
     full = {"moe_gate_weight": rng.randn(E, D),
             "moe_experts_i2h_gate_weight": 0.5 * rng.randn(E, D, H),
@@ -443,7 +449,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
             "moe_shared_h2o_weight": 0.5 * rng.randn(D, H)}
     full = {n: v.astype(np.float32) for n, v in full.items()}
     bias = (0.3 * rng.randn(E)).astype(np.float32)
-    m = {"num_experts": E, "experts_per_tok": k, "routed_scale": 2.446}
+    m = {"num_experts": E, "experts_per_tok": k, "routed_scale": scale}
     p = {n: jnp.asarray(v) for n, v in full.items()}
     p["moe_dispatch_select_bias"] = jnp.asarray(bias)
     with jax.default_matmul_precision("highest"):
@@ -455,7 +461,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
     for first in range(0, E, held):
         mine = {n: (v[first:first + held] if "experts" in n else v)
                 for n, v in full.items()}
-        net = _share_block(E, k, H, D, held, first)
+        net = _share_block(E, k, H, D, held, first, scale)
         exe = net.simple_bind(mx.cpu(), data=(T, D), grad_req="null")
         exe.arg_dict["data"][:] = x
         for n, v in mine.items():
