@@ -1183,7 +1183,7 @@ mx.nd.minus_scalar <- function(..., ctx = mx.cpu()) {
   mx.nd.internal.invoke('_minus_scalar', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
 }
 
-#' Imperative _moe_combine: 3 NDArray input(s), 0 scalar(s)
+#' Imperative _moe_combine: 4 NDArray input(s), 0 scalar(s)
 #' @return MXNDArray
 mx.nd.moe_combine <- function(..., ctx = mx.cpu()) {
   a <- list(...)

@@ -19,7 +19,10 @@ expert order, ``grouped_matmul`` multiplies each expert's run of rows by
 that expert's matrix, ``combine_sorted`` gathers them back.  Forward AND
 backward are gathers through the plan's permutation and its inverse
 (each a ``custom_vjp``): the autodiff transpose of a gather is a
-scatter-add, which a TPU executes row by row.
+scatter-add, which a TPU executes row by row.  Each is in bounds by
+construction and says so (``_rows``), and the plan's ``order`` and
+``slot`` are all the backward passes need: no permutation is found
+again.
 
 When called eagerly (serving probes, bench, tests) the primitives emit
 ``moe:dispatch`` / ``moe:combine`` trace spans plus a per-call
@@ -102,10 +105,28 @@ def combine(expert_out, slot, weight, num_experts: int, capacity: int):
 
 # -- the drop-free (sorted) layout -------------------------------------------
 
+def _rows(x, index):
+    """Rows ``index`` of ``x``.  Every index of this layout is a value
+    of the plan's permutation (``order``, ``slot``), in range by
+    construction, and the gather says so (a clip that moves nothing):
+    ``take``'s default ``mode="fill"`` would select each gathered row
+    against NaN, a whole pass over a ``(T*k, D)`` array that guards
+    nothing."""
+    return jnp.take(x, index, axis=0, mode="clip")
+
+
+def _moved(values, place):
+    """``out[place[i]] = values[i]`` for ``T*k`` scalars, ``place`` a
+    permutation: a key-value sort.  XLA:TPU gathers scalars one at a
+    time (1.05 ms for 131 072 on a v5e) and sorts as many pairs in
+    0.13 ms (PERF.md, PR 36)."""
+    return jax.lax.sort((place, values), num_keys=1)[1]
+
+
 @jax.custom_vjp
 def _sort_rows(x, order, slot):
     k = slot.shape[1]
-    return jnp.take(x, order // k, axis=0)
+    return _rows(x, order // k)
 
 
 def _sort_rows_fwd(x, order, slot):
@@ -115,7 +136,7 @@ def _sort_rows_fwd(x, order, slot):
 def _sort_rows_bwd(slot, g):
     T, k = slot.shape
     # token t's gradient: the sum of its k sorted rows' gradients
-    dx = jnp.take(g, slot.reshape(T * k), axis=0).reshape(T, k, -1)
+    dx = _rows(g, slot.reshape(T * k)).reshape(T, k, -1)
     return dx.sum(axis=1).astype(g.dtype), None, None
 
 
@@ -129,37 +150,41 @@ def sort_rows(x, order, slot):
 
 
 @jax.custom_vjp
-def _combine_sorted(rows, slot, weight):
+def _combine_sorted(rows, order, slot, weight):
+    return _combine_sorted_fwd(rows, order, slot, weight)[0]
+
+
+def _combine_sorted_fwd(rows, order, slot, weight):
     T, k = slot.shape
-    picked = jnp.take(rows, slot.reshape(T * k), axis=0).reshape(T, k, -1)
-    return (picked * weight[..., None].astype(rows.dtype)).sum(axis=1)
-
-
-def _combine_sorted_fwd(rows, slot, weight):
-    return _combine_sorted(rows, slot, weight), (rows, slot, weight)
+    # the gathered rows are what the backward pass needs of ``rows``:
+    # saved, they are not gathered a second time
+    picked = _rows(rows, slot.reshape(T * k)).reshape(T, k, -1)
+    out = (picked * weight[..., None].astype(rows.dtype)).sum(axis=1)
+    return out, (picked, order, slot, weight)
 
 
 def _combine_sorted_bwd(res, g):
-    rows, slot, weight = res
+    picked, order, slot, weight = res
     T, k = slot.shape
-    flat = slot.reshape(T * k)
-    order = jnp.argsort(flat)                # the permutation's inverse
-    w_sorted = jnp.take(weight.reshape(T * k), order)
-    d_rows = jnp.take(g, order // k, axis=0) \
-        * w_sorted[:, None].astype(g.dtype)
-    picked = jnp.take(rows, flat, axis=0).reshape(T, k, -1)
+    w_sorted = _moved(weight.reshape(T * k), slot.reshape(T * k))
+    d_rows = _rows(g, order // k) * w_sorted[:, None].astype(g.dtype)
     d_weight = (picked.astype(jnp.float32)
                 * g[:, None, :].astype(jnp.float32)).sum(axis=-1)
-    return d_rows.astype(rows.dtype), None, d_weight.astype(weight.dtype)
+    return d_rows.astype(picked.dtype), None, None, \
+        d_weight.astype(weight.dtype)
 
 
 _combine_sorted.defvjp(_combine_sorted_fwd, _combine_sorted_bwd)
 
 
-def combine_sorted(rows, slot, weight):
+def combine_sorted(rows, order, slot, weight):
     """``(T*k, O)`` expert outputs in sorted order -> ``(T, O)``: each
-    token's k rows, weighted by its gate values and summed."""
-    return _combine_sorted(rows, slot, weight)
+    token's k rows, weighted by its gate values and summed.  ``order``
+    and ``slot`` are the plan's permutation and its inverse: the forward
+    pass gathers through ``slot``, the backward pass through ``order``,
+    and no permutation is found again (the backward pass brings the
+    ``T*k`` weights, scalars, to sorted order by one key-value sort)."""
+    return _combine_sorted(rows, order, slot, weight)
 
 
 def grouped_matmul(rows, w, group_sizes):

@@ -31,6 +31,7 @@ _AUX_IDX = 3
 _COUNTS_IDX = 4
 _HITS_IDX = 5
 _DROPPED_IDX = 6
+_ORDER_IDX = 7
 
 
 def MoEFeedForward(data, num_hidden: int, num_experts: int, k: int = 2,
@@ -104,7 +105,7 @@ def MoEFeedForward(data, num_hidden: int, num_experts: int, k: int = 2,
                                output_dim=output_dim, act_type=act_type,
                                no_bias=no_bias, gated=gated,
                                name=name + "_experts", **scope, **share)
-    out = _sym._moe_combine(ffn, disp[1], disp[2],
+    out = _sym._moe_combine(ffn, disp[1], disp[2], disp[_ORDER_IDX],
                             name=name + "_combine", **scope)
     if not shared_hidden:
         return out

@@ -17,9 +17,10 @@ Two layouts, chosen by the dispatch node's ``capacity_factor`` alone.
 ``> 0``: capacity buckets, ``dispatched`` is ``(E, C, D)`` and
 over-capacity choices drop.  ``<= 0``: nothing drops and nothing is
 bucketed; ``dispatched`` is the ``(T*k, D)`` rows sorted by expert,
-``slot`` the sorted row of each (token, choice), ``counts`` the group
-sizes, and ``_moe_expert_ffn`` is three grouped matmuls over exactly
-``T*k`` rows.  ``_moe_expert_ffn`` and ``_moe_combine`` tell the two by
+``slot`` the sorted row of each (token, choice), ``order`` the
+(token, choice) of each sorted row, ``counts`` the group sizes, and
+``_moe_expert_ffn`` is three grouped matmuls over exactly ``T*k``
+rows.  ``_moe_expert_ffn`` and ``_moe_combine`` tell the two by
 the rank of their data, so a pass that re-pins a dispatch node's
 capacity (``MoEServeParityPass``) need touch nothing else.
 
@@ -44,8 +45,25 @@ def _act(name):
     return (lambda x: x) if name == "identity" else ACTIVATIONS[name]
 
 
-# _moe_dispatch's output "counts" (list_outputs)
+# _moe_dispatch's outputs "slot", "counts" and "order" (list_outputs)
+_SLOT_OUT = 2
 _COUNTS_OUT = 4
+_ORDER_OUT = 7
+
+
+def _from_dispatch(op, given, via, via_out, want, want_out, why):
+    """``{want: output want_out}`` of the ``_moe_dispatch`` node whose
+    output ``via_out`` is the input ``via`` (``implied_inputs``: a caller
+    that does not give ``want``, and a graph saved before it was an
+    input)."""
+    if want in given or via not in given:
+        return {}
+    src, out = given[via]
+    if src.is_variable or src.op.name != "_moe_dispatch" or out != via_out:
+        raise MXNetError(
+            "%s: %s is not a _moe_dispatch node's output, so give %s (%s)"
+            % (op, via, want, why))
+    return {want: (src, want_out)}
 
 
 def _scope(kind, p):
@@ -92,7 +110,7 @@ class MoEDispatchOp(OpDef):
 
     def list_outputs(self, p):
         return ["dispatched", "weight", "slot", "aux", "counts", "hits",
-                "dropped"]
+                "dropped", "order"]
 
     def _cap(self, p, T):
         from ..moe.router import resolve_capacity
@@ -101,7 +119,7 @@ class MoEDispatchOp(OpDef):
     def infer_shape(self, p, in_shapes):
         d, lg = in_shapes
         if d is None:
-            return in_shapes, [None] * 7, []
+            return in_shapes, [None] * 8, []
         if len(d) != 2:
             raise MXNetError("_moe_dispatch: data must be (tokens, dim), "
                              "got %r" % (d,))
@@ -129,14 +147,15 @@ class MoEDispatchOp(OpDef):
                                  p.first_expert,
                                  p.first_expert + p.experts_held - 1, E))
         return [d, (T, E)], \
-            [buf, (T, k), (T, k), (1,), (E,), (T, E), (1,)], \
+            [buf, (T, k), (T, k), (1,), (E,), (T, E), (1,), (T * k,)], \
             [(E,)] * len(self.list_auxiliary_states(p))
 
     def infer_type(self, p, in_types):
         t = in_types[0] if in_types[0] is not None else np.dtype(np.float32)
         f32 = np.dtype(np.float32)
         return [t, f32], \
-            [t, f32, np.dtype(np.int32), f32, f32, f32, f32], \
+            [t, f32, np.dtype(np.int32), f32, f32, f32, f32,
+             np.dtype(np.int32)], \
             [f32] * len(self.list_auxiliary_states(p))
 
     def forward(self, p, inputs, aux, ctx):
@@ -152,13 +171,15 @@ class MoEDispatchOp(OpDef):
                     scale=p.scale, select_bias=aux[0] if aux else None,
                     held=(p.first_expert, p.experts_held)
                     if p.experts_held else None)
-                buf = sort_rows(x, plan.order, plan.slot)
+                buf, order = sort_rows(x, plan.order, plan.slot), plan.order
             else:
                 C = self._cap(p, T)
                 plan = _route(logits, p.k, C, renormalize=p.renormalize)
                 buf = _dispatch(x, plan.slot, p.num_experts, C)
+                # buckets have no order; the combine node reads none
+                order = jnp.zeros((T * p.k,), jnp.int32)
             outs = [buf, plan.weight, plan.slot, plan.aux.reshape(1),
-                    plan.counts, plan.hits, plan.dropped.reshape(1)]
+                    plan.counts, plan.hits, plan.dropped.reshape(1), order]
             if not aux:
                 return outs
             # the selection bias is a state of the op, as BatchNorm's
@@ -203,15 +224,10 @@ class MoEExpertFFNOp(OpDef):
         # counts are the dispatch node's: a caller that gives data alone,
         # and a graph saved before counts was an input, get them from
         # the node data comes from
-        if "counts" in given or "data" not in given:
-            return {}
-        src, out = given["data"]
-        if src.is_variable or src.op.name != "_moe_dispatch" or out != 0:
-            raise MXNetError(
-                "_moe_expert_ffn: data is not a _moe_dispatch node's "
-                "first output, so give counts (the group sizes of sorted "
-                "rows; unused over (experts, capacity, dim) buckets)")
-        return {"counts": (src, _COUNTS_OUT)}
+        return _from_dispatch(
+            "_moe_expert_ffn", given, "data", 0, "counts", _COUNTS_OUT,
+            "the group sizes of sorted rows; unused over (experts, "
+            "capacity, dim) buckets")
 
     def infer_shape(self, p, in_shapes):
         d, cnt = in_shapes[0], in_shapes[-1]
@@ -300,32 +316,43 @@ class MoECombineOp(OpDef):
     the sentinel slot reads zero (clip-gather + explicit mask in
     ``moe.dispatch.combine``) so dropped tokens contribute exactly
     nothing, or from the (T*k, O) sorted rows, where nothing was
-    dropped."""
+    dropped.  ``order`` is the dispatch node's, the inverse of ``slot``
+    over sorted rows: their backward pass gathers through it (unused
+    over buckets)."""
     params = [Param("layer", int, default=-1)]
 
     def list_arguments(self, p):
-        return ["data", "weight", "slot"]
+        return ["data", "weight", "slot", "order"]
+
+    def implied_inputs(self, p, given):
+        # as the expert node's counts: the dispatch node's, for a caller
+        # that gives none and a graph saved before order was an input
+        return _from_dispatch(
+            "_moe_combine", given, "slot", _SLOT_OUT, "order", _ORDER_OUT,
+            "the (token, choice) of each sorted row; unused over "
+            "(experts, capacity, dim) buckets")
 
     def infer_shape(self, p, in_shapes):
-        d, w, s = in_shapes
+        d, w, s, _ = in_shapes
         if d is None or (w is None and s is None):
             return in_shapes, [None], []
         if len(d) not in (2, 3):
             raise MXNetError("_moe_combine: data must be (experts, "
                              "capacity, dim) or sorted (rows, dim), got %r"
                              % (d,))
-        tk = w if w is not None else s
-        return [d, tuple(tk), tuple(tk)], [(tk[0], d[-1])], []
+        tk = tuple(w if w is not None else s)
+        return [d, tk, tk, (tk[0] * tk[1],)], [(tk[0], d[-1])], []
 
     def infer_type(self, p, in_types):
         t = in_types[0] if in_types[0] is not None else np.dtype(np.float32)
-        return [t, np.dtype(np.float32), np.dtype(np.int32)], [t], []
+        i32 = np.dtype(np.int32)
+        return [t, np.dtype(np.float32), i32, i32], [t], []
 
     def forward(self, p, inputs, aux, ctx):
         from ..moe.dispatch import combine as _combine, combine_sorted
-        x, weight, slot = inputs
+        x, weight, slot, order = inputs
         with _scope("moe_combine", p):
             if x.ndim == 2:
-                return [combine_sorted(x, slot, weight)]
+                return [combine_sorted(x, order, slot, weight)]
             E, C = x.shape[0], x.shape[1]
             return [_combine(x, slot, weight, E, C)]

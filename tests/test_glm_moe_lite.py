@@ -515,3 +515,13 @@ def test_device_scopes_name_the_blocks_parts_and_the_module():
     with tf_ops.node_scope(None), tf_ops.layer_scope("attn", 3):
         pass
     assert getattr(tf_ops._scope, "prefix", "") == ""
+
+
+def test_a_checkpoint_written_before_pr36_still_loads():
+    """Two expert layers and the prediction module's block, 4 of 16
+    experts held: parameters, saved graph and both losses are the commit
+    before's (``tests/common/old_checkpoint.py``)."""
+    from old_checkpoint import check_checkpoint_written_before_pr36
+    net, _, _, tokens, labels = _tiny(seed=36)
+    check_checkpoint_written_before_pr36("glm", net, tokens, labels,
+                                         dict(ADAM))

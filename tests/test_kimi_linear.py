@@ -713,3 +713,13 @@ def test_fit_feeds_the_held_share_to_moe_load():
         assert 0 < e["args"]["held"] < routed
     assert [n for n in mod._aux_names] == [b + "_select_bias"
                                            for b in blocks]
+
+
+def test_a_checkpoint_written_before_pr36_still_loads():
+    """One rank's share (4 of 16 experts held, rows behind the groups):
+    parameters, saved graph and loss are the commit before's
+    (``tests/common/old_checkpoint.py``)."""
+    from old_checkpoint import check_checkpoint_written_before_pr36
+    net, _, _, tokens, labels = _tiny(seed=36, num_layers=2)
+    check_checkpoint_written_before_pr36("kimi", net, tokens, labels,
+                                         dict(ADAM))

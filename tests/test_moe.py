@@ -558,3 +558,203 @@ def test_decode_engine_routes_and_reports():
         DecodeEngine(_decode_net(0.0), dict(params), num_slots=2,
                      state_shapes={"moe_hits": (E,)},
                      moe_hits_state="nope", name="moe-decode-bad")
+
+
+# -- the sorted layout's row movement (PR 36) ---------------------------------
+
+def _sorted_plan(held, dtype):
+    """A ``SortedPlan`` over 6 experts with uneven groups and an empty
+    one (expert 1 gets no token), all here or experts 2..4 held, and the
+    block's arrays in ``dtype``."""
+    from mxnet_tpu.moe.router import route_sorted
+    T, D, n_exp, k = 24, 12, 6, 2
+    rng = np.random.RandomState(7)
+    logits = rng.randn(T, n_exp).astype(np.float32)
+    logits[:, 1] = -9.0
+    logits[: T // 2, 4] += 2.0
+    plan = route_sorted(jnp.asarray(logits), k, renormalize=True,
+                        held=(2, 3) if held else None)
+    counts = np.asarray(plan.counts)
+    assert counts[1] == 0 and len(set(counts)) > 2 and counts.sum() == T * k
+    x = jnp.asarray(rng.randn(T, D), dtype)
+    rows = jnp.asarray(rng.randn(T * k, D), dtype)
+    if held:
+        mine = int(counts[2:5].sum())
+        assert 0 < mine < T * k
+        rows = rows.at[mine:].set(0)
+    return plan, x, rows
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-6),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("held", [False, True],
+                         ids=["all-experts", "3-of-6-held"])
+def test_sorted_layout_backward_equals_autodiff_of_plain_gathers(held, dtype,
+                                                                 tol):
+    """``sort_rows`` and ``combine_sorted`` against the same gathers
+    written plainly and differentiated by JAX (scatter-adds): values and
+    the gradients of the tokens, the expert rows and the combine
+    weights, whose backward pass reads ``order`` where it used to sort
+    ``slot``, and the gathered rows the forward pass saved where it used
+    to gather them again."""
+    from mxnet_tpu.moe.dispatch import combine_sorted, sort_rows
+    plan, x, rows = _sorted_plan(held, dtype)
+    T, k = plan.slot.shape
+    flat = plan.slot.reshape(T * k)
+    f32 = jnp.float32
+
+    def ours(x, rows, weight):
+        return (sort_rows(x, plan.order, plan.slot).astype(f32),
+                combine_sorted(rows, plan.order, plan.slot,
+                               weight).astype(f32))
+
+    def plain(x, rows, weight):
+        picked = rows[flat].reshape(T, k, -1)
+        return (x[plan.order // k].astype(f32),
+                (picked * weight[..., None].astype(rows.dtype)).sum(
+                    axis=1).astype(f32))
+
+    rng = np.random.RandomState(9)
+    cot = (jnp.asarray(rng.randn(T * k, x.shape[1]), f32),
+           jnp.asarray(rng.randn(T, x.shape[1]), f32))
+    got, want = [], []
+    for fn, into in ((ours, got), (plain, want)):
+        outs, vjp = jax.vjp(fn, x, rows, plan.weight)
+        into.extend(list(outs) + list(vjp(cot)))
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.abs(w).max() > 0
+        assert np.allclose(g, w, rtol=tol, atol=tol * np.abs(w).max())
+
+
+@pytest.mark.parametrize("n", [1, 7, 4096])
+def test_scalars_moved_by_a_key_value_sort_land_where_a_scatter_puts_them(n):
+    """``dispatch._moved``: ``out[place[i]] = values[i]``, which
+    brings the ``T*k`` weights to sorted order in the combine's backward
+    pass."""
+    from mxnet_tpu.moe.dispatch import _moved
+    rng = np.random.RandomState(n)
+    place = rng.permutation(n).astype(np.int32)
+    values = rng.randn(n).astype(np.float32)
+    want = np.empty_like(values)
+    want[place] = values
+    got = _moved(jnp.asarray(values), jnp.asarray(place))
+    assert got.dtype == jnp.float32 and np.array_equal(np.asarray(got), want)
+    # and through a permutation's inverse it is the gather
+    inverse = np.argsort(place).astype(np.int32)
+    assert np.array_equal(np.asarray(_moved(jnp.asarray(values),
+                                            jnp.asarray(inverse))),
+                          values[place])
+
+
+def _expert_ffn(no_bias=True, gated=True):
+    op = mx.ops.get_op("_moe_expert_ffn")
+    p = op.parse_params({"num_hidden": HID, "act_type": "silu",
+                         "gated": gated, "no_bias": no_bias})
+    return lambda *inputs: op.forward(p, list(inputs), [], None)[0]
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+def test_no_pass_over_the_sorted_rows_guards_or_finds_nothing():
+    """The jaxpr of ``sort_rows -> gated FFN -> combine_sorted``, forward
+    and under ``jax.grad``, the plan given (so a sort here is one
+    outside the router): no select and no pad over a matrix of ``T*k``
+    rows (every gather is in bounds and says so); no row gather beyond
+    the four the layout is made of (the combine's backward pass reads
+    the rows its forward pass gathered); the only sort is the backward
+    pass's key-value sort of ``T*k`` scalars (the weights to sorted
+    order), not an argsort for a permutation the plan holds; three
+    grouped matmuls forward and six more backward, and the
+    one sum of two cotangents ``(T*k, D)`` wide that gate and up, two
+    products over the same rows, leave (merging them was measured and
+    refused, PERF.md PR 36)."""
+    from mxnet_tpu.moe.dispatch import combine_sorted, sort_rows
+    plan, x, _ = _sorted_plan(False, jnp.float32)
+    (T, k), D, n_exp = plan.slot.shape, x.shape[1], plan.counts.shape[0]
+    rng = np.random.RandomState(8)
+    wg, w1 = (jnp.asarray(rng.randn(n_exp, D, HID), jnp.float32)
+              for _ in range(2))
+    w2 = jnp.asarray(rng.randn(n_exp, HID, D), jnp.float32)
+    ffn = _expert_ffn()
+
+    def block(x, wg, w1, w2, weight):
+        rows = sort_rows(x, plan.order, plan.slot)
+        rows = ffn(rows, wg, w1, w2, plan.counts)
+        return combine_sorted(rows, plan.order, plan.slot, weight).sum()
+
+    args = (x, wg, w1, w2, plan.weight)
+    forward = list(_eqns(jax.make_jaxpr(block)(*args).jaxpr))
+    both = list(_eqns(jax.make_jaxpr(jax.grad(
+        block, argnums=(0, 1, 2, 3, 4)))(*args).jaxpr))
+
+    def named(eqns, *names):
+        return [e for e in eqns if e.primitive.name in names]
+
+    def sorted_rows(eqn, width=None):
+        """Makes a matrix of ``T*k`` rows (``width`` wide)?"""
+        return any(len(getattr(v.aval, "shape", ())) == 2
+                   and v.aval.shape[0] == T * k
+                   and width in (None, v.aval.shape[1])
+                   for v in eqn.outvars)
+
+    for eqns in (forward, both):
+        assert not [e for e in named(eqns, "select_n", "pad")
+                    if sorted_rows(e)]
+    assert {v.aval.shape for e in both if sorted_rows(e)
+            for v in e.outvars} >= {(T * k, D), (T * k, HID)}, \
+        "the walk sees them"
+    assert len([e for e in named(forward, "gather")
+                if sorted_rows(e, D)]) == 2
+    assert len([e for e in named(both, "gather")
+                if sorted_rows(e, D)]) == 2 + 2
+    assert not named(forward, "sort")
+    sorts = named(both, "sort")
+    assert len(sorts) == 1 and all(
+        e.params["num_keys"] == 1 and len(e.invars) == 2
+        and all(v.aval.shape == (T * k,) for v in e.invars) for e in sorts)
+    assert len(named(forward, "ragged_dot_general")) == 3
+    assert len(named(both, "ragged_dot_general")) == 3 + 6
+    assert len([e for e in named(both, "add_any")
+                if sorted_rows(e, D)]) == 1
+
+
+def test_combine_node_takes_order_from_the_dispatch_node():
+    """``order`` is the dispatch node's eighth output and the combine
+    node's last input: wired by ``MoEFeedForward``, implied for a caller
+    that gives ``slot`` alone, refused where ``slot`` is not a dispatch
+    node's (no silent ``<name>_order`` variable), and no argument of the
+    bound symbol in either layout."""
+    for cf in (0.0, 1.25):
+        net = MoEFeedForward(mx.sym.Variable("data"), num_hidden=HID,
+                             num_experts=E, k=K, capacity_factor=cf,
+                             name="moe")
+        assert not any("order" in a for a in net.list_arguments())
+        nodes = json.loads(net.tojson())["nodes"]
+        disp = next(i for i, n in enumerate(nodes)
+                    if n["op"] == "_moe_dispatch")
+        comb = next(n for n in nodes if n["op"] == "_moe_combine")
+        assert comb["inputs"][1:] == [[disp, 1], [disp, 2], [disp, 7]]
+        shapes = dict(zip(net.get_internals().list_outputs(),
+                          net.get_internals().infer_shape(data=(16, 6))[1]))
+        assert shapes["moe_dispatch_order"] == (16 * K,)
+    disp = mx.sym._moe_dispatch(mx.sym.Variable("data"),
+                                mx.sym.Variable("logits"), num_experts=E,
+                                k=K, name="d")
+    implied = mx.sym._moe_combine(mx.sym.Variable("rows"), disp[1], disp[2],
+                                  name="c")
+    assert implied.list_arguments() == ["rows", "data", "logits"]
+    with pytest.raises(mx.base.MXNetError, match="give order"):
+        mx.sym._moe_combine(mx.sym.Variable("rows"), mx.sym.Variable("w"),
+                            mx.sym.Variable("s"), name="c")

@@ -573,3 +573,15 @@ def test_fit_feeds_moe_load_counter_and_stats():
     rep = mod._fused.moe_stats.report()["blocks"]
     assert rep["l0_moe_dispatch"]["steps"] == steps
     assert rep["l0_moe_dispatch"]["dropped"] == 0.0
+
+
+def test_a_checkpoint_written_before_pr36_still_loads():
+    """The combine node reads the dispatch node's ``order`` since PR 36:
+    the parameters, the saved graph's arguments and the loss are the
+    commit before's (``tests/common/old_checkpoint.py``)."""
+    from old_checkpoint import check_checkpoint_written_before_pr36
+    net, _, tokens, labels = _tiny_params(
+        dict(TINY, num_experts=8, experts_per_tok=2), seed=36)
+    check_checkpoint_written_before_pr36(
+        "olmoe", net, tokens, labels,
+        {"learning_rate": 1e-3, "rescale_grad": 1.0})
