@@ -192,8 +192,6 @@ class CheckpointManager:
             self._write_state(step, snap, meta)
             dt = time.perf_counter() - t0
             self.stats.add(last_overhead_s=dt, overhead_s=dt)
-            _trace.complete("ckpt:save(blocking)", t0, dt, cat="ckpt",
-                            step=step)
             return
         self._writer.submit(lambda: self._write_state(step, snap, meta))
         dt = time.perf_counter() - t0

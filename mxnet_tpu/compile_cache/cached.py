@@ -303,6 +303,38 @@ def _wrap_live(compiled, lowered, args, name: str):
         return None
 
 
+def optimized_hlo_text(executable) -> Optional[str]:
+    """The optimized (post-partitioner) HLO text of an entry a
+    ``CachedFunction`` dispatches to: a ``Compiled`` or a
+    ``_CachedExecutable``.  None where the backend gives none."""
+    try:
+        if hasattr(executable, "as_text"):
+            return executable.as_text()
+        return executable._loaded.hlo_modules()[0].to_string()
+    except Exception:
+        return None
+
+
+def _arg_specs(args):
+    """``args`` as ``jax.ShapeDtypeStruct`` leaves: what a later
+    ``jit.lower`` needs to make the same program again, holding no
+    buffer (a donated argument is dead after the call it was given to).
+    A committed array keeps its sharding; an uncommitted one, which jit
+    places itself, none."""
+    import jax
+
+    def spec(x):
+        if not isinstance(x, jax.Array):
+            return x
+        # a typed PRNG key array has no weak_type; a tracer (the
+        # function called under an outer transform) no placement
+        placed = not isinstance(x, jax.core.Tracer) and x.committed
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, weak_type=getattr(x, "weak_type", False),
+            sharding=x.sharding if placed else None)
+    return jax.tree_util.tree_map(spec, args)
+
+
 # -- the disk-backed cache ---------------------------------------------------
 
 class CompileCache:
@@ -610,6 +642,10 @@ class CachedFunction:
         self._entries: Dict[Tuple, Any] = {}
         self._last: Optional[Tuple[Tuple, Any]] = None
         self._called = False
+        # the first dispatch's avals, and whether this process traced
+        # the function: what ``optimized_hlo`` needs afterwards
+        self._specs = None
+        self._traced = False
         self._lock = make_lock("compile_cache.cached_fn")
 
     @property
@@ -621,7 +657,9 @@ class CachedFunction:
     def __call__(self, *args):
         if not self._entries and get_cache() is None:
             # cold default path: plain jit, zero added machinery
-            self._called = True
+            if not self._called:
+                self._specs = _arg_specs(args)
+                self._called = self._traced = True
             return self._jit(*args)
         sig = _signature(args)
         last = self._last
@@ -660,6 +698,28 @@ class CachedFunction:
             entry = self._acquire(sig, args)
         return entry
 
+    def optimized_hlo(self) -> Optional[str]:
+        """The optimized HLO text of the executable that runs: of the
+        entry last dispatched to (or warmed) where there is one.  The
+        plain ``jax.jit`` path holds no executable: there the function
+        is lowered and compiled again for the avals of its first
+        dispatch, which gives the same program and, where JAX's
+        persistent cache is on, reads it from there.  None before the
+        first dispatch.  Never called on the step's path: it is what
+        ``trace.scopes.program_scopes`` builds its table from, on
+        request.  A program that the fast key served without tracing is
+        traced once here, for the scopes it enters."""
+        if self._last is not None:
+            entry = self._last[1]
+        else:
+            entry = next(reversed(self._entries.values()), None)
+        if self._specs is not None and (entry is None or not self._traced):
+            lowered = self._jit.lower(*self._specs)
+            self._traced = True
+            if entry is None:
+                entry = lowered.compile()
+        return optimized_hlo_text(entry) if entry is not None else None
+
     # -- internals ---------------------------------------------------------
     def _first_call(self, sig, entry, args):
         """Validated first execution of a deserialized entry; any
@@ -689,6 +749,8 @@ class CachedFunction:
             entry = self._entries.get(sig)
             if entry is not None:
                 return entry
+            if self._specs is None:
+                self._specs = _arg_specs(args)
             # a second signature on an already-compiled program is a
             # RETRACE — in a steady loop that's the silent-10x bug the
             # recompile guard exists to catch
@@ -708,6 +770,7 @@ class CachedFunction:
                     return entry
             t0 = time.perf_counter()
             lowered = self._jit.lower(*args)
+            self._traced = True
             dt0 = time.perf_counter() - t0
             stats.note_trace_lower(self.name, dt0)
             _trace.complete("compile:trace_lower", t0, dt0, cat="compile",
@@ -757,6 +820,7 @@ class CachedFunction:
                       and cache.bypass_reason() is None)
         t0 = time.perf_counter()
         lowered = self._jit.lower(*args)
+        self._traced = True
         dt0 = time.perf_counter() - t0
         stats.note_trace_lower(self.name, dt0)
         _trace.complete("compile:trace_lower", t0, dt0, cat="compile",

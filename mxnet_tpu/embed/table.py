@@ -27,14 +27,12 @@ look up through the same traced path.
 """
 from __future__ import annotations
 
-import time as _time
 from typing import Optional
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .. import trace as _trace
 from ..base import MXNetError, get_env
 from .sparse import (dedup_ids, dedup_scatter_add, resolve_cap,
                      slot_leaves_row_shaped, sparse_apply_rows)
@@ -330,10 +328,7 @@ class EmbeddingTable:
         cap = self._cap(ids_h, n_uniq)
         self.stats.note_ids("%s_weight" % self.name, ids_h, n_uniq=n_uniq)
         prog = self._lookup_prog(cap, combiner)
-        t0 = _time.perf_counter()
         out = prog(self.rows, jnp.asarray(ids_h.astype(np.int32)))
-        _trace.complete("embed:lookup", t0, _time.perf_counter() - t0,
-                        cat="embed")
         return out
 
     def update(self, ids, grads, lr: Optional[float] = None):
@@ -354,14 +349,11 @@ class EmbeddingTable:
         # raise mid-call (bad grads shape, trace error) must not skew
         # Adam-style bias correction on the retry
         t_next = self._t + 1
-        t0 = _time.perf_counter()
         self.rows, self.slots = prog(
             self.rows, self.slots, jnp.asarray(ids_h.astype(np.int32)),
             jnp.asarray(g), jnp.asarray(lr, jnp.float32),
             jnp.asarray(t_next, jnp.int32))
         self._t = t_next
-        _trace.complete("embed:update", t0, _time.perf_counter() - t0,
-                        cat="embed")
         return self.rows
 
     def accumulate(self, ids, values):
@@ -372,12 +364,9 @@ class EmbeddingTable:
         n_uniq = self._distinct(ids_h)
         cap = self._cap(ids_h, n_uniq)
         self.stats.note_ids("%s_weight" % self.name, ids_h, n_uniq=n_uniq)
-        t0 = _time.perf_counter()
         self.rows = self._accumulate_prog(cap)(
             self.rows, jnp.asarray(ids_h.astype(np.int32)),
             jnp.asarray(v))
-        _trace.complete("embed:update", t0, _time.perf_counter() - t0,
-                        cat="embed")
         return self.rows
 
     def set_rows(self, value) -> None:

@@ -105,21 +105,20 @@ class _GraphProgram:
                     ins = [jax.device_put(x, dev) for x in ins]
             aux_names = _node_aux_names(node)
             aux_in = [aux[a] for a in aux_names]
-            key = jax.random.fold_in(rng, k) if node.op.needs_rng else None
-            opctx = OpContext(is_train=is_train, rng=key)
-
-            def run(op=node.op, p=node.params, ins=ins, aux_in=aux_in, opctx=opctx):
-                return op.forward(p, ins, aux_in, opctx)
-
             mirror = (self.do_mirror
                       or node.attrs.get("force_mirroring", "").lower() == "true")
-            with node_scope(node.attrs.get("__scope__")):
+            # every device operation of the node, its key's too, carries
+            # the node's scope (trace/scopes.py)
+            with node_scope(node.attrs.get("__scope__"), node.op.name,
+                            node.name):
+                key = jax.random.fold_in(rng, k) if node.op.needs_rng else None
+                opctx = OpContext(is_train=is_train, rng=key)
                 if mirror and not aux_names:
                     outs = jax.checkpoint(
                         lambda *i: node.op.forward(node.params, list(i), [],
                                                    opctx))(*ins)
                 else:
-                    outs = run()
+                    outs = node.op.forward(node.params, ins, aux_in, opctx)
             if isinstance(outs, tuple):
                 outs, aux_out = outs
                 for a, v in zip(aux_names, aux_out):

@@ -95,7 +95,8 @@ def kimi_linear_lm(num_layers, hidden_size, full_attn_layers, dense_layers,
     def mla(h, pre, l):
         return latent_attention(
             h, pre, seq_len, hidden_size, mla_heads, kv_lora_rank,
-            qk_nope_dim, qk_rope_dim, v_head_dim, rms_eps, layer=l)
+            qk_nope_dim, qk_rope_dim, v_head_dim, rms_eps, layer=l,
+            scope="")
 
     x = sym.Embedding(sym.Variable("data"), input_dim=vocab_size,
                       output_dim=hidden_size, name="embed")

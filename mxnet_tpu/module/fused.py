@@ -37,6 +37,7 @@ from ..base import MXNetError, get_env
 from ..executor import _GraphProgram
 from ..ndarray import NDArray
 from .. import trace as _trace
+from ..trace import scopes as _scopes
 
 __all__ = ["FusedTrainStep"]
 
@@ -703,7 +704,11 @@ class FusedTrainStep:
             # per-step randomness derived in-program from one resident key:
             # creating a fresh host key every batch would cost a transfer
             rng = jax.random.fold_in(base_key, t)
-            batch = self._maybe_augment(batch, rng, train=True)
+            # what the step adds around the graph carries a declared
+            # device scope too (trace/scopes.py): augment, cast.params,
+            # embed_sparse.<table>, optimizer.<parameter>
+            with _scopes.declared("augment"):
+                batch = self._maybe_augment(batch, rng, train=True)
 
             # sparse embed prologue: dedup each table's id batch, gather
             # the unique rows ONCE (zero-masked for out-of-range / padded
@@ -720,21 +725,23 @@ class FusedTrainStep:
                 batch = dict(batch)
                 params = dict(params)
                 for n, sp in sparse.items():
-                    ids = batch[sp.ids_name]
-                    flat = ids.reshape(-1).astype(jnp.int32)
-                    cap = resolve_cap(sp.cap, flat.shape[0], sp.vocab)
-                    uniq, inv = dedup_ids(flat, cap, sentinel=sp.vocab)
-                    full_tables[n] = params[n]
-                    raw = jnp.take(params[n], uniq, axis=0, mode="clip")
-                    params[n] = _mask_oov_rows(raw, uniq, sp.vocab)
-                    batch[sp.ids_name] = inv.reshape(ids.shape)
-                    sparse_ctx[n] = (uniq, cap)
+                    with _scopes.declared("embed_sparse." + n):
+                        ids = batch[sp.ids_name]
+                        flat = ids.reshape(-1).astype(jnp.int32)
+                        cap = resolve_cap(sp.cap, flat.shape[0], sp.vocab)
+                        uniq, inv = dedup_ids(flat, cap, sentinel=sp.vocab)
+                        full_tables[n] = params[n]
+                        raw = jnp.take(params[n], uniq, axis=0, mode="clip")
+                        params[n] = _mask_oov_rows(raw, uniq, sp.vocab)
+                        batch[sp.ids_name] = inv.reshape(ids.shape)
+                        sparse_ctx[n] = (uniq, cap)
 
             def loss_fn(train_params):
                 args = dict(train_params)
                 args.update(fixed)
                 args.update(batch)
-                args = self._cast_compute(args)
+                with _scopes.declared("cast.params"):
+                    args = self._cast_compute(args)
                 outs, new_aux = prog.eval(args, aux, rng, True)
                 # aux (BN moving stats) must keep its dtype or the donated
                 # state changes signature between steps
@@ -755,52 +762,56 @@ class FusedTrainStep:
             if sparse:
                 from ..embed.sparse import sparse_apply_rows
             new_params, new_opt = {}, {}
-            for n, sp in sparse.items():
+
+            def constrain_update(n):
+                if constrained:
+                    new_params[n] = jax.lax.with_sharding_constraint(
+                        new_params[n], self._param_sharding(n))
+                    new_opt[n] = jax.tree_util.tree_map(
+                        lambda x: jax.lax.with_sharding_constraint(
+                            x, self._update_spec(x, n)), new_opt[n])
+
+            for n, w in full_tables.items():
                 # grads[n] is ALREADY per-unique-row: the take-over-inv
                 # VJP segment-summed the per-occurrence grads into the
                 # cap-row buffer.  Lazy per-row optimizer on the touched
                 # rows only; sentinel rows drop on the scatter.
                 uniq, cap = sparse_ctx[n]
-                w = full_tables[n]
-                g = grads[n].astype(w.dtype) * rescale
-                if clip is not None:
-                    g = jnp.clip(g, -clip, clip)
-                new_params[n], new_opt[n] = sparse_apply_rows(
-                    w, state["opt"][n], uniq, g, opt_update,
-                    lr * lr_mult[n], wd[n], t)
-                if constrained:
-                    new_params[n] = jax.lax.with_sharding_constraint(
-                        new_params[n], self._param_sharding(n))
-                    new_opt[n] = jax.tree_util.tree_map(
-                        lambda x, _n=n: jax.lax.with_sharding_constraint(
-                            x, self._update_spec(x, _n)), new_opt[n])
+                with _scopes.declared("optimizer." + n):
+                    g = grads[n].astype(w.dtype) * rescale
+                    if clip is not None:
+                        g = jnp.clip(g, -clip, clip)
+                    new_params[n], new_opt[n] = sparse_apply_rows(
+                        w, state["opt"][n], uniq, g, opt_update,
+                        lr * lr_mult[n], wd[n], t)
+                    constrain_update(n)
             for n, w in params.items():
                 if n in sparse:
                     continue
-                g = grads[n].astype(w.dtype) * rescale
-                if clip is not None:
-                    g = jnp.clip(g, -clip, clip)
-                if constrained:
-                    # grads arrive sharded (reduce-scatter over dp,
-                    # tensor-parallel shards stay put), the update runs
-                    # on the shard, params leave in their at-rest spec
-                    # (all-gather over dp when replicated there) and
-                    # optimizer state stays sharded
-                    g = jax.lax.with_sharding_constraint(
-                        g, self._update_spec(g, n))
-                new_params[n], new_opt[n] = opt_update(
-                    w, g, state["opt"][n], lr * lr_mult[n], wd[n], t)
-                if constrained:
-                    new_params[n] = jax.lax.with_sharding_constraint(
-                        new_params[n], self._param_sharding(n))
-                    new_opt[n] = jax.tree_util.tree_map(
-                        lambda x, _n=n: jax.lax.with_sharding_constraint(
-                            x, self._update_spec(x, _n)), new_opt[n])
+                with _scopes.declared("optimizer." + n):
+                    g = grads[n].astype(w.dtype) * rescale
+                    if clip is not None:
+                        g = jnp.clip(g, -clip, clip)
+                    if constrained:
+                        # grads arrive sharded (reduce-scatter over dp,
+                        # tensor-parallel shards stay put), the update
+                        # runs on the shard, params leave in their
+                        # at-rest spec (all-gather over dp when
+                        # replicated there) and optimizer state stays
+                        # sharded
+                        g = jax.lax.with_sharding_constraint(
+                            g, self._update_spec(g, n))
+                    new_params[n], new_opt[n] = opt_update(
+                        w, g, state["opt"][n], lr * lr_mult[n], wd[n], t)
+                    constrain_update(n)
             merged_aux = dict(aux)
             merged_aux.update(new_aux)
             return ({"params": new_params, "opt": new_opt,
                      "aux": merged_aux, "fixed": fixed, "t": t}, outs)
 
+        # the scope scheme is part of the module's name, which JAX's
+        # persistent cache hashes (its key strips the scopes themselves)
+        step.__name__ = _scopes.module_name("step")
         return step
 
     def _program_desc(self, tag: str) -> str:
@@ -853,6 +864,7 @@ class FusedTrainStep:
         self._step = cached_jit(self._make_step_fn(), name="fused:step",
                                 donate_argnums=(0,),
                                 fast_key=self._program_desc("step"))
+        _scopes.register_program("fused:step", self._step)
         return self._step
 
     def _build_fwd(self):
@@ -914,6 +926,7 @@ class FusedTrainStep:
                                            unroll=unroll)
             return state, acc
 
+        superstep.__name__ = _scopes.module_name("superstep")
         from ..compile_cache import cached_jit
         # the traced metric reducer is part of the program; identify it
         # by owner class + qualname — process-stable, unlike a repr with
@@ -926,10 +939,12 @@ class FusedTrainStep:
                 type(owner).__name__ if owner is not None else "",
                 getattr(metric_update, "__qualname__",
                         type(metric_update).__name__))
-        return cached_jit(superstep, name="fused:superstep:k%d" % k,
-                          donate_argnums=(0,),
-                          fast_key=self._program_desc(
-                              "superstep:k%d:u%d:%s" % (k, unroll, mtag)))
+        program = cached_jit(superstep, name="fused:superstep:k%d" % k,
+                             donate_argnums=(0,),
+                             fast_key=self._program_desc(
+                                 "superstep:k%d:u%d:%s" % (k, unroll, mtag)))
+        _scopes.register_program(program.name, program)
+        return program
 
     def step(self, state, batch, base_key):
         """Advance one batch; returns (new_state, outputs)."""
@@ -1034,14 +1049,8 @@ class FusedTrainStep:
             # the optimized (post-SPMD-partitioner) HLO names the REAL
             # collectives; parse counts + payload bytes for the
             # collective-vs-compute split in multichip_report()
-            txt = None
-            try:
-                if hasattr(compiled, "as_text"):
-                    txt = compiled.as_text()
-                elif hasattr(compiled, "_loaded"):
-                    txt = compiled._loaded.hlo_modules()[0].to_string()
-            except Exception:
-                pass
+            from ..compile_cache.cached import optimized_hlo_text
+            txt = optimized_hlo_text(compiled)
             from .. import profiler as _prof
             census = _prof.parse_hlo_collectives(txt) if txt else None
             self.multichip_stats.set_cost(

@@ -23,25 +23,16 @@ scatter-add, which a TPU executes row by row.  Each is in bounds by
 construction and says so (``_rows``), and the plan's ``order`` and
 ``slot`` are all the backward passes need: no permutation is found
 again.
-
-When called eagerly (serving probes, bench, tests) the primitives emit
-``moe:dispatch`` / ``moe:combine`` trace spans plus a per-call
-``moe:expert_occupancy`` counter; under a jit trace they stay silent —
-host-side timing of a traced region would record tracing, not compute.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from .. import trace
+from ..trace import scopes as _scopes
 
 __all__ = ["dispatch", "combine", "sort_rows", "combine_sorted",
            "grouped_matmul"]
-
-
-def _eager(*xs) -> bool:
-    return not any(isinstance(x, jax.core.Tracer) for x in xs)
 
 
 def dispatch(x, slot, num_experts: int, capacity: int):
@@ -57,23 +48,11 @@ def dispatch(x, slot, num_experts: int, capacity: int):
     T, D = x.shape
     k = slot.shape[1]
 
-    def impl():
-        buf = jnp.zeros((E * C, D), dtype=x.dtype)
-        rows = jnp.broadcast_to(x[:, None, :], (T, k, D)).reshape(T * k, D)
-        buf = buf.at[slot.reshape(T * k)].set(rows, mode="drop",
-                                              unique_indices=True)
-        return buf.reshape(E, C, D)
-
-    if not _eager(x, slot):
-        return impl()
-    with trace.span("moe:dispatch", cat="moe", tokens=int(T),
-                    experts=E, capacity=C):
-        out = jax.block_until_ready(impl())
-    occ = jnp.bincount(jnp.minimum(slot.reshape(-1) // C, E),
-                       length=E + 1)[:E]
-    trace.counter("moe:expert_occupancy", cat="moe",
-                  **{"e%d" % i: int(occ[i]) for i in range(E)})
-    return out
+    buf = jnp.zeros((E * C, D), dtype=x.dtype)
+    rows = jnp.broadcast_to(x[:, None, :], (T, k, D)).reshape(T * k, D)
+    buf = buf.at[slot.reshape(T * k)].set(rows, mode="drop",
+                                          unique_indices=True)
+    return buf.reshape(E, C, D)
 
 
 def combine(expert_out, slot, weight, num_experts: int, capacity: int):
@@ -88,19 +67,12 @@ def combine(expert_out, slot, weight, num_experts: int, capacity: int):
     n = E * C
     T, k = slot.shape
 
-    def impl():
-        flat = expert_out.reshape(n, expert_out.shape[-1])
-        rows = jnp.take(flat, jnp.minimum(slot, n - 1).reshape(T * k),
-                        axis=0).reshape(T, k, -1)
-        live = (slot < n)[..., None].astype(flat.dtype)
-        w = weight[..., None].astype(flat.dtype)
-        return (rows * live * w).sum(axis=1)
-
-    if not _eager(expert_out, slot, weight):
-        return impl()
-    with trace.span("moe:combine", cat="moe", tokens=int(T),
-                    experts=E, capacity=C):
-        return jax.block_until_ready(impl())
+    flat = expert_out.reshape(n, expert_out.shape[-1])
+    rows = jnp.take(flat, jnp.minimum(slot, n - 1).reshape(T * k),
+                    axis=0).reshape(T, k, -1)
+    live = (slot < n)[..., None].astype(flat.dtype)
+    w = weight[..., None].astype(flat.dtype)
+    return (rows * live * w).sum(axis=1)
 
 
 # -- the drop-free (sorted) layout -------------------------------------------
@@ -199,3 +171,8 @@ def grouped_matmul(rows, w, group_sizes):
         if rows.dtype == jnp.bfloat16 else None
     return jax.lax.ragged_dot(rows, w, group_sizes.astype(jnp.int32),
                               precision=precision)
+
+
+# XLA:TPU writes the ragged dot's kernels itself and names them anew
+# ("ragged-dot-none"): the scope of their one caller, ``_moe_expert_ffn``
+_scopes.adopt("ragged-dot", "moe_experts")

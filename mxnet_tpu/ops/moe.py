@@ -266,40 +266,42 @@ class MoEExpertFFNOp(OpDef):
             layers = [(w, None) for w in layers]
         else:
             layers = list(zip(layers[0::2], layers[1::2]))
-        if x.ndim == 3:
-            def linear(h, w, b):
-                out = jnp.einsum("ecd,edh->ech", h, w)
-                return out if b is None else out + b[:, None, :]
-        else:
-            from ..moe.dispatch import grouped_matmul
-            sizes = counts.astype(jnp.int32)
-            mine = None
-            if p.experts_held:
-                # the dispatch node sorted this rank's rows first: the
-                # groups are its experts', the rows behind them no one's
-                sizes = sizes[p.first_expert:p.first_expert
-                              + p.experts_held]
-                mine = (jnp.arange(x.shape[0]) < sizes.sum())[:, None]
-            E = sizes.shape[0]
-
-            def own(rows):
-                """Rows that belong to no group read exactly zero, in
-                this pass and (the select's transpose) in the backward
-                one: a grouped matmul leaves them unwritten, which on a
-                TPU is whatever the buffer held."""
-                return rows if mine is None else jnp.where(
-                    mine, rows, jnp.zeros((), rows.dtype))
-
-            x = own(x)
-            expert_of_row = None if p.no_bias else jnp.repeat(
-                jnp.arange(E), sizes, total_repeat_length=x.shape[0])
-
-            def linear(h, w, b):
-                out = grouped_matmul(h, w, sizes)
-                return own(out if b is None else out + jnp.take(
-                    b, expert_of_row, axis=0))
-        act = _act(p.act_type)
+        # the whole body: the rank's row mask and its first select are
+        # the experts' work too
         with _scope("moe_experts", p):
+            if x.ndim == 3:
+                def linear(h, w, b):
+                    out = jnp.einsum("ecd,edh->ech", h, w)
+                    return out if b is None else out + b[:, None, :]
+            else:
+                from ..moe.dispatch import grouped_matmul
+                sizes = counts.astype(jnp.int32)
+                mine = None
+                if p.experts_held:
+                    # the dispatch node sorted this rank's rows first: the
+                    # groups are its experts', the rows behind them no one's
+                    sizes = sizes[p.first_expert:p.first_expert
+                                  + p.experts_held]
+                    mine = (jnp.arange(x.shape[0]) < sizes.sum())[:, None]
+                E = sizes.shape[0]
+
+                def own(rows):
+                    """Rows that belong to no group read exactly zero, in
+                    this pass and (the select's transpose) in the backward
+                    one: a grouped matmul leaves them unwritten, which on a
+                    TPU is whatever the buffer held."""
+                    return rows if mine is None else jnp.where(
+                        mine, rows, jnp.zeros((), rows.dtype))
+
+                x = own(x)
+                expert_of_row = None if p.no_bias else jnp.repeat(
+                    jnp.arange(E), sizes, total_repeat_length=x.shape[0])
+
+                def linear(h, w, b):
+                    out = grouped_matmul(h, w, sizes)
+                    return own(out if b is None else out + jnp.take(
+                        b, expert_of_row, axis=0))
+            act = _act(p.act_type)
             if p.gated:
                 (wg, bg), (w1, b1), (w2, b2) = layers
                 h = act(linear(x, wg, bg)) * linear(x, w1, b1)

@@ -20,11 +20,12 @@ chooses from what it sees, no option does; ``attn:lowering`` records
 the choice.
 
 The bodies of ``CausalSelfAttention`` and ``SoftmaxCELoss`` run under a
-``jax.named_scope`` (``attn.l<layer>``, ``lm_loss``) so a device trace
-can tell the block's parts apart.  A builder names the parts that are
-made of plain ops through the symbol attribute ``__scope__``
-(``node_scope``): ``mla_q.l3`` around a projection, ``mtp.`` before the
-scopes the ops of a prediction module name themselves.
+declared device scope (``attn.l<layer>``, ``lm_loss``; ``trace/scopes.py``)
+so a device trace can tell the block's parts apart.  A builder names the
+parts that are made of plain ops through the symbol attribute
+``__scope__`` (``node_scope``): ``mla_q.l3`` around a projection,
+``mtp.`` before the scopes of a prediction module's nodes.  Every other
+node gets the generic scope of its op type and name.
 """
 from __future__ import annotations
 
@@ -38,6 +39,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .. import trace
+from ..trace import scopes as _scopes
 from ..base import MXNetError
 from .pallas_kernels import _kernel_on_tpu
 from .registry import OpDef, Param, register_op
@@ -58,34 +60,43 @@ _scope = threading.local()         # .prefix: what node_scope("x.") set
 
 
 def layer_scope(kind: str, layer):
-    """``jax.named_scope`` of one block part: ``attn.l3``, ``moe_experts.l0``
-    (no suffix where the builder gave no layer index), behind the prefix
-    of the ``node_scope`` it runs in, if any: ``mtp.attn``."""
+    """Declared device scope of one block part: ``attn.l3``,
+    ``moe_experts.l0`` (no suffix where the builder gave no layer index),
+    behind the prefix of the ``node_scope`` it runs in, if any:
+    ``mtp.attn``."""
     name = kind if layer is None or layer < 0 else "%s.l%d" % (kind, layer)
-    return jax.named_scope(getattr(_scope, "prefix", "") + name)
+    return _scopes.declared(getattr(_scope, "prefix", "") + name)
 
 
 @contextlib.contextmanager
-def node_scope(name):
-    """What the executor enters around a node whose symbol carries the
-    attribute ``__scope__`` (``mx.AttrScope(__scope__=...)`` in a model
-    builder), so that a device trace can tell apart block parts that are
-    made of plain ops: ``mla_q.l3`` is the ``jax.named_scope`` of the
-    node's operations; a name that ends in ``.`` (``mtp.``) is put before
-    the scopes the node's op names itself (``layer_scope``).  Nothing
-    for a node without the attribute."""
-    if not name:
-        yield
-    elif name.endswith("."):
-        was = getattr(_scope, "prefix", "")
-        _scope.prefix = name
-        try:
+def node_scope(name, op_type=None, node_name=None):
+    """What the executor enters around every op node, so that a device
+    trace can tell the step's operations apart (``trace/scopes.py``).  A
+    node whose symbol carries the attribute ``__scope__``
+    (``mx.AttrScope(__scope__=...)`` in a model builder) is a block part
+    made of plain ops and keeps that name, a declared scope: ``mla_q.l3``.
+    Any other node gets the generic ``<op type, lower case>.<node
+    name>`` (``convolution.stage1_unit1_conv1``), which a scope the op
+    declares itself (``layer_scope``) wins over.  A ``__scope__`` that
+    ends in ``.`` (``mtp.``) is a prefix: it goes before the generic name
+    and before the scopes the node's op names itself.  Nothing where
+    there is neither attribute nor node."""
+    prefix = name if name and name.endswith(".") else ""
+    if name and not prefix:
+        with _scopes.declared(name):
             yield
-        finally:
-            _scope.prefix = was
-    else:
-        with jax.named_scope(name):
+        return
+    was = getattr(_scope, "prefix", "")
+    _scope.prefix = prefix
+    try:
+        if op_type is None:
             yield
+        else:
+            with _scopes.generic("%s%s.%s" % (prefix, op_type.lower(),
+                                              node_name)):
+                yield
+    finally:
+        _scope.prefix = was
 
 
 def rms_norm(x, gamma, eps: float):

@@ -29,6 +29,13 @@ while a profiler session is open it lands in the ``.xplane.pb`` host
 plane beside the device operations, on their clock (``complete`` cannot:
 an interval already measured cannot be annotated afterwards).
 
+Device side (``scopes.py``): every operation of the fused step carries
+the name of the graph node or step part that made it (a
+``jax.named_scope`` while the step is traced), and
+``trace.program_scopes("fused:step")`` is the program's own table
+``{HLO instruction: scope}`` of the executable it runs, built on request:
+what a device profile is joined with to sum its time by scope.
+
 Env knobs: ``MXNET_TRACE`` (default 1), ``MXNET_TRACE_BUF_EVENTS``
 (ring capacity per thread, default 65536), ``MXNET_TRACE_JOURNAL`` /
 ``MXNET_TRACE_JOURNAL_EVERY`` (run-metrics JSONL, journal.py),
@@ -48,6 +55,8 @@ from ..base import make_lock as _make_lock
 from .journal import (journal_every, journal_path, maybe_journal_step,
                       reset_journal, write_journal_line)
 from .recorder import DEFAULT_BUF_EVENTS, Recorder
+from . import scopes
+from .scopes import program_scopes
 
 __all__ = ["span", "complete", "instant", "counter", "async_begin",
            "async_instant", "async_end", "next_async_id", "enabled",
@@ -55,7 +64,7 @@ __all__ = ["span", "complete", "instant", "counter", "async_begin",
            "configure_spill", "flush_spill", "label_process",
            "event_count", "drop_count", "span_events", "instant_events",
            "counter_events",
-           "trace_report",
+           "trace_report", "scopes", "program_scopes",
            "reset", "maybe_journal_step", "write_journal_line",
            "journal_path", "journal_every", "reset_journal"]
 
