@@ -35,6 +35,7 @@ from .context import Context, cpu, current_context
 from .ndarray import NDArray, zeros as nd_zeros, array as nd_array
 from .ops.registry import OpContext
 from .ops.transformer import node_scope
+from .parallel.mesh import tracing_over
 from . import random as _random
 from .symbol import Symbol, _topo, _Node
 
@@ -312,7 +313,10 @@ class Executor:
             is_train = (kind == "fwd_train")
 
             def fn(args, aux, rng, _t=is_train):
-                return prog.eval(args, aux, rng, _t)
+                # set_mesh's programs span the mesh: an op with a kernel
+                # lowering has to see that (parallel.mesh.traced_devices)
+                with tracing_over(self._mesh):
+                    return prog.eval(args, aux, rng, _t)
             jfn = cached_jit(fn, name=name, fast_key=fast_key)
         self._jit_cache[kind] = jfn
         return jfn

@@ -36,6 +36,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..base import MXNetError, get_env
 from ..executor import _GraphProgram
 from ..ndarray import NDArray
+from ..parallel.mesh import tracing_over
 from .. import trace as _trace
 from ..trace import scopes as _scopes
 
@@ -742,7 +743,8 @@ class FusedTrainStep:
                 args.update(batch)
                 with _scopes.declared("cast.params"):
                     args = self._cast_compute(args)
-                outs, new_aux = prog.eval(args, aux, rng, True)
+                with tracing_over(self.mesh):
+                    outs, new_aux = prog.eval(args, aux, rng, True)
                 # aux (BN moving stats) must keep its dtype or the donated
                 # state changes signature between steps
                 new_aux = {k: v.astype(aux[k].dtype) if k in aux else v
@@ -880,7 +882,8 @@ class FusedTrainStep:
                 args.update(state["fixed"])
                 args.update(batch)
                 args = self._cast_compute(args)
-                outs, _ = prog.eval(args, state["aux"], rng, is_train)
+                with tracing_over(self.mesh):
+                    outs, _ = prog.eval(args, state["aux"], rng, is_train)
                 return outs
             mode = "train" if is_train else "eval"
             return cached_jit(fwd, name="fused:fwd_%s" % mode,

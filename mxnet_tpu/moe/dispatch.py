@@ -16,7 +16,14 @@ in ``multichip_report()``'s collective census.
 The drop-free layout (``capacity_factor <= 0``) has no buffer to
 scatter into: ``sort_rows`` gathers the ``T*k`` (token, choice) rows in
 expert order, ``grouped_matmul`` multiplies each expert's run of rows by
-that expert's matrix, ``combine_sorted`` gathers them back.  Forward AND
+that expert's matrix, ``combine_sorted`` gathers them back.
+``grouped_matmul`` is the one primitive here that is not pure jnp
+everywhere: a program lowered for a TPU, over one device, with bfloat16
+or float32 operands, rows a multiple of 256 and ``K``, ``N`` multiples
+of 128 runs the tiled Pallas kernel pair of ``moe/gmm.py`` (the tile
+table is ``gmm.tiles_for``, the tile -> group map ``group_tiles``, made
+once a layer); every other platform, mesh, dtype and shape runs
+``lax.ragged_dot``.  Forward AND
 backward are gathers through the plan's permutation and its inverse
 (each a ``custom_vjp``): the autodiff transpose of a gather is a
 scatter-add, which a TPU executes row by row.  Each is in bounds by
@@ -30,9 +37,10 @@ import jax
 import jax.numpy as jnp
 
 from ..trace import scopes as _scopes
+from . import gmm as _gmm
 
 __all__ = ["dispatch", "combine", "sort_rows", "combine_sorted",
-           "grouped_matmul"]
+           "grouped_matmul", "group_tiles"]
 
 
 def dispatch(x, slot, num_experts: int, capacity: int):
@@ -159,20 +167,28 @@ def combine_sorted(rows, order, slot, weight):
     return _combine_sorted(rows, order, slot, weight)
 
 
+# what a layer hands ``grouped_matmul`` as its group sizes: the sizes with
+# the kernels' tile -> group map where they can run, made once a layer
+group_tiles = _gmm.group_tiles
+
+
 def grouped_matmul(rows, w, group_sizes):
     """``rows`` (M, K) in expert order x stacked ``w`` (E, K, N) -> (M, N):
     rows ``sum(group_sizes[:e]) .. + group_sizes[e]`` meet ``w[e]``.
     Exactly M rows of work whatever the routing: an expert that got no
-    token costs nothing, one that got them all takes them all."""
-    # bfloat16 products are exact at any precision, and XLA:TPU's ragged
-    # dot refuses bfloat16 operands under a "highest" default ("Bad lhs
-    # type", jax 0.9.0): name the precision they run at anyway
-    precision = jax.lax.Precision.DEFAULT \
-        if rows.dtype == jnp.bfloat16 else None
-    return jax.lax.ragged_dot(rows, w, group_sizes.astype(jnp.int32),
-                              precision=precision)
+    token costs nothing, one that got them all takes them all.  Rows
+    behind the last group are left unwritten on a TPU.
+
+    Two lowerings, chosen from what the code can see (``moe/gmm.py``):
+    a program lowered for a TPU, over one device, with bfloat16 or
+    float32 operands of ``gmm.tiles_for``'s shapes runs the tiled Pallas
+    kernels ``ragged-dot-gmm`` / ``ragged-dot-tgmm`` with their own vjp;
+    every other platform, mesh and shape runs ``lax.ragged_dot``.
+    ``group_sizes`` may be ``group_tiles``' result."""
+    return _gmm.tiled_matmul(rows, w, group_sizes)
 
 
-# XLA:TPU writes the ragged dot's kernels itself and names them anew
-# ("ragged-dot-none"): the scope of their one caller, ``_moe_expert_ffn``
+# where ``lax.ragged_dot`` stays, XLA:TPU writes its kernels itself and
+# names them anew ("ragged-dot-none"): the scope of their one caller,
+# ``_moe_expert_ffn`` (this repo's kernels keep the caller's path)
 _scopes.adopt("ragged-dot", "moe_experts")

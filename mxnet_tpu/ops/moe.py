@@ -274,7 +274,7 @@ class MoEExpertFFNOp(OpDef):
                     out = jnp.einsum("ecd,edh->ech", h, w)
                     return out if b is None else out + b[:, None, :]
             else:
-                from ..moe.dispatch import grouped_matmul
+                from ..moe.dispatch import group_tiles, grouped_matmul
                 sizes = counts.astype(jnp.int32)
                 mine = None
                 if p.experts_held:
@@ -296,9 +296,11 @@ class MoEExpertFFNOp(OpDef):
                 x = own(x)
                 expert_of_row = None if p.no_bias else jnp.repeat(
                     jnp.arange(E), sizes, total_repeat_length=x.shape[0])
+                # one tile -> group map for the layer's nine products
+                groups = group_tiles(sizes, x.shape[0])
 
                 def linear(h, w, b):
-                    out = grouped_matmul(h, w, sizes)
+                    out = grouped_matmul(h, w, groups)
                     return own(out if b is None else out + jnp.take(
                         b, expert_of_row, axis=0))
             act = _act(p.act_type)

@@ -8,6 +8,8 @@ reference implemented as cudaMemcpy reductions and ps-lite RPCs.
 """
 from __future__ import annotations
 
+import contextlib
+import threading
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -17,7 +19,31 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 __all__ = ["make_mesh", "parse_mesh_spec", "mesh_from_env",
            "normalize_spec", "spec_axes", "validate_spec",
            "sharding_attrs", "dp_sharding", "replicated",
+           "tracing_over", "traced_devices",
            "PartitionSpec", "NamedSharding", "Mesh"]
+
+_tracing = threading.local()
+
+
+@contextlib.contextmanager
+def tracing_over(mesh: Optional[Mesh]):
+    """What a program's function enters around its body, so that the ops
+    it traces can see how many devices the program spans (one where
+    ``mesh`` is None): GSPMD shows a traced op neither the mesh nor its
+    operands' shardings."""
+    was = getattr(_tracing, "devices", 1)
+    _tracing.devices = 1 if mesh is None else int(mesh.devices.size)
+    try:
+        yield
+    finally:
+        _tracing.devices = was
+
+
+def traced_devices() -> int:
+    """Devices of the program being traced by this thread (1 outside
+    ``tracing_over``).  A Pallas call is one device's: an op with a
+    kernel lowering keeps its plain one where this is more than 1."""
+    return getattr(_tracing, "devices", 1)
 
 
 def make_mesh(axes: Sequence[Tuple[str, int]], devices=None) -> Mesh:

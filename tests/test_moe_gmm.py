@@ -1,0 +1,317 @@
+"""The grouped matmul's tiled Pallas kernel pair (ISSUE 38, tier-1).
+
+``moe/gmm.py``'s ``ragged-dot-gmm`` / ``ragged-dot-tgmm`` run here in
+the Pallas interpreter, held to ``lax.ragged_dot`` and its autodiff: the
+forward, the backward-data and the backward-weight product, in float32
+and bfloat16, over balanced groups, one group holding every row, several
+empty groups, and groups that sum to fewer rows than the matrix has.
+Which lowering a call gets (shape, platform, devices of the program) and
+how often a process traces the kernels are read from the jaxpr, the
+exported text and the counter ``moe:gmm_trace``.  Times and the chip's
+own numerics: ``tests/tpu/test_olmoe_tpu.py``."""
+import functools
+import importlib
+import re
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import mxnet_tpu as mx
+from mxnet_tpu.moe import MoEFeedForward, gmm
+from mxnet_tpu.parallel.mesh import make_mesh, tracing_over
+
+# the package's name ``dispatch`` is the bucket scatter, not the module
+dispatch = importlib.import_module("mxnet_tpu.moe.dispatch")
+
+ROWS, K, N = 1024, 256, 128       # four row tiles; one k and one n tile
+
+LAYOUTS = {
+    "balanced": [256, 256, 256, 256],
+    "one_holds_all": [0, 1024, 0, 0],
+    "empty_groups": [0, 100, 0, 300, 0, 0, 624, 0],
+    # a rank's share: the groups end inside the second tile, the last
+    # two tiles are nobody's and are never visited
+    "fewer_rows_than_m": [100, 0, 200, 17],
+}
+TOLERANCE = {"float32": 2e-6, "bfloat16": 1e-2}
+
+
+@functools.lru_cache(maxsize=None)
+def _both_ways(layout, dtype_name, wide=False):
+    """(kernels, ragged_dot) each as {product: array}: the output and
+    both gradients of ``own(grouped(own(rows), w))`` against a fixed
+    cotangent, ``own`` being ``_moe_expert_ffn``'s select of the rows
+    that belong to a group."""
+    dtype = jnp.dtype(dtype_name)
+    sizes = jnp.asarray(LAYOUTS[layout], jnp.int32)
+    k, n = (1280, 1280) if wide else (K, N)
+    rng = np.random.RandomState(len(layout))
+    rows = jnp.asarray(rng.randn(ROWS, k), dtype)
+    w = jnp.asarray(rng.randn(len(sizes), k, n) / np.sqrt(k), dtype)
+    ct = jnp.asarray(rng.randn(ROWS, n), dtype)
+    mine = (jnp.arange(ROWS) < sizes.sum())[:, None]
+
+    def own(x):
+        return jnp.where(mine, x, jnp.zeros((), x.dtype))
+
+    def run(matmul):
+        def loss(rows, w):
+            out = own(matmul(own(rows), w, sizes))
+            return (out.astype(jnp.float32)
+                    * ct.astype(jnp.float32)).sum(), out
+        (_, out), (d_rows, d_w) = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True))(rows, w)
+        return {"forward": out, "backward_data": d_rows,
+                "backward_weight": d_w}
+
+    return (run(functools.partial(gmm.tiled_matmul, interpret=True)),
+            run(gmm.ragged_matmul))
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("product", ["forward", "backward_data",
+                                     "backward_weight"])
+def test_kernels_match_ragged_dot(product, dtype, layout):
+    got, want = (x[product] for x in _both_ways(layout, dtype))
+    assert got.dtype == want.dtype and got.shape == want.shape
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    assert np.isfinite(got).all()
+    assert np.abs(got - want).max() <= TOLERANCE[dtype] * max(
+        np.abs(want).max(), 1.0)
+    if product == "backward_weight":
+        empty = [i for i, s in enumerate(LAYOUTS[layout]) if s == 0]
+        assert not got[empty].any(), "an expert without rows writes zeros"
+    elif layout == "fewer_rows_than_m":
+        assert not got[sum(LAYOUTS[layout]):].any()
+
+
+@pytest.mark.parametrize("product", ["forward", "backward_data",
+                                     "backward_weight"])
+def test_kernels_match_ragged_dot_over_several_k_and_n_tiles(product):
+    """``K`` and ``N`` of two tiles each (1280 = 2 x 640 in float32): the
+    accumulator across k steps, the output's n tiles, both read
+    transposed in the backward-data product."""
+    got, want = (np.asarray(x[product], np.float32)
+                 for x in _both_ways("empty_groups", "float32", wide=True))
+    assert gmm.tiles_for(ROWS, 1280, 1280, 8, jnp.float32) == (
+        gmm.ROW_TILE, 640, 640)
+    assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+
+
+@pytest.mark.parametrize("experts,tiles", [(1, 1), (4, 2), (8, 16), (64, 4)])
+def test_visits_cover_every_groups_rows_once_and_in_order(experts, tiles):
+    """The tile -> group map over random group sizes (balanced, skewed,
+    tile-aligned, summing to all, some or none of the rows): every row of
+    every group lies in exactly one visit's tile and group, no row behind
+    the groups in any; a tile's visits and a group's are consecutive;
+    every group, empty or not, has a visit; none beyond
+    ``m / tm + E - 1``."""
+    tm, rng = gmm.ROW_TILE, np.random.RandomState(experts + tiles)
+    m = tm * tiles
+    for trial in range(40):
+        p = np.exp(rng.randn(experts) * rng.choice([0.0, 1.0, 3.0]))
+        sizes = rng.multinomial(int(m * rng.choice([1.0, 1.0, 0.3, 0.0])),
+                                p / p.sum()).astype(np.int32)
+        if trial % 3 == 0:
+            sizes = sizes // tm * tm
+        t = gmm.group_tiles(jnp.asarray(sizes), m)
+        n = int(t.visits[0])
+        group, tile, off = (np.asarray(x) for x in (
+            t.group_of[:n], t.tile_of[:n], t.offsets))
+        assert n <= tiles + experts - 1
+        assert (np.diff(group) >= 0).all() and (np.diff(tile) >= 0).all()
+        assert sorted(set(group)) == list(range(experts))
+        assert tile.min() >= 0 and tile.max() < tiles
+        covered = np.zeros(m, int)
+        for g, i in zip(group, tile):
+            lo, hi = max(off[g], i * tm), min(off[g + 1], (i + 1) * tm)
+            assert hi > lo or sizes[g] == 0, (sizes, g, i)
+            covered[lo:max(lo, hi)] += 1
+        held = sizes.sum()
+        assert (covered[:held] == 1).all() and not covered[held:].any()
+
+
+def _expert_ffn(**params):
+    op = mx.ops.get_op("_moe_expert_ffn")
+    p = op.parse_params(dict({"num_hidden": 2 * N, "act_type": "silu",
+                              "gated": True, "no_bias": True}, **params))
+    return lambda *inputs: op.forward(p, list(inputs), [], None)[0]
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+def _primitives(fn, *args):
+    names = [e.primitive.name for e in _eqns(jax.make_jaxpr(fn)(*args).jaxpr)]
+    return names.count("pallas_call"), names.count("ragged_dot_general")
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """``grouped_matmul`` with its kernels in the Pallas interpreter."""
+    monkeypatch.setattr(dispatch, "grouped_matmul", functools.partial(
+        gmm.tiled_matmul, interpret=True))
+    return monkeypatch
+
+
+def test_a_ranks_share_reads_zero_behind_its_groups(interpreted):
+    """``_moe_expert_ffn`` holding experts 2..5 of 8 over 1024 sorted
+    rows of which 600 are its own: through the kernels the output and
+    the rows' gradient behind the groups are exactly zero, and all of it
+    is what ``ragged_dot`` gives."""
+    rng = np.random.RandomState(3)
+    counts = jnp.asarray([50, 70, 150, 0, 250, 200, 204, 100], jnp.float32)
+    x = jnp.asarray(rng.randn(ROWS, K), jnp.float32)
+    ws = [jnp.asarray(rng.randn(4, *s) / 16, jnp.float32)
+          for s in ((K, 2 * N), (K, 2 * N), (2 * N, K))]
+    ffn = _expert_ffn(experts_held=4, first_expert=2)
+
+    def grad():
+        """A new function each time: jax caches a function's trace."""
+        def loss(x, *ws):
+            out = ffn(x, *ws, counts)
+            return jnp.square(out).sum(), out
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3), has_aux=True)
+
+    assert _primitives(grad(), x, *ws) == (9, 0)
+    (_, out), grads = jax.jit(grad())(x, *ws)
+    assert not np.asarray(out[600:]).any()
+    assert not np.asarray(grads[0][600:]).any()
+    assert np.asarray(out[:600]).any() and np.asarray(grads[0][:600]).any()
+    interpreted.setattr(dispatch, "grouped_matmul", lambda rows, w, groups:
+                        gmm.ragged_matmul(rows, w, groups.sizes))
+    assert _primitives(grad(), x, *ws) == (0, 9)
+    (_, want), want_grads = jax.jit(grad())(x, *ws)
+    for a, b in zip((out,) + grads, (want,) + want_grads):
+        assert np.abs(np.asarray(a) - np.asarray(b)).max() <= 2e-5 * max(
+            np.abs(np.asarray(b)).max(), 1.0)
+
+
+@pytest.mark.parametrize("why,rows,k,n,dtype,devices", [
+    ("rows_not_whole_tiles", 640, K, N, "float32", 1),
+    ("k_not_whole_lanes", ROWS, 192, N, "float32", 1),
+    ("n_not_whole_lanes", ROWS, K, 64, "float32", 1),
+    ("a_dtype_without_a_kernel", ROWS, K, N, "float16", 1),
+    ("a_program_over_two_devices", ROWS, K, N, "float32", 2),
+    ("the_kernels", ROWS, K, N, "float32", 1),
+])
+def test_what_the_kernels_refuse_keeps_ragged_dot(why, rows, k, n, dtype,
+                                                  devices):
+    """The choice is static and made while the op is traced: a refused
+    call is ``ragged_dot_general`` in the jaxpr, forward and backward,
+    with no kernel beside it.  The last case is a call the kernels take:
+    three kernels, with ``ragged_dot`` and its autodiff as the other
+    branch of a choice by platform that the lowering makes."""
+    x = jnp.zeros((rows, k), dtype)
+    w = jnp.zeros((4, k, n), dtype)
+    sizes = jnp.asarray([rows // 4] * 4, jnp.int32)
+
+    def both(x, w):
+        with tracing_over(make_mesh([("dp", devices)])):
+            groups = dispatch.group_tiles(sizes, rows)
+            grad = jax.grad(lambda x, w: dispatch.grouped_matmul(
+                x, w, groups).astype(jnp.float32).sum(), argnums=(0, 1))
+            return grad(x, w)
+
+    want = (3, 4) if why == "the_kernels" else (0, 3)
+    assert _primitives(both, x, w) == want
+
+
+@pytest.mark.parametrize("platform,custom_calls", [("cpu", 0), ("tpu", 3)])
+def test_the_platform_chooses_when_the_program_is_lowered(platform,
+                                                           custom_calls):
+    """Shapes the kernels take, exported for a CPU: no Mosaic call
+    (``ragged_dot``, which that platform spells out in plain operations);
+    for a TPU: the three kernels under the names the benchmark's
+    roofline reader sums (``ragged-dot*``)."""
+    x = jnp.zeros((ROWS, K), jnp.bfloat16)
+    w = jnp.zeros((4, K, N), jnp.bfloat16)
+    sizes = jnp.asarray([256] * 4, jnp.int32)
+    text = jax.export.export(jax.jit(jax.grad(
+        lambda x, w: jnp.square(dispatch.grouped_matmul(x, w, sizes).astype(
+            jnp.float32)).sum(), argnums=(0, 1))),
+        platforms=[platform])(x, w).mlir_module()
+    assert text.count("tpu_custom_call") == custom_calls
+    if platform == "tpu":
+        names = re.findall(r'kernel_name = "([^"]+)"', text)
+        assert sorted(names) == ["ragged-dot-gmm", "ragged-dot-gmm",
+                                 "ragged-dot-tgmm"], names
+
+
+def _routed_net(layers, tokens, dim, hidden, experts, k):
+    net = mx.sym.Variable("data")
+    for i in range(layers):
+        net = net + MoEFeedForward(
+            net, num_hidden=hidden, num_experts=experts, k=k,
+            capacity_factor=0.0, name="l%d_moe" % i, act_type="silu",
+            gated=True, no_bias=True, layer=i)
+    return mx.sym.LinearRegressionOutput(net, name="lro")
+
+
+def _one_fused_step(net, tokens, dim, seed):
+    rng = np.random.RandomState(seed)
+    mod = mx.mod.Module(net, context=mx.cpu(0), label_names=["lro_label"])
+    mod.bind(data_shapes=[("data", (tokens, dim))],
+             label_shapes=[("lro_label", (tokens, dim))])
+    mod.init_params(initializer=mx.init.Normal(0.05))
+    mod.init_optimizer(optimizer="sgd",
+                       optimizer_params={"learning_rate": 0.01})
+    assert mod._fused is not None
+    mod.forward_backward(mx.io.DataBatch(
+        data=[mx.nd.array(rng.randn(tokens, dim).astype(np.float32))],
+        label=[mx.nd.array(rng.randn(tokens, dim).astype(np.float32))],
+        pad=0))
+    mod.update()
+    assert np.isfinite(mod.get_outputs()[0].asnumpy()).all()
+
+
+def _gmm_traces(run):
+    was = mx.trace.enabled()
+    mx.trace.set_enabled(True)
+    try:
+        mark = time.perf_counter_ns()
+        run()
+        return mx.trace.counter_events(["moe:gmm_trace"], since_ns=mark)
+    finally:
+        mx.trace.set_enabled(was)
+
+
+def test_kernels_are_traced_once_a_signature_whatever_the_layers(
+        interpreted):
+    """Two fused modules (as the harness's reference check and ``fit``
+    are) of three routed layers each: 54 grouped matmuls, six kernel
+    signatures (gate and up share theirs), each traced once.  The shapes
+    (384 rows of 640) are no other test's of this process."""
+    net = _routed_net(3, 128, 640, 384, 4, 4)
+    # 128 tokens x 4 choices = 512 rows: two row tiles
+    traces = _gmm_traces(lambda: [_one_fused_step(net, 128, 640, seed)
+                                  for seed in (1, 2)])
+    assert len(traces) == 6, [e["id"] for e in traces]
+    assert sorted(k for e in traces for k in ("gmm", "gmm_t", "tgmm")
+                  if e["args"][k]) == ["gmm"] * 2 + ["gmm_t"] * 2 \
+        + ["tgmm"] * 2
+    assert {(e["args"]["tm"], e["args"]["tk"], e["args"]["tn"])
+            for e in traces} == {(gmm.ROW_TILE, 640, 384),
+                                 (gmm.ROW_TILE, 384, 640)}
+    assert len({e["id"] for e in traces}) == 6
+
+
+def test_a_graph_without_routed_experts_traces_no_kernel():
+    def dense():
+        net = mx.sym.FullyConnected(mx.sym.Variable("data"), num_hidden=640,
+                                    name="fc")
+        _one_fused_step(mx.sym.LinearRegressionOutput(net, name="lro"),
+                        128, 640, 5)
+    assert _gmm_traces(dense) == []

@@ -13,7 +13,8 @@ train step in float32 and one in bfloat16 (the gradient read back as
 ``(w - w') / lr``); and the configuration's own Adam step in bfloat16,
 where the token embedding takes the lazy sparse path.  The numbers go to
 ``chiprun_out/olmoe_parity.json`` after every phase, before anything is
-asserted.
+asserted.  Beside it: the attention kernel and the grouped-matmul kernel
+pair, each against its plain lowering at the cells' shapes.
 tests/tpu/conftest.py pins "highest" matmul precision, so float32 is
 float32 on both sides; bfloat16 operands are exact under it.
 """
@@ -288,6 +289,93 @@ def test_attention_kernel_matches_plain_blocks_at_the_cell_shape():
         q, k.at[:, -1].add(1.0), v.at[:, -1].add(-1.0), scale), np.float32)
     assert np.array_equal(moved[:, :-1], got[0][:, :-1])
     assert not np.array_equal(moved[:, -1], got[0][:, -1])
+
+
+# bfloat16 outputs of float32 sums taken in another order: a rounding or
+# two of the largest element (0.0016-0.0036 measured, PR 38)
+GMM_MAX_ERR_SHARE = 0.02
+
+
+def _group_sizes(rows, experts, spread, seed):
+    rng = np.random.RandomState(seed)
+    p = np.exp(spread * rng.standard_normal(experts))
+    return rng.multinomial(rows, p / p.sum()).astype(np.int32)
+
+
+@pytest.mark.parametrize("cell,m,k,n,experts,held,spread", [
+    # the cell's gate / up and down products, a router as uneven as the
+    # cell's (fullest expert 3.5 x the mean)
+    ("olmoe_gate_up", 131072, 2048, 1024, 64, 131072, 0.7),
+    ("olmoe_down", 131072, 1024, 2048, 64, 131072, 0.7),
+    # one expert-parallel rank's share: 3 % / 17 % of the rows in groups
+    ("kimi_gate_up", 32768, 2304, 1024, 8, 1024, 0.3),
+    ("glm_gate_up", 16384, 2048, 1536, 8, 2800, 1.0),
+])
+def test_grouped_matmul_kernels_match_ragged_dot_at_the_cells_shapes(
+        cell, m, k, n, experts, held, spread):
+    """``moe.dispatch.grouped_matmul`` at a cell's published shapes in
+    bfloat16 compiles to ``ragged-dot-gmm`` / ``ragged-dot-tgmm`` on the
+    chip; its output and both gradients over the rows that belong to a
+    group agree with ``lax.ragged_dot``'s on the same chip, and both
+    sides' times (forward; forward and backward) are printed."""
+    import importlib
+    import jax
+    import jax.numpy as jnp
+    import mxnet_tpu as mx
+    from mxnet_tpu.moe import gmm
+    dispatch = importlib.import_module("mxnet_tpu.moe.dispatch")
+    sizes = jnp.asarray(_group_sizes(held, experts, spread, 38))
+    key = jax.random.PRNGKey(38)
+    rows = jax.random.normal(key, (m, k), jnp.bfloat16)
+    w = (jax.random.normal(key, (experts, k, n), jnp.float32)
+         / np.sqrt(k)).astype(jnp.bfloat16)
+    ct = jax.random.normal(jax.random.PRNGKey(39), (m, n), jnp.bfloat16)
+    mine = (jnp.arange(m) < held)[:, None]
+
+    def own(x):
+        return jnp.where(mine, x, jnp.zeros((), x.dtype))
+
+    def passes(matmul):
+        def both(rows, w):
+            out, vjp = jax.vjp(
+                lambda rows, w: own(matmul(own(rows), w, sizes)), rows, w)
+            return (out,) + vjp(ct)
+        return (jax.jit(lambda rows, w: own(matmul(own(rows), w, sizes))),
+                jax.jit(both))
+
+    def ms(fn):
+        jax.block_until_ready(fn(rows, w))
+        t0 = time.perf_counter()
+        for _ in range(10):
+            out = fn(rows, w)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) * 100.0
+
+    mark = time.perf_counter_ns()
+    kernel = passes(dispatch.grouped_matmul)
+    plain = passes(gmm.ragged_matmul)
+    text = kernel[1].lower(rows, w).compile().as_text()
+    assert text.count("ragged-dot-gmm") >= 2 and "ragged-dot-tgmm" in text
+    assert "ragged-dot-none" not in text
+    assert "ragged-dot-gmm" not in plain[1].lower(rows, w).compile().as_text()
+    traced = mx.trace.counter_events(["moe:gmm_trace"], since_ns=mark)
+    got = [np.asarray(x, np.float32) for x in kernel[1](rows, w)]
+    want = [np.asarray(x, np.float32) for x in plain[1](rows, w)]
+    report = {
+        "cell": cell, "shape": [m, k, n, experts], "rows_in_groups": held,
+        "tiles": sorted({(e["args"]["tm"], e["args"]["tk"], e["args"]["tn"])
+                         for e in traced}),
+        "max_err_share": [float(np.abs(g - r).max() / np.abs(r).max())
+                          for g, r in zip(got, want)],
+        "kernel_ms": {"forward": ms(kernel[0]), "both": ms(kernel[1])},
+        "ragged_dot_ms": {"forward": ms(plain[0]), "both": ms(plain[1])}}
+    print("\nGMM_KERNEL_PARITY " + json.dumps(report), flush=True)
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(ROOT, "chiprun_out", "gmm_kernel_parity.jsonl"),
+              "a") as f:
+        f.write(json.dumps(report) + "\n")
+    assert all(np.isfinite(g).all() for g in got)
+    assert max(report["max_err_share"]) <= GMM_MAX_ERR_SHARE, report
 
 
 def test_the_cell_s_bound_module_runs_the_kernel():
