@@ -51,9 +51,11 @@ mx.symbol.CausalConv1D <- function(...) {
   mx.symbol.internal.create('CausalConv1D', list(...))
 }
 
-#' Causal multi-head self-attention over ``(B, T, H, Dh)`` query
+#' Masked multi-head self-attention over ``(B, T, H, Dh)`` query,
 #' @param scale scale parameter (default 0.0)
 #' @param layer layer parameter (default -1)
+#' @param mask mask parameter (default causal; one of causal, block_diffusion)
+#' @param block block parameter (default 0)
 #' @param name string, node name (auto when omitted)
 #' @return MXSymbol
 mx.symbol.CausalSelfAttention <- function(...) {
@@ -321,6 +323,7 @@ mx.symbol.Reshape <- function(...) {
 
 #' Rotary position embedding of ``(B, T, H, Dh)`` (Su et al. 2021),
 #' @param theta theta parameter (default 10000.0)
+#' @param period period parameter (default 0)
 #' @param name string, node name (auto when omitted)
 #' @return MXSymbol
 mx.symbol.RotaryEmbedding <- function(...) {

@@ -81,6 +81,19 @@ def find_prediction_heads(symbol):
             float(p.valid_thresh) if p.normalization == "valid" else None)
 
 
+def find_noise_head(symbol):
+    """The index of the output ``diffusion_noise_output``: the ``(3,)``
+    head a block-diffusion symbol groups on (``models.sdar_moe``): the
+    step's masked positions, positions, and the masked positions' summed
+    weights, behind a ``BlockGrad``.  None for a symbol without one."""
+    from ..models.sdar_moe import NOISE_HEAD
+    for i, (node, _) in enumerate(symbol._heads):
+        if not node.is_variable and node.name == NOISE_HEAD \
+                and getattr(node.op, "name", "") == "BlockGrad":
+            return i
+    return None
+
+
 class FusedTrainStep:
     """One donated XLA program per (shapes, dtypes): fwd+bwd+reduce+update.
 
@@ -239,6 +252,9 @@ class FusedTrainStep:
         # a second per-token loss head (a multi-token-prediction module):
         # its mean reaches the trace from the step's outputs too
         self.prediction_heads = find_prediction_heads(symbol)
+        # a block-diffusion symbol's noise head: what the step's labels
+        # masked, for the trace
+        self.noise_head = find_noise_head(symbol)
         self.moe_stats = None
         if self.moe_blocks:
             from ..moe.stats import MoeStats
@@ -668,6 +684,18 @@ class FusedTrainStep:
                        main=float(outs[main].asnumpy().mean()),
                        mtp=float(second.mean()) if second.size else 0.0,
                        weight=weight)
+
+    def note_diffusion_noise(self, outs) -> None:
+        """Feed the ``diffusion:noise`` trace counter, one sample a step,
+        from the step's noise head as the metric gets it: ``masked`` the
+        positions the step's labels gave a target, ``positions`` all of
+        them, ``weight_sum`` the masked positions' summed ``1 / t``.  One
+        host read of three numbers the metric update before this call
+        already waited for."""
+        masked, positions, weight_sum = (
+            float(x) for x in outs[self.noise_head].asnumpy())
+        _trace.counter("diffusion:noise", cat="train", masked=masked,
+                       positions=positions, weight_sum=weight_sum)
 
     # -- compiled programs ---------------------------------------------------
     def _make_step_fn(self):

@@ -1182,11 +1182,14 @@ class Module(BaseModule):
         head (``FusedTrainStep.note_outputs``), under a span of its own,
         ``fit:moe_load``, and the mean of a second per-token loss head
         beside the first's (``note_prediction_loss``) under
-        ``fit:mtp_loss``: nothing, and no span, where the fused step is
-        off or the symbol carries no such head."""
+        ``fit:mtp_loss``, and what a block-diffusion symbol's noise head
+        counted (``note_diffusion_noise``) under ``fit:diffusion_noise``:
+        nothing, and no span, where the fused step is off or the symbol
+        carries no such head; the last two only while tracing is on."""
         fused = self._fused
         if fused is None or not self._fused_live() \
-                or not (fused.moe_load_heads or fused.prediction_heads):
+                or not (fused.moe_load_heads or fused.prediction_heads
+                        or fused.noise_head is not None):
             return
         outs = self.get_outputs() if outputs is None else outputs
         if fused.moe_load_heads:
@@ -1195,6 +1198,9 @@ class Module(BaseModule):
         if fused.prediction_heads and _trace.enabled():
             with _trace.span("fit:mtp_loss", cat="train"):
                 fused.note_prediction_loss(outs)
+        if fused.noise_head is not None and _trace.enabled():
+            with _trace.span("fit:diffusion_noise", cat="train"):
+                fused.note_diffusion_noise(outs)
 
     def _outputs_in_flight(self):
         """The overlap hook of fit() and score(): the outputs of the

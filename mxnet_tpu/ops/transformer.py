@@ -17,7 +17,11 @@ is lowered for a TPU and the inputs are ones the kernel takes (bfloat16,
 attention under a causal mask (online softmax in VMEM, tiles above the
 diagonal skipped, a fused backward kernel) runs instead.  The code
 chooses from what it sees, no option does; ``attn:lowering`` records
-the choice.
+the choice.  Key and value may have fewer heads than the query (grouped
+queries: query head ``j`` reads key/value head ``j // (H / Hkv)``), and
+the mask is one of ``MASKS``: ``causal``, or ``block_diffusion`` over a
+doubled sequence ``[noised ; clean]`` (``block_diffusion_allowed``).
+Both lowerings take both, from the one definition of the mask.
 
 The bodies of ``CausalSelfAttention`` and ``SoftmaxCELoss`` run under a
 declared device scope (``attn.l<layer>``, ``lm_loss``; ``trace/scopes.py``)
@@ -107,16 +111,20 @@ def rms_norm(x, gamma, eps: float):
     return (x32 * lax.rsqrt(var + eps)).astype(x.dtype) * gamma.astype(x.dtype)
 
 
-def rotary_embedding(x, theta: float):
+def rotary_embedding(x, theta: float, period: int = 0):
     """Rotary position embedding of ``(B, T, H, Dh)`` at positions
-    ``0..T-1``, half-split pairing (dimension ``i`` rotates with
-    ``i + Dh/2``, as the ``olmoe``/``llama`` modelling code), angles in
-    float32."""
+    ``0..T-1``, or ``n mod period`` for row ``n`` where ``period`` is
+    given (a sequence laid out as copies of the same positions),
+    half-split pairing (dimension ``i`` rotates with ``i + Dh/2``, as the
+    ``olmoe``/``llama`` modelling code), angles in float32."""
     t, dh = x.shape[1], x.shape[3]
     half = dh // 2
     inv_freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32)
                                 * 2.0 / dh))
-    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    pos = jnp.arange(t, dtype=jnp.float32)
+    if period:
+        pos = (jnp.arange(t) % period).astype(jnp.float32)
+    ang = pos[:, None] * inv_freq[None, :]
     cos = jnp.cos(ang)[None, :, None, :]
     sin = jnp.sin(ang)[None, :, None, :]
     x32 = x.astype(jnp.float32)
@@ -126,11 +134,41 @@ def rotary_embedding(x, theta: float):
     return out.astype(x.dtype)
 
 
-def causal_attention(q, k, v, scale: float):
-    """Causal multi-head self-attention of ``(B, T, H, Dh)`` q and k and
-    ``(B, T, H, Dv)`` v -> ``(B, T, H, Dv)`` (the value heads may be
-    narrower or wider than the query's, as in latent attention); scores,
-    softmax and accumulation in float32.
+MASKS = ("causal", "block_diffusion")
+
+
+def block_diffusion_allowed(q_ids, k_ids, half: int, block: int):
+    """Whether query row ``q_ids`` reads key row ``k_ids`` of a doubled
+    sequence of ``2 * half`` rows: the noised copy, then the clean one,
+    row ``n`` at position ``n mod half`` in block ``(n mod half) //
+    block`` (the vectorised form of the block-diffusion objective,
+    arXiv:2503.09573).  A noised query sees the noised keys of its own
+    block, both directions, and the clean keys of earlier blocks; a clean
+    query sees the clean keys of its own and earlier blocks; nothing
+    else.  Operators only: the ids are numpy arrays where the kernel's
+    tiles are classified and traced arrays inside both lowerings."""
+    if half & (half - 1) or block & (block - 1):
+        q_blk, k_blk = (q_ids % half) // block, (k_ids % half) // block
+    else:
+        # powers of two: a mask and a shift where the vector unit would
+        # emulate two integer divisions an element of every partial tile
+        low, shift = half - 1, block.bit_length() - 1
+        q_blk, k_blk = (q_ids & low) >> shift, (k_ids & low) >> shift
+    q_clean, k_clean = q_ids >= half, k_ids >= half
+    return (k_clean & (k_blk < q_blk)) \
+        | ((q_clean == k_clean) & (k_blk == q_blk))
+
+
+def causal_attention(q, k, v, scale: float, mask: str = "causal",
+                     block: int = 0):
+    """Masked self-attention of ``(B, T, H, Dh)`` q, ``(B, T, Hkv, Dh)``
+    k and ``(B, T, Hkv, Dv)`` v -> ``(B, T, H, Dv)``; scores, softmax and
+    accumulation in float32.  The value heads may be narrower or wider
+    than the query's (latent attention); ``Hkv`` is ``H`` or a divisor of
+    it, and query head ``j`` then reads key/value head ``j // (H /
+    Hkv)``.  ``mask`` is ``causal`` (the name the function keeps) or
+    ``block_diffusion`` with its ``block`` length, over ``T = 2 x`` the
+    clean length (``block_diffusion_allowed``).
 
     One algorithm, two lowerings.  Inputs the flash-attention kernel
     takes (``_kernel_takes``) run it where the program is LOWERED for a
@@ -139,18 +177,37 @@ def causal_attention(q, k, v, scale: float):
     counter ``attn:lowering``: ``kernel`` 1 means this op's TPU lowering
     is the kernel (the lowered text of a CPU program holds the plain
     blocks all the same), ``plain`` 1 the plain blocks on every platform;
-    the track names dtype and shape."""
+    the track names dtype and shape, then ``/kv<Hkv>`` where the keys
+    have fewer heads and ``/<mask><block>`` where the mask is not the
+    causal one."""
+    t, h, hkv = q.shape[1], q.shape[2], k.shape[2]
+    if mask not in MASKS:
+        raise MXNetError("attention mask %r is none of %s" % (mask, MASKS))
+    if h % hkv or v.shape[2] != hkv:
+        raise MXNetError("attention: %d query heads over %d key and %d "
+                         "value heads" % (h, hkv, v.shape[2]))
+    if mask == "causal":
+        kind = ("causal", 0)
+    elif block > 0 and t % 2 == 0 and (t // 2) % block == 0:
+        kind = (mask, int(block))
+    else:
+        raise MXNetError("block_diffusion attention over %d rows in blocks "
+                         "of %d: the rows are two copies of whole blocks"
+                         % (t, block))
     kernel = _kernel_takes(q, k, v)
     trace.counter("attn:lowering", cat="ops",
-                  track="%s%s%s" % (q.dtype.name, list(q.shape),
-                                    "" if v.shape[3] == q.shape[3]
-                                    else "x%d" % v.shape[3]),
+                  track="%s%s%s%s%s" % (
+                      q.dtype.name, list(q.shape),
+                      "" if v.shape[3] == q.shape[3] else "x%d" % v.shape[3],
+                      "" if hkv == h else "/kv%d" % hkv,
+                      "" if mask == "causal" else "/%s%d" % kind),
                   kernel=int(kernel), plain=int(not kernel))
     if not kernel:
-        return _plain_attention(q, k, v, scale)
+        return _plain_attention(q, k, v, scale, kind)
     return _kernel_on_tpu(
-        lambda q, k, v: _flash_attention(q, k, v, scale),
-        lambda q, k, v: _plain_attention(q, k, v, scale), False, q, k, v)
+        lambda q, k, v: _flash_attention(q, k, v, scale, kind),
+        lambda q, k, v: _plain_attention(q, k, v, scale, kind), False,
+        q, k, v)
 
 
 def _kernel_tiles(t: int):
@@ -177,21 +234,49 @@ def _kernel_takes(q, k, v) -> bool:
             and t % tile == 0 and tile % piece == 0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _flash_attention(q, k, v, scale: float):
+@functools.lru_cache(maxsize=None)
+def _splash_block_diffusion():
+    """The library's computable-mask class for ``block_diffusion``: the
+    kernel forms a partial tile's mask from row and key ids inside the
+    tile, as it does the causal one, and never loads a ``(T, T)``
+    array.  Made once, at the first lowering that needs the library."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_mask as sm)
+
+    class BlockDiffusionMask(sm._ComputableMask):
+        def __init__(self, t: int, block: int):
+            self.block = block
+            super().__init__(
+                (t, t), lambda q_ids, k_ids: block_diffusion_allowed(
+                    q_ids, k_ids, t // 2, block))
+
+        def __eq__(self, other):
+            return isinstance(other, type(self)) \
+                and (self.shape, self.block) == (other.shape, other.block)
+
+        def __hash__(self):
+            return hash((type(self), self.shape, self.block))
+
+    return BlockDiffusionMask
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash_attention(q, k, v, scale: float, kind=("causal", 0)):
     """The TPU lowering: ``jax.experimental.pallas.ops.tpu.
-    splash_attention`` under a causal mask (online softmax in VMEM, key
-    tiles above the diagonal never visited, one fused backward kernel
-    that recomputes the scores tile by tile), tiled by
-    ``_kernel_tiles``.  The kernel takes one sequence as
-    ``(H, T, Dh)`` and has no scale of its own: the queries are scaled
-    first (one more bfloat16 rounding of q), the batch is a ``vmap``, and
-    three transposes go in and one out, with theirs in the backward
-    pass."""
-    return _flash_fwd(q, k, v, scale)[0]
+    splash_attention`` under the mask ``kind`` (online softmax in VMEM,
+    key tiles the mask empties never visited: those above the diagonal
+    for the causal mask, 40 of 64 at 8192 rows of ``block_diffusion``;
+    one fused backward kernel that recomputes the scores tile by tile),
+    tiled by ``_kernel_tiles``.  The kernel takes one sequence as
+    ``(H, T, Dh)`` against ``(Hkv, T, Dh)`` (a key/value head serves its
+    group of query heads in place: nothing is repeated) and has no scale
+    of its own: the queries are scaled first (one more bfloat16 rounding
+    of q), the batch is a ``vmap``, and three transposes go in and one
+    out, with theirs in the backward pass."""
+    return _flash_fwd(q, k, v, scale, kind)[0]
 
 
-def _flash_fwd(q, k, v, scale):
+def _flash_fwd(q, k, v, scale, kind):
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm)
     t, h = q.shape[1], q.shape[2]
@@ -200,8 +285,10 @@ def _flash_fwd(q, k, v, scale):
         block_q=tile, block_kv=tile, block_kv_compute=piece,
         block_q_dkv=tile, block_kv_dkv=tile, block_kv_dkv_compute=piece,
         use_fused_bwd_kernel=True)
+    one_head = sm.CausalMask((t, t)) if kind[0] == "causal" \
+        else _splash_block_diffusion()(t, kind[1])
     attend = sk.make_splash_mha_single_device(
-        sm.MultiHeadMask([sm.CausalMask((t, t))] * h), block_sizes=sizes)
+        sm.MultiHeadMask([one_head] * h), block_sizes=sizes)
 
     lanes = -q.shape[3] % 128        # query/key heads to whole 128 lanes
 
@@ -219,7 +306,7 @@ def _flash_fwd(q, k, v, scale):
         return jax.vjp(kernel, q, k, v)
 
 
-def _flash_bwd(scale, kernel_vjp, g):
+def _flash_bwd(scale, kind, kernel_vjp, g):
     with jax.default_matmul_precision("default"):
         return kernel_vjp(g)
 
@@ -227,12 +314,14 @@ def _flash_bwd(scale, kernel_vjp, g):
 _flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _plain_attention(q, k, v, scale: float):
+def _plain_attention(q, k, v, scale: float, kind=("causal", 0)):
     """The lowering of every platform, and the kernel's parity twin:
     queries in blocks of ATTN_BLOCK_Q; a block's scores live only inside
     its (checkpointed) body, in the forward and again in the backward
-    pass."""
+    pass.  Grouped queries are a reshape of the query heads to ``(Hkv,
+    H / Hkv)`` against the keys as they are."""
     b, t, h, dh = q.shape
+    hkv = k.shape[2]
     bq = min(ATTN_BLOCK_Q, t)
     nb = -(-t // bq)
     pad = nb * bq - t
@@ -242,16 +331,32 @@ def _plain_attention(q, k, v, scale: float):
     blocks = q.reshape(b, nb, bq, h, dh).transpose(1, 0, 2, 3, 4)
     k_pos = jnp.arange(t)
 
+    def allowed(i):
+        """(bq, T): which keys the queries of block ``i`` read."""
+        q_pos = i * bq + jnp.arange(bq)
+        if kind[0] == "causal":
+            return q_pos[:, None] >= k_pos[None, :]
+        return block_diffusion_allowed(q_pos[:, None], k_pos[None, :],
+                                       t // 2, kind[1])
+
     @jax.checkpoint
     def one_block(args):
         i, qi = args
-        s = jnp.einsum("bqhd,bkhd->bhqk", qi, k,
+        if hkv == h:
+            # the statements, and their order, of the op before it took
+            # groups: an older symbol's step lowers to the text it had
+            s = jnp.einsum("bqhd,bkhd->bhqk", qi, k,
+                           preferred_element_type=jnp.float32) * scale
+            s = jnp.where(allowed(i)[None, None], s, jnp.float32(-1e30))
+            p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+            return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+        s = jnp.einsum("bqngd,bknd->bngqk",
+                       qi.reshape(b, bq, hkv, h // hkv, dh), k,
                        preferred_element_type=jnp.float32) * scale
-        q_pos = i * bq + jnp.arange(bq)
-        s = jnp.where((q_pos[:, None] >= k_pos[None, :])[None, None],
-                      s, jnp.float32(-1e30))
+        s = jnp.where(allowed(i)[None, None, None], s, jnp.float32(-1e30))
         p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-        return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+        return jnp.einsum("bngqk,bknd->bqngd", p, v).reshape(
+            b, bq, h, v.shape[3])
 
     if nb == 1:
         out = one_block((jnp.int32(0), blocks[0]))[None]
@@ -283,8 +388,12 @@ class RMSNormOp(OpDef):
 @register_op("RotaryEmbedding", hint="rotary")
 class RotaryEmbeddingOp(OpDef):
     """Rotary position embedding of ``(B, T, H, Dh)`` (Su et al. 2021),
-    half-split pairing, positions ``0..T-1``."""
-    params = [Param("theta", float, default=10000.0)]
+    half-split pairing, positions ``0..T-1``; with ``period`` > 0 row
+    ``n`` is at position ``n mod period`` (a sequence made of copies of
+    the same positions, as the block-diffusion objective's ``[noised ;
+    clean]``)."""
+    params = [Param("theta", float, default=10000.0),
+              Param("period", int, default=0)]
 
     def infer_shape(self, p, in_shapes):
         d = in_shapes[0]
@@ -294,19 +403,28 @@ class RotaryEmbeddingOp(OpDef):
         return in_shapes, [d], []
 
     def forward(self, p, inputs, aux, ctx):
-        return [rotary_embedding(inputs[0], p.theta)]
+        return [rotary_embedding(inputs[0], p.theta, p.period)]
 
 
 @register_op("CausalSelfAttention", hint="attention")
 class CausalSelfAttentionOp(OpDef):
-    """Causal multi-head self-attention over ``(B, T, H, Dh)`` query
-    and key and ``(B, T, H, Dv)`` value (``Dv`` may differ from ``Dh``:
-    latent attention's 192 against 128, or equal it at 256 against 256
-    where the value heads are as wide as the nope and rope parts
-    together): ``softmax(q k^T * scale +
-    causal mask) v`` per head -> ``(B, T, H, Dv)``, softmax in float32,
-    scores never materialized whole.  ``scale`` 0 means ``Dh**-0.5``;
-    ``layer`` names the trace scope.
+    """Masked multi-head self-attention over ``(B, T, H, Dh)`` query,
+    ``(B, T, Hkv, Dh)`` key and ``(B, T, Hkv, Dv)`` value (``Dv`` may
+    differ from ``Dh``: latent attention's 192 against 128, or equal it
+    at 256 against 256 where the value heads are as wide as the nope and
+    rope parts together): ``softmax(q k^T * scale + mask) v`` per head
+    -> ``(B, T, H, Dv)``, softmax in float32, scores never materialized
+    whole.  ``scale`` 0 means ``Dh**-0.5``; ``layer`` names the trace
+    scope.
+
+    ``Hkv`` is ``H`` or a whole divisor of it (grouped queries: query
+    head ``j`` reads key/value head ``j // (H / Hkv)``).  ``mask`` is
+    ``causal`` (the default, and the op's name) or ``block_diffusion``
+    with ``block`` > 0: ``T`` is then two copies of one sequence, the
+    noised one then the clean one, and a row reads what
+    ``block_diffusion_allowed`` says.  One op and one inner function for
+    both masks, because the two lowerings, their choice and their
+    counter are the same code with another mask object handed to each.
 
     Which lowering runs is ``causal_attention``'s choice, from the
     platform the program is lowered for and the inputs: bfloat16 with
@@ -318,7 +436,9 @@ class CausalSelfAttentionOp(OpDef):
     other shape and every other platform are the plain query blocks.
     The counter ``attn:lowering`` records it per bind."""
     params = [Param("scale", float, default=0.0),
-              Param("layer", int, default=-1)]
+              Param("layer", int, default=-1),
+              Param("mask", str, default="causal", enum=MASKS),
+              Param("block", int, default=0)]
 
     def list_arguments(self, p):
         return ["query", "key", "value"]
@@ -334,20 +454,22 @@ class CausalSelfAttentionOp(OpDef):
             if len(s) != 4:
                 raise MXNetError("CausalSelfAttention: %s must be (batch, "
                                  "seq, heads, head_dim), got %r" % (name, s))
-        if tuple(k) != tuple(q):
+        if (tuple(k[:2]), k[3]) != (tuple(q[:2]), q[3]) \
+                or k[2] < 1 or q[2] % k[2]:
             raise MXNetError("CausalSelfAttention: key %r differs from "
-                             "query %r" % (tuple(k), tuple(q)))
-        if tuple(v[:3]) != tuple(q[:3]):
+                             "query %r by more than a whole divisor of "
+                             "its heads" % (tuple(k), tuple(q)))
+        if (tuple(v[:2]), v[2]) != (tuple(q[:2]), k[2]):
             raise MXNetError("CausalSelfAttention: value %r differs from "
-                             "query %r before the head size"
-                             % (tuple(v), tuple(q)))
-        return [q, k, v], [v], []
+                             "query %r before the heads, or from key %r in "
+                             "its heads" % (tuple(v), tuple(q), tuple(k)))
+        return [q, k, v], [tuple(q[:3]) + (v[3],)], []
 
     def forward(self, p, inputs, aux, ctx):
         q, k, v = inputs
         scale = p.scale or float(q.shape[-1]) ** -0.5
         with layer_scope("attn", p.layer):
-            return [causal_attention(q, k, v, scale)]
+            return [causal_attention(q, k, v, scale, p.mask, p.block)]
 
 
 def _softmax_ce(logits, label, ignore=None):

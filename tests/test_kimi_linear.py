@@ -417,25 +417,30 @@ def test_sigmoid_router_bias_moves_the_choice_and_not_the_weights():
 
 
 def _share_block(E, k, H, D, held, first, scale):
+    """One rank's share; ``scale`` None is the renormalised softmax
+    router with no selection bias and no shared expert."""
+    router = dict(score="softmax") if scale is None else dict(
+        score="sigmoid", scale=scale, bias_rate=1e-3, shared_hidden=H)
     return MoEFeedForward(mx.sym.Variable("data"), num_hidden=H,
                           num_experts=E, k=k, capacity_factor=0.0,
                           name="moe", act_type="silu", gated=True,
-                          no_bias=True, renormalize=True, score="sigmoid",
-                          scale=scale, bias_rate=1e-3, shared_hidden=H,
-                          output_dim=D, experts_held=held,
-                          first_expert=first)
+                          no_bias=True, renormalize=True, output_dim=D,
+                          experts_held=held, first_expert=first, **router)
 
 
 @pytest.mark.parametrize("config,E,k,held,scale", [
     ("kimi-linear-48b-a3b", 16, 4, 4, 2.446),
-    ("glm-4.7-flash", 16, 2, 2, 1.8)],
-    ids=["kimi-4-ranks-of-4", "glm-8-ranks-of-2"])
+    ("glm-4.7-flash", 16, 2, 2, 1.8),
+    ("sdar-30b-a3b", 128, 8, 16, None)],
+    ids=["kimi-4-ranks-of-4", "glm-8-ranks-of-2", "sdar-8-ranks-of-16"])
 def test_the_shares_add_up_to_the_uncut_layer(config, E, k, held, scale):
     """E = 16 experts over 4 ranks of 4 (8 ranks of 2, as the GLM
-    configuration's eight): each rank's output (its held experts' part
-    plus the shared expert), summed, with the shared expert counted
-    once, is that configuration's reference layer with all 16 held; and
-    each rank's output is the reference given the same share."""
+    configuration's eight; 128 over 8 ranks of 16 under a renormalised
+    softmax with neither bias nor shared expert, as the SDAR
+    configuration's): each rank's output (its held experts' part plus
+    the shared expert), summed, with the shared expert counted once, is
+    that configuration's reference layer with all experts held; and each
+    rank's output is the reference given the same share."""
     REF = manifest.load_module("reference", config)
     rng = np.random.RandomState(5)
     T, D, H = 40, 12, 10
@@ -443,20 +448,25 @@ def test_the_shares_add_up_to_the_uncut_layer(config, E, k, held, scale):
     full = {"moe_gate_weight": rng.randn(E, D),
             "moe_experts_i2h_gate_weight": 0.5 * rng.randn(E, D, H),
             "moe_experts_i2h_weight": 0.5 * rng.randn(E, D, H),
-            "moe_experts_h2o_weight": 0.5 * rng.randn(E, H, D),
-            "moe_shared_i2h_gate_weight": 0.5 * rng.randn(H, D),
-            "moe_shared_i2h_weight": 0.5 * rng.randn(H, D),
-            "moe_shared_h2o_weight": 0.5 * rng.randn(D, H)}
+            "moe_experts_h2o_weight": 0.5 * rng.randn(E, H, D)}
+    if scale is not None:
+        full.update({"moe_shared_i2h_gate_weight": 0.5 * rng.randn(H, D),
+                     "moe_shared_i2h_weight": 0.5 * rng.randn(H, D),
+                     "moe_shared_h2o_weight": 0.5 * rng.randn(D, H)})
     full = {n: v.astype(np.float32) for n, v in full.items()}
     bias = (0.3 * rng.randn(E)).astype(np.float32)
     m = {"num_experts": E, "experts_per_tok": k, "routed_scale": scale}
-    p = {n: jnp.asarray(v) for n, v in full.items()}
-    p["moe_dispatch_select_bias"] = jnp.asarray(bias)
+    state = {} if scale is None else \
+        {"moe_dispatch_select_bias": jnp.asarray(bias)}
+    p = dict({n: jnp.asarray(v) for n, v in full.items()}, **state)
+    shared = np.zeros((T, D), np.float32)
     with jax.default_matmul_precision("highest"):
-        whole, counts = REF.moe(p, "", jnp.asarray(x), m)
-        shared = REF.swiglu(jnp.asarray(x), *(
-            p["moe_shared_%s_weight" % n]
-            for n in ("i2h_gate", "i2h", "h2o")))
+        # (output, ..., choices per expert), whatever lies between
+        whole, *_, counts = REF.moe(p, "", jnp.asarray(x), m)
+        if scale is not None:
+            shared = REF.swiglu(jnp.asarray(x), *(
+                p["moe_shared_%s_weight" % n]
+                for n in ("i2h_gate", "i2h", "h2o")))
     total = np.zeros((T, D), np.float32)
     for first in range(0, E, held):
         mine = {n: (v[first:first + held] if "experts" in n else v)
@@ -466,20 +476,21 @@ def test_the_shares_add_up_to_the_uncut_layer(config, E, k, held, scale):
         exe.arg_dict["data"][:] = x
         for n, v in mine.items():
             exe.arg_dict[n][:] = v
-        exe.aux_dict["moe_dispatch_select_bias"][:] = bias
+        if scale is not None:
+            exe.aux_dict["moe_dispatch_select_bias"][:] = bias
         exe.forward(is_train=False)
         out = exe.outputs[0].asnumpy()
         with jax.default_matmul_precision("highest"):
-            want, _ = REF.moe(
-                {**{n: jnp.asarray(v) for n, v in mine.items()},
-                 "moe_dispatch_select_bias": jnp.asarray(bias)}, "",
-                jnp.asarray(x), dict(m, experts_held=held,
-                                     first_expert=first))
+            want = REF.moe(
+                dict({n: jnp.asarray(v) for n, v in mine.items()}, **state),
+                "", jnp.asarray(x), dict(m, experts_held=held,
+                                         first_expert=first))[0]
         assert np.abs(out - np.asarray(want)).max() \
             <= 1e-4 * np.abs(np.asarray(want)).max()
         # evaluation does not move the bias
-        assert np.array_equal(
-            exe.aux_dict["moe_dispatch_select_bias"].asnumpy(), bias)
+        if scale is not None:
+            assert np.array_equal(
+                exe.aux_dict["moe_dispatch_select_bias"].asnumpy(), bias)
         total += out - np.asarray(shared)
     total += np.asarray(shared)
     assert np.asarray(counts).sum() == T * k
