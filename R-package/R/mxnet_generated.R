@@ -1210,6 +1210,14 @@ mx.nd.moe_expert_ffn <- function(..., ctx = mx.cpu()) {
   mx.nd.internal.invoke('_moe_expert_ffn', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
 }
 
+#' Imperative _moe_share_ffn: 1 NDArray input(s), 0 scalar(s)
+#' @return MXNDArray
+mx.nd.moe_share_ffn <- function(..., ctx = mx.cpu()) {
+  a <- list(...)
+  is.nd <- vapply(a, function(x) inherits(x, 'MXNDArray'), logical(1))
+  mx.nd.internal.invoke('_moe_share_ffn', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
+}
+
 #' Imperative _mul: 2 NDArray input(s), 0 scalar(s)
 #' @return MXNDArray
 mx.nd.mul <- function(..., ctx = mx.cpu()) {

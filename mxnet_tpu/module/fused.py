@@ -652,21 +652,30 @@ class FusedTrainStep:
         sample a block: max, mean, empty experts, routed, held, dropped)
         from one step's outputs, given as the metric gets them.
         ``held`` counts the routed choices that fell on experts this
-        rank holds (all of them where it holds all).  One host read of
-        the ``(blocks, E + 1)`` load head, which the metric update
-        before this call already waited for."""
+        rank holds (all of them where it holds all).  A rank's share
+        also says ``bound``, the static row bound its sorted layout is
+        sized by (``moe.dispatch.held_rows_bound``, the op's own rule):
+        ``held <= bound`` says the step ran the block over ``bound``
+        rows, and not over all that were routed.  One host read of the
+        ``(blocks, E + 1)`` load head, which the metric update before
+        this call already waited for."""
+        from ..moe.dispatch import held_rows_bound
         idx, blocks = self.moe_load_heads
         for block, row in zip(blocks, outs[idx].asnumpy()):
             counts, dropped = row[:-1], float(row[-1])
             self.moe_stats.note_counts(block, counts, dropped)
-            _trace.counter("moe:load", cat="moe", track=block,
-                           max=float(counts.max()),
-                           mean=float(counts.mean()),
-                           empty=int((counts == 0).sum()),
-                           routed=float(counts.sum()),
-                           held=float(counts[self.moe_blocks[block].held]
-                                      .sum()),
-                           dropped=dropped)
+            spec = self.moe_blocks[block]
+            sample = dict(max=float(counts.max()),
+                          mean=float(counts.mean()),
+                          empty=int((counts == 0).sum()),
+                          routed=float(counts.sum()),
+                          held=float(counts[spec.held].sum()),
+                          dropped=dropped)
+            held = spec.held.stop - spec.held.start
+            if held < spec.num_experts:
+                sample["bound"] = float(held_rows_bound(
+                    sample["routed"], spec.num_experts, held))
+            _trace.counter("moe:load", cat="moe", track=block, **sample)
 
     def note_prediction_loss(self, outs) -> None:
         """Feed the ``mtp:loss`` trace counter, one sample a step, from

@@ -15,10 +15,13 @@ Layers of the subsystem:
 * ``dispatch``  capacity-bucketed dispatch/combine as pure-jnp
                 primitives (THE scatter choke point — see the
                 ``moe-raw-scatter`` lint rule), and the drop-free
-                layout: rows sorted by expert, grouped matmuls
+                layout: rows sorted by expert, grouped matmuls, and
+                the static row bound of one rank's share
+                (``held_rows_bound``)
 * ``layer``     ``MoEFeedForward`` symbol block over the
                 ``_moe_dispatch`` / ``_moe_expert_ffn`` /
-                ``_moe_combine`` ops, ``with_aux_loss`` head attach
+                ``_moe_combine`` ops (``_moe_share_ffn`` for a rank's
+                share), ``with_aux_loss`` head attach
 * ``detect``    graph-side discovery (``find_moe_blocks``) feeding the
                 fused step's program descriptor + stats registration
 * ``stats``     ``MoeStats`` behind ``mx.profiler.moe_report()``

@@ -33,6 +33,9 @@ again.
 """
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -40,7 +43,16 @@ from ..trace import scopes as _scopes
 from . import gmm as _gmm
 
 __all__ = ["dispatch", "combine", "sort_rows", "combine_sorted",
-           "grouped_matmul", "group_tiles"]
+           "grouped_matmul", "group_tiles", "held_rows_bound"]
+
+# a rank's sorted layout holds this many times its balanced share of the
+# rows before it falls back to all T*k (``held_rows_bound``; PERF.md, PR 40)
+HELD_ROWS_SLACK = 4
+# ... and is bounded at all only where the bound saves this many rows: the
+# device's choice between the bound and all rows is a conditional, at which
+# the step's fusions and prefetches stop (~1 ms a layer on a v5e, what the
+# layer's passes over about this many rows cost; PERF.md, PR 40)
+BOUND_WORTH_ROWS = 16384
 
 
 def dispatch(x, slot, num_experts: int, capacity: int):
@@ -103,30 +115,71 @@ def _moved(values, place):
     return jax.lax.sort((place, values), num_keys=1)[1]
 
 
+def held_rows_bound(rows: int, num_experts: int, experts_held: int) -> int:
+    """The static row bound ``R`` of one expert-parallel rank's sorted
+    layout: ``rows`` = ``T*k`` choices over ``num_experts`` experts of
+    which the rank holds ``experts_held`` give it ``B = rows *
+    experts_held / num_experts`` rows under a balanced router, and
+    ``R`` is ``HELD_ROWS_SLACK * B`` rounded up to whole row tiles of
+    the grouped-matmul kernels.  Where that saves fewer than
+    ``BOUND_WORTH_ROWS`` of the ``rows`` it is ``rows``: no bound.  One
+    rule from what the code sees: the op (``_moe_share_ffn``) sizes its
+    passes by it, and ``FusedTrainStep.note_outputs`` reports it as
+    ``bound`` of the ``moe:load`` sample.  A rank that holds every expert
+    has ``rows``."""
+    rows = int(rows)
+    if not experts_held or experts_held >= num_experts:
+        return rows
+    tile = _gmm.ROW_TILE
+    balanced = rows * int(experts_held) / float(num_experts)
+    bound = tile * int(math.ceil(HELD_ROWS_SLACK * balanced / tile))
+    return bound if rows - bound >= BOUND_WORTH_ROWS else rows
+
+
 @jax.custom_vjp
-def _sort_rows(x, order, slot):
+def _sort_rows(x, order, slot, held):
     k = slot.shape[1]
     return _rows(x, order // k)
 
 
-def _sort_rows_fwd(x, order, slot):
-    return _sort_rows(x, order, slot), slot
+def _sort_rows_fwd(x, order, slot, held):
+    return _sort_rows(x, order, slot, held), (slot, held)
 
 
-def _sort_rows_bwd(slot, g):
+def _sort_rows_bwd(res, g):
+    slot, held = res
     T, k = slot.shape
     # token t's gradient: the sum of its k sorted rows' gradients
     dx = _rows(g, slot.reshape(T * k)).reshape(T, k, -1)
-    return dx.sum(axis=1).astype(g.dtype), None, None
+    if held is not None:
+        # ``g`` may be a window of the sorted rows: a choice whose row
+        # lies outside it reads a clipped row, and must count for nothing
+        dx = jnp.where(((slot >= 0) & (slot < held))[..., None], dx,
+                       jnp.zeros((), dx.dtype))
+    return dx.sum(axis=1).astype(g.dtype), None, None, None
 
 
 _sort_rows.defvjp(_sort_rows_fwd, _sort_rows_bwd)
 
 
-def sort_rows(x, order, slot):
+def sort_rows(x, order, slot, held=None, window=None):
     """``(T, D)`` tokens -> the ``(T*k, D)`` rows of a ``SortedPlan``:
-    row ``r`` is token ``order[r] // k``."""
-    return _sort_rows(x, order, slot)
+    row ``r`` is token ``order[r] // k``.  A rank's share gives
+    ``held``, the number of rows its experts got (the plan's first), and
+    ``window = (lo, n)``: sorted rows ``lo .. lo + n - 1`` only, and the
+    choices whose row is not a held one of them add nothing to the
+    tokens' gradient."""
+    if window is not None:
+        lo, n = window
+        order, slot, held = order[lo:lo + n], slot - lo, \
+            jnp.minimum(held - lo, n)
+    return _sort_rows(x, order, slot, held)
+
+
+def _picked_sum(rows, slot, weight):
+    T, k = slot.shape
+    picked = _rows(rows, slot.reshape(T * k)).reshape(T, k, -1)
+    return picked, (picked * weight[..., None].astype(rows.dtype)).sum(axis=1)
 
 
 @jax.custom_vjp
@@ -135,11 +188,9 @@ def _combine_sorted(rows, order, slot, weight):
 
 
 def _combine_sorted_fwd(rows, order, slot, weight):
-    T, k = slot.shape
     # the gathered rows are what the backward pass needs of ``rows``:
     # saved, they are not gathered a second time
-    picked = _rows(rows, slot.reshape(T * k)).reshape(T, k, -1)
-    out = (picked * weight[..., None].astype(rows.dtype)).sum(axis=1)
+    picked, out = _picked_sum(rows, slot, weight)
     return out, (picked, order, slot, weight)
 
 
@@ -157,13 +208,59 @@ def _combine_sorted_bwd(res, g):
 _combine_sorted.defvjp(_combine_sorted_fwd, _combine_sorted_bwd)
 
 
-def combine_sorted(rows, order, slot, weight):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _combine_held(rows, order, slot, weight, lo):
+    return _combine_held_fwd(rows, order, slot, weight, lo)[0]
+
+
+def _combine_held_fwd(rows, order, slot, weight, lo):
+    # sorted rows lo .. lo + n - 1: a choice outside them reads a clipped
+    # row and weighs nothing.  Saved: the rows themselves, n of them, and
+    # not the T*k gathered ones
+    at = slot - lo
+    inside = (at >= 0) & (at < rows.shape[0])
+    out = _picked_sum(rows, at, jnp.where(inside, weight, 0.0))[1]
+    return out, (rows, order, slot, weight)
+
+
+def _combine_held_bwd(lo, res, g):
+    rows, order, slot, weight = res
+    T, k = slot.shape
+    hi = lo + rows.shape[0]
+    w_sorted = _moved(weight.reshape(T * k), slot.reshape(T * k))[lo:hi]
+    # both gradients on the sorted side, over the window's rows: a
+    # weight's is its row's product with the token's cotangent, brought
+    # to (token, choice) order by the key-value sort; behind the held
+    # rows ``rows`` is zero, outside the window the padding: exact zeros
+    g_sorted = _rows(g, order[lo:hi] // k)
+    d_rows = g_sorted * w_sorted[:, None].astype(g.dtype)
+    d_weight = (rows.astype(jnp.float32)
+                * g_sorted.astype(jnp.float32)).sum(axis=-1)
+    d_weight = _moved(jnp.pad(d_weight, (lo, T * k - hi)), order)
+    return d_rows.astype(rows.dtype), None, None, \
+        d_weight.reshape(T, k).astype(weight.dtype)
+
+
+_combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
+
+
+def combine_sorted(rows, order, slot, weight, share_from=None):
     """``(T*k, O)`` expert outputs in sorted order -> ``(T, O)``: each
     token's k rows, weighted by its gate values and summed.  ``order``
     and ``slot`` are the plan's permutation and its inverse: the forward
     pass gathers through ``slot``, the backward pass through ``order``,
     and no permutation is found again (the backward pass brings the
-    ``T*k`` weights, scalars, to sorted order by one key-value sort)."""
+    ``T*k`` weights, scalars, to sorted order by one key-value sort).
+
+    ``share_from = lo``: a rank's share, whose held rows come first and
+    whose other rows are zero with weight 0, and ``rows`` are sorted
+    rows ``lo .. lo + n - 1`` of the ``T*k``: a choice outside them reads
+    a clipped row, finite, times 0.  The backward pass saves ``rows``
+    (not the ``T*k`` gathered ones) and forms both gradients over the
+    ``n`` sorted rows, so a choice outside them, and an absent one,
+    gets exactly 0 for its weight."""
+    if share_from is not None:
+        return _combine_held(rows, order, slot, weight, int(share_from))
     return _combine_sorted(rows, order, slot, weight)
 
 

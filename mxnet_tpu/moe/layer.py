@@ -1,7 +1,9 @@
 """``MoEFeedForward``: the routed-expert block at the symbol level.
 
 One call builds gate -> ``_moe_dispatch`` -> ``_moe_expert_ffn`` ->
-``_moe_combine`` and returns the combined ``(T, D)`` output symbol.
+``_moe_combine`` (for one expert-parallel rank's share: gate ->
+``_moe_dispatch`` -> ``_moe_share_ffn``) and returns the combined
+``(T, D)`` output symbol.
 The load-balance aux loss stays an un-consumed extra output of the
 dispatch node until ``with_aux_loss(net)`` groups ``MakeLoss`` heads
 onto the final symbol — at which point the fused train step's vjp
@@ -70,7 +72,10 @@ def MoEFeedForward(data, num_hidden: int, num_experts: int, k: int = 2,
     that chose another expert are left out of the grouped matmuls and
     of the output (no code stands in for the other ranks or their
     exchange; the shares of all ranks, with the shared expert once, sum
-    to the whole layer's output).  Returns the
+    to the whole layer's output).  A share's gather, experts and combine
+    are one node, ``_moe_share_ffn``, whose sorted-row passes are sized
+    by a static bound on the rows the rank holds, with the rows behind
+    it as the exact fallback (``moe.dispatch.held_rows_bound``).  Returns the
     combined output symbol; recover the aux-loss / counts heads with
     ``aux_loss_symbols`` / ``count_symbols`` or attach them in one move
     with ``with_aux_loss``.
@@ -95,18 +100,26 @@ def MoEFeedForward(data, num_hidden: int, num_experts: int, k: int = 2,
 
     row3 = "%s,None,None" % expert_axis
     row2 = "%s,None" % expert_axis
-    args = [disp[0]]
+    weights = []
     for stem in (["i2h_gate"] if gated else []) + ["i2h", "h2o"]:
-        args.append(expert_var(stem + "_weight", row3))
+        weights.append(expert_var(stem + "_weight", row3))
         if not no_bias:
-            args.append(expert_var(stem + "_bias", row2))
-    args.append(disp[_COUNTS_IDX])
-    ffn = _sym._moe_expert_ffn(*args, num_hidden=num_hidden,
-                               output_dim=output_dim, act_type=act_type,
-                               no_bias=no_bias, gated=gated,
-                               name=name + "_experts", **scope, **share)
-    out = _sym._moe_combine(ffn, disp[1], disp[2], disp[_ORDER_IDX],
-                            name=name + "_combine", **scope)
+            weights.append(expert_var(stem + "_bias", row2))
+    ffn = dict(num_hidden=num_hidden, output_dim=output_dim,
+               act_type=act_type, no_bias=no_bias, gated=gated, **scope,
+               **share)
+    if experts_held:
+        # a rank's share: gather, experts and combine are one node, sized
+        # by a static bound on the rows it holds (ops/moe.py); the
+        # dispatch node's sorted rows are not read
+        out = _sym._moe_share_ffn(
+            data, disp[1], disp[2], disp[_ORDER_IDX], disp[_COUNTS_IDX],
+            *weights, name=name + "_share", **ffn)
+    else:
+        rows = _sym._moe_expert_ffn(disp[0], *weights, disp[_COUNTS_IDX],
+                                    name=name + "_experts", **ffn)
+        out = _sym._moe_combine(rows, disp[1], disp[2], disp[_ORDER_IDX],
+                                name=name + "_combine", **scope)
     if not shared_hidden:
         return out
     if not output_dim:

@@ -1,4 +1,6 @@
-"""MoE ops: ``_moe_dispatch`` / ``_moe_expert_ffn`` / ``_moe_combine``.
+"""MoE ops: ``_moe_dispatch`` / ``_moe_expert_ffn`` / ``_moe_combine``,
+and ``_moe_share_ffn``, the last two in one node for one expert-parallel
+rank's share.
 
 The symbol-level surface of ``mxnet_tpu.moe`` (ISSUE 19).  The routing
 math and the expert-buffer scatter/gather live in ``moe.router`` /
@@ -25,15 +27,20 @@ the rank of their data, so a pass that re-pins a dispatch node's
 capacity (``MoEServeParityPass``) need touch nothing else.
 
 Each body runs under a ``jax.named_scope`` (``moe_route``,
-``moe_experts``, ``moe_combine``, with ``.l<layer>``).
+``moe_experts``, ``moe_combine``, with ``.l<layer>``);
+``_moe_share_ffn`` enters the three around its gather, its experts and
+its combine.
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
-from ..base import MXNetError
+from ..base import MXNetError, _AttrDict
 from ..moe.router import drop_free
 from .registry import OpDef, Param, register_op
 
@@ -188,6 +195,108 @@ class MoEDispatchOp(OpDef):
                           if ctx.is_train else aux[0]]
 
 
+def _layers(p, tensors):
+    """``[(weight, bias or None)]`` of an expert FFN's stacked inputs."""
+    tensors = list(tensors)
+    if p.no_bias:
+        return [(w, None) for w in tensors]
+    return list(zip(tensors[0::2], tensors[1::2]))
+
+
+def _ffn(p, x, layers, linear):
+    act = _act(p.act_type)
+    if p.gated:
+        (wg, bg), (w1, b1), (w2, b2) = layers
+        h = act(linear(x, wg, bg)) * linear(x, w1, b1)
+    else:
+        (w1, b1), (w2, b2) = layers
+        h = act(linear(x, w1, b1))
+    return linear(h, w2, b2)
+
+
+def _held_sizes(p, counts):
+    """The group sizes of the experts this rank holds (all, where
+    ``experts_held`` is 0), of the dispatch node's ``counts``."""
+    sizes = counts.astype(jnp.int32)
+    if p.experts_held:
+        sizes = sizes[p.first_expert:p.first_expert + p.experts_held]
+    return sizes
+
+
+def _sorted_ffn(p, x, layers, counts, window=None):
+    """The expert FFN over sorted rows ``x`` ``(rows, D)``: grouped
+    matmuls whose group sizes are the held experts' ``counts``.  The
+    rows are all ``T*k`` of the plan, or a rank's sorted rows ``lo .. lo
+    + n - 1`` (``window = (lo, n)``, ``lo`` no further than its held
+    rows reach): then each group's part inside the window."""
+    from ..moe.dispatch import group_tiles, grouped_matmul
+    sizes = _held_sizes(p, counts)
+    if window is not None:
+        lo, n = window
+        ends = jnp.cumsum(sizes)
+        sizes = jnp.maximum(jnp.minimum(ends, lo + n)
+                            - jnp.maximum(ends - sizes, lo), 0)
+    mine = None
+    if p.experts_held:
+        # the dispatch node sorted this rank's rows first: the
+        # groups are its experts', the rows behind them no one's
+        mine = (jnp.arange(x.shape[0]) < sizes.sum())[:, None]
+    E = sizes.shape[0]
+
+    def own(rows):
+        """Rows that belong to no group read exactly zero, in
+        this pass and (the select's transpose) in the backward
+        one: a grouped matmul leaves them unwritten, which on a
+        TPU is whatever the buffer held."""
+        return rows if mine is None else jnp.where(
+            mine, rows, jnp.zeros((), rows.dtype))
+
+    x = own(x)
+    expert_of_row = None if p.no_bias else jnp.repeat(
+        jnp.arange(E), sizes, total_repeat_length=x.shape[0])
+    # one tile -> group map for the layer's nine products
+    groups = group_tiles(sizes, x.shape[0])
+
+    def linear(h, w, b):
+        out = grouped_matmul(h, w, groups)
+        return own(out if b is None else out + jnp.take(
+            b, expert_of_row, axis=0))
+    return _ffn(p, x, layers, linear)
+
+
+def _ffn_arguments(p):
+    # *_weight / *_bias suffixes keep auto-created variables on the
+    # initializer's name-pattern dispatch (the RNN op's convention)
+    names = []
+    for stem in (["i2h_gate"] if p.gated else []) + ["i2h", "h2o"]:
+        names.append(stem + "_weight")
+        if not p.no_bias:
+            names.append(stem + "_bias")
+    return names
+
+
+def _ffn_shapes(p, E, D):
+    """The stacked tensors' shapes, in ``_ffn_arguments``' order."""
+    H, O = p.num_hidden, p.output_dim or D
+    shapes = []
+    for w, b in ([((E, D, H), (E, H))] if p.gated else []) \
+            + [((E, D, H), (E, H)), ((E, H, O), (E, O))]:
+        shapes.append(w)
+        if not p.no_bias:
+            shapes.append(b)
+    return shapes
+
+
+_FFN_PARAMS = [Param("num_hidden", int, required=True),
+               Param("output_dim", int, default=0),
+               Param("act_type", str, default="relu", enum=_ACTS),
+               Param("no_bias", bool, default=False),
+               Param("gated", bool, default=False),
+               Param("layer", int, default=-1),
+               Param("experts_held", int, default=0),
+               Param("first_expert", int, default=0)]
+
+
 @register_op("_moe_expert_ffn", hint="moe_experts")
 class MoEExpertFFNOp(OpDef):
     """Per-expert 2-layer FFN.  Plain: ``act(x @ w1[e] + b1[e]) @ w2[e]
@@ -201,24 +310,10 @@ class MoEExpertFFNOp(OpDef):
     the stacked weights hold that many experts, ``first_expert`` on, of
     the ``counts`` routed over (sorted rows only), and the rows behind
     their groups come out zero."""
-    params = [Param("num_hidden", int, required=True),
-              Param("output_dim", int, default=0),
-              Param("act_type", str, default="relu", enum=_ACTS),
-              Param("no_bias", bool, default=False),
-              Param("gated", bool, default=False),
-              Param("layer", int, default=-1),
-              Param("experts_held", int, default=0),
-              Param("first_expert", int, default=0)]
+    params = _FFN_PARAMS
 
     def list_arguments(self, p):
-        # *_weight / *_bias suffixes keep auto-created variables on the
-        # initializer's name-pattern dispatch (the RNN op's convention)
-        names = ["data"]
-        for stem in (["i2h_gate"] if p.gated else []) + ["i2h", "h2o"]:
-            names.append(stem + "_weight")
-            if not p.no_bias:
-                names.append(stem + "_bias")
-        return names + ["counts"]
+        return ["data"] + _ffn_arguments(p) + ["counts"]
 
     def implied_inputs(self, p, given):
         # counts are the dispatch node's: a caller that gives data alone,
@@ -243,16 +338,9 @@ class MoEExpertFFNOp(OpDef):
             raise MXNetError("_moe_expert_ffn: data must be (experts, "
                              "capacity, dim) or sorted (rows, dim), got %r"
                              % (d,))
-        H = p.num_hidden
-        O = p.output_dim or D
-        shapes = [d]
-        for w, b in ([((E, D, H), (E, H))] if p.gated else []) \
-                + [((E, D, H), (E, H)), ((E, H, O), (E, O))]:
-            shapes.append(w)
-            if not p.no_bias:
-                shapes.append(b)
-        return shapes + [cnt if cnt is not None else (E,)], \
-            [tuple(d[:-1]) + (O,)], []
+        return [d] + _ffn_shapes(p, E, D) \
+            + [cnt if cnt is not None else (E,)], \
+            [tuple(d[:-1]) + (p.output_dim or D,)], []
 
     def infer_type(self, p, in_types):
         t = in_types[0] if in_types[0] is not None else np.dtype(np.float32)
@@ -261,56 +349,162 @@ class MoEExpertFFNOp(OpDef):
 
     def forward(self, p, inputs, aux, ctx):
         x, counts = inputs[0], inputs[-1]
-        layers = list(inputs[1:-1])
-        if p.no_bias:
-            layers = [(w, None) for w in layers]
-        else:
-            layers = list(zip(layers[0::2], layers[1::2]))
+        layers = _layers(p, inputs[1:-1])
         # the whole body: the rank's row mask and its first select are
         # the experts' work too
         with _scope("moe_experts", p):
-            if x.ndim == 3:
-                def linear(h, w, b):
-                    out = jnp.einsum("ecd,edh->ech", h, w)
-                    return out if b is None else out + b[:, None, :]
-            else:
-                from ..moe.dispatch import group_tiles, grouped_matmul
-                sizes = counts.astype(jnp.int32)
-                mine = None
-                if p.experts_held:
-                    # the dispatch node sorted this rank's rows first: the
-                    # groups are its experts', the rows behind them no one's
-                    sizes = sizes[p.first_expert:p.first_expert
-                                  + p.experts_held]
-                    mine = (jnp.arange(x.shape[0]) < sizes.sum())[:, None]
-                E = sizes.shape[0]
+            if x.ndim == 2:
+                return [_sorted_ffn(p, x, layers, counts)]
 
-                def own(rows):
-                    """Rows that belong to no group read exactly zero, in
-                    this pass and (the select's transpose) in the backward
-                    one: a grouped matmul leaves them unwritten, which on a
-                    TPU is whatever the buffer held."""
-                    return rows if mine is None else jnp.where(
-                        mine, rows, jnp.zeros((), rows.dtype))
+            def linear(h, w, b):
+                out = jnp.einsum("ecd,edh->ech", h, w)
+                return out if b is None else out + b[:, None, :]
+            return [_ffn(p, x, layers, linear)]
 
-                x = own(x)
-                expert_of_row = None if p.no_bias else jnp.repeat(
-                    jnp.arange(E), sizes, total_repeat_length=x.shape[0])
-                # one tile -> group map for the layer's nine products
-                groups = group_tiles(sizes, x.shape[0])
 
-                def linear(h, w, b):
-                    out = grouped_matmul(h, w, groups)
-                    return own(out if b is None else out + jnp.take(
-                        b, expert_of_row, axis=0))
-            act = _act(p.act_type)
-            if p.gated:
-                (wg, bg), (w1, b1), (w2, b2) = layers
-                h = act(linear(x, wg, bg)) * linear(x, w1, b1)
-            else:
-                (w1, b1), (w2, b2) = layers
-                h = act(linear(x, w1, b1))
-            return [linear(h, w2, b2)]
+@register_op("_moe_share_ffn", hint="moe_share")
+class MoEShareFFNOp(OpDef):
+    """One expert-parallel rank's share of a drop-free routed layer in
+    ONE node: ``data`` (T, D) tokens, the dispatch node's ``weight``,
+    ``slot``, ``order`` and ``counts``, and the stacked weights of the
+    ``experts_held`` experts from ``first_expert`` on -> (T, O).  It
+    is ``sort_rows``, ``_moe_expert_ffn``'s sorted body and
+    ``combine_sorted`` (the same functions, under the scopes
+    ``moe_route``, ``moe_experts``, ``moe_combine``) over the first ``R``
+    sorted rows, ``R = moe.dispatch.held_rows_bound(T*k, E,
+    experts_held)``: the dispatch node sorted the held rows first, so
+    they are rows ``0 .. held - 1``, and where ``held <= R`` nothing
+    behind row ``R`` is anyone's.  Where ``held > R`` the same body runs
+    once more, over rows ``R .. T*k - 1``, and its output is added: one
+    ``lax.cond`` on the device whose other branch is zeros, so no
+    choice is dropped and the mathematics on the held rows is the same
+    (a token's rows on both sides of ``R`` are summed in two parts).
+    That branch is checkpointed: it saves the node's inputs, so a common
+    step writes no ``T*k``-sized zeros for it.  Both passes of the first
+    ``R`` rows run outside any conditional (XLA:TPU stops its fusions at
+    one, and its code for a conditional with kernels in both branches is
+    ten times either branch's: PERF.md, PR 40).  A program over more
+    than one device, and a geometry whose ``R`` is ``T*k`` (the bound
+    would save too few rows to be worth a conditional), run the three
+    nodes' statements over all rows with no ``cond``.  Token-sized all
+    the same: the combine's gather through ``slot`` and the row
+    gradient's.  A window's parts and the bounded node are jits of this
+    module (``_WINDOW_PARTS``, ``_share_bounded``): traced once a
+    process, whatever the layer, the pass and the module."""
+    params = _FFN_PARAMS
+
+    def list_arguments(self, p):
+        # the dispatch node's four before the stacked tensors: a graph's
+        # arguments are listed in the order its nodes' inputs reach them,
+        # and the router's weight came before the experts' in every
+        # saved graph
+        return ["data", "weight", "slot", "order", "counts"] \
+            + _ffn_arguments(p)
+
+    def infer_shape(self, p, in_shapes):
+        d, w, s, _, cnt = in_shapes[:5]
+        if not p.experts_held:
+            raise MXNetError("_moe_share_ffn is a rank's share: "
+                             "experts_held > 0 (the whole layer is "
+                             "_moe_expert_ffn between _moe_dispatch and "
+                             "_moe_combine)")
+        tk = w if w is not None else s
+        if d is None or tk is None:
+            return in_shapes, [None], []
+        if len(d) != 2 or len(tk) != 2 or tk[0] != d[0]:
+            raise MXNetError("_moe_share_ffn: data must be (tokens, dim) "
+                             "and weight, slot (tokens, k), got %r and %r"
+                             % (d, tk))
+        tk = tuple(tk)
+        return [d, tk, tk, (tk[0] * tk[1],), cnt] \
+            + _ffn_shapes(p, p.experts_held, d[1]), \
+            [(d[0], p.output_dim or d[1])], []
+
+    def infer_type(self, p, in_types):
+        t = in_types[0] if in_types[0] is not None else np.dtype(np.float32)
+        f32, i32 = np.dtype(np.float32), np.dtype(np.int32)
+        return [t, f32, i32, i32, f32] + [t] * len(_ffn_arguments(p)), \
+            [t], []
+
+    def forward(self, p, inputs, aux, ctx):
+        from ..moe.dispatch import held_rows_bound
+        from ..parallel.mesh import traced_devices
+        from .transformer import scope_prefix
+        slot, counts = inputs[2], inputs[4]
+        every = slot.shape[0] * slot.shape[1]
+        bound = held_rows_bound(every, counts.shape[0], p.experts_held)
+        if bound == every or traced_devices() > 1:
+            return [_share_window(p)(*inputs)]
+        return [_share_bounded(tuple(sorted(p.items())), scope_prefix(),
+                               bound, *inputs)]
+
+
+def _window_rows(params, window, x, order, slot, counts):
+    from ..moe.dispatch import sort_rows
+    held = _held_sizes(_AttrDict(params), counts).sum() if window else None
+    return sort_rows(x, order, slot, held, window)
+
+
+def _window_ffn(params, window, rows, counts, *stacked):
+    p = _AttrDict(params)
+    return _sorted_ffn(p, rows, _layers(p, stacked), counts, window)
+
+
+def _window_sum(params, window, rows, order, slot, weight):
+    from ..moe.dispatch import combine_sorted
+    return combine_sorted(rows, order, slot, weight,
+                          share_from=window[0] if window else None)
+
+
+# lint: allow(raw-jit) — never dispatched on their own: jits inside the step
+# program (as moe/gmm.py's), so that a process traces, differentiates and
+# lowers a window's three parts once a geometry and not once a layer, a
+# pass and a module: the layers' nodes differ by their scopes' names alone,
+# and those are entered around the calls
+_WINDOW_PARTS = tuple(jax.jit(part, static_argnums=(0, 1)) for part in
+                      (_window_rows, _window_ffn, _window_sum))
+
+
+def _share_window(p, window=None):
+    """``_moe_share_ffn``'s body over sorted rows ``lo .. lo + n - 1``
+    (``window = (lo, n)``), or, given none, over all of them: the three
+    nodes' statements."""
+    params = tuple(sorted((k, v) for k, v in p.items() if k != "layer"))
+    gather, experts, combine = _WINDOW_PARTS if window else (
+        _window_rows, _window_ffn, _window_sum)
+
+    def body(x, weight, slot, order, counts, *stacked):
+        with _scope("moe_route", p):
+            rows = gather(params, window, x, order, slot, counts)
+        with _scope("moe_experts", p):
+            rows = experts(params, window, rows, counts, *stacked)
+        with _scope("moe_combine", p):
+            return combine(params, window, rows, order, slot, weight)
+    return body
+
+
+# lint: allow(raw-jit) — never dispatched on its own: a jit inside the step
+# program, so that a process traces a node's body once (as moe/gmm.py's)
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _share_bounded(params, prefix, bound, *inputs):
+    """``_moe_share_ffn`` under a row bound below ``T*k``.  A jit of the
+    module, so that a process traces and differentiates a layer's two
+    passes once: a second module over the same symbol (a run's checking
+    module and its training one) finds them made.  ``params`` are the
+    node's, ``prefix`` the scope prefix it is traced under (the named
+    scopes inside are read when it is traced: both are in the key)."""
+    p = _AttrDict(params)
+    slot, counts = inputs[2], inputs[4]
+    every = slot.shape[0] * slot.shape[1]
+    out = _share_window(p, (0, bound))(*inputs)
+    # the rows behind the bound, where any is held: the same body over
+    # them, added.  The branch that runs nothing is all a common step
+    # pays for it (zeros out and, backward, zero gradients)
+    overflows = _held_sizes(p, counts).sum() > bound
+    return out + jax.lax.cond(
+        overflows,
+        jax.checkpoint(_share_window(p, (bound, every - bound))),
+        lambda *_: jnp.zeros_like(out), *inputs)
 
 
 @register_op("_moe_combine", hint="moe_combine")

@@ -69,7 +69,13 @@ def layer_scope(kind: str, layer):
     behind the prefix of the ``node_scope`` it runs in, if any:
     ``mtp.attn``."""
     name = kind if layer is None or layer < 0 else "%s.l%d" % (kind, layer)
-    return _scopes.declared(getattr(_scope, "prefix", "") + name)
+    return _scopes.declared(scope_prefix() + name)
+
+
+def scope_prefix() -> str:
+    """The prefix of the ``node_scope`` this thread is tracing under
+    (``mtp.``), or nothing."""
+    return getattr(_scope, "prefix", "")
 
 
 @contextlib.contextmanager

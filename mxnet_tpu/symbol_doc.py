@@ -83,6 +83,17 @@ class _moe_expert_ffnDoc(SymbolDoc):
     ``mx.moe.MoEFeedForward``."""
 
 
+class _moe_share_ffnDoc(SymbolDoc):
+    """One expert-parallel rank's share of a drop-free routed layer:
+    gather, expert FFN and combine in one node over ``data`` (T, D) and
+    the ``weight``, ``slot``, ``order`` and ``counts`` of the
+    ``_moe_dispatch`` node that routed it (``experts_held > 0`` on
+    both).  Its sorted-row passes run over a static bound on the rows the
+    rank holds, ``moe.dispatch.held_rows_bound(T*k, E, experts_held)``,
+    and once more over the rows behind it in a step whose held rows
+    pass the bound: nothing is dropped.  Built by ``mx.moe.MoEFeedForward``."""
+
+
 def build_doc(func_name: str, desc: str, arg_names, arg_types, arg_descs,
               key_var_num_args: str = "", ret_type: str = "Symbol"):
     """Assemble a numpy-style docstring from registry metadata (reference
