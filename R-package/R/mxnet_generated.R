@@ -54,8 +54,9 @@ mx.symbol.CausalConv1D <- function(...) {
 #' Masked multi-head self-attention over ``(B, T, H, Dh)`` query,
 #' @param scale scale parameter (default 0.0)
 #' @param layer layer parameter (default -1)
-#' @param mask mask parameter (default causal; one of causal, block_diffusion)
+#' @param mask mask parameter (default causal; one of causal, block_diffusion, sliding_window)
 #' @param block block parameter (default 0)
+#' @param window window parameter (default 0)
 #' @param name string, node name (auto when omitted)
 #' @return MXSymbol
 mx.symbol.CausalSelfAttention <- function(...) {

@@ -18,6 +18,7 @@ from .olmoe import olmoe_lm
 from .kimi_linear import kimi_linear_lm  # noqa: F401
 from .glm_moe_lite import glm_moe_lite_lm  # noqa: F401
 from .sdar_moe import sdar_moe_lm  # noqa: F401
+from .afmoe import afmoe_lm  # noqa: F401
 from .gru import gru_unroll, gru_cell, rnn_unroll, rnn_cell, GRUState, \
     GRUParam, RNNState, RNNParam
 

@@ -19,9 +19,12 @@ diagonal skipped, a fused backward kernel) runs instead.  The code
 chooses from what it sees, no option does; ``attn:lowering`` records
 the choice.  Key and value may have fewer heads than the query (grouped
 queries: query head ``j`` reads key/value head ``j // (H / Hkv)``), and
-the mask is one of ``MASKS``: ``causal``, or ``block_diffusion`` over a
-doubled sequence ``[noised ; clean]`` (``block_diffusion_allowed``).
-Both lowerings take both, from the one definition of the mask.
+the mask is one of ``MASKS``: ``causal``, ``block_diffusion`` over a
+doubled sequence ``[noised ; clean]`` (``block_diffusion_allowed``), or
+``sliding_window``: the causal mask cut to a query's own position and
+the ``window - 1`` before it (``sliding_window_allowed``).  Both
+lowerings take all three, from the one definition of each mask
+(``_mask_function``).
 
 The bodies of ``CausalSelfAttention`` and ``SoftmaxCELoss`` run under a
 declared device scope (``attn.l<layer>``, ``lm_loss``; ``trace/scopes.py``)
@@ -140,7 +143,7 @@ def rotary_embedding(x, theta: float, period: int = 0):
     return out.astype(x.dtype)
 
 
-MASKS = ("causal", "block_diffusion")
+MASKS = ("causal", "block_diffusion", "sliding_window")
 
 
 def block_diffusion_allowed(q_ids, k_ids, half: int, block: int):
@@ -165,16 +168,40 @@ def block_diffusion_allowed(q_ids, k_ids, half: int, block: int):
         | ((q_clean == k_clean) & (k_blk == q_blk))
 
 
+def sliding_window_allowed(q_ids, k_ids, window: int):
+    """Whether query row ``q_ids`` reads key row ``k_ids`` under a causal
+    window of ``window`` positions: the query's own and the ``window -
+    1`` before it, ``0 <= q - k < window``.  Operators only, as
+    ``block_diffusion_allowed``."""
+    return (q_ids >= k_ids) & (q_ids - k_ids < window)
+
+
+def _mask_function(kind, t: int):
+    """The mask ``kind`` = (name, size) over ``t`` rows as a function of
+    broadcastable ``(q_ids, k_ids)``: what the plain blocks evaluate on
+    traced ids and what the kernel's computable mask is made of."""
+    name, size = kind
+    if name == "causal":
+        return lambda q_ids, k_ids: q_ids >= k_ids
+    if name == "sliding_window":
+        return lambda q_ids, k_ids: sliding_window_allowed(q_ids, k_ids,
+                                                           size)
+    return lambda q_ids, k_ids: block_diffusion_allowed(q_ids, k_ids,
+                                                        t // 2, size)
+
+
 def causal_attention(q, k, v, scale: float, mask: str = "causal",
-                     block: int = 0):
+                     block: int = 0, window: int = 0):
     """Masked self-attention of ``(B, T, H, Dh)`` q, ``(B, T, Hkv, Dh)``
     k and ``(B, T, Hkv, Dv)`` v -> ``(B, T, H, Dv)``; scores, softmax and
     accumulation in float32.  The value heads may be narrower or wider
     than the query's (latent attention); ``Hkv`` is ``H`` or a divisor of
     it, and query head ``j`` then reads key/value head ``j // (H /
-    Hkv)``.  ``mask`` is ``causal`` (the name the function keeps) or
+    Hkv)``.  ``mask`` is ``causal`` (the name the function keeps),
     ``block_diffusion`` with its ``block`` length, over ``T = 2 x`` the
-    clean length (``block_diffusion_allowed``).
+    clean length (``block_diffusion_allowed``), or ``sliding_window``
+    with its ``window`` >= 1 (``sliding_window_allowed``); a window of
+    ``T`` or more is the causal mask and lowers, and is counted, as it.
 
     One algorithm, two lowerings.  Inputs the flash-attention kernel
     takes (``_kernel_takes``) run it where the program is LOWERED for a
@@ -184,8 +211,8 @@ def causal_attention(q, k, v, scale: float, mask: str = "causal",
     is the kernel (the lowered text of a CPU program holds the plain
     blocks all the same), ``plain`` 1 the plain blocks on every platform;
     the track names dtype and shape, then ``/kv<Hkv>`` where the keys
-    have fewer heads and ``/<mask><block>`` where the mask is not the
-    causal one."""
+    have fewer heads and ``/<mask><block or window>``
+    (``/sliding_window2048``) where the mask is not the causal one."""
     t, h, hkv = q.shape[1], q.shape[2], k.shape[2]
     if mask not in MASKS:
         raise MXNetError("attention mask %r is none of %s" % (mask, MASKS))
@@ -194,6 +221,11 @@ def causal_attention(q, k, v, scale: float, mask: str = "causal",
                          "value heads" % (h, hkv, v.shape[2]))
     if mask == "causal":
         kind = ("causal", 0)
+    elif mask == "sliding_window":
+        if window < 1:
+            raise MXNetError("sliding_window attention over a window of %d: "
+                             "a query reads at least itself" % window)
+        kind = (mask, int(window)) if window < t else ("causal", 0)
     elif block > 0 and t % 2 == 0 and (t // 2) % block == 0:
         kind = (mask, int(block))
     else:
@@ -206,7 +238,7 @@ def causal_attention(q, k, v, scale: float, mask: str = "causal",
                       q.dtype.name, list(q.shape),
                       "" if v.shape[3] == q.shape[3] else "x%d" % v.shape[3],
                       "" if hkv == h else "/kv%d" % hkv,
-                      "" if mask == "causal" else "/%s%d" % kind),
+                      "" if kind[0] == "causal" else "/%s%d" % kind),
                   kernel=int(kernel), plain=int(not kernel))
     if not kernel:
         return _plain_attention(q, k, v, scale, kind)
@@ -241,29 +273,29 @@ def _kernel_takes(q, k, v) -> bool:
 
 
 @functools.lru_cache(maxsize=None)
-def _splash_block_diffusion():
-    """The library's computable-mask class for ``block_diffusion``: the
+def _splash_mask():
+    """The library's computable-mask class over ``_mask_function``: the
     kernel forms a partial tile's mask from row and key ids inside the
     tile, as it does the causal one, and never loads a ``(T, T)``
-    array.  Made once, at the first lowering that needs the library."""
+    array.  Made once, at the first lowering that needs the library;
+    two masks of one ``(t, kind)`` are equal, so a process compiles one
+    kernel pair a kind however many layers use it."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_mask as sm)
 
-    class BlockDiffusionMask(sm._ComputableMask):
-        def __init__(self, t: int, block: int):
-            self.block = block
-            super().__init__(
-                (t, t), lambda q_ids, k_ids: block_diffusion_allowed(
-                    q_ids, k_ids, t // 2, block))
+    class KindMask(sm._ComputableMask):
+        def __init__(self, t: int, kind):
+            self.kind = kind
+            super().__init__((t, t), _mask_function(kind, t))
 
         def __eq__(self, other):
             return isinstance(other, type(self)) \
-                and (self.shape, self.block) == (other.shape, other.block)
+                and (self.shape, self.kind) == (other.shape, other.kind)
 
         def __hash__(self):
-            return hash((type(self), self.shape, self.block))
+            return hash((type(self), self.shape, self.kind))
 
-    return BlockDiffusionMask
+    return KindMask
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -271,8 +303,10 @@ def _flash_attention(q, k, v, scale: float, kind=("causal", 0)):
     """The TPU lowering: ``jax.experimental.pallas.ops.tpu.
     splash_attention`` under the mask ``kind`` (online softmax in VMEM,
     key tiles the mask empties never visited: those above the diagonal
-    for the causal mask, 40 of 64 at 8192 rows of ``block_diffusion``;
-    one fused backward kernel that recomputes the scores tile by tile),
+    for the causal mask, 40 of 64 at 8192 rows of ``block_diffusion``, 7
+    of 16 at 4096 rows under a window of 2048, where the causal mask
+    empties 6; one fused backward kernel that recomputes the scores tile
+    by tile),
     tiled by ``_kernel_tiles``.  The kernel takes one sequence as
     ``(H, T, Dh)`` against ``(Hkv, T, Dh)`` (a key/value head serves its
     group of query heads in place: nothing is repeated) and has no scale
@@ -292,7 +326,7 @@ def _flash_fwd(q, k, v, scale, kind):
         block_q_dkv=tile, block_kv_dkv=tile, block_kv_dkv_compute=piece,
         use_fused_bwd_kernel=True)
     one_head = sm.CausalMask((t, t)) if kind[0] == "causal" \
-        else _splash_block_diffusion()(t, kind[1])
+        else _splash_mask()(t, kind)
     attend = sk.make_splash_mha_single_device(
         sm.MultiHeadMask([one_head] * h), block_sizes=sizes)
 
@@ -340,10 +374,7 @@ def _plain_attention(q, k, v, scale: float, kind=("causal", 0)):
     def allowed(i):
         """(bq, T): which keys the queries of block ``i`` read."""
         q_pos = i * bq + jnp.arange(bq)
-        if kind[0] == "causal":
-            return q_pos[:, None] >= k_pos[None, :]
-        return block_diffusion_allowed(q_pos[:, None], k_pos[None, :],
-                                       t // 2, kind[1])
+        return _mask_function(kind, t)(q_pos[:, None], k_pos[None, :])
 
     @jax.checkpoint
     def one_block(args):
@@ -425,12 +456,15 @@ class CausalSelfAttentionOp(OpDef):
 
     ``Hkv`` is ``H`` or a whole divisor of it (grouped queries: query
     head ``j`` reads key/value head ``j // (H / Hkv)``).  ``mask`` is
-    ``causal`` (the default, and the op's name) or ``block_diffusion``
-    with ``block`` > 0: ``T`` is then two copies of one sequence, the
+    ``causal`` (the default, and the op's name), ``block_diffusion``
+    with ``block`` > 0 (``T`` is then two copies of one sequence, the
     noised one then the clean one, and a row reads what
-    ``block_diffusion_allowed`` says.  One op and one inner function for
-    both masks, because the two lowerings, their choice and their
-    counter are the same code with another mask object handed to each.
+    ``block_diffusion_allowed`` says), or ``sliding_window`` with
+    ``window`` >= 1: a row reads itself and the ``window - 1`` rows
+    before it (``sliding_window_allowed``; a window of ``T`` or more is
+    the causal mask).  One op and one inner function for all three,
+    because the two lowerings, their choice and their counter are the
+    same code with another mask object handed to each.
 
     Which lowering runs is ``causal_attention``'s choice, from the
     platform the program is lowered for and the inputs: bfloat16 with
@@ -444,13 +478,17 @@ class CausalSelfAttentionOp(OpDef):
     params = [Param("scale", float, default=0.0),
               Param("layer", int, default=-1),
               Param("mask", str, default="causal", enum=MASKS),
-              Param("block", int, default=0)]
+              Param("block", int, default=0),
+              Param("window", int, default=0)]
 
     def list_arguments(self, p):
         return ["query", "key", "value"]
 
     def infer_shape(self, p, in_shapes):
         q, k, v = in_shapes
+        if p.mask == "sliding_window" and p.window < 1:
+            raise MXNetError("CausalSelfAttention: mask sliding_window "
+                             "needs window >= 1, got %d" % p.window)
         if q is None and k is None and v is None:
             return in_shapes, [None], []
         # a shape that is not given is query's (or key's, or value's)
@@ -475,7 +513,8 @@ class CausalSelfAttentionOp(OpDef):
         q, k, v = inputs
         scale = p.scale or float(q.shape[-1]) ** -0.5
         with layer_scope("attn", p.layer):
-            return [causal_attention(q, k, v, scale, p.mask, p.block)]
+            return [causal_attention(q, k, v, scale, p.mask, p.block,
+                                     p.window)]
 
 
 def _softmax_ce(logits, label, ignore=None):

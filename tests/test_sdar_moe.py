@@ -105,17 +105,18 @@ def test_the_block_mask_is_its_definition_written_out_by_hand():
     assert not want[16:, :16].any() and want[21, 16:24].all()
     assert want.sum() == REF.allowed_pairs(16, 4) == 16 * 16 + 16 * 4
     # the library's mask object says the same tile by tile
-    splash = tf_ops._splash_block_diffusion()(32, 4)
+    splash = tf_ops._splash_mask()(32, ("block_diffusion", 4))
     assert np.array_equal(splash[0:32, 0:32], want)
-    assert splash == tf_ops._splash_block_diffusion()(32, 4)
-    assert splash != tf_ops._splash_block_diffusion()(32, 2)
-    assert hash(splash) == hash(tf_ops._splash_block_diffusion()(32, 4))
+    assert splash == tf_ops._splash_mask()(32, ("block_diffusion", 4))
+    assert splash != tf_ops._splash_mask()(32, ("block_diffusion", 2))
+    assert hash(splash) \
+        == hash(tf_ops._splash_mask()(32, ("block_diffusion", 4)))
 
 
 def test_the_tiles_the_kernel_visits_at_the_cells_shape():
     """8 x 8 tiles of 1024 over 8192 rows: 24 hold an allowed pair, 12
     of them whole."""
-    splash = tf_ops._splash_block_diffusion()(8192, 4)
+    splash = tf_ops._splash_mask()(8192, ("block_diffusion", 4))
     some = whole = 0
     for i in range(8):
         for j in range(8):
