@@ -210,9 +210,9 @@ def causal_attention(q, k, v, scale: float, mask: str = "causal",
     counter ``attn:lowering``: ``kernel`` 1 means this op's TPU lowering
     is the kernel (the lowered text of a CPU program holds the plain
     blocks all the same), ``plain`` 1 the plain blocks on every platform;
-    the track names dtype and shape, then ``/kv<Hkv>`` where the keys
-    have fewer heads and ``/<mask><block or window>``
-    (``/sliding_window2048``) where the mask is not the causal one."""
+    the track names dtype and shape, ``/kv<Hkv>`` where the keys have
+    fewer heads and ``/<mask><size>`` where the mask is not the causal
+    one; ``mask_form`` is ``kernel_mask``'s, ``none`` on the plain path."""
     t, h, hkv = q.shape[1], q.shape[2], k.shape[2]
     if mask not in MASKS:
         raise MXNetError("attention mask %r is none of %s" % (mask, MASKS))
@@ -233,13 +233,13 @@ def causal_attention(q, k, v, scale: float, mask: str = "causal",
                          "of %d: the rows are two copies of whole blocks"
                          % (t, block))
     kernel = _kernel_takes(q, k, v)
-    trace.counter("attn:lowering", cat="ops",
-                  track="%s%s%s%s%s" % (
-                      q.dtype.name, list(q.shape),
-                      "" if v.shape[3] == q.shape[3] else "x%d" % v.shape[3],
-                      "" if hkv == h else "/kv%d" % hkv,
-                      "" if kind[0] == "causal" else "/%s%d" % kind),
-                  kernel=int(kernel), plain=int(not kernel))
+    trace.counter("attn:lowering", cat="ops", track="%s%s%s%s%s" % (
+        q.dtype.name, list(q.shape),
+        "" if v.shape[3] == q.shape[3] else "x%d" % v.shape[3],
+        "" if hkv == h else "/kv%d" % hkv,
+        "" if kind[0] == "causal" else "/%s%d" % kind),
+        kernel=int(kernel), plain=int(not kernel),
+        mask_form=kernel_mask(kind, t)[0] if kernel else "none")
     if not kernel:
         return _plain_attention(q, k, v, scale, kind)
     return _kernel_on_tpu(
@@ -274,19 +274,19 @@ def _kernel_takes(q, k, v) -> bool:
 
 @functools.lru_cache(maxsize=None)
 def _splash_mask():
-    """The library's computable-mask class over ``_mask_function``: the
-    kernel forms a partial tile's mask from row and key ids inside the
-    tile, as it does the causal one, and never loads a ``(T, T)``
-    array.  Made once, at the first lowering that needs the library;
-    two masks of one ``(t, kind)`` are equal, so a process compiles one
-    kernel pair a kind however many layers use it."""
+    """The library's computable-mask class over ``kernel_mask``: the
+    kernel evaluates its function in EVERY tile it visits and never loads
+    a ``(T, T)`` array.  Made once; two masks of one ``(t, kind)`` are
+    equal: one kernel pair a kind a process, however many layers."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_mask as sm)
 
     class KindMask(sm._ComputableMask):
         def __init__(self, t: int, kind):
             self.kind = kind
-            super().__init__((t, t), _mask_function(kind, t))
+            _, rows, allowed = kernel_mask(kind, t)
+            super().__init__((t, t), allowed)
+            self.q_sequence = rows
 
         def __eq__(self, other):
             return isinstance(other, type(self)) \
@@ -305,8 +305,8 @@ def _flash_attention(q, k, v, scale: float, kind=("causal", 0)):
     key tiles the mask empties never visited: those above the diagonal
     for the causal mask, 40 of 64 at 8192 rows of ``block_diffusion``, 7
     of 16 at 4096 rows under a window of 2048, where the causal mask
-    empties 6; one fused backward kernel that recomputes the scores tile
-    by tile),
+    empties 6; a fused backward kernel that recomputes the scores tile by
+    tile; the mask formed in every visited tile, whole or partial),
     tiled by ``_kernel_tiles``.  The kernel takes one sequence as
     ``(H, T, Dh)`` against ``(Hkv, T, Dh)`` (a key/value head serves its
     group of query heads in place: nothing is repeated) and has no scale
@@ -515,6 +515,58 @@ class CausalSelfAttentionOp(OpDef):
         with layer_scope("attn", p.layer):
             return [causal_attention(q, k, v, scale, p.mask, p.block,
                                      p.window)]
+
+
+# Below the op, so that no line above its ``forward`` moves: a Mosaic
+# kernel's payload names its call sites by file and line and is part of
+# the compile cache's key, so the other kinds' kernels stay cache hits.
+def block_diffusion_codes(half: int, block: int):
+    """``block_diffusion_allowed`` with the query's side made on the
+    host, for ``half`` and ``block`` powers of two: ``(codes, allowed)``
+    with ``allowed(codes[q_ids], k_ids) == block_diffusion_allowed(q_ids,
+    k_ids, half, block)``, pair for pair.  A row's code is its block's
+    index over the doubled sequence with the two copies swapped
+    (``(n >> shift) ^ nb`` for ``nb = half / block`` blocks a copy: a
+    clean row's is ``0 .. nb - 1``, a noised row's ``nb .. 2 nb - 1``).
+    The keys' side is the same two operations on ``k_ids``; then a key
+    is read where the codes are equal (the same block of the same copy)
+    or the key's is under the row's block (an earlier clean block: a
+    noised key's code is ``nb`` or more, never under it).  Six passes
+    over a tile of scores where the function as it is written takes
+    fourteen."""
+    shift, nb = block.bit_length() - 1, half // block
+    codes = (np.arange(2 * half, dtype=np.int32) >> shift) ^ nb
+
+    def allowed(q_codes, k_ids):
+        k_codes = (k_ids >> shift) ^ nb
+        return (k_codes == q_codes) | (k_codes < (q_codes & (nb - 1)))
+
+    return codes, allowed
+
+
+def kernel_mask(kind, t: int):
+    """The form in which the TPU kernel gets the mask ``kind`` over ``t``
+    rows: ``(form, rows, allowed)``, the counter's ``mask_form``, one
+    int32 entry a row (the library's ``q_sequence``) and the function of
+    ``(rows' entries, key ids)`` that the kernel evaluates in every tile
+    it visits, whole or partial, forward and backward, on two int32
+    arrays the size of the tile's scores, and the host once a tile to
+    tell the empty, partial and whole tiles apart.  ``function``: the
+    row ids and ``_mask_function`` itself (the window's four passes).
+    ``codes``: ``block_diffusion_codes``, for a block mask whose sizes
+    are powers of two (as the function's own shift branch asks): the
+    same allowed pairs from six passes for fourteen, which is 3.7 ms a
+    layer at 8192 rows over 32 heads on a v5e (PERF.md, PR 42).
+    ``library``: the causal mask is the library's own object (one
+    compare); there are no rows and no function to hand over."""
+    name, size = kind
+    if name == "causal":
+        return "library", None, None
+    half = t // 2
+    if name == "block_diffusion" \
+            and not (half & (half - 1) or size & (size - 1)):
+        return ("codes",) + block_diffusion_codes(half, size)
+    return "function", np.arange(t, dtype=np.int32), _mask_function(kind, t)
 
 
 def _softmax_ce(logits, label, ignore=None):

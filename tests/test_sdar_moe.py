@@ -126,6 +126,159 @@ def test_the_tiles_the_kernel_visits_at_the_cells_shape():
     assert (some, whole) == (24, 12)
 
 
+# -- the form the kernel gets the mask in --------------------------------------
+@pytest.mark.parametrize("half, block", [(16, 4), (256, 4), (256, 64)])
+def test_the_row_codes_are_the_function_over_every_pair(half, block):
+    """``block_diffusion_codes``: the query's side made once on the host,
+    the keys' side a shift and an xor in the kernel; equal to
+    ``block_diffusion_allowed`` pair for pair, on numpy ids (the host's
+    tile classification) and on traced ones (the kernel's tiles)."""
+    ids = np.arange(2 * half, dtype=np.int32)
+    want = tf_ops.block_diffusion_allowed(ids[:, None], ids[None, :], half,
+                                          block)
+    assert np.array_equal(want, _by_hand(half, block))
+    codes, allowed = tf_ops.block_diffusion_codes(half, block)
+    assert codes.dtype == np.int32 and codes.shape == (2 * half,)
+    # a row's code: its block's index, the clean copy's first
+    nb = half // block
+    assert np.array_equal(codes[:half], nb + ids[:half] // block)
+    assert np.array_equal(codes[half:], ids[:half] // block)
+    assert np.array_equal(allowed(codes[:, None], ids[None, :]), want)
+    got = jax.jit(lambda c, k: allowed(c[:, None], k[None, :]))(
+        jnp.asarray(codes), jnp.asarray(ids))
+    assert got.dtype == jnp.bool_ and np.array_equal(np.asarray(got), want)
+    # what the kernel does with them: the codes tiled along the keys
+    # against an iota from a tile's first key
+    tile = jax.jit(lambda c: allowed(
+        jnp.tile(c[:, None], (1, half)),
+        half + jax.lax.broadcasted_iota(jnp.int32, (2 * half, half), 1)))(
+        jnp.asarray(codes))
+    assert np.array_equal(np.asarray(tile), want[:, half:])
+    # the mask object hands the kernel exactly these
+    splash = tf_ops._splash_mask()(2 * half, ("block_diffusion", block))
+    assert tf_ops.kernel_mask(("block_diffusion", block),
+                              2 * half)[0] == "codes"
+    assert np.array_equal(splash.q_sequence, codes)
+    assert np.array_equal(splash[0:2 * half, 0:2 * half], want)
+    assert np.array_equal(
+        splash.mask_function(splash.q_sequence[:, None], ids[None, :]), want)
+
+
+@pytest.mark.parametrize("i", range(8))
+def test_the_mask_object_at_the_cells_shape_tile_by_tile(i):
+    """Row ``i`` of the 8 x 8 tiles of 1024 over the cell's 8192 rows
+    (two copies of 4096 in blocks of 4): what ``__getitem__`` hands the
+    library's tile classification is the function as it is written, on
+    every pair of every tile."""
+    splash = tf_ops._splash_mask()(8192, ("block_diffusion", 4))
+    assert splash.q_sequence.shape == (8192,)
+    rows = np.arange(i * 1024, (i + 1) * 1024)
+    visited = []
+    for j in range(8):
+        cols = np.arange(j * 1024, (j + 1) * 1024)
+        tile = splash[i * 1024:(i + 1) * 1024, j * 1024:(j + 1) * 1024]
+        assert np.array_equal(tile, tf_ops.block_diffusion_allowed(
+            rows[:, None], cols[None, :], 4096, 4)), (i, j)
+        if tile.any():
+            visited.append((j, bool(tile.all())))
+    # a noised row of tiles: its own diagonal tile (partial), the clean
+    # tiles of earlier blocks (whole) and the clean diagonal one
+    # (partial); a clean row: the clean tiles to its diagonal
+    if i < 4:
+        want = [(i, False)] + [(4 + j, True) for j in range(i)] \
+            + [(4 + i, False)]
+    else:
+        want = [(j, True) for j in range(4, i)] + [(i, False)]
+    assert visited == want
+
+
+@pytest.mark.parametrize("half, block", [(12, 3), (24, 8), (48, 6),
+                                         (192, 4)])
+def test_sizes_that_are_no_powers_of_two_keep_the_function(half, block):
+    """The coded form is the shift branch's: where ``half`` or ``block``
+    is no power of two the kernel's mask object carries the row ids and
+    ``block_diffusion_allowed`` itself, divisions and all."""
+    t, kind = 2 * half, ("block_diffusion", block)
+    assert tf_ops.kernel_mask(kind, t)[0] == "function"
+    splash = tf_ops._splash_mask()(t, kind)
+    ids = np.arange(t)
+    assert np.array_equal(splash.q_sequence, ids)
+    assert splash.q_sequence.dtype == np.int32
+    want = _by_hand(half, block)
+    assert np.array_equal(splash[0:t, 0:t], want)
+    assert np.array_equal(
+        splash.mask_function(ids[:, None], ids[None, :]), want)
+    # and the other kinds' form does not depend on their size
+    assert tf_ops.kernel_mask(("causal", 0), t)[0] == "library"
+    assert tf_ops.kernel_mask(("sliding_window", block), t)[0] == "function"
+    assert np.array_equal(
+        tf_ops._splash_mask()(t, ("sliding_window", block)).q_sequence, ids)
+
+
+def test_one_kernel_pair_a_kind_a_process_under_the_coded_form():
+    """Two mask objects of one ``(t, kind)`` are equal and hash alike
+    whatever their form, so the library's ``process_mask`` answers the
+    second layer's from its cache with the FIRST one's function: one
+    static argument, one kernel pair a kind a process."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_mask as sm, splash_attention_mask_info as mi)
+    make = tf_ops._splash_mask()
+    # shapes no other test of this file hands to process_mask
+    coded = [make(1024, ("block_diffusion", 8)) for _ in range(2)]
+    plain = [make(768, ("block_diffusion", 8)) for _ in range(2)]
+    for a, b in (coded, plain):
+        assert a is not b and a == b and hash(a) == hash(b)
+        assert a.mask_function is not b.mask_function
+    assert coded[0] != plain[0]
+    assert coded[0] != make(1024, ("block_diffusion", 4))
+    assert coded[0] != make(1024, ("sliding_window", 8))
+    for a, b in (coded, plain):
+        before = mi._process_mask.cache_info()
+        first = mi.process_mask(sm.MultiHeadMask([a] * 4), (128, 128))
+        again = mi.process_mask(sm.MultiHeadMask([b] * 4), (128, 128))
+        after = mi._process_mask.cache_info()
+        assert after.misses == before.misses + 1
+        assert after.hits == before.hits + 1
+        assert again[1] is first[1] is a.mask_function
+        assert np.array_equal(first[0].q_sequence, a.q_sequence)
+        # a computable mask: nothing to load, whatever the form
+        assert first[0].partial_mask_blocks is None
+        assert first[0].mask_next is None
+
+
+@pytest.mark.parametrize("mask, size, t, dtype, form", [
+    ("causal", 0, 512, "bfloat16", "library"),
+    ("sliding_window", 128, 512, "bfloat16", "function"),
+    ("block_diffusion", 4, 512, "bfloat16", "codes"),
+    ("block_diffusion", 4, 384, "bfloat16", "function"),
+    ("block_diffusion", 3, 384, "bfloat16", "function"),
+    ("block_diffusion", 4, 512, "float32", "none"),
+    ("block_diffusion", 4, 32, "bfloat16", "none"),
+    ("causal", 0, 512, "float32", "none")])
+def test_the_lowering_counter_names_the_masks_form(mask, size, t, dtype,
+                                                   form):
+    """``attn:lowering`` says in which form the kernel gets the mask
+    (``library`` / ``function`` / ``codes``), ``none`` where the op runs
+    the plain blocks on every platform; ``kernel``, ``plain`` and the
+    track are what they were."""
+    q = jax.ShapeDtypeStruct((1, t, 4, 128), jnp.dtype(dtype))
+    kv = jax.ShapeDtypeStruct((1, t, 2, 128), jnp.dtype(dtype))
+    was = mx.trace.enabled()
+    mx.trace.set_enabled(True)
+    try:
+        mark = time.perf_counter_ns()
+        jax.eval_shape(lambda q, k, v: tf_ops.causal_attention(
+            q, k, v, 0.1, mask, block=size, window=size), q, kv, kv)
+        events = mx.trace.counter_events(["attn:lowering"], since_ns=mark)
+    finally:
+        mx.trace.set_enabled(was)
+    kernel = int(form != "none")
+    assert [e["args"] for e in events] == [
+        {"kernel": kernel, "plain": 1 - kernel, "mask_form": form}]
+    assert events[0]["id"] == "%s[1, %d, 4, 128]/kv2%s" % (
+        dtype, t, "" if mask == "causal" else "/%s%d" % (mask, size))
+
+
 @pytest.mark.parametrize("mask, block", [("causal", 0),
                                          ("block_diffusion", 4)])
 def test_grouped_heads_are_the_same_heads_repeated(mask, block):
@@ -231,6 +384,39 @@ def test_the_kernel_under_the_block_mask_interpreted(monkeypatch):
     assert not np.array_equal(moved[:, 264:268], got[0][:, 264:268])
 
 
+def test_the_kernel_keeps_the_function_where_the_sizes_are_no_powers_of_two(
+        monkeypatch):
+    """384 rows are two copies of 192, no power of two: the interpreted
+    kernel runs ``block_diffusion_allowed`` itself on row ids (the form
+    every size had before the codes) and agrees with the plain blocks,
+    forward and backward."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk)
+    monkeypatch.setattr(sk, "make_splash_mha_single_device",
+                        functools.partial(sk.make_splash_mha_single_device,
+                                          interpret=True))
+    monkeypatch.setattr(tf_ops, "ATTN_KERNEL_BLOCK", 128)
+    rng = np.random.RandomState(7)
+    q, k, v = (jnp.asarray(rng.randn(1, 384, h, 128), BF16)
+               for h in (2, 1, 1))
+    w = jnp.asarray(rng.randn(1, 384, 2, 128), F32)
+    assert tf_ops._kernel_takes(q, k, v)
+    kind, scale = ("block_diffusion", 4), 128 ** -0.5
+    assert tf_ops.kernel_mask(kind, 384)[0] == "function"
+
+    def run(fn, *args):
+        out, vjp = jax.vjp(lambda *a: fn(*a, scale, kind).astype(F32),
+                           *args)
+        return [np.asarray(x, np.float32) for x in (out,) + vjp(w)]
+
+    got = run(tf_ops._flash_attention, q, k, v)
+    want = run(tf_ops._plain_attention, *(x.astype(F32) for x in (q, k, v)))
+    assert np.allclose(want[0], _dense(q, k, v, scale, _by_hand(192, 4)),
+                       atol=1e-4)
+    for g, r in zip(got, want):
+        assert np.abs(g - r).max() <= 0.02 * np.abs(r).max()
+
+
 # -- the program of the cells that are there -----------------------------------
 def test_the_olmoe_symbols_lowered_text_is_what_it_was():
     """Causal attention over as many key heads as query heads is node for
@@ -281,7 +467,8 @@ def test_the_attention_at_the_cells_shape_lowers_to_the_kernel_on_a_tpu():
     assert text.count("tpu_custom_call") == 2
     assert "splash_mha_fwd" in text and "splash_mha_dkv" in text
     assert "stablehlo.pad" not in text
-    assert events[0]["args"] == {"kernel": 1, "plain": 0}
+    assert events[0]["args"] == {"kernel": 1, "plain": 0,
+                                 "mask_form": "codes"}
     assert events[0]["id"] == \
         "bfloat16[1, 8192, 32, 128]/kv4/block_diffusion4"
 

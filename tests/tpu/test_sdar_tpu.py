@@ -200,7 +200,8 @@ def test_published_width_step_matches_reference():
     # mask over 4 key/value heads
     assert len(bf16["attn_lowering"]) == kw["num_layers"]
     for track, args in bf16["attn_lowering"]:
-        assert args == {"kernel": 1, "plain": 0}, (track, args)
+        assert args == {"kernel": 1, "plain": 0, "mask_form": "codes"}, \
+            (track, args)
         assert track == "bfloat16[1, 8192, 32, 128]/kv4/block_diffusion4"
     # float8 weights are refused by at least one limit
     assert fp8["loss_rel_err"] > limits["loss_rtol"] or any(
@@ -216,7 +217,9 @@ def test_attention_kernel_matches_plain_blocks_under_the_block_mask():
     q over 4 key/value heads under ``block_diffusion`` in blocks of 4
     compiles to the Mosaic kernels on the chip; output and all three
     input gradients agree with the plain blocks', and a block's clean
-    rows do not reach its noised rows."""
+    rows do not reach its noised rows.  The kernel gets the mask as row
+    codes (``mask_form`` ``codes``); the forward and the forward +
+    backward ms of the layer's pair are printed and kept."""
     import jax
     import jax.numpy as jnp
     import mxnet_tpu as mx
@@ -242,7 +245,7 @@ def test_attention_kernel_matches_plain_blocks_under_the_block_mask():
     assert "tpu_custom_call" in text and "splash_mha" in text
     assert "tpu_custom_call" not in plain.lower(q, k, v).compile().as_text()
     event = mx.trace.counter_events(["attn:lowering"], since_ns=mark)[-1]
-    assert event["args"] == {"kernel": 1, "plain": 0}
+    assert event["args"] == {"kernel": 1, "plain": 0, "mask_form": "codes"}
     assert event["id"] == "bfloat16[1, 8192, 32, 128]/kv4/block_diffusion4"
     got = [np.asarray(x, np.float32) for x in kernel(q, k, v)]
     want = [np.asarray(x, np.float32) for x in plain(q, k, v)]
@@ -260,7 +263,14 @@ def test_attention_kernel_matches_plain_blocks_under_the_block_mask():
         jax.block_until_ready(out)
         return (time.perf_counter() - t0) / 5 * 1e3
 
-    report["ms_a_layer"] = {"kernel_forward_backward": ms(kernel, q, k, v),
+    # one layer's kernel pair in isolation, with the op's transposes and
+    # the partial dq planes' sum: the figure to hold beside the traced
+    # cell's splash_mha* operations (PERF.md section 5)
+    forward = jax.jit(lambda q, k, v: tf_ops.causal_attention(
+        q, k, v, scale, *kind))
+    report["mask_form"] = event["args"]["mask_form"]
+    report["ms_a_layer"] = {"kernel_forward": ms(forward, q, k, v),
+                            "kernel_forward_backward": ms(kernel, q, k, v),
                             "plain_forward_backward": ms(plain, q, k, v)}
     out_dir = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)
