@@ -28,11 +28,16 @@ the inputs are ones the kernels take (``_kernel_takes``: whole chunks,
 heads of 128), two Pallas kernels run the rule instead,
 ``kda_chunk_fwd`` and ``kda_chunk_bwd``, over a grid of (batch, a few
 heads, chunk): the heads' states and the chunk's float32 products stay
-in VMEM, the state is carried along the sequential chunk axis in
-scratch, and the backward kernel computes the chunk algebra again from
-q, k, v, g, beta and the chunk's entry state, which the forward kernel
-writes out (``f32[B, H, N, Dv, Dk]``).  Inside the kernels ``exp(G_i -
-G_j)`` is kept below 1 by halving: at level ``l`` a block of ``2 << l``
+in VMEM and the state is carried along the sequential chunk axis in
+scratch.  The forward kernel writes out every chunk's entry state
+(``f32[B, H, N, Dv, Dk]``) and the three ``(C, C)`` matrices that cost
+it most MXU passes, the scores ``A`` and ``Bs`` and the triangular
+inverse ``T`` (``f32[B, T, H * 3 * C]``, a head's three side by side);
+the backward kernel reads them back and computes again, from q, k, v, g
+and beta, only the cheap rest: the running sum, the decays, the residual
+against the entry state and the corrections ``u = T (beta r)``
+(``_chunk_entry``, which both kernels run).  Inside the kernels ``exp(G_i
+- G_j)`` is kept below 1 by halving: at level ``l`` a block of ``2 << l``
 tokens takes the running sum at the end of its first half as the
 reference point of its lower-left quarter, so every pair ``j < i`` is
 formed once, by a matmul, at the level where it first falls into two
@@ -44,15 +49,17 @@ as the plain chunks form them in float32; every other product follows
 the precision in force, as the plain chunks' matmuls do.
 
 The lowering differentiates itself (``jax.custom_vjp`` around the op's
-body, which keeps the op's inputs and the entry states and nothing
-else) through two module-level ``jax.jit`` functions of arrays and
-static sizes only, so a process traces each kernel once and a program
-holds each once, whatever the number of layers and modules that call
-them: the counter ``kda:kernel_trace`` (``fwd`` / ``bwd``) fires from
-inside their bodies and so counts traces, not calls.  The counter
-``kda:lowering`` records the choice per traced op (``kernel`` /
-``plain``) as ``attn:lowering`` does for attention, and the op's body
-runs under ``kda.l<layer>``.
+body, which keeps the op's inputs, the entry states and ``A``, ``Bs``,
+``T`` and nothing else: ``_kept_shapes``) through two module-level
+``jax.jit`` functions of arrays and static sizes only, so a process
+traces each kernel once and a program holds each once, whatever the
+number of layers and modules that call them: the counter
+``kda:kernel_trace`` (``fwd`` / ``bwd``; the ``bwd`` event's
+``kept_products`` is the number of chunk matrices that backward takes
+from the forward kernel, 3) fires from inside their bodies and so counts
+traces, not calls. The counter ``kda:lowering`` records the choice per
+traced op (``kernel`` / ``plain``) as ``attn:lowering`` does for
+attention, and the op's body runs under ``kda.l<layer>``.
 """
 from __future__ import annotations
 
@@ -185,6 +192,9 @@ def gated_delta_rule(q, k, v, g, beta, scale: float, chunk: int = KDA_CHUNK):
 
 # heads a grid step: their chains of small dependent matmuls interleave
 KDA_KERNEL_HEADS = 4
+# the (C, C) chunk matrices the forward kernel keeps for the backward one:
+# the scores A and Bs and the triangular inverse T
+KDA_KEPT = 3
 _NN = (((2,), (1,)), ((0,), (0,)))    # a @ b, a batch of heads
 _NT = (((2,), (2,)), ((0,), (0,)))    # a @ b.T
 _TN = (((1,), (1,)), ((0,), (0,)))    # a.T @ b
@@ -271,17 +281,23 @@ def _pair_masks(c):
 _FINE_LEVELS = KDA_SUB.bit_length() - 1
 
 
-def _chunk_forward(q, k, v, g, beta, St, scale):
-    """One chunk of a few heads, everything float32: q, k, g ``(H, C,
-    Dk)``, v ``(H, C, Dv)``, beta ``(H, C, 1)``, the entry states
-    transposed ``(H, Dv, Dk)`` -> the chunk's products by name.  ``A``
-    and ``Bs`` are the scores of ``_chunk_scores`` (``Bs`` times
-    ``scale``), ``T`` the inverse of ``I + beta A``, ``u`` the
-    corrections, ``o`` the output and ``S1t`` the exit states."""
-    c = q.shape[1]
-    row, col, pairs = _pair_masks(c)
-    G = _sum_rows(row >= col, g)                 # running sum of g
+def _chunk_sums(g):
+    """The running sum ``G`` of a chunk's log-decay ``(H, C, Dk)`` and
+    the chunk's ``_pair_masks``: what every other part of the chunk
+    algebra starts from.  -> G, (row, col, pairs)."""
+    masks = _pair_masks(g.shape[1])
+    row, col, _ = masks
+    return _sum_rows(row >= col, g), masks
 
+
+def _chunk_scores_and_inverse(q, k, g, beta, G, scale, masks):
+    """One chunk of a few heads, everything float32: q, k, g and their
+    running sum ``(H, C, Dk)``, beta ``(H, C, 1)`` -> ``A`` and ``Bs``,
+    the scores of ``_chunk_scores`` (``Bs`` times ``scale``), and ``T``,
+    the inverse of ``I + beta A``, each ``(H, C, C)``.  The forward
+    kernel's alone: the backward kernel reads the three back."""
+    c = q.shape[1]
+    row, col, pairs = masks
     A = Bs = jnp.zeros((c, c), jnp.float32)
     for l, pair in enumerate(pairs):
         kl, ql, kr, _, _ = _level(l, q, k, g, G, scale, row)
@@ -297,20 +313,25 @@ def _chunk_forward(q, k, v, g, beta, St, scale):
     for pair in pairs[1:]:          # blocks of 2s from blocks of s
         X = jnp.where(pair, M, 0.0)
         T = T - _dot(T, _dot(X, T, _NN, True), _NN, True)
+    return A, Bs, T
+
+
+def _chunk_entry(q, k, v, beta, St, scale, G, T):
+    """What both kernels form of a chunk from its inputs, its running
+    sum, its inverse ``T`` and the entry states transposed ``(H, Dv,
+    Dk)``: the decays from the chunk's start and to its end, k and q
+    under them, the residual ``r`` of v against the entry state and the
+    corrections ``u``, by name."""
+    c = q.shape[1]
     decayed = jnp.exp(G)
     Gc = G[:, c - 1:c]
     to_end = jnp.exp(Gc - G)
     kd = k * decayed
-    qd = q * decayed * scale
-    kc = k * to_end
-    ec = jnp.exp(Gc)                                    # (H, 1, Dk)
     r = v - _dot(kd, St, _NT)
-    u = _dot(T, beta * r, _NN, True)
-    o = _dot(qd, St, _NT) + _dot(Bs, u, _NN)
-    S1t = St * ec + _dot(u, kc, _TN)
-    return dict(G=G, A=A, Bs=Bs, T=T, kd=kd, qd=qd, kc=kc, ec=ec, r=r, u=u,
-                o=o, S1t=S1t, decayed=decayed, to_end=to_end, row=row,
-                col=col, pairs=pairs)
+    return dict(decayed=decayed, to_end=to_end, kd=kd,
+                qd=q * decayed * scale, kc=k * to_end,
+                ec=jnp.exp(Gc),                         # (H, 1, Dk)
+                r=r, u=_dot(T, beta * r, _NN, True))
 
 
 def _heads(ref, d):
@@ -325,8 +346,8 @@ def _put_heads(ref, x):
         ref[:, i * d:(i + 1) * d] = x[i].astype(ref.dtype)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, state, *,
-                scale):
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, kept_ref,
+                state, *, scale):
     @pl.when(pl.program_id(2) == 0)
     def _():
         state[...] = jnp.zeros_like(state)
@@ -334,17 +355,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, state, *,
     d = state.shape[-1]
     St = state[...]
     s_ref[...] = St
-    c = _chunk_forward(_heads(q_ref, d), _heads(k_ref, d), _heads(v_ref, d),
-                       _heads(g_ref, d), b_ref[...], St, scale)
-    _put_heads(o_ref, c["o"])
-    state[...] = c["S1t"]
+    q, k, g, beta = _heads(q_ref, d), _heads(k_ref, d), _heads(g_ref, d), \
+        b_ref[...]
+    G, masks = _chunk_sums(g)
+    A, Bs, T = _chunk_scores_and_inverse(q, k, g, beta, G, scale, masks)
+    # a head's three side by side: (C, heads * 3 * C), whole rows of lanes
+    _put_heads(kept_ref,
+               jnp.stack([A, Bs, T], 1).reshape((-1,) + T.shape[1:]))
+    c = _chunk_entry(q, k, _heads(v_ref, d), beta, St, scale, G, T)
+    _put_heads(o_ref, _dot(c["qd"], St, _NT) + _dot(Bs, c["u"], _NN))
+    state[...] = St * c["ec"] + _dot(c["u"], c["kc"], _TN)
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref, dq_ref,
-                dk_ref, dv_ref, dg_ref, db_ref, dstate, *, scale):
-    """The chunks in reverse: the chunk algebra again from the inputs and
-    the entry states, then its transpose; ``dstate`` carries the states'
-    cotangent (transposed) to the chunk before."""
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, kept_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, dstate, *, scale):
+    """The chunks in reverse: the scores and the inverse as the forward
+    kernel wrote them, the decays, ``r`` and ``u`` again from the inputs
+    and the entry states (``_chunk_entry``), then the chunk's transpose;
+    ``dstate`` carries the states' cotangent (transposed) to the chunk
+    before."""
     @pl.when(pl.program_id(2) == 0)
     def _():
         dstate[...] = jnp.zeros_like(dstate)
@@ -352,14 +381,16 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref, dq_ref,
     d = dstate.shape[-1]
     q, k, g = _heads(q_ref, d), _heads(k_ref, d), _heads(g_ref, d)
     beta, St, dS1t, do = b_ref[...], s_ref[...], dstate[...], _heads(do_ref, d)
-    c = _chunk_forward(q, k, _heads(v_ref, d), g, beta, St, scale)
     n = q.shape[1]
-    row, col = c["row"], c["col"]
-    u, T, kd, qd, kc = c["u"], c["T"], c["kd"], c["qd"], c["kc"]
+    kept = _heads(kept_ref, n).reshape(-1, KDA_KEPT, n, n)
+    A, Bs, T = (kept[:, i] for i in range(KDA_KEPT))
+    G, (row, col, pairs) = _chunk_sums(g)
+    c = _chunk_entry(q, k, _heads(v_ref, d), beta, St, scale, G, T)
+    u, kd, qd, kc = c["u"], c["kd"], c["qd"], c["kc"]
 
     dqd = _dot(do, St, _NN)
     dBs = jnp.where(row >= col, _dot(do, u, _NT), 0.0)
-    du = _dot(c["Bs"], do, _TN) + _dot(kc, dS1t, _NT)
+    du = _dot(Bs, do, _TN) + _dot(kc, dS1t, _NT)
     dkc = _dot(u, dS1t, _NN)
     dGc = jnp.sum(dS1t * St, axis=1, keepdims=True) * c["ec"] \
         + jnp.sum(dkc * kc, axis=1, keepdims=True)
@@ -367,7 +398,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref, dq_ref,
     dM = jnp.where(row > col, -_dot(dy, u, _NT), 0.0)
     dA = beta * dM
     dr = beta * dy
-    db_ref[...] = jnp.sum(dM * c["A"], axis=2, keepdims=True) \
+    db_ref[...] = jnp.sum(dM * A, axis=2, keepdims=True) \
         + jnp.sum(dy * c["r"], axis=2, keepdims=True)
     _put_heads(dv_ref, dr)
     dkd = -_dot(dr, St, _NN)
@@ -378,9 +409,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref, dq_ref,
     dq = dqd * c["decayed"] * scale + diag * k
     dk = dkd * c["decayed"] + dkc * c["to_end"] + diag * q
     dG = dkd * kd + dqd * qd - dkc * kc + jnp.where(row == n - 1, dGc, 0.0)
-    for l, pair in enumerate(c["pairs"]):
+    for l, pair in enumerate(pairs):
         exact = l < _FINE_LEVELS
-        kl, ql, kr, left, right = _level(l, q, k, g, c["G"], scale, row)
+        kl, ql, kr, left, right = _level(l, q, k, g, G, scale, row)
         dAB = jnp.concatenate([jnp.where(pair, dA, 0.0),
                                jnp.where(pair, dBs, 0.0)], 1)
         dleft = _dot(dAB, kr, _NN, exact)               # (H, 2C, Dk)
@@ -409,7 +440,8 @@ def _kernel_specs(b, t, h, d, flip):
     """The grid over (batch, heads, chunk) and the blocks of one step:
     ``(C, heads * D)`` of a ``(B, T, H * D)`` array, ``(heads, C, 1)`` of
     beta's ``(B, H, T, 1)``, ``(heads, D, D)`` states of ``(B, H, N, D,
-    D)``.  ``flip`` walks the chunks from the last."""
+    D)``, ``(C, heads * 3 * C)`` of the kept matrices' ``(B, T, H * 3 *
+    C)``.  ``flip`` walks the chunks from the last."""
     from jax.experimental.pallas import tpu as pltpu
     chunk, n = KDA_CHUNK, t // KDA_CHUNK
     heads = next(x for x in range(min(KDA_KERNEL_HEADS, h), 0, -1)
@@ -423,6 +455,8 @@ def _kernel_specs(b, t, h, d, flip):
                          lambda i, j, m: (i, j, at(m), 0)),
         state=pl.BlockSpec((None, heads, None, d, d),
                            lambda i, j, m: (i, j, at(m), 0, 0)),
+        kept=pl.BlockSpec((None, chunk, heads * KDA_KEPT * chunk),
+                          lambda i, j, m: (i, at(m), j)),
         scratch=[pltpu.VMEM((heads, d, d), jnp.float32)],
         # the unrolled levels of a few heads spill more than the 16 MiB
         # a kernel is given unasked; a v5e core has 128 MiB
@@ -432,15 +466,27 @@ def _kernel_specs(b, t, h, d, flip):
     )
 
 
+def _kept_shapes(b, t, h, d):
+    """What the kernel lowering keeps of the forward pass beside the op's
+    inputs: the chunks' entry states and their ``A``, ``Bs``, ``T``
+    (134 MB + 100.7 MB a layer at 4096 tokens of 32 heads)."""
+    f32 = jnp.float32
+    return [jax.ShapeDtypeStruct((b, h, t // KDA_CHUNK, d, d), f32),
+            jax.ShapeDtypeStruct((b, t, h * KDA_KEPT * KDA_CHUNK), f32)]
+
+
 # lint: allow(raw-jit) — never dispatched on its own: a jit inside the step
 # program, there so that every call site shares one traced jaxpr and one
 # lowered function; the step that holds it goes through the cache
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def _kda_fwd(q, k, v, g, beta, *, scale, interpret):
     """``kda_chunk_fwd`` over ``(B, T, H * D)`` q, k, g (float32) and v,
-    ``(B, H, T, 1)`` beta -> the output in v's layout and dtype and every
-    chunk's entry state ``(B, H, N, D, D)``, transposed.  Module-level
-    and free of per-call objects: traced once a process."""
+    ``(B, H, T, 1)`` beta -> the output in v's layout and dtype and what
+    ``_kda_bwd`` wants back: every chunk's entry state ``(B, H, N, D,
+    D)``, transposed, and its scores and inverse ``A``, ``Bs``, ``T``, a
+    head's three side by side ``(B, T, H * 3 * C)``, float32.  A
+    forward-only caller runs the same kernel and drops them.
+    Module-level and free of per-call objects: traced once a process."""
     b, t, hd = q.shape
     h = beta.shape[1]
     d = hd // h
@@ -456,10 +502,9 @@ def _kda_fwd(q, k, v, g, beta, *, scale, interpret):
         functools.partial(_fwd_kernel, scale=scale),
         grid=sp["grid"],
         in_specs=[sp["seq"]] * 4 + [sp["col"]],
-        out_specs=[sp["seq"], sp["state"]],
-        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
-                   jax.ShapeDtypeStruct((b, h, t // KDA_CHUNK, d, d),
-                                        jnp.float32)],
+        out_specs=[sp["seq"], sp["state"], sp["kept"]],
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype)]
+        + _kept_shapes(b, t, h, d),
         scratch_shapes=sp["scratch"], compiler_params=sp["params"],
         interpret=interpret, name="kda_chunk_fwd",
     )(q, k, v, g, beta)
@@ -467,28 +512,31 @@ def _kda_fwd(q, k, v, g, beta, *, scale, interpret):
 
 # lint: allow(raw-jit) — as _kda_fwd
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _kda_bwd(q, k, v, g, beta, states, do, *, scale, interpret):
+def _kda_bwd(q, k, v, g, beta, states, kept, do, *, scale, interpret):
     """``kda_chunk_bwd``: the cotangents of q, k, v, g and beta from the
-    forward kernel's inputs, its entry states and the output's cotangent,
-    all in ``_kda_fwd``'s layouts.  Traced once a process."""
+    forward kernel's inputs, its entry states, its kept ``A``, ``Bs``,
+    ``T`` and the output's cotangent, all in ``_kda_fwd``'s layouts.
+    Traced once a process."""
     b, t, hd = q.shape
     h = beta.shape[1]
     d = hd // h
     trace.counter("kda:kernel_trace", cat="ops",
-                  track="%s%s" % (v.dtype.name, [b, t, h, d]), fwd=0, bwd=1)
+                  track="%s%s" % (v.dtype.name, [b, t, h, d]), fwd=0, bwd=1,
+                  kept_products=KDA_KEPT)
     sp = _kernel_specs(b, t, h, d, flip=True)
     f32 = jax.ShapeDtypeStruct(q.shape, jnp.float32)
     # lint: allow(raw-pallas-call) — as _kda_fwd
     return pl.pallas_call(
         functools.partial(_bwd_kernel, scale=scale),
         grid=sp["grid"],
-        in_specs=[sp["seq"]] * 4 + [sp["col"], sp["state"], sp["seq"]],
+        in_specs=[sp["seq"]] * 4 + [sp["col"], sp["state"], sp["kept"],
+                                    sp["seq"]],
         out_specs=[sp["seq"]] * 4 + [sp["col"]],
         out_shape=[f32, f32, jax.ShapeDtypeStruct(v.shape, v.dtype), f32,
                    jax.ShapeDtypeStruct(beta.shape, jnp.float32)],
         scratch_shapes=sp["scratch"], compiler_params=sp["params"],
         interpret=interpret, name="kda_chunk_bwd",
-    )(q, k, v, g, beta, states, do)
+    )(q, k, v, g, beta, states, kept, do)
 
 
 def _kernel_layout(q, k, v, g, beta):
@@ -503,20 +551,20 @@ def _kernel_layout(q, k, v, g, beta):
 
 def _kernel_rule(q, k, v, g, beta, scale: float, interpret: bool = False):
     """``gated_delta_rule`` by ``kda_chunk_fwd`` for inputs
-    ``_kernel_takes`` accepts -> the output and every chunk's entry
-    state, which ``_kernel_rule_vjp`` wants back."""
-    o, states = _kda_fwd(*_kernel_layout(q, k, v, g, beta), scale=scale,
-                         interpret=interpret)
-    return o.reshape(v.shape), states
+    ``_kernel_takes`` accepts -> the output and what the forward kernel
+    kept (``_kept_shapes``), which ``_kernel_rule_vjp`` wants back."""
+    o, *kept = _kda_fwd(*_kernel_layout(q, k, v, g, beta), scale=scale,
+                        interpret=interpret)
+    return o.reshape(v.shape), kept
 
 
-def _kernel_rule_vjp(q, k, v, g, beta, states, do, scale: float,
+def _kernel_rule_vjp(q, k, v, g, beta, kept, do, scale: float,
                      interpret: bool = False):
     """The cotangents of ``_kernel_rule``'s five inputs for the output's
     cotangent ``do``, by ``kda_chunk_bwd``."""
     b, t = q.shape[:2]
     dq, dk, dv, dg, db = _kda_bwd(
-        *_kernel_layout(q, k, v, g, beta), states, do.reshape(b, t, -1),
+        *_kernel_layout(q, k, v, g, beta), *kept, do.reshape(b, t, -1),
         scale=scale, interpret=interpret)
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
             dg.reshape(g.shape), db[..., 0].transpose(0, 2, 1))
@@ -544,11 +592,13 @@ def _two_lowerings(q, k, v, decay, beta, a_log, dt_bias, interpret: bool):
     program is lowered for a TPU, the plain chunks elsewhere
     (``_kernel_on_tpu``), in the forward and in the backward pass.  The
     backward pass keeps the op's own inputs and, from the kernels, the
-    chunks' entry states (134 MB a layer at 4096 tokens of 32 heads) and
+    chunks' entry states and their scores and inverse ``A``, ``Bs``,
+    ``T`` (134 MB + 100.7 MB a layer at 4096 tokens of 32 heads) and
     computes everything else again: the normalized q and k and the gates
-    (0.27 GB a layer in float32, one elementwise pass), the chunk
-    products inside the backward kernel; the plain chunks are computed
-    again whole.  The choice lies inside the two rules, so that neither
+    (0.27 GB a layer in float32, one elementwise pass), the chunks'
+    running sums, decays and corrections inside the backward kernel; the
+    plain chunks are computed again whole and keep zeros in the kernels'
+    shapes.  The choice lies inside the two rules, so that neither
     lowering is differentiated through the choice."""
     return _two_lowerings_fwd(q, k, v, decay, beta, a_log, dt_bias,
                               interpret)[0]
@@ -562,33 +612,32 @@ def _two_lowerings_fwd(q, k, v, decay, beta, a_log, dt_bias, interpret):
         return _kernel_rule(qn, kn, args[2], g, b, _scale(qn), interpret)
 
     def plain(*args):
-        b, t, h, d = args[0].shape
-        return _plain_attention(*args), jnp.zeros(
-            (b, h, t // KDA_CHUNK, d, d), jnp.float32)
+        return _plain_attention(*args), [
+            jnp.zeros(x.shape, x.dtype) for x in _kept_shapes(*args[0].shape)]
 
-    o, states = _kernel_on_tpu(kernels, plain, interpret, *args)
-    return o, (args, states)
+    o, kept = _kernel_on_tpu(kernels, plain, interpret, *args)
+    return o, (args, kept)
 
 
 def _two_lowerings_bwd(interpret, res, do):
     # as jax.checkpoint ties what it computes again to the cotangent's
     # arrival: without it XLA is free to form every layer's float32 q, k
     # and g at the start of the backward pass and hold them
-    (args, states), do = lax.optimization_barrier((res, do))
+    (args, kept), do = lax.optimization_barrier((res, do))
 
-    def kernels(do, states, *args):
+    def kernels(do, kept, *args):
         v = args[2]
         (qn, kn, g, b), before = jax.vjp(_normalized_and_gated, *args[:2],
                                          *args[3:])
-        dqn, dkn, dv, dg, db = _kernel_rule_vjp(qn, kn, v, g, b, states, do,
+        dqn, dkn, dv, dg, db = _kernel_rule_vjp(qn, kn, v, g, b, kept, do,
                                                 _scale(qn), interpret)
         dq, dk, ddecay, dbeta, da_log, ddt_bias = before((dqn, dkn, dg, db))
         return dq, dk, dv, ddecay, dbeta, da_log, ddt_bias
 
-    def plain(do, states, *args):
+    def plain(do, kept, *args):
         return jax.vjp(_plain_attention, *args)[1](do)
 
-    return _kernel_on_tpu(kernels, plain, interpret, do, states, *args)
+    return _kernel_on_tpu(kernels, plain, interpret, do, kept, *args)
 
 
 _two_lowerings.defvjp(_two_lowerings_fwd, _two_lowerings_bwd)

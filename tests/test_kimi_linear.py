@@ -190,6 +190,80 @@ def test_kernel_lowering_carries_the_gradient_through_the_gates():
         assert np.abs(x - y).max() <= 4e-3 * np.abs(y).max()
 
 
+def _kernel_dots(jaxpr):
+    """The ``dot_general``s of a jaxpr and of every jaxpr inside it (a
+    ``pallas_call``'s kernel body)."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "dot_general"
+        for value in eqn.params.values():
+            for x in value if isinstance(value, (list, tuple)) else [value]:
+                x = getattr(x, "jaxpr", x)
+                if hasattr(x, "eqns"):
+                    n += _kernel_dots(x)
+    return n
+
+
+# MXU passes a chunk in ``kda_chunk_bwd``'s body: with the scores and the
+# inverse formed again (the commit before ISSUE 45: 14 + 30 passes, and 3
+# for an output nobody read) and with the three read back
+BWD_DOTS_BEFORE_PR45, BWD_DOTS = 103, 56
+
+
+def _bwd_kernel_dots(b, t, h, d):
+    """-> the passes of the backward kernel traced for ``(B, T, H, D)``
+    inputs."""
+    seq = jax.ShapeDtypeStruct((b, t, h * d), jnp.float32)
+    beta = jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32)
+    return _kernel_dots(jax.make_jaxpr(
+        lambda *a: kda_ops._kda_bwd(*a, scale=0.1, interpret=False))(
+            seq, seq, seq, seq, beta, *kda_ops._kept_shapes(b, t, h, d),
+            seq).jaxpr)
+
+
+@pytest.mark.parametrize("lo,hi", [(-3.0, -0.01), (-40.0, -1e-4)],
+                         ids=["mixed", "wide"])
+def test_kept_chunk_products_are_the_ones_formed_again(lo, hi):
+    """What ``kda_chunk_fwd`` keeps for ``kda_chunk_bwd`` (ISSUE 45) is,
+    bit for bit, what the chunk algebra's own statements give from the
+    inputs (``_chunk_sums``, ``_chunk_scores_and_inverse``, called here
+    outside any kernel over every chunk-head at once), and so are the
+    five cotangents the backward kernel makes of either: the split of
+    the algebra between the kernels moved statements and changed none.
+    Three chunks of two heads; float32 and exact products throughout."""
+    t, heads, c, d = 192, 2, kda_ops.KDA_CHUNK, 128
+    args = _kda_inputs(t, lo, hi, heads=heads, seed=45)
+    w = jnp.asarray(np.random.RandomState(2).randn(*args[2].shape),
+                    jnp.float32)
+    scale = d ** -0.5
+
+    @jax.jit
+    def formed_again(q, k, g, beta):
+        def chunks(x):          # (1, T, H, ..) -> (H * N, C, ..)
+            x = x.reshape((t // c, c, heads) + x.shape[3:])
+            return jnp.moveaxis(x, 2, 0).reshape((-1, c) + x.shape[3:])
+        q, k, g, beta = chunks(q), chunks(k), chunks(g), chunks(beta[..., None])
+        G, masks = kda_ops._chunk_sums(g)
+        kept = jnp.stack(kda_ops._chunk_scores_and_inverse(
+            q, k, g, beta, G, scale, masks), 1)          # (H * N, 3, C, C)
+        # a head's three side by side, as the forward kernel lays them
+        kept = kept.reshape(heads, t // c, 3, c, c).transpose(1, 3, 0, 2, 4)
+        return kept.reshape(1, t, heads * 3 * c)
+
+    with jax.default_matmul_precision("highest"):
+        _, (states, kept) = kda_ops._kernel_rule(*args, scale,
+                                                 interpret=True)
+        again = formed_again(args[0], args[1], args[3], args[4])
+        assert kept.shape == again.shape == (1, t, heads * 3 * c)
+        assert np.array_equal(np.asarray(kept), np.asarray(again))
+        assert np.abs(np.asarray(kept)).max() > 0
+        grads, grads_again = (
+            kda_ops._kernel_rule_vjp(*args, (states, x), w, scale,
+                                     interpret=True) for x in (kept, again))
+    for x, y in zip(grads, grads_again):
+        assert np.array_equal(np.asarray(x), np.asarray(y))
+
+
 @pytest.mark.parametrize("t,dk,dv,dtype,kernel", [
     (128, 128, 128, "bfloat16", True), (128, 128, 128, "float32", True),
     (96, 128, 128, "bfloat16", False), (128, 64, 128, "bfloat16", False),
@@ -232,6 +306,12 @@ def test_delta_rule_lowering_is_chosen_from_shape_and_dtype(t, dk, dv, dtype,
             <= 2e-2 * np.abs(np.asarray(want)).max()
 
 
+# one trace of each kernel a process; the backward one says how many chunk
+# matrices it takes from the forward one (A, Bs, T) and does not form again
+KERNEL_TRACES = [{"fwd": 1, "bwd": 0},
+                 {"fwd": 0, "bwd": 1, "kept_products": 3}]
+
+
 def test_the_tpu_program_traces_each_kernel_once():
     """The same count on the path the chip takes (no interpreter: the
     choice by ``lax.platform_dependent``, two ops under ``jax.grad``,
@@ -258,12 +338,15 @@ def test_the_tpu_program_traces_each_kernel_once():
         traces = mx.trace.counter_events(["kda:kernel_trace"], since_ns=mark)
     finally:
         mx.trace.set_enabled(was)
-    assert [e["args"] for e in traces] == [{"fwd": 1, "bwd": 0},
-                                           {"fwd": 0, "bwd": 1}]
+    assert [e["args"] for e in traces] == KERNEL_TRACES
     for fn in ("_kda_fwd", "_kda_bwd"):
         assert len(re.findall(r"func\.func private @%s\b" % fn, text)) == 1
         assert len(re.findall(r"call @%s\b" % fn, text)) == 2, fn
     assert "triangular_solve" not in text and "stablehlo.while" not in text
+    # the backward kernel this program holds forms no score level and no
+    # ``T - T (X T)`` again: 47 passes a chunk fewer than it had
+    assert _bwd_kernel_dots(*q.shape) == BWD_DOTS \
+        == BWD_DOTS_BEFORE_PR45 - 14 - 30 - 3
 
 
 def test_kernels_are_traced_once_a_process_and_lowered_once_a_program(
@@ -301,8 +384,7 @@ def test_kernels_are_traced_once_a_process_and_lowered_once_a_program(
         choices = mx.trace.counter_events(["kda:lowering"], since_ns=mark)
     finally:
         mx.trace.set_enabled(was)
-    assert [e["args"] for e in traces] == [{"fwd": 1, "bwd": 0},
-                                           {"fwd": 0, "bwd": 1}]
+    assert [e["args"] for e in traces] == KERNEL_TRACES
     assert len(choices) >= 8 and all(
         e["args"]["kernel"] == 1 for e in choices)
     for text in texts:
@@ -310,6 +392,8 @@ def test_kernels_are_traced_once_a_process_and_lowered_once_a_program(
             assert len(re.findall(r"func\.func private @%s\b" % fn,
                                   text)) == 1, fn
             assert len(re.findall(r"call @%s\b" % fn, text)) == 4, fn
+    assert _bwd_kernel_dots(BATCH, over["seq_len"], over["kda_heads"],
+                            over["kda_head_dim"]) == BWD_DOTS
 
 
 def test_causal_conv_and_silu():

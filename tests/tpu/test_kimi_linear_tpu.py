@@ -220,8 +220,18 @@ def test_kda_kernels_against_the_plain_chunks_at_the_published_shape():
     forward = {"kernel": jax.jit(
         lambda *a: kda._kernel_rule(*a, scale)[0]),
         "plain": jax.jit(lambda *a: kda.gated_delta_rule(*a, scale))}
+    # what the lowering keeps of a layer's forward pass beside the op's
+    # inputs: the entry states and the chunks' A, Bs, T, float32
+    kept = jax.eval_shape(
+        lambda *a: kda._kernel_rule(*a, scale)[1],
+        *(jax.ShapeDtypeStruct((b, t, h, d), jnp.float32),) * 4,
+        jax.ShapeDtypeStruct((b, t, h), jnp.float32))
+    kept_bytes = [x.size * x.dtype.itemsize for x in kept]
+    assert kept_bytes == [b * h * (t // 64) * d * d * 4,
+                          b * h * (t // 64) * 3 * 64 * 64 * 4] \
+        == [134217728, 100663296]
     report = {"device": jax.devices()[0].device_kind, "shape": [b, t, h, d],
-              "bands": {}}
+              "kept_bytes_a_layer": kept_bytes, "bands": {}}
 
     def rels(got, want):
         return {n: _rel(x, y) for n, x, y in zip(names, got, want)}
