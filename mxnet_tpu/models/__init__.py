@@ -15,10 +15,10 @@ from .dcgan import make_generator, make_discriminator
 from .fcn import get_fcn32s, get_fcn16s, get_fcn8s
 from .rcnn import get_fast_rcnn, get_rpn
 from .olmoe import olmoe_lm
-from .kimi_linear import kimi_linear_lm  # noqa: F401
-from .glm_moe_lite import glm_moe_lite_lm  # noqa: F401
-from .sdar_moe import sdar_moe_lm  # noqa: F401
-from .afmoe import afmoe_lm  # noqa: F401
+from .kimi_linear import kimi_linear_lm
+from .glm_moe_lite import glm_moe_lite_lm
+from .sdar_moe import sdar_moe_lm
+from .afmoe import afmoe_lm
 from .gru import gru_unroll, gru_cell, rnn_unroll, rnn_cell, GRUState, \
     GRUParam, RNNState, RNNParam
 
@@ -26,8 +26,8 @@ __all__ = ["get_mlp", "get_lenet", "get_resnet", "get_resnet50",
            "get_resnet_cifar",
            "get_inception_bn", "get_inception_bn_28small", "get_vgg",
            "lstm_unroll", "lstm_unroll_scan", "lstm_cell", "LSTMState",
-           "LSTMParam",
-           "make_generator", "make_discriminator", "get_fcn32s", "get_fcn16s", "get_fcn8s",
-           "get_fast_rcnn", "get_rpn", "olmoe_lm", "gru_unroll", "gru_cell",
-           "rnn_unroll", "rnn_cell", "GRUState", "GRUParam", "RNNState",
-           "RNNParam"]
+           "LSTMParam", "make_generator", "make_discriminator",
+           "get_fcn32s", "get_fcn16s", "get_fcn8s", "get_fast_rcnn",
+           "get_rpn", "olmoe_lm", "kimi_linear_lm", "glm_moe_lite_lm",
+           "sdar_moe_lm", "afmoe_lm", "gru_unroll", "gru_cell", "rnn_unroll",
+           "rnn_cell", "GRUState", "GRUParam", "RNNState", "RNNParam"]

@@ -43,8 +43,9 @@ product) beside the ops' own ``attn.l<i>``, ``moe_*.l<i>`` and
 ``lm_loss``.
 """
 from .. import symbol as sym
-from ..moe.layer import MoEFeedForward, with_load_heads
-from .latent_attention import scoped
+from ..moe.layer import with_load_heads
+from .decoder import (block, embed, gqa_attention, lm_head_loss,
+                      routed_experts, swiglu)
 
 LAYER_KINDS = ("sliding", "full")
 
@@ -64,70 +65,30 @@ def afmoe_lm(num_layers, hidden_size, layer_types, dense_layers, num_heads,
         raise ValueError("%d query heads over %d key/value heads"
                          % (num_heads, num_kv_heads))
 
-    def norm(x, name):
-        return sym.RMSNorm(x, eps=rms_eps, name=name)
-
-    def proj(x, name, width):
-        return sym.FullyConnected(x, num_hidden=width, no_bias=True,
-                                  name=name)
-
-    def heads(x, n):
-        return sym.Reshape(x, shape=(-1, seq_len, n, head_dim))
-
     def attention(h, pre, layer, sliding):
-        """h (B*T, D) -> (B*T, D).  The kind is the op's mask and
-        whether the heads are rotated; nothing else differs."""
-        def placed(x):
-            return sym.RotaryEmbedding(x, theta=rope_theta) if sliding else x
+        """The kind is the op's mask and whether the heads are rotated;
+        nothing else differs."""
+        how = dict(rotate=lambda t: sym.RotaryEmbedding(t, theta=rope_theta),
+                   mask="sliding_window", window=window) if sliding else {}
+        return gqa_attention(h, pre, layer, seq_len, num_heads, num_kv_heads,
+                             head_dim, hidden_size, rms_eps, gated=True, **how)
 
-        with scoped("", "attn_proj", layer):
-            q = placed(norm(heads(proj(h, pre + "q_proj",
-                                       num_heads * head_dim), num_heads),
-                            pre + "q_norm"))
-            k = placed(norm(heads(proj(h, pre + "k_proj",
-                                       num_kv_heads * head_dim),
-                                  num_kv_heads), pre + "k_norm"))
-            v = heads(proj(h, pre + "v_proj", num_kv_heads * head_dim),
-                      num_kv_heads)
-        mask = dict(mask="sliding_window", window=window) if sliding else {}
-        a = sym.CausalSelfAttention(q, k, v, layer=layer, name=pre + "attn",
-                                    **mask)
-        with scoped("", "attn_gate", layer):
-            gate = sym.Activation(proj(h, pre + "attn_gate_proj",
-                                       num_heads * head_dim),
-                                  act_type="sigmoid")
-            a = sym.Reshape(a, shape=(-1, num_heads * head_dim)) * gate
-        with scoped("", "attn_proj", layer):
-            return proj(a, pre + "o_proj", hidden_size)
-
-    def mlp(h, pre, layer, dense):
-        if dense:
-            gate = sym.Activation(proj(h, pre + "gate_proj", dense_width),
-                                  act_type="silu")
-            return proj(gate * proj(h, pre + "up_proj", dense_width),
-                        pre + "down_proj", hidden_size)
-        return MoEFeedForward(
-            h, num_hidden=expert_width, num_experts=num_experts,
-            k=experts_per_tok, capacity_factor=0.0, name=pre + "moe",
-            act_type="silu", gated=True, no_bias=True, layer=layer,
-            renormalize=True, score="sigmoid", scale=route_scale,
+    def mlp(h, pre, layer):
+        if layer < dense_layers:
+            return swiglu(h, pre, dense_width, hidden_size)
+        return routed_experts(
+            h, pre, layer, num_experts, experts_per_tok, expert_width,
+            hidden_size, renormalize=True, score="sigmoid", scale=route_scale,
             bias_rate=bias_rate, shared_hidden=shared_width,
-            output_dim=hidden_size, experts_held=experts_held,
-            first_expert=first_expert)
+            experts_held=experts_held, first_expert=first_expert)
 
-    x = sym.Embedding(sym.Variable("data"), input_dim=vocab_size,
-                      output_dim=hidden_size, name="embed")
-    x = sym.Reshape(x, shape=(-1, hidden_size))             # (B*T, D)
+    x = embed(sym.Variable("data"), vocab_size, hidden_size)
     if embed_scale != 1.0:
         x = x * float(embed_scale)
     for l, kind in enumerate(layer_types):
         pre = "l%d_" % l
-        x = x + norm(attention(norm(x, pre + "attn_norm"), pre, l,
-                               kind == "sliding"), pre + "attn_post_norm")
-        x = x + norm(mlp(norm(x, pre + "ffn_norm"), pre, l,
-                         l < dense_layers), pre + "ffn_post_norm")
-    logits = proj(norm(x, "final_norm"), "lm_head", vocab_size)
-    label = sym.Reshape(sym.Variable("softmax_label"), shape=(-1,))
-    loss = sym.SoftmaxCELoss(logits, label, name="lm_loss")
-    return with_load_heads(sym.MakeLoss(loss, normalization="batch",
-                                        name="lm"))
+        x = block(x, pre, rms_eps,
+                  lambda h: attention(h, pre, l, kind == "sliding"),
+                  lambda h: mlp(h, pre, l),
+                  post_norms=("attn_post_norm", "ffn_post_norm"))
+    return with_load_heads(lm_head_loss(x, vocab_size, rms_eps))

@@ -1,0 +1,134 @@
+"""The decoder skeleton: what the LM builders of this package share,
+written once.  A decoder is ``embed`` -> L x ``block`` -> ``lm_head_loss``
+over ``(B*T, D)`` rows; its builder says which mixer (``gqa_attention``,
+``latent_attention``, its own) and which MLP (``swiglu``,
+``routed_experts``) a layer gets, as functions of the normed rows, and its
+head: a sixth decoder is one builder file over this one, a configuration,
+a reference and its tests.  Unnamed nodes are numbered in the order they
+are made and a node takes the attribute scope it is made in, so the order
+of the statements here is part of every builder's symbol, which
+``tests/test_decoder_symbols.py`` holds by its hash.
+"""
+import contextlib
+
+from .. import symbol as sym
+from ..attribute import AttrScope
+from ..moe.layer import MoEFeedForward
+
+
+def norm(x, name, eps):
+    return sym.RMSNorm(x, eps=eps, name=name)
+
+
+def proj(x, name, width, bias=False, **inputs):
+    return sym.FullyConnected(x, num_hidden=width, no_bias=not bias,
+                              name=name, **inputs)
+
+
+def scoped(prefix, kind=None, layer=-1):
+    """The ``__scope__`` attribute scope (``ops.transformer.node_scope``)
+    of one block part, for the device trace.  A part made of plain ops is
+    named ``prefix + kind`` (``.l<layer>`` behind it where the block has
+    an index): ``mla_q.l3``, ``mtp.eh_proj``.  With no ``kind`` the
+    part's ops name their own scope (attention, the expert layer, the
+    loss) and take ``prefix`` alone, before it: ``mtp.`` gives
+    ``mtp.attn``.  ``prefix`` None (or nothing to say): no attribute."""
+    if prefix is None or (kind is None and not prefix):
+        return contextlib.nullcontext()
+    if kind is None:
+        return AttrScope(__scope__=prefix)
+    return AttrScope(__scope__=prefix + kind
+                     + ("" if layer < 0 else ".l%d" % layer))
+
+
+def embed(tokens, vocab_size, hidden_size, name="embed", **inputs):
+    """Ids ``(B, T)`` -> rows ``(B*T, D)``; ``weight=`` shares a table."""
+    x = sym.Embedding(tokens, input_dim=vocab_size, output_dim=hidden_size,
+                      name=name, **inputs)
+    return sym.Reshape(x, shape=(-1, hidden_size))
+
+
+def swiglu(h, pre, width, hidden_size):
+    """The dense MLP: ``(silu(h Wg) * (h Wu)) Wd``."""
+    gate = sym.Activation(proj(h, pre + "gate_proj", width), act_type="silu")
+    return proj(gate * proj(h, pre + "up_proj", width), pre + "down_proj",
+                hidden_size)
+
+
+def routed_experts(h, pre, layer, num_experts, experts_per_tok, expert_width,
+                   hidden_size=0, **router):
+    """The routed expert layer ``pre + "moe"``: SwiGLU experts, no bias,
+    no token-choice dropped.  ``layer`` < 0: no trace index.
+    ``hidden_size``: the output's width, which a shared expert needs said
+    (0: the input's, left to the op).  ``router``: the router's kind and a
+    rank's share, as ``MoEFeedForward`` names them."""
+    return MoEFeedForward(
+        h, num_hidden=expert_width, num_experts=num_experts,
+        k=experts_per_tok, capacity_factor=0.0, name=pre + "moe",
+        act_type="silu", gated=True, no_bias=True,
+        layer=None if layer < 0 else layer, output_dim=hidden_size, **router)
+
+
+def gqa_attention(h, pre, layer, rows, num_heads, num_kv_heads, head_dim,
+                  hidden_size, eps, rotate=lambda x: x, gated=False, **mask):
+    """Grouped-query attention with an RMSNorm over each head's lanes of
+    q and of k, ``(B*rows, D)`` -> ``(B*rows, D)``.  ``rotate`` places q
+    and k (default: no positions but the order), ``mask`` is
+    ``CausalSelfAttention``'s (default: causal), ``gated`` multiplies the
+    heads' outputs by ``sigmoid(h Wg)`` before ``o_proj``.  Scopes:
+    ``attn_proj.l<i>``, ``attn_gate.l<i>``."""
+    width = num_heads * head_dim
+
+    def heads(name, n):
+        return sym.Reshape(proj(h, pre + name + "_proj", n * head_dim),
+                           shape=(-1, rows, n, head_dim))
+
+    def placed(name, n):
+        return rotate(norm(heads(name, n), pre + name + "_norm", eps))
+
+    with scoped("", "attn_proj", layer):
+        q, k = placed("q", num_heads), placed("k", num_kv_heads)
+        v = heads("v", num_kv_heads)
+    a = sym.CausalSelfAttention(q, k, v, layer=layer, name=pre + "attn",
+                                **mask)
+    with scoped("", "attn_gate" if gated else "attn_proj", layer):
+        a = sym.Reshape(a, shape=(-1, width))
+        if gated:
+            a = a * sym.Activation(proj(h, pre + "attn_gate_proj", width),
+                                   act_type="sigmoid")
+    with scoped("", "attn_proj", layer):
+        return proj(a, pre + "o_proj", hidden_size)
+
+
+def block(x, pre, eps, mixer, mlp, mixer_norm="attn_norm",
+          post_norms=(None, None), sum_scopes=(None, None)):
+    """One residual block: ``x + [post](mixer(norm(x)))``, then
+    ``x + [post](mlp(norm(x)))``.  ``mixer`` and ``mlp`` are functions of
+    the normed rows; ``post_norms`` names the two norms inside the
+    branches of a sandwich block.  ``sum_scopes``: the scope a sum is
+    made in (``scoped``), where a builder's symbol has one there."""
+    for branch, pre_norm, post_norm, scope in zip(
+            (mixer, mlp), (mixer_norm, "ffn_norm"), post_norms, sum_scopes):
+        y = branch(norm(x, pre + pre_norm, eps))
+        if post_norm:
+            y = norm(y, pre + post_norm, eps)
+        with scope or contextlib.nullcontext():
+            x = x + y
+    return x
+
+
+def lm_head_loss(x, vocab_size, eps, label=None, head_weight=None,
+                 row_weight=None, **ignoring):
+    """Rows ``(B*T, D)`` -> ``final_norm`` -> ``lm_head`` -> the per-row
+    cross entropy -> the loss head ``lm``, whose gradient is 1 / rows.
+    ``label`` None: ``softmax_label`` ``(B, T)``, flattened.
+    ``head_weight`` shares the head's matrix, ``row_weight`` multiplies
+    the rows' losses, ``ignoring`` is ``SoftmaxCELoss``'s."""
+    if label is None:
+        label = sym.Reshape(sym.Variable("softmax_label"), shape=(-1,))
+    head = {} if head_weight is None else {"weight": head_weight}
+    logits = proj(norm(x, "final_norm", eps), "lm_head", vocab_size, **head)
+    rows = sym.SoftmaxCELoss(logits, label, name="lm_loss", **ignoring)
+    if row_weight is not None:
+        rows = rows * row_weight
+    return sym.MakeLoss(rows, normalization="batch", name="lm")

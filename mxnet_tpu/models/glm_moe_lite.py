@@ -46,8 +46,10 @@ Device scopes (``__scope__`` attributes, ``ops.transformer.node_scope``):
 ``mtp.lm_loss``, ...).
 """
 from .. import symbol as sym
-from ..moe.layer import MoEFeedForward, with_load_heads
-from .latent_attention import latent_attention, scoped
+from ..moe.layer import with_load_heads
+from .decoder import (block, embed, lm_head_loss, norm, proj,
+                      routed_experts, scoped, swiglu)
+from .latent_attention import latent_attention
 
 
 def glm_moe_lite_lm(num_layers, hidden_size, dense_layers, heads,
@@ -62,73 +64,59 @@ def glm_moe_lite_lm(num_layers, hidden_size, dense_layers, heads,
         raise ValueError("glm_moe_lite_lm builds 0 or 1 prediction "
                          "modules, not %r" % (nextn_layers,))
 
-    def norm(x, name):
-        return sym.RMSNorm(x, eps=rms_eps, name=name)
-
-    def proj(x, name, width):
-        return sym.FullyConnected(x, num_hidden=width, no_bias=True,
-                                  name=name)
-
-    def block(x, pre, layer, dense, scope):
+    def layer_block(x, pre, layer, dense, scope):
         """One decoder block; ``layer`` is the ops' trace index (-1:
         none), ``scope`` the prefix of its device scopes."""
-        x = x + latent_attention(
-            norm(x, pre + "mixer_norm"), pre, seq_len, hidden_size, heads,
-            kv_lora_rank, qk_nope_dim, qk_rope_dim, v_head_dim, rms_eps,
-            layer=layer, q_lora_rank=q_lora_rank, rope_theta=rope_theta,
-            scope=scope)
-        h = norm(x, pre + "ffn_norm")
-        if dense:
-            gate = sym.Activation(proj(h, pre + "gate_proj", dense_width),
-                                  act_type="silu")
-            return x + proj(gate * proj(h, pre + "up_proj", dense_width),
-                            pre + "down_proj", hidden_size)
-        with scoped(scope):
-            return x + MoEFeedForward(
-                h, num_hidden=expert_width, num_experts=num_experts,
-                k=experts_per_tok, capacity_factor=0.0, name=pre + "moe",
-                act_type="silu", gated=True, no_bias=True,
-                layer=None if layer < 0 else layer, renormalize=True,
-                score="sigmoid", scale=routed_scale, bias_rate=bias_rate,
-                shared_hidden=shared_width, output_dim=hidden_size,
-                experts_held=experts_held, first_expert=first_expert)
+        def mla(h):
+            return latent_attention(
+                h, pre, seq_len, hidden_size, heads, kv_lora_rank,
+                qk_nope_dim, qk_rope_dim, v_head_dim, rms_eps, layer=layer,
+                q_lora_rank=q_lora_rank, rope_theta=rope_theta, scope=scope)
 
-    def head(x, label, pre, name, **loss):
-        """(B*T, D) residual state -> the per-token loss head ``name``
-        through the shared ``lm_head_weight``."""
-        logits = sym.FullyConnected(norm(x, pre + "final_norm"),
-                                    weight=lm_head, num_hidden=vocab_size,
-                                    no_bias=True, name=pre + "lm_head")
-        return sym.SoftmaxCELoss(logits, sym.Reshape(label, shape=(-1,)),
-                                 name=name, **loss)
+        def mlp(h):
+            if dense:
+                return swiglu(h, pre, dense_width, hidden_size)
+            with scoped(scope):
+                return routed_experts(
+                    h, pre, layer, num_experts, experts_per_tok,
+                    expert_width, hidden_size, renormalize=True,
+                    score="sigmoid", scale=routed_scale,
+                    bias_rate=bias_rate, shared_hidden=shared_width,
+                    experts_held=experts_held, first_expert=first_expert)
 
-    def embed(tokens, name):
-        x = sym.Embedding(tokens, weight=embed_weight, input_dim=vocab_size,
-                          output_dim=hidden_size, name=name)
-        return sym.Reshape(x, shape=(-1, hidden_size))     # (B*T, D)
+        return block(x, pre, rms_eps, mla, mlp, mixer_norm="mixer_norm",
+                     sum_scopes=(None, None if dense else scoped(scope)))
+
+    def flat(label):
+        return sym.Reshape(label, shape=(-1,))
 
     embed_weight = sym.Variable("embed_weight")
     lm_head = sym.Variable("lm_head_weight")
     label = sym.Variable("softmax_label")
-    x = embed(sym.Variable("data"), "embed")
+    x = embed(sym.Variable("data"), vocab_size, hidden_size,
+              weight=embed_weight)
     for l in range(num_layers):
-        x = block(x, "l%d_" % l, l, l < dense_layers, "")
-    heads_out = [sym.MakeLoss(head(x, label, "", "lm_loss"),
-                              normalization="batch", name="lm")]
+        x = layer_block(x, "l%d_" % l, l, l < dense_layers, "")
+    heads_out = [lm_head_loss(x, vocab_size, rms_eps, label=flat(label),
+                              head_weight=lm_head)]
     if nextn_layers:
         with scoped("mtp.", "eh_proj"):
-            u = proj(sym.Concat(norm(embed(label, "mtp_embed"), "mtp_enorm"),
-                                norm(x, "mtp_hnorm"), dim=1),
-                     "mtp_eh_proj", hidden_size)
-        u = block(u, "mtp_", -1, False, "mtp.")
+            u = proj(sym.Concat(
+                norm(embed(label, vocab_size, hidden_size, "mtp_embed",
+                           weight=embed_weight), "mtp_enorm", rms_eps),
+                norm(x, "mtp_hnorm", rms_eps), dim=1),
+                "mtp_eh_proj", hidden_size)
+        u = layer_block(u, "mtp_", -1, False, "mtp.")
         # the target of position i is the token after its label: the
         # labels moved one place, the sequence's last position left out
         last = sym.slice_axis(label, axis=1, begin=0, end=1) * 0 - 1
         target = sym.Concat(sym.slice_axis(label, axis=1, begin=1,
                                            end=seq_len), last, dim=1)
         with scoped("mtp."):
-            loss = head(u, target, "mtp_", "mtp_loss", use_ignore=True,
-                        ignore_label=-1)
+            logits = proj(norm(u, "mtp_final_norm", rms_eps), "mtp_lm_head",
+                          vocab_size, weight=lm_head)
+            loss = sym.SoftmaxCELoss(logits, flat(target), name="mtp_loss",
+                                     use_ignore=True, ignore_label=-1)
         heads_out.append(sym.MakeLoss(loss, grad_scale=float(mtp_weight),
                                       normalization="valid", name="mtp"))
     return with_load_heads(sym.Group(heads_out))

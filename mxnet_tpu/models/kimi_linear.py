@@ -37,7 +37,9 @@ head normalizes its own gradient, so ``rescale_grad`` is 1; there is no
 load-balance loss (the selection bias balances).
 """
 from .. import symbol as sym
-from ..moe.layer import MoEFeedForward, with_load_heads
+from ..moe.layer import with_load_heads
+from .decoder import (block, embed, lm_head_loss, norm, proj,
+                      routed_experts, swiglu)
 from .latent_attention import latent_attention
 
 
@@ -50,27 +52,18 @@ def kimi_linear_lm(num_layers, hidden_size, full_attn_layers, dense_layers,
                    rms_eps=1e-5):
     """The training symbol; see the module docstring.  No KDA core is
     marked ``force_mirroring``: where the op's kernels run (heads of 128,
-    whole chunks, a TPU) its backward pass keeps the op's inputs and the
-    chunks' entry states, and the backward kernel computes the float32
-    chunk products again itself (0.6 GB a layer at 4096 tokens of the
-    published widths, were they kept)."""
+    whole chunks, a TPU) its backward pass keeps the op's inputs, the
+    chunks' entry states and the three products the forward kernel
+    formed (``A``, ``Bs``, ``T``), and the backward kernel reads them
+    (134 MB + 100.7 MB a layer at 4096 tokens of the published widths)."""
     full_attn_layers = set(full_attn_layers)
     kda_width = kda_heads * kda_head_dim
-
-    def norm(x, name):
-        return sym.RMSNorm(x, eps=rms_eps, name=name)
-
-    def proj(x, name, width, bias=False):
-        return sym.FullyConnected(x, num_hidden=width, no_bias=not bias,
-                                  name=name)
-
-    def silu(x):
-        return sym.Activation(x, act_type="silu")
 
     def kda(h, pre, l):
         def conv(x, name):
             x = sym.Reshape(x, shape=(-1, seq_len, kda_width))
-            x = silu(sym.CausalConv1D(x, kernel=conv_kernel, name=name))
+            x = sym.Activation(sym.CausalConv1D(x, kernel=conv_kernel,
+                                                name=name), act_type="silu")
             return sym.Reshape(x, shape=(-1, seq_len, kda_heads,
                                          kda_head_dim))
 
@@ -84,7 +77,8 @@ def kimi_linear_lm(num_layers, hidden_size, full_attn_layers, dense_layers,
                            shape=(-1, seq_len, kda_heads))
         o = sym.KimiDeltaAttention(q, k, v, decay, beta, layer=l,
                                    name=pre + "kda")
-        o = norm(sym.Reshape(o, shape=(-1, kda_head_dim)), pre + "o_norm")
+        o = norm(sym.Reshape(o, shape=(-1, kda_head_dim)), pre + "o_norm",
+                 rms_eps)
         gate = proj(proj(h, pre + "g_down", kda_head_dim),
                     pre + "g_up", kda_width, bias=True)
         gate = sym.Activation(sym.Reshape(gate, shape=(-1, kda_head_dim)),
@@ -93,34 +87,23 @@ def kimi_linear_lm(num_layers, hidden_size, full_attn_layers, dense_layers,
                     pre + "o_proj", hidden_size)
 
     def mla(h, pre, l):
-        return latent_attention(
-            h, pre, seq_len, hidden_size, mla_heads, kv_lora_rank,
-            qk_nope_dim, qk_rope_dim, v_head_dim, rms_eps, layer=l,
-            scope="")
+        return latent_attention(h, pre, seq_len, hidden_size, mla_heads,
+                                kv_lora_rank, qk_nope_dim, qk_rope_dim,
+                                v_head_dim, rms_eps, layer=l, scope="")
 
-    x = sym.Embedding(sym.Variable("data"), input_dim=vocab_size,
-                      output_dim=hidden_size, name="embed")
-    x = sym.Reshape(x, shape=(-1, hidden_size))           # (B*T, D)
+    def mlp(h, pre, l):
+        if l <= dense_layers:
+            return swiglu(h, pre, dense_width, hidden_size)
+        return routed_experts(
+            h, pre, l, num_experts, experts_per_tok, expert_width,
+            hidden_size, renormalize=True, score="sigmoid", scale=routed_scale,
+            bias_rate=bias_rate, shared_hidden=shared_width,
+            experts_held=experts_held, first_expert=first_expert)
+
+    x = embed(sym.Variable("data"), vocab_size, hidden_size)
     for l in range(1, num_layers + 1):
         pre = "l%d_" % l
-        h = norm(x, pre + "mixer_norm")
-        x = x + (mla if l in full_attn_layers else kda)(h, pre, l)
-        h = norm(x, pre + "ffn_norm")
-        if l <= dense_layers:
-            x = x + proj(silu(proj(h, pre + "gate_proj", dense_width))
-                         * proj(h, pre + "up_proj", dense_width),
-                         pre + "down_proj", hidden_size)
-        else:
-            x = x + MoEFeedForward(
-                h, num_hidden=expert_width, num_experts=num_experts,
-                k=experts_per_tok, capacity_factor=0.0, name=pre + "moe",
-                act_type="silu", gated=True, no_bias=True, layer=l,
-                renormalize=True, score="sigmoid", scale=routed_scale,
-                bias_rate=bias_rate, shared_hidden=shared_width,
-                output_dim=hidden_size, experts_held=experts_held,
-                first_expert=first_expert)
-    logits = proj(norm(x, "final_norm"), "lm_head", vocab_size)
-    label = sym.Reshape(sym.Variable("softmax_label"), shape=(-1,))
-    loss = sym.SoftmaxCELoss(logits, label, name="lm_loss")
-    return with_load_heads(
-        sym.MakeLoss(loss, normalization="batch", name="lm"))
+        mixer = mla if l in full_attn_layers else kda
+        x = block(x, pre, rms_eps, lambda h: mixer(h, pre, l),
+                  lambda h: mlp(h, pre, l), mixer_norm="mixer_norm")
+    return with_load_heads(lm_head_loss(x, vocab_size, rms_eps))

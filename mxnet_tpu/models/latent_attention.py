@@ -14,26 +14,8 @@ rope part of every query head and the shared key part at positions
 lanes), 0 leaves both plain projections.  The softmax scale is
 ``(qk_nope_dim + qk_rope_dim) ** -0.5`` (``CausalSelfAttention``'s own).
 """
-import contextlib
-
 from .. import symbol as sym
-from ..attribute import AttrScope
-
-
-def scoped(prefix, kind=None, layer=-1):
-    """The ``__scope__`` attribute scope (``ops.transformer.node_scope``)
-    of one block part, for the device trace.  A part made of plain ops is
-    named ``prefix + kind`` (``.l<layer>`` behind it where the block has
-    an index): ``mla_q.l3``, ``mtp.eh_proj``.  With no ``kind`` the
-    part's ops name their own scope (attention, the expert layer, the
-    loss) and take ``prefix`` alone, before it: ``mtp.`` gives
-    ``mtp.attn``.  ``prefix`` None (or nothing to say): no attribute."""
-    if prefix is None or (kind is None and not prefix):
-        return contextlib.nullcontext()
-    if kind is None:
-        return AttrScope(__scope__=prefix)
-    return AttrScope(__scope__=prefix + kind
-                     + ("" if layer < 0 else ".l%d" % layer))
+from .decoder import norm, proj, scoped
 
 
 def latent_attention(h, pre, seq_len, hidden_size, heads, kv_lora_rank,
@@ -47,24 +29,15 @@ def latent_attention(h, pre, seq_len, hidden_size, heads, kv_lora_rank,
     ``mla_q``, ``mla_kv``, ``rope``; ``""`` for a trunk's block,
     ``"mtp."`` inside a prediction module); None sets no attribute and
     leaves the symbol as it was before scopes."""
-    def within(kind=None):
-        return scoped(scope, kind, layer)
-
-    def norm(x, name):
-        return sym.RMSNorm(x, eps=rms_eps, name=name)
-
-    def proj(x, name, width):
-        return sym.FullyConnected(x, num_hidden=width, no_bias=True,
-                                  name=name)
-
     def rotate(x):
-        with within("rope"):
+        with scoped(scope, "rope", layer):
             return sym.RotaryEmbedding(x, theta=rope_theta)
 
     qk_dim = qk_nope_dim + qk_rope_dim
-    with within("mla_q"):
+    with scoped(scope, "mla_q", layer):
         q = proj(norm(proj(h, pre + "q_a_proj", q_lora_rank),
-                      pre + "q_a_norm"), pre + "q_b_proj", heads * qk_dim) \
+                      pre + "q_a_norm", rms_eps),
+                 pre + "q_b_proj", heads * qk_dim) \
             if q_lora_rank else proj(h, pre + "q_proj", heads * qk_dim)
         q = sym.Reshape(q, shape=(-1, seq_len, heads, qk_dim))
     if rope_theta:
@@ -72,10 +45,11 @@ def latent_attention(h, pre, seq_len, hidden_size, heads, kv_lora_rank,
             sym.slice_axis(q, axis=3, begin=0, end=qk_nope_dim),
             rotate(sym.slice_axis(q, axis=3, begin=qk_nope_dim, end=qk_dim)),
             dim=3)
-    with within("mla_kv"):
+    with scoped(scope, "mla_kv", layer):
         kv_a = proj(h, pre + "kv_a_proj", kv_lora_rank + qk_rope_dim)
         latent = norm(sym.slice_axis(kv_a, axis=1, begin=0,
-                                     end=kv_lora_rank), pre + "kv_a_norm")
+                                     end=kv_lora_rank), pre + "kv_a_norm",
+                      rms_eps)
         k_shared = sym.Reshape(
             sym.slice_axis(kv_a, axis=1, begin=kv_lora_rank,
                            end=kv_lora_rank + qk_rope_dim),
@@ -91,7 +65,7 @@ def latent_attention(h, pre, seq_len, hidden_size, heads, kv_lora_rank,
             sym.broadcast_axis(k_shared, axis=2, size=heads), dim=3)
         v = sym.slice_axis(kv, axis=3, begin=qk_nope_dim,
                            end=qk_nope_dim + v_head_dim)
-    with within():
+    with scoped(scope):
         a = sym.CausalSelfAttention(q, k, v, layer=layer, name=pre + "attn")
     return proj(sym.Reshape(a, shape=(-1, heads * v_head_dim)),
                 pre + "o_proj", hidden_size)

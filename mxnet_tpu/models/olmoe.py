@@ -18,7 +18,9 @@ normalizes its own gradient (1 / tokens), so the optimizer's
 sum(load balance)``.
 """
 from .. import symbol as sym
-from ..moe.layer import MoEFeedForward, with_aux_loss, with_load_heads
+from ..moe.layer import with_aux_loss, with_load_heads
+from .decoder import (block, embed, lm_head_loss, norm, proj,
+                      routed_experts)
 
 
 def olmoe_lm(num_layers, hidden_size, num_heads, num_experts,
@@ -30,39 +32,23 @@ def olmoe_lm(num_layers, hidden_size, num_heads, num_experts,
         raise ValueError("hidden_size %d is not num_heads %d x head_dim"
                          % (hidden_size, num_heads))
 
-    def norm(x, name):
-        return sym.RMSNorm(x, eps=rms_eps, name=name)
-
-    def proj(x, name, width=hidden_size):
-        return sym.FullyConnected(x, num_hidden=width, no_bias=True,
-                                  name=name)
-
     def heads(x):
         return sym.Reshape(x, shape=(-1, seq_len, num_heads, head_dim))
 
-    x = sym.Embedding(sym.Variable("data"), input_dim=vocab_size,
-                      output_dim=hidden_size, name="embed")
-    x = sym.Reshape(x, shape=(-1, hidden_size))           # (B*T, D)
+    def attention(h, pre, l):
+        q, k = (sym.RotaryEmbedding(heads(norm(
+            proj(h, pre + s + "_proj", hidden_size), pre + s + "_norm",
+            rms_eps)), theta=rope_theta) for s in "qk")
+        v = heads(proj(h, pre + "v_proj", hidden_size))
+        a = sym.CausalSelfAttention(q, k, v, layer=l, name=pre + "attn")
+        return proj(sym.Reshape(a, shape=(-1, hidden_size)),
+                    pre + "o_proj", hidden_size)
+
+    x = embed(sym.Variable("data"), vocab_size, hidden_size)
     for l in range(num_layers):
         pre = "l%d_" % l
-        h = norm(x, pre + "attn_norm")
-        q = norm(proj(h, pre + "q_proj"), pre + "q_norm")
-        k = norm(proj(h, pre + "k_proj"), pre + "k_norm")
-        v = proj(h, pre + "v_proj")
-        q = sym.RotaryEmbedding(heads(q), theta=rope_theta)
-        k = sym.RotaryEmbedding(heads(k), theta=rope_theta)
-        a = sym.CausalSelfAttention(q, k, heads(v), layer=l,
-                                    name=pre + "attn")
-        a = sym.Reshape(a, shape=(-1, hidden_size))
-        x = x + proj(a, pre + "o_proj")
-        h = norm(x, pre + "ffn_norm")
-        x = x + MoEFeedForward(h, num_hidden=expert_width,
-                               num_experts=num_experts, k=experts_per_tok,
-                               capacity_factor=0.0, name=pre + "moe",
-                               act_type="silu", gated=True, no_bias=True,
-                               layer=l)
-    logits = proj(norm(x, "final_norm"), "lm_head", vocab_size)
-    label = sym.Reshape(sym.Variable("softmax_label"), shape=(-1,))
-    loss = sym.SoftmaxCELoss(logits, label, name="lm_loss")
-    net = sym.MakeLoss(loss, normalization="batch", name="lm")
+        x = block(x, pre, rms_eps, lambda h: attention(h, pre, l),
+                  lambda h: routed_experts(h, pre, l, num_experts,
+                                           experts_per_tok, expert_width))
+    net = lm_head_loss(x, vocab_size, rms_eps)
     return with_load_heads(with_aux_loss(net, grad_scale=aux_coef))

@@ -41,10 +41,10 @@ Device scopes: ``attn_proj.l<i>`` (projections, head norms, rotation)
 beside the ops' own ``attn.l<i>``, ``moe_*.l<i>`` and ``lm_loss``.
 """
 from .. import symbol as sym
-from ..moe.layer import MoEFeedForward, with_aux_loss, with_load_heads
-from .latent_attention import scoped
-
-NOISE_HEAD = "diffusion_noise"
+from ..module.fused import NOISE_HEAD
+from ..moe.layer import with_aux_loss, with_load_heads
+from .decoder import (block, embed, gqa_attention, lm_head_loss,
+                      routed_experts, scoped)
 
 
 def sdar_moe_lm(num_layers, hidden_size, num_heads, num_kv_heads, head_dim,
@@ -60,59 +60,32 @@ def sdar_moe_lm(num_layers, hidden_size, num_heads, num_kv_heads, head_dim,
         raise ValueError("seq_len %d is not whole blocks of %d"
                          % (seq_len, block_len))
     rows = 2 * seq_len
-
-    def norm(x, name):
-        return sym.RMSNorm(x, eps=rms_eps, name=name)
-
-    def proj(x, name, width):
-        return sym.FullyConnected(x, num_hidden=width, no_bias=True,
-                                  name=name)
-
-    def heads(x, n):
-        return sym.Reshape(x, shape=(-1, rows, n, head_dim))
-
-    def rotate(x):
-        return sym.RotaryEmbedding(x, theta=rope_theta, period=seq_len)
-
-    x = sym.Embedding(sym.Variable("data"), input_dim=vocab_size,
-                      output_dim=hidden_size, name="embed")
-    x = sym.Reshape(x, shape=(-1, hidden_size))           # (B*2T, D)
+    x = embed(sym.Variable("data"), vocab_size, hidden_size)  # (B*2T, D)
     for l in range(num_layers):
         pre = "l%d_" % l
-        h = norm(x, pre + "attn_norm")
-        with scoped("", "attn_proj", l):
-            q = rotate(norm(heads(proj(h, pre + "q_proj",
-                                       num_heads * head_dim), num_heads),
-                            pre + "q_norm"))
-            k = rotate(norm(heads(proj(h, pre + "k_proj",
-                                       num_kv_heads * head_dim),
-                                  num_kv_heads), pre + "k_norm"))
-            v = heads(proj(h, pre + "v_proj", num_kv_heads * head_dim),
-                      num_kv_heads)
-        a = sym.CausalSelfAttention(q, k, v, layer=l, name=pre + "attn",
-                                    mask="block_diffusion", block=block_len)
-        with scoped("", "attn_proj", l):
-            x = x + proj(sym.Reshape(a, shape=(-1, num_heads * head_dim)),
-                         pre + "o_proj", hidden_size)
-        x = x + MoEFeedForward(
-            norm(x, pre + "ffn_norm"), num_hidden=expert_width,
-            num_experts=num_experts, k=experts_per_tok, capacity_factor=0.0,
-            name=pre + "moe", act_type="silu", gated=True, no_bias=True,
-            layer=l, renormalize=True, score="softmax",
-            output_dim=hidden_size, experts_held=experts_held,
-            first_expert=first_expert)
+        # this builder's first sum lies in its ``o_proj``'s scope
+        x = block(
+            x, pre, rms_eps,
+            lambda h: gqa_attention(
+                h, pre, l, rows, num_heads, num_kv_heads, head_dim,
+                hidden_size, rms_eps, mask="block_diffusion", block=block_len,
+                rotate=lambda t: sym.RotaryEmbedding(t, theta=rope_theta,
+                                                     period=seq_len)),
+            lambda h: routed_experts(
+                h, pre, l, num_experts, experts_per_tok, expert_width,
+                hidden_size, renormalize=True, score="softmax",
+                experts_held=experts_held, first_expert=first_expert),
+            sum_scopes=(scoped("", "attn_proj", l), None))
     # the head reads the noised half: rows 0..T-1 of each sequence
     noised = sym.slice_axis(sym.Reshape(x, shape=(-1, rows, hidden_size)),
                             axis=1, begin=0, end=seq_len)
-    logits = proj(norm(sym.Reshape(noised, shape=(-1, hidden_size)),
-                       "final_norm"), "lm_head", vocab_size)
+    noised = sym.Reshape(noised, shape=(-1, hidden_size))
     label = sym.Variable("softmax_label")                  # (B, 2, T)
     target, weight = (sym.Reshape(sym.slice_axis(label, axis=1, begin=i,
                                                  end=i + 1), shape=(-1,))
                       for i in (0, 1))
-    loss = sym.SoftmaxCELoss(logits, target, use_ignore=True,
-                             ignore_label=-1, name="lm_loss") * weight
-    net = sym.MakeLoss(loss, normalization="batch", name="lm")
+    net = lm_head_loss(noised, vocab_size, rms_eps, label=target,
+                       row_weight=weight, use_ignore=True, ignore_label=-1)
     if aux_coef:
         net = with_aux_loss(net, grad_scale=aux_coef)
     masked = sym.sign(target + 1.0)             # 1 where there is a target

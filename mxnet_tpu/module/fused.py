@@ -81,12 +81,14 @@ def find_prediction_heads(symbol):
             float(p.valid_thresh) if p.normalization == "valid" else None)
 
 
+NOISE_HEAD = "diffusion_noise"
+
+
 def find_noise_head(symbol):
     """The index of the output ``diffusion_noise_output``: the ``(3,)``
     head a block-diffusion symbol groups on (``models.sdar_moe``): the
     step's masked positions, positions, and the masked positions' summed
     weights, behind a ``BlockGrad``.  None for a symbol without one."""
-    from ..models.sdar_moe import NOISE_HEAD
     for i, (node, _) in enumerate(symbol._heads):
         if not node.is_variable and node.name == NOISE_HEAD \
                 and getattr(node.op, "name", "") == "BlockGrad":

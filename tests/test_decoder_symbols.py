@@ -1,0 +1,244 @@
+"""The decoder builders' symbols, node for node (ISSUE 46, tier-1).
+
+The five LM builders of ``mxnet_tpu/models`` are assembled from one
+skeleton, ``models/decoder.py``.  What holds that assembly still is the
+graph each builder returns: under a fresh ``NameManager`` the symbol's
+JSON (every node's op, name, keywords, attributes and inputs, the unnamed
+nodes numbered in the order they were made) hashes to what the commit
+before the skeleton (945e6c8, each builder its own trunk) gave for the
+same arguments.  Same symbol -> same graph program -> same lowered step.
+
+Every hash here was taken at that commit by this file's own ``_build``:
+the five cells' ``model.kwargs`` (``benchmark/configs/*.json``) and a
+tiny-width case for each branch a builder has.  Beside them the lowered
+text of a tiny step of the three builders whose step no other test holds
+(OLMoE's is in ``tests/test_sdar_moe.py``, SDAR's in
+``tests/test_afmoe.py``).  A new branch takes a new case, its hash from
+the commit that adds it; a hash is never re-taken to make a refactor
+pass."""
+import hashlib
+import importlib
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu import models
+from mxnet_tpu import symbol as sym
+from mxnet_tpu.executor import _GraphProgram
+from mxnet_tpu.models.latent_attention import latent_attention
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+OLMOE = dict(num_layers=2, hidden_size=32, num_heads=2, num_experts=8,
+             experts_per_tok=2, expert_width=16, vocab_size=64, seq_len=16)
+KIMI = dict(num_layers=5, hidden_size=32, full_attn_layers=[4, 8],
+            dense_layers=1, kda_heads=2, kda_head_dim=8, conv_kernel=4,
+            mla_heads=2, kv_lora_rank=16, qk_nope_dim=8, qk_rope_dim=4,
+            v_head_dim=8, dense_width=64, num_experts=16, experts_per_tok=4,
+            expert_width=24, shared_width=24, routed_scale=2.446,
+            vocab_size=50, seq_len=72, experts_held=4, first_expert=4,
+            bias_rate=1e-3, rms_eps=1e-5)
+GLM = dict(num_layers=3, hidden_size=32, dense_layers=1, heads=2,
+           q_lora_rank=12, kv_lora_rank=16, qk_nope_dim=8, qk_rope_dim=4,
+           v_head_dim=12, rope_theta=1e6, dense_width=64, num_experts=16,
+           experts_per_tok=4, expert_width=24, shared_width=24,
+           routed_scale=1.8, vocab_size=50, seq_len=24, nextn_layers=1,
+           mtp_weight=0.3, experts_held=4, first_expert=4, bias_rate=1e-3,
+           rms_eps=1e-5)
+SDAR = dict(num_layers=2, hidden_size=32, num_heads=4, num_kv_heads=2,
+            head_dim=8, num_experts=16, experts_per_tok=4, expert_width=24,
+            vocab_size=50, seq_len=16, block_len=4, rope_theta=1e6,
+            rms_eps=1e-6, aux_coef=0.001, experts_held=4, first_expert=4)
+AFMOE = dict(num_layers=4, hidden_size=32,
+             layer_types=["sliding", "sliding", "sliding", "full"],
+             dense_layers=1, num_heads=4, num_kv_heads=2, head_dim=8,
+             window=6, rope_theta=1e4, dense_width=48, num_experts=16,
+             experts_per_tok=4, expert_width=24, shared_width=24,
+             route_scale=2.826, vocab_size=50, seq_len=16,
+             embed_scale=32 ** 0.5, experts_held=4, first_expert=4,
+             bias_rate=1e-3, rms_eps=1e-5)
+WHOLE = dict(experts_held=0, first_expert=0)
+# latent attention by itself: seq_len, hidden_size, heads, kv_lora_rank,
+# qk_nope_dim, qk_rope_dim, v_head_dim, rms_eps
+MLA = (40, 32, 2, 16, 8, 4, 12, 1e-5)
+
+
+def _cell(config):
+    """A cell's own builder and arguments, as ``benchmark/`` reads them."""
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           config + ".json")) as f:
+        model = json.load(f)["model"]
+    module, name = model["builder"].rsplit(".", 1)
+    return getattr(importlib.import_module(module), name), model["kwargs"]
+
+
+def _tiny(builder, base, **over):
+    return getattr(models, builder), dict(base, **over)
+
+
+def _mla(**kwargs):
+    return (lambda: latent_attention(sym.Variable("h"), "l4_", *MLA,
+                                     layer=4, **kwargs)), {}
+
+
+# id -> (builder, its arguments)
+SYMBOLS = {
+    # the cells' configurations: the hashes of ISSUE 46's table
+    "olmoe-1b-7b": _cell("olmoe-1b-7b"),
+    "kimi-linear-48b-a3b": _cell("kimi-linear-48b-a3b"),
+    "glm-4.7-flash": _cell("glm-4.7-flash"),
+    "sdar-30b-a3b": _cell("sdar-30b-a3b"),
+    "trinity-mini": _cell("trinity-mini"),
+    # OLMoE: its load-balance heads stay on at coefficient 0
+    "olmoe-tiny": _tiny("olmoe_lm", OLMOE),
+    "olmoe-aux-0": _tiny("olmoe_lm", OLMOE, aux_coef=0.0),
+    # Kimi: a rank's share and the whole layer; which layers attend in
+    # full and which are dense, counted from 1
+    "kimi-share": _tiny("kimi_linear_lm", KIMI),
+    "kimi-whole": _tiny("kimi_linear_lm", KIMI, **WHOLE),
+    "kimi-mixed": _tiny("kimi_linear_lm", KIMI, full_attn_layers=[1, 3, 5],
+                        dense_layers=3),
+    # GLM: with and without its prediction module and query compression
+    "glm-share": _tiny("glm_moe_lite_lm", GLM),
+    "glm-whole": _tiny("glm_moe_lite_lm", GLM, **WHOLE),
+    "glm-no-mtp": _tiny("glm_moe_lite_lm", GLM, nextn_layers=0),
+    "glm-plain-q": _tiny("glm_moe_lite_lm", GLM, q_lora_rank=0),
+    # SDAR: its load-balance heads go at coefficient 0
+    "sdar-share": _tiny("sdar_moe_lm", SDAR),
+    "sdar-whole": _tiny("sdar_moe_lm", SDAR, **WHOLE),
+    "sdar-aux-0": _tiny("sdar_moe_lm", SDAR, aux_coef=0.0),
+    # AFMoE: the embedding's scale, each kind of layer alone, the dense
+    # layers
+    "afmoe-share": _tiny("afmoe_lm", AFMOE),
+    "afmoe-whole": _tiny("afmoe_lm", AFMOE, **WHOLE),
+    "afmoe-unscaled": _tiny("afmoe_lm", AFMOE, embed_scale=1.0),
+    "afmoe-sliding": _tiny("afmoe_lm", AFMOE, layer_types=["sliding"] * 4),
+    "afmoe-full": _tiny("afmoe_lm", AFMOE, layer_types=["full"] * 4),
+    "afmoe-no-dense": _tiny("afmoe_lm", AFMOE, dense_layers=0),
+    "afmoe-all-dense": _tiny("afmoe_lm", AFMOE, dense_layers=4),
+    # latent attention by itself: no scope attribute at all, and the
+    # compressed, rotated form under a prediction module's prefix
+    "mla-unscoped": _mla(),
+    "mla-mtp": _mla(q_lora_rank=12, rope_theta=1e6, scope="mtp."),
+}
+
+# sha256 of each symbol's JSON at 945e6c8; the first five are ISSUE 46's
+# table
+SYMBOL_WAS = {
+    "olmoe-1b-7b":
+        "3010508f9af6d214d25b4ea18ba84994901ecdb99e011aa1a16b0b8e402fb4b7",
+    "kimi-linear-48b-a3b":
+        "a09491b671c7c595c325ebaecac166464282947bd39b524ddfea1ad3bb17d556",
+    "glm-4.7-flash":
+        "787b1b2114b39c79c8efd33a4dd949f218c07bf94a39b900e6b0333c00100433",
+    "sdar-30b-a3b":
+        "c0ee067be3ca69380c71a9e30645329c7717f3e867b201153c5744dd812f7d83",
+    "trinity-mini":
+        "3e5e7ce8c564e558445076d7db9f435b29431733b2063a6ccb1fbe3797ed571f",
+    "olmoe-tiny":
+        "af8dc705e9e7aeb26b2806967a870d607de9f4892070d6b09552c77d2034efc0",
+    "olmoe-aux-0":
+        "56de2d26c2c517cb4762f53be03544ae0560a3f024c25b59df3c4986f064eddb",
+    "kimi-share":
+        "c4188086853a4d8cff3b2c70af8edd016b600b1521675eef5d61b341d59a92d6",
+    "kimi-whole":
+        "0effb24bd436fac90b5a3b09daf8e2d8084eeee2a8e5a89aef209baab0986bfa",
+    "kimi-mixed":
+        "06d26997f08401c2a6bb0b7bf88146db74a8f93d5d9247d87327873d7adcc158",
+    "glm-share":
+        "8fcc0fbc997e1497b3671ef97ad37ddaf922eea12d3c38139aebc9553073a9a8",
+    "glm-whole":
+        "0701683aeeffa8ac821097b06c39f3f81e4255258222735c908a2ef4a3ee6e36",
+    "glm-no-mtp":
+        "177747956dddc152220380fad55c4483f1af51aec890e7265bf15a9b62719104",
+    "glm-plain-q":
+        "6388f2f59006d9c6031358443a95311a8d690772ed624c4b3c4c673f5fb69b79",
+    "sdar-share":
+        "2d83d30a65d2bcf58ab95c9077e927f7e8a2326837e7cfc66c47ea0a67bccad0",
+    "sdar-whole":
+        "13409b7766155319f82afed3b1c2536b86b9993abbcfc854f68494f61894e479",
+    "sdar-aux-0":
+        "6adca4ee43a9aa21f1fd67750ea029348468f27f65a5fd7de4fe0477c26136ac",
+    "afmoe-share":
+        "132bec7ac759c3c3632747c8cde3f428bc6442ad740b9d5f0d007dab36be2c18",
+    "afmoe-whole":
+        "5e07e8ae61be77e12a75d79aa493dd2170f420e491d96ae13df5f51563f80d67",
+    "afmoe-unscaled":
+        "1c81e3650bab94348e9d11383067421b02ae37f743c703507066b76e61495fa2",
+    "afmoe-sliding":
+        "9113a9b5c92a50d0a10a74e9a8cc5b9dba602b1142e62b48de2c96ff94f424cd",
+    "afmoe-full":
+        "f8d42f162b8401c7ab71763eef66a3bee5833dbe2e091b0bef39ff04e6ef447e",
+    "afmoe-no-dense":
+        "24a718720548b59203a2426cc1ec455419000c8b861142763e21da7c6e09ff57",
+    "afmoe-all-dense":
+        "cf725ecb8b1155d7d3e7943bc4c8360d8ae70a52464164bc47b0a2693269c410",
+    "mla-unscoped":
+        "abfabac33e4456b26e6087fd726edff992019de2edcfabf201ea739a7275c832",
+    "mla-mtp":
+        "cf9dd247a23b054411934d6c18ae766ffc59b532cb7a19ce457da1040d143806",
+}
+
+
+def _build(builder, kwargs):
+    with mx.name.NameManager():
+        return builder(**kwargs)
+
+
+@pytest.mark.parametrize("case", sorted(SYMBOLS))
+def test_the_symbol_is_node_for_node_what_it_was(case):
+    text = _build(*SYMBOLS[case]).tojson()
+    assert hashlib.sha256(text.encode()).hexdigest() == SYMBOL_WAS[case]
+
+
+# id -> (builder, its arguments), the step's inputs
+STEPS = {
+    "kimi": (_tiny("kimi_linear_lm", KIMI, num_layers=4),
+             dict(data=(2, 72), softmax_label=(2, 72))),
+    "glm": (_tiny("glm_moe_lite_lm", GLM),
+            dict(data=(2, 24), softmax_label=(2, 24))),
+    "afmoe": (_tiny("afmoe_lm", AFMOE),
+              dict(data=(2, 16), softmax_label=(2, 16))),
+}
+
+# sha256 of each step's lowered text at 945e6c8
+STEP_WAS = {
+    "kimi":
+        "91d06b67a624693589abcc3e239cd8f92198f03787b307f377eb8f36c898cffa",
+    "glm":
+        "3a00c3fc2d2fd85cce626d1955a2174d85b0f52940d1f41a8edac6b24be37a9f",
+    "afmoe":
+        "56a01f25a20c009757a2834695e48964ac2b23da9fcc48d26a5cf9c814f7356e",
+}
+
+
+@pytest.mark.parametrize("case", sorted(STEPS))
+def test_the_symbols_lowered_step_is_what_it_was(case):
+    """Forward and every gradient of a tiny step lower to the text the
+    commit before the skeleton gave (lowering only: nothing compiles)."""
+    built, inputs = STEPS[case]
+    net = _build(*built)
+    shapes, _, aux_shapes = net.infer_shape(**inputs)
+    args = {n: jax.ShapeDtypeStruct(s, jnp.int32 if n in inputs
+                                    else jnp.float32)
+            for n, s in zip(net.list_arguments(), shapes)}
+    aux = {n: jax.ShapeDtypeStruct(s, jnp.float32)
+           for n, s in zip(net.list_auxiliary_states(), aux_shapes)}
+    prog = _GraphProgram(net, {}, None, do_mirror=False)
+
+    def loss(a, x):
+        outs = prog.eval(a, x, jax.random.PRNGKey(0), True)[0]
+        return sum(jnp.sum(o.astype(jnp.float32)) for o in outs)
+
+    def step(p, x, d, l):
+        return jax.value_and_grad(
+            lambda p: loss(dict(p, data=d, softmax_label=l), x))(p)
+
+    params = {k: v for k, v in args.items() if k not in inputs}
+    text = jax.jit(step).lower(params, aux, args["data"],
+                               args["softmax_label"]).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == STEP_WAS[case]
