@@ -44,10 +44,8 @@ product) beside the ops' own ``attn.l<i>``, ``moe_*.l<i>`` and
 """
 from .. import symbol as sym
 from ..moe.layer import with_load_heads
-from .decoder import (block, embed, gqa_attention, lm_head_loss,
+from .decoder import (block, embed, kind_attention, layer_kinds, lm_head_loss,
                       routed_experts, swiglu)
-
-LAYER_KINDS = ("sliding", "full")
 
 
 def afmoe_lm(num_layers, hidden_size, layer_types, dense_layers, num_heads,
@@ -56,22 +54,10 @@ def afmoe_lm(num_layers, hidden_size, layer_types, dense_layers, num_heads,
              route_scale, vocab_size, seq_len, embed_scale=1.0,
              experts_held=0, first_expert=0, bias_rate=1e-3, rms_eps=1e-5):
     """The training symbol; see the module docstring."""
-    layer_types = list(layer_types)
-    if len(layer_types) != num_layers \
-            or any(kind not in LAYER_KINDS for kind in layer_types):
-        raise ValueError("layer_types %r: %d layers, each one of %s"
-                         % (layer_types, num_layers, LAYER_KINDS))
+    layer_types = layer_kinds(layer_types, num_layers)
     if num_heads % num_kv_heads:
         raise ValueError("%d query heads over %d key/value heads"
                          % (num_heads, num_kv_heads))
-
-    def attention(h, pre, layer, sliding):
-        """The kind is the op's mask and whether the heads are rotated;
-        nothing else differs."""
-        how = dict(rotate=lambda t: sym.RotaryEmbedding(t, theta=rope_theta),
-                   mask="sliding_window", window=window) if sliding else {}
-        return gqa_attention(h, pre, layer, seq_len, num_heads, num_kv_heads,
-                             head_dim, hidden_size, rms_eps, gated=True, **how)
 
     def mlp(h, pre, layer):
         if layer < dense_layers:
@@ -88,7 +74,10 @@ def afmoe_lm(num_layers, hidden_size, layer_types, dense_layers, num_heads,
     for l, kind in enumerate(layer_types):
         pre = "l%d_" % l
         x = block(x, pre, rms_eps,
-                  lambda h: attention(h, pre, l, kind == "sliding"),
+                  lambda h: kind_attention(
+                      h, pre, l, kind, window, rope_theta, seq_len,
+                      num_heads, num_kv_heads, head_dim, hidden_size,
+                      rms_eps, gated=True),
                   lambda h: mlp(h, pre, l),
                   post_norms=("attn_post_norm", "ffn_post_norm"))
     return with_load_heads(lm_head_loss(x, vocab_size, rms_eps))

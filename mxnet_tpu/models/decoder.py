@@ -3,7 +3,7 @@ written once.  A decoder is ``embed`` -> L x ``block`` -> ``lm_head_loss``
 over ``(B*T, D)`` rows; its builder says which mixer (``gqa_attention``,
 ``latent_attention``, its own) and which MLP (``swiglu``,
 ``routed_experts``) a layer gets, as functions of the normed rows, and its
-head: a sixth decoder is one builder file over this one, a configuration,
+head: a further decoder is one builder file over this one, a configuration,
 a reference and its tests.  Unnamed nodes are numbered in the order they
 are made and a node takes the attribute scope it is made in, so the order
 of the statements here is part of every builder's symbol, which
@@ -56,23 +56,27 @@ def swiglu(h, pre, width, hidden_size):
 
 
 def routed_experts(h, pre, layer, num_experts, experts_per_tok, expert_width,
-                   hidden_size=0, **router):
-    """The routed expert layer ``pre + "moe"``: SwiGLU experts, no bias,
-    no token-choice dropped.  ``layer`` < 0: no trace index.
-    ``hidden_size``: the output's width, which a shared expert needs said
-    (0: the input's, left to the op).  ``router``: the router's kind and a
-    rank's share, as ``MoEFeedForward`` names them."""
+                   hidden_size=0, act_type="silu", **router):
+    """The routed expert layer ``pre + "moe"``: gated experts (SwiGLU;
+    ReGLU with ``act_type="relu"``), no bias, no token-choice dropped.
+    ``layer`` < 0: no trace index.  ``hidden_size``: the output's width,
+    which a shared expert needs said (0: the input's, left to the op).
+    ``router``: the router's kind, the rows it reads where they are not
+    ``h`` (``router_data``) and a rank's share, as ``MoEFeedForward``
+    names them."""
     return MoEFeedForward(
         h, num_hidden=expert_width, num_experts=num_experts,
         k=experts_per_tok, capacity_factor=0.0, name=pre + "moe",
-        act_type="silu", gated=True, no_bias=True,
+        act_type=act_type, gated=True, no_bias=True,
         layer=None if layer < 0 else layer, output_dim=hidden_size, **router)
 
 
 def gqa_attention(h, pre, layer, rows, num_heads, num_kv_heads, head_dim,
-                  hidden_size, eps, rotate=lambda x: x, gated=False, **mask):
+                  hidden_size, eps, rotate=lambda x: x, gated=False,
+                  head_norms=True, **mask):
     """Grouped-query attention with an RMSNorm over each head's lanes of
-    q and of k, ``(B*rows, D)`` -> ``(B*rows, D)``.  ``rotate`` places q
+    q and of k (none with ``head_norms`` off), ``(B*rows, D)`` ->
+    ``(B*rows, D)``.  ``rotate`` places q
     and k (default: no positions but the order), ``mask`` is
     ``CausalSelfAttention``'s (default: causal), ``gated`` multiplies the
     heads' outputs by ``sigmoid(h Wg)`` before ``o_proj``.  Scopes:
@@ -84,7 +88,10 @@ def gqa_attention(h, pre, layer, rows, num_heads, num_kv_heads, head_dim,
                            shape=(-1, rows, n, head_dim))
 
     def placed(name, n):
-        return rotate(norm(heads(name, n), pre + name + "_norm", eps))
+        x = heads(name, n)
+        if head_norms:
+            x = norm(x, pre + name + "_norm", eps)
+        return rotate(x)
 
     with scoped("", "attn_proj", layer):
         q, k = placed("q", num_heads), placed("k", num_kv_heads)
@@ -100,16 +107,52 @@ def gqa_attention(h, pre, layer, rows, num_heads, num_kv_heads, head_dim,
         return proj(a, pre + "o_proj", hidden_size)
 
 
+LAYER_KINDS = ("sliding", "full")
+
+
+def layer_kinds(layer_types, num_layers):
+    """``layer_types`` as a list, one of ``LAYER_KINDS`` for each of the
+    ``num_layers`` layers BUILT, of a model that mixes sliding-window and
+    full attention."""
+    layer_types = list(layer_types)
+    if len(layer_types) != num_layers \
+            or any(kind not in LAYER_KINDS for kind in layer_types):
+        raise ValueError("layer_types %r: %d layers, each one of %s"
+                         % (layer_types, num_layers, LAYER_KINDS))
+    return layer_types
+
+
+def kind_attention(h, pre, layer, kind, window, rope_theta, *sizes, **how):
+    """``gqa_attention`` of one layer of such a model.  The kind is the
+    op's mask and whether the heads are rotated, nothing else: a
+    ``sliding`` layer rotates q and k (``rope_theta``, half-split
+    pairing) and reads under ``CausalSelfAttention``'s ``sliding_window``
+    mask of ``window``; a ``full`` layer rotates NOTHING (it has no
+    positions but the causal order) and reads under the causal mask.
+    ``sizes`` and ``how`` are ``gqa_attention``'s, from ``rows`` on."""
+    if kind == "sliding":
+        how = dict(how, mask="sliding_window", window=window,
+                   rotate=lambda t: sym.RotaryEmbedding(t, theta=rope_theta))
+    return gqa_attention(h, pre, layer, *sizes, **how)
+
+
 def block(x, pre, eps, mixer, mlp, mixer_norm="attn_norm",
-          post_norms=(None, None), sum_scopes=(None, None)):
+          post_norms=(None, None), sum_scopes=(None, None),
+          mlp_sees_mixer_rows=False):
     """One residual block: ``x + [post](mixer(norm(x)))``, then
     ``x + [post](mlp(norm(x)))``.  ``mixer`` and ``mlp`` are functions of
     the normed rows; ``post_norms`` names the two norms inside the
     branches of a sandwich block.  ``sum_scopes``: the scope a sum is
-    made in (``scoped``), where a builder's symbol has one there."""
+    made in (``scoped``), where a builder's symbol has one there.
+    ``mlp_sees_mixer_rows``: ``mlp`` is a function of its own normed rows
+    and, second, of the rows the mixer read (a router placed before the
+    mixer)."""
+    normed = []
     for branch, pre_norm, post_norm, scope in zip(
             (mixer, mlp), (mixer_norm, "ffn_norm"), post_norms, sum_scopes):
-        y = branch(norm(x, pre + pre_norm, eps))
+        normed.append(norm(x, pre + pre_norm, eps))
+        y = branch(*reversed(normed)) \
+            if mlp_sees_mixer_rows and branch is mlp else branch(normed[-1])
         if post_norm:
             y = norm(y, pre + post_norm, eps)
         with scope or contextlib.nullcontext():

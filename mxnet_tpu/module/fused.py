@@ -248,9 +248,13 @@ class FusedTrainStep:
         # traffic reaches the stats from the step's own outputs where
         # the symbol carries the blocks' load head (note_outputs),
         # else from bench/serve samplers
-        from ..moe.detect import find_load_heads, find_moe_blocks
+        from ..moe.detect import (find_act_zeros_head, find_load_heads,
+                                  find_moe_blocks)
         self.moe_blocks = find_moe_blocks(symbol)
         self.moe_load_heads = find_load_heads(symbol)
+        # what a rank's expert blocks count of their activated lanes,
+        # for the trace
+        self.act_zeros_head = find_act_zeros_head(symbol)
         # a second per-token loss head (a multi-token-prediction module):
         # its mean reaches the trace from the step's outputs too
         self.prediction_heads = find_prediction_heads(symbol)
@@ -678,6 +682,18 @@ class FusedTrainStep:
                 sample["bound"] = float(held_rows_bound(
                     sample["routed"], spec.num_experts, held))
             _trace.counter("moe:load", cat="moe", track=block, **sample)
+
+    def note_act_zeros(self, outs) -> None:
+        """Feed the ``moe:act_zeros`` trace counter, one sample a step
+        and expert block, from the step's ``moe_act_zeros`` head as the
+        metric gets it: ``zeros`` of the ``lanes`` activated lanes
+        (``act(x Wg)``) of the rows this rank really held.  One host read
+        of ``(blocks, 2)`` numbers the metric update before this call
+        already waited for."""
+        idx, blocks = self.act_zeros_head
+        for block, (zeros, lanes) in zip(blocks, outs[idx].asnumpy()):
+            _trace.counter("moe:act_zeros", cat="moe", track=block,
+                           zeros=float(zeros), lanes=float(lanes))
 
     def note_prediction_loss(self, outs) -> None:
         """Feed the ``mtp:loss`` trace counter, one sample a step, from

@@ -1183,13 +1183,16 @@ class Module(BaseModule):
         ``fit:moe_load``, and the mean of a second per-token loss head
         beside the first's (``note_prediction_loss``) under
         ``fit:mtp_loss``, and what a block-diffusion symbol's noise head
-        counted (``note_diffusion_noise``) under ``fit:diffusion_noise``:
+        counted (``note_diffusion_noise``) under ``fit:diffusion_noise``,
+        and what a rank's expert blocks counted of their activated lanes
+        (``note_act_zeros``) under ``fit:moe_act_zeros``:
         nothing, and no span, where the fused step is off or the symbol
-        carries no such head; the last two only while tracing is on."""
+        carries no such head; the last three only while tracing is on."""
         fused = self._fused
         if fused is None or not self._fused_live() \
                 or not (fused.moe_load_heads or fused.prediction_heads
-                        or fused.noise_head is not None):
+                        or fused.noise_head is not None
+                        or fused.act_zeros_head):
             return
         outs = self.get_outputs() if outputs is None else outputs
         if fused.moe_load_heads:
@@ -1201,6 +1204,9 @@ class Module(BaseModule):
         if fused.noise_head is not None and _trace.enabled():
             with _trace.span("fit:diffusion_noise", cat="train"):
                 fused.note_diffusion_noise(outs)
+        if fused.act_zeros_head and _trace.enabled():
+            with _trace.span("fit:moe_act_zeros", cat="train"):
+                fused.note_act_zeros(outs)
 
     def _outputs_in_flight(self):
         """The overlap hook of fit() and score(): the outputs of the

@@ -25,8 +25,8 @@ from ..base import get_env
 from .. import symbol as _sym
 
 __all__ = ["MoEFeedForward", "aux_loss_symbols", "count_symbols",
-           "hit_symbols", "dropped_symbols", "with_aux_loss",
-           "with_load_heads"]
+           "hit_symbols", "dropped_symbols", "with_act_zeros_head",
+           "with_aux_loss", "with_load_heads"]
 
 # _moe_dispatch output indices (ops/moe.py list_outputs)
 _AUX_IDX = 3
@@ -34,6 +34,8 @@ _COUNTS_IDX = 4
 _HITS_IDX = 5
 _DROPPED_IDX = 6
 _ORDER_IDX = 7
+# the head ``with_act_zeros_head`` groups on (``detect.find_act_zeros_head``)
+ACT_ZEROS_HEAD = "moe_act_zeros"
 
 
 def MoEFeedForward(data, num_hidden: int, num_experts: int, k: int = 2,
@@ -45,7 +47,8 @@ def MoEFeedForward(data, num_hidden: int, num_experts: int, k: int = 2,
                    gated: bool = False, layer: Optional[int] = None,
                    score: str = "softmax", scale: float = 1.0,
                    bias_rate: float = 0.0, shared_hidden: int = 0,
-                   experts_held: int = 0, first_expert: int = 0):
+                   experts_held: int = 0, first_expert: int = 0,
+                   router_data=None, act_zeros: bool = False):
     """Build one routed MoE feed-forward block over ``data`` (T, D).
 
     ``capacity_factor`` None reads ``MXNET_MOE_CAPACITY_FACTOR``
@@ -83,16 +86,23 @@ def MoEFeedForward(data, num_hidden: int, num_experts: int, k: int = 2,
     if capacity_factor is None:
         capacity_factor = get_env("MXNET_MOE_CAPACITY_FACTOR", 0.0, float)
     scope = {} if layer is None else {"layer": int(layer)}
-    logits = _sym.FullyConnected(data, num_hidden=num_experts,
+    if act_zeros and not experts_held:
+        raise ValueError("MoEFeedForward: act_zeros counts the rows a rank "
+                         "holds (experts_held > 0)")
+    logits = _sym.FullyConnected(data if router_data is None else router_data,
+                                 num_hidden=num_experts,
                                  no_bias=True, name=name + "_gate")
     share = {"experts_held": int(experts_held),
              "first_expert": int(first_expert)}
+    # said only where it is so: an unset parameter is not in a node's JSON
+    read = {} if router_data is None else {"router_rows": "mixer"}
     disp = _sym._moe_dispatch(data, logits, num_experts=num_experts,
                               k=k, capacity_factor=capacity_factor,
                               renormalize=renormalize, score=score,
                               scale=float(scale),
                               bias_rate=float(bias_rate),
-                              name=name + "_dispatch", **scope, **share)
+                              name=name + "_dispatch", **scope, **share,
+                              **read)
 
     def expert_var(suffix, spec):
         attr = {"__sharding__": spec} if expert_axis else None
@@ -114,7 +124,10 @@ def MoEFeedForward(data, num_hidden: int, num_experts: int, k: int = 2,
         # dispatch node's sorted rows are not read
         out = _sym._moe_share_ffn(
             data, disp[1], disp[2], disp[_ORDER_IDX], disp[_COUNTS_IDX],
-            *weights, name=name + "_share", **ffn)
+            *weights, name=name + "_share", **ffn,
+            **({"act_zeros": True} if act_zeros else {}))
+        if act_zeros:
+            out = out[0]
     else:
         rows = _sym._moe_expert_ffn(disp[0], *weights, disp[_COUNTS_IDX],
                                     name=name + "_experts", **ffn)
@@ -193,6 +206,27 @@ def with_load_heads(net):
         return net
     load = rows[0] if len(rows) == 1 else _sym.Concat(*rows, dim=0)
     return _sym.Group([net, _sym.BlockGrad(load, name="moe_load")])
+
+
+def with_act_zeros_head(net):
+    """Group ONE head, ``moe_act_zeros``, onto ``net`` behind
+    ``BlockGrad``: the ``(blocks, 2)`` stack of ``(zeros, lanes)`` of
+    every share node built with ``act_zeros`` (``MoEFeedForward``), in
+    topological order.  It travels with the step's outputs beside
+    ``moe_load``, and ``Module.fit`` feeds the ``moe:act_zeros`` trace
+    counter from it while tracing is on
+    (``FusedTrainStep.note_act_zeros``).  Returns ``net`` unchanged when
+    no node carries the output."""
+    from ..symbol import Symbol, _topo
+    rows = [_sym.Reshape(Symbol([(node, 1)]), shape=(1, 2))
+            for node in _topo(net._heads)
+            if not node.is_variable
+            and getattr(node.op, "name", "") == "_moe_share_ffn"
+            and node.params.get("act_zeros")]
+    if not rows:
+        return net
+    stack = rows[0] if len(rows) == 1 else _sym.Concat(*rows, dim=0)
+    return _sym.Group([net, _sym.BlockGrad(stack, name=ACT_ZEROS_HEAD)])
 
 
 def with_aux_loss(net, grad_scale: Optional[float] = None):

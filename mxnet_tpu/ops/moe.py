@@ -40,6 +40,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import trace as _trace
 from ..base import MXNetError, _AttrDict
 from ..moe.router import drop_free
 from .registry import OpDef, Param, register_op
@@ -96,7 +97,11 @@ class MoEDispatchOp(OpDef):
     ``experts_held`` > 0 says this rank holds experts ``first_expert ..
     first_expert + experts_held - 1`` of the E routed over: their rows
     come first, the absent experts' behind them with weight 0, and
-    ``counts`` stays E wide."""
+    ``counts`` stays E wide.  ``router_rows`` says which rows ``logits``
+    were read from, ``ffn`` (``data``; unset says the same) or ``mixer``
+    (the rows the block's mixer read, ``MoEFeedForward``'s
+    ``router_data``): each trace of the node records it, as the counter
+    ``moe:router_rows``."""
     params = [Param("num_experts", int, required=True),
               Param("k", int, default=2),
               Param("capacity_factor", float, default=0.0),
@@ -107,7 +112,9 @@ class MoEDispatchOp(OpDef):
               Param("scale", float, default=1.0),
               Param("bias_rate", float, default=0.0),
               Param("experts_held", int, default=0),
-              Param("first_expert", int, default=0)]
+              Param("first_expert", int, default=0),
+              # unset (the rows the experts read) is not in a node's JSON
+              Param("router_rows", str, enum=["mixer", "ffn"])]
 
     def list_arguments(self, p):
         return ["data", "logits"]
@@ -171,6 +178,10 @@ class MoEDispatchOp(OpDef):
                                   route_sorted)
         x, logits = inputs
         T = x.shape[0]
+        mixer = p.router_rows == "mixer"
+        _trace.counter("moe:router_rows", cat="moe",
+                       track="l%d" % p.layer if p.layer >= 0 else "moe",
+                       mixer=int(mixer), ffn=int(not mixer))
         with _scope("moe_route", p):
             if drop_free(p.capacity_factor):
                 plan = route_sorted(
@@ -203,14 +214,21 @@ def _layers(p, tensors):
     return list(zip(tensors[0::2], tensors[1::2]))
 
 
-def _ffn(p, x, layers, linear):
+def _ffn(p, x, layers, linear, note=None):
+    """``note``, where given, is called with the activated lanes: ``act(x
+    Wg)`` of a gated layer, ``act(x W1)`` of a plain one."""
     act = _act(p.act_type)
     if p.gated:
         (wg, bg), (w1, b1), (w2, b2) = layers
-        h = act(linear(x, wg, bg)) * linear(x, w1, b1)
+        h = act(linear(x, wg, bg))
+        if note:
+            note(h)
+        h = h * linear(x, w1, b1)
     else:
         (w1, b1), (w2, b2) = layers
         h = act(linear(x, w1, b1))
+        if note:
+            note(h)
     return linear(h, w2, b2)
 
 
@@ -228,7 +246,11 @@ def _sorted_ffn(p, x, layers, counts, window=None):
     matmuls whose group sizes are the held experts' ``counts``.  The
     rows are all ``T*k`` of the plan, or a rank's sorted rows ``lo .. lo
     + n - 1`` (``window = (lo, n)``, ``lo`` no further than its held
-    rows reach): then each group's part inside the window."""
+    rows reach): then each group's part inside the window.  With the
+    node's ``act_zeros``: -> ``(rows, (zeros, lanes))``, float32 counts
+    of the activated lanes of the rows that belong to a group (never of
+    the rows a static bound pads): how many there are, and how many of
+    them are exactly 0."""
     from ..moe.dispatch import group_tiles, grouped_matmul
     sizes = _held_sizes(p, counts)
     if window is not None:
@@ -261,7 +283,14 @@ def _sorted_ffn(p, x, layers, counts, window=None):
         out = grouped_matmul(h, w, groups)
         return own(out if b is None else out + jnp.take(
             b, expert_of_row, axis=0))
-    return _ffn(p, x, layers, linear)
+    if not p.get("act_zeros"):
+        return _ffn(p, x, layers, linear)
+    seen = []
+    out = _ffn(p, x, layers, linear, seen.append)
+    lanes = jax.lax.stop_gradient(seen[0])
+    zeros = jnp.sum((lanes == 0) & mine, dtype=jnp.int32)
+    return out, jnp.stack([zeros, sizes.sum() * lanes.shape[1]]) \
+        .astype(jnp.float32)
 
 
 def _ffn_arguments(p):
@@ -390,8 +419,17 @@ class MoEShareFFNOp(OpDef):
     the same: the combine's gather through ``slot`` and the row
     gradient's.  A window's parts and the bounded node are jits of this
     module (``_WINDOW_PARTS``, ``_share_bounded``): traced once a
-    process, whatever the layer, the pass and the module."""
-    params = _FFN_PARAMS
+    process, whatever the layer, the pass and the module.
+
+    ``act_zeros`` adds a second output ``(2,)`` float32, no gradient:
+    ``(zeros, lanes)`` of the activated lanes (``act(x Wg)``) of the rows
+    the rank really holds, both sides of the bound summed
+    (``_sorted_ffn``).  Unset, the node is the one it was."""
+    # unset is not in a node's JSON: the symbols that are there keep theirs
+    params = _FFN_PARAMS + [Param("act_zeros", bool)]
+
+    def list_outputs(self, p):
+        return ["output", "act_zeros"] if p.act_zeros else ["output"]
 
     def list_arguments(self, p):
         # the dispatch node's four before the stacked tensors: a graph's
@@ -418,13 +456,13 @@ class MoEShareFFNOp(OpDef):
         tk = tuple(tk)
         return [d, tk, tk, (tk[0] * tk[1],), cnt] \
             + _ffn_shapes(p, p.experts_held, d[1]), \
-            [(d[0], p.output_dim or d[1])], []
+            [(d[0], p.output_dim or d[1])] + ([(2,)] if p.act_zeros else []), []
 
     def infer_type(self, p, in_types):
         t = in_types[0] if in_types[0] is not None else np.dtype(np.float32)
         f32, i32 = np.dtype(np.float32), np.dtype(np.int32)
         return [t, f32, i32, i32, f32] + [t] * len(_ffn_arguments(p)), \
-            [t], []
+            [t] + ([f32] if p.act_zeros else []), []
 
     def forward(self, p, inputs, aux, ctx):
         from ..moe.dispatch import held_rows_bound
@@ -434,9 +472,17 @@ class MoEShareFFNOp(OpDef):
         every = slot.shape[0] * slot.shape[1]
         bound = held_rows_bound(every, counts.shape[0], p.experts_held)
         if bound == every or traced_devices() > 1:
-            return [_share_window(p)(*inputs)]
-        return [_share_bounded(tuple(sorted(p.items())), scope_prefix(),
-                               bound, *inputs)]
+            out = _share_window(p)(*inputs)
+        else:
+            out = _share_bounded(_static(p.items()), scope_prefix(), bound,
+                                 *inputs)
+        return list(out) if p.act_zeros else [out]
+
+
+def _static(items):
+    """A node's parameters as a jit's static argument; one that is unset
+    is left out, so that a parameter a later PR adds keys nothing."""
+    return tuple(sorted((k, v) for k, v in items if v is not None))
 
 
 def _window_rows(params, window, x, order, slot, counts):
@@ -468,8 +514,9 @@ _WINDOW_PARTS = tuple(jax.jit(part, static_argnums=(0, 1)) for part in
 def _share_window(p, window=None):
     """``_moe_share_ffn``'s body over sorted rows ``lo .. lo + n - 1``
     (``window = (lo, n)``), or, given none, over all of them: the three
-    nodes' statements."""
-    params = tuple(sorted((k, v) for k, v in p.items() if k != "layer"))
+    nodes' statements.  With the node's ``act_zeros``: -> ``(output,
+    (zeros, lanes))`` of the window's rows."""
+    params = _static((k, v) for k, v in p.items() if k != "layer")
     gather, experts, combine = _WINDOW_PARTS if window else (
         _window_rows, _window_ffn, _window_sum)
 
@@ -478,8 +525,11 @@ def _share_window(p, window=None):
             rows = gather(params, window, x, order, slot, counts)
         with _scope("moe_experts", p):
             rows = experts(params, window, rows, counts, *stacked)
+        if p.get("act_zeros"):
+            rows, seen = rows
         with _scope("moe_combine", p):
-            return combine(params, window, rows, order, slot, weight)
+            out = combine(params, window, rows, order, slot, weight)
+        return (out, seen) if p.get("act_zeros") else out
     return body
 
 
@@ -501,10 +551,10 @@ def _share_bounded(params, prefix, bound, *inputs):
     # them, added.  The branch that runs nothing is all a common step
     # pays for it (zeros out and, backward, zero gradients)
     overflows = _held_sizes(p, counts).sum() > bound
-    return out + jax.lax.cond(
+    return jax.tree.map(jnp.add, out, jax.lax.cond(
         overflows,
         jax.checkpoint(_share_window(p, (bound, every - bound))),
-        lambda *_: jnp.zeros_like(out), *inputs)
+        lambda *_: jax.tree.map(jnp.zeros_like, out), *inputs))
 
 
 @register_op("_moe_combine", hint="moe_combine")

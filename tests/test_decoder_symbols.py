@@ -1,7 +1,7 @@
 """The decoder builders' symbols, node for node (ISSUE 46, tier-1).
 
-The five LM builders of ``mxnet_tpu/models`` are assembled from one
-skeleton, ``models/decoder.py``.  What holds that assembly still is the
+The LM builders of ``mxnet_tpu/models`` (five then, six now) are
+assembled from one skeleton, ``models/decoder.py``.  What holds that assembly still is the
 graph each builder returns: under a fresh ``NameManager`` the symbol's
 JSON (every node's op, name, keywords, attributes and inputs, the unnamed
 nodes numbered in the order they were made) hashes to what the commit
@@ -61,6 +61,12 @@ AFMOE = dict(num_layers=4, hidden_size=32,
              route_scale=2.826, vocab_size=50, seq_len=16,
              embed_scale=32 ** 0.5, experts_held=4, first_expert=4,
              bias_rate=1e-3, rms_eps=1e-5)
+SMALLTHINKER = dict(num_layers=4, hidden_size=32,
+                    layer_types=["full", "sliding", "sliding", "sliding"],
+                    num_heads=6, num_kv_heads=2, head_dim=8, window=6,
+                    rope_theta=1.5e6, num_experts=16, experts_per_tok=3,
+                    expert_width=24, vocab_size=50, seq_len=16,
+                    experts_held=4, first_expert=4, rms_eps=1e-6)
 WHOLE = dict(experts_held=0, first_expert=0)
 # latent attention by itself: seq_len, hidden_size, heads, kv_lora_rank,
 # qk_nope_dim, qk_rope_dim, v_head_dim, rms_eps
@@ -93,6 +99,14 @@ SYMBOLS = {
     "glm-4.7-flash": _cell("glm-4.7-flash"),
     "sdar-30b-a3b": _cell("sdar-30b-a3b"),
     "trinity-mini": _cell("trinity-mini"),
+    # the sixth builder, from the commit that added it (ISSUE 47): its
+    # cell, a rank's share with and without the counter's head, the whole
+    # layer
+    "smallthinker-21b-a3b": _cell("smallthinker-21b-a3b"),
+    "smallthinker-share": _tiny("smallthinker_lm", SMALLTHINKER),
+    "smallthinker-counted": _tiny("smallthinker_lm", SMALLTHINKER,
+                                  act_zeros=True),
+    "smallthinker-whole": _tiny("smallthinker_lm", SMALLTHINKER, **WHOLE),
     # OLMoE: its load-balance heads stay on at coefficient 0
     "olmoe-tiny": _tiny("olmoe_lm", OLMOE),
     "olmoe-aux-0": _tiny("olmoe_lm", OLMOE, aux_coef=0.0),
@@ -127,7 +141,7 @@ SYMBOLS = {
 }
 
 # sha256 of each symbol's JSON at 945e6c8; the first five are ISSUE 46's
-# table
+# table, the four behind them the sixth builder's
 SYMBOL_WAS = {
     "olmoe-1b-7b":
         "3010508f9af6d214d25b4ea18ba84994901ecdb99e011aa1a16b0b8e402fb4b7",
@@ -139,6 +153,15 @@ SYMBOL_WAS = {
         "c0ee067be3ca69380c71a9e30645329c7717f3e867b201153c5744dd812f7d83",
     "trinity-mini":
         "3e5e7ce8c564e558445076d7db9f435b29431733b2063a6ccb1fbe3797ed571f",
+    # taken at the commit that added the builder (ISSUE 47)
+    "smallthinker-21b-a3b":
+        "d610d2522f3a17701ea206b16b6426c8054b081f462b43aebb18f96e733d2bcd",
+    "smallthinker-share":
+        "28eb66fcbf3cedccff08db0eeee123e1a831d4492d975664c1273ee8b6952324",
+    "smallthinker-counted":
+        "4dd9b03064d193eab8d657784f597d6d779c00b8cae09e19fdec3613e90ebdfd",
+    "smallthinker-whole":
+        "b7b3aee331521264f4ec20024a1d4ea0c553144c1c2666ef2e413f8688f07b58",
     "olmoe-tiny":
         "af8dc705e9e7aeb26b2806967a870d607de9f4892070d6b09552c77d2034efc0",
     "olmoe-aux-0":
@@ -203,6 +226,8 @@ STEPS = {
             dict(data=(2, 24), softmax_label=(2, 24))),
     "afmoe": (_tiny("afmoe_lm", AFMOE),
               dict(data=(2, 16), softmax_label=(2, 16))),
+    "smallthinker": (_tiny("smallthinker_lm", SMALLTHINKER, act_zeros=True),
+                     dict(data=(2, 16), softmax_label=(2, 16))),
 }
 
 # sha256 of each step's lowered text at 945e6c8
@@ -213,6 +238,9 @@ STEP_WAS = {
         "3a00c3fc2d2fd85cce626d1955a2174d85b0f52940d1f41a8edac6b24be37a9f",
     "afmoe":
         "56a01f25a20c009757a2834695e48964ac2b23da9fcc48d26a5cf9c814f7356e",
+    # taken at the commit that added the builder (ISSUE 47)
+    "smallthinker":
+        "b1a83ee5679c16bad171411fc23ea7d6a0df99b255614ddcd14ccc724757f01c",
 }
 
 

@@ -1,0 +1,342 @@
+"""SmallThinker-21BA3B at its published widths on the chip (as the
+``smallthinker-21b-a3b`` configuration is cut: its four layers, the held
+share of the 64 experts, an eighth of the vocabulary), against the plain
+reference ``benchmark/reference/smallthinker-21b-a3b.py`` computed on the
+same chip.
+
+    MXNET_TPU_TESTS=1 python -m pytest tests/tpu/test_smallthinker_tpu.py -s -q
+
+The first test has phases that each release what they held (the chip
+holds one module of this size at a time): the reference's loss,
+gradients and first Adam step at one sequence of 8192, and the same with
+its weights rounded to float8 (what the configuration's limits have to
+refuse); the configuration's own Adam step in bfloat16 at the default
+matmul precision, as the cell's reference check runs it, on
+``SMALLTHINKER_PARITY_SEEDS`` seeds (20; weights and batch both from the
+seed), with the ``attn:lowering`` samples of the bind, the
+``moe:gmm_trace`` samples of the process (the grouped-matmul kernels at
+``K`` = 2560) and the step's ``moe_act_zeros`` head against the
+reference's count; and the Adam step in float32 compute against the
+reference at one sequence of 2048 under a window of 1024.  The numbers go
+to ``chiprun_out/smallthinker_parity.json`` after every phase, before
+anything is asserted.
+
+The second holds ``causal_attention``'s TPU kernel under the window
+against its plain blocks at the cell's shape, ``(1, 8192, 28, 128)`` over
+4 key/value heads (groups of 7) under a window of 4096, with the window
+kernel's and the causal kernel's times.
+"""
+import gc
+import json
+import os
+import sys
+import time
+
+import numpy as np
+
+from _mirror import tpu_gate
+
+pytestmark = [tpu_gate()]
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+# as tests/tpu/test_olmoe_tpu.py: each side rounds its probabilities and
+# results to 8 bits of mantissa
+ATTN_MAX_ERR_SHARE = 0.02
+ATTN_L2_ERR = 0.01
+SEED = 4700000047
+TRACKS = ["bfloat16[1, 8192, 28, 128]/kv4"] \
+    + ["bfloat16[1, 8192, 28, 128]/kv4/sliding_window4096"] * 3
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+def _adam_step(net, params, data, labels, opt_params, compute_dtype, names):
+    """One step of the fused train step on the chip.  -> (the loss,
+    choices per expert a block, (zeros, lanes) a block, {name: after -
+    before})."""
+    import mxnet_tpu as mx
+    if compute_dtype:
+        os.environ["MXNET_COMPUTE_DTYPE"] = compute_dtype
+    else:
+        os.environ.pop("MXNET_COMPUTE_DTYPE", None)
+    try:
+        mod = mx.mod.Module(net, context=mx.tpu(0))
+        mod.bind(data_shapes=[("data", data.shape)],
+                 label_shapes=[("softmax_label", labels.shape)])
+        mod.init_params(mx.init.Zero(), allow_missing=True, arg_params={
+            k: mx.nd.array(v) for k, v in params.items()})
+        gc.collect()
+        mod.init_optimizer(optimizer="adam",
+                           optimizer_params=dict(opt_params))
+        assert mod._fused is not None
+        batch = mx.io.DataBatch(
+            data=[mx.nd.array(data, dtype=np.int32)],
+            label=[mx.nd.array(labels, dtype=np.int32)], pad=0)
+        mod.forward_backward(batch)
+        mod.update()
+        assert mod._exec_group.execs == []
+        outs = [o.asnumpy() for o in mod.get_outputs()]
+        load = mod._fused.moe_load_heads[0]
+        seen = mod._fused.act_zeros_head[0]
+        after, _ = mod.get_params()
+        delta = {n: after[n].asnumpy() - params[n] for n in names}
+        del mod, after, batch
+    finally:
+        os.environ.pop("MXNET_COMPUTE_DTYPE", None)
+    gc.collect()
+    return float(outs[0].mean()), outs[load][:, :-1], outs[seen], delta
+
+
+def test_published_width_step_matches_reference():
+    import jax
+    import jax.numpy as jnp
+    import mxnet_tpu as mx
+    from mxnet_tpu.models import smallthinker_lm
+    sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+    import manifest
+    ref = manifest.load_module("reference", "smallthinker-21b-a3b")
+    gen = manifest.load_module("generators", "token_packed")
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "smallthinker-21b-a3b.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(ROOT, "benchmark", "traffic",
+                           "packed-8k-b1.json")) as f:
+        traffic = json.load(f)
+    kw = cfg["model"]["kwargs"]
+    names = cfg["reference"]["weights"]
+    limits = cfg["reference"]
+    adam = cfg["optimizer"]["params"]
+    seq = kw["seq_len"]
+    seeds = int(os.environ.get("SMALLTHINKER_PARITY_SEEDS", "20"))
+    net = smallthinker_lm(**kw)
+    shapes = dict(zip(net.list_arguments(), net.infer_shape(
+        data=(1, seq), softmax_label=(1, seq))[0]))
+
+    def weights(seed):
+        rng = np.random.default_rng(seed)
+        return {n: (np.ones(s, np.float32) if n.endswith("gamma") else
+                    0.02 * rng.standard_normal(s, dtype=np.float32))
+                for n, s in shapes.items()
+                if n not in ("data", "softmax_label")}
+
+    def batch_of(seed, config=cfg):
+        batches = gen.build(dict(traffic, distinct_batches=1), config, seed,
+                            [mx.cpu(0)], None)
+        (data,), (labels,) = (list(d.values()) for d in
+                              batches.reference_batch(1)[:2])
+        return data, labels
+
+    params = weights(SEED)
+    data, labels = batch_of(SEED)
+    report = {"device": jax.devices()[0].device_kind,
+              "params_M": sum(v.size for v in params.values()) / 1e6,
+              "experts_held": kw["experts_held"], "adam_bf16": {}}
+    out_dir = os.path.join(ROOT, "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+
+    def save():
+        with open(os.path.join(out_dir, "smallthinker_parity.json"),
+                  "w") as f:
+            json.dump(report, f, indent=1)
+
+    def reference(p, d, lb, config=cfg):
+        t0 = time.perf_counter()
+        out = ref.reference_step(config, p, {"data": d},
+                                 {"softmax_label": lb}, adam, names)
+        gc.collect()
+        report.setdefault("reference_s", []).append(
+            round(time.perf_counter() - t0, 1))
+        return out
+
+    def loss_of(got, want):
+        return {"loss": got, "reference_loss": want["loss"],
+                "loss_rel_err": abs(got - want["loss"]) / want["loss"]}
+
+    # A. the reference on this chip, and with float8 weights (e4m3, the
+    # nearest format under bfloat16; arithmetic stays float32)
+    want = reference(params, data, labels)
+    coarse = {n: np.asarray(jnp.asarray(v).astype(jnp.float8_e4m3fn)
+                            .astype(jnp.float32))
+              for n, v in params.items()}
+    out = reference(coarse, data, labels)
+    report["reference_fp8_weights"] = dict(
+        loss_of(out["loss"], want),
+        adam_update_rel_err={n: _rel(out["updates"][n], want["updates"][n])
+                             for n in names})
+    del out, coarse
+    gc.collect()
+    save()
+    print("\nSMALLTHINKER_PARITY fp8 " + json.dumps(
+        report["reference_fp8_weights"]), flush=True)
+
+    # B. the configuration's step, bfloat16 at the default precision,
+    # weights and batch from each seed
+    mx.trace.set_enabled(True)
+    start = time.perf_counter_ns()
+    for i in range(seeds):
+        seed = SEED + i
+        if i:
+            params, (data, labels) = weights(seed), batch_of(seed)
+            want = reference(params, data, labels)
+        mark = time.perf_counter_ns()
+        with jax.default_matmul_precision("default"):
+            loss, counts, seen, delta = _adam_step(
+                net, params, data, labels, adam, "bfloat16", names)
+        report.setdefault("module_step_s", []).append(
+            round((time.perf_counter_ns() - mark) / 1e9, 1))
+        lowered = mx.trace.counter_events(["attn:lowering"], since_ns=mark)
+        held = [float(c[:kw["experts_held"]].sum()) for c in counts]
+        report["adam_bf16"][str(seed)] = dict(
+            loss_of(loss, want),
+            update_rel_err={n: _rel(delta[n], want["updates"][n])
+                            for n in names},
+            held_rows=held, act_zeros=[[float(z), float(n)] for z, n in seen],
+            attn_lowering=[[e["id"], e["args"]] for e in lowered])
+        save()
+        print("\nSMALLTHINKER_PARITY bf16 %d " % seed + json.dumps(
+            report["adam_bf16"][str(seed)]), flush=True)
+        del want, delta
+        gc.collect()
+    report["gmm_trace"] = [[e["id"], e["args"]] for e in
+                           mx.trace.counter_events(["moe:gmm_trace"],
+                                                   since_ns=start)]
+    save()
+    print("\nSMALLTHINKER_PARITY gmm " + json.dumps(report["gmm_trace"]),
+          flush=True)
+
+    # C. float32 compute against the reference, one sequence of 2048
+    # under a window of 1024: the plain blocks' window at published widths
+    short = dict(kw, seq_len=2048, window=1024)
+    cfg_short = dict(cfg, model=dict(cfg["model"], kwargs=short),
+                     input=dict(cfg["input"], seq_len=2048))
+    params = weights(SEED)
+    d32, l32 = batch_of(SEED, cfg_short)
+    want = reference(params, d32, l32, cfg_short)
+    zeros32 = ref.loss_and_grads(cfg_short, params, d32, l32,
+                                 names=[])["act_zeros"]
+    loss32, _, seen32, delta32 = _adam_step(
+        smallthinker_lm(**short), params, d32, l32, adam, None, names)
+    report["adam_f32_t2048"] = dict(
+        loss_of(loss32, want),
+        update_rel_err={n: _rel(delta32[n], want["updates"][n])
+                        for n in names},
+        act_zeros=[[float(z), float(n)] for z, n in seen32],
+        reference_act_zeros=[[float(v) for v in zeros32["l%d_moe_share" % l]]
+                             for l in range(kw["num_layers"])])
+    save()
+    print("\nSMALLTHINKER_PARITY f32 " + json.dumps(report["adam_f32_t2048"]),
+          flush=True)
+
+    fp8 = report["reference_fp8_weights"]
+    for seed, bf16 in report["adam_bf16"].items():
+        assert bf16["loss_rel_err"] <= limits["loss_rtol"], seed
+        for n in names:
+            assert bf16["update_rel_err"][n] <= limits["update_rtol"][n], \
+                (seed, n)
+        # one attention call a layer, every one the kernel: the full
+        # layer under the causal mask, then three under the window
+        assert [t for t, _ in bf16["attn_lowering"]] == TRACKS
+        assert all(a["kernel"] == 1 and a["plain"] == 0
+                   for _, a in bf16["attn_lowering"])
+        # the lanes counted are the held rows', not the bound's 24 576
+        for rows, (zeros, lanes) in zip(bf16["held_rows"],
+                                        bf16["act_zeros"]):
+            assert lanes == rows * kw["expert_width"]
+            assert 0.3 * lanes < zeros < 0.7 * lanes
+    # the grouped-matmul kernels ran at K = 2560 (two k steps of 1280),
+    # not ragged_dot: the forward product, both backward ones
+    kinds = {track.split(" ")[0]: args for track, args in
+             report["gmm_trace"] if "2560" in track}
+    assert {"gmm", "gmm_t", "tgmm"} <= set(kinds), report["gmm_trace"]
+    assert any(a["tk"] == 1280 for _, a in report["gmm_trace"])
+    # float8 weights are refused by at least one limit
+    assert fp8["loss_rel_err"] > limits["loss_rtol"] or any(
+        fp8["adam_update_rel_err"][n] > limits["update_rtol"][n]
+        for n in names)
+    f32 = report["adam_f32_t2048"]
+    assert f32["loss_rel_err"] <= 1e-4
+    assert max(f32["update_rel_err"].values()) <= 0.1, f32
+    # a lane whose pre-activation lies within rounding of 0 may fall on
+    # either side: a handful of 4.7 M
+    for (zeros, lanes), (want_zeros, want_lanes) in zip(
+            f32["act_zeros"], f32["reference_act_zeros"]):
+        assert lanes == want_lanes
+        assert abs(zeros - want_zeros) <= 1e-4 * lanes
+
+
+def test_attention_kernel_matches_plain_blocks_under_the_window():
+    """``causal_attention`` at the cell's ``(1, 8192, 28, 128)`` bfloat16
+    q over 4 key/value heads (seven query heads a key/value head) under a
+    window of 4096 compiles to the Mosaic kernels on the chip; output and
+    all three input gradients agree with the plain blocks', and a key
+    that has left a query's window does not move it."""
+    import jax
+    import jax.numpy as jnp
+    import mxnet_tpu as mx
+    from mxnet_tpu.ops import transformer as tf_ops
+    scale = 128 ** -0.5
+    rng = np.random.RandomState(47)
+    q, k, v = (jnp.asarray(rng.standard_normal((1, 8192, h, 128)),
+                           jnp.bfloat16) for h in (28, 4, 4))
+    w = jnp.asarray(rng.standard_normal((1, 8192, 28, 128)), jnp.float32)
+    kind = ("sliding_window", 4096)
+
+    def both_passes(attend, *mask):
+        def run(q, k, v):
+            out, vjp = jax.vjp(lambda *a: attend(*a, scale, *mask), q, k, v)
+            return (out,) + vjp(w.astype(out.dtype))
+        return jax.jit(run)
+
+    def windowed(q, k, v, scale):
+        return tf_ops.causal_attention(q, k, v, scale, "sliding_window",
+                                       window=4096)
+
+    mx.trace.set_enabled(True)
+    mark = time.perf_counter_ns()
+    kernel = both_passes(windowed)
+    plain = both_passes(tf_ops._plain_attention, kind)
+    causal = both_passes(tf_ops.causal_attention)
+    text = kernel.lower(q, k, v).compile().as_text()
+    assert "tpu_custom_call" in text and "splash_mha" in text
+    event = mx.trace.counter_events(["attn:lowering"], since_ns=mark)[-1]
+    assert event["args"]["kernel"] == 1 and event["id"] == TRACKS[1]
+    got = [np.asarray(x, np.float32) for x in kernel(q, k, v)]
+    want = [np.asarray(x, np.float32) for x in plain(q, k, v)]
+    report = {"max_err_share": [], "l2_err": []}
+    for g, r in zip(got, want):
+        report["max_err_share"].append(
+            float(np.abs(g - r).max() / np.abs(r).max()))
+        report["l2_err"].append(_rel(g, r))
+
+    def ms(fn, *a):
+        jax.block_until_ready(fn(*a))
+        t0 = time.perf_counter()
+        for _ in range(10):
+            out = fn(*a)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / 10 * 1e3
+
+    report["ms_a_layer"] = {
+        "window_kernel_forward_backward": ms(kernel, q, k, v),
+        "causal_kernel_forward_backward": ms(causal, q, k, v)}
+    out_dir = os.path.join(ROOT, "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, "smallthinker_attn_parity.json"),
+              "w") as f:
+        json.dump(report, f, indent=1)
+    print("\nSMALLTHINKER_ATTN_PARITY " + json.dumps(report), flush=True)
+    assert max(report["max_err_share"]) <= ATTN_MAX_ERR_SHARE, report
+    assert max(report["l2_err"]) <= ATTN_L2_ERR, report
+    # keys 100..103: rows 100..4198 read at least one of them, rows from
+    # 4199 have left them behind
+    at = slice(100, 104)
+    moved = np.asarray(jax.jit(windowed, static_argnums=(3,))(
+        q, k.at[:, at].add(1.0), v.at[:, at].add(-1.0), scale), np.float32)
+    assert np.array_equal(moved[:, :100], got[0][:, :100])
+    assert np.array_equal(moved[:, 4199:], got[0][:, 4199:])
+    assert not np.array_equal(moved[:, 100:4199], got[0][:, 100:4199])
