@@ -42,11 +42,23 @@ tokens takes the running sum at the end of its first half as the
 reference point of its lower-left quarter, so every pair ``j < i`` is
 formed once, by a matmul, at the level where it first falls into two
 halves; the unit triangular system is inverted by the same halving (``T
-<- T - T X T``).  Pairs less than ``KDA_SUB`` apart, the inverse, its
+<- T - T X T``).  A level's products stream only the rows that carry
+pairs: k and q under the decay from the reference point are zero on every
+first-half row of a block, so the level packs them into one ``(C, Dk)``
+operand, k's rows where they lie and q's ``s = 1 << l`` rows above their
+own (one sublane roll, ``_packed``); one product with the first half's
+rows gives ``A``'s part in the second-half rows and ``Bs``'s in the
+first-half ones, which a roll brings back down (``_level_scores``), and
+the backward kernel packs the two scores' cotangents the same way
+(``_level_transposes``).  Level 0's blocks are two rows, one pair a
+packed row: the forward kernel forms them as float32 row products on the
+VPU.  Pairs less than ``KDA_SUB`` apart, the inverse, its
 products with the right-hand sides and the running sums keep float32's
-digits whatever the matmul precision in force (three bfloat16 passes),
-as the plain chunks form them in float32; every other product follows
-the precision in force, as the plain chunks' matmuls do.
+digits whatever the matmul precision in force (three bfloat16 products
+a pair, ``_dot``: in two MXU passes where the contraction is ``C`` deep,
+half a pass's depth, in three where it is ``Dk``), as the plain chunks
+form them in float32; every other product follows the precision in
+force, as the plain chunks' matmuls do.
 
 The lowering differentiates itself (``jax.custom_vjp`` around the op's
 body, which keeps the op's inputs, the entry states and ``A``, ``Bs``,
@@ -56,7 +68,9 @@ traces each kernel once and a program holds each once, whatever the
 number of layers and modules that call them: the counter
 ``kda:kernel_trace`` (``fwd`` / ``bwd``; the ``bwd`` event's
 ``kept_products`` is the number of chunk matrices that backward takes
-from the forward kernel, 3) fires from inside their bodies and so counts
+from the forward kernel, 3; both events' ``level_rows`` the rows a
+level's products stream, 64, and ``vpu_levels`` the levels that kernel
+forms off the MXU, 1 and 0) fires from inside their bodies and so counts
 traces, not calls. The counter ``kda:lowering`` records the choice per
 traced op (``kernel`` / ``plain``) as ``attn:lowering`` does for
 attention, and the op's body runs under ``kda.l<layer>``.
@@ -198,6 +212,8 @@ KDA_KEPT = 3
 _NN = (((2,), (1,)), ((0,), (0,)))    # a @ b, a batch of heads
 _NT = (((2,), (2,)), ((0,), (0,)))    # a @ b.T
 _TN = (((1,), (1,)), ((0,), (0,)))    # a.T @ b
+# the contraction a pass of a v5e's MXU takes whole
+_MXU_DEPTH = 128
 
 
 def _bf16_parts(x, n):
@@ -219,11 +235,19 @@ def _dot(a, b, dims, exact=False):
     """A batch of float32 products on the MXU: at the precision in force
     (one bfloat16 pass at the default one), or ``exact``, to 2**-16 of
     each product whatever is in force, by three passes over the
-    operands' bfloat16 halves."""
+    operands' bfloat16 halves.  Where the contraction is ``C`` deep, half
+    of what a pass takes, the two passes over b's upper half are one:
+    a's two halves side by side along the contraction against b's upper
+    half twice."""
     if not exact:
         return lax.dot_general(a, b, dims,
                                preferred_element_type=jnp.float32)
     (ah, al), (bh, bl) = _bf16_parts(a, 2), _bf16_parts(b, 2)
+    ((ca,), (cb,)), _ = dims
+    if 2 * a.shape[ca] <= _MXU_DEPTH:
+        return _one_pass(jnp.concatenate([ah, al], ca),
+                         jnp.concatenate([bh, bh], cb), dims) \
+            + _one_pass(ah, bl, dims)
     return _one_pass(ah, bh, dims) + (_one_pass(ah, bl, dims)
                                       + _one_pass(al, bh, dims))
 
@@ -265,6 +289,57 @@ def _level(l, q, k, g, G, scale, row):
     return k * left, q * left * scale, k * right, left, right
 
 
+def _roll_rows(x, shift):
+    """``x`` ``(H, C, ..)`` with row ``i`` moved to row ``i + shift`` (mod
+    C): a sublane roll."""
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.roll(x, shift % x.shape[1], 1)
+
+
+def _packed(second, moved, l):
+    """Two operands of level ``l`` that are zero outside the rows of a
+    block's second half, in one of their size: ``second`` where it lies
+    and ``moved``'s rows ``s = 1 << l`` above their own, in the first
+    half of the same block, which both leave empty.  A product over the
+    packed rows streams the rows that carry pairs and no others."""
+    return second + _roll_rows(moved, -(1 << l))
+
+
+# the levels whose scores the forward kernel forms off the MXU: level 0,
+# whose blocks are two rows, so that a packed row meets one row of kr, the
+# even row of its block
+KDA_VPU_LEVELS = 1
+
+
+def _level_scores(l, pair, kl, ql, kr):
+    """Level ``l``'s part of ``A`` and ``Bs`` from ``_level``'s rows: one
+    product of the packed ``(H, C, Dk)`` rows with kr gives ``kl kr^T``
+    in a block's second-half rows and, ``s`` rows above their own, ``ql
+    kr^T``; ``pair`` keeps each in its block.  Level 0's one pair a row
+    is a float32 row product on the VPU, a lane sum."""
+    z = _packed(kl, ql, l)
+    if l < KDA_VPU_LEVELS:
+        both = jnp.sum(z * (kr + _roll_rows(kr, 1)), axis=2, keepdims=True)
+    else:
+        both = _dot(z, kr, _NT, l < _FINE_LEVELS)
+    return (jnp.where(pair, both, 0.0),
+            jnp.where(pair, _roll_rows(both, 1 << l), 0.0))
+
+
+def _level_transposes(l, pair, dA, dBs, kl, ql, kr):
+    """``_level_scores`` transposed: the cotangents of kl, ql and kr from
+    ``A``'s and ``Bs``'s, packed as the rows were (every level by the
+    MXU: two passes ``C`` deep cost the backward kernel what level 0's
+    row products would).  dkl and dql hold the other's rows where their
+    own are not: every reader multiplies them by ``left``, kl or ql,
+    which are zero there."""
+    exact = l < _FINE_LEVELS
+    dAB = _packed(jnp.where(pair, dA, 0.0), jnp.where(pair, dBs, 0.0), l)
+    dkl = _dot(dAB, kr, _NN, exact)                     # (H, C, Dk)
+    dkr = _dot(dAB, _packed(kl, ql, l), _TN, exact)
+    return dkl, _roll_rows(dkl, 1 << l), dkr
+
+
 def _pair_masks(c):
     """-> the row and column indices ``(C, 1)`` / ``(1, C)`` and, a
     level, the ``(C, C)`` mask of pairs whose row lies in the second and
@@ -301,10 +376,8 @@ def _chunk_scores_and_inverse(q, k, g, beta, G, scale, masks):
     A = Bs = jnp.zeros((c, c), jnp.float32)
     for l, pair in enumerate(pairs):
         kl, ql, kr, _, _ = _level(l, q, k, g, G, scale, row)
-        both = _dot(jnp.concatenate([kl, ql], 1), kr, _NT,
-                    exact=l < _FINE_LEVELS)
-        A = A + jnp.where(pair, both[:, :c], 0.0)
-        Bs = Bs + jnp.where(pair, both[:, c:], 0.0)
+        a, bs = _level_scores(l, pair, kl, ql, kr)
+        A, Bs = A + a, Bs + bs
     Bs = Bs + jnp.where(row == col, jnp.sum(q * k, axis=2, keepdims=True)
                         * scale, 0.0)
 
@@ -412,11 +485,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, kept_ref, do_ref,
     for l, pair in enumerate(pairs):
         exact = l < _FINE_LEVELS
         kl, ql, kr, left, right = _level(l, q, k, g, G, scale, row)
-        dAB = jnp.concatenate([jnp.where(pair, dA, 0.0),
-                               jnp.where(pair, dBs, 0.0)], 1)
-        dleft = _dot(dAB, kr, _NN, exact)               # (H, 2C, Dk)
-        dkl, dql = dleft[:, :n], dleft[:, n:]
-        dkr = _dot(dAB, jnp.concatenate([kl, ql], 1), _TN, exact)
+        dkl, dql, dkr = _level_transposes(l, pair, dA, dBs, kl, ql, kr)
         dq = dq + dql * left * scale
         dk = dk + dkl * left + dkr * right
         moved = dkl * kl + dql * ql - dkr * kr
@@ -491,7 +560,8 @@ def _kda_fwd(q, k, v, g, beta, *, scale, interpret):
     h = beta.shape[1]
     d = hd // h
     trace.counter("kda:kernel_trace", cat="ops",
-                  track="%s%s" % (v.dtype.name, [b, t, h, d]), fwd=1, bwd=0)
+                  track="%s%s" % (v.dtype.name, [b, t, h, d]), fwd=1, bwd=0,
+                  level_rows=KDA_CHUNK, vpu_levels=KDA_VPU_LEVELS)
     sp = _kernel_specs(b, t, h, d, flip=False)
     # lint: allow(raw-pallas-call) — one lowering of this op, a pair with
     # its own vjp: ops/pallas_kernels holds forward kernels behind the
@@ -522,7 +592,7 @@ def _kda_bwd(q, k, v, g, beta, states, kept, do, *, scale, interpret):
     d = hd // h
     trace.counter("kda:kernel_trace", cat="ops",
                   track="%s%s" % (v.dtype.name, [b, t, h, d]), fwd=0, bwd=1,
-                  kept_products=KDA_KEPT)
+                  kept_products=KDA_KEPT, level_rows=KDA_CHUNK, vpu_levels=0)
     sp = _kernel_specs(b, t, h, d, flip=True)
     f32 = jax.ShapeDtypeStruct(q.shape, jnp.float32)
     # lint: allow(raw-pallas-call) — as _kda_fwd

@@ -5,6 +5,7 @@ expert-parallel rank's share against the uncut layer, the whole tiny
 model (loss, every gradient, Adam's first step, the bias's first move)
 against ``benchmark/reference/kimi-linear-48b-a3b.py`` in float32, and
 what ``Module`` holds and hands back while the fused step is live."""
+import contextlib
 import os
 import re
 import sys
@@ -19,6 +20,7 @@ sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 
 import jax                                                # noqa: E402
 import jax.numpy as jnp                                   # noqa: E402
+from jax import lax                                       # noqa: E402
 
 import mxnet_tpu as mx                                    # noqa: E402
 from mxnet_tpu.models import kimi_linear_lm               # noqa: E402
@@ -190,35 +192,212 @@ def test_kernel_lowering_carries_the_gradient_through_the_gates():
         assert np.abs(x - y).max() <= 4e-3 * np.abs(y).max()
 
 
-def _kernel_dots(jaxpr):
+def _kernel_dot_eqns(jaxpr):
     """The ``dot_general``s of a jaxpr and of every jaxpr inside it (a
     ``pallas_call``'s kernel body)."""
-    n = 0
     for eqn in jaxpr.eqns:
-        n += eqn.primitive.name == "dot_general"
+        if eqn.primitive.name == "dot_general":
+            yield eqn
         for value in eqn.params.values():
             for x in value if isinstance(value, (list, tuple)) else [value]:
                 x = getattr(x, "jaxpr", x)
                 if hasattr(x, "eqns"):
-                    n += _kernel_dots(x)
-    return n
+                    yield from _kernel_dot_eqns(x)
+
+
+def _kernel_dots(jaxpr):
+    return sum(1 for _ in _kernel_dot_eqns(jaxpr))
+
+
+def _streamed_rows(eqn):
+    """The rows of a ``dot_general``'s left operand that are neither
+    contracted nor a batch (one head's): what one pass streams."""
+    (contract, _), (batch, _) = eqn.params["dimension_numbers"]
+    return int(np.prod([x for i, x in enumerate(eqn.invars[0].aval.shape)
+                        if i not in contract and i not in batch]))
+
+
+def _kernel_row_cycles(jaxpr):
+    """The rows every ``dot_general`` of a kernel body streams through the
+    MXU, summed, so that an exact product counts once a pass.  What
+    ``PERF.md`` §7 counts by hand, and what the kernels' time follows."""
+    return sum(map(_streamed_rows, _kernel_dot_eqns(jaxpr)))
 
 
 # MXU passes a chunk in ``kda_chunk_bwd``'s body: with the scores and the
 # inverse formed again (the commit before ISSUE 45: 14 + 30 passes, and 3
-# for an output nobody read) and with the three read back
-BWD_DOTS_BEFORE_PR45, BWD_DOTS = 103, 56
+# for an output nobody read), with the three read back (before ISSUE 48),
+# and with an exact product over ``C`` rows of contraction in two passes
+# for three (levels 0-3's two each, ``u``, ``dy``)
+BWD_DOTS_BEFORE_PR45, BWD_DOTS_BEFORE_PR48, BWD_DOTS = 103, 56, 46
+# rows x passes a chunk-head, forward and backward kernel: with a level's
+# two half-empty operands side by side and every exact product three
+# passes (the commit before ISSUE 48); with the levels packed (3520 and
+# 3712), the forward's level 0 on the VPU (3328) and the exact products
+# ``C`` deep in two passes
+ROW_CYCLES_BEFORE_PR48 = {"fwd": 4416, "bwd": 4608}
+ROW_CYCLES = {"fwd": 2624, "bwd": 3072}
+
+
+def _kernel_jaxpr(which, b, t, h, d, jitted=True):
+    """-> the jaxpr of ``_kda_fwd`` / ``_kda_bwd`` traced for ``(B, T, H,
+    D)`` inputs; ``jitted`` False traces the function's body anew
+    whatever the process has traced."""
+    seq = jax.ShapeDtypeStruct((b, t, h * d), jnp.float32)
+    beta = jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32)
+    fn = {"fwd": kda_ops._kda_fwd, "bwd": kda_ops._kda_bwd}[which]
+    fn = fn if jitted else fn.__wrapped__
+    args = (seq, seq, seq, seq, beta) + (
+        () if which == "fwd" else
+        (*kda_ops._kept_shapes(b, t, h, d), seq))
+    return jax.make_jaxpr(
+        lambda *a: fn(*a, scale=0.1, interpret=False))(*args).jaxpr
 
 
 def _bwd_kernel_dots(b, t, h, d):
     """-> the passes of the backward kernel traced for ``(B, T, H, D)``
     inputs."""
-    seq = jax.ShapeDtypeStruct((b, t, h * d), jnp.float32)
-    beta = jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32)
-    return _kernel_dots(jax.make_jaxpr(
-        lambda *a: kda_ops._kda_bwd(*a, scale=0.1, interpret=False))(
-            seq, seq, seq, seq, beta, *kda_ops._kept_shapes(b, t, h, d),
-            seq).jaxpr)
+    return _kernel_dots(_kernel_jaxpr("bwd", b, t, h, d))
+
+
+# -- the statements the kernels held before ISSUE 48: the reference ----------
+
+def _unpacked_level_scores(l, pair, kl, ql, kr):
+    c = kl.shape[1]
+    both = kda_ops._dot(jnp.concatenate([kl, ql], 1), kr, kda_ops._NT,
+                        exact=l < kda_ops._FINE_LEVELS)
+    return (jnp.where(pair, both[:, :c], 0.0),
+            jnp.where(pair, both[:, c:], 0.0))
+
+
+def _unpacked_level_transposes(l, pair, dA, dBs, kl, ql, kr):
+    n = kl.shape[1]
+    exact = l < kda_ops._FINE_LEVELS
+    dAB = jnp.concatenate([jnp.where(pair, dA, 0.0),
+                           jnp.where(pair, dBs, 0.0)], 1)
+    dleft = kda_ops._dot(dAB, kr, kda_ops._NN, exact)       # (H, 2C, Dk)
+    dkr = kda_ops._dot(dAB, jnp.concatenate([kl, ql], 1), kda_ops._TN, exact)
+    return dleft[:, :n], dleft[:, n:], dkr
+
+
+def _three_pass_dot(a, b, dims, exact=False):
+    if not exact:
+        return lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+    (ah, al), (bh, bl) = (kda_ops._bf16_parts(x, 2) for x in (a, b))
+    return kda_ops._one_pass(ah, bh, dims) + (
+        kda_ops._one_pass(ah, bl, dims) + kda_ops._one_pass(al, bh, dims))
+
+
+@pytest.fixture
+def statements_before(monkeypatch):
+    """-> ``before(rows_only)``, a context under which the kernels' bodies
+    hold the statements of the commit before ISSUE 48 (for
+    ``fn.__wrapped__``: a jitted kernel function keeps what it traced): a
+    level's two operands side by side and, unless ``rows_only``, the
+    forward's level 0 on the MXU and every exact product in three
+    passes.  ``rows_only`` undoes the packing alone, of the levels the
+    MXU forms."""
+    now = kda_ops._level_scores
+
+    @contextlib.contextmanager
+    def before(rows_only=False):
+        vpu = kda_ops.KDA_VPU_LEVELS if rows_only else 0
+        with monkeypatch.context() as m:
+            m.setattr(kda_ops, "_level_scores", lambda l, *a: (
+                now if l < vpu else _unpacked_level_scores)(l, *a))
+            m.setattr(kda_ops, "_level_transposes",
+                      _unpacked_level_transposes)
+            if not rows_only:
+                m.setattr(kda_ops, "_dot", _three_pass_dot)
+            yield
+    return before
+
+
+@pytest.mark.parametrize("which", ["fwd", "bwd"])
+def test_a_level_streams_only_the_rows_that_carry_pairs(which,
+                                                        statements_before):
+    """The row-cycle count of each kernel body (ISSUE 48): a level's
+    score product and its two transposes stream ``C`` rows a pass, not
+    the ``2 C`` of which half were zeros (896 row-cycles a chunk-head
+    less in each kernel); the forward's level 0 forms its 32 pairs on
+    the VPU; an exact product whose contraction is ``C`` deep takes two
+    passes."""
+    shape = (1, 64, 1, 128)
+    c = kda_ops.KDA_CHUNK
+    got = _kernel_jaxpr(which, *shape, jitted=False)
+    with statements_before():
+        before = _kernel_jaxpr(which, *shape, jitted=False)
+    with statements_before(rows_only=True):
+        unpacked = _kernel_jaxpr(which, *shape, jitted=False)
+    assert _kernel_row_cycles(before) == ROW_CYCLES_BEFORE_PR48[which]
+    assert _kernel_row_cycles(got) <= ROW_CYCLES[which] \
+        < ROW_CYCLES_BEFORE_PR48[which] - 896
+    # the packing alone: the same passes over half the rows (and in the
+    # backward kernel kr's cotangent sums over C rows, not 2 C: a pass
+    # less at each of levels 0-3)
+    assert _kernel_dots(unpacked) - _kernel_dots(got) \
+        == {"fwd": 0, "bwd": 4}[which]
+    assert 2 * c in map(_streamed_rows, _kernel_dot_eqns(unpacked))
+    assert 2 * c in map(_streamed_rows, _kernel_dot_eqns(before))
+    if which == "bwd":
+        assert (_kernel_dots(before), _kernel_dots(got)) \
+            == (BWD_DOTS_BEFORE_PR48, BWD_DOTS)
+        # what is left above C rows a pass reads the states: Dk rows
+        assert max(map(_streamed_rows, _kernel_dot_eqns(got))) \
+            == kda_ops.KDA_KERNEL_DIM
+
+
+@pytest.mark.parametrize("precision", ["highest", "default"])
+@pytest.mark.parametrize("lo,hi", [(-3.0, -0.01), (-40.0, -1e-4),
+                                   (-0.01, -1e-4)],
+                         ids=["mixed", "wide", "decay-near-1"])
+def test_packed_levels_equal_the_unpacked_statements(lo, hi, precision,
+                                                     statements_before):
+    """Both kernels under the interpreter against the same kernels with
+    the statements they held before ISSUE 48.  With the packing alone
+    undone the output, ``A``, ``Bs``, ``T`` and the cotangents of q, v
+    and beta are equal bit for bit (only rows moved); k's and g's take
+    the transposed product over kr, whose sum over a block's pairs runs
+    in another order, so they agree to float32's rounding.  With level 0
+    on the MXU and three-pass exact products besides, all agree inside
+    ``test_kernels_match_the_plain_chunks``' tolerances: the forward's
+    level 0 forms float32 products now, and the two passes over b's
+    upper half add up in one accumulator.  Three chunks of two heads."""
+    t, heads, d = 192, 2, 128
+    args = _kda_inputs(t, lo, hi, heads=heads, seed=48)
+    w = jnp.asarray(np.random.RandomState(3).randn(*args[2].shape),
+                    jnp.float32)
+    scale = d ** -0.5
+
+    def run():
+        lay = kda_ops._kernel_layout(*args)
+        o, states, kept = kda_ops._kda_fwd.__wrapped__(
+            *lay, scale=scale, interpret=True)
+        grads = kda_ops._kda_bwd.__wrapped__(
+            *lay, states, kept, w.reshape(1, t, -1), scale=scale,
+            interpret=True)
+        return [np.asarray(x) for x in (o, states, kept) + tuple(grads)]
+
+    with jax.default_matmul_precision(precision):
+        got = run()
+        with statements_before(rows_only=True):
+            unpacked = run()
+        with statements_before():
+            before = run()
+    names = ("o", "states", "kept", "dq", "dk", "dv", "dg", "dbeta")
+    for name, x, y, z in zip(names, got, unpacked, before):
+        assert np.abs(y).max() > 0, name
+        if name in ("dk", "dg"):
+            assert np.abs(x - y).max() <= 1e-6 * np.abs(y).max(), name
+        else:
+            assert np.array_equal(x, y), name
+        if precision == "highest":
+            # at the default precision one bfloat16 rounding that falls
+            # the other way is 2**-9 of a product
+            assert np.abs(x - z).max() <= (
+                1e-4 if name in ("o", "states", "kept") else 5e-4) \
+                * np.abs(z).max(), name
 
 
 @pytest.mark.parametrize("lo,hi", [(-3.0, -0.01), (-40.0, -1e-4)],
@@ -308,8 +487,9 @@ def test_delta_rule_lowering_is_chosen_from_shape_and_dtype(t, dk, dv, dtype,
 
 # one trace of each kernel a process; the backward one says how many chunk
 # matrices it takes from the forward one (A, Bs, T) and does not form again
-KERNEL_TRACES = [{"fwd": 1, "bwd": 0},
-                 {"fwd": 0, "bwd": 1, "kept_products": 3}]
+KERNEL_TRACES = [{"fwd": 1, "bwd": 0, "level_rows": 64, "vpu_levels": 1},
+                 {"fwd": 0, "bwd": 1, "kept_products": 3, "level_rows": 64,
+                  "vpu_levels": 0}]
 
 
 def test_the_tpu_program_traces_each_kernel_once():
@@ -344,9 +524,10 @@ def test_the_tpu_program_traces_each_kernel_once():
         assert len(re.findall(r"call @%s\b" % fn, text)) == 2, fn
     assert "triangular_solve" not in text and "stablehlo.while" not in text
     # the backward kernel this program holds forms no score level and no
-    # ``T - T (X T)`` again: 47 passes a chunk fewer than it had
+    # ``T - T (X T)`` again: 47 passes a chunk fewer than it had (ISSUE
+    # 45), then 10 fewer (ISSUE 48)
     assert _bwd_kernel_dots(*q.shape) == BWD_DOTS \
-        == BWD_DOTS_BEFORE_PR45 - 14 - 30 - 3
+        == BWD_DOTS_BEFORE_PR45 - 14 - 30 - 3 - 10
 
 
 def test_kernels_are_traced_once_a_process_and_lowered_once_a_program(
