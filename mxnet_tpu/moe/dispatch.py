@@ -29,7 +29,7 @@ backward are gathers through the plan's permutation and its inverse
 scatter-add, which a TPU executes row by row.  Each is in bounds by
 construction and says so (``_rows``), and the plan's ``order`` and
 ``slot`` are all the backward passes need: no permutation is found
-again.
+again.  (A rank's window sums on the sorted side: ``held_sum``, below.)
 """
 from __future__ import annotations
 
@@ -136,27 +136,28 @@ def held_rows_bound(rows: int, num_experts: int, experts_held: int) -> int:
     return bound if rows - bound >= BOUND_WORTH_ROWS else rows
 
 
-@jax.custom_vjp
-def _sort_rows(x, order, slot, held):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _sort_rows(x, order, slot, held, lo):
     k = slot.shape[1]
     return _rows(x, order // k)
 
 
-def _sort_rows_fwd(x, order, slot, held):
-    return _sort_rows(x, order, slot, held), (slot, held)
+def _sort_rows_fwd(x, order, slot, held, lo):
+    return _sort_rows(x, order, slot, held, lo), (order, slot, held)
 
 
-def _sort_rows_bwd(res, g):
-    slot, held = res
+def _sort_rows_bwd(lo, res, g):
+    order, slot, held = res
     T, k = slot.shape
     # token t's gradient: the sum of its k sorted rows' gradients
-    dx = _rows(g, slot.reshape(T * k)).reshape(T, k, -1)
-    if held is not None:
-        # ``g`` may be a window of the sorted rows: a choice whose row
-        # lies outside it reads a clipped row, and must count for nothing
-        dx = jnp.where(((slot >= 0) & (slot < held))[..., None], dx,
-                       jnp.zeros((), dx.dtype))
-    return dx.sum(axis=1).astype(g.dtype), None, None, None
+    if held is None:
+        dx = _rows(g, slot.reshape(T * k)).reshape(T, k, -1).sum(axis=1)
+    else:
+        # ``g`` is a window of the sorted rows, sorted rows lo .. lo + n -
+        # 1: the held ones of it, summed on the sorted side where
+        # ``held_sum``'s kernel runs
+        dx = held_sum(g, order, slot, held, lo=lo)
+    return dx.astype(g.dtype), None, None, None
 
 
 _sort_rows.defvjp(_sort_rows_fwd, _sort_rows_bwd)
@@ -164,16 +165,15 @@ _sort_rows.defvjp(_sort_rows_fwd, _sort_rows_bwd)
 
 def sort_rows(x, order, slot, held=None, window=None):
     """``(T, D)`` tokens -> the ``(T*k, D)`` rows of a ``SortedPlan``:
-    row ``r`` is token ``order[r] // k``.  A rank's share gives
-    ``held``, the number of rows its experts got (the plan's first), and
-    ``window = (lo, n)``: sorted rows ``lo .. lo + n - 1`` only, and the
-    choices whose row is not a held one of them add nothing to the
-    tokens' gradient."""
-    if window is not None:
-        lo, n = window
-        order, slot, held = order[lo:lo + n], slot - lo, \
-            jnp.minimum(held - lo, n)
-    return _sort_rows(x, order, slot, held)
+    row ``r`` is token ``order[r] // k``.  A rank's share gives ``held``,
+    the rows each of its experts got (the plan's first groups; a scalar:
+    their sum), and ``window = (lo, n)``: sorted rows ``lo .. lo + n - 1``
+    only, and the choices whose row is not a held one of them add
+    nothing to the tokens' gradient, which is ``held_sum`` of the rows'
+    (below: over the window's held rows where its kernel runs, through
+    ``slot`` elsewhere)."""
+    lo, n = window if window is not None else (0, order.shape[0])
+    return _sort_rows(x, order[lo:lo + n], slot, held, int(lo))
 
 
 def _picked_sum(rows, slot, weight):
@@ -208,18 +208,18 @@ def _combine_sorted_bwd(res, g):
 _combine_sorted.defvjp(_combine_sorted_fwd, _combine_sorted_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _combine_held(rows, order, slot, weight, lo):
-    return _combine_held_fwd(rows, order, slot, weight, lo)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _combine_held(rows, order, slot, weight, held, lo):
+    return _combine_held_fwd(rows, order, slot, weight, held, lo)[0]
 
 
-def _combine_held_fwd(rows, order, slot, weight, lo):
-    # sorted rows lo .. lo + n - 1: a choice outside them reads a clipped
-    # row and weighs nothing.  Saved: the rows themselves, n of them, and
-    # not the T*k gathered ones
-    at = slot - lo
-    inside = (at >= 0) & (at < rows.shape[0])
-    out = _picked_sum(rows, at, jnp.where(inside, weight, 0.0))[1]
+def _combine_held_fwd(rows, order, slot, weight, held, lo):
+    # sorted rows lo .. lo + n - 1, the held ones of them: a choice
+    # outside them weighs nothing (``held_sum``, below: on the sorted
+    # side where its kernel runs).  Saved: the rows themselves, n of
+    # them, and not the T*k gathered ones
+    out = held_sum(rows, order[lo:lo + rows.shape[0]], slot, held, weight,
+                   lo)
     return out, (rows, order, slot, weight)
 
 
@@ -238,13 +238,13 @@ def _combine_held_bwd(lo, res, g):
                 * g_sorted.astype(jnp.float32)).sum(axis=-1)
     d_weight = _moved(jnp.pad(d_weight, (lo, T * k - hi)), order)
     return d_rows.astype(rows.dtype), None, None, \
-        d_weight.reshape(T, k).astype(weight.dtype)
+        d_weight.reshape(T, k).astype(weight.dtype), None
 
 
 _combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
 
 
-def combine_sorted(rows, order, slot, weight, share_from=None):
+def combine_sorted(rows, order, slot, weight, share_from=None, held=None):
     """``(T*k, O)`` expert outputs in sorted order -> ``(T, O)``: each
     token's k rows, weighted by its gate values and summed.  ``order``
     and ``slot`` are the plan's permutation and its inverse: the forward
@@ -252,15 +252,15 @@ def combine_sorted(rows, order, slot, weight, share_from=None):
     and no permutation is found again (the backward pass brings the
     ``T*k`` weights, scalars, to sorted order by one key-value sort).
 
-    ``share_from = lo``: a rank's share, whose held rows come first and
-    whose other rows are zero with weight 0, and ``rows`` are sorted
-    rows ``lo .. lo + n - 1`` of the ``T*k``: a choice outside them reads
-    a clipped row, finite, times 0.  The backward pass saves ``rows``
-    (not the ``T*k`` gathered ones) and forms both gradients over the
-    ``n`` sorted rows, so a choice outside them, and an absent one,
-    gets exactly 0 for its weight."""
+    ``share_from = lo``: a rank's share (``held`` as ``sort_rows``'; None:
+    every row), whose held rows come first and whose other rows are zero
+    with weight 0, and ``rows`` are sorted rows ``lo .. lo + n - 1`` of
+    the ``T*k``: the forward pass is ``held_sum`` (below) of the held
+    ones.  The backward pass saves ``rows`` (not the ``T*k`` gathered
+    ones) and forms both gradients over the ``n`` sorted rows, so a
+    choice outside them, and an absent one, gets 0 for its weight."""
     if share_from is not None:
-        return _combine_held(rows, order, slot, weight, int(share_from))
+        return _combine_held(rows, order, slot, weight, held, int(share_from))
     return _combine_sorted(rows, order, slot, weight)
 
 
@@ -289,3 +289,81 @@ def grouped_matmul(rows, w, group_sizes):
 # names them anew ("ragged-dot-none"): the scope of their one caller,
 # ``_moe_expert_ffn`` (this repo's kernels keep the caller's path)
 _scopes.adopt("ragged-dot", "moe_experts")
+
+
+# -- a rank's window brought back to its tokens -------------------------------
+#
+# One expert-parallel rank's share works on a *window* of the sorted rows
+# (``sort_rows(window=)``, ``combine_sorted(share_from=)``), of which only the
+# rank's held rows count.  The two passes that bring a window back to the
+# tokens (the combine's forward, ``sort_rows``' backward) are one function,
+# ``held_sum``, with two forms: through ``slot``, ``T*k`` rows gathered for
+# the few that count (every platform but a TPU, float32), and on the sorted
+# side, where the repo's ``token-sum`` kernel reads the held rows where they
+# lie and moves none (bfloat16 on a TPU, ``moe/gmm.py``).  It stands at the
+# end of the file so that no line above it moves: the grouped-matmul kernels'
+# Mosaic payloads name ``grouped_matmul``'s line and are part of JAX's cache
+# key (a cell that runs none of this keeps its compiled programs).
+
+def held_sum(rows, order, slot, held, weight=None, lo=0, interpret=False):
+    """For every token the sum of the window's held rows that are its
+    choices, ``(T, D)``: ``out[t] = sum_j [0 <= slot[t, j] - lo < h]
+    weight[t, j] * rows[slot[t, j] - lo]``.  ``rows`` ``(n, D)`` are sorted
+    rows ``lo .. lo + n - 1`` of the plan, ``order`` ``(n,)`` the plan's
+    over them, ``held`` the rows each held expert got (the plan's first
+    groups; a scalar: their sum; None: every row of the window is held)
+    and ``h`` what of them lies in the window; ``weight`` None weighs
+    every choice 1.  It is the combine's forward pass and the backward
+    pass of ``sort_rows`` over a rank's window.
+
+    Two forms of the one sum, chosen as ``grouped_matmul``'s lowerings
+    are (``gmm.token_sum_tiles`` from dtype and static shapes when the op
+    is traced, the platform when the program is lowered):
+
+    * **token side** (every platform but a TPU, a program over more than
+      one device, float32, a ``T`` or ``n`` that is no whole number of
+      256-row tiles, a ``D`` that is no whole number of lanes, a scalar
+      ``held``): gather all ``T*k`` choices' rows through ``slot``, weigh
+      the ones outside the held rows by 0, sum over ``k``.  ``T*k`` rows
+      moved for the ``h`` that count: 2.2-2.5 ms a call in the SDAR and
+      SmallThinker cells, 84 % of it for a weight of 0.
+    * **sorted side** (bfloat16 on a TPU): ``gmm.token_sums``.  A held
+      expert's rows are in token order, so its rows of one tile of 256
+      tokens are consecutive, and the kernel adds each such run to its
+      token tile by a product with the matrix of the rows' weights at
+      their places in the tile: exact products, float32 sums, one
+      rounding, zeros for a token tile that holds no row, no row behind
+      the held ones read, no row moved.  The weights come to sorted order
+      by ``_moved``, rounded to the rows' dtype as the token side rounds
+      them.  On the chip the two forms agree bit for bit (XLA:TPU keeps
+      the token side's products in float32 too).  PERF.md, PR 49."""
+    from ..ops.pallas_kernels import _kernel_on_tpu
+    from ..parallel.mesh import traced_devices
+    T, k = slot.shape
+    n = rows.shape[0]
+    at = slot - lo
+    held = jnp.asarray(lo + n if held is None else held)
+
+    def token_side(rows, order, at, held, *weight):
+        inside = (at >= 0) & (at < jnp.minimum(held.sum() - lo, n))
+        picked = _rows(rows, at.reshape(T * k)).reshape(T, k, -1)
+        if weight:
+            # a choice outside the held rows reads a clipped row, finite
+            # (behind them the experts' output is zero), and weighs 0
+            w = jnp.where(inside, weight[0], 0.0)
+            return (picked * w[..., None].astype(rows.dtype)).sum(axis=1)
+        # a cotangent's rows: whatever lies outside counts for nothing
+        return jnp.where(inside[..., None], picked,
+                         jnp.zeros((), rows.dtype)).sum(axis=1)
+
+    def sorted_side(rows, order, at, held, *weight):
+        w_sorted = _moved(weight[0].reshape(T * k),
+                          at.reshape(T * k))[lo:lo + n] if weight else None
+        return _gmm.token_sums(rows, order // k, held, lo, T, w_sorted,
+                               interpret=interpret)
+
+    args = (rows, order, at, held) + (() if weight is None else (weight,))
+    if held.ndim == 0 or traced_devices() > 1 or not _gmm.token_sum_tiles(
+            n, rows.shape[1], T, rows.dtype):
+        return token_side(*args)
+    return _kernel_on_tpu(sorted_side, token_side, interpret, *args)

@@ -38,7 +38,7 @@ backward pass are two module-level ``jax.jit`` functions free of
 per-call objects (``_forward``, ``_backward``): a process traces each
 once a signature, choice and kernels with it, and a program holds each
 once for any number of layers.  The counter ``moe:gmm_trace`` fires
-where a kernel is traced: once a signature a process.
+where a kernel is traced.  A third kernel, ``token-sum``: the file's end.
 """
 from __future__ import annotations
 
@@ -54,7 +54,7 @@ from ..ops.pallas_kernels import _kernel_on_tpu, pl
 from ..parallel.mesh import traced_devices
 
 __all__ = ["GroupTiles", "group_tiles", "tiles_for", "tiled_matmul",
-           "ragged_matmul"]
+           "ragged_matmul", "token_sums", "token_sum_tiles"]
 
 ROW_TILE = 256
 # a v5e core has 128 MiB of VMEM and gives a kernel 16 unasked; the
@@ -275,7 +275,7 @@ def _note_trace(which, lhs, rhs, tiles):
         track="%s %s%s x %s" % (which, lhs.dtype.name, list(lhs.shape),
                                 list(rhs.shape)),
         gmm=int(which == "gmm"), gmm_t=int(which == "gmm_t"),
-        tgmm=int(which == "tgmm"),
+        tgmm=int(which == "tgmm"), tsum=int(which == "tsum"),
         **dict(zip(("tm", "tk", "tn"), tiles)))
 
 
@@ -434,3 +434,160 @@ def tiled_matmul(rows, w, group_sizes, interpret: bool = False):
             and tiles_for(rows.shape[0], k, n, e, rows.dtype):
         return _two_lowerings(rows, w, tiles, interpret)
     return ragged_matmul(rows, w, getattr(tiles, "sizes", tiles))
+
+
+# -- a rank's window summed a token (``dispatch.held_sum``) -------------------
+#
+# ``token-sum`` (PR 49): no grouped matmul of an expert FFN but the same walk
+# turned round, for one expert-parallel rank's window of the sorted rows:
+# ``(M, N)`` rows whose groups are each in token order -> ``(T, N)``, every
+# token's rows summed.  Its name does not begin ``ragged-dot``: it is none of
+# the nine products ``moe_gmm_roofline`` counts work for.  It stands at the
+# end of the file so that no line above it moves: a Mosaic kernel's payload
+# names its source lines and is part of JAX's cache key.
+
+# rows a visit of ``token-sum`` reads: a run (one expert's rows of one tile
+# of ROW_TILE tokens) is ~20-40 rows in the cells that run it.  A v5e, SDAR's
+# window (32 768 rows of 2048, 8 287 held in 16 groups), the combine's
+# forward, ms a call: 0.92 at 32 rows, 0.80 at 64, 0.81 at 128, 1.17 at 256;
+# the gathers it replaces 2.91 (my chip runs, PR 49)
+SUM_ROWS = 64
+
+
+def token_sum_tiles(m: int, n: int, tokens: int, dtype
+                    ) -> Optional[Tuple[int, int]]:
+    """``(tm, tn)`` of the ``token-sum`` kernel for ``(M, N)`` rows summed
+    into ``tokens`` tokens, or None where it does not run and the caller's
+    gathers stay: float32 (a 0/1 left operand moves bfloat16 rows exactly
+    -- one MXU pass, float32 accumulation, one rounding at the output --
+    while float32 rows would pass through Mosaic's default product, which
+    nobody has shown to be exact), rows that are no whole number of row
+    tiles, tokens that are no whole number of ``ROW_TILE``-token tiles, an
+    ``N`` that is no whole number of 128-lane tiles."""
+    tn = _divisor(n, 4096)
+    if dtype != jnp.bfloat16 or m % ROW_TILE or tokens % ROW_TILE or not tn:
+        return None
+    return SUM_ROWS, tn
+
+
+def _run_visits(token, sizes, lo, *, tokens, tm):
+    """The visits of ``token-sum``.  Sorted rows ``lo .. lo + n - 1`` of a
+    plan whose first groups have ``sizes`` rows, a group's rows in token
+    order (``token`` ``(n,)`` says which): group ``e``'s rows of token
+    tile ``g`` are one *run* of consecutive rows, and run ``(g, e)``
+    visits the row tiles of ``tm`` it touches, an empty run one tile,
+    once (a token tile with no row at all still has its zeros written).
+    Runs are walked ``g`` first, so the visits that add to one token tile
+    are consecutive.  -> ``(tile_of, out_of, lo_of, hi_of, visits)``: visit
+    ``v`` adds rows ``lo_of[v] .. hi_of[v] - 1`` of row tile ``tile_of[v]``
+    to token tile ``out_of[v]``; at most ``n / tm + 2 G E`` of them."""
+    n, groups, tiles_g = token.shape[0], sizes.shape[0], tokens // ROW_TILE
+    runs, tiles_m = groups * tiles_g, n // tm
+    ends = jnp.clip(jnp.cumsum(sizes.astype(jnp.int32)) - lo, 0, n)
+    row = jnp.arange(n, dtype=jnp.int32)
+    group = (row[:, None] >= ends[None, :]).sum(axis=1, dtype=jnp.int32)
+    # rows ascend in (group, token tile); behind the groups: no run's
+    key = jnp.where(row < ends[-1], group * tiles_g + token // ROW_TILE, runs)
+    upto_key = (key[None, :] < jnp.arange(runs + 1, dtype=jnp.int32)[:, None]
+                ).sum(axis=1, dtype=jnp.int32)
+    # run (g, e) is rows start[g, e] .. stop[g, e] - 1
+    start = upto_key[:-1].reshape(groups, tiles_g).T.reshape(runs)
+    stop = upto_key[1:].reshape(groups, tiles_g).T.reshape(runs)
+    first = jnp.minimum(start // tm, tiles_m - 1)
+    count = jnp.where(stop > start, (stop - 1) // tm - first, 0) + 1
+    upto = jnp.cumsum(count)
+    v = jnp.arange(tiles_m + 2 * runs, dtype=jnp.int32)
+    run_of = jnp.minimum(
+        (v[:, None] >= upto[None, :]).sum(axis=1, dtype=jnp.int32), runs - 1)
+    tile_of = jnp.minimum((first - upto + count)[run_of] + v, tiles_m - 1)
+    return (tile_of, run_of // groups, start[run_of], stop[run_of],
+            upto[-1:])
+
+
+def _token_sum_kernel(tile_of, out_of, lo_of, hi_of, token, rows, *rest, tm):
+    (weight, out, acc) = rest if len(rest) == 3 else (None,) + rest
+    v, last = pl.program_id(1), pl.num_programs(1) - 1
+    g = out_of[v]
+    start, end, row0 = lo_of[v], hi_of[v], tile_of[v] * tm
+
+    @pl.when((v == 0) | (out_of[jnp.maximum(v - 1, 0)] != g))
+    def _():
+        acc[...] = jnp.zeros_like(acc)
+
+    @pl.when(end > start)
+    def _():
+        # through float32: a v5e selects no bfloat16
+        own = jnp.where(_row_mask(rows.shape, row0, start, end),
+                        rows[...].astype(jnp.float32), 0.0)
+        # (ROW_TILE, tm): row r of the tile stands at token column
+        # token[r] - ROW_TILE g of the token tile, with its weight (1
+        # where there is none): the products are exact in float32
+        place = (lax.broadcasted_iota(jnp.int32, (ROW_TILE, tm), 0)
+                 == token[...] - g * ROW_TILE)
+        place = place.astype(jnp.float32) if weight is None else \
+            jnp.where(place, weight[...], 0.0)
+        acc[...] += lax.dot_general(
+            place.astype(rows.dtype), own.astype(rows.dtype),
+            (((1,), (0,)), ((), ())), precision=_precision(rows.dtype),
+            preferred_element_type=jnp.float32)
+
+    @pl.when((v == last) | (out_of[jnp.minimum(v + 1, last)] != g))
+    def _():
+        out[...] = acc[...].astype(out.dtype)
+
+
+def token_sums(rows, token, sizes, lo, tokens, weight=None, *, interpret):
+    """``token-sum``: for every token the sum of its rows, ``(tokens, N)``
+    in ``rows``' dtype.  ``rows`` ``(M, N)`` are sorted rows ``lo .. lo + M
+    - 1`` of a plan whose first groups have ``sizes`` rows, each group's
+    in token order; ``token`` ``(M,)`` int32 their tokens; ``weight``
+    ``(M,)`` weighs each row, rounded to the rows' dtype first.  Rows
+    behind the groups are never read.
+
+    No row moves: a group's rows of one tile of ``ROW_TILE`` tokens are
+    consecutive (a *run*), and the kernel adds each run to its token tile
+    by one product with the matrix that holds each row's weight (1 where
+    there is none) at the row's place in the tile and 0 elsewhere, made
+    in VMEM from ``token``: a bfloat16 weight times a bfloat16 row is
+    exact in float32, the sums are float32 in VMEM over the token tile's
+    runs, and the output rounds once.  That is what XLA:TPU's fused
+    ``(rows * w).sum()`` gives on the chip too (it keeps the product in
+    float32: ``xla_allow_excess_precision``), bit for bit (tests/tpu).
+    Grid ``(n tiles, visits)``, ``_run_visits``' list prefetched as
+    scalars, as the grouped-matmul kernels walk theirs.  Who calls it:
+    ``dispatch.held_sum`` (the combine's forward pass and the backward
+    pass of ``sort_rows`` over one expert-parallel rank's window).
+    Counter ``moe:gmm_trace`` with ``which`` = ``tsum``."""
+    from jax.experimental.pallas import tpu as pltpu
+    m, n = rows.shape
+    tm, tn = token_sum_tiles(m, n, tokens, rows.dtype)
+    _note_trace("tsum", jax.ShapeDtypeStruct((m, ROW_TILE), rows.dtype),
+                rows, (tm, ROW_TILE, tn))
+    visits = _run_visits(token, sizes, lo, tokens=tokens, tm=tm)
+    operands = [token.reshape(m // tm, 1, tm), rows]
+    in_specs = [
+        pl.BlockSpec((None, 1, tm), lambda n_i, v, t, *_: (t[v], 0, 0)),
+        pl.BlockSpec((tm, tn), lambda n_i, v, t, *_: (t[v], n_i))]
+    if weight is not None:
+        operands.append(weight.astype(rows.dtype).astype(jnp.float32)
+                        .reshape(m // tm, 1, tm))
+        in_specs.append(in_specs[0])
+    # lint: allow(raw-pallas-call) — as _gmm
+    return pl.pallas_call(
+        functools.partial(_token_sum_kernel, tm=tm),
+        out_shape=jax.ShapeDtypeStruct((tokens, n), rows.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec(
+                (ROW_TILE, tn), lambda n_i, v, t, o, *_: (o[v], n_i)),
+            grid=(n // tn, visits[4][0]),
+            scratch_shapes=[pltpu.VMEM((ROW_TILE, tn), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * m * ROW_TILE * n, transcendentals=0,
+            bytes_accessed=rows.dtype.itemsize * (m + tokens) * n),
+        interpret=interpret, name="token-sum",
+    )(*visits[:4], *operands)

@@ -415,11 +415,11 @@ class MoEShareFFNOp(OpDef):
     ten times either branch's: PERF.md, PR 40).  A program over more
     than one device, and a geometry whose ``R`` is ``T*k`` (the bound
     would save too few rows to be worth a conditional), run the three
-    nodes' statements over all rows with no ``cond``.  Token-sized all
-    the same: the combine's gather through ``slot`` and the row
-    gradient's.  A window's parts and the bounded node are jits of this
-    module (``_WINDOW_PARTS``, ``_share_bounded``): traced once a
-    process, whatever the layer, the pass and the module.
+    nodes' statements over all rows with no ``cond``.  Nothing ``T*k``-
+    sized is left in a window where ``moe.dispatch.held_sum``'s kernel
+    runs (the combine's forward, the row gradient's backward).  A
+    window's parts and the bounded node are jits of this module
+    (``_WINDOW_PARTS``, ``_share_bounded``): traced once a process.
 
     ``act_zeros`` adds a second output ``(2,)`` float32, no gradient:
     ``(zeros, lanes)`` of the activated lanes (``act(x Wg)``) of the rows
@@ -487,7 +487,7 @@ def _static(items):
 
 def _window_rows(params, window, x, order, slot, counts):
     from ..moe.dispatch import sort_rows
-    held = _held_sizes(_AttrDict(params), counts).sum() if window else None
+    held = _held_sizes(_AttrDict(params), counts) if window else None
     return sort_rows(x, order, slot, held, window)
 
 
@@ -496,10 +496,10 @@ def _window_ffn(params, window, rows, counts, *stacked):
     return _sorted_ffn(p, rows, _layers(p, stacked), counts, window)
 
 
-def _window_sum(params, window, rows, order, slot, weight):
-    from ..moe.dispatch import combine_sorted
-    return combine_sorted(rows, order, slot, weight,
-                          share_from=window[0] if window else None)
+def _window_sum(params, window, rows, order, slot, weight, counts):
+    from ..moe.dispatch import combine_sorted as combine
+    held = _held_sizes(_AttrDict(params), counts) if window else None
+    return combine(rows, order, slot, weight, window and window[0], held)
 
 
 # lint: allow(raw-jit) — never dispatched on their own: jits inside the step
@@ -528,7 +528,7 @@ def _share_window(p, window=None):
         if p.get("act_zeros"):
             rows, seen = rows
         with _scope("moe_combine", p):
-            out = combine(params, window, rows, order, slot, weight)
+            out = combine(params, window, rows, order, slot, weight, counts)
         return (out, seen) if p.get("act_zeros") else out
     return body
 
