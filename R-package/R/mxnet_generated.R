@@ -189,6 +189,14 @@ mx.symbol.FullyConnected <- function(...) {
   mx.symbol.internal.create('FullyConnected', list(...))
 }
 
+#' Gated DeltaNet (arXiv:2412.06464) over ``(B, T, Hk, Dk)`` query
+#' @param layer layer parameter (default -1)
+#' @param name string, node name (auto when omitted)
+#' @return MXSymbol
+mx.symbol.GatedDeltaNet <- function(...) {
+  mx.symbol.internal.create('GatedDeltaNet', list(...))
+}
+
 #' reference identity_attach_KL_sparse_reg-inl.h: identity forward with a
 #' @param sparseness_target sparseness_target parameter (default 0.1)
 #' @param penalty penalty parameter (default 0.001)
@@ -825,6 +833,14 @@ mx.nd.FullyConnected <- function(..., ctx = mx.cpu()) {
   a <- list(...)
   is.nd <- vapply(a, function(x) inherits(x, 'MXNDArray'), logical(1))
   mx.nd.internal.invoke('FullyConnected', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
+}
+
+#' Imperative GatedDeltaNet: 7 NDArray input(s), 0 scalar(s)
+#' @return MXNDArray
+mx.nd.GatedDeltaNet <- function(..., ctx = mx.cpu()) {
+  a <- list(...)
+  is.nd <- vapply(a, function(x) inherits(x, 'MXNDArray'), logical(1))
+  mx.nd.internal.invoke('GatedDeltaNet', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
 }
 
 #' Imperative KimiDeltaAttention: 7 NDArray input(s), 0 scalar(s)

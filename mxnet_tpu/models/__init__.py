@@ -20,6 +20,7 @@ from .glm_moe_lite import glm_moe_lite_lm
 from .sdar_moe import sdar_moe_lm
 from .afmoe import afmoe_lm
 from .smallthinker import smallthinker_lm
+from .qwen3_next import qwen3_next_lm
 from .gru import gru_unroll, gru_cell, rnn_unroll, rnn_cell, GRUState, \
     GRUParam, RNNState, RNNParam
 
@@ -30,5 +31,6 @@ __all__ = ["get_mlp", "get_lenet", "get_resnet", "get_resnet50",
            "LSTMParam", "make_generator", "make_discriminator",
            "get_fcn32s", "get_fcn16s", "get_fcn8s", "get_fast_rcnn",
            "get_rpn", "olmoe_lm", "kimi_linear_lm", "glm_moe_lite_lm",
-           "sdar_moe_lm", "afmoe_lm", "smallthinker_lm", "gru_unroll", "gru_cell", "rnn_unroll",
+           "sdar_moe_lm", "afmoe_lm", "smallthinker_lm", "qwen3_next_lm",
+           "gru_unroll", "gru_cell", "rnn_unroll",
            "rnn_cell", "GRUState", "GRUParam", "RNNState", "RNNParam"]

@@ -79,30 +79,36 @@ def gqa_attention(h, pre, layer, rows, num_heads, num_kv_heads, head_dim,
     ``(B*rows, D)``.  ``rotate`` places q
     and k (default: no positions but the order), ``mask`` is
     ``CausalSelfAttention``'s (default: causal), ``gated`` multiplies the
-    heads' outputs by ``sigmoid(h Wg)`` before ``o_proj``.  Scopes:
+    heads' outputs by the sigmoid of a gate before ``o_proj``: True, the
+    gate is its own projection ``h Wg``; ``"query"``, it is the second
+    half of a doubled ``q_proj``, head by head ``[q | gate]``.  Scopes:
     ``attn_proj.l<i>``, ``attn_gate.l<i>``."""
     width = num_heads * head_dim
 
-    def heads(name, n):
-        return sym.Reshape(proj(h, pre + name + "_proj", n * head_dim),
-                           shape=(-1, rows, n, head_dim))
+    def heads(name, n, lanes=head_dim):
+        return sym.Reshape(proj(h, pre + name + "_proj", n * lanes),
+                           shape=(-1, rows, n, lanes))
 
-    def placed(name, n):
-        x = heads(name, n)
+    def placed(x, name):
         if head_norms:
             x = norm(x, pre + name + "_norm", eps)
         return rotate(x)
 
     with scoped("", "attn_proj", layer):
-        q, k = placed("q", num_heads), placed("k", num_kv_heads)
+        q = heads("q", num_heads, head_dim * (2 if gated == "query" else 1))
+        if gated == "query":
+            q, gate = (sym.slice_axis(q, axis=3, begin=lo, end=lo + head_dim)
+                       for lo in (0, head_dim))
+        q, k = placed(q, "q"), placed(heads("k", num_kv_heads), "k")
         v = heads("v", num_kv_heads)
     a = sym.CausalSelfAttention(q, k, v, layer=layer, name=pre + "attn",
                                 **mask)
     with scoped("", "attn_gate" if gated else "attn_proj", layer):
         a = sym.Reshape(a, shape=(-1, width))
         if gated:
-            a = a * sym.Activation(proj(h, pre + "attn_gate_proj", width),
-                                   act_type="sigmoid")
+            gate = sym.Reshape(gate, shape=(-1, width)) if gated == "query" \
+                else proj(h, pre + "attn_gate_proj", width)
+            a = a * sym.Activation(gate, act_type="sigmoid")
     with scoped("", "attn_proj", layer):
         return proj(a, pre + "o_proj", hidden_size)
 

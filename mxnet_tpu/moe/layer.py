@@ -48,7 +48,8 @@ def MoEFeedForward(data, num_hidden: int, num_experts: int, k: int = 2,
                    score: str = "softmax", scale: float = 1.0,
                    bias_rate: float = 0.0, shared_hidden: int = 0,
                    experts_held: int = 0, first_expert: int = 0,
-                   router_data=None, act_zeros: bool = False):
+                   router_data=None, act_zeros: bool = False,
+                   shared_gate: bool = False):
     """Build one routed MoE feed-forward block over ``data`` (T, D).
 
     ``capacity_factor`` None reads ``MXNET_MOE_CAPACITY_FACTOR``
@@ -68,8 +69,11 @@ def MoEFeedForward(data, num_hidden: int, num_experts: int, k: int = 2,
     and moves by ``bias_rate * sign(mean load - load)`` a training step;
     ``shared_hidden`` > 0 adds one shared expert of that width (three
     ``FullyConnected``, in the experts' gated or plain form) to every
-    token's output.  ``experts_held`` > 0 makes this one expert-parallel
-    rank's share: the router, its weights' renormalization and the load
+    token's output, with ``shared_gate`` times ``sigmoid(x w_sg)``, one
+    number a token from a fourth, bias-free ``FullyConnected`` of width 1
+    (``<name>_shared_gate``).  ``experts_held`` > 0 makes this one
+    expert-parallel rank's share: the router, its weights'
+    renormalization and the load
     head stay ``num_experts`` wide, the stacked weights hold experts
     ``first_expert .. first_expert + experts_held - 1`` only, and rows
     that chose another expert are left out of the grouped matmuls and
@@ -150,7 +154,13 @@ def MoEFeedForward(data, num_hidden: int, num_experts: int, k: int = 2,
     hidden = fc(data, "i2h", shared_hidden)
     hidden = act(fc(data, "i2h_gate", shared_hidden)) * hidden if gated \
         else act(hidden)
-    return out + fc(hidden, "h2o", output_dim)
+    shared = fc(hidden, "h2o", output_dim)
+    if shared_gate:
+        shared = _sym.broadcast_mul(shared, _sym.Activation(
+            _sym.FullyConnected(data, num_hidden=1, no_bias=True,
+                                name=name + "_shared_gate"),
+            act_type="sigmoid"))
+    return out + shared
 
 
 def _dispatch_heads(symbol, out_idx: int) -> List:

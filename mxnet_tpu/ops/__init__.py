@@ -14,7 +14,7 @@ from . import quantized  # noqa: F401 (registers q/dq + int8 matmul/conv)
 from . import fused   # noqa: F401  (registers the epilogue-fused op family)
 from . import moe     # noqa: F401  (registers the routed-MoE dispatch family)
 from . import transformer  # noqa: F401 (registers RMSNorm/RoPE/attention/loss head)
-from . import linear_attention  # noqa: F401 (registers KimiDeltaAttention/CausalConv1D)
+from . import linear_attention  # noqa: F401 (registers the delta-rule ops and CausalConv1D)
 
 __all__ = ["OpDef", "OpContext", "Param", "register_op", "register_simple_op",
            "get_op", "list_ops"]

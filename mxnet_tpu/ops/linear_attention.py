@@ -1,6 +1,8 @@
 """Linear-attention block ops: ``KimiDeltaAttention`` (the gated delta
-rule with a per-channel decay, "KDA": Kimi Linear, arXiv:2510.26692) and
-the depthwise ``CausalConv1D`` in front of it.
+rule with a per-channel decay, "KDA": Kimi Linear, arXiv:2510.26692),
+``GatedDeltaNet`` (the same rule with ONE decay a head and token and
+fewer key heads than value heads: Gated DeltaNet, arXiv:2412.06464) and
+the depthwise ``CausalConv1D`` in front of them.
 
 Per head the layer keeps a ``(Dk, Dv)`` state and, token by token,
 
@@ -19,8 +21,15 @@ exp(-G_j)`` across more than the chunk's decay allows: blocks of
 <= 1), and a block against itself is computed channel by channel.  So a
 decay of ``exp(-40)`` a token is as safe as one of 1.
 
-The op's body, ``kimi_delta_attention`` (normalize q and k, the two
-gates, the rule), is one algorithm with two lowerings, chosen from what
+The ops' body (normalize q and k, the two gates, the rule) is one
+algorithm behind two front ends, ``kimi_delta_attention`` and
+``gated_delta_net``.  What tells them apart is the rank of the decay's
+projection, read in one place (``kda_gates``, ``_normalized_and_gated``):
+``(B, T, H, Dk)`` is a key lane's, ``(B, T, Hv)`` a head's one number,
+which is then handed over the key lanes while the ``Hk`` key heads are
+repeated for their value heads; both inside the ``custom_vjp``'s body,
+so that a step keeps the op's own inputs and nothing of their size
+times ``Dk``.  The body has two lowerings, chosen from what
 the code observes as ``causal_attention`` chooses.  The plain ``lax`` /
 ``jnp`` chunks above, differentiable by autodiff, run on every platform
 and are the parity oracle.  Where the program is LOWERED for a TPU and
@@ -71,9 +80,11 @@ number of layers and modules that call them: the counter
 from the forward kernel, 3; both events' ``level_rows`` the rows a
 level's products stream, 64, and ``vpu_levels`` the levels that kernel
 forms off the MXU, 1 and 0) fires from inside their bodies and so counts
-traces, not calls. The counter ``kda:lowering`` records the choice per
-traced op (``kernel`` / ``plain``) as ``attn:lowering`` does for
-attention, and the op's body runs under ``kda.l<layer>``.
+traces, not calls. The counter ``kda:lowering`` (``gdn:lowering`` for
+``GatedDeltaNet``, with its ``key_heads`` and ``value_heads``) records
+the choice per traced op (``kernel`` / ``plain``) as ``attn:lowering``
+does for attention, and either op's body runs under ``kda.l<layer>``, the
+scope of the rule and its kernels.
 """
 from __future__ import annotations
 
@@ -89,8 +100,8 @@ from .pallas_kernels import _kernel_on_tpu, pl
 from .registry import OpDef, Param, register_op
 from .transformer import layer_scope
 
-__all__ = ["causal_conv1d", "gated_delta_rule", "kda_gates",
-           "kimi_delta_attention"]
+__all__ = ["causal_conv1d", "gated_delta_net", "gated_delta_rule",
+           "kda_gates", "kimi_delta_attention"]
 
 # tokens a chunk: one triangular system and one step of the state's scan
 KDA_CHUNK = 64
@@ -108,14 +119,18 @@ def _l2norm(x, eps=1e-6):
 
 
 def kda_gates(decay, beta, a_log, dt_bias):
-    """The layer's two gates from their projections, in float32:
-    ``g = -exp(a_log[head]) * softplus(decay + dt_bias)`` per channel
-    (``decay`` ``(B, T, H, Dk)``, ``dt_bias`` ``(H * Dk,)``) and ``beta =
-    sigmoid(beta)`` per head."""
+    """The layer's two gates from their projections, in float32: ``g =
+    -exp(a_log[head]) * softplus(decay + dt_bias)`` in ``decay``'s shape
+    and ``beta = sigmoid(beta)`` per head.  ``decay``'s rank says whose
+    the decay is: ``(B, T, H, Dk)`` with ``dt_bias`` ``(H * Dk,)`` is a
+    key lane's (KDA), ``(B, T, H)`` with ``dt_bias`` ``(H,)`` a head's
+    one number (Gated DeltaNet)."""
     f32 = jnp.float32
-    h, dk = decay.shape[2], decay.shape[3]
-    g = -jnp.exp(a_log.astype(f32))[:, None] * jax.nn.softplus(
-        decay.astype(f32) + dt_bias.astype(f32).reshape(h, dk))
+    a = jnp.exp(a_log.astype(f32))
+    if decay.ndim == 4:
+        a = a[:, None]
+    g = -a * jax.nn.softplus(
+        decay.astype(f32) + dt_bias.astype(f32).reshape(decay.shape[2:]))
     return g, jax.nn.sigmoid(beta.astype(f32))
 
 
@@ -642,8 +657,19 @@ def _kernel_rule_vjp(q, k, v, g, beta, kept, do, scale: float,
 
 def _normalized_and_gated(q, k, decay, beta, a_log, dt_bias):
     """What the op does before the rule, all float32: q and k
-    L2-normalized a head, the log-decay and the write gate."""
-    return (_l2norm(q), _l2norm(k)) + kda_gates(decay, beta, a_log, dt_bias)
+    L2-normalized a head, the log-decay and the write gate, at the
+    VALUE's heads and over the key lanes, as the rule takes them.  A
+    head's decay (``kda_gates``: its rank) is handed over the key lanes
+    here, and ``Hk`` key heads under ``Hv`` value heads are repeated
+    here (value head ``j`` reads key head ``j // (Hv / Hk)``): inside
+    the ``custom_vjp``'s body, so that neither is kept across the step."""
+    q, k = _l2norm(q), _l2norm(k)
+    g, beta = kda_gates(decay, beta, a_log, dt_bias)
+    if g.ndim == 3:
+        group = g.shape[2] // q.shape[2]
+        q, k = jnp.repeat(q, group, axis=2), jnp.repeat(k, group, axis=2)
+        g = jnp.broadcast_to(g[..., None], q.shape)
+    return q, k, g, beta
 
 
 def _scale(q) -> float:
@@ -683,7 +709,7 @@ def _two_lowerings_fwd(q, k, v, decay, beta, a_log, dt_bias, interpret):
 
     def plain(*args):
         return _plain_attention(*args), [
-            jnp.zeros(x.shape, x.dtype) for x in _kept_shapes(*args[0].shape)]
+            jnp.zeros(x.shape, x.dtype) for x in _kept_shapes(*args[2].shape)]
 
     o, kept = _kernel_on_tpu(kernels, plain, interpret, *args)
     return o, (args, kept)
@@ -713,29 +739,51 @@ def _two_lowerings_bwd(interpret, res, do):
 _two_lowerings.defvjp(_two_lowerings_fwd, _two_lowerings_bwd)
 
 
-def kimi_delta_attention(q, k, v, decay, beta, a_log, dt_bias,
-                         interpret: bool = False):
-    """Kimi Delta Attention of ``KimiDeltaAttentionOp``'s seven inputs:
-    normalize, gate, ``gated_delta_rule``.
-
-    One algorithm, two lowerings.  Inputs the kernels take
-    (``_kernel_takes``) run them where the program is LOWERED for a TPU
-    (anywhere under ``interpret``, the Pallas interpreter: the tests) and
-    the plain chunks on any other platform; every other input runs the
-    plain chunks everywhere.  Each trace records which, as the counter
-    ``kda:lowering``: ``kernel`` 1 means this op's TPU lowering is the
-    kernels (the lowered text of a CPU program holds the plain chunks all
-    the same), ``plain`` 1 the plain chunks on every platform; the track
-    names dtype and shape."""
+def _delta_attention(counter, track, args, interpret, **fields):
+    """The op's body behind either front end: one algorithm, two
+    lowerings.  Inputs the kernels take (``_kernel_takes``) run them
+    where the program is LOWERED for a TPU (anywhere under ``interpret``,
+    the Pallas interpreter: the tests) and the plain chunks on any other
+    platform; every other input runs the plain chunks everywhere.  Each
+    trace records which, as ``counter``: ``kernel`` 1 means this op's TPU
+    lowering is the kernels (the lowered text of a CPU program holds the
+    plain chunks all the same), ``plain`` 1 the plain chunks on every
+    platform."""
+    q, v = args[0], args[2]
     kernel = _kernel_takes(q, v)
-    trace.counter("kda:lowering", cat="ops",
-                  track="%s%s" % (v.dtype.name, list(q.shape)),
+    trace.counter(counter, cat="ops", track=track,
                   chunked=1, chunk=min(KDA_CHUNK, q.shape[1]),
-                  kernel=int(kernel), plain=int(not kernel))
-    args = (q, k, v, decay, beta, a_log, dt_bias)
+                  kernel=int(kernel), plain=int(not kernel), **fields)
     if not kernel:
         return _plain_attention(*args)
     return _two_lowerings(*args, interpret)
+
+
+def kimi_delta_attention(q, k, v, decay, beta, a_log, dt_bias,
+                         interpret: bool = False):
+    """Kimi Delta Attention of ``KimiDeltaAttentionOp``'s seven inputs:
+    normalize, gate, ``gated_delta_rule`` with a decay a key lane
+    (``_delta_attention``).  The counter is ``kda:lowering``; the track
+    names dtype and shape."""
+    return _delta_attention(
+        "kda:lowering", "%s%s" % (v.dtype.name, list(q.shape)),
+        (q, k, v, decay, beta, a_log, dt_bias), interpret)
+
+
+def gated_delta_net(q, k, v, decay, beta, a_log, dt_bias,
+                    interpret: bool = False):
+    """Gated DeltaNet of ``GatedDeltaNetOp``'s seven inputs: the same
+    body (``_delta_attention``) with a head's one decay, ``decay`` ``(B,
+    T, Hv)``, and ``Hk`` key heads under ``Hv`` value heads; what tells
+    the two front ends apart is the decay's rank, read in one place
+    (``kda_gates``, ``_normalized_and_gated``).  The counter is
+    ``gdn:lowering`` with ``key_heads`` and ``value_heads``; the track is
+    ``<dtype>[B, T, Hv, D]/k<Hk>``."""
+    hk, hv = q.shape[2], v.shape[2]
+    return _delta_attention(
+        "gdn:lowering", "%s%s/k%d" % (v.dtype.name, list(v.shape), hk),
+        (q, k, v, decay, beta, a_log, dt_bias), interpret,
+        key_heads=hk, value_heads=hv)
 
 
 def _kernel_takes(q, v) -> bool:
@@ -811,3 +859,41 @@ class KimiDeltaAttentionOp(OpDef):
     def forward(self, p, inputs, aux, ctx):
         with layer_scope("kda", p.layer):
             return [kimi_delta_attention(*inputs)]
+
+
+@register_op("GatedDeltaNet", hint="gdn")
+class GatedDeltaNetOp(OpDef):
+    """Gated DeltaNet (arXiv:2412.06464) over ``(B, T, Hk, Dk)`` query
+    and key and ``(B, T, Hv, Dv)`` value, ``Hk`` a whole divisor of
+    ``Hv`` (value head ``j`` reads key head ``j // (Hv / Hk)``): q and k
+    are L2-normalized per head, the decay is ONE number a value head and
+    token, ``g = -exp(a_log[head]) * softplus(decay + dt_bias[head])``
+    from its projection ``decay`` ``(B, T, Hv)``, the write gate is
+    ``sigmoid(beta)`` from ``(B, T, Hv)``, and the output is the gated
+    delta rule's ``S_t^T q_t * Dk**-0.5`` (``gated_delta_rule`` with
+    ``g`` equal over a head's key lanes), ``(B, T, Hv, Dv)``.  The same
+    body and the same two lowerings as ``KimiDeltaAttention``: where the
+    kernels run they are ``kda_chunk_fwd`` / ``kda_chunk_bwd``, so the
+    op's body runs under ``kda.l<layer>``, the scope of the rule."""
+    params = [Param("layer", int, default=-1)]
+
+    def list_arguments(self, p):
+        return ["query", "key", "value", "decay", "beta", "a_log_bias",
+                "dt_bias"]
+
+    def infer_shape(self, p, in_shapes):
+        q, v = in_shapes[0], in_shapes[2]
+        if q is None or v is None:
+            return in_shapes, [None], []
+        if len(q) != 4 or len(v) != 4 or tuple(q[:2]) != tuple(v[:2]) \
+                or q[2] < 1 or v[2] % q[2]:
+            raise MXNetError("GatedDeltaNet: query (batch, seq, key_heads, "
+                             "key_dim) and value (batch, seq, value_heads, "
+                             "value_dim) with key_heads a whole divisor of "
+                             "value_heads, got %r and %r" % (q, v))
+        gate = tuple(v[:3])
+        return [q, q, v, gate, gate, (v[2],), (v[2],)], [v], []
+
+    def forward(self, p, inputs, aux, ctx):
+        with layer_scope("kda", p.layer):
+            return [gated_delta_net(*inputs)]

@@ -1,6 +1,6 @@
 """The decoder builders' symbols, node for node (ISSUE 46, tier-1).
 
-The LM builders of ``mxnet_tpu/models`` (five then, six now) are
+The LM builders of ``mxnet_tpu/models`` (five then, seven now) are
 assembled from one skeleton, ``models/decoder.py``.  What holds that assembly still is the
 graph each builder returns: under a fresh ``NameManager`` the symbol's
 JSON (every node's op, name, keywords, attributes and inputs, the unnamed
@@ -67,6 +67,13 @@ SMALLTHINKER = dict(num_layers=4, hidden_size=32,
                     rope_theta=1.5e6, num_experts=16, experts_per_tok=3,
                     expert_width=24, vocab_size=50, seq_len=16,
                     experts_held=4, first_expert=4, rms_eps=1e-6)
+QWEN3_NEXT = dict(num_layers=4, hidden_size=32, full_attention_interval=4,
+                  gdn_key_heads=2, gdn_value_heads=4, gdn_head_dim=8,
+                  conv_kernel=4, num_heads=4, num_kv_heads=2, head_dim=16,
+                  rotary_dim=4, rope_theta=1e7, num_experts=16,
+                  experts_per_tok=4, expert_width=24, shared_width=24,
+                  vocab_size=50, seq_len=24, rms_eps=1e-6, aux_coef=0.001,
+                  experts_held=4, first_expert=4)
 WHOLE = dict(experts_held=0, first_expert=0)
 # latent attention by itself: seq_len, hidden_size, heads, kv_lora_rank,
 # qk_nope_dim, qk_rope_dim, v_head_dim, rms_eps
@@ -107,6 +114,17 @@ SYMBOLS = {
     "smallthinker-counted": _tiny("smallthinker_lm", SMALLTHINKER,
                                   act_zeros=True),
     "smallthinker-whole": _tiny("smallthinker_lm", SMALLTHINKER, **WHOLE),
+    # the seventh builder, from the commit that added it (ISSUE 50): its
+    # cell, a rank's share, the whole layer, no balance heads, every lane
+    # rotated, attention in every second layer
+    "qwen3-next-80b-a3b": _cell("qwen3-next-80b-a3b"),
+    "qwen3-next-share": _tiny("qwen3_next_lm", QWEN3_NEXT),
+    "qwen3-next-whole": _tiny("qwen3_next_lm", QWEN3_NEXT, **WHOLE),
+    "qwen3-next-aux-0": _tiny("qwen3_next_lm", QWEN3_NEXT, aux_coef=0.0),
+    "qwen3-next-all-rotated": _tiny("qwen3_next_lm", QWEN3_NEXT,
+                                    rotary_dim=16),
+    "qwen3-next-every-second": _tiny("qwen3_next_lm", QWEN3_NEXT,
+                                     full_attention_interval=2),
     # OLMoE: its load-balance heads stay on at coefficient 0
     "olmoe-tiny": _tiny("olmoe_lm", OLMOE),
     "olmoe-aux-0": _tiny("olmoe_lm", OLMOE, aux_coef=0.0),
@@ -162,6 +180,19 @@ SYMBOL_WAS = {
         "4dd9b03064d193eab8d657784f597d6d779c00b8cae09e19fdec3613e90ebdfd",
     "smallthinker-whole":
         "b7b3aee331521264f4ec20024a1d4ea0c553144c1c2666ef2e413f8688f07b58",
+    # taken at the commit that added the builder (ISSUE 50)
+    "qwen3-next-80b-a3b":
+        "fc0fa8e66990e59ec2e0530707989493d3c9b32144d5e31680128b54958f32b1",
+    "qwen3-next-share":
+        "f8d0043899ef9c23a96cca11f09af6896cf852d73bc9191fdc5c6089e710bd45",
+    "qwen3-next-whole":
+        "d458e91e9f75c6a413d5d8b4720a8835d31538254f4f11b5e0fbe48c91b9f4b6",
+    "qwen3-next-aux-0":
+        "894915db681d3025cfca0323028cfa009573f54fc379e0228647821b09c2c16d",
+    "qwen3-next-all-rotated":
+        "e1a196070230587c4f0e05522fae34616b78965247f4757e3c371820a4430fb6",
+    "qwen3-next-every-second":
+        "9d4034e3aa1f0d517fa5f76042c09563aa8c77fde3b8fc393acf1ef4b926c61f",
     "olmoe-tiny":
         "af8dc705e9e7aeb26b2806967a870d607de9f4892070d6b09552c77d2034efc0",
     "olmoe-aux-0":
@@ -228,6 +259,8 @@ STEPS = {
               dict(data=(2, 16), softmax_label=(2, 16))),
     "smallthinker": (_tiny("smallthinker_lm", SMALLTHINKER, act_zeros=True),
                      dict(data=(2, 16), softmax_label=(2, 16))),
+    "qwen3-next": (_tiny("qwen3_next_lm", QWEN3_NEXT),
+                   dict(data=(2, 24), softmax_label=(2, 24))),
 }
 
 # sha256 of each step's lowered text at 945e6c8
@@ -241,6 +274,9 @@ STEP_WAS = {
     # taken at the commit that added the builder (ISSUE 47)
     "smallthinker":
         "b1a83ee5679c16bad171411fc23ea7d6a0df99b255614ddcd14ccc724757f01c",
+    # taken at the commit that added the builder (ISSUE 50)
+    "qwen3-next":
+        "6e4944b4858261fffebfe74aa076f97c54dc917fe18ff7fa1f4903003165ecb7",
 }
 
 

@@ -1,0 +1,385 @@
+"""Qwen3-Next-80B-A3B at its published widths on the chip (as the
+``qwen3-next-80b-a3b`` configuration is cut: its four layers, the held
+share of the 512 experts, an eighth of the vocabulary), against the plain
+reference ``benchmark/reference/qwen3-next-80b-a3b.py`` computed on the
+same chip.
+
+    MXNET_TPU_TESTS=1 python -m pytest tests/tpu/test_qwen3_next_tpu.py -s -q
+
+The first test has phases that each release what they held (the chip
+holds one module of this size at a time): the reference's loss,
+gradients and first Adam step at one sequence of 4096, and the same with
+its weights rounded to float8 (what the configuration's limits have to
+refuse); the configuration's own Adam step in bfloat16 at the default
+matmul precision, as the cell's reference check runs it, on
+``QWEN3_NEXT_PARITY_SEEDS`` seeds (8; weights and batch both from the
+seed), with the ``gdn:lowering``, ``attn:lowering`` and
+``kda:kernel_trace`` samples of the bind; and the Adam step in float32
+compute against the reference at one sequence of 1024.  The numbers go to
+``chiprun_out/qwen3_next_parity.json`` after every phase, before
+anything is asserted.
+
+The second holds ``gated_delta_net``'s two lowerings against each other
+at the cell's shape, ``(1, 4096, 32, 128)`` values under 16 key heads
+with a head's decay: forward and all seven gradients, with both
+lowerings' times a layer.  The third holds ``causal_attention``'s TPU
+kernel against its plain blocks at ``(1, 4096, 16, 256)`` over 2
+key/value heads.
+"""
+import gc
+import json
+import os
+import sys
+import time
+
+import numpy as np
+
+from _mirror import tpu_gate
+
+pytestmark = [tpu_gate()]
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+# as tests/tpu/test_olmoe_tpu.py: each side rounds its probabilities and
+# results to 8 bits of mantissa
+ATTN_MAX_ERR_SHARE = 0.02
+ATTN_L2_ERR = 0.01
+SEED = 5000000050
+GDN_TRACK = "bfloat16[1, 4096, 32, 128]/k16"
+ATTN_TRACK = "bfloat16[1, 4096, 16, 256]/kv2"
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+def _adam_step(net, params, data, labels, opt_params, compute_dtype, names):
+    """One step of the fused train step on the chip.  -> (the loss,
+    choices per expert a block, {name: after - before})."""
+    import mxnet_tpu as mx
+    if compute_dtype:
+        os.environ["MXNET_COMPUTE_DTYPE"] = compute_dtype
+    else:
+        os.environ.pop("MXNET_COMPUTE_DTYPE", None)
+    try:
+        mod = mx.mod.Module(net, context=mx.tpu(0))
+        mod.bind(data_shapes=[("data", data.shape)],
+                 label_shapes=[("softmax_label", labels.shape)])
+        mod.init_params(mx.init.Zero(), allow_missing=True, arg_params={
+            k: mx.nd.array(v) for k, v in params.items()})
+        gc.collect()
+        mod.init_optimizer(optimizer="adam",
+                           optimizer_params=dict(opt_params))
+        assert mod._fused is not None
+        batch = mx.io.DataBatch(
+            data=[mx.nd.array(data, dtype=np.int32)],
+            label=[mx.nd.array(labels, dtype=np.int32)], pad=0)
+        mod.forward_backward(batch)
+        mod.update()
+        assert mod._exec_group.execs == []
+        outs = [o.asnumpy() for o in mod.get_outputs()]
+        load = mod._fused.moe_load_heads[0]
+        after, _ = mod.get_params()
+        delta = {n: after[n].asnumpy() - params[n] for n in names}
+        del mod, after, batch
+    finally:
+        os.environ.pop("MXNET_COMPUTE_DTYPE", None)
+    gc.collect()
+    return float(outs[0].mean()), outs[load][:, :-1], delta
+
+
+def test_published_width_step_matches_reference():
+    import jax
+    import jax.numpy as jnp
+    import mxnet_tpu as mx
+    from mxnet_tpu.models import qwen3_next_lm
+    sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+    import manifest
+    ref = manifest.load_module("reference", "qwen3-next-80b-a3b")
+    gen = manifest.load_module("generators", "token_packed")
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "qwen3-next-80b-a3b.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(ROOT, "benchmark", "traffic",
+                           "packed-4k-b1.json")) as f:
+        traffic = json.load(f)
+    kw = cfg["model"]["kwargs"]
+    names = cfg["reference"]["weights"]
+    limits = cfg["reference"]
+    adam = cfg["optimizer"]["params"]
+    seq = kw["seq_len"]
+    seeds = int(os.environ.get("QWEN3_NEXT_PARITY_SEEDS", "8"))
+    net = qwen3_next_lm(**kw)
+    shapes = dict(zip(net.list_arguments(), net.infer_shape(
+        data=(1, seq), softmax_label=(1, seq))[0]))
+
+    def weights(seed):
+        rng = np.random.default_rng(seed)
+        return {n: (np.ones(s, np.float32) if n.endswith("gamma") else
+                    np.zeros(s, np.float32) if n.endswith("bias") else
+                    0.02 * rng.standard_normal(s, dtype=np.float32))
+                for n, s in shapes.items()
+                if n not in ("data", "softmax_label")}
+
+    def batch_of(seed, config=cfg):
+        batches = gen.build(dict(traffic, distinct_batches=1), config, seed,
+                            [mx.cpu(0)], None)
+        (data,), (labels,) = (list(d.values()) for d in
+                              batches.reference_batch(1)[:2])
+        return data, labels
+
+    params = weights(SEED)
+    data, labels = batch_of(SEED)
+    report = {"device": jax.devices()[0].device_kind,
+              "params_M": sum(v.size for v in params.values()) / 1e6,
+              "experts_held": kw["experts_held"], "adam_bf16": {}}
+    out_dir = os.path.join(ROOT, "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+
+    def save():
+        with open(os.path.join(out_dir, "qwen3_next_parity.json"),
+                  "w") as f:
+            json.dump(report, f, indent=1)
+
+    def reference(p, d, lb, config=cfg):
+        t0 = time.perf_counter()
+        out = ref.reference_step(config, p, {"data": d},
+                                 {"softmax_label": lb}, adam, names)
+        gc.collect()
+        report.setdefault("reference_s", []).append(
+            round(time.perf_counter() - t0, 1))
+        return out
+
+    def loss_of(got, want):
+        return {"loss": got, "reference_loss": want["loss"],
+                "loss_rel_err": abs(got - want["loss"]) / want["loss"]}
+
+    # A. the reference on this chip, and with float8 weights (e4m3, the
+    # nearest format under bfloat16; arithmetic stays float32)
+    want = reference(params, data, labels)
+    coarse = {n: np.asarray(jnp.asarray(v).astype(jnp.float8_e4m3fn)
+                            .astype(jnp.float32))
+              for n, v in params.items()}
+    out = reference(coarse, data, labels)
+    report["reference_fp8_weights"] = dict(
+        loss_of(out["loss"], want),
+        adam_update_rel_err={n: _rel(out["updates"][n], want["updates"][n])
+                             for n in names})
+    del out, coarse
+    gc.collect()
+    save()
+    print("\nQWEN3_NEXT_PARITY fp8 " + json.dumps(
+        report["reference_fp8_weights"]), flush=True)
+
+    # B. the configuration's step, bfloat16 at the default precision,
+    # weights and batch from each seed
+    mx.trace.set_enabled(True)
+    for i in range(seeds):
+        seed = SEED + i
+        if i:
+            params, (data, labels) = weights(seed), batch_of(seed)
+            want = reference(params, data, labels)
+        mark = time.perf_counter_ns()
+        with jax.default_matmul_precision("default"):
+            loss, counts, delta = _adam_step(
+                net, params, data, labels, adam, "bfloat16", names)
+        report.setdefault("module_step_s", []).append(
+            round((time.perf_counter_ns() - mark) / 1e9, 1))
+        counters = {c: [[e["id"], e["args"]] for e in
+                        mx.trace.counter_events([c], since_ns=mark)]
+                    for c in ("gdn:lowering", "attn:lowering",
+                              "kda:kernel_trace")}
+        report["adam_bf16"][str(seed)] = dict(
+            loss_of(loss, want),
+            update_rel_err={n: _rel(delta[n], want["updates"][n])
+                            for n in names},
+            held_rows=[float(c[:kw["experts_held"]].sum()) for c in counts],
+            **counters)
+        save()
+        print("\nQWEN3_NEXT_PARITY bf16 %d " % seed + json.dumps(
+            report["adam_bf16"][str(seed)]), flush=True)
+        del want, delta
+        gc.collect()
+
+    # C. float32 compute against the reference, one sequence of 1024
+    short = dict(kw, seq_len=1024)
+    cfg_short = dict(cfg, model=dict(cfg["model"], kwargs=short),
+                     input=dict(cfg["input"], seq_len=1024))
+    params = weights(SEED)
+    d32, l32 = batch_of(SEED, cfg_short)
+    want = reference(params, d32, l32, cfg_short)
+    loss32, _, delta32 = _adam_step(
+        qwen3_next_lm(**short), params, d32, l32, adam, None, names)
+    report["adam_f32_t1024"] = dict(
+        loss_of(loss32, want),
+        update_rel_err={n: _rel(delta32[n], want["updates"][n])
+                        for n in names})
+    save()
+    print("\nQWEN3_NEXT_PARITY f32 " + json.dumps(report["adam_f32_t1024"]),
+          flush=True)
+
+    fp8 = report["reference_fp8_weights"]
+    traces = []
+    for seed, bf16 in report["adam_bf16"].items():
+        assert bf16["loss_rel_err"] <= limits["loss_rtol"], seed
+        for n in names:
+            assert bf16["update_rel_err"][n] <= limits["update_rtol"][n], \
+                (seed, n)
+        # one op a layer, every one the kernels: three of the rule with a
+        # head's decay, then attention under the causal mask
+        assert [t for t, _ in bf16["gdn:lowering"]] == [GDN_TRACK] * 3
+        assert all(a["kernel"] == 1 and a["plain"] == 0
+                   and (a["key_heads"], a["value_heads"]) == (16, 32)
+                   for _, a in bf16["gdn:lowering"])
+        assert bf16["attn:lowering"] == [[ATTN_TRACK, {
+            "kernel": 1, "plain": 0, "mask_form": "library"}]]
+        traces += bf16["kda:kernel_trace"]
+    # the process traced each kernel once for all the steps' nine layers
+    assert sorted((a["fwd"], a["bwd"]) for _, a in traces) \
+        == [(0, 1), (1, 0)]
+    # float8 weights are refused by at least one limit
+    assert fp8["loss_rel_err"] > limits["loss_rtol"] or any(
+        fp8["adam_update_rel_err"][n] > limits["update_rtol"][n]
+        for n in names)
+    f32 = report["adam_f32_t1024"]
+    assert f32["loss_rel_err"] <= 1e-4
+    assert max(f32["update_rel_err"].values()) <= 0.1, f32
+
+
+def test_gdn_kernels_match_the_plain_chunks_at_the_cells_shape():
+    """``gated_delta_net`` at ``(1, 4096, 32, 128)`` bfloat16 values under
+    16 key heads with a head's decay: the kernel lowering (compiled by
+    Mosaic) against the plain chunks, forward and all seven gradients,
+    at HIGHEST precision; at the default precision the kernels are no
+    further from that than the plain chunks are."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import linear_attention as kda
+    b, t, hk, hv, d = 1, 4096, 16, 32, 128
+    names = ("o", "dq", "dk", "dv", "ddecay", "dbeta", "da_log", "ddt_bias")
+    rng = np.random.RandomState(50)
+    q, k = (jnp.asarray(rng.standard_normal((b, t, hk, d)), jnp.bfloat16)
+            for _ in range(2))
+    v = jnp.asarray(rng.standard_normal((b, t, hv, d)), jnp.bfloat16)
+    decay = jnp.asarray(rng.uniform(-4, 4, (b, t, hv)), jnp.bfloat16)
+    beta = jnp.asarray(rng.uniform(-3, 3, (b, t, hv)), jnp.bfloat16)
+    a_log = jnp.asarray(rng.uniform(-1, 1, (hv,)), jnp.float32)
+    dt_bias = jnp.asarray(rng.uniform(-1, 1, (hv,)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((b, t, hv, d)), jnp.bfloat16)
+    args = (q, k, v, decay, beta, a_log, dt_bias)
+    assert kda._kernel_takes(q, v)
+
+    def both_passes(fn):
+        def run(*a):
+            out, vjp = jax.vjp(fn, *a)
+            return (out,) + vjp(w)
+        return jax.jit(run)
+
+    kernel = both_passes(kda.gated_delta_net)
+    plain = both_passes(kda._plain_attention)
+    text = kernel.lower(*args).compile().as_text()
+    assert "kda_chunk_fwd" in text and "kda_chunk_bwd" in text
+    assert "kda_chunk" not in plain.lower(*args).compile().as_text()
+
+    def rels(got, want):
+        return {n: _rel(x, y) for n, x, y in zip(names, got, want)}
+
+    with jax.default_matmul_precision("highest"):
+        want = plain(*args)
+        exact = rels(kernel(*args), want)
+    with jax.default_matmul_precision("default"):
+        got = kernel(*args)
+        report = {
+            "shape": [b, t, hv, d], "key_heads": hk,
+            "kernel_vs_plain_highest": exact,
+            "kernel_default_vs_plain_highest": rels(got, want),
+            "plain_default_vs_plain_highest": rels(plain(*args), want),
+            "finite": bool(all(np.isfinite(np.asarray(x, np.float32)).all()
+                               for x in got))}
+
+        def ms(fn, *a):
+            jax.block_until_ready(fn(*a))
+            t0 = time.perf_counter()
+            for _ in range(5):
+                out = fn(*a)
+            jax.block_until_ready(out)
+            return (time.perf_counter() - t0) / 5 * 1e3
+
+        report["ms_a_layer"] = {
+            "kernel_forward_backward": ms(kernel, *args),
+            "plain_forward_backward": ms(plain, *args)}
+    out_dir = os.path.join(ROOT, "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, "gdn_kernel_parity.json"), "w") as f:
+        json.dump(report, f, indent=1)
+    print("\nGDN_KERNEL_PARITY " + json.dumps(report), flush=True)
+    assert report["finite"]
+    for n in names:
+        # outputs and gradients are rounded to bfloat16 on both sides
+        assert report["kernel_vs_plain_highest"][n] <= 1e-2, (n, report)
+        assert report["kernel_default_vs_plain_highest"][n] <= \
+            1.05 * report["plain_default_vs_plain_highest"][n] + 1e-2, \
+            (n, report)
+
+
+def test_attention_kernel_matches_plain_blocks_at_256_over_two():
+    """``causal_attention`` at the cell's ``(1, 4096, 16, 256)`` bfloat16
+    q over 2 key/value heads (groups of 8) compiles to the Mosaic
+    kernels on the chip; output and all three input gradients agree with
+    the plain blocks'."""
+    import jax
+    import jax.numpy as jnp
+    import mxnet_tpu as mx
+    from mxnet_tpu.ops import transformer as tf_ops
+    scale = 256 ** -0.5
+    rng = np.random.RandomState(50)
+    q, k, v = (jnp.asarray(rng.standard_normal((1, 4096, h, 256)),
+                           jnp.bfloat16) for h in (16, 2, 2))
+    w = jnp.asarray(rng.standard_normal((1, 4096, 16, 256)), jnp.float32)
+
+    def both_passes(attend, *mask):
+        def run(q, k, v):
+            out, vjp = jax.vjp(lambda *a: attend(*a, scale, *mask), q, k, v)
+            return (out,) + vjp(w.astype(out.dtype))
+        return jax.jit(run)
+
+    mx.trace.set_enabled(True)
+    mark = time.perf_counter_ns()
+    kernel = both_passes(tf_ops.causal_attention)
+    plain = both_passes(tf_ops._plain_attention)
+    text = kernel.lower(q, k, v).compile().as_text()
+    assert "tpu_custom_call" in text and "splash_mha" in text
+    assert "tpu_custom_call" not in plain.lower(q, k, v).compile().as_text()
+    event = mx.trace.counter_events(["attn:lowering"], since_ns=mark)[-1]
+    assert event["args"] == {"kernel": 1, "plain": 0,
+                             "mask_form": "library"}
+    assert event["id"] == ATTN_TRACK
+    got = [np.asarray(x, np.float32) for x in kernel(q, k, v)]
+    want = [np.asarray(x, np.float32) for x in plain(q, k, v)]
+    report = {"max_err_share": [], "l2_err": []}
+    for g, r in zip(got, want):
+        report["max_err_share"].append(
+            float(np.abs(g - r).max() / np.abs(r).max()))
+        report["l2_err"].append(_rel(g, r))
+
+    def ms(fn, *a):
+        jax.block_until_ready(fn(*a))
+        t0 = time.perf_counter()
+        for _ in range(10):
+            out = fn(*a)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / 10 * 1e3
+
+    report["ms_a_layer"] = {
+        "kernel_forward_backward": ms(kernel, q, k, v),
+        "plain_forward_backward": ms(plain, q, k, v)}
+    out_dir = os.path.join(ROOT, "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, "qwen3_next_attn_parity.json"),
+              "w") as f:
+        json.dump(report, f, indent=1)
+    print("\nQWEN3_NEXT_ATTN_PARITY " + json.dumps(report), flush=True)
+    assert max(report["max_err_share"]) <= ATTN_MAX_ERR_SHARE, report
+    assert max(report["l2_err"]) <= ATTN_L2_ERR, report
