@@ -26,10 +26,12 @@ algorithm behind two front ends, ``kimi_delta_attention`` and
 ``gated_delta_net``.  What tells them apart is the rank of the decay's
 projection, read in one place (``kda_gates``, ``_normalized_and_gated``):
 ``(B, T, H, Dk)`` is a key lane's, ``(B, T, Hv)`` a head's one number,
-which is then handed over the key lanes while the ``Hk`` key heads are
-repeated for their value heads; both inside the ``custom_vjp``'s body,
-so that a step keeps the op's own inputs and nothing of their size
-times ``Dk``.  The body has two lowerings, chosen from what
+under which the ``Hk`` key heads are repeated for their value heads
+inside the ``custom_vjp``'s body, so that a step keeps the op's own
+inputs and nothing of their size times ``Dk``.  A head's decay stays one
+number a head and token wherever it can: the plain chunks take it handed
+over the key lanes (``_plain_attention``), the kernels as it is, a chunk
+a row (below).  The body has two lowerings, chosen from what
 the code observes as ``causal_attention`` chooses.  The plain ``lax`` /
 ``jnp`` chunks above, differentiable by autodiff, run on every platform
 and are the parity oracle.  Where the program is LOWERED for a TPU and
@@ -69,6 +71,38 @@ half a pass's depth, in three where it is ``Dk``), as the plain chunks
 form them in float32; every other product follows the precision in
 force, as the plain chunks' matmuls do.
 
+All of that halving is a LANE's decay's: ``exp(G_i - G_j)`` a key lane
+does not leave the dot product ``k_i . k_j``.  A head's decay does: it
+is one number a pair, so ``A = tril(K K^T, -1) * D`` and ``Bs = scale *
+tril(Q K^T) * D`` with ``D[i, j] = exp(G_i - G_j)`` on ``j <= i``, a
+``(C, C)`` matrix whose every entry is <= 1 (``exp(G_i)`` and ``exp(-
+G_j)`` are never formed apart), and there is no reference point, no
+level and no packing.  The same two kernels hold both forms, and what
+decides is the decay's rank where ``_kernel_layout`` hands it over: a
+lane's as ``(B, T, H * Dk)`` float32 beside q and k, a head's as ``(B,
+H, T / C, 1, C)``, a chunk a row of lanes (``_kernel_rule`` /
+``_kernel_rule_vjp`` carry it, and its cotangent, as they carry
+beta's; not in beta's ``(B, H, T, 1)``, which lies on the chip with its
+last dimension padded to 128 lanes: the bytes of the decay on every
+key lane).  The forms share the grid, the block specs, what is kept
+(``_kept_shapes``), the inverse (``_chunk_scores_and_inverse``'s second
+half), ``_chunk_entry``, the output, the state and the backward kernel
+down to the states' cotangent; under a head's decay the decays there
+are ``(H, C, 1)`` columns that scale rows.  They differ in how the
+chunk's running sum,
+``A``, ``Bs`` and their transposes are formed: a lane's by
+``_sum_rows``, ``_lane_scores`` and the level loop of ``_bwd_kernel``; a
+head's by a float32 sum of 64 numbers on the VPU (``_chunk_sums``), by
+k and q stacked, ``2 C`` rows against ``k^T`` in ONE exact product under
+``D`` (``_head_scores``: no pair at a lower precision than the levels
+give it), and in the backward kernel by the two cotangents under ``D``
+stacked against k and, transposed, against ``[k; q]``
+(``_head_cotangents``); ``G``'s cotangent from the scores is ``rowsum(P)
+- colsum(P)`` of ``P = dA A + dBs Bs`` off the diagonal, which adds up
+to nothing over a chunk by construction, the states' terms are summed
+over the lanes inside the kernel, and ``dg`` leaves a chunk a row, as
+g came.
+
 The lowering differentiates itself (``jax.custom_vjp`` around the op's
 body, which keeps the op's inputs, the entry states and ``A``, ``Bs``,
 ``T`` and nothing else: ``_kept_shapes``) through two module-level
@@ -77,11 +111,13 @@ traces each kernel once and a program holds each once, whatever the
 number of layers and modules that call them: the counter
 ``kda:kernel_trace`` (``fwd`` / ``bwd``; the ``bwd`` event's
 ``kept_products`` is the number of chunk matrices that backward takes
-from the forward kernel, 3; both events' ``level_rows`` the rows a
+from the forward kernel, 3; both events' ``decay`` whose decay the
+kernel was traced for, ``lane`` or ``head``, ``level_rows`` the rows a
 level's products stream, 64, and ``vpu_levels`` the levels that kernel
-forms off the MXU, 1 and 0) fires from inside their bodies and so counts
-traces, not calls. The counter ``kda:lowering`` (``gdn:lowering`` for
-``GatedDeltaNet``, with its ``key_heads`` and ``value_heads``) records
+forms off the MXU, 1 and 0; under a head's decay both read 0: no level)
+fires from inside their bodies and so counts traces, not calls: one a
+kernel, process and decay's kind. The counter ``kda:lowering``
+(``gdn:lowering`` for ``GatedDeltaNet``, with its ``key_heads`` and ``value_heads``) records
 the choice per traced op (``kernel`` / ``plain``) as ``attn:lowering``
 does for attention, and either op's body runs under ``kda.l<layer>``, the
 scope of the rule and its kernels.
@@ -371,21 +407,66 @@ def _pair_masks(c):
 _FINE_LEVELS = KDA_SUB.bit_length() - 1
 
 
+def _heads_decay(G) -> bool:
+    """Whose a chunk's log-decay is, from its running sum's shape: ``(H,
+    C, 1)`` is a head's one number a token, ``(H, C, Dk)`` a key
+    lane's."""
+    return G.shape[2] == 1
+
+
+def _as_row(x, row, col):
+    """A column a head ``(H, C, 1)`` as a row ``(H, 1, C)``, the same
+    bits: the diagonal of its spread over the lanes, summed over the
+    sublanes (every other term is a zero)."""
+    return jnp.sum(jnp.where(row == col, x, 0.0), axis=1, keepdims=True)
+
+
+def _as_col(x, row, col):
+    """``_as_row`` the other way: ``(H, 1, C)`` -> ``(H, C, 1)``."""
+    return jnp.sum(jnp.where(row == col, x, 0.0), axis=2, keepdims=True)
+
+
 def _chunk_sums(g):
-    """The running sum ``G`` of a chunk's log-decay ``(H, C, Dk)`` and
-    the chunk's ``_pair_masks``: what every other part of the chunk
-    algebra starts from.  -> G, (row, col, pairs)."""
-    masks = _pair_masks(g.shape[1])
+    """The running sum ``G`` of a chunk's log-decay and the chunk's
+    ``_pair_masks``: what every other part of the chunk algebra starts
+    from.  -> G, (row, col, pairs).  A lane's decay ``(H, C, Dk)`` is
+    summed by the MXU into its own shape; a head's, a row a head ``(H,
+    1, C)``, in float32 on the VPU (64 numbers a head) into a column
+    ``(H, C, 1)``, which scales rows wherever a lane's ``G`` scales
+    elements."""
+    head = g.shape[1] == 1
+    masks = _pair_masks(g.shape[2 if head else 1])
     row, col, _ = masks
+    if head:
+        return jnp.sum(jnp.where(row >= col, g, 0.0), axis=2,
+                       keepdims=True), masks
     return _sum_rows(row >= col, g), masks
 
 
-def _chunk_scores_and_inverse(q, k, g, beta, G, scale, masks):
-    """One chunk of a few heads, everything float32: q, k, g and their
-    running sum ``(H, C, Dk)``, beta ``(H, C, 1)`` -> ``A`` and ``Bs``,
-    the scores of ``_chunk_scores`` (``Bs`` times ``scale``), and ``T``,
-    the inverse of ``I + beta A``, each ``(H, C, C)``.  The forward
-    kernel's alone: the backward kernel reads the three back."""
+def _head_decays(G, row, col):
+    """``D[i, j] = exp(G_i - G_j)`` on ``j <= i`` and zero above, ``(H,
+    C, C)`` from a head's running sum ``(H, C, 1)``: every entry <= 1
+    since ``g <= 0``, the diagonal exactly 1 (the row is the column's
+    bits).  What a head's decay leaves of the levels: it is one number a
+    pair, so it stands outside the pair's sum over the lanes."""
+    return jnp.where(row >= col, jnp.exp(jnp.minimum(
+        G - _as_row(G, row, col), 0.0)), 0.0)
+
+
+def _head_scores(q, k, G, scale, masks):
+    """``A`` and ``Bs`` of a chunk under a head's decay: k and q
+    stacked, ``2 C`` rows against ``k^T`` in one exact product, times
+    ``_head_decays``."""
+    row, col, _ = masks
+    c = q.shape[1]
+    D = _head_decays(G, row, col)
+    S = _dot(jnp.concatenate([k, q], 1), k, _NT, True)
+    return jnp.where(row > col, S[:, :c] * D, 0.0), S[:, c:] * D * scale
+
+
+def _lane_scores(q, k, g, G, scale, masks):
+    """``A`` and ``Bs`` of a chunk under a lane's decay: the six halving
+    levels, each from its own reference point, and ``Bs``'s diagonal."""
     c = q.shape[1]
     row, col, pairs = masks
     A = Bs = jnp.zeros((c, c), jnp.float32)
@@ -395,6 +476,24 @@ def _chunk_scores_and_inverse(q, k, g, beta, G, scale, masks):
         A, Bs = A + a, Bs + bs
     Bs = Bs + jnp.where(row == col, jnp.sum(q * k, axis=2, keepdims=True)
                         * scale, 0.0)
+    return A, Bs
+
+
+def _chunk_scores_and_inverse(q, k, g, beta, G, scale, masks):
+    """One chunk of a few heads, everything float32: q, k ``(H, C,
+    Dk)``, g and its running sum (a lane's, both ``(H, C, Dk)``, or a
+    head's, ``(H, 1, C)`` and ``(H, C, 1)``: ``_chunk_sums``), beta
+    ``(H, C, 1)`` -> ``A`` and ``Bs``, the scores of ``_chunk_scores``
+    (``Bs`` times ``scale``), and ``T``, the inverse of ``I + beta A``,
+    each ``(H, C, C)``.  The scores are formed as the decay's shape
+    admits (``_head_scores`` / ``_lane_scores``); the inverse is one
+    statement for both.  The forward kernel's alone: the backward kernel
+    reads the three back."""
+    row, col, pairs = masks
+    if _heads_decay(G):
+        A, Bs = _head_scores(q, k, G, scale, masks)
+    else:
+        A, Bs = _lane_scores(q, k, g, G, scale, masks)
 
     M = beta * A
     T = jnp.where(row == col, 1.0, 0.0) - jnp.where(pairs[0], M, 0.0)
@@ -409,7 +508,8 @@ def _chunk_entry(q, k, v, beta, St, scale, G, T):
     sum, its inverse ``T`` and the entry states transposed ``(H, Dv,
     Dk)``: the decays from the chunk's start and to its end, k and q
     under them, the residual ``r`` of v against the entry state and the
-    corrections ``u``, by name."""
+    corrections ``u``, by name.  The decays take the running sum's
+    shape: under a head's ``(H, C, 1)`` they scale rows."""
     c = q.shape[1]
     decayed = jnp.exp(G)
     Gc = G[:, c - 1:c]
@@ -418,8 +518,30 @@ def _chunk_entry(q, k, v, beta, St, scale, G, T):
     r = v - _dot(kd, St, _NT)
     return dict(decayed=decayed, to_end=to_end, kd=kd,
                 qd=q * decayed * scale, kc=k * to_end,
-                ec=jnp.exp(Gc),                         # (H, 1, Dk)
+                ec=jnp.exp(Gc),                    # (H, 1, Dk or 1)
                 r=r, u=_dot(T, beta * r, _NN, True))
+
+
+def _head_cotangents(q, k, G, A, Bs, dA, dBs, scale, row, col):
+    """``_head_scores`` transposed: what the cotangents of ``A`` (zero
+    on and above the diagonal) and of ``Bs`` (zero above it) give q, k
+    and the running sum ``G``.  The two under the decay, stacked, meet k
+    in one product (``A``'s rows for k, ``Bs``'s for q) and ``[k; q]``
+    in one transposed product (for k); every product exact, as the
+    scores.  ``G_i`` moves row ``i`` of ``P = dA A + dBs Bs`` (the KEPT
+    scores) up and column ``i`` down, so its cotangent is ``rowsum(P) -
+    colsum(P)`` off the diagonal, which adds up to nothing over the
+    chunk whatever the products lost.  -> dq, dk ``(H, C, Dk)``, dG
+    ``(H, C, 1)``."""
+    c = q.shape[1]
+    D = _head_decays(G, row, col)
+    under = jnp.concatenate([dA * D, dBs * D * scale], 1)    # (H, 2C, C)
+    rows = _dot(under, k, _NN, True)                         # (H, 2C, Dk)
+    dk = rows[:, :c] + _dot(under, jnp.concatenate([k, q], 1), _TN, True)
+    P = jnp.where(row > col, dA * A + dBs * Bs, 0.0)
+    dG = jnp.sum(P, axis=2, keepdims=True) \
+        - _as_col(jnp.sum(P, axis=1, keepdims=True), row, col)
+    return rows[:, c:], dk, dG
 
 
 def _heads(ref, d):
@@ -434,6 +556,13 @@ def _put_heads(ref, x):
         ref[:, i * d:(i + 1) * d] = x[i].astype(ref.dtype)
 
 
+def _decay_block(ref, d):
+    """A grid step's log-decay, float32: a head's block, a row a head
+    ``(heads, 1, C)``, as it lies; a lane's ``(C, heads * D)`` by
+    heads."""
+    return ref[...] if len(ref.shape) == 3 else _heads(ref, d)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, kept_ref,
                 state, *, scale):
     @pl.when(pl.program_id(2) == 0)
@@ -443,8 +572,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, kept_ref,
     d = state.shape[-1]
     St = state[...]
     s_ref[...] = St
-    q, k, g, beta = _heads(q_ref, d), _heads(k_ref, d), _heads(g_ref, d), \
-        b_ref[...]
+    q, k, g, beta = _heads(q_ref, d), _heads(k_ref, d), \
+        _decay_block(g_ref, d), b_ref[...]
     G, masks = _chunk_sums(g)
     A, Bs, T = _chunk_scores_and_inverse(q, k, g, beta, G, scale, masks)
     # a head's three side by side: (C, heads * 3 * C), whole rows of lanes
@@ -467,7 +596,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, kept_ref, do_ref,
         dstate[...] = jnp.zeros_like(dstate)
 
     d = dstate.shape[-1]
-    q, k, g = _heads(q_ref, d), _heads(k_ref, d), _heads(g_ref, d)
+    q, k, g = _heads(q_ref, d), _heads(k_ref, d), _decay_block(g_ref, d)
     beta, St, dS1t, do = b_ref[...], s_ref[...], dstate[...], _heads(do_ref, d)
     n = q.shape[1]
     kept = _heads(kept_ref, n).reshape(-1, KDA_KEPT, n, n)
@@ -492,6 +621,19 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, kept_ref, do_ref,
     dkd = -_dot(dr, St, _NN)
     dstate[...] = _dot(do, qd, _TN) + dS1t * c["ec"] - _dot(dr, kd, _TN)
 
+    if _heads_decay(G):
+        dq, dk, dG = _head_cotangents(q, k, G, A, Bs, dA, dBs, scale, row,
+                                      col)
+        dG = dG + jnp.sum(dkd * kd + dqd * qd - dkc * kc
+                          + jnp.where(row == n - 1, dGc, 0.0), axis=2,
+                          keepdims=True)
+        _put_heads(dq_ref, dq + dqd * c["decayed"] * scale)
+        _put_heads(dk_ref, dk + dkd * c["decayed"] + dkc * c["to_end"])
+        # g's cotangent is the running sum of G's from the chunk's end:
+        # a column's, summed over the sublanes into g's row
+        dg_ref[...] = jnp.sum(jnp.where(row >= col, dG, 0.0), axis=1,
+                              keepdims=True)
+        return
     diag = jnp.sum(jnp.where(row == col, dBs, 0.0), axis=2,
                    keepdims=True) * scale
     dq = dqd * c["decayed"] * scale + diag * k
@@ -520,23 +662,29 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, kept_ref, do_ref,
     _put_heads(dg_ref, _sum_rows(row <= col, dG))
 
 
-def _kernel_specs(b, t, h, d, flip):
+def _kernel_specs(b, t, h, d, flip, heads_decay):
     """The grid over (batch, heads, chunk) and the blocks of one step:
     ``(C, heads * D)`` of a ``(B, T, H * D)`` array, ``(heads, C, 1)`` of
     beta's ``(B, H, T, 1)``, ``(heads, D, D)`` states of ``(B, H, N, D,
     D)``, ``(C, heads * 3 * C)`` of the kept matrices' ``(B, T, H * 3 *
-    C)``.  ``flip`` walks the chunks from the last."""
+    C)``; the decay's is a lane's, q's block, or (``heads_decay``)
+    ``(heads, 1, C)`` of a head's ``(B, H, N, 1, C)``.  ``flip`` walks
+    the chunks from the last."""
     from jax.experimental.pallas import tpu as pltpu
     chunk, n = KDA_CHUNK, t // KDA_CHUNK
     heads = next(x for x in range(min(KDA_KERNEL_HEADS, h), 0, -1)
                  if h % x == 0)
     at = (lambda i: n - 1 - i) if flip else (lambda i: i)
+    seq = pl.BlockSpec((None, chunk, heads * d),
+                       lambda i, j, m: (i, at(m), j))
     return dict(
         grid=(b, h // heads, n),
-        seq=pl.BlockSpec((None, chunk, heads * d),
-                         lambda i, j, m: (i, at(m), j)),
+        seq=seq,
         col=pl.BlockSpec((None, heads, chunk, 1),
                          lambda i, j, m: (i, j, at(m), 0)),
+        decay=pl.BlockSpec((None, heads, None, 1, chunk),
+                           lambda i, j, m: (i, j, at(m), 0, 0))
+        if heads_decay else seq,
         state=pl.BlockSpec((None, heads, None, d, d),
                            lambda i, j, m: (i, j, at(m), 0, 0)),
         kept=pl.BlockSpec((None, chunk, heads * KDA_KEPT * chunk),
@@ -559,25 +707,38 @@ def _kept_shapes(b, t, h, d):
             jax.ShapeDtypeStruct((b, t, h * KDA_KEPT * KDA_CHUNK), f32)]
 
 
+def _trace_fields(head: bool, vpu_levels: int):
+    """What ``kda:kernel_trace`` says of the form a kernel was traced
+    in: whose the decay is, and the halving levels' rows and VPU levels,
+    none under a head's decay."""
+    if head:
+        return dict(decay="head", level_rows=0, vpu_levels=0)
+    return dict(decay="lane", level_rows=KDA_CHUNK, vpu_levels=vpu_levels)
+
+
 # lint: allow(raw-jit) — never dispatched on its own: a jit inside the step
 # program, there so that every call site shares one traced jaxpr and one
 # lowered function; the step that holds it goes through the cache
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def _kda_fwd(q, k, v, g, beta, *, scale, interpret):
-    """``kda_chunk_fwd`` over ``(B, T, H * D)`` q, k, g (float32) and v,
-    ``(B, H, T, 1)`` beta -> the output in v's layout and dtype and what
-    ``_kda_bwd`` wants back: every chunk's entry state ``(B, H, N, D,
-    D)``, transposed, and its scores and inverse ``A``, ``Bs``, ``T``, a
-    head's three side by side ``(B, T, H * 3 * C)``, float32.  A
-    forward-only caller runs the same kernel and drops them.
-    Module-level and free of per-call objects: traced once a process."""
+    """``kda_chunk_fwd`` over ``(B, T, H * D)`` q, k (float32) and v,
+    ``(B, H, T, 1)`` beta and the log-decay g, a lane's ``(B, T, H *
+    D)`` or a head's, a chunk a row ``(B, H, N, 1, C)`` (its rank says
+    which, and with it how the kernel forms the scores) -> the output in
+    v's layout and dtype and what ``_kda_bwd`` wants back: every chunk's
+    entry state ``(B, H, N, D, D)``, transposed, and its scores and
+    inverse ``A``, ``Bs``, ``T``, a head's three side by side ``(B, T, H
+    * 3 * C)``, float32.  A forward-only caller runs the same kernel and
+    drops them.  Module-level and free of per-call objects: traced once
+    a process and decay's kind."""
     b, t, hd = q.shape
     h = beta.shape[1]
     d = hd // h
+    head = g.ndim == 5
     trace.counter("kda:kernel_trace", cat="ops",
                   track="%s%s" % (v.dtype.name, [b, t, h, d]), fwd=1, bwd=0,
-                  level_rows=KDA_CHUNK, vpu_levels=KDA_VPU_LEVELS)
-    sp = _kernel_specs(b, t, h, d, flip=False)
+                  **_trace_fields(head, KDA_VPU_LEVELS))
+    sp = _kernel_specs(b, t, h, d, False, head)
     # lint: allow(raw-pallas-call) — one lowering of this op, a pair with
     # its own vjp: ops/pallas_kernels holds forward kernels behind the
     # kernel search's bitwise gate, which a pair chosen by platform and
@@ -586,7 +747,7 @@ def _kda_fwd(q, k, v, g, beta, *, scale, interpret):
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale),
         grid=sp["grid"],
-        in_specs=[sp["seq"]] * 4 + [sp["col"]],
+        in_specs=[sp["seq"]] * 3 + [sp["decay"], sp["col"]],
         out_specs=[sp["seq"], sp["state"], sp["kept"]],
         out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype)]
         + _kept_shapes(b, t, h, d),
@@ -605,19 +766,21 @@ def _kda_bwd(q, k, v, g, beta, states, kept, do, *, scale, interpret):
     b, t, hd = q.shape
     h = beta.shape[1]
     d = hd // h
+    head = g.ndim == 5
     trace.counter("kda:kernel_trace", cat="ops",
                   track="%s%s" % (v.dtype.name, [b, t, h, d]), fwd=0, bwd=1,
-                  kept_products=KDA_KEPT, level_rows=KDA_CHUNK, vpu_levels=0)
-    sp = _kernel_specs(b, t, h, d, flip=True)
+                  kept_products=KDA_KEPT, **_trace_fields(head, 0))
+    sp = _kernel_specs(b, t, h, d, True, head)
     f32 = jax.ShapeDtypeStruct(q.shape, jnp.float32)
     # lint: allow(raw-pallas-call) — as _kda_fwd
     return pl.pallas_call(
         functools.partial(_bwd_kernel, scale=scale),
         grid=sp["grid"],
-        in_specs=[sp["seq"]] * 4 + [sp["col"], sp["state"], sp["kept"],
-                                    sp["seq"]],
-        out_specs=[sp["seq"]] * 4 + [sp["col"]],
-        out_shape=[f32, f32, jax.ShapeDtypeStruct(v.shape, v.dtype), f32,
+        in_specs=[sp["seq"]] * 3 + [sp["decay"], sp["col"], sp["state"],
+                                    sp["kept"], sp["seq"]],
+        out_specs=[sp["seq"]] * 3 + [sp["decay"], sp["col"]],
+        out_shape=[f32, f32, jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct(g.shape, jnp.float32),
                    jax.ShapeDtypeStruct(beta.shape, jnp.float32)],
         scratch_shapes=sp["scratch"], compiler_params=sp["params"],
         interpret=interpret, name="kda_chunk_bwd",
@@ -625,12 +788,20 @@ def _kda_bwd(q, k, v, g, beta, states, kept, do, *, scale, interpret):
 
 
 def _kernel_layout(q, k, v, g, beta):
-    """``(B, T, H, D)`` -> ``(B, T, H * D)`` (free), q, k, g in float32;
-    beta ``(B, T, H)`` -> ``(B, H, T, 1)``."""
+    """``(B, T, H, D)`` -> ``(B, T, H * D)`` (free), q, k and a lane's g
+    in float32; beta ``(B, T, H)`` -> ``(B, H, T, 1)``; a head's g ``(B,
+    T, H)`` -> ``(B, H, N, 1, C)``, a chunk a row of lanes: a float32
+    array whose last dimension is 1 lies on the chip with that dimension
+    padded to a row of 128 lanes, ``(B, H, T, 1)`` in the 67 MB a layer
+    of the decay handed over the key lanes."""
     b, t = q.shape[:2]
     f32 = jnp.float32
+    if g.ndim == 3:
+        g = g.astype(f32).transpose(0, 2, 1).reshape(
+            b, -1, t // KDA_CHUNK, 1, KDA_CHUNK)
     return (q.astype(f32).reshape(b, t, -1), k.astype(f32).reshape(b, t, -1),
-            v.reshape(b, t, -1), g.astype(f32).reshape(b, t, -1),
+            v.reshape(b, t, -1),
+            g if g.ndim == 5 else g.astype(f32).reshape(b, t, -1),
             beta.astype(f32).transpose(0, 2, 1)[..., None])
 
 
@@ -651,24 +822,25 @@ def _kernel_rule_vjp(q, k, v, g, beta, kept, do, scale: float,
     dq, dk, dv, dg, db = _kda_bwd(
         *_kernel_layout(q, k, v, g, beta), *kept, do.reshape(b, t, -1),
         scale=scale, interpret=interpret)
+    if g.ndim == 3:
+        dg = dg.reshape(b, -1, t).transpose(0, 2, 1)
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
             dg.reshape(g.shape), db[..., 0].transpose(0, 2, 1))
 
 
 def _normalized_and_gated(q, k, decay, beta, a_log, dt_bias):
     """What the op does before the rule, all float32: q and k
-    L2-normalized a head, the log-decay and the write gate, at the
-    VALUE's heads and over the key lanes, as the rule takes them.  A
-    head's decay (``kda_gates``: its rank) is handed over the key lanes
-    here, and ``Hk`` key heads under ``Hv`` value heads are repeated
-    here (value head ``j`` reads key head ``j // (Hv / Hk)``): inside
-    the ``custom_vjp``'s body, so that neither is kept across the step."""
+    L2-normalized a head, at the VALUE's heads, the log-decay in the
+    rank of its projection (``kda_gates``: a lane's ``(B, T, H, Dk)``, a
+    head's ``(B, T, Hv)``) and the write gate.  Under a head's decay
+    ``Hk`` key heads under ``Hv`` value heads are repeated here (value
+    head ``j`` reads key head ``j // (Hv / Hk)``): inside the
+    ``custom_vjp``'s body, so that they are not kept across the step."""
     q, k = _l2norm(q), _l2norm(k)
     g, beta = kda_gates(decay, beta, a_log, dt_bias)
     if g.ndim == 3:
         group = g.shape[2] // q.shape[2]
         q, k = jnp.repeat(q, group, axis=2), jnp.repeat(k, group, axis=2)
-        g = jnp.broadcast_to(g[..., None], q.shape)
     return q, k, g, beta
 
 
@@ -677,8 +849,11 @@ def _scale(q) -> float:
 
 
 def _plain_attention(q, k, v, decay, beta, a_log, dt_bias):
-    """The op's body on every platform: the plain chunks."""
+    """The op's body on every platform: the plain chunks, which take the
+    decay a key lane: a head's is handed over its lanes here."""
     q, k, g, beta = _normalized_and_gated(q, k, decay, beta, a_log, dt_bias)
+    if g.ndim == 3:
+        g = jnp.broadcast_to(g[..., None], q.shape)
     return gated_delta_rule(q, k, v, g, beta, _scale(q))
 
 
@@ -776,7 +951,9 @@ def gated_delta_net(q, k, v, decay, beta, a_log, dt_bias,
     body (``_delta_attention``) with a head's one decay, ``decay`` ``(B,
     T, Hv)``, and ``Hk`` key heads under ``Hv`` value heads; what tells
     the two front ends apart is the decay's rank, read in one place
-    (``kda_gates``, ``_normalized_and_gated``).  The counter is
+    (``kda_gates``, ``_normalized_and_gated``), and the same rank says
+    which factorisation of the chunk's scores the kernels run
+    (``_kernel_layout``).  The counter is
     ``gdn:lowering`` with ``key_heads`` and ``value_heads``; the track is
     ``<dtype>[B, T, Hv, D]/k<Hk>``."""
     hk, hv = q.shape[2], v.shape[2]
@@ -872,9 +1049,15 @@ class GatedDeltaNetOp(OpDef):
     ``sigmoid(beta)`` from ``(B, T, Hv)``, and the output is the gated
     delta rule's ``S_t^T q_t * Dk**-0.5`` (``gated_delta_rule`` with
     ``g`` equal over a head's key lanes), ``(B, T, Hv, Dv)``.  The same
-    body and the same two lowerings as ``KimiDeltaAttention``: where the
-    kernels run they are ``kda_chunk_fwd`` / ``kda_chunk_bwd``, so the
-    op's body runs under ``kda.l<layer>``, the scope of the rule."""
+    body and the same two lowerings as ``KimiDeltaAttention``: the plain
+    chunks are fed the decay on every key lane, and where the kernels
+    run they are ``kda_chunk_fwd`` / ``kda_chunk_bwd`` in the form a
+    head's decay admits (it enters a chunk a row, one number a head and
+    token, and the chunk's scores are one product under a ``(C, C)``
+    decay matrix, where a lane's decay takes six halving levels; the
+    inverse, the corrections, the state and what a step keeps are the
+    same statements), so the op's body runs under ``kda.l<layer>``, the
+    scope of the rule."""
     params = [Param("layer", int, default=-1)]
 
     def list_arguments(self, p):

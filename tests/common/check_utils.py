@@ -92,3 +92,16 @@ def check_symbolic_forward(sym, location, expected, check_eps=1e-4):
     for out, exp in zip(executor.outputs, expected):
         assert reldiff(out.asnumpy(), exp) < check_eps, \
             "forward mismatch: %s vs %s" % (out.asnumpy(), exp)
+
+
+def jaxpr_eqns(jaxpr, name):
+    """The equations called ``name`` of a jaxpr and of every jaxpr inside
+    it (a ``pallas_call``'s kernel body, a ``jit``'s, a ``custom_vjp``'s)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            yield eqn
+        for value in eqn.params.values():
+            for x in value if isinstance(value, (list, tuple)) else [value]:
+                x = getattr(x, "jaxpr", x)
+                if hasattr(x, "eqns"):
+                    yield from jaxpr_eqns(x, name)

@@ -28,7 +28,7 @@ from mxnet_tpu.moe import MoEFeedForward                  # noqa: E402
 from mxnet_tpu.moe.router import route_sorted             # noqa: E402
 from mxnet_tpu.ops import linear_attention as kda_ops     # noqa: E402
 from mxnet_tpu.ops import transformer as tf_ops           # noqa: E402
-from check_utils import check_symbolic_forward            # noqa: E402
+from check_utils import check_symbolic_forward, jaxpr_eqns  # noqa: E402
 
 import manifest                                           # noqa: E402
 
@@ -195,14 +195,7 @@ def test_kernel_lowering_carries_the_gradient_through_the_gates():
 def _kernel_dot_eqns(jaxpr):
     """The ``dot_general``s of a jaxpr and of every jaxpr inside it (a
     ``pallas_call``'s kernel body)."""
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "dot_general":
-            yield eqn
-        for value in eqn.params.values():
-            for x in value if isinstance(value, (list, tuple)) else [value]:
-                x = getattr(x, "jaxpr", x)
-                if hasattr(x, "eqns"):
-                    yield from _kernel_dot_eqns(x)
+    return jaxpr_eqns(jaxpr, "dot_general")
 
 
 def _kernel_dots(jaxpr):
@@ -239,15 +232,17 @@ ROW_CYCLES_BEFORE_PR48 = {"fwd": 4416, "bwd": 4608}
 ROW_CYCLES = {"fwd": 2624, "bwd": 3072}
 
 
-def _kernel_jaxpr(which, b, t, h, d, jitted=True):
+def _kernel_jaxpr(which, b, t, h, d, jitted=True, heads_decay=False):
     """-> the jaxpr of ``_kda_fwd`` / ``_kda_bwd`` traced for ``(B, T, H,
-    D)`` inputs; ``jitted`` False traces the function's body anew
+    D)`` inputs, the decay a lane's or (``heads_decay``) a head's, a
+    chunk a row; ``jitted`` False traces the function's body anew
     whatever the process has traced."""
     seq = jax.ShapeDtypeStruct((b, t, h * d), jnp.float32)
     beta = jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32)
+    rows = jax.ShapeDtypeStruct((b, h, t // 64, 1, 64), jnp.float32)
     fn = {"fwd": kda_ops._kda_fwd, "bwd": kda_ops._kda_bwd}[which]
     fn = fn if jitted else fn.__wrapped__
-    args = (seq, seq, seq, seq, beta) + (
+    args = (seq, seq, seq, rows if heads_decay else seq, beta) + (
         () if which == "fwd" else
         (*kda_ops._kept_shapes(b, t, h, d), seq))
     return jax.make_jaxpr(
@@ -346,6 +341,43 @@ def test_a_level_streams_only_the_rows_that_carry_pairs(which,
         # what is left above C rows a pass reads the states: Dk rows
         assert max(map(_streamed_rows, _kernel_dot_eqns(got))) \
             == kda_ops.KDA_KERNEL_DIM
+
+
+# rows x passes a chunk-head under a HEAD's decay (ISSUE 51): no level;
+# k and q stacked against k^T exactly (3 x 128) where the forward's levels
+# streamed 704 and the lanes' running sum 192, and in the backward kernel
+# the two cotangents stacked against k (2 x 128) and, transposed, against
+# [k; q] (3 x 64) where the levels streamed 1792 and two running sums 384
+HEAD_ROW_CYCLES = {"fwd": 2112, "bwd": 1472}
+
+
+@pytest.mark.parametrize("which", ["fwd", "bwd"])
+def test_a_heads_decay_forms_the_scores_without_a_level(which):
+    """The kernels traced with a head's decay, ``(B, H, N, 1, C)``, a
+    chunk a row (ISSUE 51): the row-cycle count of each body, pinned; no roll
+    and no exponential over the key lanes (the levels' own), every
+    exponential over ``(H, C, C)`` or a column; the same function traced
+    with a lane's decay ``(B, T, H * D)`` streams what it did."""
+    shape = (1, 64, 1, 128)
+    c = kda_ops.KDA_CHUNK
+    head = _kernel_jaxpr(which, *shape, jitted=False, heads_decay=True)
+    lane = _kernel_jaxpr(which, *shape, jitted=False)
+    assert _kernel_row_cycles(head) == HEAD_ROW_CYCLES[which]
+    assert _kernel_row_cycles(lane) == ROW_CYCLES[which]
+    # the widest product is the two stacked scores' (or cotangents'), 2 C
+    # rows a pass; nothing reads the states with more rows than Dk
+    assert max(map(_streamed_rows, _kernel_dot_eqns(head))) == 2 * c \
+        == kda_ops.KDA_KERNEL_DIM
+    exps = [e.outvars[0].aval.shape for e in jaxpr_eqns(head, "exp")]
+    assert exps and all(s[-1] in (1, c) for s in exps), exps
+    assert any(s[-1] == kda_ops.KDA_KERNEL_DIM
+               for s in (e.outvars[0].aval.shape for e in jaxpr_eqns(lane, "exp")))
+    assert not list(jaxpr_eqns(head, "roll")) and list(jaxpr_eqns(lane, "roll"))
+    # the decay enters and its cotangent leaves a chunk a row, 64 lanes
+    call, = jaxpr_eqns(head, "pallas_call")
+    assert call.invars[3].aval.shape == (1, 1, 1, 1, 64)
+    if which == "bwd":
+        assert call.outvars[3].aval.shape == (1, 1, 1, 1, 64)
 
 
 @pytest.mark.parametrize("precision", ["highest", "default"])
@@ -487,9 +519,12 @@ def test_delta_rule_lowering_is_chosen_from_shape_and_dtype(t, dk, dv, dtype,
 
 # one trace of each kernel a process; the backward one says how many chunk
 # matrices it takes from the forward one (A, Bs, T) and does not form again
-KERNEL_TRACES = [{"fwd": 1, "bwd": 0, "level_rows": 64, "vpu_levels": 1},
-                 {"fwd": 0, "bwd": 1, "kept_products": 3, "level_rows": 64,
-                  "vpu_levels": 0}]
+# and (ISSUE 51) whose decay it was traced for: a key lane's here, so the
+# six levels, 64 packed rows each
+KERNEL_TRACES = [{"fwd": 1, "bwd": 0, "decay": "lane", "level_rows": 64,
+                  "vpu_levels": 1},
+                 {"fwd": 0, "bwd": 1, "kept_products": 3, "decay": "lane",
+                  "level_rows": 64, "vpu_levels": 0}]
 
 
 def test_the_tpu_program_traces_each_kernel_once():

@@ -6,8 +6,11 @@ tolerance's reason beside it and the same step in bfloat16 refused by it;
 against a token-by-token recurrence, with fewer key heads than value
 heads, a decay down to ``exp(-20)`` a token and, for the plain chunks, a
 length that is no whole chunks; the op with its decay spread equals
-``KimiDeltaAttention`` fed that decay on every lane; what the backward
-pass keeps; the sixteen ranks' shares, the gated shared expert once, add
+``KimiDeltaAttention`` fed that decay on every lane; the kernels' head
+form (ISSUE 51: the decay handed over a chunk a row) against
+their lane form fed the same decay on every lane, and what the two
+``pallas_call``s take and return for the decay; what the backward pass
+keeps; the sixteen ranks' shares, the gated shared expert once, add
 up to the uncut layer; the partial rotation leaves lanes 64.. bit for
 bit; the gate half of ``q_proj`` gets gradient only through the gate;
 ``shared_gate`` unset is not in the graph; scopes and counters."""
@@ -20,6 +23,7 @@ import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "common"))
 sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 
 import jax                                                # noqa: E402
@@ -31,6 +35,7 @@ from mxnet_tpu.models import decoder, qwen3_next_lm       # noqa: E402
 from mxnet_tpu.moe import MoEFeedForward                  # noqa: E402
 from mxnet_tpu.ops import linear_attention as kda_ops     # noqa: E402
 
+from check_utils import jaxpr_eqns                        # noqa: E402
 import manifest                                           # noqa: E402
 
 REF = manifest.load_module("reference", "qwen3-next-80b-a3b")
@@ -273,14 +278,19 @@ def test_plain_chunks_match_the_token_recurrence(lo, hi):
             <= tol * np.abs(np.asarray(y)).max()
 
 
-@pytest.mark.parametrize("lo,hi", [(-3.0, -0.01), (-20.0, -1e-4)],
-                         ids=["mixed", "wide"])
+@pytest.mark.parametrize("lo,hi", [(-3.0, -0.01), (-20.0, -1e-4),
+                                   (-0.01, -1e-4), (-20.0, -5.0)],
+                         ids=["mixed", "wide", "decay-near-1",
+                              "decay-near-0"])
 def test_kernels_match_the_token_recurrence(lo, hi):
     """The kernel lowering under the Pallas interpreter: heads of 128,
     two chunks, 1 key head under 2 value heads, the head's decay handed
-    over the key lanes inside the ``custom_vjp``'s body: the output and
-    all seven gradients (q and k summed back over a key head's value
-    heads, the decay summed over the lanes)."""
+    to the kernels as it is, one number a head and token (their head
+    form, ISSUE 51: the chunk's scores as one product under a ``(C, C)``
+    decay matrix): the output and all seven gradients (q and k summed
+    back over a key head's value heads, the decay's summed over the
+    lanes inside the backward kernel), the decay from nearly none to
+    ``exp(-20)`` a token."""
     args = _gdn_inputs(128, lo, hi)
     assert kda_ops._kernel_takes(args[0], args[2])
     with jax.default_matmul_precision("highest"):
@@ -311,6 +321,94 @@ def test_a_heads_decay_is_kimis_rule_with_that_decay_on_every_lane():
             jnp.broadcast_to(decay[..., None], v.shape), beta, a_log,
             jnp.repeat(dt_bias, 16))
     assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("lo,hi", [(-3.0, -0.01), (-20.0, -1e-4),
+                                   (-0.01, -1e-4)],
+                         ids=["mixed", "wide", "decay-near-1"])
+def test_the_head_form_is_the_lane_form_fed_that_decay_on_every_lane(lo, hi):
+    """One rule, two factorisations (ISSUE 51): ``_kernel_rule`` and its
+    vjp given a head's decay ``(B, T, H)`` against the same given that
+    decay on every key lane ``(B, T, H, Dk)`` (the six halving levels:
+    what ran until then), three chunks of two heads under the
+    interpreter: the output and the five cotangents, the lanes' decay
+    cotangent summed, to the tolerances either has against the plain
+    chunks (``test_kernels_match_the_plain_chunks``)."""
+    t, heads, d = 192, 2, 128
+    rng = np.random.RandomState(51)
+    q, k = (jnp.asarray(x / np.linalg.norm(x, axis=-1, keepdims=True),
+                        jnp.float32)
+            for x in (rng.randn(1, t, heads, d) for _ in range(2)))
+    v, w = (jnp.asarray(rng.randn(1, t, heads, d), jnp.float32)
+            for _ in range(2))
+    g = jnp.asarray(rng.uniform(lo, hi, (1, t, heads)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0.05, 0.99, (1, t, heads)), jnp.float32)
+    scale = d ** -0.5
+
+    def run(g):
+        o, kept = kda_ops._kernel_rule(q, k, v, g, beta, scale,
+                                       interpret=True)
+        return o, kept, kda_ops._kernel_rule_vjp(
+            q, k, v, g, beta, kept, w, scale, interpret=True)
+
+    with jax.default_matmul_precision("highest"):
+        o, kept, grads = run(g)
+        o_lane, kept_lane, lane = run(
+            jnp.broadcast_to(g[..., None], q.shape))
+    assert grads[3].shape == g.shape and lane[3].shape == q.shape
+    lane = lane[:3] + (lane[3].sum(-1),) + lane[4:]
+    assert np.abs(np.asarray(o - o_lane)).max() \
+        <= 1e-4 * np.abs(np.asarray(o_lane)).max()
+    # the states and the chunks' A, Bs, T: what the step keeps is what
+    # it kept
+    for x, y in zip(kept, kept_lane):
+        assert x.shape == y.shape
+        assert np.abs(np.asarray(x - y)).max() \
+            <= 1e-4 * np.abs(np.asarray(y)).max()
+    for x, y in zip(grads, lane):
+        assert np.abs(np.asarray(y)).max() > 0
+        assert np.abs(np.asarray(x - y)).max() \
+            <= 5e-4 * np.abs(np.asarray(y)).max()
+
+
+def test_the_kernels_are_handed_the_decay_a_head():
+    """What the kernel lowering moves for the decay (ISSUE 51): the two
+    ``pallas_call``s of ``gated_delta_net``'s forward and backward pass
+    take it, and return its cotangent, as ``(B, Hv, N, 1, C)``, a chunk
+    a row of 64 lanes (``(B, Hv, T, 1)``, beta's layout, lies on the
+    chip with its last dimension padded to 128 lanes: the bytes of the
+    decay on every key lane); of float32 arrays the size of ``(B, T, Hv,
+    Dk)`` the forward kernel takes two (q and k) and the backward one
+    takes two and returns two (theirs): none for the decay, where each
+    held one more each way."""
+    args = _gdn_inputs(128, -3.0, -0.01)
+    args = args[:2] + (args[2].astype(jnp.bfloat16),) + args[3:]
+    b, t, hv, d = args[2].shape
+
+    def loss(*a):
+        return kda_ops.gated_delta_net(*a, interpret=True).astype(
+            jnp.float32).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=tuple(range(7))))(*args)
+    calls = {c.params["name"]: c
+             for c in jaxpr_eqns(jaxpr.jaxpr, "pallas_call")}
+    assert set(calls) == {"kda_chunk_fwd", "kda_chunk_bwd"}
+
+    def lane_wide(avals):
+        return sum(1 for x in avals if x.dtype == jnp.float32
+                   and x.size == b * t * hv * d)
+
+    for name, taken, given in (("kda_chunk_fwd", 2, 0),
+                               ("kda_chunk_bwd", 2, 2)):
+        call = calls[name]
+        ins = [x.aval for x in call.invars]
+        outs = [x.aval for x in call.outvars]
+        assert (lane_wide(ins), lane_wide(outs)) == (taken, given), name
+        # the decay, one number a head and token, and beta
+        assert [x.shape for x in ins[3:5]] == [(b, hv, t // 64, 1, 64),
+                                               (b, hv, t, 1)], name
+    assert [x.aval.shape for x in calls["kda_chunk_bwd"].outvars[3:]] \
+        == [(b, hv, t // 64, 1, 64), (b, hv, t, 1)]
 
 
 def test_the_backward_pass_keeps_the_ops_own_inputs():
@@ -395,8 +493,11 @@ def test_three_gdn_layers_trace_each_kernel_once():
         mx.trace.set_enabled(was)
     assert "kda_chunk_fwd" in text and "kda_chunk_bwd" in text
     assert [e["args"]["kernel"] for e in chosen] == [1, 1, 1]
+    # each kernel once, in the form a head's decay admits: no level
     assert sorted((e["args"]["fwd"], e["args"]["bwd"]) for e in kernel) \
         == [(0, 1), (1, 0)]
+    assert all((e["args"]["decay"], e["args"]["level_rows"],
+                e["args"]["vpu_levels"]) == ("head", 0, 0) for e in kernel)
     assert {e["id"] for e in kernel} == {"float32%s" % [b, t, hv, d]}
 
 

@@ -21,8 +21,13 @@ anything is asserted.
 
 The second holds ``gated_delta_net``'s two lowerings against each other
 at the cell's shape, ``(1, 4096, 32, 128)`` values under 16 key heads
-with a head's decay: forward and all seven gradients, with both
-lowerings' times a layer.  The third holds ``causal_attention``'s TPU
+with a head's decay in four bands: forward and all seven gradients, with
+both lowerings' times a layer beside the 7.98 ms before ISSUE 51, the
+two kernels alone in the head form and in the lane form fed the same
+decay on every lane, and the head form without its score products (the
+ablation ISSUE 51's prediction was rewritten from), in
+``chiprun_out/gdn_kernel_parity.json`` before anything is asserted.  The
+third holds ``causal_attention``'s TPU
 kernel against its plain blocks at ``(1, 4096, 16, 256)`` over 2
 key/value heads.
 """
@@ -236,9 +241,11 @@ def test_published_width_step_matches_reference():
         assert bf16["attn:lowering"] == [[ATTN_TRACK, {
             "kernel": 1, "plain": 0, "mask_form": "library"}]]
         traces += bf16["kda:kernel_trace"]
-    # the process traced each kernel once for all the steps' nine layers
-    assert sorted((a["fwd"], a["bwd"]) for _, a in traces) \
-        == [(0, 1), (1, 0)]
+    # the process traced each kernel once for all the steps' GDN layers,
+    # in the form a head's decay admits: no halving level
+    assert sorted((a["fwd"], a["bwd"], a["decay"], a["level_rows"])
+                  for _, a in traces) \
+        == [(0, 1, "head", 0), (1, 0, "head", 0)]
     # float8 weights are refused by at least one limit
     assert fp8["loss_rel_err"] > limits["loss_rtol"] or any(
         fp8["adam_update_rel_err"][n] > limits["update_rtol"][n]
@@ -248,80 +255,166 @@ def test_published_width_step_matches_reference():
     assert max(f32["update_rel_err"].values()) <= 0.1, f32
 
 
-def test_gdn_kernels_match_the_plain_chunks_at_the_cells_shape():
+# the log-decay a token of each band, as tests/tpu/test_kimi_linear_tpu.py
+# has them for a lane's decay; a head's projection reaches exp(-20)
+GDN_BANDS = {"near-1": (-0.01, -1e-4), "mixed": (-3.0, -0.01),
+             "near-0": (-20.0, -5.0), "wide": (-20.0, -1e-4)}
+# gated_delta_net's kernel lowering, forward + backward a layer at this
+# shape, with the head's decay spread over the lanes for the six halving
+# levels (my chip run, PR 50: the commit before ISSUE 51)
+GDN_MS_A_LAYER_BEFORE_PR51 = 7.98
+
+
+def _ms(fn, *a, reps=5):
+    import jax
+    jax.block_until_ready(fn(*a))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        out = fn(*a)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / reps * 1e3
+
+
+def test_gdn_kernels_match_the_plain_chunks_at_the_cells_shape(monkeypatch):
     """``gated_delta_net`` at ``(1, 4096, 32, 128)`` bfloat16 values under
-    16 key heads with a head's decay: the kernel lowering (compiled by
-    Mosaic) against the plain chunks, forward and all seven gradients,
+    16 key heads with a head's decay, in four decay bands: the kernel
+    lowering (compiled by Mosaic, the head form: ``kda:kernel_trace``
+    says so) against the plain chunks, forward and all seven gradients,
     at HIGHEST precision; at the default precision the kernels are no
-    further from that than the plain chunks are."""
+    further from that than the plain chunks are.  Then the times: the op
+    a layer in both lowerings beside the 7.98 ms before ISSUE 51, the
+    two kernels alone in the head form and in the lane form fed the same
+    decay on every lane (what ran until then), and the ablation: the
+    head form without its score products (a stand-in that reads ``A``
+    and ``Bs`` off k and q by the VPU: not the rule), which says what
+    the two products under the ``(C, C)`` decay cost of a call."""
     import jax
     import jax.numpy as jnp
+    import mxnet_tpu as mx
     from mxnet_tpu.ops import linear_attention as kda
     b, t, hk, hv, d = 1, 4096, 16, 32, 128
     names = ("o", "dq", "dk", "dv", "ddecay", "dbeta", "da_log", "ddt_bias")
-    rng = np.random.RandomState(50)
-    q, k = (jnp.asarray(rng.standard_normal((b, t, hk, d)), jnp.bfloat16)
-            for _ in range(2))
-    v = jnp.asarray(rng.standard_normal((b, t, hv, d)), jnp.bfloat16)
-    decay = jnp.asarray(rng.uniform(-4, 4, (b, t, hv)), jnp.bfloat16)
-    beta = jnp.asarray(rng.uniform(-3, 3, (b, t, hv)), jnp.bfloat16)
-    a_log = jnp.asarray(rng.uniform(-1, 1, (hv,)), jnp.float32)
-    dt_bias = jnp.asarray(rng.uniform(-1, 1, (hv,)), jnp.float32)
-    w = jnp.asarray(rng.standard_normal((b, t, hv, d)), jnp.bfloat16)
-    args = (q, k, v, decay, beta, a_log, dt_bias)
-    assert kda._kernel_takes(q, v)
+
+    def inputs(lo, hi):
+        rng = np.random.RandomState(51)
+        q, k = (jnp.asarray(rng.standard_normal((b, t, hk, d)), jnp.bfloat16)
+                for _ in range(2))
+        v = jnp.asarray(rng.standard_normal((b, t, hv, d)), jnp.bfloat16)
+        g = rng.uniform(lo, hi, (b, t, hv))
+        a_log = rng.uniform(-1, 1, (hv,))
+        dt_bias = rng.uniform(-1, 1, (hv,))
+        # softplus(decay + dt_bias) = -g / exp(a_log)
+        decay = np.log(np.expm1(-g / np.exp(a_log))) - dt_bias
+        beta = rng.uniform(-3, 3, (b, t, hv))
+        w = jnp.asarray(rng.standard_normal((b, t, hv, d)), jnp.bfloat16)
+        return (q, k, v, jnp.asarray(decay, jnp.float32),
+                jnp.asarray(beta, jnp.bfloat16),
+                jnp.asarray(a_log, jnp.float32),
+                jnp.asarray(dt_bias, jnp.float32)), w
 
     def both_passes(fn):
-        def run(*a):
+        def run(w, *a):
             out, vjp = jax.vjp(fn, *a)
             return (out,) + vjp(w)
         return jax.jit(run)
 
+    mx.trace.set_enabled(True)
+    mark = time.perf_counter_ns()
     kernel = both_passes(kda.gated_delta_net)
     plain = both_passes(kda._plain_attention)
-    text = kernel.lower(*args).compile().as_text()
+    args, w = inputs(*GDN_BANDS["mixed"])
+    assert kda._kernel_takes(args[0], args[2])
+    text = kernel.lower(w, *args).compile().as_text()
     assert "kda_chunk_fwd" in text and "kda_chunk_bwd" in text
-    assert "kda_chunk" not in plain.lower(*args).compile().as_text()
+    assert "kda_chunk" not in plain.lower(w, *args).compile().as_text()
+    traces = [e["args"] for e in mx.trace.counter_events(
+        ["kda:kernel_trace"], since_ns=mark)]
 
     def rels(got, want):
         return {n: _rel(x, y) for n, x, y in zip(names, got, want)}
 
-    with jax.default_matmul_precision("highest"):
-        want = plain(*args)
-        exact = rels(kernel(*args), want)
+    report = {"shape": [b, t, hv, d], "key_heads": hk,
+              "kernel_traces": traces, "bands": {}}
+    for band, (lo, hi) in GDN_BANDS.items():
+        args, w = inputs(lo, hi)
+        with jax.default_matmul_precision("highest"):
+            want = plain(w, *args)
+            exact = rels(kernel(w, *args), want)
+        with jax.default_matmul_precision("default"):
+            got = kernel(w, *args)
+            report["bands"][band] = {
+                "kernel_vs_plain_highest": exact,
+                "kernel_default_vs_plain_highest": rels(got, want),
+                "plain_default_vs_plain_highest": rels(plain(w, *args),
+                                                       want),
+                "finite": bool(all(np.isfinite(np.asarray(x, np.float32))
+                                   .all() for x in got))}
+        del want, got
+
+    # the times, at the configuration's dtypes and precision
+    args, w = inputs(*GDN_BANDS["mixed"])
+    scale = d ** -0.5
+    qn, kn, g, beta = jax.jit(kda._normalized_and_gated)(
+        *args[:2], *args[3:])
+    head = kda._kernel_layout(qn, kn, args[2], g, beta)
+    lane = kda._kernel_layout(
+        qn, kn, args[2], jnp.broadcast_to(g[..., None], qn.shape), beta)
+    do = w.reshape(b, t, -1)
+
+    def kernels_alone(lay):
+        """ms a call of the two kernels, traced anew with the module's
+        statements as they stand (a stand-in's too)."""
+        fwd = jax.jit(lambda *a: kda._kda_fwd.__wrapped__(
+            *a, scale=scale, interpret=False))
+        bwd = jax.jit(lambda *a: kda._kda_bwd.__wrapped__(
+            *a, scale=scale, interpret=False))
+        _, states, kept = fwd(*lay)
+        return {"forward": _ms(fwd, *lay),
+                "backward": _ms(bwd, *lay, states, kept, do)}
+
+    def scores_off_the_vpu(q, k, G, scale, masks):
+        row, col, _ = masks
+        D = kda._head_decays(G, row, col)
+        s = jnp.sum(k + q, axis=2, keepdims=True)
+        return jnp.where(row > col, s * D, 0.0), s * D * scale
+
+    def cotangents_off_the_vpu(q, k, G, A, Bs, dA, dBs, scale, row, col):
+        D = kda._head_decays(G, row, col)
+        P = jnp.where(row > col, dA * A + dBs * Bs, 0.0)
+        s = jnp.sum((dA + dBs) * D, axis=2, keepdims=True)
+        dG = jnp.sum(P, axis=2, keepdims=True) - kda._as_col(
+            jnp.sum(P, axis=1, keepdims=True), row, col)
+        return s * k, s * (k + q), dG
+
     with jax.default_matmul_precision("default"):
-        got = kernel(*args)
-        report = {
-            "shape": [b, t, hv, d], "key_heads": hk,
-            "kernel_vs_plain_highest": exact,
-            "kernel_default_vs_plain_highest": rels(got, want),
-            "plain_default_vs_plain_highest": rels(plain(*args), want),
-            "finite": bool(all(np.isfinite(np.asarray(x, np.float32)).all()
-                               for x in got))}
-
-        def ms(fn, *a):
-            jax.block_until_ready(fn(*a))
-            t0 = time.perf_counter()
-            for _ in range(5):
-                out = fn(*a)
-            jax.block_until_ready(out)
-            return (time.perf_counter() - t0) / 5 * 1e3
-
         report["ms_a_layer"] = {
-            "kernel_forward_backward": ms(kernel, *args),
-            "plain_forward_backward": ms(plain, *args)}
+            "kernel_forward_backward": _ms(kernel, w, *args),
+            "kernel_forward_backward_before_pr51": GDN_MS_A_LAYER_BEFORE_PR51,
+            "plain_forward_backward": _ms(plain, w, *args)}
+        report["ms_a_call"] = {"head_form": kernels_alone(head),
+                               "lane_form_same_decay": kernels_alone(lane)}
+        monkeypatch.setattr(kda, "_head_scores", scores_off_the_vpu)
+        monkeypatch.setattr(kda, "_head_cotangents", cotangents_off_the_vpu)
+        report["ms_a_call"]["head_form_without_score_products"] = \
+            kernels_alone(head)
+        monkeypatch.undo()
     out_dir = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, "gdn_kernel_parity.json"), "w") as f:
         json.dump(report, f, indent=1)
     print("\nGDN_KERNEL_PARITY " + json.dumps(report), flush=True)
-    assert report["finite"]
-    for n in names:
-        # outputs and gradients are rounded to bfloat16 on both sides
-        assert report["kernel_vs_plain_highest"][n] <= 1e-2, (n, report)
-        assert report["kernel_default_vs_plain_highest"][n] <= \
-            1.05 * report["plain_default_vs_plain_highest"][n] + 1e-2, \
-            (n, report)
+    # the head form, where this test is the process's first to trace the
+    # kernels at this shape (a jitted kernel function is traced once)
+    assert all((a["decay"], a["level_rows"], a["vpu_levels"])
+               == ("head", 0, 0) for a in traces), traces
+    for band, r in report["bands"].items():
+        assert r["finite"], band
+        for n in names:
+            # outputs and gradients are rounded to bfloat16 on both sides
+            assert r["kernel_vs_plain_highest"][n] <= 1e-2, (band, n, r)
+            assert r["kernel_default_vs_plain_highest"][n] <= \
+                1.05 * r["plain_default_vs_plain_highest"][n] + 1e-2, \
+                (band, n, r)
 
 
 def test_attention_kernel_matches_plain_blocks_at_256_over_two():
@@ -364,17 +457,9 @@ def test_attention_kernel_matches_plain_blocks_at_256_over_two():
             float(np.abs(g - r).max() / np.abs(r).max()))
         report["l2_err"].append(_rel(g, r))
 
-    def ms(fn, *a):
-        jax.block_until_ready(fn(*a))
-        t0 = time.perf_counter()
-        for _ in range(10):
-            out = fn(*a)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / 10 * 1e3
-
     report["ms_a_layer"] = {
-        "kernel_forward_backward": ms(kernel, q, k, v),
-        "plain_forward_backward": ms(plain, q, k, v)}
+        "kernel_forward_backward": _ms(kernel, q, k, v, reps=10),
+        "plain_forward_backward": _ms(plain, q, k, v, reps=10)}
     out_dir = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, "qwen3_next_attn_parity.json"),
