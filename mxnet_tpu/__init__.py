@@ -64,6 +64,11 @@ from . import moe
 from . import predictor
 from . import serve
 from . import trace
+if trace.enabled():
+    # every program JAX compiles from here on, the traffic's and a
+    # checking module's too, leaves compile:trace / lower / backend on
+    # the ring; jax.monitoring alone is touched, no backend starts
+    compile_cache.record_compile_spans()
 from . import profiler
 from . import faults
 from . import online

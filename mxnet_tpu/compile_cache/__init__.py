@@ -27,8 +27,8 @@ This subsystem kills that cold start on three legs:
 """
 from .cached import (CachedFunction, CompileCache, cached_jit, configure,
                      get_cache, reset)
-from .jaxcache import (CompileCounter, count_backend_compiles,
-                       jax_cache_dir, place_jax_cache)
+from .jaxcache import (CompileCounter, count_backend_compiles, jax_cache_dir,
+                       place_jax_cache, record_compile_spans)
 from .stats import CompileStats, get_stats
 from .warmup import WarmupError, default_warmup_threads, parallel_warm
 
@@ -36,4 +36,4 @@ __all__ = ["CachedFunction", "CompileCache", "CompileCounter", "CompileStats",
            "WarmupError", "cached_jit", "configure",
            "count_backend_compiles", "default_warmup_threads", "get_cache",
            "get_stats", "jax_cache_dir", "parallel_warm", "place_jax_cache",
-           "reset"]
+           "record_compile_spans", "reset"]

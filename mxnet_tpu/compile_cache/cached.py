@@ -773,8 +773,6 @@ class CachedFunction:
             self._traced = True
             dt0 = time.perf_counter() - t0
             stats.note_trace_lower(self.name, dt0)
-            _trace.complete("compile:trace_lower", t0, dt0, cat="compile",
-                            program=self.name)
             entry = None
             key = None
             if cache is not None:
@@ -798,9 +796,6 @@ class CachedFunction:
                     compiled = lowered.compile()
                 dt1 = time.perf_counter() - t1
                 stats.note_compile(self.name, dt1, retrace=retrace)
-                _trace.complete("compile:backend_compile", t1, dt1,
-                                cat="compile", program=self.name,
-                                retrace=retrace)
                 if key is not None:
                     cache.store_entry(key, compiled, lowered, args,
                                       self.name, fkey=fkey)
@@ -823,8 +818,6 @@ class CachedFunction:
         self._traced = True
         dt0 = time.perf_counter() - t0
         stats.note_trace_lower(self.name, dt0)
-        _trace.complete("compile:trace_lower", t0, dt0, cat="compile",
-                        program=self.name)
         t1 = time.perf_counter()
         if will_store:
             with _fresh_compile_ctx():
@@ -833,8 +826,6 @@ class CachedFunction:
             compiled = lowered.compile()
         dt1 = time.perf_counter() - t1
         stats.note_compile(self.name, dt1)
-        _trace.complete("compile:backend_compile", t1, dt1, cat="compile",
-                        program=self.name)
         if will_store:
             fkey = None
             if self._fast_desc is not None:

@@ -4,6 +4,8 @@ Reference: python/mxnet/module/base_module.py (fit at lines 273-393).
 """
 from __future__ import annotations
 
+import functools
+import itertools
 import logging
 import time
 from typing import List, Optional
@@ -57,11 +59,41 @@ def _inspects_outputs(callbacks):
     return any(getattr(cb, "inspects_outputs", False) for cb in cbs)
 
 
+_module_numbers = itertools.count(1)
+
+
+def _recorded(name, *attrs):
+    """Decorator for a module's set-up methods and ``fit``: one span
+    ``name`` (cat ``train``) a call, from its entry to its return or
+    raise, whoever calls.  Its ``module`` is the object's number
+    (``BaseModule._trace_module``); each of ``attrs`` is read from the
+    object when the call ends (``for_training``: what the module is
+    bound for, also where the call found it bound and did nothing)."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapped(self, *args, **kwargs):
+            if not _trace.enabled():
+                return fn(self, *args, **kwargs)
+            t0 = time.perf_counter()
+            try:
+                return fn(self, *args, **kwargs)
+            finally:
+                _trace.complete(name, t0, time.perf_counter() - t0,
+                                cat="train", module=self._trace_module,
+                                **{a: getattr(self, a) for a in attrs})
+        return wrapped
+    return deco
+
+
 class BaseModule:
     """Abstract module (reference base_module.py:41)."""
 
     def __init__(self, logger=logging):
         self.logger = logger
+        # what the module's spans call it: unique in the process, fixed
+        # for the object's life; a module made by another (a bucket's)
+        # takes its maker's
+        self._trace_module = next(_module_numbers)
         self.binded = False
         self.for_training = False
         self.inputs_need_grad = False
@@ -216,6 +248,7 @@ class BaseModule:
             return output_list2
         return output_list
 
+    @_recorded("fit:call")
     def fit(self, train_data, eval_data=None, eval_metric="acc",
             epoch_end_callback=None, batch_end_callback=None, kvstore="local",
             optimizer="sgd", optimizer_params=None,

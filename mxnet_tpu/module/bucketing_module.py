@@ -15,7 +15,7 @@ import logging
 
 from ..base import MXNetError
 from ..initializer import Uniform
-from .base_module import BaseModule
+from .base_module import BaseModule, _recorded
 from .module import Module
 
 __all__ = ["BucketingModule"]
@@ -76,10 +76,21 @@ class BucketingModule(BaseModule):
             return res
         return (res, ("data",), ("softmax_label",))
 
+    def _bucket_module(self, bucket_key):
+        """An unbound Module over ``bucket_key``'s symbol, whose spans
+        carry this module's number."""
+        symbol, data_names, label_names = self._call_sym_gen(bucket_key)
+        module = Module(symbol, data_names, label_names,
+                        logger=self.logger, context=self._context,
+                        work_load_list=self._work_load_list)
+        module._trace_module = self._trace_module
+        return module
+
     def get_params(self):
         assert self.binded and self.params_initialized
         return self._curr_module.get_params()
 
+    @_recorded("module:init_params")
     def init_params(self, initializer=Uniform(0.01), arg_params=None,
                     aux_params=None, allow_missing=False, force_init=False):
         if self.params_initialized and not force_init:
@@ -92,6 +103,7 @@ class BucketingModule(BaseModule):
                                       force_init=force_init)
         self.params_initialized = True
 
+    @_recorded("module:bind", "for_training")
     def bind(self, data_shapes, label_shapes=None, for_training=True,
              inputs_need_grad=False, force_rebind=False, shared_module=None,
              grad_req="write"):
@@ -108,11 +120,7 @@ class BucketingModule(BaseModule):
         self.inputs_need_grad = inputs_need_grad
         self.binded = True
 
-        symbol, data_names, label_names = self._call_sym_gen(
-            self._default_bucket_key)
-        module = Module(symbol, data_names, label_names,
-                        logger=self.logger, context=self._context,
-                        work_load_list=self._work_load_list)
+        module = self._bucket_module(self._default_bucket_key)
         module.bind(data_shapes, label_shapes, for_training,
                     inputs_need_grad, force_rebind=False, shared_module=None,
                     grad_req=grad_req)
@@ -124,10 +132,7 @@ class BucketingModule(BaseModule):
         (reference bucketing_module.py:189-213)."""
         assert self.binded, "call bind before switching bucket"
         if bucket_key not in self._buckets:
-            symbol, data_names, label_names = self._call_sym_gen(bucket_key)
-            module = Module(symbol, data_names, label_names,
-                            logger=self.logger, context=self._context,
-                            work_load_list=self._work_load_list)
+            module = self._bucket_module(bucket_key)
             module.bind(data_shapes, label_shapes, self._curr_module.for_training,
                         self._curr_module.inputs_need_grad,
                         force_rebind=False,
@@ -136,6 +141,7 @@ class BucketingModule(BaseModule):
             self._buckets[bucket_key] = module
         self._curr_module = self._buckets[bucket_key]
 
+    @_recorded("module:prepare")
     def prepare(self, bucket_shapes):
         """Pre-bind and pre-compile bucket executables off the hot loop.
 
@@ -292,6 +298,7 @@ class BucketingModule(BaseModule):
         parallel_warm(tasks, threads=threads)
         return [label for label, _ in tasks]
 
+    @_recorded("module:init_optimizer")
     def init_optimizer(self, kvstore="local", optimizer="sgd",
                        optimizer_params=None, force_init=False):
         assert self.binded and self.params_initialized

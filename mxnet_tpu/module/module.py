@@ -16,7 +16,7 @@ from ..ndarray import NDArray, _lives_on_host, zeros as nd_zeros
 from .. import optimizer as opt_mod
 from ..model import (_create_kvstore, _initialize_kvstore, _param_idx2name,
                      _update_params, _update_params_on_kvstore)
-from .base_module import BaseModule
+from .base_module import BaseModule, _recorded
 from .executor_group import DataParallelExecutorGroup
 from .fused import FusedTrainStep
 
@@ -139,6 +139,7 @@ class Module(BaseModule):
             self._sync_params_from_devices()
         return (self._arg_params, self._aux_params)
 
+    @_recorded("module:init_params")
     def init_params(self, initializer=Uniform(0.01), arg_params=None,
                     aux_params=None, allow_missing=False, force_init=False):
         if self.params_initialized and not force_init:
@@ -269,6 +270,7 @@ class Module(BaseModule):
                 restore_train_state(self, *carried)
 
     # -- bind ----------------------------------------------------------------
+    @_recorded("module:bind", "for_training")
     def bind(self, data_shapes, label_shapes=None, for_training=True,
              inputs_need_grad=False, force_rebind=False, shared_module=None,
              grad_req="write", no_slice_names=None, mesh=None,
@@ -380,6 +382,7 @@ class Module(BaseModule):
             self._exec_group.set_params(self._arg_params, self._aux_params)
 
     # -- optimizer ------------------------------------------------------------
+    @_recorded("module:init_optimizer")
     def init_optimizer(self, kvstore="local", optimizer="sgd",
                        optimizer_params=None, force_init=False):
         """reference module.py:271-335."""
@@ -752,6 +755,7 @@ class Module(BaseModule):
         pend = self._fused.make_batch(data_batch)
         self._fused.warm_step(self._fused_state, pend, self._fused_key)
 
+    @_recorded("module:prepare")
     def prepare(self, data_batch=None, threads=None):
         """AOT-compile this module's hot-loop program(s) before the loop
         runs them — through the persistent compile cache when
