@@ -45,6 +45,8 @@ mx.symbol.Cast <- function(...) {
 
 #' Depthwise causal convolution over time of ``(B, T, C)``: one
 #' @param kernel kernel parameter (default 4)
+#' @param lanes lanes parameter
+#' @param act_type act_type parameter (one of relu, sigmoid, tanh, softrelu, silu)
 #' @param name string, node name (auto when omitted)
 #' @return MXSymbol
 mx.symbol.CausalConv1D <- function(...) {

@@ -62,8 +62,8 @@ def kimi_linear_lm(num_layers, hidden_size, full_attn_layers, dense_layers,
     def kda(h, pre, l):
         def conv(x, name):
             x = sym.Reshape(x, shape=(-1, seq_len, kda_width))
-            x = sym.Activation(sym.CausalConv1D(x, kernel=conv_kernel,
-                                                name=name), act_type="silu")
+            x = sym.CausalConv1D(x, kernel=conv_kernel, act_type="silu",
+                                 name=name)
             return sym.Reshape(x, shape=(-1, seq_len, kda_heads,
                                          kda_head_dim))
 

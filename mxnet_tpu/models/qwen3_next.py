@@ -12,8 +12,11 @@ decay a head and token), ``gdn_key_heads`` key heads under
 ``gdn_value_heads`` value heads of ``gdn_head_dim``: one fused projection
 ``qkvz_proj`` laid out key head by key head ``[q | k | v of its value
 heads | z of its value heads]`` and one ``ba_proj`` ``[b | a]`` likewise;
-q, k and v side by side through one depthwise causal convolution of
-``conv_kernel`` taps and SiLU; the op (q and k L2-normalized, a key head
+q, k and v through one depthwise causal convolution of ``conv_kernel``
+taps and SiLU, applied to the projection where it lies (``CausalConv1D``
+takes a key head's q, k and v lanes and gives the channels in the
+published filter's order, ``[q heads | k heads | v heads]``, and beside
+them the z lanes as they are); the op (q and k L2-normalized, a key head
 repeated for its value heads, ``beta = sigmoid(b)``, ``g = -exp(A_log)
 softplus(a + dt_bias)``); the output through a per-head RMSNorm times
 ``silu(z)``, then ``o_proj``.
@@ -79,23 +82,21 @@ def qwen3_next_lm(num_layers, hidden_size, full_attention_interval,
             qkvz = sym.Reshape(
                 proj(h, pre + "qkvz_proj", hk * (2 + 2 * group) * d),
                 shape=(-1, seq_len, hk, (2 + 2 * group) * d))
-            q, k, v, z = cut(qkvz, 3, d, d, group * d, group * d)
             ba = sym.Reshape(proj(h, pre + "ba_proj", 2 * hv),
                              shape=(-1, seq_len, hk, 2 * group))
             b, a = (sym.Reshape(x, shape=(-1, seq_len, hv))
                     for x in cut(ba, 3, group, group))
-            widths = (hk * d, hk * d, hv * d)
-            mixed = sym.Concat(*(sym.Reshape(x, shape=(-1, seq_len, w))
-                                 for x, w in zip((q, k, v), widths)), dim=2)
-            mixed = sym.Activation(
-                sym.CausalConv1D(mixed, kernel=conv_kernel,
-                                 name=pre + "conv"), act_type="silu")
+            mixed = sym.CausalConv1D(qkvz, kernel=conv_kernel,
+                                     act_type="silu",
+                                     lanes=(d, d, group * d),
+                                     name=pre + "conv")
             q, k, v = (sym.Reshape(x, shape=(-1, seq_len, n, d))
-                       for x, n in zip(cut(mixed, 2, *widths), (hk, hk, hv)))
+                       for x, n in zip(cut(mixed[0], 2, hk * d, hk * d,
+                                           hv * d), (hk, hk, hv)))
         o = sym.GatedDeltaNet(q, k, v, a, b, layer=l, name=pre + "gdn")
         with scoped("", "gdn_proj", l):
             o = norm(sym.Reshape(o, shape=(-1, d)), pre + "o_norm", rms_eps)
-            o = o * sym.Activation(sym.Reshape(z, shape=(-1, d)),
+            o = o * sym.Activation(sym.Reshape(mixed[1], shape=(-1, d)),
                                    act_type="silu")
             return proj(sym.Reshape(o, shape=(-1, hv * d)), pre + "o_proj",
                         hidden_size)

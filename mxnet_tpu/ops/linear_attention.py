@@ -2,7 +2,7 @@
 rule with a per-channel decay, "KDA": Kimi Linear, arXiv:2510.26692),
 ``GatedDeltaNet`` (the same rule with ONE decay a head and token and
 fewer key heads than value heads: Gated DeltaNet, arXiv:2412.06464) and
-the depthwise ``CausalConv1D`` in front of them.
+the depthwise ``CausalConv1D`` in front of them (``ops/causal_conv.py``).
 
 Per head the layer keeps a ``(Dk, Dv)`` state and, token by token,
 
@@ -89,8 +89,7 @@ key lane).  The forms share the grid, the block specs, what is kept
 half), ``_chunk_entry``, the output, the state and the backward kernel
 down to the states' cotangent; under a head's decay the decays there
 are ``(H, C, 1)`` columns that scale rows.  They differ in how the
-chunk's running sum,
-``A``, ``Bs`` and their transposes are formed: a lane's by
+chunk's running sum, ``A``, ``Bs`` and their transposes are formed: a lane's by
 ``_sum_rows``, ``_lane_scores`` and the level loop of ``_bwd_kernel``; a
 head's by a float32 sum of 64 numbers on the VPU (``_chunk_sums``), by
 k and q stacked, ``2 C`` rows against ``k^T`` in ONE exact product under
@@ -99,9 +98,8 @@ give it), and in the backward kernel by the two cotangents under ``D``
 stacked against k and, transposed, against ``[k; q]``
 (``_head_cotangents``); ``G``'s cotangent from the scores is ``rowsum(P)
 - colsum(P)`` of ``P = dA A + dBs Bs`` off the diagonal, which adds up
-to nothing over a chunk by construction, the states' terms are summed
-over the lanes inside the kernel, and ``dg`` leaves a chunk a row, as
-g came.
+to nothing over a chunk by construction, the states' terms are summed over
+the lanes inside the kernel, and ``dg`` leaves a chunk a row, as g came.
 
 The lowering differentiates itself (``jax.custom_vjp`` around the op's
 body, which keeps the op's inputs, the entry states and ``A``, ``Bs``,
@@ -132,6 +130,8 @@ from jax import lax
 
 from .. import trace
 from ..base import MXNetError
+from .causal_conv import causal_conv, causal_conv1d
+from .nn import ACTIVATIONS
 from .pallas_kernels import _kernel_on_tpu, pl
 from .registry import OpDef, Param, register_op
 from .transformer import layer_scope
@@ -972,38 +972,38 @@ def _kernel_takes(q, v) -> bool:
             and v.dtype in (jnp.bfloat16, jnp.float32))
 
 
-def causal_conv1d(x, w):
-    """Depthwise causal convolution over time: ``(B, T, C)`` data, one
-    ``W``-tap filter a channel ``(C, W)``, no bias: ``y_t = sum_j w[:, j]
-    x_{t - (W - 1) + j}``, positions before 0 read as zero."""
-    width = w.shape[1]
-    t = x.shape[1]
-    xp = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
-    w = w.astype(x.dtype)
-    return sum(xp[:, j:j + t, :] * w[:, j] for j in range(width))
-
-
 @register_op("CausalConv1D", hint="causalconv1d")
 class CausalConv1DOp(OpDef):
     """Depthwise causal convolution over time of ``(B, T, C)``: one
     ``kernel``-tap filter a channel (``weight`` ``(C, kernel)``), no bias;
-    output ``t`` reads inputs ``t - kernel + 1 .. t``."""
-    params = [Param("kernel", int, default=4)]
+    output ``t`` reads inputs ``t - kernel + 1 .. t``; then ``act_type``
+    (an ``Activation``'s; unset: none).  With ``lanes`` ``(w_0, w_1, ..)``
+    the data is ``(B, T, G, Dw)``, a fused projection: every group's
+    leading lanes are convolved where they lie, part by part ``(B, T, G
+    * sum(lanes))`` as ``weight``'s rows; the lanes behind them are the
+    second output, ``rest``.  Two lowerings (``causal_conv``)."""
+    params = [Param("kernel", int, default=4), Param("lanes", "shape"),
+              Param("act_type", str, enum=list(ACTIVATIONS))]
 
     def list_arguments(self, p):
         return ["data", "weight"]
 
+    def list_outputs(self, p):
+        return ["output", "rest"] if p.lanes else ["output"]
+
     def infer_shape(self, p, in_shapes):
-        d = in_shapes[0]
+        d, taken = in_shapes[0], sum(p.lanes or ())
         if d is None:
-            return in_shapes, [None], []
-        if len(d) != 3:
-            raise MXNetError("CausalConv1D: data must be (batch, seq, "
-                             "channels), got %r" % (d,))
-        return [d, (d[2], p.kernel)], [d], []
+            return in_shapes, [None] * (2 if p.lanes else 1), []
+        if len(d) != (4 if p.lanes else 3) or taken > d[-1]:
+            raise MXNetError("CausalConv1D: data (batch, seq, channels) or, "
+                             "with lanes, (.., groups, width); got %r" % (d,))
+        outs = [tuple(d[:2]) + (d[2] * n,) for n in (taken, d[3] - taken)] \
+            if p.lanes else [d]
+        return [d, (outs[0][2], p.kernel)], outs, []
 
     def forward(self, p, inputs, aux, ctx):
-        return [causal_conv1d(inputs[0], inputs[1])]
+        return causal_conv(*inputs, act_type=p.act_type, lanes=p.lanes)
 
 
 @register_op("KimiDeltaAttention", hint="kda")

@@ -15,7 +15,10 @@ text of a tiny step of the three builders whose step no other test holds
 (OLMoE's is in ``tests/test_sdar_moe.py``, SDAR's in
 ``tests/test_afmoe.py``).  A new branch takes a new case, its hash from
 the commit that adds it; a hash is never re-taken to make a refactor
-pass."""
+pass.  ISSUE 53 meant to move two builders' graphs and took theirs
+again (every ``kimi*`` and ``qwen3-next*`` symbol and the two steps:
+``CausalConv1D`` carries its SiLU, and Qwen3-Next's reads the fused
+projection where it lies); every other builder's stand."""
 import hashlib
 import importlib
 import json
@@ -164,7 +167,7 @@ SYMBOL_WAS = {
     "olmoe-1b-7b":
         "3010508f9af6d214d25b4ea18ba84994901ecdb99e011aa1a16b0b8e402fb4b7",
     "kimi-linear-48b-a3b":
-        "a09491b671c7c595c325ebaecac166464282947bd39b524ddfea1ad3bb17d556",
+        "4d129c2816b2423686a37318e20ecdca9ab8ef75a9238dc94ee9757f0dc3ca44",
     "glm-4.7-flash":
         "787b1b2114b39c79c8efd33a4dd949f218c07bf94a39b900e6b0333c00100433",
     "sdar-30b-a3b":
@@ -182,27 +185,27 @@ SYMBOL_WAS = {
         "b7b3aee331521264f4ec20024a1d4ea0c553144c1c2666ef2e413f8688f07b58",
     # taken at the commit that added the builder (ISSUE 50)
     "qwen3-next-80b-a3b":
-        "fc0fa8e66990e59ec2e0530707989493d3c9b32144d5e31680128b54958f32b1",
+        "d02bcecd33cf7bf01e6ba648bf134c1deb802f3417edfe61d89e53096f4b282b",
     "qwen3-next-share":
-        "f8d0043899ef9c23a96cca11f09af6896cf852d73bc9191fdc5c6089e710bd45",
+        "7e4b15905697ab30e84574f4e94f0ae242f0f24e61355ddef6c3bc2fcf37f265",
     "qwen3-next-whole":
-        "d458e91e9f75c6a413d5d8b4720a8835d31538254f4f11b5e0fbe48c91b9f4b6",
+        "f5ea23d967030b0d5d1073124b9fe25fd33bf3be207cf4dc73048b4e90478691",
     "qwen3-next-aux-0":
-        "894915db681d3025cfca0323028cfa009573f54fc379e0228647821b09c2c16d",
+        "6a208c40c6852fe06e098356e0601caf8024de83eb0a9b23fd18fb185a5e8465",
     "qwen3-next-all-rotated":
-        "e1a196070230587c4f0e05522fae34616b78965247f4757e3c371820a4430fb6",
+        "e79bc102d9f2dfe90f1753da0792f9c2eb791a1ea9d55cc6f7a0cbacba7a88ce",
     "qwen3-next-every-second":
-        "9d4034e3aa1f0d517fa5f76042c09563aa8c77fde3b8fc393acf1ef4b926c61f",
+        "4eb005aa5b1a6fde901cfc10ccc42d4aa449021e7fc03b8dca4e6a541a61dbc6",
     "olmoe-tiny":
         "af8dc705e9e7aeb26b2806967a870d607de9f4892070d6b09552c77d2034efc0",
     "olmoe-aux-0":
         "56de2d26c2c517cb4762f53be03544ae0560a3f024c25b59df3c4986f064eddb",
     "kimi-share":
-        "c4188086853a4d8cff3b2c70af8edd016b600b1521675eef5d61b341d59a92d6",
+        "b086532bd9522f3b1bfb59261fd5720841fffb673164a7398b95fdca6601aaf7",
     "kimi-whole":
-        "0effb24bd436fac90b5a3b09daf8e2d8084eeee2a8e5a89aef209baab0986bfa",
+        "c8b5eac50af6cffbbbf6090d643b0231acec060ce9f0590e972dd7bd6dd23ee3",
     "kimi-mixed":
-        "06d26997f08401c2a6bb0b7bf88146db74a8f93d5d9247d87327873d7adcc158",
+        "ca9a76ea70b1c574b0f9d5ec231ac2a2c7b173770a9c669b736e58e8997c23da",
     "glm-share":
         "8fcc0fbc997e1497b3671ef97ad37ddaf922eea12d3c38139aebc9553073a9a8",
     "glm-whole":
@@ -266,7 +269,7 @@ STEPS = {
 # sha256 of each step's lowered text at 945e6c8
 STEP_WAS = {
     "kimi":
-        "91d06b67a624693589abcc3e239cd8f92198f03787b307f377eb8f36c898cffa",
+        "78f89a019fa4f63160937f02bb44e0941124fad984ee247f58275f12920f1587",
     "glm":
         "3a00c3fc2d2fd85cce626d1955a2174d85b0f52940d1f41a8edac6b24be37a9f",
     "afmoe":
@@ -276,7 +279,7 @@ STEP_WAS = {
         "b1a83ee5679c16bad171411fc23ea7d6a0df99b255614ddcd14ccc724757f01c",
     # taken at the commit that added the builder (ISSUE 50)
     "qwen3-next":
-        "6e4944b4858261fffebfe74aa076f97c54dc917fe18ff7fa1f4903003165ecb7",
+        "933120a5a69823361198a22b5351ff73ff4276adbc2aa0a56e10def7701e9033",
 }
 
 

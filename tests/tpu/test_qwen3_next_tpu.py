@@ -29,7 +29,11 @@ ablation ISSUE 51's prediction was rewritten from), in
 ``chiprun_out/gdn_kernel_parity.json`` before anything is asserted.  The
 third holds ``causal_attention``'s TPU
 kernel against its plain blocks at ``(1, 4096, 16, 256)`` over 2
-key/value heads.
+key/value heads.  The fourth holds ``causal_conv``'s kernel pair
+``causal_conv_fwd`` / ``causal_conv_bwd`` against the plain form at the
+two cells' shapes, a key head's q, k, v lanes out of ``(1, 4096, 16,
+768)`` and Kimi's contiguous ``(1, 4096, 4096)``, with both lowerings'
+times (``chiprun_out/conv_kernel_parity.json``).
 """
 import gc
 import json
@@ -53,6 +57,7 @@ ATTN_L2_ERR = 0.01
 SEED = 5000000050
 GDN_TRACK = "bfloat16[1, 4096, 32, 128]/k16"
 ATTN_TRACK = "bfloat16[1, 4096, 16, 256]/kv2"
+CONV_TRACK = "bfloat16[1, 4096, 12288]/8192"
 
 
 def _rel(a, b):
@@ -195,7 +200,7 @@ def test_published_width_step_matches_reference():
         counters = {c: [[e["id"], e["args"]] for e in
                         mx.trace.counter_events([c], since_ns=mark)]
                     for c in ("gdn:lowering", "attn:lowering",
-                              "kda:kernel_trace")}
+                              "kda:kernel_trace", "conv:lowering")}
         report["adam_bf16"][str(seed)] = dict(
             loss_of(loss, want),
             update_rel_err={n: _rel(delta[n], want["updates"][n])
@@ -240,6 +245,9 @@ def test_published_width_step_matches_reference():
                    for _, a in bf16["gdn:lowering"])
         assert bf16["attn:lowering"] == [[ATTN_TRACK, {
             "kernel": 1, "plain": 0, "mask_form": "library"}]]
+        # the three mixers' convolutions read the projection where it lies
+        assert bf16["conv:lowering"] == [[CONV_TRACK, {
+            "kernel": 1, "plain": 0}]] * 3
         traces += bf16["kda:kernel_trace"]
     # the process traced each kernel once for all the steps' GDN layers,
     # in the form a head's decay admits: no halving level
@@ -468,3 +476,71 @@ def test_attention_kernel_matches_plain_blocks_at_256_over_two():
     print("\nQWEN3_NEXT_ATTN_PARITY " + json.dumps(report), flush=True)
     assert max(report["max_err_share"]) <= ATTN_MAX_ERR_SHARE, report
     assert max(report["l2_err"]) <= ATTN_L2_ERR, report
+
+
+def test_conv_kernels_match_the_plain_form_at_the_cells_shapes():
+    """``causal_conv`` with SiLU, the kernel pair compiled by Mosaic
+    against the plain ``causal_conv1d`` + SiLU computed in float32 from
+    the same bfloat16 inputs, forward and both cotangents, at the
+    Qwen3-Next mixer's shape (q, k, v lanes of 16 key heads of 768, the
+    z lanes left where they lie) and at Kimi's (4096 contiguous lanes);
+    the kernels are no further from that than the plain form in
+    bfloat16 is.  Then both lowerings' times, forward and forward +
+    backward."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import causal_conv as cc
+    report = {"device": jax.devices()[0].device_kind}
+
+    def sides(shape, parts):
+        rng = np.random.RandomState(53)
+        g = shape[2] if len(shape) == 4 else 1
+        c = g * sum(parts)
+        x = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+        w = jnp.asarray(0.5 * rng.standard_normal((c, 4)), jnp.bfloat16)
+        x4 = x.reshape(shape[:2] + (g, -1))
+        cts = tuple(jnp.asarray(rng.standard_normal(shape[:2] + (n,)),
+                                jnp.bfloat16)
+                    for n in (c, x4.size // (shape[0] * shape[1]) - c))
+
+        def both(fn):
+            def run(x, w, cts):
+                out, vjp = jax.vjp(fn, x, w)
+                return out + vjp(cts)
+            return jax.jit(run), jax.jit(fn)
+
+        kernel = both(lambda x, w: cc._two_lowerings(x, w, parts, False))
+        plain = both(lambda x, w: cc._plain(x, w, parts, "silu"))
+        f32 = jnp.float32
+        want = plain[0](x4.astype(f32), w.astype(f32),
+                        tuple(c.astype(f32) for c in cts))
+        out = {}
+        for name, (step, fwd) in (("kernel", kernel), ("plain", plain)):
+            got = step(x4, w, cts)
+            out[name] = {
+                "rel_err": {n: _rel(a, b) for n, a, b in
+                            zip(("y", "rest", "dx", "dw"), got, want)
+                            if a.size},
+                "fwd_ms": _ms(fwd, x4, w),
+                "fwd_bwd_ms": _ms(step, x4, w, cts)}
+        text = kernel[0].lower(x4, w, cts).compile().as_text()
+        out["kernel"]["custom_calls"] = [
+            n for n in ("causal_conv_fwd", "causal_conv_bwd") if n in text]
+        return out
+
+    for name, shape, parts in (
+            ("qwen3_next", (1, 4096, 16, 768), (128, 128, 256)),
+            ("kimi", (1, 4096, 4096), (4096,))):
+        report[name] = sides(shape, parts)
+        print("\nCONV_KERNEL_PARITY %s " % name + json.dumps(report[name]),
+              flush=True)
+    out_dir = os.path.join(ROOT, "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, "conv_kernel_parity.json"), "w") as f:
+        json.dump(report, f, indent=1)
+    for name in ("qwen3_next", "kimi"):
+        kernel, plain = report[name]["kernel"], report[name]["plain"]
+        assert kernel["custom_calls"] == ["causal_conv_fwd",
+                                          "causal_conv_bwd"]
+        for n, err in kernel["rel_err"].items():
+            assert err <= max(plain["rel_err"][n], 4e-3), (name, n)
