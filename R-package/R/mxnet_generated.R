@@ -322,6 +322,17 @@ mx.symbol.ROIPooling <- function(...) {
   mx.symbol.internal.create('ROIPooling', list(...))
 }
 
+#' Apply a body Symbol ``num_steps`` times over one set of inputs;
+#' @param body body parameter (required)
+#' @param num_steps num_steps parameter (required)
+#' @param carry carry parameter (required)
+#' @param recompute recompute parameter (default True)
+#' @param name string, node name (auto when omitted)
+#' @return MXSymbol
+mx.symbol.Repeat <- function(...) {
+  mx.symbol.internal.create('Repeat', list(...))
+}
+
 #' reference reshape-inl.h:370 (supports 0 = copy dim, -1 = infer).
 #' @param target_shape target_shape parameter
 #' @param shape shape parameter
@@ -939,6 +950,14 @@ mx.nd.ROIPooling <- function(..., ctx = mx.cpu()) {
   a <- list(...)
   is.nd <- vapply(a, function(x) inherits(x, 'MXNDArray'), logical(1))
   mx.nd.internal.invoke('ROIPooling', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
+}
+
+#' Imperative Repeat: 1 NDArray input(s), 0 scalar(s)
+#' @return MXNDArray
+mx.nd.Repeat <- function(..., ctx = mx.cpu()) {
+  a <- list(...)
+  is.nd <- vapply(a, function(x) inherits(x, 'MXNDArray'), logical(1))
+  mx.nd.internal.invoke('Repeat', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
 }
 
 #' Imperative Reshape: 1 NDArray input(s), 0 scalar(s)

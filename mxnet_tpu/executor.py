@@ -109,8 +109,10 @@ class _GraphProgram:
             mirror = (self.do_mirror
                       or node.attrs.get("force_mirroring", "").lower() == "true")
             # every device operation of the node, its key's too, carries
-            # the node's scope (trace/scopes.py)
-            with node_scope(node.attrs.get("__scope__"), node.op.name,
+            # the node's scope (trace/scopes.py); a node that holds a body
+            # leaves the naming to the body's nodes
+            with node_scope(node.attrs.get("__scope__"),
+                            node.op.name if node.op.own_scope else None,
                             node.name):
                 key = jax.random.fold_in(rng, k) if node.op.needs_rng else None
                 opctx = OpContext(is_train=is_train, rng=key)

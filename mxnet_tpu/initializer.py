@@ -5,6 +5,7 @@ initializations, everything else goes through the subclass hook.
 """
 from __future__ import annotations
 
+import json
 import re
 from typing import Dict, Optional
 
@@ -15,11 +16,17 @@ from .ndarray import NDArray, array as nd_array
 from . import random as _random
 
 __all__ = ["Initializer", "Uniform", "Normal", "Orthogonal", "Xavier",
-           "MSRAPrelu", "Load", "Mixed", "One", "Zero"]
+           "MSRAPrelu", "Load", "Mixed", "One", "Zero", "create"]
 
 
 class Initializer:
     """Base initializer (reference initializer.py:14-84)."""
+
+    def dumps(self) -> str:
+        """``[class name, constructor arguments]`` as JSON: what
+        ``Variable(init=...)`` keeps as the variable's ``__init__``
+        attribute and ``create`` reads back."""
+        return json.dumps([type(self).__name__.lower(), vars(self)])
 
     def __call__(self, name: str, arr: NDArray):
         if not isinstance(name, str):
@@ -161,6 +168,9 @@ class MSRAPrelu(Xavier):
         magnitude = 2.0 / (1 + slope ** 2)
         super().__init__("gaussian", factor_type, magnitude)
 
+    def dumps(self) -> str:
+        return json.dumps(["xavier", vars(self)])   # the Xavier it is
+
 
 class Load:
     """Initialize from existing param dict (reference initializer.py:199)."""
@@ -218,3 +228,15 @@ class Zero(Initializer):
 
     def _init_default(self, _, arr):
         arr[:] = 0.0
+
+
+def create(text: str) -> Initializer:
+    """The initializer ``Initializer.dumps`` wrote: a variable's own
+    (``Variable(init=...)``), which ``Module.init_params`` uses for that
+    variable in place of the one it was handed."""
+    name, kwargs = json.loads(text)
+    known = {cls.__name__.lower(): cls for cls in (
+        Uniform, Normal, Orthogonal, Xavier, One, Zero)}
+    if name not in known:
+        raise MXNetError("no initializer %r (have %s)" % (name, sorted(known)))
+    return known[name](**kwargs)

@@ -82,6 +82,17 @@ def find_prediction_heads(symbol):
 
 
 NOISE_HEAD = "diffusion_noise"
+EXIT_HEAD = "loop_exit"
+
+
+def _find_counter_head(symbol, name):
+    """The index of the output that the ``BlockGrad`` node ``name``
+    gives, or None for a symbol without one."""
+    for i, (node, _) in enumerate(symbol._heads):
+        if not node.is_variable and node.name == name \
+                and getattr(node.op, "name", "") == "BlockGrad":
+            return i
+    return None
 
 
 def find_noise_head(symbol):
@@ -89,11 +100,15 @@ def find_noise_head(symbol):
     head a block-diffusion symbol groups on (``models.sdar_moe``): the
     step's masked positions, positions, and the masked positions' summed
     weights, behind a ``BlockGrad``.  None for a symbol without one."""
-    for i, (node, _) in enumerate(symbol._heads):
-        if not node.is_variable and node.name == NOISE_HEAD \
-                and getattr(node.op, "name", "") == "BlockGrad":
-            return i
-    return None
+    return _find_counter_head(symbol, NOISE_HEAD)
+
+
+def find_exit_head(symbol):
+    """The index of the output ``loop_exit_output``: the ``(R + 1,)``
+    head a looped symbol groups on (``models.ouro``): the rows' summed
+    exit probabilities ``p_1 .. p_R`` and their summed full-depth cross
+    entropy, behind a ``BlockGrad``.  None for a symbol without one."""
+    return _find_counter_head(symbol, EXIT_HEAD)
 
 
 class FusedTrainStep:
@@ -261,6 +276,8 @@ class FusedTrainStep:
         # a block-diffusion symbol's noise head: what the step's labels
         # masked, for the trace
         self.noise_head = find_noise_head(symbol)
+        # a looped symbol's exit head: where the gate sends the rows
+        self.exit_head = find_exit_head(symbol)
         self.moe_stats = None
         if self.moe_blocks:
             from ..moe.stats import MoeStats
@@ -723,6 +740,20 @@ class FusedTrainStep:
             float(x) for x in outs[self.noise_head].asnumpy())
         _trace.counter("diffusion:noise", cat="train", masked=masked,
                        positions=positions, weight_sum=weight_sum)
+
+    def note_loop_exit(self, outs) -> None:
+        """Feed the ``loop:exit`` trace counter, one sample a step, from
+        the step's exit head as the metric gets it: ``p1 .. pR`` the
+        rows' mean probability of leaving after each pass, ``depth`` the
+        mean exit depth ``sum_t t p_t`` and ``ce_last`` the mean
+        cross entropy at full depth.  One host read of ``R + 1`` numbers
+        the metric update before this call already waited for."""
+        sums = [float(x) for x in outs[self.exit_head].asnumpy()]
+        rows = float(sum(sums[:-1])) or 1.0     # a row's p sums to 1
+        p = [x / rows for x in sums[:-1]]
+        _trace.counter("loop:exit", cat="train", ce_last=sums[-1] / rows,
+                       depth=sum((t + 1) * x for t, x in enumerate(p)),
+                       **{"p%d" % (t + 1): x for t, x in enumerate(p)})
 
     # -- compiled programs ---------------------------------------------------
     def _make_step_fn(self):

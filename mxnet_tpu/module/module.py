@@ -11,7 +11,7 @@ from typing import Dict, List, Optional, Sequence
 from .. import trace as _trace
 from ..base import MXNetError, get_env
 from ..context import Context, cpu, current_context
-from ..initializer import Uniform
+from ..initializer import Uniform, create as _create_initializer
 from ..ndarray import NDArray, _lives_on_host, zeros as nd_zeros
 from .. import optimizer as opt_mod
 from ..model import (_create_kvstore, _initialize_kvstore, _param_idx2name,
@@ -157,6 +157,17 @@ class Module(BaseModule):
             self._aux_params = {name: arr for name, arr in
                                 zip(self._aux_names, aux_arrays)}
 
+        own = {name: attrs["__init__"] for name, attrs
+               in self._symbol.attr_dict().items() if "__init__" in attrs}
+
+        def fresh(name, arr):
+            # a variable's own initializer (``Variable(init=...)``) before
+            # the one this call was handed
+            if name in own:
+                _create_initializer(own[name])(name, arr)
+            elif initializer is not None:
+                initializer(name, arr)
+
         def _impl(name, arr, cache):
             if cache is not None:
                 if name in cache:
@@ -166,10 +177,9 @@ class Module(BaseModule):
                 else:
                     if not allow_missing:
                         raise RuntimeError("%s is not presented" % name)
-                    if initializer is not None:
-                        initializer(name, arr)
+                    fresh(name, arr)
             else:
-                initializer(name, arr)
+                fresh(name, arr)
 
         for name, arr in self._arg_params.items():
             _impl(name, arr, arg_params)
@@ -1189,14 +1199,17 @@ class Module(BaseModule):
         ``fit:mtp_loss``, and what a block-diffusion symbol's noise head
         counted (``note_diffusion_noise``) under ``fit:diffusion_noise``,
         and what a rank's expert blocks counted of their activated lanes
-        (``note_act_zeros``) under ``fit:moe_act_zeros``:
+        (``note_act_zeros``) under ``fit:moe_act_zeros``, and where a
+        looped symbol's exit gate sent the rows (``note_loop_exit``)
+        under ``fit:loop_exit``:
         nothing, and no span, where the fused step is off or the symbol
-        carries no such head; the last three only while tracing is on."""
+        carries no such head; the last four only while tracing is on."""
         fused = self._fused
         if fused is None or not self._fused_live() \
                 or not (fused.moe_load_heads or fused.prediction_heads
                         or fused.noise_head is not None
-                        or fused.act_zeros_head):
+                        or fused.act_zeros_head
+                        or fused.exit_head is not None):
             return
         outs = self.get_outputs() if outputs is None else outputs
         if fused.moe_load_heads:
@@ -1211,6 +1224,9 @@ class Module(BaseModule):
         if fused.act_zeros_head and _trace.enabled():
             with _trace.span("fit:moe_act_zeros", cat="train"):
                 fused.note_act_zeros(outs)
+        if fused.exit_head is not None and _trace.enabled():
+            with _trace.span("fit:loop_exit", cat="train"):
+                fused.note_loop_exit(outs)
 
     def _outputs_in_flight(self):
         """The overlap hook of fit() and score(): the outputs of the

@@ -70,6 +70,11 @@ class Param:
             return None
         if self.typ == "shape":
             return _parse_shape(value)
+        if self.typ == "symbol":
+            # a node that holds a graph (ops/control_flow.py): the Symbol
+            # itself, or its JSON as a saved graph carries it
+            from ..symbol import Symbol, load_json
+            return value if isinstance(value, Symbol) else load_json(value)
         if self.typ is bool:
             return _parse_bool(value)
         if self.typ is int:
@@ -88,6 +93,8 @@ class Param:
         """Serialize for symbol JSON attrs (reference stores param strings)."""
         if self.typ == "shape":
             return "(" + ", ".join(str(x) for x in value) + ")"
+        if self.typ == "symbol":
+            return value.tojson(indent=None)
         if self.typ is bool:
             return "True" if value else "False"
         return str(value)
@@ -129,6 +136,9 @@ class OpDef:
     # analogue of the reference's ref_count==0 omission check
     # (graph_executor.cc:1017-1024)
     head_grad_optional: bool = False
+    # False for a node that holds a graph of its own (Repeat): the
+    # executor enters no scope around it, its body's nodes name theirs
+    own_scope: bool = True
 
     def __init__(self, name: str):
         self.name = name

@@ -374,7 +374,7 @@ class Symbol:
         return arg_types, out_types, aux_types
 
     # -- serialization (reference Symbol::Save JSON) ------------------------
-    def tojson(self) -> str:
+    def tojson(self, indent=2) -> str:
         nodes = _topo(self._heads)
         idx = {id(n): i for i, n in enumerate(nodes)}
         jnodes = []
@@ -394,7 +394,7 @@ class Symbol:
         attrs.update(self._graph_attrs)
         return json.dumps({"nodes": jnodes, "arg_nodes": arg_nodes,
                            "heads": heads, "attrs": attrs},
-                          indent=2)
+                          indent=indent)
 
     def save(self, fname: str) -> None:
         from .base import atomic_local_write, is_local_path, open_stream
@@ -461,6 +461,10 @@ def Variable(name: str, attr=None, shape=None, lr_mult=None, wd_mult=None,
         attr["lr_mult"] = str(lr_mult)
     if wd_mult is not None:
         attr["wd_mult"] = str(wd_mult)
+    if init is not None:
+        # the variable's own initializer (``initializer.create`` reads it
+        # back; ``Module.init_params`` prefers it to the one it is handed)
+        attr["__init__"] = init if isinstance(init, str) else init.dumps()
     node = _Node(None, name, attrs=attr)
     return Symbol([(node, 0)])
 
@@ -582,6 +586,43 @@ def _init_symbol_module():
 
 
 _init_symbol_module()
+
+
+def Repeat(body: Symbol, carry: Dict[str, Symbol], num_steps: int,
+           name: Optional[str] = None, recompute: bool = True, attr=None,
+           **bound: Symbol) -> Symbol:
+    """``body`` applied ``num_steps`` times with one set of weights, as
+    ONE node of the graph (``ops/control_flow.py``).
+
+    ``carry`` maps free variables of ``body`` to their first values; the
+    body's first ``len(carry)`` outputs, in that order, are what the next
+    pass reads in their place.  ``bound`` maps further free variables to
+    symbols of this graph that every pass reads (the flattened labels, a
+    weight another node shares).  Every free variable left is a weight:
+    it becomes a ``Variable`` of this graph under the name and the
+    attributes it has in the body, listed once whatever ``num_steps`` is.
+    Outputs: the last carry, then each further output of the body stacked
+    ``(num_steps, ...)``.  ``recompute``: the backward pass forms a pass
+    again from its carry and keeps no pass's activations."""
+    if not isinstance(body, Symbol):
+        raise TypeError("Repeat expects a Symbol body")
+    carry = dict(carry)
+    for k, v in list(carry.items()) + list(bound.items()):
+        if not isinstance(v, Symbol):
+            raise TypeError("Repeat: %r is bound to %r, not a Symbol"
+                            % (k, v))
+    free = {n.name: n for n in _topo(body._heads)
+            if n.is_variable and not n.is_aux}
+    unknown = [k for k in list(carry) + list(bound) if k not in free]
+    if unknown:
+        raise MXNetError("Repeat: %s are not free variables of the body, "
+                         "which has %s" % (unknown, sorted(free)))
+    weights = {k: Symbol([(_Node(None, k, attrs=node.attrs), 0)])
+               for k, node in free.items()
+               if k not in carry and k not in bound}
+    return _create("Repeat", [], name=name, attr=attr, body=body.tojson(),
+                   num_steps=num_steps, carry=",".join(carry),
+                   recompute=recompute, **carry, **bound, **weights)
 
 
 def __getattr__(name):

@@ -56,7 +56,7 @@ from .journal import (journal_every, journal_path, maybe_journal_step,
                       reset_journal, write_journal_line)
 from .recorder import DEFAULT_BUF_EVENTS, Recorder
 from . import scopes
-from .scopes import program_scopes
+from .scopes import program_op_names, program_scopes
 
 __all__ = ["span", "complete", "instant", "counter", "async_begin",
            "async_instant", "async_end", "next_async_id", "enabled",
@@ -64,7 +64,7 @@ __all__ = ["span", "complete", "instant", "counter", "async_begin",
            "configure_spill", "flush_spill", "label_process",
            "event_count", "drop_count", "span_events", "instant_events",
            "counter_events",
-           "trace_report", "scopes", "program_scopes",
+           "trace_report", "scopes", "program_scopes", "program_op_names",
            "reset", "maybe_journal_step", "write_journal_line",
            "journal_path", "journal_every", "reset_journal"]
 

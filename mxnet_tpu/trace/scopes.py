@@ -15,10 +15,15 @@ what a reader sums by (``kind_of``).  There are two sorts:
   ``module/fused.py``);
 * **generic** (``generic``): what the executor enters around every
   other op node, ``<op type, lower case>.<node name>``
-  (``convolution.stage1_unit1_conv1``).
+  (``convolution.stage1_unit1_conv1``);
+* **enclosing** (``enclosing``): what a node that holds a body enters
+  around it (``loop``, ``ops/control_flow.py``).  It names only what no
+  scope of the body's own names: the loop's ``while``, its counters, the
+  sums of the passes' gradients.
 
 Precedence (``resolve``): a declared scope wins over a generic one, and
-of nested declared scopes the outermost wins.  Which names are scopes,
+of nested declared scopes the outermost wins; an enclosing scope loses
+to both, wherever it stands on the path.  Which names are scopes,
 and of which sort, is what this process entered: JAX's own path
 segments (``while``, ``body``, ``checkpoint``, ``jit(_where)``) are
 never taken for one.
@@ -48,18 +53,22 @@ import re
 import time
 from typing import Dict, Optional, Set
 
-__all__ = ["SCHEME", "module_name", "declared", "generic", "adopt", "resolve",
-           "kind_of", "table_of", "register_program", "program_scopes"]
+__all__ = ["SCHEME", "module_name", "declared", "generic", "enclosing", "adopt",
+           "resolve", "kind_of", "op_names_of", "table_of", "register_program",
+           "program_scopes", "program_op_names"]
 
 SCHEME = 1
 TABLE_SPAN = "trace:scope_table"
 
 _declared: Set[str] = set()
 _generic: Set[str] = set()
+_enclosing: Set[str] = set()
 # op_name prefix of a kernel the compiler writes itself -> its scope
 _adopted: Dict[str, str] = {}
 # cached_jit name -> [the latest program built under it, its table]
 _programs: Dict[str, list] = {}
+# cached_jit name -> (that program, its instructions' op_names)
+_op_names: Dict[str, tuple] = {}
 
 
 def module_name(base: str) -> str:
@@ -79,6 +88,14 @@ def generic(name: str):
     """``jax.named_scope`` of a node nobody named, by op type and node."""
     import jax
     _generic.add(name)
+    return jax.named_scope(name)
+
+
+def enclosing(name: str):
+    """``jax.named_scope`` of a node around the body it holds: the scope
+    of the operations under it that no declared or generic scope names."""
+    import jax
+    _enclosing.add(name)
     return jax.named_scope(name)
 
 
@@ -124,14 +141,19 @@ def _segments(op_name: str):
 
 def resolve(op_name: str) -> Optional[str]:
     """The scope an HLO ``op_name`` lies in, or None: the outermost
-    declared scope on its path, else the outermost generic one, else
-    the scope that adopted a compiler-written kernel of this name."""
-    first_generic = None
+    declared scope on its path, else the outermost generic one, else the
+    outermost enclosing one, else the scope that adopted a
+    compiler-written kernel of this name."""
+    first_generic = around = None
     for seg in _segments(op_name):
         if seg in _declared:
             return seg
         if first_generic is None and seg in _generic:
             first_generic = seg
+        if around is None and seg in _enclosing:
+            around = seg
+    if first_generic is None:
+        first_generic = around
     if first_generic is None:
         for prefix, scope in _adopted.items():
             if op_name.startswith(prefix):
@@ -143,26 +165,37 @@ _INSTRUCTION = re.compile(r'^\s*(?:ROOT\s+)?%?([^\s=(){}"]+) = ', re.M)
 _OP_NAME = re.compile(r'\bmetadata=\{op_name="([^"]*)"')
 
 
-def table_of(hlo_text: str) -> Dict[str, str]:
-    """{instruction name: scope} of one optimized HLO module's text:
-    every instruction of every computation whose ``op_name`` resolves.
-    An instruction may span lines (a Pallas kernel's custom call holds a
+def op_names_of(hlo_text: str) -> Dict[str, str]:
+    """{instruction name: op_name} of one optimized HLO module's text:
+    every instruction of every computation that carries one.  An
+    instruction may span lines (a Pallas kernel's custom call holds a
     JSON ``kernel_metadata`` before its ``metadata``): what lies between
     one instruction's start and the next is its own."""
     out = {}
-    memo: Dict[str, Optional[str]] = {}
     text = hlo_text or ""
     starts = list(_INSTRUCTION.finditer(text))
     for m, following in zip(starts, starts[1:] + [None]):
         found = _OP_NAME.search(
             text, m.end(), following.start() if following else len(text))
-        if found is None:
-            continue
-        op_name = found.group(1)
+        if found is not None:
+            out[m.group(1)] = found.group(1)
+    return out
+
+
+def table_of(hlo_text: str) -> Dict[str, str]:
+    """{instruction name: scope} of one optimized HLO module's text:
+    every instruction whose ``op_name`` (``op_names_of``) resolves."""
+    return _table(op_names_of(hlo_text))
+
+
+def _table(op_names: Dict[str, str]) -> Dict[str, str]:
+    out = {}
+    memo: Dict[str, Optional[str]] = {}
+    for instruction, op_name in op_names.items():
         if op_name not in memo:
             memo[op_name] = resolve(op_name)
         if memo[op_name] is not None:
-            out[m.group(1)] = memo[op_name]
+            out[instruction] = memo[op_name]
     return out
 
 
@@ -185,11 +218,30 @@ def program_scopes(name: str = "fused:step") -> Optional[Dict[str, str]]:
         return None
     if held[1] is None:
         t0 = time.perf_counter()
-        text = held[0].optimized_hlo()
-        if text is None:
+        names = program_op_names(name)
+        if names is None:
             return None
-        held[1] = table_of(text)
+        held[1] = _table(names)
         from . import complete
         complete(TABLE_SPAN, t0, time.perf_counter() - t0, cat="compile",
                  program=name, instructions=len(held[1]))
     return held[1]
+
+
+def program_op_names(name: str = "fused:step") -> Optional[Dict[str, str]]:
+    """{HLO instruction: op_name} of the latest program built under
+    ``name``: JAX's whole path of each instruction, its own segments
+    too (``while/body``, ``checkpoint/rematted_computation``: what a
+    backward pass forms again), for a reader that asks what no scope
+    says.  None where ``program_scopes`` gives None; built with it, at
+    the first request of either."""
+    held = _programs.get(name)
+    if held is None:
+        return None
+    kept = _op_names.get(name)
+    if kept is None or kept[0] is not held[0]:
+        text = held[0].optimized_hlo()
+        if text is None:
+            return None
+        kept = _op_names[name] = (held[0], op_names_of(text))
+    return kept[1]

@@ -1,6 +1,6 @@
 """The decoder builders' symbols, node for node (ISSUE 46, tier-1).
 
-The LM builders of ``mxnet_tpu/models`` (five then, seven now) are
+The LM builders of ``mxnet_tpu/models`` (five then, eight now) are
 assembled from one skeleton, ``models/decoder.py``.  What holds that assembly still is the
 graph each builder returns: under a fresh ``NameManager`` the symbol's
 JSON (every node's op, name, keywords, attributes and inputs, the unnamed
@@ -77,6 +77,9 @@ QWEN3_NEXT = dict(num_layers=4, hidden_size=32, full_attention_interval=4,
                   experts_per_tok=4, expert_width=24, shared_width=24,
                   vocab_size=50, seq_len=24, rms_eps=1e-6, aux_coef=0.001,
                   experts_held=4, first_expert=4)
+OURO = dict(num_layers=2, hidden_size=32, num_heads=4, num_kv_heads=4,
+            head_dim=8, mlp_width=48, vocab_size=50, seq_len=16,
+            total_ut_steps=4, rope_theta=1e6, rms_eps=1e-6, exit_beta=0.1)
 WHOLE = dict(experts_held=0, first_expert=0)
 # latent attention by itself: seq_len, hidden_size, heads, kv_lora_rank,
 # qk_nope_dim, qk_rope_dim, v_head_dim, rms_eps
@@ -128,6 +131,17 @@ SYMBOLS = {
                                     rotary_dim=16),
     "qwen3-next-every-second": _tiny("qwen3_next_lm", QWEN3_NEXT,
                                      full_attention_interval=2),
+    # the eighth builder, from the commit that added it (ISSUE 54): its
+    # cell, four passes and one as ONE loop node (the body rides the
+    # node's ``body`` parameter, so the hash holds the body too), the body
+    # kept and not formed again, fewer key/value heads, the embedding's
+    # own initializer
+    "ouro-2.6b": _cell("ouro-2.6b"),
+    "ouro-tiny": _tiny("ouro_lm", OURO),
+    "ouro-one-pass": _tiny("ouro_lm", OURO, total_ut_steps=1),
+    "ouro-kept": _tiny("ouro_lm", OURO, recompute=False),
+    "ouro-grouped": _tiny("ouro_lm", OURO, num_kv_heads=2),
+    "ouro-wide-embedding": _tiny("ouro_lm", OURO, embed_sigma=4.0),
     # OLMoE: its load-balance heads stay on at coefficient 0
     "olmoe-tiny": _tiny("olmoe_lm", OLMOE),
     "olmoe-aux-0": _tiny("olmoe_lm", OLMOE, aux_coef=0.0),
@@ -196,6 +210,19 @@ SYMBOL_WAS = {
         "e79bc102d9f2dfe90f1753da0792f9c2eb791a1ea9d55cc6f7a0cbacba7a88ce",
     "qwen3-next-every-second":
         "4eb005aa5b1a6fde901cfc10ccc42d4aa449021e7fc03b8dca4e6a541a61dbc6",
+    # taken at the commit that added the builder (ISSUE 54)
+    "ouro-2.6b":
+        "a6237f2acd13d5d18d5a2fe516b57a08d4d8b58d51e529a1552893f9c88f15f3",
+    "ouro-tiny":
+        "8a7127bf3a21a28c0c279c69149be96d239a3ae5f2b5f37214cefe815e313acb",
+    "ouro-one-pass":
+        "eb9d2058606bdfc102781e3c565a1364fc79778f24df8e43c2ca7e303f1223fd",
+    "ouro-kept":
+        "8ea406b517cee9237e488a330e5aa8c741db56f5ca2bf347f33c69a28ea97b19",
+    "ouro-grouped":
+        "d25c2955f4329377c144aff6f552bcb7cf7ae56f513f95b566bd62bbf88e150d",
+    "ouro-wide-embedding":
+        "1e32bfb3be57e690901828997a7b534b564ad51ad7752defadbcae93627279b1",
     "olmoe-tiny":
         "af8dc705e9e7aeb26b2806967a870d607de9f4892070d6b09552c77d2034efc0",
     "olmoe-aux-0":
