@@ -29,10 +29,11 @@ lambda) = -softplus(g)``) in float32.
 The graph.  The passes are ONE node, ``sym.Repeat`` (``loop``): its body
 holds the L blocks, the final norm (the carry), the head, the cross
 entropy and the gate, so each weight is an argument once and the lowered
-step holds one copy of a pass.  The head is inside the body on purpose:
+step does not grow with the passes.  The head is inside the body on purpose:
 a pass's float32 logits live only inside that pass, which the backward
 pass forms again from the pass's carry (``recompute``), one pass at a
-time.  The per-pass outputs ``ce_t`` and ``g_t`` leave the loop stacked
+time, but for the last pass, which it reads as the forward left it.  The
+per-pass outputs ``ce_t`` and ``g_t`` leave the loop stacked
 ``(R, rows)``; the objective behind the loop is plain ops.
 
 The symbol trains through ``Module.fit`` as it stands: inputs ``data``

@@ -3,8 +3,12 @@ unrolled by hand over shared ``Variable``s (outputs and every gradient),
 what the graph lists, infers and saves, what is refused, that the
 recomputed and the kept body give the same gradients and that only the
 recomputed one is formed again, the lowered program's size beside the
-unrolled build's, and the device scopes of a body's nodes."""
+unrolled build's, and the device scopes of a body's nodes.  Since ISSUE
+56 also what the recomputed loop's backward pass keeps: the earlier
+passes' carries, and the last pass as the forward left it, which is read
+off the compiled program."""
 import json
+import re
 import time
 
 import numpy as np
@@ -98,11 +102,12 @@ def _run(net, values):
 
 
 # -- against the hand-unrolled graph ---------------------------------------------
+@pytest.mark.parametrize("steps", [1, 2, STEPS, 4])
 @pytest.mark.parametrize("recompute", [True, False])
-def test_the_loop_equals_the_passes_written_out(recompute):
+def test_the_loop_equals_the_passes_written_out(recompute, steps):
     values = _values()
-    outs, grads = _run(_looped(recompute), values)
-    want_outs, want = _run(_unrolled(), values)
+    outs, grads = _run(_looped(recompute, steps), values)
+    want_outs, want = _run(_unrolled(steps), values)
     for got, ref in zip(outs, want_outs):
         np.testing.assert_allclose(got, ref, rtol=2e-6, atol=1e-6)
     assert set(grads) == set(want)
@@ -124,10 +129,11 @@ def test_one_pass_is_the_body_itself():
         np.testing.assert_allclose(grads[k], want[k], rtol=1e-5, atol=1e-6)
 
 
-def test_recomputed_and_kept_bodies_give_the_same_gradients():
+@pytest.mark.parametrize("steps", [1, 2, STEPS, 4])
+def test_recomputed_and_kept_bodies_give_the_same_gradients(steps):
     values = _values(2)
-    _, kept = _run(_looped(recompute=False), values)
-    _, again = _run(_looped(recompute=True), values)
+    _, kept = _run(_looped(recompute=False, steps=steps), values)
+    _, again = _run(_looped(recompute=True, steps=steps), values)
     for k in kept:
         np.testing.assert_allclose(again[k], kept[k], rtol=1e-6, atol=1e-7)
 
@@ -257,47 +263,120 @@ def _lowered(net, values):
     return jax.jit(step).lower(train)
 
 
-def test_the_lowered_program_holds_one_copy_of_the_body():
+def _count(pattern, text):
+    return len(re.findall(pattern, text))
+
+
+def test_the_program_does_not_grow_with_the_passes():
     """The loop's program does not grow with the passes; the written-out
     graph's does.  Counted in the lowered text's ``dot_general``s: one
-    pass has three matmuls forward."""
+    pass has three matmuls forward and six backward.  The recomputed loop
+    is lowered as a forward half (the earlier passes' ``while`` and the
+    last pass call it) and a backward half that forms the forward again
+    (the backward ``while`` and the last pass call it): under two copies
+    and a forward, at 3 passes and at 12."""
     values = _values()
 
     def dots(net):
         return _lowered(net, values).as_text().count("dot_general")
 
-    assert dots(_looped(steps=3)) == dots(_looped(steps=12))
+    one_copy = dots(_looped(recompute=False))
+    assert one_copy == 3 + 6 == dots(_looped(recompute=False, steps=12))
+    again = dots(_looped(steps=3))
+    assert again == dots(_looped(steps=12)) == dots(_looped(steps=2))
+    assert one_copy < again <= 2 * one_copy + 3
     assert dots(_unrolled(steps=6)) > 1.8 * dots(_unrolled(steps=3))
     assert dots(_looped(steps=12)) < dots(_unrolled(steps=6))
     # recomputation forms the forward again: more products in the text,
-    # under JAX's own name for it; the kept body has none
+    # under JAX's own name for it; the kept body has none, and one pass
+    # alone is the body itself: nothing is formed again, no ``while``
     text = _lowered(_looped(recompute=True), values).as_text(debug_info=True)
-    kept = _lowered(_looped(recompute=False), values).as_text(
-        debug_info=True)
     assert "rematted_computation" in text
-    assert "rematted_computation" not in kept
-    assert "checkpoint" not in kept
+    for net in (_looped(recompute=False), _looped(steps=1)):
+        kept = _lowered(net, values).as_text(debug_info=True)
+        assert "rematted_computation" not in kept
+        assert "checkpoint" not in kept
+    # (the one pass's rows are the graph's input: no product for theirs)
+    assert dots(_looped(steps=1)) == one_copy - 1
+    assert "stablehlo.while" not in _lowered(_looped(steps=1),
+                                             values).as_text()
 
 
-def test_the_backward_pass_keeps_the_carries_only():
-    """What ``scan`` stacks for the backward pass: with ``recompute`` one
-    ``(rows, width)`` carry a pass (and the label's gather indices),
-    without it every activation of every pass."""
+@pytest.mark.parametrize("steps", [3, 4, 12])
+def test_the_compiled_program_forms_the_last_pass_once(steps):
+    """The last pass stands between the two ``while``s, its forward and
+    its backward half in one computation with no barrier between them,
+    and the compiler merges what the backward half would form again with
+    what the forward half has just computed.  That is the compiler's
+    doing and not the lowered text's (which says ``rematted_computation``
+    for the last pass too), so it is read off the COMPILED program (the
+    CPU's here; the chip's in ``tests/tpu/test_ouro_tpu.py``), by the one
+    ``tanh`` of a pass: once in the forward ``while``, ONCE for the last
+    pass, once more in the backward ``while``, which forms a pass again.
+    A compiler that stopped merging reads 4 here: the time of ISSUE 56
+    given back, no memory."""
     values = _values()
 
-    def stacked_floats(net):
-        prog = _GraphProgram(net, {}, None, do_mirror=False)
-        args = {k: jnp.asarray(values[k]) for k in net.list_arguments()}
-        _, vjp = jax.vjp(lambda a: prog.eval(a, {}, None, True)[0], args)
-        return sum(int(np.prod(x.shape)) for x in
-                   jax.tree_util.tree_leaves(vjp)
-                   if hasattr(x, "shape") and len(x.shape) >= 1
-                   and x.shape[0] == STEPS
-                   and jnp.issubdtype(x.dtype, jnp.floating))
+    def compiled(net):
+        text = _lowered(net, values).compile().as_text()
+        return _count(r" tanh\(", text), _count(r" while\(", text)
 
-    again, kept = stacked_floats(_looped(True)), stacked_floats(_looped(False))
-    assert again == STEPS * ROWS * WIDTH
-    assert kept > 4 * again
+    assert compiled(_looped(steps=steps)) == (3, 2)
+    # everything kept: one copy, stacked by ``scan``
+    assert compiled(_looped(recompute=False, steps=steps)) == (1, 2)
+
+
+def test_at_two_passes_the_barrier_is_the_chips():
+    """A ``while`` of one trip is unrolled, and the earlier pass then
+    stands in one computation with the last: the loop's barrier is what
+    keeps it formed again there.  It is in the lowered text; XLA's CPU
+    pipeline expands barriers away before its last merge and keeps both
+    passes (``jax.checkpoint`` outside a ``scan`` reads the same on the
+    CPU), the chip's does not (``tests/tpu/test_ouro_tpu.py``)."""
+    values = _values()
+    lowered = _lowered(_looped(steps=2), values)
+    assert "optimization_barrier" in lowered.as_text()
+    text = lowered.compile().as_text()
+    assert _count(r" while\(", text) == 0
+    assert _count(r" tanh\(", text) in (2, 3)      # 3: the contract held
+
+
+def _held(net, values):
+    """{shape: count} of the floating-point arrays the backward pass
+    holds: the leaves of the whole graph's ``jax.vjp``."""
+    prog = _GraphProgram(net, {}, None, do_mirror=False)
+    args = {k: jnp.asarray(values[k]) for k in net.list_arguments()}
+    _, vjp = jax.vjp(lambda a: prog.eval(a, {}, None, True)[0], args)
+    held = {}
+    for x in jax.tree_util.tree_leaves(vjp):
+        if hasattr(x, "shape") and jnp.issubdtype(x.dtype, jnp.floating):
+            held[tuple(x.shape)] = held.get(tuple(x.shape), 0) + 1
+    return held
+
+
+@pytest.mark.parametrize("steps", [2, STEPS, 5])
+def test_the_backward_pass_keeps_the_carries_only(steps):
+    """What the backward pass is handed.  With ``recompute``: the carries
+    the ``steps - 1`` earlier passes started from, stacked, and the last
+    pass's, alone; no other activation of any pass (the last pass's are
+    the compiler's to hold: the test above).  Without it every activation
+    of every pass, stacked by ``scan``."""
+    values = _values()
+    front = steps - 1
+    carry, hidden = (ROWS, WIDTH), (ROWS, 2 * WIDTH)
+    logits = (ROWS, CLASSES)
+
+    def stacked(held, n):
+        return {s[1:]: c for s, c in held.items()
+                if len(s) >= 2 and s[0] == n and s[1] == ROWS}
+
+    again = _held(_looped(True, steps), values)
+    assert stacked(again, front) == {carry: 1}
+    assert again[carry] == 1 and hidden not in again and logits not in again
+    kept = _held(_looped(False, steps), values)
+    every = stacked(kept, steps)
+    assert every[hidden] >= 1 and every[logits] >= 1 and every[carry] >= 3
+    assert hidden not in kept and logits not in kept
 
 
 # -- the trace ------------------------------------------------------------------------------
@@ -336,12 +415,13 @@ def test_a_bodys_nodes_keep_their_scopes_and_the_loop_names_the_rest():
         == "loop"
 
 
-def test_each_trace_of_the_node_records_loop_body():
+@pytest.mark.parametrize("recompute", [True, False])
+def test_each_trace_of_the_node_records_loop_body(recompute):
     was = mx.trace.enabled()
     mx.trace.set_enabled(True)
     try:
         mark = time.perf_counter_ns()
-        _run(_looped(), _values())
+        _run(_looped(recompute), _values())
         events = mx.trace.counter_events(["loop:body"], since_ns=mark)
     finally:
         mx.trace.set_enabled(was)
@@ -350,4 +430,5 @@ def test_each_trace_of_the_node_records_loop_body():
         assert e["id"] == "%dx%d" % (STEPS, e["args"]["nodes"])
         assert e["args"] == {"num_steps": STEPS, "nodes": 7,
                              "carry_bytes": ROWS * WIDTH * 4,
-                             "recompute": 1}
+                             "recompute": int(recompute),
+                             "kept_passes": 1 if recompute else STEPS}

@@ -355,7 +355,8 @@ def test_fit_feeds_loop_exit_once_a_step_and_the_bind_loop_body():
         assert 3.0 < a["ce_last"] < 5.0         # ln 50 = 3.9
     assert bodies and all(
         b["args"] == {"num_steps": 4, "nodes": bodies[0]["args"]["nodes"],
-                      "carry_bytes": BATCH * 16 * 32 * 4, "recompute": 1}
+                      "carry_bytes": BATCH * 16 * 32 * 4, "recompute": 1,
+                      "kept_passes": 1}     # the last, as the forward left it
         for b in bodies)
     # the body's nodes: what one pass is made of, not four
     assert bodies[0]["args"]["nodes"] < 60

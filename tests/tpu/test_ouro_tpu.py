@@ -13,7 +13,11 @@ configuration's own Adam step in bfloat16 at the default matmul
 precision, as the cell's reference check runs it, on
 ``OURO_PARITY_SEEDS`` seeds (8; weights and batch both from the seed),
 with the ``loop:body`` and ``attn:lowering`` samples of the bind and the
-step's exit head beside the reference's exit distribution; and the Adam
+step's exit head beside the reference's exit distribution, and of the
+first seed's step program what its backward ``while`` forms again
+(ISSUE 56: three earlier passes from their stacked carries, the last
+pass's forward in the program once, and a toy loop of two passes, where
+the loop's barrier alone keeps the first pass formed again); and the Adam
 step in float32 compute against the reference at one sequence of 1024.
 The numbers go to ``chiprun_out/ouro_parity.json`` after every phase,
 before anything is asserted.
@@ -42,9 +46,56 @@ def _rel(a, b):
     return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
 
 
-def _adam_step(net, params, data, labels, opt_params, compute_dtype, names):
+def _formed_again():
+    """Of the step program that has just run: what lies under
+    ``rematted_computation`` (what a checkpoint forms again), read from
+    its optimized HLO."""
+    import re
+    import mxnet_tpu as mx
+    from mxnet_tpu.trace import scopes
+    names = mx.trace.program_op_names("fused:step")
+    again = [n for n in names.values() if "/rematted_computation/" in n]
+    kernels = [n for n in names.values() if "splash_mha" in n]
+    text = scopes._programs["fused:step"][0].optimized_hlo()
+    head = re.compile(r"loop_head\)*/dot_general")
+    # an instruction's text runs to the next one's start (a kernel's
+    # custom call holds its payload and metadata on further lines)
+    starts = [m.start() for m in scopes._INSTRUCTION.finditer(text)]
+    instructions = [text[a:b] for a, b in zip(starts, starts[1:] + [None])]
+
+    def calls(kernel, among=instructions):
+        return [i for i in among
+                if "custom-call(" in i and "tpu_custom_call" in i
+                and kernel in i]
+
+    # the entry computation is the text's last: what stands between the
+    # two ``while``s
+    entry_at = text.index("\nENTRY ")
+    entry = instructions[sum(a < entry_at for a in starts):]
+
+    return {
+        "instructions": len(again),
+        "splash_mha_fwd": sum("splash_mha_fwd" in n for n in again),
+        "head_products": sum(bool(head.search(n)) for n in again),
+        "head_products_forward_while": sum(
+            bool(head.search(n)) for n in names.values()
+            if "/jvp(loop)/while/body/" in n),
+        "kernel_instructions": len(kernels),
+        # the kernels' calls in the whole program, every computation
+        "fwd_kernel_calls": len(calls("splash_mha_fwd")),
+        "fwd_kernel_calls_in_entry": len(calls("splash_mha_fwd", entry)),
+        "bwd_kernel_calls": len(calls("splash_mha_dkv")),
+        # what the two ``while``s carry: the earlier passes' carries,
+        # stacked a pass
+        "stacked_carries": sorted(set(re.findall(
+            r"bf16\[(\d+),4096,2048\]", text)))}
+
+
+def _adam_step(net, params, data, labels, opt_params, compute_dtype, names,
+               program=None):
     """One step of the fused train step on the chip.  -> (the objective,
-    the exit head, {name: after - before})."""
+    the exit head, {name: after - before}); ``program``, a dict, takes
+    ``_formed_again`` of the step's program."""
     import mxnet_tpu as mx
     if compute_dtype:
         os.environ["MXNET_COMPUTE_DTYPE"] = compute_dtype
@@ -67,6 +118,8 @@ def _adam_step(net, params, data, labels, opt_params, compute_dtype, names):
         mod.update()
         assert mod._exec_group.execs == []
         outs = [o.asnumpy() for o in mod.get_outputs()]
+        if program is not None:
+            program.update(_formed_again())
         exits = outs[mod._fused.exit_head]
         after, _ = mod.get_params()
         delta = {n: after[n].asnumpy() - params[n] for n in names}
@@ -75,6 +128,38 @@ def _adam_step(net, params, data, labels, opt_params, compute_dtype, names):
         os.environ.pop("MXNET_COMPUTE_DTYPE", None)
     gc.collect()
     return float(outs[0].mean()), exits / outs[0].shape[0], delta
+
+
+def test_two_passes_form_the_first_again_on_the_chip():
+    """At two passes the ``while`` of one trip is unrolled and the first
+    pass stands in one computation with the last: the loop's barrier is
+    what keeps it formed again there and not merged with its own forward
+    (XLA's CPU pipeline drops the barrier and keeps both passes,
+    ``tests/test_loop_node.py``).  Read off the chip's compiled program by
+    the one ``tanh`` of a pass: the first pass, the last ONCE, the first
+    again."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu import sym
+    from mxnet_tpu.executor import _GraphProgram
+    x = sym.Variable("x")
+    body = x + sym.Activation(sym.FullyConnected(
+        x, num_hidden=128, no_bias=True, name="fc"), act_type="tanh")
+    net = sym.MakeLoss(sym.sum_axis(
+        sym.Repeat(body, {"x": sym.Variable("data")}, 2, name="loop"),
+        axis=1))
+    prog = _GraphProgram(net, {}, None, do_mirror=False)
+    data, w = jnp.ones((256, 128)), jnp.full((128, 128), 0.01)
+
+    def step(w):
+        outs, vjp = jax.vjp(lambda w: prog.eval(
+            {"data": data, "fc_weight": w}, {}, None, True)[0], w)
+        return vjp([jnp.ones_like(o) for o in outs])[0]
+
+    text = jax.jit(step).lower(w).compile().as_text()
+    assert len(re.findall(r" while\(", text)) == 0
+    assert len(re.findall(r" tanh\(", text)) == 3, text
 
 
 def test_published_width_step_matches_reference():
@@ -175,7 +260,8 @@ def test_published_width_step_matches_reference():
         mark = time.perf_counter_ns()
         with jax.default_matmul_precision("default"):
             loss, exits, delta = _adam_step(
-                net, params, data, labels, adam, "bfloat16", names)
+                net, params, data, labels, adam, "bfloat16", names,
+                program=None if i else report.setdefault("formed_again", {}))
         report.setdefault("module_step_s", []).append(
             round((time.perf_counter_ns() - mark) / 1e9, 1))
         counters = {c: [[e["id"], e["args"]] for e in
@@ -219,10 +305,12 @@ def test_published_width_step_matches_reference():
             assert bf16["update_rel_err"][n] <= limits["update_rtol"][n], \
                 (seed, n)
         # one loop node of four passes, its body recomputed; the carry is
-        # one (4096, 2048) bfloat16 array
+        # one (4096, 2048) bfloat16 array; the last pass is kept whole, of
+        # the earlier ones their carries and nothing else
         assert bf16["loop:body"] and all(
             a["num_steps"] == 4 and a["recompute"] == 1
             and a["carry_bytes"] == 4096 * 2048 * 2
+            and a["kept_passes"] == 1
             for _, a in bf16["loop:body"])
         # every trace of the body names eight layers, every one the kernel
         assert bf16["attn:lowering"] and all(
@@ -231,6 +319,20 @@ def test_published_width_step_matches_reference():
         assert abs(sum(bf16["exit_p"]) - 1.0) < 1e-3
         np.testing.assert_allclose(bf16["exit_p"], bf16["reference_exit_p"],
                                    atol=5e-3)
+    # the backward ``while`` forms three passes again (its carries are
+    # stacked by three, nothing by four), the head's products among them.
+    # Eight layers' forward kernel in the forward ``while``, ONCE more for
+    # the last pass, which the compiler does not form again (its backward
+    # half's copy is merged with its forward), and once in the backward
+    # ``while``; the backward kernel there and for the last pass
+    again = report["formed_again"]
+    assert again["instructions"] > 0 and again["kernel_instructions"] > 0
+    assert again["head_products"] >= 1
+    # (a leading 1 is the batch's: one sequence)
+    assert again["stacked_carries"] == ["1", "3"], again
+    assert again["fwd_kernel_calls"] == 24, again
+    assert again["fwd_kernel_calls_in_entry"] == 8, again
+    assert again["bwd_kernel_calls"] == 16, again
     # float8 weights are refused by at least one limit
     assert fp8["loss_rel_err"] > limits["loss_rtol"] or any(
         fp8["adam_update_rel_err"][n] > limits["update_rtol"][n]
