@@ -209,6 +209,16 @@ mx.symbol.IdentityAttachKLSparseReg <- function(...) {
   mx.symbol.internal.create('IdentityAttachKLSparseReg', list(...))
 }
 
+#' Causal self-attention over the keys a learned indexer selects
+#' @param topk topk parameter (required)
+#' @param scale scale parameter (default 0.0)
+#' @param layer layer parameter (default -1)
+#' @param name string, node name (auto when omitted)
+#' @return MXSymbol
+mx.symbol.IndexedSelfAttention <- function(...) {
+  mx.symbol.internal.create('IndexedSelfAttention', list(...))
+}
+
 #' Kimi Delta Attention over ``(B, T, H, Dk)`` query and key, ``(B,
 #' @param layer layer parameter (default -1)
 #' @param name string, node name (auto when omitted)
@@ -234,6 +244,14 @@ mx.symbol.L2Normalization <- function(...) {
 #' @return MXSymbol
 mx.symbol.LRN <- function(...) {
   mx.symbol.internal.create('LRN', list(...))
+}
+
+#' Layer normalization over the last axis (Ba et al. 2016): ``(x -
+#' @param eps eps parameter (default 1e-05)
+#' @param name string, node name (auto when omitted)
+#' @return MXSymbol
+mx.symbol.LayerNorm <- function(...) {
+  mx.symbol.internal.create('LayerNorm', list(...))
 }
 
 #' reference leaky_relu-inl.h:328 (leaky/prelu/rrelu/elu).
@@ -346,6 +364,8 @@ mx.symbol.Reshape <- function(...) {
 #' Rotary position embedding of ``(B, T, H, Dh)`` (Su et al. 2021),
 #' @param theta theta parameter (default 10000.0)
 #' @param period period parameter (default 0)
+#' @param sections sections parameter
+#' @param with_positions with_positions parameter
 #' @param name string, node name (auto when omitted)
 #' @return MXSymbol
 mx.symbol.RotaryEmbedding <- function(...) {
@@ -856,6 +876,14 @@ mx.nd.GatedDeltaNet <- function(..., ctx = mx.cpu()) {
   mx.nd.internal.invoke('GatedDeltaNet', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
 }
 
+#' Imperative IndexedSelfAttention: 1 NDArray input(s), 1 scalar(s)
+#' @return MXNDArray
+mx.nd.IndexedSelfAttention <- function(..., ctx = mx.cpu()) {
+  a <- list(...)
+  is.nd <- vapply(a, function(x) inherits(x, 'MXNDArray'), logical(1))
+  mx.nd.internal.invoke('IndexedSelfAttention', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
+}
+
 #' Imperative KimiDeltaAttention: 7 NDArray input(s), 0 scalar(s)
 #' @return MXNDArray
 mx.nd.KimiDeltaAttention <- function(..., ctx = mx.cpu()) {
@@ -878,6 +906,14 @@ mx.nd.LRN <- function(..., ctx = mx.cpu()) {
   a <- list(...)
   is.nd <- vapply(a, function(x) inherits(x, 'MXNDArray'), logical(1))
   mx.nd.internal.invoke('LRN', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
+}
+
+#' Imperative LayerNorm: 3 NDArray input(s), 1 scalar(s)
+#' @return MXNDArray
+mx.nd.LayerNorm <- function(..., ctx = mx.cpu()) {
+  a <- list(...)
+  is.nd <- vapply(a, function(x) inherits(x, 'MXNDArray'), logical(1))
+  mx.nd.internal.invoke('LayerNorm', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
 }
 
 #' Imperative LeakyReLU: 1 NDArray input(s), 3 scalar(s)

@@ -73,7 +73,7 @@ def routed_experts(h, pre, layer, num_experts, experts_per_tok, expert_width,
 
 def gqa_attention(h, pre, layer, rows, num_heads, num_kv_heads, head_dim,
                   hidden_size, eps, rotate=lambda x: x, gated=False,
-                  head_norms=True, **mask):
+                  head_norms=True, core=None, **mask):
     """Grouped-query attention with an RMSNorm over each head's lanes of
     q and of k (none with ``head_norms`` off), ``(B*rows, D)`` ->
     ``(B*rows, D)``.  ``rotate`` places q
@@ -81,7 +81,9 @@ def gqa_attention(h, pre, layer, rows, num_heads, num_kv_heads, head_dim,
     ``CausalSelfAttention``'s (default: causal), ``gated`` multiplies the
     heads' outputs by the sigmoid of a gate before ``o_proj``: True, the
     gate is its own projection ``h Wg``; ``"query"``, it is the second
-    half of a doubled ``q_proj``, head by head ``[q | gate]``.  Scopes:
+    half of a doubled ``q_proj``, head by head ``[q | gate]``.  ``core``:
+    another core op than ``CausalSelfAttention``, a function of the
+    placed ``(q, k, v)`` that gives the heads' outputs.  Scopes:
     ``attn_proj.l<i>``, ``attn_gate.l<i>``."""
     width = num_heads * head_dim
 
@@ -101,8 +103,8 @@ def gqa_attention(h, pre, layer, rows, num_heads, num_kv_heads, head_dim,
                        for lo in (0, head_dim))
         q, k = placed(q, "q"), placed(heads("k", num_kv_heads), "k")
         v = heads("v", num_kv_heads)
-    a = sym.CausalSelfAttention(q, k, v, layer=layer, name=pre + "attn",
-                                **mask)
+    a = core(q, k, v) if core else sym.CausalSelfAttention(
+        q, k, v, layer=layer, name=pre + "attn", **mask)
     with scoped("", "attn_gate" if gated else "attn_proj", layer):
         a = sym.Reshape(a, shape=(-1, width))
         if gated:

@@ -83,6 +83,7 @@ def find_prediction_heads(symbol):
 
 NOISE_HEAD = "diffusion_noise"
 EXIT_HEAD = "loop_exit"
+DSA_HEAD = "dsa_select"
 
 
 def _find_counter_head(symbol, name):
@@ -109,6 +110,16 @@ def find_exit_head(symbol):
     exit probabilities ``p_1 .. p_R`` and their summed full-depth cross
     entropy, behind a ``BlockGrad``.  None for a symbol without one."""
     return _find_counter_head(symbol, EXIT_HEAD)
+
+
+def find_selection_head(symbol):
+    """The index of the output ``dsa_select_output``: the ``(L, B, 6)``
+    head a symbol whose attention selects its keys groups on
+    (``models.keye_vl``): a block's and sequence's rows, selected pairs,
+    causal pairs, tiles hit, causal tiles (``ops.sparse_attention.STATS``)
+    and index loss, behind a ``BlockGrad``.  None for a symbol without
+    one."""
+    return _find_counter_head(symbol, DSA_HEAD)
 
 
 class FusedTrainStep:
@@ -278,6 +289,8 @@ class FusedTrainStep:
         self.noise_head = find_noise_head(symbol)
         # a looped symbol's exit head: where the gate sends the rows
         self.exit_head = find_exit_head(symbol)
+        # a key-selecting symbol's head: what each block's selection kept
+        self.selection_head = find_selection_head(symbol)
         self.moe_stats = None
         if self.moe_blocks:
             from ..moe.stats import MoeStats
@@ -754,6 +767,21 @@ class FusedTrainStep:
         _trace.counter("loop:exit", cat="train", ce_last=sums[-1] / rows,
                        depth=sum((t + 1) * x for t, x in enumerate(p)),
                        **{"p%d" % (t + 1): x for t, x in enumerate(p)})
+
+    def note_selection(self, outs) -> None:
+        """Feed the ``dsa:select`` trace counter, one sample a step and
+        block (track ``l<i>``), from the step's selection head as the
+        metric gets it: ``rows``, ``selected_pairs`` of ``causal_pairs``,
+        ``tiles_hit`` of ``tiles_causal`` (512 x 512 causal tiles that
+        hold a selected pair), summed over the batch's sequences, and
+        ``kl``, the block's index loss (their mean).  One host read of
+        ``(L, B, 6)`` numbers the metric update before this call already
+        waited for."""
+        from ..ops.sparse_attention import STATS
+        for l, per_seq in enumerate(outs[self.selection_head].asnumpy()):
+            _trace.counter("dsa:select", cat="train", track="l%d" % l,
+                           kl=float(per_seq[:, -1].mean()),
+                           **dict(zip(STATS, per_seq[:, :-1].sum(0).tolist())))
 
     # -- compiled programs ---------------------------------------------------
     def _make_step_fn(self):

@@ -1201,15 +1201,17 @@ class Module(BaseModule):
         and what a rank's expert blocks counted of their activated lanes
         (``note_act_zeros``) under ``fit:moe_act_zeros``, and where a
         looped symbol's exit gate sent the rows (``note_loop_exit``)
-        under ``fit:loop_exit``:
+        under ``fit:loop_exit``, and what each block's learned selection
+        kept (``note_selection``) under ``fit:dsa_select``:
         nothing, and no span, where the fused step is off or the symbol
-        carries no such head; the last four only while tracing is on."""
+        carries no such head; the last five only while tracing is on."""
         fused = self._fused
         if fused is None or not self._fused_live() \
                 or not (fused.moe_load_heads or fused.prediction_heads
                         or fused.noise_head is not None
                         or fused.act_zeros_head
-                        or fused.exit_head is not None):
+                        or fused.exit_head is not None
+                        or fused.selection_head is not None):
             return
         outs = self.get_outputs() if outputs is None else outputs
         if fused.moe_load_heads:
@@ -1227,6 +1229,9 @@ class Module(BaseModule):
         if fused.exit_head is not None and _trace.enabled():
             with _trace.span("fit:loop_exit", cat="train"):
                 fused.note_loop_exit(outs)
+        if fused.selection_head is not None and _trace.enabled():
+            with _trace.span("fit:dsa_select", cat="train"):
+                fused.note_selection(outs)
 
     def _outputs_in_flight(self):
         """The overlap hook of fit() and score(): the outputs of the

@@ -143,6 +143,32 @@ def rotary_embedding(x, theta: float, period: int = 0):
     return out.astype(x.dtype)
 
 
+def sectioned_rotary(x, positions=None, theta: float = 10000.0,
+                     period: int = 0, sections=None):
+    """``rotary_embedding`` whose pairs are shared out among several
+    position axes (M-RoPE, Qwen2-VL's: temporal, height, width):
+    ``sections`` ``(n_0, n_1, ..)`` sum to ``Dh / 2``, and of a head's
+    ``Dh / 2`` frequencies the first ``n_0`` turn by ``positions[:, 0]``,
+    the next ``n_1`` by ``positions[:, 1]``, and so on; ``positions``
+    ``(B, len(sections), T)``.  Without ``positions`` every axis is the
+    row's index in its sequence, which is ``rotary_embedding`` itself."""
+    if positions is None:
+        return rotary_embedding(x, theta, period)
+    dh = x.shape[3]
+    half = dh // 2
+    inv_freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32)
+                                * 2.0 / dh))
+    axis_of = np.repeat(np.arange(len(sections)), sections)
+    pos = positions.astype(jnp.float32)[:, axis_of, :]      # (B, half, T)
+    ang = pos.transpose(0, 2, 1) * inv_freq[None, None, :]
+    cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., :half], x32[..., half:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                          axis=-1)
+    return out.astype(x.dtype)
+
+
 MASKS = ("causal", "block_diffusion", "sliding_window")
 
 
@@ -422,25 +448,75 @@ class RMSNormOp(OpDef):
         return [rms_norm(inputs[0], inputs[1], p.eps)]
 
 
+@register_op("LayerNorm", hint="layernorm")
+class LayerNormOp(OpDef):
+    """Layer normalization over the last axis (Ba et al. 2016): ``(x -
+    mean) / sqrt(var + eps) * gamma + beta``, the statistics in float32."""
+    params = [Param("eps", float, default=1e-5)]
+
+    def list_arguments(self, p):
+        return ["data", "gamma", "beta"]
+
+    def infer_shape(self, p, in_shapes):
+        d = in_shapes[0]
+        if d is None:
+            return in_shapes, [None], []
+        return [d, (d[-1],), (d[-1],)], [d], []
+
+    def forward(self, p, inputs, aux, ctx):
+        x, gamma, beta = inputs
+        x32 = x.astype(jnp.float32)
+        centred = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(centred), axis=-1, keepdims=True)
+        return [(centred * lax.rsqrt(var + p.eps)).astype(x.dtype)
+                * gamma.astype(x.dtype) + beta.astype(x.dtype)]
+
+
 @register_op("RotaryEmbedding", hint="rotary")
 class RotaryEmbeddingOp(OpDef):
     """Rotary position embedding of ``(B, T, H, Dh)`` (Su et al. 2021),
     half-split pairing, positions ``0..T-1``; with ``period`` > 0 row
     ``n`` is at position ``n mod period`` (a sequence made of copies of
     the same positions, as the block-diffusion objective's ``[noised ;
-    clean]``)."""
+    clean]``).
+
+    ``sections`` ``(n_0, n_1, ..)`` share a head's ``Dh / 2`` pairs out
+    among several position axes (M-RoPE: temporal, height, width): the
+    first ``n_0`` frequencies turn by axis 0's position, the next ``n_1``
+    by axis 1's, and so on (``sectioned_rotary``).  The positions are a
+    second input ``(B, len(sections), T)`` with ``with_positions``;
+    without it every axis is the row's index in its sequence, which is
+    plain rotary."""
     params = [Param("theta", float, default=10000.0),
-              Param("period", int, default=0)]
+              Param("period", int, default=0),
+              Param("sections", "shape"),
+              Param("with_positions", bool)]
+
+    def list_arguments(self, p):
+        return ["data", "positions"] if p.with_positions else ["data"]
 
     def infer_shape(self, p, in_shapes):
         d = in_shapes[0]
         if d is not None and (len(d) != 4 or d[3] % 2):
             raise MXNetError("RotaryEmbedding: data must be (batch, seq, "
                              "heads, even head_dim), got %r" % (d,))
-        return in_shapes, [d], []
+        sections = tuple(p.sections or ())
+        if sections and (min(sections) < 1 or p.period
+                         or (d is not None and sum(sections) * 2 != d[3])):
+            raise MXNetError("RotaryEmbedding: sections %r are the pairs "
+                             "of a head of %s lanes, axis by axis, without "
+                             "a period" % (sections, d and d[3]))
+        if not p.with_positions:
+            return in_shapes, [d], []
+        if not sections:
+            raise MXNetError("RotaryEmbedding: a positions input needs "
+                             "sections, the pairs each of its axes turns")
+        return ([d, None if d is None else (d[0], len(sections), d[1])],
+                [d], [])
 
     def forward(self, p, inputs, aux, ctx):
-        return [rotary_embedding(inputs[0], p.theta, p.period)]
+        return [sectioned_rotary(*inputs, theta=p.theta, period=p.period,
+                                 sections=p.sections)]
 
 
 @register_op("CausalSelfAttention", hint="attention")

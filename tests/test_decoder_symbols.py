@@ -1,6 +1,6 @@
 """The decoder builders' symbols, node for node (ISSUE 46, tier-1).
 
-The LM builders of ``mxnet_tpu/models`` (five then, eight now) are
+The LM builders of ``mxnet_tpu/models`` (five then, nine now) are
 assembled from one skeleton, ``models/decoder.py``.  What holds that assembly still is the
 graph each builder returns: under a fresh ``NameManager`` the symbol's
 JSON (every node's op, name, keywords, attributes and inputs, the unnamed
@@ -80,6 +80,11 @@ QWEN3_NEXT = dict(num_layers=4, hidden_size=32, full_attention_interval=4,
 OURO = dict(num_layers=2, hidden_size=32, num_heads=4, num_kv_heads=4,
             head_dim=8, mlp_width=48, vocab_size=50, seq_len=16,
             total_ut_steps=4, rope_theta=1e6, rms_eps=1e-6, exit_beta=0.1)
+KEYE = dict(num_layers=2, hidden_size=32, num_heads=4, num_kv_heads=2,
+            head_dim=8, index_heads=2, index_dim=4, topk=8, num_experts=16,
+            experts_per_tok=4, expert_width=24, vocab_size=50, seq_len=16,
+            mrope_sections=(1, 1, 2), rope_theta=1e7, rms_eps=1e-6,
+            aux_coef=0.001, experts_held=4, first_expert=4)
 WHOLE = dict(experts_held=0, first_expert=0)
 # latent attention by itself: seq_len, hidden_size, heads, kv_lora_rank,
 # qk_nope_dim, qk_rope_dim, v_head_dim, rms_eps
@@ -142,6 +147,16 @@ SYMBOLS = {
     "ouro-kept": _tiny("ouro_lm", OURO, recompute=False),
     "ouro-grouped": _tiny("ouro_lm", OURO, num_kv_heads=2),
     "ouro-wide-embedding": _tiny("ouro_lm", OURO, embed_sigma=4.0),
+    # the ninth builder, from the commit that added it (ISSUE 57): its
+    # cell, a rank's share, the whole layer, no balance heads, a
+    # positions input, the embedding's own initializer (the expert layers
+    # are marked for recomputation in every one)
+    "keye-vl-2.0-30b-a3b": _cell("keye-vl-2.0-30b-a3b"),
+    "keye-share": _tiny("keye_lm", KEYE),
+    "keye-whole": _tiny("keye_lm", KEYE, **WHOLE),
+    "keye-aux-0": _tiny("keye_lm", KEYE, aux_coef=0.0),
+    "keye-positions": _tiny("keye_lm", KEYE, positions=True),
+    "keye-wide-embedding": _tiny("keye_lm", KEYE, embed_sigma=1.0),
     # OLMoE: its load-balance heads stay on at coefficient 0
     "olmoe-tiny": _tiny("olmoe_lm", OLMOE),
     "olmoe-aux-0": _tiny("olmoe_lm", OLMOE, aux_coef=0.0),
@@ -223,6 +238,19 @@ SYMBOL_WAS = {
         "d25c2955f4329377c144aff6f552bcb7cf7ae56f513f95b566bd62bbf88e150d",
     "ouro-wide-embedding":
         "1e32bfb3be57e690901828997a7b534b564ad51ad7752defadbcae93627279b1",
+    # taken at the commit that added the builder (ISSUE 57)
+    "keye-aux-0":
+        "3f7802952bb019a6771000414b3ef2f3634497074a95fa27e4ae84ce3a94d45f",
+    "keye-positions":
+        "4037d94f8faded7ae89a1a73980939db69b25ec97e94ffec749da899209dba4f",
+    "keye-share":
+        "32539ecb44ecda162787cfb26a3d358093ae442744e32337438cdc5bd867d91f",
+    "keye-vl-2.0-30b-a3b":
+        "99be1309ec9a0b073e9146315671dc85e58b65d2fb5edd0195a5fa2bf2df08e1",
+    "keye-whole":
+        "e01e43dbaa2a055c54b06713a3b97c17c95a6be4a113fe94d976fa0a9a1e0232",
+    "keye-wide-embedding":
+        "eb834439a3fe90b9840ee600f5fba26d34a9ff58d2c29f71042b45c519c297d7",
     "olmoe-tiny":
         "af8dc705e9e7aeb26b2806967a870d607de9f4892070d6b09552c77d2034efc0",
     "olmoe-aux-0":

@@ -732,13 +732,15 @@ def _share_block(E, k, H, D, held, first, scale):
 @pytest.mark.parametrize("config,E,k,held,scale", [
     ("kimi-linear-48b-a3b", 16, 4, 4, 2.446),
     ("glm-4.7-flash", 16, 2, 2, 1.8),
-    ("sdar-30b-a3b", 128, 8, 16, None)],
-    ids=["kimi-4-ranks-of-4", "glm-8-ranks-of-2", "sdar-8-ranks-of-16"])
+    ("sdar-30b-a3b", 128, 8, 16, None),
+    ("keye-vl-2.0-30b-a3b", 128, 8, 16, None)],
+    ids=["kimi-4-ranks-of-4", "glm-8-ranks-of-2", "sdar-8-ranks-of-16",
+         "keye-8-ranks-of-16"])
 def test_the_shares_add_up_to_the_uncut_layer(config, E, k, held, scale):
     """E = 16 experts over 4 ranks of 4 (8 ranks of 2, as the GLM
     configuration's eight; 128 over 8 ranks of 16 under a renormalised
-    softmax with neither bias nor shared expert, as the SDAR
-    configuration's): each rank's output (its held experts' part plus
+    softmax with neither bias nor shared expert, as the SDAR and the Keye
+    configurations'): each rank's output (its held experts' part plus
     the shared expert), summed, with the shared expert counted once, is
     that configuration's reference layer with all experts held; and each
     rank's output is the reference given the same share."""

@@ -1,0 +1,266 @@
+"""``IndexedSelfAttention`` (``mxnet_tpu/ops/sparse_attention.py``), tier-1:
+the op against the layer's equations written out by hand with dense ``(T,
+T)`` arrays (outputs, cotangents both ways), the selection's count and
+tie rule by both k-th-value methods, the isolation of the two losses as
+EXACT zeros, ``topk >= T`` against ``CausalSelfAttention``, the TPU
+kernels interpreted at a small shape, and the two small ops the model
+brings with it (``LayerNorm``, ``RotaryEmbedding(sections=)``)."""
+import numpy as np
+import jax
+import jax.numpy as jnp
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu.ops import sparse_attention as sa
+from mxnet_tpu.ops import transformer as tr
+
+DH, HI, DI = 16, 4, 8
+
+
+def dense(q, k, v, qi, ki, w, topk, scale):
+    """The equations with every ``(T, T)`` array whole: ``(outputs, a
+    sequence's mean row loss, the selection)``."""
+    b, t, h, _ = q.shape
+    hkv = k.shape[2]
+    z = jnp.einsum("btjd,bsd->btjs", qi, ki[:, :, 0])
+    scores = (jax.nn.relu(z) * w[..., None]).sum(2) \
+        * qi.shape[3] ** -0.5 * qi.shape[2] ** -0.5
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    _, best = jax.lax.top_k(jnp.where(causal, scores, -jnp.inf),
+                            min(topk, t))
+    chosen = jax.vmap(jax.vmap(lambda row, i: row.at[i].set(True)))(
+        jnp.zeros((b, t, t), bool), best) & causal
+    chosen = jax.lax.stop_gradient(chosen)
+    kk, vv = (jnp.repeat(x, h // hkv, axis=2) for x in (k, v))
+    s = jnp.einsum("bthd,bshd->bhts", q, kk) * scale
+    a = jax.nn.softmax(jnp.where(chosen[:, None], s, -jnp.inf), axis=-1)
+    out = jnp.einsum("bhts,bshd->bthd", a, vv)
+    p = jax.lax.stop_gradient(a.mean(1))
+    log_index = jax.nn.log_softmax(jnp.where(chosen, scores, -jnp.inf), -1)
+    kl = jnp.where(chosen, jax.scipy.special.xlogy(p, p)
+                   - p * jnp.where(chosen, log_index, 0.0), 0.0).sum(-1)
+    return out, kl.mean(1), chosen
+
+
+def inputs(seed, b, t, h, hkv, dtype=np.float32, dh=DH):
+    rng = np.random.RandomState(seed)
+
+    def make(*shape):
+        return jnp.asarray(rng.randn(*shape).astype(np.float32)).astype(dtype)
+
+    return (make(b, t, h, dh), make(b, t, hkv, dh), make(b, t, hkv, dh),
+            make(b, t, HI, DI), make(b, t, 1, DI), make(b, t, HI))
+
+
+def op(topk, scale=DH ** -0.5):
+    return lambda *a: sa.indexed_attention(*a, topk=topk, scale=scale,
+                                           layer=0)
+
+
+@pytest.fixture(autouse=True)
+def small_blocks(monkeypatch):
+    """Several blocks of rows at these lengths: 64 = 2 x 32, 200 = 8 x 25."""
+    monkeypatch.setattr(sa, "DSA_BLOCK_Q", 32)
+
+
+@pytest.mark.parametrize("t", [64, 200])
+@pytest.mark.parametrize("topk", [16, 256])
+@pytest.mark.parametrize("h,hkv", [(8, 1), (4, 4)], ids=["groups-of-8",
+                                                         "groups-of-1"])
+def test_the_op_is_the_equations_written_out(t, topk, h, hkv):
+    """Two sequences a batch (nothing is selected across them): the
+    outputs, the row losses, the counter's numbers, and the cotangents of
+    q, k, v from the first output and of qI, kI, w from the second."""
+    args = inputs(t + topk + h, 2, t, h, hkv)
+    scale = DH ** -0.5
+    out, loss, stats = op(topk)(*args)
+    want_out, want_loss, chosen = dense(*args, topk, scale)
+    assert np.abs(out - want_out).max() < 2e-5
+    assert np.allclose(loss, want_loss, rtol=1e-5, atol=1e-6)
+    kept = sum(min(i + 1, topk) for i in range(t))
+    assert np.array_equal(np.asarray(chosen.sum(-1)),
+                          np.tile(np.minimum(np.arange(t) + 1, topk), (2, 1)))
+    tiles = -(-t // min(sa.DSA_TILE, t))
+    assert np.array_equal(np.asarray(stats), np.tile(np.float32(
+        [t, kept, t * (t + 1) // 2, tiles * (tiles + 1) // 2,
+         tiles * (tiles + 1) // 2]), (2, 1)))
+    rng = np.random.RandomState(1)
+    g_out = jnp.asarray(rng.randn(*out.shape).astype(np.float32))
+    g_loss = jnp.asarray(rng.randn(2).astype(np.float32))
+    for pick, cot, live in ((0, g_out, (0, 1, 2)), (1, g_loss, (3, 4, 5))):
+        got = jax.grad(lambda *a: (op(topk)(*a)[pick] * cot).sum(),
+                       argnums=tuple(range(6)))(*args)
+        want = jax.grad(
+            lambda *a: (dense(*a, topk, scale)[pick] * cot).sum(),
+            argnums=tuple(range(6)))(*args)
+        for i, (a, b) in enumerate(zip(got, want)):
+            if i in live:
+                assert np.abs(b).max() > 1e-4
+                assert np.abs(a - b).max() <= 2e-5 * max(1.0,
+                                                         np.abs(b).max())
+            else:
+                # the isolation is EXACT, both ways
+                assert not np.asarray(a).any() and not np.asarray(b).any()
+
+
+def test_a_selection_of_every_key_is_causal_attention():
+    args = inputs(3, 2, 64, 8, 2)
+    scale = DH ** -0.5
+    out, _, stats = op(64)(*args)
+    want = tr.causal_attention(*args[:3], scale)
+    assert np.abs(out - want).max() < 2e-6
+    assert np.array_equal(np.asarray(stats[:, 1]), np.asarray(stats[:, 2]))
+    got = jax.grad(lambda q, k, v: op(1000)(q, k, v, *args[3:])[0].sum(),
+                   argnums=(0, 1, 2))(*args[:3])
+    ref = jax.grad(lambda q, k, v: tr.causal_attention(q, k, v,
+                                                       scale).sum(),
+                   argnums=(0, 1, 2))(*args[:3])
+    for a, b in zip(got, ref):
+        assert np.abs(a - b).max() < 2e-5
+
+
+def test_every_row_selects_its_count_and_ties_go_to_the_earlier_key():
+    """Scores on a grid of nine values, so that most rows have keys level
+    with their k-th: exactly ``min(t + 1, topk)`` selected, and among
+    level keys the earliest, as ``lax.top_k`` orders them.  ``w = 0``
+    (every score 0.0 or -0.0) selects the first ``topk`` keys."""
+    t, topk = 96, 20
+    rng = np.random.RandomState(7)
+    scores = jnp.asarray(rng.randint(-4, 5, (t, t)).astype(np.float32) / 4)
+    scores = scores.at[5].set(-0.0).at[6, ::2].set(-0.0)
+    pos = jnp.arange(t)
+    got = np.asarray(sa.select_keys(scores, pos, topk))
+    causal = np.tril(np.ones((t, t), bool))
+    _, best = jax.lax.top_k(jnp.where(causal, scores, -jnp.inf), topk)
+    want = np.zeros((t, t), bool)
+    want[np.arange(t)[:, None], np.asarray(best)] = True
+    want &= causal
+    assert np.array_equal(got, want)
+    assert np.array_equal(got.sum(1), np.minimum(np.arange(t) + 1, topk))
+    # a block of rows from the middle of a sequence, no tie at all
+    smooth = jnp.asarray(rng.randn(32, t).astype(np.float32))
+    part = np.asarray(sa.select_keys(smooth, 40 + jnp.arange(32), topk))
+    assert np.array_equal(part.sum(1), np.full(32, topk))
+    assert not (part & ~causal[40:72]).any()
+    args = inputs(11, 1, 64, 4, 2)
+    zero_w = args[:5] + (jnp.zeros_like(args[5]),)
+    out, _, _ = op(16)(*zero_w)
+    want_out, _, chosen = dense(*zero_w, 16, DH ** -0.5)
+    assert np.abs(out - want_out).max() < 2e-5
+    first = np.tril(np.ones((64, 64), bool)) & (np.arange(64)[None] < 16)
+    assert np.array_equal(np.asarray(chosen[0]), first)
+
+
+def test_the_ordered_image_keeps_the_order_of_floats():
+    x = jnp.asarray([-jnp.inf, -3.5, -1e-30, -0.0, 0.0, 1e-30, 2.0,
+                     jnp.inf], jnp.float32)
+    image = np.asarray(sa._ordered(x)).astype(np.int64)
+    assert image[3] == image[4] and image.min() > 0
+    assert (np.diff(np.delete(image, 3)) > 0).all()
+    keys = jnp.asarray(np.random.RandomState(0).randint(
+        1, 2 ** 32 - 1, (5, 40), dtype=np.int64).astype(np.uint32))
+    want = jnp.asarray([1, 7, 40, 13, 2])
+    got = np.asarray(sa._kth_largest(keys, want))
+    ranked = -np.sort(-np.asarray(keys).astype(np.int64), axis=1)
+    assert np.array_equal(got, ranked[np.arange(5), np.asarray(want) - 1])
+
+
+def test_the_kernel_lowering_interpreted_is_the_plain_blocks(monkeypatch):
+    """The library's splash-attention kernels under the selection as a
+    dynamic mask, interpreted on the CPU at (256, 4 heads over 2, 128) in
+    tiles of 128: forward, log-sum-exp and the three cotangents against
+    the plain blocks on the same bfloat16 inputs."""
+    monkeypatch.setattr(tr, "ATTN_KERNEL_BLOCK", 128)
+    monkeypatch.setattr(tr, "ATTN_KERNEL_SLICE", 128)
+    q, k, v, qi, ki, w = (x[0] for x in inputs(5, 1, 256, 4, 2,
+                                               jnp.bfloat16, dh=128))
+    assert tr._kernel_takes(q[None], k[None], v[None])
+    mask = sa._select(qi, ki[:, 0], w, 48, None)
+    assert int(mask.sum()) == sum(min(i + 1, 48) for i in range(256))
+    qs = q * jnp.bfloat16(128 ** -0.5)
+    out, lse = sa._attend_kernel(qs, k, v, mask, interpret=True)
+    want, want_lse = sa._attend_plain(qs, k, v, mask)
+    f32 = jnp.float32
+    assert np.abs(out.astype(f32) - want.astype(f32)).max() < 0.03
+    assert np.abs(lse - want_lse).max() < 1e-3
+    g = inputs(6, 1, 256, 4, 2, jnp.bfloat16, dh=128)[0][0]
+    got = sa._attend_kernel_bwd(qs, k, v, mask, out, lse, g, interpret=True)
+    ref = sa._attend_plain_bwd(qs, k, v, mask, want, want_lse, g)
+    for a, b in zip(got, ref):
+        assert a.dtype == b.dtype == jnp.bfloat16
+        err = jnp.linalg.norm((a.astype(f32) - b.astype(f32)).ravel())
+        assert err < 0.02 * jnp.linalg.norm(b.astype(f32).ravel())
+
+
+def test_the_op_node_its_shapes_and_its_counter():
+    q, k, v, qi, ki, w = (mx.sym.Variable(n) for n in
+                          ("q", "k", "v", "qi", "ki", "w"))
+    node = mx.sym.IndexedSelfAttention(q, k, v, qi, ki, w, topk=8, layer=3,
+                                       name="attn")
+    assert node.list_outputs() == ["attn_output", "attn_index_loss",
+                                   "attn_selection"]
+    args, outs, _ = node.infer_shape(q=(2, 32, 4, 8), k=(2, 32, 2, 8),
+                                     v=(2, 32, 2, 8), qi=(2, 32, 3, 4))
+    assert args[4:] == [(2, 32, 1, 4), (2, 32, 3)]
+    assert outs == [(2, 32, 4, 8), (2,), (2, 5)]
+    with pytest.raises(mx.base.MXNetError):
+        mx.sym.IndexedSelfAttention(q, k, v, qi, ki, w, topk=0).infer_shape(
+            q=(2, 32, 4, 8))
+    with pytest.raises(mx.base.MXNetError):
+        node.infer_shape(q=(2, 32, 4, 8), k=(2, 32, 3, 8), v=(2, 32, 3, 8),
+                         qi=(2, 32, 3, 4))
+    was = mx.trace.enabled()
+    mx.trace.set_enabled(True)
+    try:
+        op(8)(*inputs(2, 1, 32, 4, 2))
+        events = [e for e in mx.trace.counter_events(["dsa:lowering"])
+                  if e["id"] == "float32[1, 32, 4, 16]/kv2/top8"]
+    finally:
+        mx.trace.reset()
+        mx.trace.set_enabled(was)
+    assert events and events[-1]["args"] == {"kernel": 0, "plain": 1}
+
+
+def test_layer_norm_is_its_equation():
+    rng = np.random.RandomState(2)
+    x = rng.randn(6, 10).astype(np.float32)
+    gamma, beta = (rng.randn(10).astype(np.float32) for _ in range(2))
+    net = mx.sym.LayerNorm(mx.sym.Variable("x"), eps=1e-6, name="ln")
+    assert net.list_arguments() == ["x", "ln_gamma", "ln_beta"]
+    exe = net.simple_bind(mx.cpu(), x=(6, 10))
+    for n, a in (("x", x), ("ln_gamma", gamma), ("ln_beta", beta)):
+        exe.arg_dict[n][:] = a
+    exe.forward(is_train=False)
+    c = x - x.mean(-1, keepdims=True)
+    want = c / np.sqrt((c * c).mean(-1, keepdims=True) + 1e-6) * gamma + beta
+    assert np.abs(exe.outputs[0].asnumpy() - want).max() < 1e-5
+
+
+def test_rotary_sections_with_equal_axes_are_todays_op():
+    """No positions: the op as it was, whatever the sections; equal axes
+    as an input: the same numbers; sections that are not the head's
+    pairs, or positions without sections, are refused."""
+    rng = np.random.RandomState(4)
+    x = jnp.asarray(rng.randn(2, 24, 3, 16).astype(np.float32))
+    plain = tr.rotary_embedding(x, 1e4)
+    assert np.array_equal(tr.sectioned_rotary(x, theta=1e4,
+                                              sections=(2, 3, 3)), plain)
+    rows = jnp.broadcast_to(jnp.arange(24.0), (2, 3, 24))
+    assert np.abs(tr.sectioned_rotary(x, rows, theta=1e4,
+                                      sections=(2, 3, 3)) - plain).max() < 1e-6
+    data, pos = mx.sym.Variable("data"), mx.sym.Variable("pos")
+    old = mx.sym.RotaryEmbedding(data, theta=1e4, name="r")
+    assert "sections" not in old.tojson() and "positions" not in old.tojson()
+    assert old.list_arguments() == ["data"]
+    new = mx.sym.RotaryEmbedding(data, positions=pos, with_positions=True,
+                                 theta=1e4, sections=(2, 3, 3), name="r")
+    assert new.list_arguments() == ["data", "pos"]
+    assert new.infer_shape(data=(2, 24, 3, 16))[0] == [(2, 24, 3, 16),
+                                                       (2, 3, 24)]
+    for bad in (dict(sections=(2, 3, 4)), dict(sections=(8,), period=4)):
+        with pytest.raises(mx.base.MXNetError):
+            mx.sym.RotaryEmbedding(data, theta=1e4, **bad).infer_shape(
+                data=(2, 24, 3, 16))
+    with pytest.raises(mx.base.MXNetError):
+        mx.sym.RotaryEmbedding(data, positions=pos, with_positions=True,
+                               theta=1e4).infer_shape(data=(2, 24, 3, 16))
