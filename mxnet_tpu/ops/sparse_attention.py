@@ -28,9 +28,14 @@ or gathers ``(T, topk, ..)``:
   ``(T, T)``, the one thing kept of it.
 * ``attend``: softmax attention under that mask.  Two lowerings, chosen
   as ``causal_attention`` chooses (``_kernel_takes``): the plain query
-  blocks on every platform; JAX's Pallas splash attention with the mask
-  as DATA (tiles the selection leaves empty are never visited) where the
-  program is lowered for a TPU.  ``dsa:lowering`` records which.
+  blocks on every platform; two kernels where the program is lowered for
+  a TPU.  Forward, this repo's own (``ops/selected_attention.py``): a
+  grid step holds the query heads of one key/value head and reads ONE
+  tile of the selection for all of them, as int8, a byte a pair.
+  Backward, JAX's Pallas splash attention: its fused kernel with the
+  mask as DATA (a loaded int32 block a visit and head, which five
+  matmuls hide), fed the forward's log-sum-exp.  ``dsa:lowering`` records
+  which lowering and the heads a loaded mask tile serves.
 * ``target``: the heads' probabilities formed again from the saved
   log-sum-exp a block of rows at a time, summed over the heads, against
   the scores formed again: the row losses and, while the op is being
@@ -54,6 +59,7 @@ from .. import trace
 from ..base import MXNetError
 from .pallas_kernels import _kernel_on_tpu
 from .registry import OpDef, Param, register_op
+from .selected_attention import forward_tiles, selected_attention_fwd
 from .transformer import _kernel_takes, _kernel_tiles, layer_scope
 
 __all__ = ["indexed_attention", "indexer_scores", "select_keys"]
@@ -220,17 +226,14 @@ def _attend_plain(q, k, v, mask):
         lse.transpose(1, 0, 2).reshape(h, t)
 
 
-def _kernel_info(mask, tile: int, dkv: bool):
-    """The library's description of one sequence's selection, one head's
-    for every head (the kernels read a one-head ``MaskInfo`` at head 0):
-    the forward kernel's, or the fused backward kernel's (``dkv``)."""
+def _kernel_info(mask, tile: int):
+    """The library's description of one sequence's selection for its
+    fused backward kernel, one head's for every head (the kernel reads a
+    one-head ``MaskInfo`` at head 0)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_mask_info as mi)
-    if dkv:
-        info = mi.process_dynamic_mask_dkv(mask[None], (tile, tile),
-                                           shrink_grid=False)[0]
-    else:
-        info = mi.process_dynamic_mask(mask[None], (tile, tile))[0]
+    info = mi.process_dynamic_mask_dkv(mask[None], (tile, tile),
+                                       shrink_grid=False)[0]
     return info._replace(partial_mask_blocks=info.partial_mask_blocks
                          .reshape(-1, tile, tile))
 
@@ -246,20 +249,11 @@ def _kernel_sizes(t: int):
 
 
 def _attend_kernel(q, k, v, mask, interpret: bool = False):
-    """``_attend_plain``'s TPU lowering: the library's splash-attention
-    forward kernel with the selection as a dynamic mask, one sequence as
-    ``(H, T, Dh)`` against ``(Hkv, T, Dh)``."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as sk)
-    tile, sizes = _kernel_sizes(q.shape[0])
+    """``_attend_plain``'s TPU lowering: this repo's forward kernel
+    (``ops/selected_attention.py``), which reads a tile of the selection
+    once for a group of query heads, a byte a pair."""
     with jax.default_matmul_precision("default"):
-        out, (lse,) = sk._splash_attention_forward(
-            _kernel_info(mask, tile, False), *(x.transpose(1, 0, 2)
-                                            for x in (q, k, v)),
-            None, None, mask_value=sk.DEFAULT_MASK_VALUE, is_mqa=False,
-            block_sizes=sizes, residual_checkpoint_name=None,
-            save_residuals=True, mask_function=None, interpret=interpret)
-    return out.transpose(1, 0, 2), lse
+        return selected_attention_fwd(q, k, v, mask, interpret)
 
 
 def _attend_kernel_bwd(q, k, v, mask, out, lse, g, interpret: bool = False):
@@ -270,7 +264,7 @@ def _attend_kernel_bwd(q, k, v, mask, out, lse, g, interpret: bool = False):
     tile, sizes = _kernel_sizes(q.shape[0])
     heads = tuple(x.transpose(1, 0, 2) for x in (q, k, v))
     res = heads + (None, None, out.transpose(1, 0, 2), lse, None,
-                   _kernel_info(mask, tile, True))
+                   _kernel_info(mask, tile))
     with jax.default_matmul_precision("default"):
         grads = sk._splash_attention_bwd(
             False, sk.DEFAULT_MASK_VALUE, False, sizes, None, None, None,
@@ -423,19 +417,24 @@ def indexed_attention(q, k, v, qi, ki, w, topk: int, scale: float,
     heads' outputs ``(B, T, H, Dv)``, each sequence's mean row loss
     ``(B,)`` float32 and the selections' STATS ``(B, 5)`` float32.
     Each trace records the attend pass's lowering as ``dsa:lowering``
-    (``kernel`` 1: splash attention under the dynamic mask where the
-    program is lowered for a TPU; ``plain`` 1: the plain blocks
-    everywhere); the track names dtype, shape, ``/kv<Hkv>`` and
-    ``/top<topk>``."""
+    (``kernel`` 1: the kernels where the program is lowered for a TPU,
+    with ``heads_a_mask_tile`` the query heads that the forward kernel
+    serves from one loaded tile of the selection; ``plain`` 1: the plain
+    blocks everywhere, ``heads_a_mask_tile`` 0); the track names dtype,
+    shape, ``/kv<Hkv>`` and ``/top<topk>``."""
     h, hkv = q.shape[2], k.shape[2]
     if h % hkv or v.shape[2] != hkv or ki.shape[2] != 1:
         raise MXNetError("indexed attention: %d query heads over %d key and "
                          "%d value heads, %d indexer key heads (one)"
                          % (h, hkv, v.shape[2], ki.shape[2]))
-    kernel = _kernel_takes(q, k, v)
+    # the forward kernel reads a head's lanes out of ``(T, H * Dh)`` rows
+    kernel = _kernel_takes(q, k, v) and q.shape[3] % 128 == 0
+    heads = forward_tiles(q.shape[1], h // hkv,
+                          max(q.shape[3], v.shape[3]))[0] if kernel else 0
     trace.counter("dsa:lowering", cat="ops", track="%s%s%s/top%d" % (
         q.dtype.name, list(q.shape), "" if hkv == h else "/kv%d" % hkv,
-        topk), kernel=int(kernel), plain=int(not kernel))
+        topk), kernel=int(kernel), plain=int(not kernel),
+        heads_a_mask_tile=heads)
     return _indexed_attention(q, k, v, qi, ki, w, int(topk), float(scale),
                               layer, kernel)
 
@@ -460,7 +459,12 @@ class IndexedSelfAttentionOp(OpDef):
     causal tiles.  ``output``'s gradient reaches query, key and value
     only, ``index_loss``'s the indexer's three inputs only; the
     selection and the target take none.  ``scale`` 0 means ``Dh**-0.5``;
-    ``layer`` names the trace scopes ``dsa_*.l<layer>``."""
+    ``layer`` names the trace scopes ``dsa_*.l<layer>``.  The attend pass
+    of a program lowered for a TPU (bfloat16, heads of whole 128 lanes,
+    ``T`` in whole tiles) runs two kernels: forward this repo's
+    ``splash_mha_fwd_selected`` (``ops/selected_attention.py``), backward
+    the library's fused splash-attention kernel under the selection as a
+    dynamic mask; everything else runs the plain blocks both ways."""
     params = [Param("topk", int, required=True),
               Param("scale", float, default=0.0),
               Param("layer", int, default=-1)]

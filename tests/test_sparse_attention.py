@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import pytest
 
 import mxnet_tpu as mx
+from mxnet_tpu.ops import selected_attention as sel
 from mxnet_tpu.ops import sparse_attention as sa
 from mxnet_tpu.ops import transformer as tr
 
@@ -165,31 +166,87 @@ def test_the_ordered_image_keeps_the_order_of_floats():
     assert np.array_equal(got, ranked[np.arange(5), np.asarray(want) - 1])
 
 
-def test_the_kernel_lowering_interpreted_is_the_plain_blocks(monkeypatch):
-    """The library's splash-attention kernels under the selection as a
-    dynamic mask, interpreted on the CPU at (256, 4 heads over 2, 128) in
-    tiles of 128: forward, log-sum-exp and the three cotangents against
-    the plain blocks on the same bfloat16 inputs."""
-    monkeypatch.setattr(tr, "ATTN_KERNEL_BLOCK", 128)
-    monkeypatch.setattr(tr, "ATTN_KERNEL_SLICE", 128)
-    q, k, v, qi, ki, w = (x[0] for x in inputs(5, 1, 256, 4, 2,
+def _rel(a, b):
+    a, b = (jnp.asarray(x, jnp.float32).ravel() for x in (a, b))
+    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+
+def _both_kernels_against_the_plain_blocks(seed, t, h, hkv, lone_row=None):
+    """This repo's forward kernel and, fed ITS log-sum-exp, the library's
+    fused backward kernel under a top-48 selection as a dynamic mask,
+    both interpreted on the CPU: outputs, log-sum-exp and the three
+    cotangents against the plain blocks on the same bfloat16 inputs of
+    128-lane heads.  ``lone_row`` selects key 3 alone."""
+    q, k, v, qi, ki, w = (x[0] for x in inputs(seed, 1, t, h, hkv,
                                                jnp.bfloat16, dh=128))
     assert tr._kernel_takes(q[None], k[None], v[None])
     mask = sa._select(qi, ki[:, 0], w, 48, None)
-    assert int(mask.sum()) == sum(min(i + 1, 48) for i in range(256))
+    assert int(mask.sum()) == sum(min(i + 1, 48) for i in range(t))
+    if lone_row is not None:
+        mask = mask.at[lone_row].set(False).at[lone_row, 3].set(True)
     qs = q * jnp.bfloat16(128 ** -0.5)
     out, lse = sa._attend_kernel(qs, k, v, mask, interpret=True)
     want, want_lse = sa._attend_plain(qs, k, v, mask)
+    assert out.dtype == want.dtype and out.shape == want.shape == (t, h, 128)
+    assert lse.dtype == jnp.float32 and lse.shape == (h, t)
     f32 = jnp.float32
     assert np.abs(out.astype(f32) - want.astype(f32)).max() < 0.03
     assert np.abs(lse - want_lse).max() < 1e-3
-    g = inputs(6, 1, 256, 4, 2, jnp.bfloat16, dh=128)[0][0]
+    if lone_row is not None:
+        # a row of one key gives that key's value row, whatever its score
+        assert np.array_equal(
+            np.asarray(out[lone_row].astype(f32)),
+            np.asarray(jnp.repeat(v[3], h // hkv, axis=0).astype(f32)))
+    g = inputs(6, 1, t, h, hkv, jnp.bfloat16, dh=128)[0][0]
     got = sa._attend_kernel_bwd(qs, k, v, mask, out, lse, g, interpret=True)
     ref = sa._attend_plain_bwd(qs, k, v, mask, want, want_lse, g)
     for a, b in zip(got, ref):
         assert a.dtype == b.dtype == jnp.bfloat16
-        err = jnp.linalg.norm((a.astype(f32) - b.astype(f32)).ravel())
-        assert err < 0.02 * jnp.linalg.norm(b.astype(f32).ravel())
+        assert _rel(a, b) < 0.02
+
+
+@pytest.mark.parametrize("h,hkv,rows,tiles", [
+    (2, 2, 1024, (1, 256, 256, 128)), (4, 2, 1024, (2, 256, 256, 128)),
+    (8, 1, 1024, (8, 128, 256, 128)), (8, 1, 512, (4, 128, 256, 128))],
+    ids=["groups-of-1", "groups-of-2", "groups-of-8",
+         "groups-of-8-four-a-step"])
+def test_the_forward_kernel_interpreted_is_the_plain_blocks(
+        monkeypatch, h, hkv, rows, tiles):
+    """``selected_attention_fwd`` at 768 rows: three key tiles of 256 read
+    128 at a time (the diagonal tile runs only the pieces its rows
+    reach), a step's heads under ONE int8 tile of the selection, one row
+    that selects a single key of the first tile."""
+    monkeypatch.setattr(sel, "ROWS", rows)
+    monkeypatch.setattr(sel, "BLOCK_KV", 256)
+    monkeypatch.setattr(sel, "PIECE", 128)
+    monkeypatch.setattr(tr, "ATTN_KERNEL_BLOCK", 256)
+    monkeypatch.setattr(tr, "ATTN_KERNEL_SLICE", 128)
+    assert sel.forward_tiles(768, h // hkv, 128) == tiles
+    _both_kernels_against_the_plain_blocks(h, 768, h, hkv, lone_row=700)
+
+
+def test_the_forward_kernels_tiles_follow_the_shapes():
+    """At the Keye cell's shape a step holds the 8 heads of a key/value
+    head, 512 rows each, against 512 keys read 256 at a time; a group
+    wider than a step's rows is split, wider heads take fewer rows, and
+    every tile is a whole divisor in whole 128s."""
+    assert sel.forward_tiles(8192, 8, 128) == (8, 512, 512, 256)
+    assert sel.forward_tiles(8192, 1, 128) == (1, 512, 512, 256)
+    assert sel.forward_tiles(8192, 128, 128) == (32, 128, 512, 256)
+    assert sel.forward_tiles(8192, 8, 256) == (8, 256, 512, 256)
+    assert sel.forward_tiles(384, 4, 128) == (4, 384, 384, 128)
+    assert sel.forward_tiles(2048, 3, 128) == (3, 512, 512, 256)
+
+
+def test_the_kernel_lowering_interpreted_is_the_plain_blocks(monkeypatch):
+    """Both kernels at (256, 4 heads over 2, 128) in tiles of 128, two
+    heads a mask tile."""
+    monkeypatch.setattr(tr, "ATTN_KERNEL_BLOCK", 128)
+    monkeypatch.setattr(tr, "ATTN_KERNEL_SLICE", 128)
+    monkeypatch.setattr(sel, "ROWS", 256)
+    monkeypatch.setattr(sel, "BLOCK_KV", 128)
+    assert sel.forward_tiles(256, 2, 128) == (2, 128, 128, 128)
+    _both_kernels_against_the_plain_blocks(5, 256, 4, 2)
 
 
 def test_the_op_node_its_shapes_and_its_counter():
@@ -218,7 +275,20 @@ def test_the_op_node_its_shapes_and_its_counter():
     finally:
         mx.trace.reset()
         mx.trace.set_enabled(was)
-    assert events and events[-1]["args"] == {"kernel": 0, "plain": 1}
+    assert events and events[-1]["args"] == {"kernel": 0, "plain": 1,
+                                             "heads_a_mask_tile": 0}
+    # what the kernels take: the group a mask tile serves beside them
+    mx.trace.set_enabled(True)
+    try:
+        jax.eval_shape(op(8, 128 ** -0.5), *inputs(2, 1, 256, 8, 2,
+                                                   jnp.bfloat16, dh=128))
+        events = mx.trace.counter_events(["dsa:lowering"])
+    finally:
+        mx.trace.reset()
+        mx.trace.set_enabled(was)
+    assert [(e["id"], e["args"]) for e in events] == [
+        ("bfloat16[1, 256, 8, 128]/kv2/top8",
+         {"kernel": 1, "plain": 0, "heads_a_mask_tile": 4})]
 
 
 def test_layer_norm_is_its_equation():

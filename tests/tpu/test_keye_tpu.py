@@ -20,13 +20,19 @@ The second holds ``IndexedSelfAttention``'s TPU lowering against its
 plain lowering at ``(1, 2048, 32, 128)`` over 4 key/value heads under a
 16 x 64 indexer's top-512 (the forward, the index loss and both
 cotangents), and at the cell's ``(1, 8192, 32, 128)`` under the top-2048
-the three passes' times in isolation with the k-th value by either
-method.
+the forward kernel against the plain blocks, the three passes' times in
+isolation with the k-th value by either method, and three forward
+attend passes side by side (PR 58): the library's splash attention under
+the selection as a dynamic mask (the op's forward until PR 58), the
+library's under a static causal mask (the floor of a kernel that visits
+every causal tile), and the op's own forward kernel, each as a jitted
+call and as its ``splash_mha*`` operation's time in a device trace.
 """
 import gc
 import json
 import os
 import sys
+import tempfile
 import time
 
 import numpy as np
@@ -219,7 +225,7 @@ def test_published_width_step_matches_reference():
         # one op a layer, every one the kernel lowering
         assert len(bf16["dsa_lowering"]) == kw["num_layers"]
         for track, args in bf16["dsa_lowering"]:
-            assert args == {"kernel": 1, "plain": 0}
+            assert args == {"kernel": 1, "plain": 0, "heads_a_mask_tile": 8}
             assert track == "bfloat16[1, 8192, 32, 128]/kv4/top2048"
     # float8 weights are refused by at least one limit
     assert fp8["loss_rel_err"] > limits["loss_rtol"] or any(
@@ -230,6 +236,62 @@ def test_published_width_step_matches_reference():
     assert np.allclose(f32["index_loss"], f32["reference_index_loss"],
                        rtol=1e-3)
     assert max(f32["update_rel_err"].values()) <= 0.1, f32
+
+
+def _three_forwards(ms, attend, qs, k, v, mask):
+    """ms a layer of three forward attend passes at the cell's shape,
+    ``{name: {"call": the jitted call, "kernel": its splash_mha*
+    operations in a device trace}}``: the op's forward kernel
+    (``attend``), and the library's splash attention under the selection
+    as a dynamic mask and under a static causal mask."""
+    import jax
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm,
+        splash_attention_mask_info as mi)
+    from mxnet_tpu.ops import sparse_attention as sa
+    sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+    import trace_reduce
+    t, h = qs.shape[:2]
+    tile, sizes = sa._kernel_sizes(t)
+
+    def heads_major(fn, *operands):
+        with jax.default_matmul_precision("default"):
+            out, (lse,) = fn(*(x.transpose(1, 0, 2) for x in operands))
+        return out.transpose(1, 0, 2), lse
+
+    def dynamic(q, k, v, mask):
+        info = mi.process_dynamic_mask(mask[None], (tile, tile))[0]
+        info = info._replace(partial_mask_blocks=info.partial_mask_blocks
+                             .reshape(-1, tile, tile))
+        return heads_major(lambda q, k, v: sk._splash_attention_forward(
+            info, q, k, v, None, None, mask_value=sk.DEFAULT_MASK_VALUE,
+            is_mqa=False, block_sizes=sizes, residual_checkpoint_name=None,
+            save_residuals=True, mask_function=None), q, k, v)
+
+    causal = sk.make_splash_mha_single_device(
+        sm.MultiHeadMask([sm.CausalMask((t, t))] * h), block_sizes=sizes,
+        save_residuals=True)
+
+    def kernel_ms(fn, *args):
+        with tempfile.TemporaryDirectory() as d:
+            with jax.profiler.trace(d):
+                for _ in range(3):
+                    out = fn(*args)
+                jax.block_until_ready(out)
+            devices, _ = trace_reduce.load_xplane(trace_reduce.find_xplane(d))
+        return sum(ns for name, _, ns in sorted(devices.items())[0][1]
+                   if name.startswith("splash_mha")) / 3e6
+
+    forwards = {}
+    for name, fn, args in (
+            ("library_dynamic_mask", jax.jit(dynamic), (qs, k, v, mask)),
+            ("library_causal_mask",
+             jax.jit(lambda q, k, v: heads_major(causal, q, k, v)),
+             (qs, k, v)),
+            ("selected", attend, (qs, k, v, mask))):
+        forwards[name] = {"call": ms(fn, *args),
+                          "kernel": kernel_ms(fn, *args)}
+    return forwards
 
 
 def test_the_op_alone_both_lowerings_and_the_passes_times():
@@ -267,7 +329,8 @@ def test_the_op_alone_both_lowerings_and_the_passes_times():
     short = [x[:, :2048] for x in (q, k, v, qi, ki, w)]
     kernel, plain = both_passes(True, 512), both_passes(False, 512)
     text = kernel.lower(*short).compile().as_text()
-    assert "tpu_custom_call" in text and "splash_mha" in text
+    assert "tpu_custom_call" in text and "splash_mha_fwd_selected" in text
+    assert "splash_mha_dkv" in text and "splash_mha_fwd_residuals" not in text
     assert "tpu_custom_call" not in plain.lower(*short).compile().as_text()
     got = [np.asarray(x, np.float32) for x in kernel(*short)]
     want = [np.asarray(x, np.float32) for x in plain(*short)]
@@ -318,7 +381,16 @@ def test_the_op_alone_both_lowerings_and_the_passes_times():
     qs = one[0] * jnp.bfloat16(scale)
     attend = jax.jit(sa._attend_kernel)
     out, lse = attend(qs, one[1], one[2], mask)
+    plain_out, plain_lse = jax.jit(sa._attend_plain)(qs, one[1], one[2], mask)
+    for n, a, b in (("output", out, plain_out), ("lse", lse, plain_lse)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        report["max_err_share"]["forward_8192_" + n] = float(
+            np.abs(a - b).max() / np.abs(b).max())
+        report["l2_err"]["forward_8192_" + n] = _rel(a, b)
+    del plain_out, plain_lse
     times["attend_kernel_forward"] = ms(attend, qs, one[1], one[2], mask)
+    report["forward_ms_a_layer"] = _three_forwards(
+        ms, attend, qs, one[1], one[2], mask)
     times["attend_kernel_backward"] = ms(
         jax.jit(sa._attend_kernel_bwd), qs, one[1], one[2], mask, out, lse,
         g[0].astype(jnp.bfloat16))
@@ -334,9 +406,16 @@ def test_the_op_alone_both_lowerings_and_the_passes_times():
     with open(os.path.join(out_dir, "keye_op_parity.json"), "w") as f:
         json.dump(report, f, indent=1)
     print("\nKEYE_OP_PARITY " + json.dumps(report), flush=True)
-    for n in ("output", "d_q", "d_k", "d_v"):
+    for n in ("output", "d_q", "d_k", "d_v", "forward_8192_output"):
         assert report["max_err_share"][n] <= ATTN_MAX_ERR_SHARE, report
         assert report["l2_err"][n] <= ATTN_L2_ERR, report
+    assert report["l2_err"]["forward_8192_lse"] <= 1e-5, report
+    # the op's own forward kernel against the library's two: under the
+    # dynamic mask's by 2 ms a layer, as a call and as an operation
+    forwards = report["forward_ms_a_layer"]
+    for kind in ("call", "kernel"):
+        assert forwards["selected"][kind] + 2.0 \
+            <= forwards["library_dynamic_mask"][kind], forwards
     # the indexer's side reads the log-sum-exp of whichever lowering ran
     assert report["l2_err"]["index_loss"] <= 1e-3, report
     for n in ("d_qi", "d_ki", "d_w"):
