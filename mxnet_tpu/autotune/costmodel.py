@@ -12,9 +12,9 @@ implementation; no forked scorers.
 Two layers:
 
 * :func:`analytic_cost` — a deterministic roofline prior over the
-  feature vector (compute / HBM / interconnect terms from the
-  ``MXNET_PEAK_TFLOPS`` / ``MXNET_HBM_GBPS`` / ``MXNET_ICI_GBPS``
-  knobs, plus dispatch/scan/padding overhead terms).  Always available,
+  feature vector (compute / HBM / interconnect terms from the assumed
+  rates ``PEAK_TFLOPS`` / ``HBM_GBPS`` / ``ICI_GBPS``, plus
+  dispatch/scan/padding overhead terms).  Always available,
   needs zero training data, and is what multi-process shardsearch uses
   (every rank must rank identically; per-host training sets differ).
 * :class:`CostModel` — ridge regression on ``log(cost)`` over
@@ -45,7 +45,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..base import get_env, make_lock
+from ..base import make_lock
 from .measure import backend_descriptor
 from .store import list_configs, load_config, store_dir
 
@@ -89,6 +89,10 @@ AUDIT_KEYS = ("_feat", "est_s", "shortlisted", "parity")
 
 # overhead priors (seconds) — rough magnitudes; the learned residual
 # absorbs the host-specific truth
+# the rates the prior assumes of any device
+PEAK_TFLOPS = 100.0
+HBM_GBPS = 800.0
+ICI_GBPS = 50.0
 _DISPATCH_S = 2e-4       # per-step host dispatch, amortized by superstep K
 _SCAN_ITER_S = 2e-5      # per-scan-iteration control, amortized by unroll
 _COST_FLOOR_S = 1e-9
@@ -112,16 +116,12 @@ def clean_config(cfg: Dict[str, Any]) -> Dict[str, Any]:
 
 
 def analytic_cost(feat: Sequence[float]) -> float:
-    """The roofline prior in seconds.  Deterministic in (features, env
-    knobs) — multi-process search ranks with THIS, never the learned
-    layer, so every rank shortlists identically."""
+    """The roofline prior in seconds.  A pure function of the features
+    — multi-process search ranks with THIS, never the learned layer, so
+    every rank shortlists identically."""
     f = dict(zip(FEATURE_NAMES, feat))
-    peak = get_env("MXNET_PEAK_TFLOPS", 100.0, float)
-    hbm = get_env("MXNET_HBM_GBPS", 800.0, float)
-    ici = get_env("MXNET_ICI_GBPS", 50.0, float)
-    compute = f["gflops"] / max(peak * 1e3, 1e-9)
-    cost = compute + f["hbm_gb"] / max(hbm, 1e-9) \
-        + f["coll_gb"] / max(ici, 1e-9)
+    compute = f["gflops"] / (PEAK_TFLOPS * 1e3)
+    cost = compute + f["hbm_gb"] / HBM_GBPS + f["coll_gb"] / ICI_GBPS
     if f["remat"]:
         cost += compute / 3.0        # one extra forward of the remat region
     cost += _DISPATCH_S * f["inv_k"]

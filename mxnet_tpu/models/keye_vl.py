@@ -55,9 +55,9 @@ beside the op's own ``dsa_score``, ``dsa_select``, ``dsa_attn``,
 from .. import symbol as sym
 from ..attribute import AttrScope
 from ..initializer import Normal
-from ..module.fused import DSA_HEAD
 from ..moe.layer import with_aux_loss, with_load_heads
 from ..ops.sparse_attention import STATS
+from ..trace.heads import DSA_SELECT
 from .decoder import (block, embed, gqa_attention, lm_head_loss, proj,
                       routed_experts, scoped)
 
@@ -145,4 +145,4 @@ def keye_lm(num_layers, hidden_size, num_heads, num_kv_heads, head_dim,
             for loss, stats in selected]
     counter = rows[0] if len(rows) == 1 else sym.Concat(*rows, dim=0)
     return sym.Group([with_load_heads(sym.Group(heads)),
-                      sym.BlockGrad(counter, name=DSA_HEAD)])
+                      sym.BlockGrad(counter, name=DSA_SELECT.name)])

@@ -54,9 +54,8 @@ entropy keeps the op's own ``lm_loss``.
 from .. import symbol as sym
 from ..attribute import AttrScope
 from ..initializer import Normal
+from ..trace.heads import LOOP_EXIT
 from .decoder import block, embed, gqa_attention, norm, proj, swiglu
-
-EXIT_HEAD = "loop_exit"
 
 
 def exit_objective(ce, gate, num_steps, exit_beta):
@@ -128,6 +127,6 @@ def ouro_lm(num_layers, hidden_size, num_heads, num_kv_heads, head_dim,
         exits = sym.BlockGrad(
             sym.Concat(sym.sum_axis(p, axis=1),
                        sym.sum_axis(full_depth, axis=1), dim=0),
-            name=EXIT_HEAD)
+            name=LOOP_EXIT.name)
         return sym.Group([sym.MakeLoss(rows, normalization="batch",
                                        name="lm"), exits])

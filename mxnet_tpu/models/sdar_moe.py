@@ -41,8 +41,8 @@ Device scopes: ``attn_proj.l<i>`` (projections, head norms, rotation)
 beside the ops' own ``attn.l<i>``, ``moe_*.l<i>`` and ``lm_loss``.
 """
 from .. import symbol as sym
-from ..module.fused import NOISE_HEAD
 from ..moe.layer import with_aux_loss, with_load_heads
+from ..trace.heads import DIFFUSION_NOISE
 from .decoder import (block, embed, gqa_attention, lm_head_loss,
                       routed_experts, scoped)
 
@@ -92,4 +92,4 @@ def sdar_moe_lm(num_layers, hidden_size, num_heads, num_kv_heads, head_dim,
     noise = sym.Concat(*(sym.Reshape(sym.sum(s), shape=(1,)) for s in (
         masked, masked * 0.0 + 1.0, masked * weight)), dim=0)
     return sym.Group([with_load_heads(net),
-                      sym.BlockGrad(noise, name=NOISE_HEAD)])
+                      sym.BlockGrad(noise, name=DIFFUSION_NOISE.name)])

@@ -38,7 +38,7 @@ from ..executor import _GraphProgram
 from ..ndarray import NDArray
 from ..parallel.mesh import tracing_over
 from .. import trace as _trace
-from ..trace import scopes as _scopes
+from ..trace import heads as _heads, scopes as _scopes
 
 __all__ = ["FusedTrainStep"]
 
@@ -55,71 +55,6 @@ def _hparams_undeclared(cls):
         return None
     fu, fh = definer("fused_update_fn"), definer("fused_hparams")
     return fh is None or not issubclass(fh, fu)
-
-
-def find_prediction_heads(symbol):
-    """``(main, extra, weight, valid_thresh)`` where ``symbol`` has two
-    per-token loss heads, ``MakeLoss`` over ``SoftmaxCELoss``: outputs
-    ``main`` (the first such head) and ``extra`` (the second: a
-    multi-token-prediction module's), found by what they are and not by
-    where they stand; ``weight`` is the second's ``grad_scale``, and
-    ``valid_thresh`` its threshold where it normalizes over the rows
-    above one (``normalization="valid"``: the positions that have a
-    target), else None.  None for a symbol with fewer than two."""
-    found = []
-    for i, (node, _) in enumerate(symbol._heads):
-        if node.is_variable or getattr(node.op, "name", "") != "MakeLoss":
-            continue
-        inner = node.inputs[0][0]
-        if not inner.is_variable \
-                and getattr(inner.op, "name", "") == "SoftmaxCELoss":
-            found.append((i, node.params))
-    if len(found) < 2:
-        return None
-    (main, _), (extra, p) = found[:2]
-    return (main, extra, float(p.grad_scale),
-            float(p.valid_thresh) if p.normalization == "valid" else None)
-
-
-NOISE_HEAD = "diffusion_noise"
-EXIT_HEAD = "loop_exit"
-DSA_HEAD = "dsa_select"
-
-
-def _find_counter_head(symbol, name):
-    """The index of the output that the ``BlockGrad`` node ``name``
-    gives, or None for a symbol without one."""
-    for i, (node, _) in enumerate(symbol._heads):
-        if not node.is_variable and node.name == name \
-                and getattr(node.op, "name", "") == "BlockGrad":
-            return i
-    return None
-
-
-def find_noise_head(symbol):
-    """The index of the output ``diffusion_noise_output``: the ``(3,)``
-    head a block-diffusion symbol groups on (``models.sdar_moe``): the
-    step's masked positions, positions, and the masked positions' summed
-    weights, behind a ``BlockGrad``.  None for a symbol without one."""
-    return _find_counter_head(symbol, NOISE_HEAD)
-
-
-def find_exit_head(symbol):
-    """The index of the output ``loop_exit_output``: the ``(R + 1,)``
-    head a looped symbol groups on (``models.ouro``): the rows' summed
-    exit probabilities ``p_1 .. p_R`` and their summed full-depth cross
-    entropy, behind a ``BlockGrad``.  None for a symbol without one."""
-    return _find_counter_head(symbol, EXIT_HEAD)
-
-
-def find_selection_head(symbol):
-    """The index of the output ``dsa_select_output``: the ``(L, B, 6)``
-    head a symbol whose attention selects its keys groups on
-    (``models.keye_vl``): a block's and sequence's rows, selected pairs,
-    causal pairs, tiles hit, causal tiles (``ops.sparse_attention.STATS``)
-    and index loss, behind a ``BlockGrad``.  None for a symbol without
-    one."""
-    return _find_counter_head(symbol, DSA_HEAD)
 
 
 class FusedTrainStep:
@@ -265,32 +200,17 @@ class FusedTrainStep:
             from .. import profiler as _prof
             self.embed_stats = EmbedStats("fused")
             _prof.register_embed_stats(self.embed_stats)
-        self._embed_stats_every = max(
-            1, get_env("MXNET_EMBED_STATS_EVERY", 1, int))
-        self._embed_stats_n = 0
         # routed-MoE blocks: graph-side detection registers the stats
         # consumer and stamps each block's routing geometry into the
         # program descriptor.  Routing is data-dependent: per-expert
         # traffic reaches the stats from the step's own outputs where
-        # the symbol carries the blocks' load head (note_outputs),
-        # else from bench/serve samplers
-        from ..moe.detect import (find_act_zeros_head, find_load_heads,
-                                  find_moe_blocks)
+        # the symbol carries the blocks' load head, else from
+        # bench/serve samplers
+        from ..moe.detect import find_moe_blocks
         self.moe_blocks = find_moe_blocks(symbol)
-        self.moe_load_heads = find_load_heads(symbol)
-        # what a rank's expert blocks count of their activated lanes,
-        # for the trace
-        self.act_zeros_head = find_act_zeros_head(symbol)
-        # a second per-token loss head (a multi-token-prediction module):
-        # its mean reaches the trace from the step's outputs too
-        self.prediction_heads = find_prediction_heads(symbol)
-        # a block-diffusion symbol's noise head: what the step's labels
-        # masked, for the trace
-        self.noise_head = find_noise_head(symbol)
-        # a looped symbol's exit head: where the gate sends the rows
-        self.exit_head = find_exit_head(symbol)
-        # a key-selecting symbol's head: what each block's selection kept
-        self.selection_head = find_selection_head(symbol)
+        # the outputs that feed trace counters (trace/heads.py), each
+        # with what its reader needs, in the order fit reads them
+        self.heads = _heads.find_all(symbol)
         self.moe_stats = None
         if self.moe_blocks:
             from ..moe.stats import MoeStats
@@ -552,19 +472,16 @@ class FusedTrainStep:
         mp = self._multiprocess()
         if self.embed_stats is not None:
             # dedup-ratio instrumentation on the HOST ids (microseconds
-            # on an int batch vs a multi-ms step), sampled every
-            # MXNET_EMBED_STATS_EVERY batches — the number
+            # on an int batch vs a multi-ms step) — the number
             # mx.profiler.embed_report() surfaces
-            self._embed_stats_n += 1
-            if self._embed_stats_n % self._embed_stats_every == 0:
-                by_name = dict(zip(self.data_names, data_batch.data))
-                from ..embed.sparse import resolve_cap
-                for n, sp in self.sparse_embeds.items():
-                    ids = by_name.get(sp.ids_name)
-                    if ids is not None:
-                        self.embed_stats.note_ids(n, ids.asnumpy())
-                        self.embed_stats.note_update(
-                            n, resolve_cap(sp.cap, ids.size, sp.vocab))
+            by_name = dict(zip(self.data_names, data_batch.data))
+            from ..embed.sparse import resolve_cap
+            for n, sp in self.sparse_embeds.items():
+                ids = by_name.get(sp.ids_name)
+                if ids is not None:
+                    self.embed_stats.note_ids(n, ids.asnumpy())
+                    self.embed_stats.note_update(
+                        n, resolve_cap(sp.cap, ids.size, sp.vocab))
 
         def put(arr):
             a = arr._get()
@@ -683,105 +600,12 @@ class FusedTrainStep:
             return P("dp") if "dp" in names else P()
         return P("dp") if (o.ndim >= 1 and o.shape[0] == rows) else P()
 
-    def note_outputs(self, outs) -> None:
-        """Feed ``MoeStats`` and the ``moe:load`` trace counter (one
-        sample a block: max, mean, empty experts, routed, held, dropped)
-        from one step's outputs, given as the metric gets them.
-        ``held`` counts the routed choices that fell on experts this
-        rank holds (all of them where it holds all).  A rank's share
-        also says ``bound``, the static row bound its sorted layout is
-        sized by (``moe.dispatch.held_rows_bound``, the op's own rule):
-        ``held <= bound`` says the step ran the block over ``bound``
-        rows, and not over all that were routed.  One host read of the
-        ``(blocks, E + 1)`` load head, which the metric update before
-        this call already waited for."""
-        from ..moe.dispatch import held_rows_bound
-        idx, blocks = self.moe_load_heads
-        for block, row in zip(blocks, outs[idx].asnumpy()):
-            counts, dropped = row[:-1], float(row[-1])
-            self.moe_stats.note_counts(block, counts, dropped)
-            spec = self.moe_blocks[block]
-            sample = dict(max=float(counts.max()),
-                          mean=float(counts.mean()),
-                          empty=int((counts == 0).sum()),
-                          routed=float(counts.sum()),
-                          held=float(counts[spec.held].sum()),
-                          dropped=dropped)
-            held = spec.held.stop - spec.held.start
-            if held < spec.num_experts:
-                sample["bound"] = float(held_rows_bound(
-                    sample["routed"], spec.num_experts, held))
-            _trace.counter("moe:load", cat="moe", track=block, **sample)
-
-    def note_act_zeros(self, outs) -> None:
-        """Feed the ``moe:act_zeros`` trace counter, one sample a step
-        and expert block, from the step's ``moe_act_zeros`` head as the
-        metric gets it: ``zeros`` of the ``lanes`` activated lanes
-        (``act(x Wg)``) of the rows this rank really held.  One host read
-        of ``(blocks, 2)`` numbers the metric update before this call
-        already waited for."""
-        idx, blocks = self.act_zeros_head
-        for block, (zeros, lanes) in zip(blocks, outs[idx].asnumpy()):
-            _trace.counter("moe:act_zeros", cat="moe", track=block,
-                           zeros=float(zeros), lanes=float(lanes))
-
-    def note_prediction_loss(self, outs) -> None:
-        """Feed the ``mtp:loss`` trace counter, one sample a step, from
-        the step's outputs as the metric gets them: ``main`` the mean of
-        the first per-token loss head, ``mtp`` the second head's mean
-        over the positions that have a target (as its ``MakeLoss``
-        normalizes), ``weight`` its ``grad_scale``.  Two host reads of
-        ``(rows,)`` outputs the metric update before this call already
-        waited for."""
-        main, extra, weight, thresh = self.prediction_heads
-        second = outs[extra].asnumpy()
-        if thresh is not None:
-            second = second[second > thresh]
-        _trace.counter("mtp:loss", cat="train",
-                       main=float(outs[main].asnumpy().mean()),
-                       mtp=float(second.mean()) if second.size else 0.0,
-                       weight=weight)
-
-    def note_diffusion_noise(self, outs) -> None:
-        """Feed the ``diffusion:noise`` trace counter, one sample a step,
-        from the step's noise head as the metric gets it: ``masked`` the
-        positions the step's labels gave a target, ``positions`` all of
-        them, ``weight_sum`` the masked positions' summed ``1 / t``.  One
-        host read of three numbers the metric update before this call
-        already waited for."""
-        masked, positions, weight_sum = (
-            float(x) for x in outs[self.noise_head].asnumpy())
-        _trace.counter("diffusion:noise", cat="train", masked=masked,
-                       positions=positions, weight_sum=weight_sum)
-
-    def note_loop_exit(self, outs) -> None:
-        """Feed the ``loop:exit`` trace counter, one sample a step, from
-        the step's exit head as the metric gets it: ``p1 .. pR`` the
-        rows' mean probability of leaving after each pass, ``depth`` the
-        mean exit depth ``sum_t t p_t`` and ``ce_last`` the mean
-        cross entropy at full depth.  One host read of ``R + 1`` numbers
-        the metric update before this call already waited for."""
-        sums = [float(x) for x in outs[self.exit_head].asnumpy()]
-        rows = float(sum(sums[:-1])) or 1.0     # a row's p sums to 1
-        p = [x / rows for x in sums[:-1]]
-        _trace.counter("loop:exit", cat="train", ce_last=sums[-1] / rows,
-                       depth=sum((t + 1) * x for t, x in enumerate(p)),
-                       **{"p%d" % (t + 1): x for t, x in enumerate(p)})
-
-    def note_selection(self, outs) -> None:
-        """Feed the ``dsa:select`` trace counter, one sample a step and
-        block (track ``l<i>``), from the step's selection head as the
-        metric gets it: ``rows``, ``selected_pairs`` of ``causal_pairs``,
-        ``tiles_hit`` of ``tiles_causal`` (512 x 512 causal tiles that
-        hold a selected pair), summed over the batch's sequences, and
-        ``kl``, the block's index loss (their mean).  One host read of
-        ``(L, B, 6)`` numbers the metric update before this call already
-        waited for."""
-        from ..ops.sparse_attention import STATS
-        for l, per_seq in enumerate(outs[self.selection_head].asnumpy()):
-            _trace.counter("dsa:select", cat="train", track="l%d" % l,
-                           kl=float(per_seq[:, -1].mean()),
-                           **dict(zip(STATS, per_seq[:, :-1].sum(0).tolist())))
+    def head(self, name):
+        """What the head ``name`` of ``trace.heads`` needs to be read
+        from this step's outputs (its output's index, or a tuple that
+        starts with one); None where the symbol carries no such head."""
+        return next((handle for head, handle in self.heads
+                     if head.name == name), None)
 
     # -- compiled programs ---------------------------------------------------
     def _make_step_fn(self):

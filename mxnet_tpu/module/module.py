@@ -1192,46 +1192,20 @@ class Module(BaseModule):
         self._exec_group.update_metric(eval_metric, labels)
 
     def _note_train_outputs(self, outputs=None):
-        """Routed-expert load of the step just scored, from its load
-        head (``FusedTrainStep.note_outputs``), under a span of its own,
-        ``fit:moe_load``, and the mean of a second per-token loss head
-        beside the first's (``note_prediction_loss``) under
-        ``fit:mtp_loss``, and what a block-diffusion symbol's noise head
-        counted (``note_diffusion_noise``) under ``fit:diffusion_noise``,
-        and what a rank's expert blocks counted of their activated lanes
-        (``note_act_zeros``) under ``fit:moe_act_zeros``, and where a
-        looped symbol's exit gate sent the rows (``note_loop_exit``)
-        under ``fit:loop_exit``, and what each block's learned selection
-        kept (``note_selection``) under ``fit:dsa_select``:
-        nothing, and no span, where the fused step is off or the symbol
-        carries no such head; the last five only while tracing is on."""
+        """Read the counter heads that the fused step found in its
+        symbol (``trace/heads.py``) from the outputs of the step just
+        scored, in their order, each under its own span: nothing, and no
+        span, where the fused step is off or the symbol carries no head;
+        a head that only feeds the trace only while tracing is on."""
         fused = self._fused
-        if fused is None or not self._fused_live() \
-                or not (fused.moe_load_heads or fused.prediction_heads
-                        or fused.noise_head is not None
-                        or fused.act_zeros_head
-                        or fused.exit_head is not None
-                        or fused.selection_head is not None):
+        if fused is None or not self._fused_live() or not fused.heads:
             return
         outs = self.get_outputs() if outputs is None else outputs
-        if fused.moe_load_heads:
-            with _trace.span("fit:moe_load", cat="train"):
-                fused.note_outputs(outs)
-        if fused.prediction_heads and _trace.enabled():
-            with _trace.span("fit:mtp_loss", cat="train"):
-                fused.note_prediction_loss(outs)
-        if fused.noise_head is not None and _trace.enabled():
-            with _trace.span("fit:diffusion_noise", cat="train"):
-                fused.note_diffusion_noise(outs)
-        if fused.act_zeros_head and _trace.enabled():
-            with _trace.span("fit:moe_act_zeros", cat="train"):
-                fused.note_act_zeros(outs)
-        if fused.exit_head is not None and _trace.enabled():
-            with _trace.span("fit:loop_exit", cat="train"):
-                fused.note_loop_exit(outs)
-        if fused.selection_head is not None and _trace.enabled():
-            with _trace.span("fit:dsa_select", cat="train"):
-                fused.note_selection(outs)
+        traced = _trace.enabled()
+        for head, handle in fused.heads:
+            if head.always or traced:
+                with _trace.span(head.span, cat="train"):
+                    head.emit(fused, handle, outs)
 
     def _outputs_in_flight(self):
         """The overlap hook of fit() and score(): the outputs of the

@@ -40,14 +40,13 @@ from .dispatch import dispatch, combine
 from .layer import (MoEFeedForward, aux_loss_symbols, count_symbols,
                     dropped_symbols, hit_symbols, with_act_zeros_head,
                     with_aux_loss, with_load_heads)
-from .detect import (MoEBlockSpec, find_act_zeros_head, find_load_heads,
-                     find_moe_blocks)
+from .detect import MoEBlockSpec, find_load_heads, find_moe_blocks
 from .stats import MoeStats
 
 __all__ = [
     "resolve_capacity", "route", "dispatch", "combine",
     "MoEFeedForward", "aux_loss_symbols", "count_symbols",
     "dropped_symbols", "hit_symbols", "with_act_zeros_head", "with_aux_loss",
-    "with_load_heads", "MoEBlockSpec", "find_act_zeros_head",
-    "find_load_heads", "find_moe_blocks", "MoeStats",
+    "with_load_heads", "MoEBlockSpec", "find_load_heads", "find_moe_blocks",
+    "MoeStats",
 ]

@@ -13,8 +13,7 @@ from __future__ import annotations
 
 from typing import Dict
 
-__all__ = ["MoEBlockSpec", "find_act_zeros_head", "find_load_heads",
-           "find_moe_blocks"]
+__all__ = ["MoEBlockSpec", "find_load_heads", "find_moe_blocks"]
 
 
 class MoEBlockSpec:
@@ -96,22 +95,4 @@ def find_load_heads(symbol):
         blocks = [block_of(r) for r in rows]
         if blocks and all(blocks):
             return i, blocks
-    return None
-
-
-def find_act_zeros_head(symbol):
-    """``(i, [share node names])``: output ``i`` of ``symbol`` is the
-    ``(blocks, 2)`` head ``moe_act_zeros`` that
-    ``moe.layer.with_act_zeros_head`` groups on, and the names are its
-    rows' ``_moe_share_ffn`` nodes.  None where the symbol has no such
-    head."""
-    from .layer import ACT_ZEROS_HEAD
-    for i, (node, _) in enumerate(symbol._heads):
-        if node.is_variable or node.name != ACT_ZEROS_HEAD \
-                or getattr(node.op, "name", "") != "BlockGrad":
-            continue
-        stack = node.inputs[0][0]
-        rows = [stack] if stack.op.name == "Reshape" else \
-            [n for n, _ in stack.inputs]
-        return i, [row.inputs[0][0].name for row in rows]
     return None

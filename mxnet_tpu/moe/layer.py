@@ -23,6 +23,7 @@ from typing import List, Optional
 
 from ..base import get_env
 from .. import symbol as _sym
+from ..trace.heads import MOE_ACT_ZEROS, MOE_LOAD
 
 __all__ = ["MoEFeedForward", "aux_loss_symbols", "count_symbols",
            "hit_symbols", "dropped_symbols", "with_act_zeros_head",
@@ -34,8 +35,6 @@ _COUNTS_IDX = 4
 _HITS_IDX = 5
 _DROPPED_IDX = 6
 _ORDER_IDX = 7
-# the head ``with_act_zeros_head`` groups on (``detect.find_act_zeros_head``)
-ACT_ZEROS_HEAD = "moe_act_zeros"
 
 
 def MoEFeedForward(data, num_hidden: int, num_experts: int, k: int = 2,
@@ -52,10 +51,10 @@ def MoEFeedForward(data, num_hidden: int, num_experts: int, k: int = 2,
                    shared_gate: bool = False):
     """Build one routed MoE feed-forward block over ``data`` (T, D).
 
-    ``capacity_factor`` None reads ``MXNET_MOE_CAPACITY_FACTOR``
-    (default 0 = no token-choice dropped: the ``T*k`` rows are sorted
-    by expert and the experts are grouped matmuls over exactly those
-    rows; ``> 0`` buckets to a capacity and drops the overflow);
+    ``capacity_factor`` None is 0 (no token-choice dropped: the ``T*k``
+    rows are sorted by expert and the experts are grouped matmuls over
+    exactly those rows; ``> 0`` buckets to a capacity and drops the
+    overflow);
     ``expert_axis`` names the mesh axis the stacked expert weights
     shard over (None = replicated).  ``gated`` adds the stacked
     ``i2h_gate`` projection: ``(act(x Wg) * (x W1)) W2``, SwiGLU with
@@ -88,7 +87,7 @@ def MoEFeedForward(data, num_hidden: int, num_experts: int, k: int = 2,
     with ``with_aux_loss``.
     """
     if capacity_factor is None:
-        capacity_factor = get_env("MXNET_MOE_CAPACITY_FACTOR", 0.0, float)
+        capacity_factor = 0.0
     scope = {} if layer is None else {"layer": int(layer)}
     if act_zeros and not experts_held:
         raise ValueError("MoEFeedForward: act_zeros counts the rows a rank "
@@ -206,7 +205,7 @@ def with_load_heads(net):
     the step's outputs, and ``Module.fit`` feeds ``MoeStats`` and the
     ``moe:load`` trace counter from it with one host read a step
     whatever the depth, and no device sync of its own
-    (``FusedTrainStep.note_outputs``).  The blocks must agree on the
+    (``trace.heads.MOE_LOAD``).  The blocks must agree on the
     number of experts.  Returns ``net`` unchanged when the graph has no
     MoE blocks."""
     rows = [_sym.Reshape(_sym.Concat(counts, dropped, dim=0), shape=(1, -1))
@@ -215,7 +214,7 @@ def with_load_heads(net):
     if not rows:
         return net
     load = rows[0] if len(rows) == 1 else _sym.Concat(*rows, dim=0)
-    return _sym.Group([net, _sym.BlockGrad(load, name="moe_load")])
+    return _sym.Group([net, _sym.BlockGrad(load, name=MOE_LOAD.name)])
 
 
 def with_act_zeros_head(net):
@@ -225,7 +224,7 @@ def with_act_zeros_head(net):
     topological order.  It travels with the step's outputs beside
     ``moe_load``, and ``Module.fit`` feeds the ``moe:act_zeros`` trace
     counter from it while tracing is on
-    (``FusedTrainStep.note_act_zeros``).  Returns ``net`` unchanged when
+    (``trace.heads.MOE_ACT_ZEROS``).  Returns ``net`` unchanged when
     no node carries the output."""
     from ..symbol import Symbol, _topo
     rows = [_sym.Reshape(Symbol([(node, 1)]), shape=(1, 2))
@@ -236,7 +235,7 @@ def with_act_zeros_head(net):
     if not rows:
         return net
     stack = rows[0] if len(rows) == 1 else _sym.Concat(*rows, dim=0)
-    return _sym.Group([net, _sym.BlockGrad(stack, name=ACT_ZEROS_HEAD)])
+    return _sym.Group([net, _sym.BlockGrad(stack, name=MOE_ACT_ZEROS.name)])
 
 
 def with_aux_loss(net, grad_scale: Optional[float] = None):

@@ -156,7 +156,7 @@ class DecodeEngine:
                  name: str = "decode", warmup: bool = True,
                  pipeline=None,
                  moe_hits_state: Optional[str] = None,
-                 moe_stats_every: Optional[int] = None):
+                 moe_stats_every: int = 16):
         if num_slots is None:
             num_slots = get_env("MXNET_SERVE_SLOTS", 8, int)
         self.num_slots = int(num_slots)
@@ -262,8 +262,6 @@ class DecodeEngine:
             from ..moe.stats import MoeStats
             self.moe_stats = MoeStats("serve:%s" % name)
             profiler.register_moe_stats(self.moe_stats)
-        if moe_stats_every is None:
-            moe_stats_every = get_env("MXNET_MOE_STATS_EVERY", 16, int)
         self._moe_stats_every = max(1, int(moe_stats_every))
         self._moe_stats_n = 0
 

@@ -26,8 +26,8 @@ from mxnet_tpu import symbol as sym                       # noqa: E402
 from mxnet_tpu.executor import _GraphProgram              # noqa: E402
 from mxnet_tpu.models import glm_moe_lite_lm, olmoe_lm    # noqa: E402
 from mxnet_tpu.models.latent_attention import latent_attention  # noqa: E402
-from mxnet_tpu.module.fused import find_prediction_heads  # noqa: E402
 from mxnet_tpu.moe import find_load_heads                 # noqa: E402
+from mxnet_tpu.trace.heads import MTP_LOSS                # noqa: E402
 from mxnet_tpu.ops import transformer as tf_ops           # noqa: E402
 
 import manifest                                           # noqa: E402
@@ -285,15 +285,15 @@ def test_the_heads_are_found_by_what_they_are():
     assert net.list_outputs() == ["lm_output", "mtp_output",
                                   "moe_load_output"]
     assert find_load_heads(net) == (2, BLOCKS)
-    assert find_prediction_heads(net) == (0, 1, 0.3, 0.0)
+    assert MTP_LOSS.find(net) == (0, 1, 0.3, 0.0)
     # the order of the group is not what finds them
     turned = sym.Group([net[2], net[1], net[0]])
     assert find_load_heads(turned)[0] == 0
-    assert find_prediction_heads(turned)[:2] == (1, 2)
+    assert MTP_LOSS.find(turned)[:2] == (1, 2)
     # one loss head, or none: nothing
-    assert find_prediction_heads(glm_moe_lite_lm(
+    assert MTP_LOSS.find(glm_moe_lite_lm(
         **dict(kwargs, nextn_layers=0))) is None
-    assert find_prediction_heads(net[2]) is None
+    assert MTP_LOSS.find(net[2]) is None
     # the two shared weights are one argument each, used twice
     args = net.list_arguments()
     assert args.count("embed_weight") == args.count("lm_head_weight") == 1
@@ -430,7 +430,7 @@ def test_fit_records_the_second_heads_loss_once_a_step():
     net, kwargs, _, _, _ = _tiny(seed=3)
     mod, counters, spans = _fit(net, kwargs["vocab_size"],
                                 kwargs["seq_len"])
-    assert mod._fused.prediction_heads == (0, 1, 0.3, 0.0)
+    assert mod._fused.head("mtp_loss") == (0, 1, 0.3, 0.0)
     losses = [e["args"] for e in counters if e["name"] == "mtp:loss"]
     assert len(losses) == 4
     chance = np.log(kwargs["vocab_size"])
@@ -462,7 +462,7 @@ def test_a_symbol_with_one_loss_head_records_neither():
                    num_experts=4, experts_per_tok=2, expert_width=12,
                    vocab_size=40, seq_len=16)
     mod, counters, spans = _fit(net, 40, 16)
-    assert mod._fused.prediction_heads is None
+    assert mod._fused.head("mtp_loss") is None
     assert not [e for e in counters if e["name"] == "mtp:loss"]
     assert not [e for e in spans if e["name"] == "fit:mtp_loss"]
     assert [e for e in counters if e["name"] == "moe:load"]

@@ -21,9 +21,9 @@ import jax.numpy as jnp                                   # noqa: E402
 
 import mxnet_tpu as mx                                    # noqa: E402
 from mxnet_tpu.models import keye_lm, olmoe_lm, sdar_moe_lm  # noqa: E402
-from mxnet_tpu.module.fused import find_selection_head    # noqa: E402
 from mxnet_tpu.ops import sparse_attention as sa          # noqa: E402
 from mxnet_tpu.ops import transformer as tf_ops           # noqa: E402
+from mxnet_tpu.trace.heads import DSA_SELECT              # noqa: E402
 
 import manifest                                           # noqa: E402
 
@@ -287,7 +287,7 @@ def test_fit_records_the_selection_once_a_step_and_block():
     finally:
         mx.trace.reset()
         mx.trace.set_enabled(was)
-    assert mod._fused.selection_head == 6 == find_selection_head(mod._symbol)
+    assert mod._fused.head("dsa_select") == 6 == DSA_SELECT.find(mod._symbol)
     samples = [e for e in counters if e["name"] == "dsa:select"]
     assert len(samples) == 4 * 2
     assert sorted({e["id"] for e in samples}) == ["l0", "l1"]
@@ -342,7 +342,7 @@ def test_no_selection_counter_for_a_symbol_without_the_head(builder, kwargs,
     finally:
         mx.trace.reset()
         mx.trace.set_enabled(was)
-    assert mod._fused.selection_head is None
+    assert mod._fused.head("dsa_select") is None
     assert not [e for e in counters if e["name"] == "dsa:select"]
     assert not [e for e in spans if e["name"] == "fit:dsa_select"]
     assert [e for e in counters if e["name"] == "moe:load"]
@@ -357,7 +357,8 @@ def test_nothing_is_recorded_while_tracing_is_off():
         mod, counters, spans = _fit(keye_lm(**TINY), data, label)
     finally:
         mx.trace.set_enabled(was)
-    assert mod._fused.selection_head == 6 and not counters and not spans
+    assert mod._fused.head("dsa_select") == 6
+    assert not counters and not spans
 
 
 def test_the_builder_refuses_sizes_that_are_no_model():

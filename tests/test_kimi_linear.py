@@ -1019,7 +1019,7 @@ def test_fit_feeds_the_held_share_to_moe_load():
     finally:
         mx.trace.set_enabled(was)
     blocks = ["l%d_moe_dispatch" % l for l in (2, 3, 4, 5)]
-    assert mod._fused.moe_load_heads[1] == blocks
+    assert mod._fused.head("moe_load")[1] == blocks
     assert len(events) == 4 * len(blocks)
     routed = BATCH * kwargs["seq_len"] * kwargs["experts_per_tok"]
     for e in events:

@@ -558,7 +558,7 @@ def test_fit_feeds_moe_load_counter_and_stats():
         mx.trace.set_enabled(was)
     assert mod._fused is not None and "embed_weight" in \
         mod._fused.sparse_embeds
-    head, blocks = mod._fused.moe_load_heads
+    head, blocks = mod._fused.head("moe_load")
     assert blocks == ["l0_moe_dispatch", "l1_moe_dispatch"]
     # ONE (blocks, E + 1) head, the symbol's last output
     assert net.list_outputs()[head] == "moe_load_output" and \

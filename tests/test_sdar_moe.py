@@ -26,9 +26,8 @@ import jax.numpy as jnp                                   # noqa: E402
 import mxnet_tpu as mx                                    # noqa: E402
 from mxnet_tpu.executor import _GraphProgram              # noqa: E402
 from mxnet_tpu.models import olmoe_lm, sdar_moe_lm        # noqa: E402
-from mxnet_tpu.module.fused import find_noise_head, \
-    find_prediction_heads                                 # noqa: E402
 from mxnet_tpu.moe import find_load_heads                 # noqa: E402
+from mxnet_tpu.trace.heads import DIFFUSION_NOISE, MTP_LOSS   # noqa: E402
 from mxnet_tpu.ops import transformer as tf_ops           # noqa: E402
 
 import manifest                                           # noqa: E402
@@ -535,10 +534,10 @@ def test_the_heads_are_found_by_what_they_are():
         "l1_moe_dispatch_aux_output", "moe_load_output",
         "diffusion_noise_output"]
     assert find_load_heads(net) == (3, BLOCKS)
-    assert find_noise_head(net) == 4
-    assert find_prediction_heads(net) is None        # one per-token loss
-    assert find_noise_head(mx.sym.Group([net[4], net[0]])) == 0
-    assert find_noise_head(net[0]) is None
+    assert DIFFUSION_NOISE.find(net) == 4
+    assert MTP_LOSS.find(net) is None                # one per-token loss
+    assert DIFFUSION_NOISE.find(mx.sym.Group([net[4], net[0]])) == 0
+    assert DIFFUSION_NOISE.find(net[0]) is None
     # no load-balance head where its coefficient is 0
     assert sdar_moe_lm(**dict(kwargs, aux_coef=0.0)).list_outputs() == [
         "lm_output", "moe_load_output", "diffusion_noise_output"]
@@ -677,7 +676,7 @@ def test_fit_records_the_noise_once_a_step():
     finally:
         mx.trace.reset()         # the ring is the process's: leave none
         mx.trace.set_enabled(was)
-    assert mod._fused.noise_head == 4
+    assert mod._fused.head("diffusion_noise") == 4
     noise = [e["args"] for e in counters if e["name"] == "diffusion:noise"]
     assert len(noise) == 4
     masked = labels[:, 0] >= 0
@@ -716,7 +715,7 @@ def test_nothing_is_recorded_for_the_olmoe_symbol_or_while_tracing_is_off():
     finally:
         mx.trace.reset()
         mx.trace.set_enabled(was)
-    assert mod._fused.noise_head is None
+    assert mod._fused.head("diffusion_noise") is None
     assert not [e for e in counters if e["name"] == "diffusion:noise"]
     assert not [e for e in spans if e["name"] == "fit:diffusion_noise"]
     assert [e for e in counters if e["name"] == "moe:load"]
@@ -726,7 +725,8 @@ def test_nothing_is_recorded_for_the_olmoe_symbol_or_while_tracing_is_off():
         mod, counters, spans = _fit(net, data, labels)
     finally:
         mx.trace.set_enabled(was)
-    assert mod._fused.noise_head == 4 and not counters and not spans
+    assert mod._fused.head("diffusion_noise") == 4
+    assert not counters and not spans
     assert mod._fused.moe_stats.report()["blocks"]     # MoeStats still fed
 
 

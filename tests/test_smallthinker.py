@@ -28,9 +28,9 @@ import mxnet_tpu as mx                                    # noqa: E402
 from mxnet_tpu.executor import _GraphProgram              # noqa: E402
 from mxnet_tpu.models import afmoe_lm, olmoe_lm, smallthinker_lm  # noqa: E402
 from mxnet_tpu.models import decoder                      # noqa: E402
-from mxnet_tpu.moe import (MoEFeedForward, find_act_zeros_head,  # noqa: E402
-                           find_load_heads)
+from mxnet_tpu.moe import MoEFeedForward, find_load_heads  # noqa: E402
 from mxnet_tpu.moe.dispatch import held_rows_bound        # noqa: E402
+from mxnet_tpu.trace.heads import MOE_ACT_ZEROS           # noqa: E402
 
 import manifest                                           # noqa: E402
 
@@ -134,7 +134,7 @@ def test_the_builder_names_its_heads_and_refuses_what_it_cannot_build():
     assert net.list_outputs() == ["lm_output", "moe_load_output",
                                   "moe_act_zeros_output"]
     assert find_load_heads(net) == (1, BLOCKS)
-    assert find_act_zeros_head(net) == (2, SHARES)
+    assert MOE_ACT_ZEROS.find(net) == (2, SHARES)
     assert net.list_auxiliary_states() == []
     args = net.list_arguments()
     for l in range(4):
@@ -149,7 +149,7 @@ def test_the_builder_names_its_heads_and_refuses_what_it_cannot_build():
     # model that holds every expert has no rank's rows to count
     plain = smallthinker_lm(**dict(kwargs, act_zeros=False))
     assert plain.list_outputs() == ["lm_output", "moe_load_output"]
-    assert find_act_zeros_head(plain) is None
+    assert MOE_ACT_ZEROS.find(plain) is None
     assert "act_zeros" not in plain.tojson()
     for bad in (dict(layer_types=["sliding"] * 3),
                 dict(layer_types=["sliding", "window", "full", "full"]),
@@ -647,7 +647,7 @@ def test_fit_records_the_zeros_once_a_step_and_block():
     finally:
         mx.trace.reset()         # the ring is the process's: leave none
         mx.trace.set_enabled(was)
-    assert mod._fused.act_zeros_head == (2, SHARES)
+    assert mod._fused.head("moe_act_zeros") == (2, SHARES)
     seen = [e for e in counters if e["name"] == "moe:act_zeros"]
     assert [e["id"] for e in seen] == SHARES * 3
     load = [e["args"] for e in counters if e["name"] == "moe:load"]
@@ -687,7 +687,7 @@ def test_nothing_is_recorded_without_the_head_or_while_tracing_is_off():
     finally:
         mx.trace.reset()
         mx.trace.set_enabled(was)
-    assert mod._fused.act_zeros_head is None
+    assert mod._fused.head("moe_act_zeros") is None
     assert not [e for e in counters if e["name"] == "moe:act_zeros"]
     assert not [e for e in spans if e["name"] == "fit:moe_act_zeros"]
     assert [e for e in counters if e["name"] == "moe:load"]
@@ -697,7 +697,7 @@ def test_nothing_is_recorded_without_the_head_or_while_tracing_is_off():
         mod, counters, spans = _fit(net, tokens, labels)
     finally:
         mx.trace.set_enabled(was)
-    assert mod._fused.act_zeros_head == (2, SHARES)
+    assert mod._fused.head("moe_act_zeros") == (2, SHARES)
     assert not [e for e in counters if e["name"] == "moe:act_zeros"]
     assert not [e for e in spans if e["name"] == "fit:moe_act_zeros"]
     # a router that reads the experts' rows says so

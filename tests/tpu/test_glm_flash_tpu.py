@@ -73,7 +73,7 @@ def _adam_step(net, params, tokens, labels, opt_params, compute_dtype,
         mod.update()
         assert mod._exec_group.execs == []
         outs = [o.asnumpy() for o in mod.get_outputs()]
-        main, extra = mod._fused.prediction_heads[:2]
+        main, extra = mod._fused.head("mtp_loss")[:2]
         after, aux = mod.get_params()
         delta = {n: after[n].asnumpy() - params[n] for n in names}
         aux = {n: v.asnumpy() for n, v in aux.items()}

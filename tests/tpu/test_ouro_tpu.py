@@ -120,7 +120,7 @@ def _adam_step(net, params, data, labels, opt_params, compute_dtype, names,
         outs = [o.asnumpy() for o in mod.get_outputs()]
         if program is not None:
             program.update(_formed_again())
-        exits = outs[mod._fused.exit_head]
+        exits = outs[mod._fused.head("loop_exit")]
         after, _ = mod.get_params()
         delta = {n: after[n].asnumpy() - params[n] for n in names}
         del mod, after, batch

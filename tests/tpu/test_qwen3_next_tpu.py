@@ -90,7 +90,7 @@ def _adam_step(net, params, data, labels, opt_params, compute_dtype, names):
         mod.update()
         assert mod._exec_group.execs == []
         outs = [o.asnumpy() for o in mod.get_outputs()]
-        load = mod._fused.moe_load_heads[0]
+        load = mod._fused.head("moe_load")[0]
         after, _ = mod.get_params()
         delta = {n: after[n].asnumpy() - params[n] for n in names}
         del mod, after, batch
