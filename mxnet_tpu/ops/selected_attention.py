@@ -1,6 +1,9 @@
-"""The forward attend pass of ``IndexedSelfAttention`` as a Pallas kernel
-of this repo: softmax attention of one sequence under a selection that is
-DATA (a ``(T, T)`` mask no function computes), grouped queries.
+"""The forward attend pass and the target pass of ``IndexedSelfAttention``
+as Pallas kernels of this repo, both under a selection that is DATA (a
+``(T, T)`` mask no function computes) over grouped queries.
+
+**The forward attend pass** (``selected_attention_fwd``): softmax
+attention of one sequence under the selection.
 
 All ``G = H / Hkv`` query heads of one key/value head share one
 selection, so a grid step holds them together: ``G x bq`` query rows
@@ -26,6 +29,32 @@ benchmark's ``dsa_attn_roofline`` divides the work of BOTH attend passes
 by the time of the operations named ``splash_mha*`` (the backward kernel
 is the library's), so the forward keeps the prefix until that reader goes
 by scope (ROADMAP D12(h)).
+
+**The target pass** (``selected_target``, PR 60): the index loss ``KL(p ||
+softmax over the selection of I)`` with ``p`` the heads' mean
+probabilities, and its gradient by the indexer's inputs.  A grid step is
+one ``(bq, bk)`` tile of pairs under the SAME int8 tile of the selection:
+the query heads of each key/value head one under another against its key
+tile (bfloat16 on the MXU into float32), ``exp(s - lse)`` in float32
+ADDED into one float32 ``(bq, bk)`` accumulator, so no ``(H, rows, keys)``
+array exists; the indexer's scores of the tile as ``indexer_scores``
+forms them (TARGET_HEADS heads a matmul); the row's ``sum p``, the
+selected scores' running log-sum-exp and the loss's terms in ``(bq,
+128)`` scratch.  The gradient of a pair, ``d_score = (sum_k p) softmax(
+score) - p``, needs two statistics of its whole row, so a row block
+keeps ``p`` and the selected scores of its causal tiles in VMEM (two
+float32 ``(bq, T)``: 16 MiB at 256 rows of 8192) and sweeps its key
+tiles a SECOND time: the indexer's products again, ``d_z`` a head in
+bfloat16, ``d_qi`` and ``d_w`` summed a row block, ``d_ki`` over the
+whole call in ONE float32 ``(T, Di)`` scratch (the grid runs in order on
+the chip's one core); all three leave as the mean row loss's gradient
+in their inputs' dtypes.  q and ``qi`` come in as ``(T, H * Dh)`` and
+``(T, Hi * Di)`` rows, what they are but for a reshape, and a row block
+cuts its heads out of the lanes once; ``d_qi`` leaves the same way.  The
+call is one inner ``jax.jit``, so the layers and modules of a process
+share one traced kernel.  Its device names are ``dsa_target_grads`` and
+``dsa_target_loss``: not ``splash_mha*``, which ``dsa_attn_roofline``'s
+work function does not count it under.
 """
 from __future__ import annotations
 
@@ -37,7 +66,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["selected_attention_fwd", "forward_tiles"]
+__all__ = ["selected_attention_fwd", "forward_tiles", "selected_target",
+           "target_tiles"]
 
 LANES = 128
 # what masks a score: the library's value, so a row's log-sum-exp over a
@@ -182,3 +212,275 @@ def selected_attention_fwd(q, k, v, mask, interpret: bool = False):
     )(q.reshape(t, h * dh), k.reshape(t, hkv * dh), v.reshape(t, hkv * dv),
       mask.astype(jnp.int8))
     return out.reshape(t, h, dv), lse.reshape(h, t)
+
+
+# The target pass: a query tile's rows against its causal key tiles, TWICE
+# when the indexer's gradient is wanted (the gradient of a pair needs two
+# statistics of its whole row).  Rows and keys a tile and the indexer
+# heads stacked under one matmul; between the sweeps a row block keeps two
+# float32 numbers a pair in VMEM (TARGET_KEPT bounds them: 16 MiB at 256
+# rows of 8192 keys), so the kernel asks for more than Mosaic's default
+# scoped VMEM (TARGET_VMEM of a v5e's 128 MiB).  Measured on a v5e at 32
+# heads over 4 and a 16 x 64 indexer, 8192 rows under a top-2048, ms a
+# call with the gradient / loss only (PERF.md, PR 60): 256 x 512 x 16
+# 6.30 / 3.59, x 8 6.62 / 3.75, x 4 6.83 / 3.88; 512 x 512 x 8 6.39 / 3.69
+# at twice the kept rows (T up to 8192 only) and 7.8 s to the first call
+# where 256 x 512 x 16 takes 5; and, while the gradients still left as
+# float32 sums (x 4 then 6.95 / 3.90): x 2 7.30 / 4.15, 256 x 256 x 4
+# 7.56 / 4.08, 256 x 1024 x 4 7.06 / 4.00
+TARGET_BQ, TARGET_BK, TARGET_HEADS = 256, 512, 16
+TARGET_KEPT = 16 << 20
+TARGET_VMEM = 100 << 20
+
+
+def target_tiles(t: int, hi: int):
+    """``(bq, bk, indexer heads a matmul)`` of the target kernel for
+    sequences of ``t`` rows under ``hi`` indexer heads, or None where the
+    kernel does not take them (``t`` not in whole 128s, or so long that
+    128 rows' kept pairs pass TARGET_KEPT)."""
+    rows = TARGET_KEPT // (8 * t) // LANES * LANES if t % LANES == 0 else 0
+    if not rows:
+        return None
+    bk = _whole(t, TARGET_BK)
+    return (_whole(bk, min(TARGET_BQ, rows)), bk,
+            next(n for n in range(min(TARGET_HEADS, hi), 0, -1)
+                 if hi % n == 0))
+
+
+def _lanes(x, n: int):
+    """A row's value in 128 lanes -> in ``n``."""
+    return jnp.tile(x, (1, n // LANES))
+
+
+def _lane_sums(x):
+    """``(rows, n)`` -> ``(rows, 128)``: the lane tiles added up, a row's
+    sum still spread over 128 lanes."""
+    return sum(x[:, at:at + LANES] for at in range(0, x.shape[1], LANES))
+
+
+def _target_step(q_ref, k_ref, lse_ref, mask_ref, qi_ref, ki_ref, w_ref,
+                 loss_ref, *rest, t, h, hkv, hi, bq, bk, stack, grads):
+    if grads:
+        dqi_ref, dki_ref, dw_ref, *rest = rest
+    (q_st, lse_st, qi_st, w_st, top_ref, sum_ref, mass_ref, part_ref,
+     *kept) = rest
+    if grads:
+        p_buf, x_buf, dqi_acc, dki_acc, dw_acc = kept
+    i, sweep, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    last_tile = ((i + 1) * bq - 1) // bk
+    group, dh = h // hkv, q_ref.shape[1] // h
+    di = qi_ref.shape[1] // hi
+    rows_a_loss = np.float32(t)
+    f32 = jnp.float32
+    factor = np.float32(di ** -0.5 * hi ** -0.5)
+    nt = (((1,), (1,)), ((), ()))
+
+    def products(at):
+        """The float32 products of ``stack`` indexer heads' rows, one head
+        under another, with this tile's keys."""
+        rows = qi_st[pl.ds(at, stack)].reshape(stack * bq, di)
+        return rows, jax.lax.dot_general(rows, ki_ref[...], nt,
+                                         preferred_element_type=f32)
+
+    @pl.when((sweep == 0) & (j == 0))
+    def _():
+        # the row block's operands as the tiles read them: a key/value
+        # head's query heads one under another, a row's log-sum-exp and
+        # head weight in every lane
+        for n in range(h):
+            at = (n // group, slice(n % group * bq, (n % group + 1) * bq))
+            q_st[at] = q_ref[:, n * dh:(n + 1) * dh]
+            lse_st[at] = jnp.broadcast_to(lse_ref[:, n:n + 1], (bq, LANES))
+        for n in range(hi):
+            qi_st[n] = qi_ref[:, n * di:(n + 1) * di]
+            w_st[n] = jnp.broadcast_to(w_ref[:, n:n + 1].astype(f32),
+                                       (bq, LANES))
+        top_ref[...] = jnp.full_like(top_ref, MASK_VALUE)
+        for ref in (sum_ref, mass_ref, part_ref):
+            ref[...] = jnp.zeros_like(ref)
+
+    @pl.when((sweep == 0) & (j <= last_tile))
+    def _():
+        def heads(n, acc):
+            s = jax.lax.dot_general(q_st[n], k_ref[n], nt,
+                                    preferred_element_type=f32)
+            a = jnp.exp(s - _lanes(lse_st[n], bk))
+            return acc + a.reshape(group, bq, bk).sum(axis=0)
+
+        def indexer(n, acc):
+            _, z = products(n * stack)
+            for u in range(stack):
+                acc = acc + jnp.maximum(z[u * bq:(u + 1) * bq], 0.0) \
+                    * _lanes(w_st[n * stack + u], bk)
+            return acc
+
+        zeros = jnp.zeros((bq, bk), f32)
+        keep = mask_ref[...].astype(jnp.int32) != 0
+        p = jnp.where(keep, jax.lax.fori_loop(0, hkv, heads, zeros), 0.0) \
+            / np.float32(h)
+        score = jax.lax.fori_loop(0, hi // stack, indexer, zeros) * factor
+        x = jnp.where(keep, score, MASK_VALUE)
+        top = jnp.maximum(top_ref[...], x.max(axis=1, keepdims=True))
+        # (a row none of whose keys came yet counts its masked pairs, and
+        # the first selected score's factor wipes them: the forward's way)
+        sum_ref[...] = jnp.exp(top_ref[...] - top) * sum_ref[...] \
+            + jnp.exp(x - _lanes(top, bk)).sum(axis=1, keepdims=True)
+        top_ref[...] = top
+        mass_ref[...] += p.sum(axis=1, keepdims=True)
+        part_ref[...] += jnp.where(p > 0.0, p * (jnp.log(p) - score),
+                                   0.0).sum(axis=1, keepdims=True)
+        if grads:
+            p_buf[j] = p
+            x_buf[j] = x
+
+    @pl.when((sweep == 0) & (j == last_tile))
+    def _():
+        # the selected scores' log-sum-exp in top_ref, the row losses' sum
+        top_ref[...] += jnp.log(sum_ref[...])
+        rows = part_ref[...] + mass_ref[...] * top_ref[...]
+        loss_ref[...] = jnp.broadcast_to(
+            jnp.sum(rows[:, :1], axis=0, keepdims=True), loss_ref.shape)
+
+    if not grads:
+        return
+
+    @pl.when((i == 0) & (sweep == 0) & (j == 0))
+    def _():
+        dki_acc[...] = jnp.zeros_like(dki_acc)
+
+    @pl.when((sweep == 1) & (j == 0))
+    def _():
+        dqi_acc[...] = jnp.zeros_like(dqi_acc)
+        dw_acc[...] = jnp.zeros_like(dw_acc)
+
+    @pl.when((sweep == 1) & (j <= last_tile))
+    def _():
+        # d loss / d score of the tile's pairs, the scores' factor in it
+        d = (_lanes(mass_ref[...], bk)
+             * jnp.exp(x_buf[j] - _lanes(top_ref[...], bk))
+             - p_buf[j]) * factor
+
+        def indexer(n, carry):
+            rows, z = products(n * stack)
+            dz = []
+            for u in range(stack):
+                z_u = z[u * bq:(u + 1) * bq]
+                dw_acc[n * stack + u] += _lane_sums(
+                    jnp.maximum(z_u, 0.0) * d)
+                dz.append(jnp.where(
+                    z_u > 0.0, d * _lanes(w_st[n * stack + u], bk),
+                    0.0).astype(rows.dtype))
+            dz = jnp.concatenate(dz, axis=0)
+            dqi_acc[pl.ds(n * stack, stack)] += jnp.dot(
+                dz, ki_ref[...], preferred_element_type=f32).reshape(
+                    stack, bq, di)
+            keys = pl.ds(pl.multiple_of(j * bk, bk), bk)
+            dki_acc[keys, :] += jax.lax.dot_general(
+                dz, rows, (((0,), (0,)), ((), ())),
+                preferred_element_type=f32)
+            return carry
+
+        jax.lax.fori_loop(0, hi // stack, indexer, 0)
+
+    # the gradients leave as the MEAN row loss's, in their inputs' dtypes:
+    # nothing float32 of them is left for XLA to keep until the backward
+    # pass scales them (64 MiB a layer of d_qi as the kernel's sums)
+    @pl.when((sweep == 1) & (j == last_tile))
+    def _():
+        for n in range(hi):
+            dqi_ref[:, n * di:(n + 1) * di] = (dqi_acc[n] / rows_a_loss).astype(
+                dqi_ref.dtype)
+        dw_ref[...] = (jnp.concatenate(
+            [dw_acc[n].sum(axis=1, keepdims=True) for n in range(hi)],
+            axis=1) / rows_a_loss).astype(dw_ref.dtype)
+
+    @pl.when((i == t // bq - 1) & (sweep == 1) & (j == last_tile))
+    def _():
+        dki_ref[...] = (dki_acc[...] / rows_a_loss).astype(dki_ref.dtype)
+
+
+def selected_target(qi, ki, w, q, k, lse, mask, grads: bool,
+                    interpret: bool = False):
+    """The index loss of one sequence and, with ``grads``, its gradient
+    by the indexer's three inputs: ``(T, Hi, Di)`` indexer queries, ``(T,
+    Di)`` indexer keys, ``(T, Hi)`` head weights, the attend pass's ``(T,
+    H, Dh)`` scaled queries, ``(T, Hkv, Dh)`` keys and float32
+    log-sum-exp ``(H, T)``, and the selection ``(T, T)`` (bool or int8)
+    -> ``(mean row loss, its gradient by (qi, ki, w) in their dtypes or
+    None)`` (the module's docstring has the kernel).  ``target_tiles``
+    must take ``T``."""
+    return _selected_target(
+        qi, ki, w, q, k, lse, mask, grads=grads, interpret=interpret,
+        tiles=target_tiles(q.shape[0], qi.shape[1]))
+
+
+# lint: allow(raw-jit) — never dispatched on its own: a jit inside the step
+# program, there so that every layer and module shares one traced kernel
+# and one lowered function (a trace and a lowering of it are ~0.9 s of
+# host time on the benchmark's machine, and set-up is judged)
+@functools.partial(jax.jit, static_argnames=("grads", "interpret", "tiles"))
+def _selected_target(qi, ki, w, q, k, lse, mask, *, grads, interpret, tiles):
+    t, h, dh = q.shape
+    hkv, hi, di = k.shape[1], qi.shape[1], qi.shape[2]
+    bq, bk, stack = tiles
+    group = h // hkv
+    f32 = jnp.float32
+
+    def diagonal(i):
+        return ((i + 1) * bq - 1) // bk
+
+    def keys_of(i, j):
+        # a step beyond the diagonal keeps the diagonal's blocks
+        return jnp.minimum(j, diagonal(i))
+
+    def first_sweep(i, s, j):
+        # what only the first sweep reads stays where that sweep ended
+        return jnp.where(s == 0, keys_of(i, j), diagonal(i))
+
+    out_specs = [pl.BlockSpec((None, 8, LANES), lambda i, s, j: (i, 0, 0))]
+    out_shape = [jax.ShapeDtypeStruct((t // bq, 8, LANES), f32)]
+    scratch = [pltpu.VMEM((hkv, group * bq, dh), q.dtype),
+               pltpu.VMEM((hkv, group * bq, LANES), f32),
+               pltpu.VMEM((hi, bq, di), qi.dtype),
+               pltpu.VMEM((hi, bq, LANES), f32)] \
+        + [pltpu.VMEM((bq, LANES), f32)] * 4
+    if grads:
+        out_specs += [
+            pl.BlockSpec((bq, hi * di), lambda i, s, j: (i, 0)),
+            pl.BlockSpec((t, di), lambda i, s, j: (0, 0)),
+            pl.BlockSpec((bq, hi), lambda i, s, j: (i, 0))]
+        out_shape += [jax.ShapeDtypeStruct((t, hi * di), qi.dtype),
+                      jax.ShapeDtypeStruct(ki.shape, ki.dtype),
+                      jax.ShapeDtypeStruct(w.shape, w.dtype)]
+        scratch += [pltpu.VMEM((t // bk, bq, bk), f32)] * 2 \
+            + [pltpu.VMEM((hi, bq, di), f32), pltpu.VMEM((t, di), f32),
+               pltpu.VMEM((hi, bq, LANES), f32)]
+    # lint: allow(raw-pallas-call) — one lowering of the op's target pass,
+    # chosen by platform and held to the plain blocks by tolerance
+    # (tests/test_sparse_attention.py, tests/tpu/test_keye_tpu.py)
+    loss, *unit = pl.pallas_call(
+        functools.partial(_target_step, t=t, h=h, hkv=hkv, hi=hi, bq=bq,
+                          bk=bk, stack=stack, grads=grads),
+        grid=(t // bq, 2 if grads else 1, t // bk),
+        in_specs=[
+            pl.BlockSpec((bq, h * dh), lambda i, s, j: (i, 0)),
+            pl.BlockSpec((hkv, bk, dh),
+                         lambda i, s, j: (0, first_sweep(i, s, j), 0)),
+            pl.BlockSpec((bq, h), lambda i, s, j: (i, 0)),
+            pl.BlockSpec((bq, bk),
+                         lambda i, s, j: (i, first_sweep(i, s, j))),
+            pl.BlockSpec((bq, hi * di), lambda i, s, j: (i, 0)),
+            pl.BlockSpec((bk, di), lambda i, s, j: (keys_of(i, j), 0)),
+            pl.BlockSpec((bq, hi), lambda i, s, j: (i, 0))],
+        out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=TARGET_VMEM),
+        interpret=interpret,
+        name="dsa_target_grads" if grads else "dsa_target_loss",
+    )(q.reshape(t, h * dh), k.transpose(1, 0, 2), lse.T,
+      mask.astype(jnp.int8), qi.reshape(t, hi * di), ki, w)
+    loss = jnp.sum(loss[:, 0, 0]) / t
+    if not grads:
+        return loss, None
+    return loss, (unit[0].reshape(qi.shape), unit[1], unit[2])

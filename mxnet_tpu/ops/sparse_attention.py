@@ -37,11 +37,17 @@ or gathers ``(T, topk, ..)``:
   matmuls hide), fed the forward's log-sum-exp.  ``dsa:lowering`` records
   which lowering and the heads a loaded mask tile serves.
 * ``target``: the heads' probabilities formed again from the saved
-  log-sum-exp a block of rows at a time, summed over the heads, against
-  the scores formed again: the row losses and, while the op is being
-  differentiated, the indexer's gradient for a unit cotangent (it is
-  linear in the sequence's one cotangent, so the backward pass scales
-  it and runs no pass over the pairs for the indexer).
+  log-sum-exp, summed over the heads, against the scores formed again:
+  the row losses and, while the op is being differentiated, the
+  indexer's gradient for a unit cotangent (it is linear in the
+  sequence's one cotangent, so the backward pass scales it and runs no
+  pass over the pairs for the indexer).  Two lowerings again: the plain
+  blocks of rows (float32 ``(Hkv, H / Hkv, rows, keys)`` and ``(rows,
+  Hi, keys)`` products through memory); beside the attend kernels ONE
+  kernel a layer of this repo's (``selected_target``), which sums the
+  heads' probabilities of a tile in VMEM under the same int8 tile of the
+  selection and sweeps a row block's key tiles twice for the gradient.
+  ``dsa:lowering``'s ``target_kernel`` records which.
 
 Device scopes: ``dsa_score.l<i>``, ``dsa_select.l<i>``, ``dsa_attn.l<i>``,
 ``dsa_kl.l<i>``.
@@ -59,14 +65,16 @@ from .. import trace
 from ..base import MXNetError
 from .pallas_kernels import _kernel_on_tpu
 from .registry import OpDef, Param, register_op
-from .selected_attention import forward_tiles, selected_attention_fwd
+from .selected_attention import (forward_tiles, selected_attention_fwd,
+                                 selected_target, target_tiles)
 from .transformer import _kernel_takes, _kernel_tiles, layer_scope
 
 __all__ = ["indexed_attention", "indexer_scores", "select_keys"]
 
-# query rows a block of the select and target passes: a block's float32
-# indexer products are bq * Hi * T * 4 bytes (128 MiB at 16 heads and 8192
-# keys), the target's head products bq * H * T * 4 (256 MiB at 32 heads)
+# query rows a block of the select pass and of the target pass's plain
+# blocks: a block's float32 indexer products are bq * Hi * T * 4 bytes
+# (128 MiB at 16 heads and 8192 keys), the plain target's head products
+# bq * H * T * 4 (256 MiB at 32 heads; its kernel holds a tile's in VMEM)
 DSA_BLOCK_Q = 256
 # the select and target passes walk the rows in this many groups, each
 # over its own causal keys only (a static slice a group: four programs of
@@ -335,7 +343,18 @@ def _target(qi, ki, w, q, k, lse, mask, layer, with_grads: bool):
                       (outs[2].reshape(w.shape) / t).astype(w.dtype))
 
 
-def _one_sequence(args, topk, scale, layer, kernel, with_grads):
+def _target_kernel(qi, ki, w, q, k, lse, mask, layer, with_grads: bool,
+                   interpret: bool = False):
+    """``_target``'s TPU lowering: this repo's kernel
+    (``ops/selected_attention.py`` ``selected_target``), which holds a
+    tile's products in VMEM."""
+    with layer_scope("dsa_kl", layer), \
+            jax.default_matmul_precision("default"):
+        return selected_target(qi, ki, w, q, k, lse, mask, with_grads,
+                               interpret)
+
+
+def _one_sequence(args, topk, scale, layer, kernel, target, with_grads):
     """All three passes over one sequence."""
     q, k, v, qi, ki, w = args
     mask = _select(qi, ki[:, 0], w, topk, layer)
@@ -348,8 +367,14 @@ def _one_sequence(args, topk, scale, layer, kernel, with_grads):
                                       qs, k, v, mask)
         else:
             out, lse = _attend_plain(qs, k, v, mask)
-    loss, grads = _target(qi, ki[:, 0], w, qs, k, lse, mask, layer,
-                          with_grads)
+    by_kernel, plain = (
+        functools.partial(fn, layer=layer, with_grads=with_grads)
+        for fn in (_target_kernel, _target))
+    operands = (qi, ki[:, 0], w, qs, k, lse, mask)
+    if target:
+        loss, grads = _kernel_on_tpu(by_kernel, plain, False, *operands)
+    else:
+        loss, grads = plain(*operands)
     return (out, loss, stats), (mask, lse, grads)
 
 
@@ -363,21 +388,22 @@ def _over_batch(fn, args):
     return lax.map(fn, args)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
-def _indexed_attention(q, k, v, qi, ki, w, topk, scale, layer, kernel):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+def _indexed_attention(q, k, v, qi, ki, w, topk, scale, layer, kernel,
+                       target):
     return _over_batch(
-        lambda args: _one_sequence(args, topk, scale, layer, kernel,
+        lambda args: _one_sequence(args, topk, scale, layer, kernel, target,
                                    False)[0], (q, k, v, qi, ki, w))
 
 
-def _indexed_fwd(q, k, v, qi, ki, w, topk, scale, layer, kernel):
+def _indexed_fwd(q, k, v, qi, ki, w, topk, scale, layer, kernel, target):
     outs, (mask, lse, grads) = _over_batch(
-        lambda args: _one_sequence(args, topk, scale, layer, kernel, True),
-        (q, k, v, qi, ki, w))
+        lambda args: _one_sequence(args, topk, scale, layer, kernel, target,
+                                   True), (q, k, v, qi, ki, w))
     return outs, (q, k, v, mask, outs[0], lse, grads)
 
 
-def _indexed_bwd(topk, scale, layer, kernel, res, cotangents):
+def _indexed_bwd(topk, scale, layer, kernel, target, res, cotangents):
     q, k, v, mask, out, lse, unit = res
     g_out, g_loss, _ = cotangents
 
@@ -421,7 +447,9 @@ def indexed_attention(q, k, v, qi, ki, w, topk: int, scale: float,
     with ``heads_a_mask_tile`` the query heads that the forward kernel
     serves from one loaded tile of the selection; ``plain`` 1: the plain
     blocks everywhere, ``heads_a_mask_tile`` 0); the track names dtype,
-    shape, ``/kv<Hkv>`` and ``/top<topk>``."""
+    shape, ``/kv<Hkv>`` and ``/top<topk>``.  ``target_kernel`` 1: the
+    target pass runs its kernel beside them (the indexer in the same
+    dtype, heads of whole 64 lanes, ``target_tiles`` takes ``T``)."""
     h, hkv = q.shape[2], k.shape[2]
     if h % hkv or v.shape[2] != hkv or ki.shape[2] != 1:
         raise MXNetError("indexed attention: %d query heads over %d key and "
@@ -431,12 +459,17 @@ def indexed_attention(q, k, v, qi, ki, w, topk: int, scale: float,
     kernel = _kernel_takes(q, k, v) and q.shape[3] % 128 == 0
     heads = forward_tiles(q.shape[1], h // hkv,
                           max(q.shape[3], v.shape[3]))[0] if kernel else 0
+    # the target kernel beside them: the indexer in the same dtype, heads
+    # of whole half lane blocks, a length whose kept rows fit its VMEM
+    target = bool(kernel and qi.shape[3] % 64 == 0
+                  and all(x.dtype == q.dtype for x in (qi, ki, w))
+                  and target_tiles(q.shape[1], qi.shape[2]))
     trace.counter("dsa:lowering", cat="ops", track="%s%s%s/top%d" % (
         q.dtype.name, list(q.shape), "" if hkv == h else "/kv%d" % hkv,
         topk), kernel=int(kernel), plain=int(not kernel),
-        heads_a_mask_tile=heads)
+        heads_a_mask_tile=heads, target_kernel=int(target))
     return _indexed_attention(q, k, v, qi, ki, w, int(topk), float(scale),
-                              layer, kernel)
+                              layer, kernel, target)
 
 
 @register_op("IndexedSelfAttention", hint="indexedattention")
@@ -464,7 +497,9 @@ class IndexedSelfAttentionOp(OpDef):
     ``T`` in whole tiles) runs two kernels: forward this repo's
     ``splash_mha_fwd_selected`` (``ops/selected_attention.py``), backward
     the library's fused splash-attention kernel under the selection as a
-    dynamic mask; everything else runs the plain blocks both ways."""
+    dynamic mask; the target pass beside them one kernel a layer,
+    ``dsa_target_grads`` (``dsa_target_loss`` where nothing is
+    differentiated); everything else runs the plain blocks."""
     params = [Param("topk", int, required=True),
               Param("scale", float, default=0.0),
               Param("layer", int, default=-1)]

@@ -3,7 +3,7 @@ the op against the layer's equations written out by hand with dense ``(T,
 T)`` arrays (outputs, cotangents both ways), the selection's count and
 tie rule by both k-th-value methods, the isolation of the two losses as
 EXACT zeros, ``topk >= T`` against ``CausalSelfAttention``, the TPU
-kernels interpreted at a small shape, and the two small ops the model
+kernels (attend and target) interpreted at small shapes, and the two small ops the model
 brings with it (``LayerNorm``, ``RotaryEmbedding(sections=)``)."""
 import numpy as np
 import jax
@@ -249,6 +249,94 @@ def test_the_kernel_lowering_interpreted_is_the_plain_blocks(monkeypatch):
     _both_kernels_against_the_plain_blocks(5, 256, 4, 2)
 
 
+def _target_inputs(t, h, hkv, topk, tied):
+    """bfloat16 operands of 128-lane heads under a 4 x 64 indexer, the
+    selection and the plain attend pass's log-sum-exp.  ``tied``: every
+    indexer key stands twice, so every score has its equal."""
+    q, k, v, qi, ki, w = (x[0] for x in inputs(t + h, 1, t, h, hkv,
+                                               jnp.bfloat16, dh=128))
+    rng = np.random.RandomState(t + hkv)
+    qi = jnp.asarray(rng.randn(t, HI, 64), jnp.bfloat16)
+    ki = jnp.asarray(rng.randn(t, 64), jnp.bfloat16)
+    if tied:
+        ki = jnp.concatenate([ki[:t // 2], ki[:t // 2]])
+    mask = sa._select(qi, ki, w, topk, None)
+    assert int(mask.sum()) == sum(min(i + 1, topk) for i in range(t))
+    qs = q * jnp.bfloat16(128 ** -0.5)
+    return (qi, ki, w, qs, k, sa._attend_plain(qs, k, v, mask)[1], mask)
+
+
+@pytest.mark.parametrize("with_grads", [False, True],
+                         ids=["loss-only", "with-gradient"])
+@pytest.mark.parametrize("h,hkv,topk,tied,tiles", [
+    (8, 1, 48, False, (128, 128)), (16, 4, 48, False, (128, 128)),
+    (4, 2, 1000, False, (128, 128)), (4, 2, 48, True, (128, 128)),
+    (4, 2, 48, False, (128, 256))],
+    ids=["groups-of-8-over-1", "groups-of-4-over-4", "topk-above-T",
+         "tied-kth-scores", "half-a-key-tile-behind-the-diagonal"])
+def test_the_target_kernel_interpreted_is_the_plain_blocks(
+        monkeypatch, with_grads, h, hkv, topk, tied, tiles):
+    """``selected_target`` at 384 rows (three query tiles of 128: with
+    key tiles of 256 the first and the third end half-way into one)
+    against ``_target`` on the same selection and log-sum-exp: the mean
+    row loss and the three unit gradients, cast as the op casts them."""
+    monkeypatch.setattr(sel, "TARGET_BQ", tiles[0])
+    monkeypatch.setattr(sel, "TARGET_BK", tiles[1])
+    monkeypatch.setattr(sel, "TARGET_HEADS", 2)
+    t = 384 if tiles[1] == 128 else 512 + 256
+    assert sel.target_tiles(t, HI) == tiles + (2,)
+    args = _target_inputs(t, h, hkv, topk, tied)
+    loss, unit = sa._target_kernel(*args, None, with_grads, interpret=True)
+    want_loss, want = sa._target(*args, None, with_grads)
+    assert loss.dtype == want_loss.dtype == jnp.float32
+    assert float(want_loss) > 1e-3
+    assert abs(float(loss) - float(want_loss)) <= 1e-3 * float(want_loss)
+    if not with_grads:
+        assert unit is None and want is None
+        return
+    for a, b, x in zip(unit, want, args[:3]):
+        assert a.dtype == b.dtype == x.dtype and a.shape == b.shape == x.shape
+        assert _rel(a, b) < 0.02
+
+
+def test_the_target_kernels_tiles_follow_the_shapes():
+    """256 rows against 512 keys and the sixteen indexer heads under one
+    matmul at the Keye cell's shape; fewer rows where a row's kept pairs
+    are longer, none beyond what 128 rows may keep; the stacked heads
+    divide Hi."""
+    assert sel.target_tiles(8192, 16) == (256, 512, 16)
+    assert sel.target_tiles(16384, 64) == (128, 512, 16)
+    assert sel.target_tiles(32768, 16) is None
+    assert sel.target_tiles(8192 + 64, 16) is None
+    assert sel.target_tiles(384, 6) == (128, 384, 6)
+    assert sel.target_tiles(2048, 24) == (256, 512, 12)
+
+
+def test_the_op_with_both_flags_off_the_chip_is_the_plain_blocks():
+    """A program lowered for the CPU runs the plain blocks under either
+    flag: outputs and cotangents bit for bit."""
+    args = inputs(9, 1, 128, 4, 2, jnp.bfloat16, dh=128)
+    args = args[:3] + tuple(jnp.asarray(
+        np.random.RandomState(i).randn(*shape), jnp.bfloat16)
+        for i, shape in enumerate([(1, 128, HI, 64), (1, 128, 1, 64)])) \
+        + args[5:]
+
+    def both(kernel):
+        def run(*a):
+            outs, vjp = jax.vjp(lambda *x: sa._indexed_attention(
+                *x, 16, 128 ** -0.5, 0, kernel, kernel)[:2], *a)
+            return outs + vjp((jnp.ones_like(outs[0]),
+                               jnp.ones((1,), jnp.float32)))
+        return jax.jit(run)(*args)
+
+    for a, b in zip(both(True), both(False)):
+        assert a.dtype == b.dtype and np.array_equal(
+            np.asarray(a, np.float32), np.asarray(b, np.float32))
+    out = jax.jit(lambda *a: sa._indexed_attention(
+        *a, 16, 128 ** -0.5, 0, True, True))(*args)
+    assert np.isfinite(float(out[1][0]))
+
+
 def test_the_op_node_its_shapes_and_its_counter():
     q, k, v, qi, ki, w = (mx.sym.Variable(n) for n in
                           ("q", "k", "v", "qi", "ki", "w"))
@@ -275,20 +363,27 @@ def test_the_op_node_its_shapes_and_its_counter():
     finally:
         mx.trace.reset()
         mx.trace.set_enabled(was)
-    assert events and events[-1]["args"] == {"kernel": 0, "plain": 1,
-                                             "heads_a_mask_tile": 0}
-    # what the kernels take: the group a mask tile serves beside them
+    assert events and events[-1]["args"] == {
+        "kernel": 0, "plain": 1, "heads_a_mask_tile": 0, "target_kernel": 0}
+    # what the kernels take: the group a mask tile serves beside them;
+    # the target kernel an indexer of 64-lane heads in the same dtype
+    took = inputs(2, 1, 256, 8, 2, jnp.bfloat16, dh=128)
+    wide = tuple(jax.ShapeDtypeStruct(x.shape[:3] + (64,), x.dtype)
+                 for x in took[3:5])
     mx.trace.set_enabled(True)
     try:
-        jax.eval_shape(op(8, 128 ** -0.5), *inputs(2, 1, 256, 8, 2,
-                                                   jnp.bfloat16, dh=128))
+        jax.eval_shape(op(8, 128 ** -0.5), *took)
+        jax.eval_shape(op(8, 128 ** -0.5), *took[:3], *wide, took[5])
+        jax.eval_shape(op(8, 128 ** -0.5), *took[:3], *wide,
+                       took[5].astype(jnp.float32))
         events = mx.trace.counter_events(["dsa:lowering"])
     finally:
         mx.trace.reset()
         mx.trace.set_enabled(was)
     assert [(e["id"], e["args"]) for e in events] == [
         ("bfloat16[1, 256, 8, 128]/kv2/top8",
-         {"kernel": 1, "plain": 0, "heads_a_mask_tile": 4})]
+         {"kernel": 1, "plain": 0, "heads_a_mask_tile": 4,
+          "target_kernel": n}) for n in (0, 1, 0)]
 
 
 def test_layer_norm_is_its_equation():

@@ -21,7 +21,10 @@ plain lowering at ``(1, 2048, 32, 128)`` over 4 key/value heads under a
 16 x 64 indexer's top-512 (the forward, the index loss and both
 cotangents), and at the cell's ``(1, 8192, 32, 128)`` under the top-2048
 the forward kernel against the plain blocks, the three passes' times in
-isolation with the k-th value by either method, and three forward
+isolation with the k-th value by either method (the target pass by both
+lowerings: the plain blocks and, since PR 60, the kernel ``dsa_target_*``,
+whose name and the absence of the blocks' float32 head products the
+compiled text is held to), and three forward
 attend passes side by side (PR 58): the library's splash attention under
 the selection as a dynamic mask (the op's forward until PR 58), the
 library's under a static causal mask (the floor of a kernel that visits
@@ -31,6 +34,7 @@ call and as its ``splash_mha*`` operation's time in a device trace.
 import gc
 import json
 import os
+import re
 import sys
 import tempfile
 import time
@@ -225,7 +229,8 @@ def test_published_width_step_matches_reference():
         # one op a layer, every one the kernel lowering
         assert len(bf16["dsa_lowering"]) == kw["num_layers"]
         for track, args in bf16["dsa_lowering"]:
-            assert args == {"kernel": 1, "plain": 0, "heads_a_mask_tile": 8}
+            assert args == {"kernel": 1, "plain": 0, "heads_a_mask_tile": 8,
+                            "target_kernel": 1}
             assert track == "bfloat16[1, 8192, 32, 128]/kv4/top2048"
     # float8 weights are refused by at least one limit
     assert fp8["loss_rel_err"] > limits["loss_rtol"] or any(
@@ -320,7 +325,7 @@ def test_the_op_alone_both_lowerings_and_the_passes_times():
         def run(*args):
             outs, vjp = jax.vjp(
                 lambda *a: sa._indexed_attention(*a, topk, scale, 0,
-                                                 kernel)[:2], *args)
+                                                 kernel, kernel)[:2], *args)
             return outs + vjp((g[:, :args[0].shape[1]].astype(outs[0].dtype),
                                jnp.ones((1,), jnp.float32)))
         return jax.jit(run)
@@ -331,7 +336,16 @@ def test_the_op_alone_both_lowerings_and_the_passes_times():
     text = kernel.lower(*short).compile().as_text()
     assert "tpu_custom_call" in text and "splash_mha_fwd_selected" in text
     assert "splash_mha_dkv" in text and "splash_mha_fwd_residuals" not in text
-    assert "tpu_custom_call" not in plain.lower(*short).compile().as_text()
+    # the target pass: this repo's kernel (no ``splash_mha`` in its name:
+    # dsa_attn_roofline's reader goes by that prefix) and none of the plain
+    # blocks' float32 head products ``(Hkv, H / Hkv, 256, keys)``
+    blocks = re.compile(r"f32\[%d,(%d,256,\d+|\d+,256,%d)\]"
+                        % (hkv, h // hkv, h // hkv))
+    assert "dsa_target_grads" in text and not blocks.search(text)
+    assert "splash_mha_dsa" not in text and "splash_mha_target" not in text
+    plain_text = plain.lower(*short).compile().as_text()
+    assert "tpu_custom_call" not in plain_text and blocks.search(plain_text)
+    del text, plain_text
     got = [np.asarray(x, np.float32) for x in kernel(*short)]
     want = [np.asarray(x, np.float32) for x in plain(*short)]
     names = ["output", "index_loss", "d_q", "d_k", "d_v", "d_qi", "d_ki",
@@ -394,10 +408,19 @@ def test_the_op_alone_both_lowerings_and_the_passes_times():
     times["attend_kernel_backward"] = ms(
         jax.jit(sa._attend_kernel_bwd), qs, one[1], one[2], mask, out, lse,
         g[0].astype(jnp.bfloat16))
+    # the target pass in isolation, both lowerings (PR 60)
     for grads in (False, True):
-        times["target_with_gradient" if grads else "target_loss_only"] = ms(
-            jax.jit(lambda *a: sa._target(*a, 0, grads)),
-            one[3], one[4], one[5], qs, one[1], lse, mask)
+        name = "target_with_gradient" if grads else "target_loss_only"
+        passes = [jax.jit(lambda *a, fn=fn: fn(*a, 0, grads))
+                  for fn in (sa._target, sa._target_kernel)]
+        operands = (one[3], one[4], one[5], qs, one[1], lse, mask)
+        times[name], times[name + "_kernel"] = (ms(fn, *operands)
+                                                for fn in passes)
+        (want_loss, want), (loss, unit) = (fn(*operands) for fn in passes)
+        report["l2_err"][name + "_8192_loss"] = _rel(loss, want_loss)
+        for n, a, b in zip(("d_qi", "d_ki", "d_w"), unit or (), want or ()):
+            report["l2_err"]["target_8192_" + n] = _rel(a, b)
+        del want, unit
     times["op_forward_backward_kernel"] = ms(both_passes(True, topk),
                                              q, k, v, qi, ki, w)
     report["ms_a_layer"] = times
@@ -420,3 +443,10 @@ def test_the_op_alone_both_lowerings_and_the_passes_times():
     assert report["l2_err"]["index_loss"] <= 1e-3, report
     for n in ("d_qi", "d_ki", "d_w"):
         assert report["l2_err"][n] <= 0.02, report
+        assert report["l2_err"]["target_8192_" + n] <= 0.02, report
+    # the target kernel on the same selection and log-sum-exp: the plain
+    # blocks' loss, in under two thirds of their time with the gradient
+    for n in ("target_loss_only", "target_with_gradient"):
+        assert report["l2_err"][n + "_8192_loss"] <= 1e-3, report
+    assert times["target_with_gradient_kernel"] * 1.5 \
+        <= times["target_with_gradient"], times
