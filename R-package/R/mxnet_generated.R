@@ -47,6 +47,7 @@ mx.symbol.Cast <- function(...) {
 #' @param kernel kernel parameter (default 4)
 #' @param lanes lanes parameter
 #' @param act_type act_type parameter (one of relu, sigmoid, tanh, softrelu, silu)
+#' @param gated gated parameter
 #' @param name string, node name (auto when omitted)
 #' @return MXSymbol
 mx.symbol.CausalConv1D <- function(...) {

@@ -118,15 +118,16 @@ def gqa_attention(h, pre, layer, rows, num_heads, num_kv_heads, head_dim,
 LAYER_KINDS = ("sliding", "full")
 
 
-def layer_kinds(layer_types, num_layers):
-    """``layer_types`` as a list, one of ``LAYER_KINDS`` for each of the
-    ``num_layers`` layers BUILT, of a model that mixes sliding-window and
-    full attention."""
+def layer_kinds(layer_types, num_layers, kinds=LAYER_KINDS):
+    """``layer_types`` as a list, one of ``kinds`` for each of the
+    ``num_layers`` layers BUILT: by default ``LAYER_KINDS``, of a model
+    that mixes sliding-window and full attention; a builder whose mixers
+    are of other kinds names its own."""
     layer_types = list(layer_types)
     if len(layer_types) != num_layers \
-            or any(kind not in LAYER_KINDS for kind in layer_types):
+            or any(kind not in kinds for kind in layer_types):
         raise ValueError("layer_types %r: %d layers, each one of %s"
-                         % (layer_types, num_layers, LAYER_KINDS))
+                         % (layer_types, num_layers, tuple(kinds)))
     return layer_types
 
 

@@ -65,7 +65,7 @@ from .. import trace
 from .nn import ACTIVATIONS
 from .pallas_kernels import _kernel_on_tpu, pl
 
-__all__ = ["causal_conv", "causal_conv1d"]
+__all__ = ["causal_conv", "causal_conv1d", "gated_conv"]
 
 # lanes a grid step takes: the largest that divides every part and the
 # group (and with them every part's place in its group)
@@ -457,3 +457,177 @@ def causal_conv(x, w, act_type=None, lanes=None, interpret: bool = False):
     y, rest = (_two_lowerings(x, w, parts, interpret) if kernel
                else _plain(x, w, parts, act_type))
     return [y, rest] if grouped else [y]
+
+
+# The gated form, below everything the SiLU form's kernels are called
+# through: their Mosaic payloads name those lines (ROADMAP.md D20).
+def _plain_gated(x, w):
+    """``gated_conv`` by the plain form: the thirds cut, ``causal_conv1d``
+    between two products."""
+    c = x.shape[2] // 3
+    return x[..., c:2 * c] * causal_conv1d(x[..., :c] * x[..., 2 * c:], w)
+
+
+def _moved(lanes, by):
+    return slice(lanes.start + by, lanes.stop + by)
+
+
+def _gated_bwd_kernel(x_ref, front_ref, w_ref, dy_ref, dx_ref, dw_ref,
+                      next_ref, acc_ref, *, width, tiles):
+    """One row tile of the cotangents, the tiles walked from the last:
+    ``z = B * u`` and its convolution again from x and the ``HALO`` rows
+    in front of the tile, ``g = dy * C`` the convolution's cotangent,
+    ``dz_t = sum_j w[:, j] g_{t + (W - 1) - j}`` with the first rows of
+    the next tile's ``g`` carried in ``next_ref``; ``dx``'s three thirds
+    ``[dz * u | dy * conv | dz * B]`` written where x's lie, and ``dw``
+    summed as ``_bwd_kernel`` sums it."""
+    f32 = jnp.float32
+    c = dy_ref.shape[1]
+    b, m = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((b == 0) & (m == 0))
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(m == 0)
+    def _():
+        next_ref[...] = jnp.zeros_like(next_ref)
+
+    front = front_ref[...]
+    front = jnp.where(m == tiles - 1, jnp.zeros_like(front), front)
+
+    def one(lanes, r0, n, after):
+        taps = _taps(w_ref, lanes, width)
+        rows = pl.ds(r0, n)
+        gate_in = _window(x_ref, front, lanes, r0, n)
+        u = _window(x_ref, front, _moved(lanes, 2 * c), r0, n)
+        z = gate_in * u
+        zs = [_shifted(z, width - 1 - j) for j in range(width)]
+        dy = dy_ref[rows, lanes].astype(f32)
+        g = dy * x_ref[rows, _moved(lanes, c)].astype(f32)
+        for j in range(width):
+            p = g * zs[j]
+            acc_ref[j, :, lanes] += sum(p[i:i + SUBLANES]
+                                        for i in range(0, n, SUBLANES))
+        gwin = jnp.concatenate([g, after], axis=0)
+        dz = sum(taps[j] * _lifted(gwin, width - 1 - j, n)
+                 for j in range(width))
+        dx_ref[rows, lanes] = (dz * u[HALO:]).astype(dx_ref.dtype)
+        dx_ref[rows, _moved(lanes, c)] = (
+            dy * sum(tap * z for tap, z in zip(taps, zs))
+        ).astype(dx_ref.dtype)
+        dx_ref[rows, _moved(lanes, 2 * c)] = (
+            dz * gate_in[HALO:]).astype(dx_ref.dtype)
+        if isinstance(r0, int):
+            next_ref[:, lanes] = g[:SUBLANES]
+        return g[:SUBLANES]
+
+    _passes(dy_ref, one, first=lambda lanes: next_ref[:, lanes], flip=True)
+
+    @pl.when((b == pl.num_programs(0) - 1) & (m == tiles - 1))
+    def _():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+        for j in range(width):
+            dw_ref[j:j + 1, :] = jnp.sum(acc_ref[j], axis=0, keepdims=True)
+
+
+def _gated_rows(t, c):
+    """Rows a grid step of the gated backward kernel takes of ``T``,
+    every lane of them (a step reads ``[B | C | u]`` side by side, so its
+    block is all ``3 C`` lanes wide and ``STEP_NUMBERS`` count a third's),
+    or None where the tiling does not take ``(T, C)``."""
+    if c % PASS_LANES or t % (PASS_ROWS if t > PASS_ROWS else HALO):
+        return None
+    rows = min(t, max(PASS_ROWS, STEP_NUMBERS // c // PASS_ROWS * PASS_ROWS))
+    while t % rows:
+        rows -= PASS_ROWS
+    return rows
+
+
+# lint: allow(raw-jit) — as _conv_fwd
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _gated_bwd(x, w, dy, *, interpret):
+    """``gated_conv_bwd``: the cotangents of ``gated_conv``'s x, every
+    lane written once, and of w.  Grid (batch, row tile), the tiles
+    walked from the last, blocks as ``_specs`` names them."""
+    from jax.experimental.pallas import tpu as pltpu
+    (b, t, _), (c, width) = x.shape, w.shape
+    rows = _gated_rows(t, c)
+    tiles = t // rows
+
+    def at(m):
+        return tiles - 1 - m
+
+    data = pl.BlockSpec((None, rows, 3 * c), lambda i, m: (i, at(m), 0))
+    taps = pl.BlockSpec((SUBLANES, c), lambda i, m: (0, 0))
+    # lint: allow(raw-pallas-call) — as _conv_fwd
+    dx, dtaps = pl.pallas_call(
+        functools.partial(_gated_bwd_kernel, width=width, tiles=tiles),
+        grid=(b, tiles),
+        in_specs=[
+            data,
+            pl.BlockSpec(
+                (None, HALO, 3 * c), lambda i, m: (
+                    i, jnp.maximum(at(m) * (rows // HALO) - 1, 0), 0)),
+            taps,
+            pl.BlockSpec((None, rows, c), lambda i, m: (i, at(m), 0))],
+        out_specs=[data, taps],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct((SUBLANES, c), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((SUBLANES, c), jnp.float32),
+                        pltpu.VMEM((width, SUBLANES, c), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret, name="gated_conv_bwd",
+    )(x, x, _tap_rows(w), dy)
+    return dx, dtaps[:width].T.astype(w.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _gated_lowerings(x, w, interpret: bool):
+    """``gated_conv`` of inputs the backward kernel takes.  The forward
+    is the plain form on every platform: XLA:TPU fuses it into one pass
+    over the projection, which a kernel written for it did not beat (PR
+    61: the cell's rate and peak memory the same either way).  The
+    backward pass keeps x and w and nothing else."""
+    return _plain_gated(x, w)
+
+
+def _gated_lowerings_fwd(x, w, interpret):
+    return _plain_gated(x, w), (x, w)
+
+
+def _gated_lowerings_bwd(interpret, res, dy):
+    return _kernel_on_tpu(
+        lambda x, w, dy: _gated_bwd(x, w, dy, interpret=interpret),
+        lambda x, w, dy: jax.vjp(_plain_gated, x, w)[1](dy),
+        interpret, *res, dy)
+
+
+_gated_lowerings.defvjp(_gated_lowerings_fwd, _gated_lowerings_bwd)
+
+
+def gated_conv(x, w, interpret: bool = False):
+    """The double-gated short convolution ``C * causal_conv1d(B * u)`` of
+    ``(B, T, 3 C)`` data ``[B | C | u]``, the three thirds of one
+    projection, under ``(C, W)`` w -> ``(B, T, C)``: no activation.  The
+    counter of ``causal_conv`` (the track names the lanes as
+    ``gated<C>``), and two lowerings of the BACKWARD pass: bfloat16 or
+    float32, whole row tiles and whole 128-lane columns run
+    ``gated_conv_bwd`` where the program is lowered for a TPU (x and
+    ``dy`` read and the projection's cotangent written once, 7 C numbers
+    a token, ``B * u`` and its convolution formed again there from the
+    kept x and w); the forward is the plain form everywhere."""
+    if x.ndim != 3 or x.shape[2] % 3:
+        raise ValueError("the gated convolution reads (B, T, 3 C) data; "
+                         "got %r" % (x.shape,))
+    b, t, c = x.shape[0], x.shape[1], x.shape[2] // 3
+    kernel = (x.dtype in (jnp.bfloat16, jnp.float32)
+              and 2 <= w.shape[1] <= SUBLANES
+              and _gated_rows(t, c) is not None)
+    trace.counter("conv:lowering", cat="ops",
+                  track="%s%s/gated%d" % (x.dtype.name, [b, t, 3 * c], c),
+                  kernel=int(kernel), plain=int(not kernel))
+    return _gated_lowerings(x, w, interpret) if kernel \
+        else _plain_gated(x, w)

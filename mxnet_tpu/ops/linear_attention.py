@@ -130,7 +130,7 @@ from jax import lax
 
 from .. import trace
 from ..base import MXNetError
-from .causal_conv import causal_conv, causal_conv1d
+from .causal_conv import causal_conv, causal_conv1d, gated_conv
 from .nn import ACTIVATIONS
 from .pallas_kernels import _kernel_on_tpu, pl
 from .registry import OpDef, Param, register_op
@@ -976,14 +976,13 @@ def _kernel_takes(q, v) -> bool:
 class CausalConv1DOp(OpDef):
     """Depthwise causal convolution over time of ``(B, T, C)``: one
     ``kernel``-tap filter a channel (``weight`` ``(C, kernel)``), no bias;
-    output ``t`` reads inputs ``t - kernel + 1 .. t``; then ``act_type``
-    (an ``Activation``'s; unset: none).  With ``lanes`` ``(w_0, w_1, ..)``
-    the data is ``(B, T, G, Dw)``, a fused projection: every group's
-    leading lanes are convolved where they lie, part by part ``(B, T, G
-    * sum(lanes))`` as ``weight``'s rows; the lanes behind them are the
-    second output, ``rest``.  Two lowerings (``causal_conv``)."""
+    output ``t`` reads inputs ``t - kernel + 1 .. t``; then ``act_type``.
+    With ``lanes`` ``(w_0, ..)`` the data is ``(B, T, G, Dw)``: a group's
+    leading lanes are convolved where they lie, part by part, as
+    ``weight``'s rows; the lanes behind them are ``rest`` (``causal_conv``).
+    ``gated`` alone: ``[B | C | u]`` ``(B, T, 3 C)`` -> ``C * conv(B * u)``."""
     params = [Param("kernel", int, default=4), Param("lanes", "shape"),
-              Param("act_type", str, enum=list(ACTIVATIONS))]
+              Param("act_type", str, enum=list(ACTIVATIONS)), Param("gated", bool)]
 
     def list_arguments(self, p):
         return ["data", "weight"]
@@ -992,19 +991,20 @@ class CausalConv1DOp(OpDef):
         return ["output", "rest"] if p.lanes else ["output"]
 
     def infer_shape(self, p, in_shapes):
-        d, taken = in_shapes[0], sum(p.lanes or ())
+        d, taken, wide = in_shapes[0], sum(p.lanes or ()), 3 if p.gated else 1
         if d is None:
             return in_shapes, [None] * (2 if p.lanes else 1), []
-        if len(d) != (4 if p.lanes else 3) or taken > d[-1]:
+        if len(d) != (4 if p.lanes else 3) or taken > d[-1] or d[2] % wide \
+                or p.gated and (p.lanes or p.act_type):
             raise MXNetError("CausalConv1D: data (batch, seq, channels) or, "
                              "with lanes, (.., groups, width); got %r" % (d,))
         outs = [tuple(d[:2]) + (d[2] * n,) for n in (taken, d[3] - taken)] \
-            if p.lanes else [d]
+            if p.lanes else [tuple(d[:2]) + (d[2] // wide,)]
         return [d, (outs[0][2], p.kernel)], outs, []
 
     def forward(self, p, inputs, aux, ctx):
-        return causal_conv(*inputs, act_type=p.act_type, lanes=p.lanes)
-
+        return causal_conv(*inputs, act_type=p.act_type, lanes=p.lanes) \
+            if not p.gated else [gated_conv(*inputs)]
 
 @register_op("KimiDeltaAttention", hint="kda")
 class KimiDeltaAttentionOp(OpDef):

@@ -13,7 +13,7 @@ that recomputes the scores in the backward pass.  It has two lowerings.
 The plain blocks walk the queries under ``lax.map`` of a
 ``jax.checkpoint``ed body and run on every platform.  Where the program
 is lowered for a TPU and the inputs are ones the kernel takes (bfloat16,
-``Dh`` a multiple of 128, ``T`` whole tiles), JAX's own Pallas splash
+heads of 64 or of 128 and more lanes, ``T`` whole tiles), Pallas splash
 attention under a causal mask (online softmax in VMEM, tiles above the
 diagonal skipped, a fused backward kernel) runs instead.  The code
 chooses from what it sees, no option does; ``attn:lowering`` records
@@ -287,14 +287,14 @@ def _kernel_tiles(t: int):
 def _kernel_takes(q, k, v) -> bool:
     """What the TPU kernel's tiling accepts: the configuration's compute
     dtype (float32 keeps the plain blocks its chip parity was measured
-    on), value heads of whole 128-lane rows (128 against queries of 192,
-    or 256 against 256), query/key heads of at least one (``_flash_fwd``
-    pads 192 to 256 with zeros, which no score sees, and pads nothing at
-    256), sequences of whole tiles and tiles of whole slices."""
+    on), heads of 64 lanes or of 128 and more, the value's a multiple of
+    64 (``_flash_fwd`` pads q, k and v with zeros to whole 128-lane rows,
+    which no score sees, and cuts the output's lanes: 64 to 128, 192 to
+    256, nothing at 128 or 256), sequences of whole tiles of slices."""
     t, dh, dv = q.shape[1], q.shape[3], v.shape[3]
     tile, piece = _kernel_tiles(t)
     return (all(x.dtype == jnp.bfloat16 for x in (q, k, v))
-            and dh >= 128 and dv % 128 == 0 and t % 128 == 0
+            and (dh >= 128 or dh == 64) and dv % 64 == 0 and t % 128 == 0
             and t % tile == 0 and tile % piece == 0)
 
 
@@ -356,14 +356,14 @@ def _flash_fwd(q, k, v, scale, kind):
     attend = sk.make_splash_mha_single_device(
         sm.MultiHeadMask([one_head] * h), block_sizes=sizes)
 
-    lanes = -q.shape[3] % 128        # query/key heads to whole 128 lanes
+    lanes = [-x.shape[3] % 128 for x in (q, k, v)]  # heads to whole 128 lanes
 
     def kernel(q, k, v):
-        q, k = (jnp.pad(x, ((0, 0),) * 3 + ((0, lanes),)) if lanes else x
-                for x in (q * scale, k))
+        q, k, v = (jnp.pad(x, ((0, 0),) * 3 + ((0, n),)) if n else x
+                   for x, n in zip((q * scale, k, v), lanes))
         out = jax.vmap(attend)(*(x.transpose(0, 2, 1, 3)
                                  for x in (q, k, v)))
-        return out.transpose(0, 2, 1, 3)
+        return out.transpose(0, 2, 1, 3)[..., :v.shape[3] - lanes[2]]
 
     # bfloat16 products are exact at any precision, and Mosaic refuses
     # bfloat16 operands under a "highest" default ("Bad lhs type"), so
@@ -544,8 +544,8 @@ class CausalSelfAttentionOp(OpDef):
 
     Which lowering runs is ``causal_attention``'s choice, from the
     platform the program is lowered for and the inputs: bfloat16 with
-    ``Dv % 128 == 0``, ``Dh >= 128`` (padded with zeros to whole 128
-    lanes inside the kernel's wrapper) and ``T`` a multiple of 128 and
+    ``Dv % 64 == 0``, ``Dh`` 64 or >= 128 (both padded with zeros to whole
+    128 lanes inside the kernel's wrapper) and ``T`` a multiple of 128 and
     of its tile (``min(1024, T)``, itself whole slices of 512, at every
     head size: 256 / 256 fits the kernels' VMEM at that tile), lowered
     for a TPU, is JAX's Pallas splash-attention kernel; float32, any

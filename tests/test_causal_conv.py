@@ -235,3 +235,119 @@ def test_the_mixer_is_the_chain_it_replaced(param, monkeypatch):
     assert np.abs(np.asarray(b)).max() > 0
     np.testing.assert_allclose(a, b, rtol=2e-4,
                                atol=2e-5 * np.abs(np.asarray(b)).max())
+
+
+# The gated form (ISSUE 61): (data, numbers a step).  One tile; three
+# row tiles (the carried rows cross two borders, both ways); a batch of
+# two over two tiles; a step of two passes (the ``fori_loop``) over two
+# tiles; a short sequence of one short pass
+GATED = {
+    "one-tile": ((1, 128, 3 * 256), None),
+    "three-row-tiles": ((1, 384, 3 * 128), 128 * 128),
+    "batch-of-two-tiles": ((2, 256, 3 * 256), 128 * 256),
+    "two-passes-a-tile": ((1, 512, 3 * 128), 256 * 128),
+    "a-short-pass": ((2, 48, 3 * 128), None),
+}
+
+
+def _gated_inputs(shape, dtype, taps=3):
+    rng = np.random.RandomState(61)
+    c = shape[2] // 3
+    x = jnp.asarray(rng.standard_normal(shape), dtype)
+    w = jnp.asarray(0.5 * rng.standard_normal((c, taps)), dtype)
+    return x, w, [jnp.asarray(rng.standard_normal(shape[:2] + (c,)), dtype)]
+
+
+def _gated_statement(x, w):
+    """``C * conv(B * u)`` as one grouped ``lax.conv_general_dilated``
+    over a left-padded sequence, in float32."""
+    x, w = x.astype(jnp.float32), w.astype(jnp.float32)
+    c, taps = w.shape
+    gate_in, gate_out, u = x[..., :c], x[..., c:2 * c], x[..., 2 * c:]
+    conv = jax.lax.conv_general_dilated(
+        gate_in * u, w.T[:, None, :], window_strides=(1,),
+        padding=[(taps - 1, 0)], dimension_numbers=("NWC", "WIO", "NWC"),
+        feature_group_count=c, precision="highest")
+    return [gate_out * conv]
+
+
+@pytest.mark.parametrize("dtype", sorted(TOLERANCE))
+@pytest.mark.parametrize("case", sorted(GATED))
+def test_gated_kernels_and_plain_form_match_the_statement(case, dtype,
+                                                          monkeypatch):
+    """Forward and the cotangents of the data (both gates and the
+    convolved third) and of the taps: the plain form against the direct
+    statement, the kernels under the interpreter against both."""
+    shape, numbers = GATED[case]
+    if numbers is not None:
+        monkeypatch.setattr(cc, "STEP_NUMBERS", numbers)
+    x, w, cts = _gated_inputs(shape, jnp.dtype(dtype))
+    c = shape[2] // 3
+    tiles = {"three-row-tiles": 3, "batch-of-two-tiles": 2,
+             "two-passes-a-tile": 2}.get(case, 1)
+    assert shape[1] // cc._gated_rows(shape[1], c) == tiles
+    want = _with_cotangents(_gated_statement, x, w, cts)
+    plain = _with_cotangents(lambda x, w: [cc._plain_gated(x, w)], x, w, cts)
+    kernels = _with_cotangents(
+        lambda x, w: [cc.gated_conv(x, w, interpret=True)], x, w, cts)
+    assert len(want) == len(plain) == len(kernels) == 3
+    for got in (plain, kernels):
+        for a, b in zip(got, want):
+            assert a.shape == b.shape and a.dtype == x.dtype
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            assert np.abs(a - b).max() <= 2 * TOLERANCE[dtype] * np.abs(b).max()
+
+
+@pytest.mark.parametrize("extra, shape", [
+    (dict(act_type="silu"), (2, 256, 3 * 128)),
+    (dict(lanes=(128,)), (2, 256, 3, 128)),
+    (dict(), (2, 256, 128))])
+def test_the_gates_stand_alone(extra, shape):
+    """No activation behind the gates and no lanes to take; data in
+    whole thirds."""
+    op = mx.sym.CausalConv1D(mx.sym.Variable("data"), kernel=3, gated=True,
+                             **extra)
+    with pytest.raises(mx.MXNetError):
+        op.infer_shape(data=shape)
+    with pytest.raises(ValueError):
+        cc.gated_conv(jnp.zeros((1, 128, 128)), jnp.zeros((128, 3)))
+
+
+def test_gated_op_shapes_counter_and_the_tpu_program():
+    data = mx.sym.Variable("data")
+    op = mx.sym.CausalConv1D(data, kernel=3, gated=True, name="c")
+    args, outs, _ = op.infer_shape(data=(2, 256, 3 * 128))
+    assert args[1] == (128, 3) and outs == [(2, 256, 128)]
+    with pytest.raises(mx.MXNetError):
+        op.infer_shape(data=(2, 256, 128))
+    # an unset ``gated`` is not serialized: the SiLU form's symbols stand
+    assert "gated" not in mx.sym.CausalConv1D(data, kernel=4).tojson()
+    x = jax.ShapeDtypeStruct((1, 256, 3 * 128), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((128, 3), jnp.bfloat16)
+    narrow = jax.ShapeDtypeStruct((1, 256, 3 * 64), jnp.bfloat16)
+
+    def grads(x, w):
+        return jax.grad(lambda x, w: jnp.square(cc.gated_conv(
+            x, w).astype(jnp.float32)).sum(), (0, 1))(x, w)
+
+    was = mx.trace.enabled()
+    mx.trace.set_enabled(True)
+    try:
+        mark = time.perf_counter_ns()
+        text = jax.export.export(jax.jit(grads), platforms=["tpu"])(
+            x, w).mlir_module()
+        refused = jax.export.export(jax.jit(grads), platforms=["tpu"])(
+            narrow, jax.ShapeDtypeStruct((64, 3), jnp.bfloat16)).mlir_module()
+        chosen = mx.trace.counter_events(["conv:lowering"], since_ns=mark)
+    finally:
+        mx.trace.set_enabled(was)
+    # the backward pass is the kernel, the forward XLA's own
+    assert "gated_conv_bwd" in text and "gated_conv_fwd" not in text
+    assert "causal_conv_fwd" not in text
+    assert "tpu_custom_call" not in refused
+    assert [(e["id"], e["args"]) for e in chosen] == [
+        ("bfloat16[1, 256, 384]/gated128", {"kernel": 1, "plain": 0}),
+        ("bfloat16[1, 256, 192]/gated64", {"kernel": 0, "plain": 1})]
+    _, kept = jax.eval_shape(
+        lambda x, w: cc._gated_lowerings_fwd(x, w, False), x, w)
+    assert [k.shape for k in kept] == [x.shape, w.shape]

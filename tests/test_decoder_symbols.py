@@ -1,6 +1,6 @@
 """The decoder builders' symbols, node for node (ISSUE 46, tier-1).
 
-The LM builders of ``mxnet_tpu/models`` (five then, nine now) are
+The LM builders of ``mxnet_tpu/models`` (five then, ten now) are
 assembled from one skeleton, ``models/decoder.py``.  What holds that assembly still is the
 graph each builder returns: under a fresh ``NameManager`` the symbol's
 JSON (every node's op, name, keywords, attributes and inputs, the unnamed
@@ -85,6 +85,13 @@ KEYE = dict(num_layers=2, hidden_size=32, num_heads=4, num_kv_heads=2,
             experts_per_tok=4, expert_width=24, vocab_size=50, seq_len=16,
             mrope_sections=(1, 1, 2), rope_theta=1e7, rms_eps=1e-6,
             aux_coef=0.001, experts_held=4, first_expert=4)
+LFM2 = dict(num_layers=5, hidden_size=32,
+            layer_types=["conv", "full_attention", "conv", "conv", "conv"],
+            dense_layers=1, num_heads=4, num_kv_heads=2, head_dim=8,
+            conv_kernel=3, rope_theta=1e6, dense_width=48, num_experts=16,
+            experts_per_tok=4, expert_width=24, vocab_size=50, seq_len=16,
+            route_scale=1.0, experts_held=4, first_expert=4, bias_rate=1e-3,
+            rms_eps=1e-5)
 WHOLE = dict(experts_held=0, first_expert=0)
 # latent attention by itself: seq_len, hidden_size, heads, kv_lora_rank,
 # qk_nope_dim, qk_rope_dim, v_head_dim, rms_eps
@@ -157,6 +164,18 @@ SYMBOLS = {
     "keye-aux-0": _tiny("keye_lm", KEYE, aux_coef=0.0),
     "keye-positions": _tiny("keye_lm", KEYE, positions=True),
     "keye-wide-embedding": _tiny("keye_lm", KEYE, embed_sigma=1.0),
+    # the tenth builder, from the commit that added it (ISSUE 61): its
+    # cell, a rank's share, the whole layer, attention first and in every
+    # second layer with no dense lead, the published two dense layers in
+    # front, the last rank's share
+    "lfm2-8b-a1b": _cell("lfm2-8b-a1b"),
+    "lfm2-share": _tiny("lfm2_moe_lm", LFM2),
+    "lfm2-whole": _tiny("lfm2_moe_lm", LFM2, **WHOLE),
+    "lfm2-every-second": _tiny(
+        "lfm2_moe_lm", LFM2, dense_layers=0,
+        layer_types=["full_attention", "conv"] * 2 + ["full_attention"]),
+    "lfm2-two-dense": _tiny("lfm2_moe_lm", LFM2, dense_layers=2),
+    "lfm2-last-rank": _tiny("lfm2_moe_lm", LFM2, first_expert=12),
     # OLMoE: its load-balance heads stay on at coefficient 0
     "olmoe-tiny": _tiny("olmoe_lm", OLMOE),
     "olmoe-aux-0": _tiny("olmoe_lm", OLMOE, aux_coef=0.0),
@@ -251,6 +270,19 @@ SYMBOL_WAS = {
         "e01e43dbaa2a055c54b06713a3b97c17c95a6be4a113fe94d976fa0a9a1e0232",
     "keye-wide-embedding":
         "eb834439a3fe90b9840ee600f5fba26d34a9ff58d2c29f71042b45c519c297d7",
+    # taken at the commit that added the builder (ISSUE 61)
+    "lfm2-8b-a1b":
+        "15f583703e2dc8409244e0723eb87bb853fd278540264834e2282b762c4080b0",
+    "lfm2-every-second":
+        "dc8a1c9ffb890736cef38d94045e5c2b597b8c9d734b4c35ed00d85f5755107c",
+    "lfm2-last-rank":
+        "ab255caf1c0e0763c47ca9469312e496f0cb654dd079a8fd5f92351a49e41406",
+    "lfm2-share":
+        "039659a5f095be401af2baddca332e5b490894fbbeb5333646fd4b13f8a76288",
+    "lfm2-two-dense":
+        "00d398f0d54af22e8b993c9d3e873abefa1411c0aeb4896c5750be8fcb59c5bc",
+    "lfm2-whole":
+        "3df3c390ba50dcd9af29227efe2351edbfc4e35f0d22a930fe0bae2d4ae496e2",
     "olmoe-tiny":
         "af8dc705e9e7aeb26b2806967a870d607de9f4892070d6b09552c77d2034efc0",
     "olmoe-aux-0":

@@ -122,15 +122,18 @@ BF16, F32 = jnp.bfloat16, jnp.float32
     (BF16, (1, 1536, 2, 128), "tpu", False),
     (BF16, (1, 768, 2, 128), "tpu", False),
     (BF16, (1, 200, 2, 128), "tpu", False),
-    (BF16, (1, 256, 2, 64), "tpu", False),
+    (BF16, (1, 256, 2, 64), "tpu", True),
+    (BF16, (1, 256, 2, 32), "tpu", False),
+    (BF16, (1, 256, 2, 96), "tpu", False),
 ], ids=["bf16-tpu", "bf16-2-tiles-tpu", "bf16-cpu", "float32-tpu",
         "seq-not-whole-tiles-tpu", "tile-not-whole-slices-tpu",
-        "seq-not-128s-tpu", "head-64-tpu"])
+        "seq-not-128s-tpu", "head-64-tpu", "head-32-tpu", "head-96-tpu"])
 def test_attention_lowering_is_chosen_from_platform_and_inputs(
         dtype, shape, platform, kernel_inputs):
     """bfloat16 at shapes the kernel's tiling takes, lowered for a TPU,
-    is the Mosaic kernel, forward and the fused backward one; float32, a
-    sequence that is not whole tiles, a 64-wide head, and ANY CPU
+    is the Mosaic kernel, forward and the fused backward one (a 64-wide
+    head too, since PR 61: the wrapper pads it to 128 lanes); float32, a
+    sequence that is not whole tiles, a 32- or 96-wide head, and ANY CPU
     lowering are the plain blocks.  Read off the text lowered for the
     platform (no chip, no libtpu) and off ``attn:lowering``, which says
     what the op's TPU lowering is."""
