@@ -1,6 +1,7 @@
-"""The forward attend pass and the target pass of ``IndexedSelfAttention``
-as Pallas kernels of this repo, both under a selection that is DATA (a
-``(T, T)`` mask no function computes) over grouped queries.
+"""The attend pass, forward and backward, and the target pass of
+``IndexedSelfAttention`` as three Pallas kernels of this repo, none of
+the library's, all under a selection that is DATA (a ``(T, T)`` mask no
+function computes) over grouped queries.
 
 **The forward attend pass** (``selected_attention_fwd``): softmax
 attention of one sequence under the selection.
@@ -21,14 +22,40 @@ The operands go in as the op has them but for one reshape, ``(T, H *
 Dh)`` rows with a head's lanes side by side (a grid step cuts its heads
 out of whole lane blocks; XLA lays q out once for it, as it did for the
 library's head-major form), and the output comes back the same way; the
-log-sum-exp leaves as ``(H, T)``, what the target pass and the library's
-backward kernel read.
+log-sum-exp leaves as ``(H, T)``, what the target pass and the backward
+kernel read.
 
 The kernel's name on the device is ``splash_mha_fwd_selected``: the
 benchmark's ``dsa_attn_roofline`` divides the work of BOTH attend passes
-by the time of the operations named ``splash_mha*`` (the backward kernel
-is the library's), so the forward keeps the prefix until that reader goes
-by scope (ROADMAP D12(h)).
+by the time of the operations named ``splash_mha*``, so both kernels
+keep the prefix until that reader goes by scope (ROADMAP D12(h)).
+
+**The backward attend pass** (``selected_attention_bwd``, PR 62;
+``splash_mha_dkv_selected`` on the device): the cotangents of the scaled
+q, of k and of v from the forward's output and log-sum-exp, on the
+forward's plan.  A grid step holds the query heads of one key/value
+head, ``G x bq`` rows, against one key tile under ONE ``(bq, bkv)`` int8
+tile of the selection, transposed once in VMEM for all of them.  The
+scores are formed TRANSPOSED, keys down and the heads' rows side by side
+along the lanes (``k q^T``, ``(bkv, G * bq)``), so a row's log-sum-exp
+and ``di = rowsum(out * d_out)`` are lane vectors (``di`` is formed in
+the kernel from the tiles it holds, once a query tile) and four of the
+five products need no transpose: ``p = exp(s - lse)`` under the tile,
+``dp = v d_out^T``, ``ds = p (dp - di)``, ``dv += p d_out`` and ``dk +=
+ds q`` as ONE contraction each over the group's ``G * bq`` rows, ``dq +=
+ds^T k``; bfloat16 operands on the MXU into float32.  With the query
+tile outermost ``dq`` of a step's rows accumulates in float32 scratch
+over its causal key tiles and is written once, rounded once; ``dk`` and
+``dv`` of one key/value head accumulate over all its query tiles and its
+group's heads in two float32 ``(T, D)`` scratches (8 MiB at 8192 x 128;
+the grid runs in order on the chip's one core) and leave a key tile at
+a time during the head's last query tile, which reads every key.  Key
+tiles beyond a query tile's last row are never visited, a diagonal tile
+runs only the pieces its rows reach.  q, the output, its cotangent and
+``dq`` are ``(T, H * Dh)`` rows, k, v, ``dk``, ``dv`` ``(T, Hkv * D)``
+rows: no ``MaskInfo``, no int32 block a head, no head-major copy and no
+partial ``dq`` plane ``(key tiles, H, T, Dh)`` to sum.  The call is one
+inner ``jax.jit``, as the target pass's.
 
 **The target pass** (``selected_target``, PR 60): the index loss ``KL(p ||
 softmax over the selection of I)`` with ``p`` the heads' mean
@@ -66,7 +93,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["selected_attention_fwd", "forward_tiles", "selected_target",
+__all__ = ["selected_attention_fwd", "forward_tiles",
+           "selected_attention_bwd", "backward_tiles", "selected_target",
            "target_tiles"]
 
 LANES = 128
@@ -89,21 +117,29 @@ def _whole(t: int, most: int) -> int:
                 if t % n == 0)
 
 
-def forward_tiles(t: int, group: int, lanes: int):
-    """``(heads, bq, bkv, piece)`` for sequences of ``t`` rows (whole
-    128s), ``group`` query heads a key/value head and heads of ``lanes``
-    lanes (the wider of Dh, Dv): the heads a step holds under one mask
-    tile (the whole group where its rows fit, else its largest divisor
-    that does), the query rows a head (within the step's rows and a key
-    tile), the keys a tile and the keys a matmul, each a whole divisor of
-    the one before in whole 128s.  A step's rows are ROWS at 128 lanes
-    and fewer at wider heads, as the blocks and the scratch grow."""
-    rows = ROWS * LANES // lanes
+def _attend_tiles(t: int, group: int, lanes: int, rows: int, block_kv: int,
+                  piece: int):
+    """``(heads, bq, bkv, piece)`` of an attend kernel whose step holds
+    ``rows`` rows at 128 lanes (fewer at wider heads, as the blocks and
+    the scratch grow) against ``block_kv`` keys read ``piece`` a matmul:
+    the heads a step holds under one mask tile (the whole group where its
+    rows fit, else its largest divisor that does), the query rows a head
+    (within the step's rows and a key tile), the keys a tile and the keys
+    a matmul, each a whole divisor of the one before in whole 128s."""
+    rows = rows * LANES // lanes
     heads = next(n for n in range(min(group, rows // LANES), 0, -1)
                  if group % n == 0)
-    bkv = _whole(t, BLOCK_KV)
+    bkv = _whole(t, block_kv)
     return (heads, _whole(t, min(rows // heads, bkv)), bkv,
-            _whole(bkv, PIECE))
+            _whole(bkv, piece))
+
+
+def forward_tiles(t: int, group: int, lanes: int):
+    """``(heads, bq, bkv, piece)`` of the forward attend kernel for
+    sequences of ``t`` rows (whole 128s), ``group`` query heads a
+    key/value head and heads of ``lanes`` lanes (the wider of Dh, Dv):
+    ``_attend_tiles`` of ROWS, BLOCK_KV and PIECE."""
+    return _attend_tiles(t, group, lanes, ROWS, BLOCK_KV, PIECE)
 
 
 def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_ref, l_ref,
@@ -212,6 +248,193 @@ def selected_attention_fwd(q, k, v, mask, interpret: bool = False):
     )(q.reshape(t, h * dh), k.reshape(t, hkv * dh), v.reshape(t, hkv * dv),
       mask.astype(jnp.int8))
     return out.reshape(t, h, dv), lse.reshape(h, t)
+
+
+# The backward attend pass: rows a grid step (heads * bq), keys a tile and
+# keys a matmul, as the forward's; a piece's float32 scores and their
+# cotangents are 2 x ROWS x PIECE x 4 bytes and ``dk`` / ``dv`` of one
+# key/value head stay in float32 VMEM over all its query tiles
+# (BACKWARD_KEPT bounds them: 2 x T x D x 4 bytes, 8 MiB at 8192 x 128),
+# so the kernel asks for more than Mosaic's default scoped VMEM, as the
+# target kernel does.  Measured on a v5e at 32 heads over 4, 8192 rows
+# under a top-2048, ms a kernel / s to the first call (PERF.md, PR 62):
+# 4096 x 512 x 512 8.49 / 4.0, 4096 x 1024 x 512 8.38 / 7.2, 2048 x 512 x
+# 512 8.74 / 2.6, 4096 x 512 x 256 8.59, 2048 x 1024 x 512 8.55, 2048 x
+# 256 x 256 9.08, 1024 x 512 x 512 9.49, 8192 x 1024 x 512 12.68 / 15.7
+BACKWARD_ROWS, BACKWARD_BLOCK_KV, BACKWARD_PIECE = 4096, 512, 512
+BACKWARD_KEPT = 32 << 20
+BACKWARD_VMEM = 100 << 20
+
+
+def backward_tiles(t: int, group: int, lanes: int):
+    """``(heads, bq, bkv, piece)`` of the backward attend kernel, as
+    ``forward_tiles`` gives the forward's, or None where the kernel does
+    not take the sequence: ``t`` not in whole 128s, or so long that one
+    key/value head's float32 ``dk`` and ``dv`` pass BACKWARD_KEPT."""
+    if t % LANES or 8 * t * lanes > BACKWARD_KEPT:
+        return None
+    return _attend_tiles(t, group, lanes, BACKWARD_ROWS, BACKWARD_BLOCK_KV,
+                         BACKWARD_PIECE)
+
+
+def _backward_step(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, mask_ref,
+                   dq_ref, dk_ref, dv_ref, q_st, do_st, di_st, dq_acc,
+                   dk_acc, dv_acc, *, heads, steps, bq, bkv, piece, dh, dv):
+    n, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    last_row = (i + 1) * bq - 1
+    last_tile = last_row // bkv
+    f32 = jnp.float32
+    nt = (((1,), (1,)), ((), ()))
+
+    @pl.when((n % steps == 0) & (i == 0) & (j == 0))
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(j == 0)
+    def _():
+        # the query tile's operands as the key tiles read them: the heads'
+        # rows one under another, and ``di = rowsum(out * d_out)`` a head
+        # along the lanes (the diagonal of a 128 x 128 square summed down
+        # its sublanes lays 128 rows' values along the lanes)
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        square = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0) \
+            == jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
+        for g in range(heads):
+            rows = slice(g * bq, (g + 1) * bq)
+            d_out = do_ref[:, g * dv:(g + 1) * dv]
+            q_st[rows] = q_ref[:, g * dh:(g + 1) * dh]
+            do_st[rows] = d_out
+            di = jnp.sum(o_ref[:, g * dv:(g + 1) * dv].astype(f32)
+                         * d_out.astype(f32), axis=1, keepdims=True)
+            for at in range(0, bq, LANES):
+                di_st[g:g + 1, at:at + LANES] = jnp.sum(
+                    jnp.where(square, di[at:at + LANES], 0.0), axis=0,
+                    keepdims=True)
+
+    def one_piece(lo):
+        # TRANSPOSED scores, keys down and the heads' rows side by side
+        # along the lanes, so a row's log-sum-exp and ``di`` are lane
+        # vectors and four of the five products need no transpose
+        k_p, v_p = k_ref[lo:lo + piece, :], v_ref[lo:lo + piece, :]
+        s = jax.lax.dot_general(k_p, q_st[...], nt,
+                                preferred_element_type=f32)
+        dp = jax.lax.dot_general(v_p, do_st[...], nt,
+                                 preferred_element_type=f32)
+        # the forward's int8 tile, transposed in VMEM for the eight heads
+        keep = mask_ref[:, lo:lo + piece].astype(f32).T != 0.0
+        p, ds = [], []
+        for g in range(heads):
+            rows = slice(g * bq, (g + 1) * bq)
+            p_g = jnp.where(keep, jnp.exp(s[:, rows] - lse_ref[g:g + 1, :]),
+                            0.0)
+            ds.append((p_g * (dp[:, rows] - di_st[g:g + 1, :])).astype(
+                k_ref.dtype))
+            p.append(p_g.astype(do_st.dtype))
+        p, ds = jnp.concatenate(p, axis=1), jnp.concatenate(ds, axis=1)
+        keys = pl.ds(pl.multiple_of(j * bkv + lo, piece), piece)
+        dv_acc[keys, :] += jnp.dot(p, do_st[...], preferred_element_type=f32)
+        dk_acc[keys, :] += jnp.dot(ds, q_st[...], preferred_element_type=f32)
+        dq_acc[...] += jax.lax.dot_general(ds, k_p, (((0,), (0,)), ((), ())),
+                                           preferred_element_type=f32)
+
+    for at in range(0, bkv, piece):
+        # as the forward: a piece whose first key lies beyond the query
+        # tile's last row holds no causal pair
+        pl.when(j * bkv + at <= last_row)(functools.partial(one_piece, at))
+
+    @pl.when(j == last_tile)
+    def _():
+        for g in range(heads):
+            dq_ref[:, g * dh:(g + 1) * dh] = \
+                dq_acc[g * bq:(g + 1) * bq].astype(dq_ref.dtype)
+
+    # the last query tile of a key/value head's last step reads every key
+    # tile: each leaves as its sum closes
+    @pl.when((n % steps == steps - 1) & (i == pl.num_programs(1) - 1))
+    def _():
+        keys = pl.ds(pl.multiple_of(j * bkv, bkv), bkv)
+        dk_ref[...] = dk_acc[keys, :].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[keys, :].astype(dv_ref.dtype)
+
+
+def selected_attention_bwd(q, k, v, mask, out, lse, d_out,
+                           interpret: bool = False):
+    """The cotangents of ``selected_attention_fwd``'s inputs: its ``(T, H,
+    Dh)`` scaled queries, ``(T, Hkv, Dh)`` keys, ``(T, Hkv, Dv)`` values
+    and selection ``(T, T)``, its output ``(T, H, Dv)`` and float32
+    log-sum-exp ``(H, T)`` and the output's cotangent -> ``(dq, dk, dv)``
+    in their inputs' shapes and dtypes (the module's docstring has the
+    kernel).  ``backward_tiles`` must take ``T``."""
+    return _selected_attention_bwd(
+        q, k, v, mask, out, lse, d_out, interpret=interpret,
+        tiles=backward_tiles(q.shape[0], q.shape[1] // k.shape[1],
+                             max(q.shape[2], v.shape[2])))
+
+
+# lint: allow(raw-jit) — never dispatched on its own: a jit inside the step
+# program, as ``_selected_target``'s, so that every layer and module shares
+# one traced kernel and one lowered function
+@functools.partial(jax.jit, static_argnames=("interpret", "tiles"))
+def _selected_attention_bwd(q, k, v, mask, out, lse, d_out, *, interpret,
+                            tiles):
+    t, h, dh = q.shape
+    hkv, dv = k.shape[1], v.shape[2]
+    heads, bq, bkv, piece = tiles
+    steps = h // hkv // heads       # of one key/value head's query heads
+    rows = heads * bq
+
+    def keys_of(i, j):
+        # a step beyond the diagonal keeps the diagonal's blocks
+        return jnp.minimum(j, ((i + 1) * bq - 1) // bkv)
+
+    def closing(n, i, j):
+        # dk and dv leave a key tile at a time in the head's last sweep
+        # and stay at tile 0, unwritten and unfetched, before it
+        return jnp.where((n % steps == steps - 1) & (i == t // bq - 1), j, 0)
+
+    def a_head(width):
+        return pl.BlockSpec((bq, heads * width), lambda n, i, j: (i, n))
+
+    def a_key_tile(width):
+        return pl.BlockSpec((bkv, width),
+                            lambda n, i, j: (keys_of(i, j), n // steps))
+
+    def closed(width):
+        return pl.BlockSpec((bkv, width),
+                            lambda n, i, j: (closing(n, i, j), n // steps))
+
+    # lint: allow(raw-pallas-call) — one lowering of the op's attend pass,
+    # chosen by platform and held to the plain blocks by tolerance
+    # (tests/test_sparse_attention.py, tests/tpu/test_keye_tpu.py)
+    dq, dk, dv_ = pl.pallas_call(
+        functools.partial(_backward_step, heads=heads, steps=steps, bq=bq,
+                          bkv=bkv, piece=piece, dh=dh, dv=dv),
+        grid=(h // heads, t // bq, t // bkv),
+        in_specs=[
+            a_head(dh), a_key_tile(dh), a_key_tile(dv), a_head(dv),
+            a_head(dv),
+            pl.BlockSpec((None, heads, bq), lambda n, i, j: (n, 0, i)),
+            pl.BlockSpec((bq, bkv), lambda n, i, j: (i, keys_of(i, j)))],
+        out_specs=[a_head(dh), closed(dh), closed(dv)],
+        out_shape=[jax.ShapeDtypeStruct((t, h * dh), q.dtype),
+                   jax.ShapeDtypeStruct((t, hkv * dh), k.dtype),
+                   jax.ShapeDtypeStruct((t, hkv * dv), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((rows, dh), q.dtype),
+                        pltpu.VMEM((rows, dv), d_out.dtype),
+                        pltpu.VMEM((heads, bq), jnp.float32),
+                        pltpu.VMEM((rows, dh), jnp.float32),
+                        pltpu.VMEM((t, dh), jnp.float32),
+                        pltpu.VMEM((t, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=BACKWARD_VMEM),
+        interpret=interpret,
+        name="splash_mha_dkv_selected",
+    )(q.reshape(t, h * dh), k.reshape(t, hkv * dh), v.reshape(t, hkv * dv),
+      out.reshape(t, h * dv), d_out.reshape(t, h * dv),
+      lse.reshape(h // heads, heads, t), mask.astype(jnp.int8))
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv_.reshape(v.shape)
 
 
 # The target pass: a query tile's rows against its causal key tiles, TWICE

@@ -28,14 +28,18 @@ or gathers ``(T, topk, ..)``:
   ``(T, T)``, the one thing kept of it.
 * ``attend``: softmax attention under that mask.  Two lowerings, chosen
   as ``causal_attention`` chooses (``_kernel_takes``): the plain query
-  blocks on every platform; two kernels where the program is lowered for
-  a TPU.  Forward, this repo's own (``ops/selected_attention.py``): a
-  grid step holds the query heads of one key/value head and reads ONE
-  tile of the selection for all of them, as int8, a byte a pair.
-  Backward, JAX's Pallas splash attention: its fused kernel with the
-  mask as DATA (a loaded int32 block a visit and head, which five
-  matmuls hide), fed the forward's log-sum-exp.  ``dsa:lowering`` records
-  which lowering and the heads a loaded mask tile serves.
+  blocks on every platform; two kernels of this repo's own where the
+  program is lowered for a TPU (``ops/selected_attention.py``), none of
+  the library's.  Forward: a grid step holds the query heads of one
+  key/value head and reads ONE tile of the selection for all of them,
+  as int8, a byte a pair.  Backward, on the same plan and under the same
+  int8 tile: the scores again from the forward's log-sum-exp, ``dq`` of
+  a step's rows summed over its causal key tiles in VMEM, ``dk`` and
+  ``dv`` of a key/value head over all its query tiles and its group's
+  heads in VMEM; q, the output, its cotangent and ``dq`` as ``(T, H *
+  Dh)`` rows.  ``dsa:lowering`` records which lowering, the heads a
+  loaded mask tile serves and whether the backward pass is the kernel
+  too (``backward_kernel``: a ``T`` whose ``dk`` and ``dv`` fit).
 * ``target``: the heads' probabilities formed again from the saved
   log-sum-exp, summed over the heads, against the scores formed again:
   the row losses and, while the op is being differentiated, the
@@ -65,9 +69,11 @@ from .. import trace
 from ..base import MXNetError
 from .pallas_kernels import _kernel_on_tpu
 from .registry import OpDef, Param, register_op
-from .selected_attention import (forward_tiles, selected_attention_fwd,
-                                 selected_target, target_tiles)
-from .transformer import _kernel_takes, _kernel_tiles, layer_scope
+from .selected_attention import (backward_tiles, forward_tiles,
+                                 selected_attention_bwd,
+                                 selected_attention_fwd, selected_target,
+                                 target_tiles)
+from .transformer import _kernel_takes, layer_scope
 
 __all__ = ["indexed_attention", "indexer_scores", "select_keys"]
 
@@ -234,28 +240,6 @@ def _attend_plain(q, k, v, mask):
         lse.transpose(1, 0, 2).reshape(h, t)
 
 
-def _kernel_info(mask, tile: int):
-    """The library's description of one sequence's selection for its
-    fused backward kernel, one head's for every head (the kernel reads a
-    one-head ``MaskInfo`` at head 0)."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_mask_info as mi)
-    info = mi.process_dynamic_mask_dkv(mask[None], (tile, tile),
-                                       shrink_grid=False)[0]
-    return info._replace(partial_mask_blocks=info.partial_mask_blocks
-                         .reshape(-1, tile, tile))
-
-
-def _kernel_sizes(t: int):
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as sk)
-    tile, piece = _kernel_tiles(t)
-    return tile, sk.BlockSizes(
-        block_q=tile, block_kv=tile, block_kv_compute=piece,
-        block_q_dkv=tile, block_kv_dkv=tile, block_kv_dkv_compute=piece,
-        use_fused_bwd_kernel=True)
-
-
 def _attend_kernel(q, k, v, mask, interpret: bool = False):
     """``_attend_plain``'s TPU lowering: this repo's forward kernel
     (``ops/selected_attention.py``), which reads a tile of the selection
@@ -265,20 +249,12 @@ def _attend_kernel(q, k, v, mask, interpret: bool = False):
 
 
 def _attend_kernel_bwd(q, k, v, mask, out, lse, g, interpret: bool = False):
-    """The library's fused backward kernel under the same selection ->
-    the cotangents of the scaled q, of k and of v."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as sk)
-    tile, sizes = _kernel_sizes(q.shape[0])
-    heads = tuple(x.transpose(1, 0, 2) for x in (q, k, v))
-    res = heads + (None, None, out.transpose(1, 0, 2), lse, None,
-                   _kernel_info(mask, tile))
+    """``_attend_plain_bwd``'s TPU lowering: this repo's backward kernel
+    (``ops/selected_attention.py``) under the same selection, fed the
+    forward's output and log-sum-exp -> the cotangents of the scaled q,
+    of k and of v."""
     with jax.default_matmul_precision("default"):
-        grads = sk._splash_attention_bwd(
-            False, sk.DEFAULT_MASK_VALUE, False, sizes, None, None, None,
-            interpret, res, g.transpose(1, 0, 2))
-    return tuple(x.transpose(1, 0, 2).astype(y.dtype)
-                 for x, y in zip(grads[3:6], (q, k, v)))
+        return selected_attention_bwd(q, k, v, mask, out, lse, g, interpret)
 
 
 def _attend_plain_bwd(q, k, v, mask, out, lse, g):
@@ -406,12 +382,13 @@ def _indexed_fwd(q, k, v, qi, ki, w, topk, scale, layer, kernel, target):
 def _indexed_bwd(topk, scale, layer, kernel, target, res, cotangents):
     q, k, v, mask, out, lse, unit = res
     g_out, g_loss, _ = cotangents
+    by_kernel = kernel and _backward_takes(q, v)
 
     def one_sequence(args):
         q, k, v, mask, out, lse, g = args
         with layer_scope("dsa_attn", layer):
             qs = q * jnp.asarray(scale, q.dtype)
-            if kernel:
+            if by_kernel:
                 d_qs, d_k, d_v = _kernel_on_tpu(
                     _attend_kernel_bwd, _attend_plain_bwd, False,
                     qs, k, v, mask, out, lse, g)
@@ -435,6 +412,14 @@ def _indexed_bwd(topk, scale, layer, kernel, target, res, cotangents):
 _indexed_attention.defvjp(_indexed_fwd, _indexed_bwd)
 
 
+def _backward_takes(q, v) -> bool:
+    """Whether the backward kernel takes what the forward kernel took:
+    ``(B, T, H, Dh)`` q and ``(B, T, Hkv, Dv)`` v whose ``T`` leaves one
+    key/value head's float32 ``dk`` and ``dv`` room in VMEM."""
+    return backward_tiles(q.shape[1], q.shape[2] // v.shape[2],
+                          max(q.shape[3], v.shape[3])) is not None
+
+
 def indexed_attention(q, k, v, qi, ki, w, topk: int, scale: float,
                       layer=None):
     """``IndexedSelfAttention``'s body: ``(B, T, H, Dh)`` q, ``(B, T, Hkv,
@@ -449,14 +434,17 @@ def indexed_attention(q, k, v, qi, ki, w, topk: int, scale: float,
     blocks everywhere, ``heads_a_mask_tile`` 0); the track names dtype,
     shape, ``/kv<Hkv>`` and ``/top<topk>``.  ``target_kernel`` 1: the
     target pass runs its kernel beside them (the indexer in the same
-    dtype, heads of whole 64 lanes, ``target_tiles`` takes ``T``)."""
+    dtype, heads of whole 64 lanes, ``target_tiles`` takes ``T``);
+    ``backward_kernel`` 1: the backward attend pass runs this repo's
+    kernel too (``backward_tiles`` takes ``T``), 0: the plain blocks."""
     h, hkv = q.shape[2], k.shape[2]
     if h % hkv or v.shape[2] != hkv or ki.shape[2] != 1:
         raise MXNetError("indexed attention: %d query heads over %d key and "
                          "%d value heads, %d indexer key heads (one)"
                          % (h, hkv, v.shape[2], ki.shape[2]))
-    # the forward kernel reads a head's lanes out of ``(T, H * Dh)`` rows
-    kernel = _kernel_takes(q, k, v) and q.shape[3] % 128 == 0
+    # the kernels read a head's lanes out of ``(T, H * Dh)`` rows
+    kernel = _kernel_takes(q, k, v) and q.shape[3] % 128 == 0 \
+        and v.shape[3] % 128 == 0
     heads = forward_tiles(q.shape[1], h // hkv,
                           max(q.shape[3], v.shape[3]))[0] if kernel else 0
     # the target kernel beside them: the indexer in the same dtype, heads
@@ -467,7 +455,8 @@ def indexed_attention(q, k, v, qi, ki, w, topk: int, scale: float,
     trace.counter("dsa:lowering", cat="ops", track="%s%s%s/top%d" % (
         q.dtype.name, list(q.shape), "" if hkv == h else "/kv%d" % hkv,
         topk), kernel=int(kernel), plain=int(not kernel),
-        heads_a_mask_tile=heads, target_kernel=int(target))
+        heads_a_mask_tile=heads, target_kernel=int(target),
+        backward_kernel=int(kernel and _backward_takes(q, v)))
     return _indexed_attention(q, k, v, qi, ki, w, int(topk), float(scale),
                               layer, kernel, target)
 
@@ -494,10 +483,11 @@ class IndexedSelfAttentionOp(OpDef):
     selection and the target take none.  ``scale`` 0 means ``Dh**-0.5``;
     ``layer`` names the trace scopes ``dsa_*.l<layer>``.  The attend pass
     of a program lowered for a TPU (bfloat16, heads of whole 128 lanes,
-    ``T`` in whole tiles) runs two kernels: forward this repo's
-    ``splash_mha_fwd_selected`` (``ops/selected_attention.py``), backward
-    the library's fused splash-attention kernel under the selection as a
-    dynamic mask; the target pass beside them one kernel a layer,
+    ``T`` in whole tiles) runs two kernels of this repo's
+    (``ops/selected_attention.py``): forward ``splash_mha_fwd_selected``,
+    backward ``splash_mha_dkv_selected``, each reading one int8 tile of
+    the selection for a group of query heads; the target pass beside
+    them one kernel a layer,
     ``dsa_target_grads`` (``dsa_target_loss`` where nothing is
     differentiated); everything else runs the plain blocks."""
     params = [Param("topk", int, required=True),

@@ -171,23 +171,29 @@ def _rel(a, b):
     return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
 
 
-def _both_kernels_against_the_plain_blocks(seed, t, h, hkv, lone_row=None):
-    """This repo's forward kernel and, fed ITS log-sum-exp, the library's
-    fused backward kernel under a top-48 selection as a dynamic mask,
-    both interpreted on the CPU: outputs, log-sum-exp and the three
-    cotangents against the plain blocks on the same bfloat16 inputs of
-    128-lane heads.  ``lone_row`` selects key 3 alone."""
+def _both_kernels_against_the_plain_blocks(seed, t, h, hkv, lone_row=None,
+                                           dv=128, early_rows=0):
+    """This repo's forward kernel and, fed ITS output and log-sum-exp,
+    its backward kernel under a top-48 selection, both interpreted on
+    the CPU: outputs, log-sum-exp and the three cotangents against the
+    plain blocks on the same bfloat16 inputs of 128-lane heads (values
+    of ``dv`` lanes).  ``lone_row`` selects key 3 alone, each of the
+    first ``early_rows`` rows key 0 alone."""
     q, k, v, qi, ki, w = (x[0] for x in inputs(seed, 1, t, h, hkv,
                                                jnp.bfloat16, dh=128))
+    if dv != 128:
+        v = jnp.asarray(np.random.RandomState(seed).randn(t, hkv, dv),
+                        jnp.bfloat16)
     assert tr._kernel_takes(q[None], k[None], v[None])
     mask = sa._select(qi, ki[:, 0], w, 48, None)
     assert int(mask.sum()) == sum(min(i + 1, 48) for i in range(t))
     if lone_row is not None:
         mask = mask.at[lone_row].set(False).at[lone_row, 3].set(True)
+    mask = mask.at[:early_rows].set(False).at[:early_rows, 0].set(True)
     qs = q * jnp.bfloat16(128 ** -0.5)
     out, lse = sa._attend_kernel(qs, k, v, mask, interpret=True)
     want, want_lse = sa._attend_plain(qs, k, v, mask)
-    assert out.dtype == want.dtype and out.shape == want.shape == (t, h, 128)
+    assert out.dtype == want.dtype and out.shape == want.shape == (t, h, dv)
     assert lse.dtype == jnp.float32 and lse.shape == (h, t)
     f32 = jnp.float32
     assert np.abs(out.astype(f32) - want.astype(f32)).max() < 0.03
@@ -197,12 +203,21 @@ def _both_kernels_against_the_plain_blocks(seed, t, h, hkv, lone_row=None):
         assert np.array_equal(
             np.asarray(out[lone_row].astype(f32)),
             np.asarray(jnp.repeat(v[3], h // hkv, axis=0).astype(f32)))
-    g = inputs(6, 1, t, h, hkv, jnp.bfloat16, dh=128)[0][0]
+    g = inputs(6, 1, t, h, hkv, jnp.bfloat16, dh=dv)[0][0]
     got = sa._attend_kernel_bwd(qs, k, v, mask, out, lse, g, interpret=True)
     ref = sa._attend_plain_bwd(qs, k, v, mask, want, want_lse, g)
     for a, b in zip(got, ref):
-        assert a.dtype == b.dtype == jnp.bfloat16
+        assert a.dtype == b.dtype == jnp.bfloat16 and a.shape == b.shape
         assert _rel(a, b) < 0.02
+
+
+def _small_tiles(monkeypatch, rows, block_kv=256, piece=128):
+    """Both attend kernels in tiles a few hundred rows fill."""
+    for name, n in (("ROWS", rows), ("BLOCK_KV", block_kv), ("PIECE", piece)):
+        monkeypatch.setattr(sel, name, n)
+        monkeypatch.setattr(sel, "BACKWARD_" + name, n)
+    monkeypatch.setattr(tr, "ATTN_KERNEL_BLOCK", block_kv)
+    monkeypatch.setattr(tr, "ATTN_KERNEL_SLICE", piece)
 
 
 @pytest.mark.parametrize("h,hkv,rows,tiles", [
@@ -216,13 +231,61 @@ def test_the_forward_kernel_interpreted_is_the_plain_blocks(
     128 at a time (the diagonal tile runs only the pieces its rows
     reach), a step's heads under ONE int8 tile of the selection, one row
     that selects a single key of the first tile."""
-    monkeypatch.setattr(sel, "ROWS", rows)
-    monkeypatch.setattr(sel, "BLOCK_KV", 256)
-    monkeypatch.setattr(sel, "PIECE", 128)
-    monkeypatch.setattr(tr, "ATTN_KERNEL_BLOCK", 256)
-    monkeypatch.setattr(tr, "ATTN_KERNEL_SLICE", 128)
+    _small_tiles(monkeypatch, rows)
     assert sel.forward_tiles(768, h // hkv, 128) == tiles
     _both_kernels_against_the_plain_blocks(h, 768, h, hkv, lone_row=700)
+
+
+@pytest.mark.parametrize("t,h,hkv,dv,rows,tiles", [
+    (768, 2, 2, 128, 256, (1, 256, 256, 128)),
+    (768, 4, 2, 128, 256, (2, 128, 256, 128)),
+    (768, 8, 1, 128, 1024, (8, 128, 256, 128)),
+    (768, 8, 1, 128, 512, (4, 128, 256, 128)),
+    (512, 4, 2, 256, 1024, (2, 256, 256, 128)),
+    (128, 4, 2, 128, 1024, (2, 128, 128, 128))],
+    ids=["groups-of-1", "groups-of-2", "groups-of-8",
+         "groups-of-8-two-steps-a-key-head", "values-of-256-lanes",
+         "a-single-tile"])
+def test_the_backward_kernel_interpreted_is_the_plain_blocks(
+        monkeypatch, t, h, hkv, dv, rows, tiles):
+    """``selected_attention_bwd`` against ``_attend_plain_bwd``: ``dq``
+    summed over a query tile's causal key tiles in VMEM, ``dk`` and
+    ``dv`` of a key/value head over all its query tiles AND its group's
+    heads (in two steps where the group is wider than a step), a step's
+    heads under ONE int8 tile of the selection, a diagonal tile that runs
+    only the pieces its rows reach; ``Dv != Dh``; one tile in all; the
+    first 40 rows each keep key 0 alone."""
+    _small_tiles(monkeypatch, rows)
+    assert sel.backward_tiles(t, h // hkv, max(128, dv)) == tiles
+    _both_kernels_against_the_plain_blocks(t + h, t, h, hkv, dv=dv,
+                                           early_rows=40)
+
+
+def test_the_backward_kernels_tiles_follow_the_shapes():
+    """At the Keye cell's shape a step holds the 8 heads of a key/value
+    head under one mask tile; the rows a head follow the group and the
+    head widths, every tile is a whole divisor in whole 128s, and a
+    sequence whose float32 ``dk`` and ``dv`` of one key/value head pass
+    BACKWARD_KEPT, or one not in whole 128s, is not taken."""
+    rows, bkv, piece = (sel.BACKWARD_ROWS, sel.BACKWARD_BLOCK_KV,
+                        sel.BACKWARD_PIECE)
+    assert sel.backward_tiles(8192, 8, 128) == (8, rows // 8, bkv, piece)
+    assert sel.backward_tiles(8192, 1, 128) == (1, min(rows, bkv), bkv, piece)
+    assert sel.backward_tiles(8192, 128, 128)[0] == min(128, rows // 128)
+    assert sel.backward_tiles(8192, 8, 256)[:2] == (8, rows // 16)
+    assert sel.backward_tiles(384, 4, 128) == (4, 384 if rows >= 1536
+                                               else 128, 384, 384 if piece
+                                               >= 384 else 128)
+    assert sel.backward_tiles(2048, 3, 128)[0] == 3
+    assert sel.backward_tiles(32768, 8, 128) is not None
+    assert sel.backward_tiles(65536, 8, 128) is None
+    assert sel.backward_tiles(32768, 8, 256) is None
+    assert sel.backward_tiles(8192 + 64, 8, 128) is None
+    for t, group, lanes in ((8192, 8, 128), (1536, 6, 128), (640, 2, 256)):
+        heads, bq, bkv, piece = sel.backward_tiles(t, group, lanes)
+        assert group % heads == 0 and t % bq == 0 and t % bkv == 0
+        assert bkv % piece == 0 and bq % 128 == 0 and piece % 128 == 0
+        assert bq <= bkv and heads * bq <= rows * 128 // lanes
 
 
 def test_the_forward_kernels_tiles_follow_the_shapes():
@@ -241,11 +304,9 @@ def test_the_forward_kernels_tiles_follow_the_shapes():
 def test_the_kernel_lowering_interpreted_is_the_plain_blocks(monkeypatch):
     """Both kernels at (256, 4 heads over 2, 128) in tiles of 128, two
     heads a mask tile."""
-    monkeypatch.setattr(tr, "ATTN_KERNEL_BLOCK", 128)
-    monkeypatch.setattr(tr, "ATTN_KERNEL_SLICE", 128)
-    monkeypatch.setattr(sel, "ROWS", 256)
-    monkeypatch.setattr(sel, "BLOCK_KV", 128)
+    _small_tiles(monkeypatch, 256, 128, 128)
     assert sel.forward_tiles(256, 2, 128) == (2, 128, 128, 128)
+    assert sel.backward_tiles(256, 2, 128) == (2, 128, 128, 128)
     _both_kernels_against_the_plain_blocks(5, 256, 4, 2)
 
 
@@ -337,6 +398,40 @@ def test_the_op_with_both_flags_off_the_chip_is_the_plain_blocks():
     assert np.isfinite(float(out[1][0]))
 
 
+def test_the_program_lowered_for_a_tpu_holds_the_repos_three_kernels(
+        monkeypatch):
+    """The op and its backward at the Keye cell's shapes (32 heads over 4,
+    8192 rows, a 16 x 64 indexer's top-2048), lowered for a TPU from
+    here: this repo's three kernels by their device names, none of the
+    library's, no int32 ``MaskInfo`` tiling of the selection and no
+    partial ``dq`` planes ``(key tiles, H, T, Dh)``."""
+    import re
+    monkeypatch.setattr(sa, "DSA_BLOCK_Q", 256)
+    t, h, hkv, dh, hi, di, topk = 8192, 32, 4, 128, 16, 64, 2048
+    shapes = [(1, t, h, dh), (1, t, hkv, dh), (1, t, hkv, dh),
+              (1, t, hi, di), (1, t, 1, di), (1, t, hi)]
+
+    def run(*args):
+        outs, vjp = jax.vjp(lambda *a: sa._indexed_attention(
+            *a, topk, dh ** -0.5, 0, True, True)[:2], *args)
+        return outs + vjp((jnp.ones_like(outs[0]),
+                           jnp.ones((1,), jnp.float32)))
+
+    # lint: allow(raw-jit) — one-off lowering inspection
+    text = jax.jit(run).trace(*(jax.ShapeDtypeStruct(s, jnp.bfloat16)
+                                for s in shapes)).lower(
+        lowering_platforms=("tpu",)).as_text()
+    for name in ("splash_mha_fwd_selected", "splash_mha_dkv_selected",
+                 "dsa_target_grads"):
+        assert text.count("tpu_custom_call") >= 3 and name in text
+    assert "splash_mha_dkv_no_residuals" not in text
+    assert "splash_mha_fwd_residuals" not in text
+    assert not re.search(r"x1024x1024xi32", text)
+    assert not re.search(r"tensor<\d+x%dx%dx%dx" % (h, t, dh), text)
+    # dq leaves the kernel as the op's rows, once
+    assert re.search(r"tensor<%dx%dxbf16>" % (t, h * dh), text)
+
+
 def test_the_op_node_its_shapes_and_its_counter():
     q, k, v, qi, ki, w = (mx.sym.Variable(n) for n in
                           ("q", "k", "v", "qi", "ki", "w"))
@@ -364,9 +459,12 @@ def test_the_op_node_its_shapes_and_its_counter():
         mx.trace.reset()
         mx.trace.set_enabled(was)
     assert events and events[-1]["args"] == {
-        "kernel": 0, "plain": 1, "heads_a_mask_tile": 0, "target_kernel": 0}
+        "kernel": 0, "plain": 1, "heads_a_mask_tile": 0, "target_kernel": 0,
+        "backward_kernel": 0}
     # what the kernels take: the group a mask tile serves beside them;
-    # the target kernel an indexer of 64-lane heads in the same dtype
+    # the target kernel an indexer of 64-lane heads in the same dtype; the
+    # backward kernel whatever the forward kernel takes, up to the length
+    # whose float32 ``dk`` and ``dv`` of a key/value head fit its VMEM
     took = inputs(2, 1, 256, 8, 2, jnp.bfloat16, dh=128)
     wide = tuple(jax.ShapeDtypeStruct(x.shape[:3] + (64,), x.dtype)
                  for x in took[3:5])
@@ -376,14 +474,19 @@ def test_the_op_node_its_shapes_and_its_counter():
         jax.eval_shape(op(8, 128 ** -0.5), *took[:3], *wide, took[5])
         jax.eval_shape(op(8, 128 ** -0.5), *took[:3], *wide,
                        took[5].astype(jnp.float32))
+        long = tuple(jax.ShapeDtypeStruct((1, 65536) + x.shape[2:], x.dtype)
+                     for x in took)
+        jax.eval_shape(op(8, 128 ** -0.5), *long)
         events = mx.trace.counter_events(["dsa:lowering"])
     finally:
         mx.trace.reset()
         mx.trace.set_enabled(was)
     assert [(e["id"], e["args"]) for e in events] == [
-        ("bfloat16[1, 256, 8, 128]/kv2/top8",
+        ("bfloat16[1, %d, 8, 128]/kv2/top8" % t,
          {"kernel": 1, "plain": 0, "heads_a_mask_tile": 4,
-          "target_kernel": n}) for n in (0, 1, 0)]
+          "target_kernel": n, "backward_kernel": b})
+        for t, n, b in ((256, 0, 1), (256, 1, 1), (256, 0, 1),
+                        (65536, 0, 0))]
 
 
 def test_layer_norm_is_its_equation():

@@ -29,7 +29,12 @@ attend passes side by side (PR 58): the library's splash attention under
 the selection as a dynamic mask (the op's forward until PR 58), the
 library's under a static causal mask (the floor of a kernel that visits
 every causal tile), and the op's own forward kernel, each as a jitted
-call and as its ``splash_mha*`` operation's time in a device trace.
+call and as its ``splash_mha*`` operation's time in a device trace; and
+two backward attend passes (PR 62): the library's fused kernel under the
+selection as a dynamic mask with what the op put around it until PR 62
+(the ``MaskInfo`` tiling, head-major copies, the partial ``dq`` planes'
+sum), and the op's own backward kernel ``splash_mha_dkv_selected``, the
+three cotangents of each against the other's and the plain blocks'.
 """
 import gc
 import json
@@ -230,7 +235,7 @@ def test_published_width_step_matches_reference():
         assert len(bf16["dsa_lowering"]) == kw["num_layers"]
         for track, args in bf16["dsa_lowering"]:
             assert args == {"kernel": 1, "plain": 0, "heads_a_mask_tile": 8,
-                            "target_kernel": 1}
+                            "target_kernel": 1, "backward_kernel": 1}
             assert track == "bfloat16[1, 8192, 32, 128]/kv4/top2048"
     # float8 weights are refused by at least one limit
     assert fp8["loss_rel_err"] > limits["loss_rtol"] or any(
@@ -243,6 +248,79 @@ def test_published_width_step_matches_reference():
     assert max(f32["update_rel_err"].values()) <= 0.1, f32
 
 
+def _library_sizes(t):
+    """The tiles ``causal_attention`` gives the library's kernels."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk)
+    from mxnet_tpu.ops import transformer as tr
+    tile, piece = tr._kernel_tiles(t)
+    return tile, sk.BlockSizes(
+        block_q=tile, block_kv=tile, block_kv_compute=piece,
+        block_q_dkv=tile, block_kv_dkv=tile, block_kv_dkv_compute=piece,
+        use_fused_bwd_kernel=True)
+
+
+def _kernel_ms(fn, *args):
+    """ms a call of the ``splash_mha*`` operations in a device trace."""
+    import jax
+    sys.path.insert(0, os.path.join(ROOT, "benchmark"))
+    import trace_reduce
+    with tempfile.TemporaryDirectory() as d:
+        with jax.profiler.trace(d):
+            for _ in range(3):
+                out = fn(*args)
+            jax.block_until_ready(out)
+        devices, _ = trace_reduce.load_xplane(trace_reduce.find_xplane(d))
+    return sum(ns for name, _, ns in sorted(devices.items())[0][1]
+               if name.startswith("splash_mha")) / 3e6
+
+
+def _library_backward(q, k, v, mask, out, lse, g):
+    """The op's backward attend pass until PR 62: the library's fused
+    kernel under the selection as a dynamic mask, one head's ``MaskInfo``
+    for every head, head-major operands, the partial ``dq`` planes
+    summed."""
+    import jax
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask_info as mi)
+    tile, sizes = _library_sizes(q.shape[0])
+    info = mi.process_dynamic_mask_dkv(mask[None], (tile, tile),
+                                       shrink_grid=False)[0]
+    info = info._replace(partial_mask_blocks=info.partial_mask_blocks
+                         .reshape(-1, tile, tile))
+    heads = tuple(x.transpose(1, 0, 2) for x in (q, k, v))
+    res = heads + (None, None, out.transpose(1, 0, 2), lse, None, info)
+    with jax.default_matmul_precision("default"):
+        grads = sk._splash_attention_bwd(
+            False, sk.DEFAULT_MASK_VALUE, False, sizes, None, None, None,
+            False, res, g.transpose(1, 0, 2))
+    return tuple(x.transpose(1, 0, 2).astype(y.dtype)
+                 for x, y in zip(grads[3:6], (q, k, v)))
+
+
+def _two_backwards(ms, operands):
+    """``({name: {"call", "kernel"}}, {pair: [l2 errors of dq, dk, dv]})``
+    of the two backward attend passes at the cell's shape and the plain
+    blocks'."""
+    import jax
+    from mxnet_tpu.ops import sparse_attention as sa
+    passes = {"library_dynamic_mask": jax.jit(_library_backward),
+              "selected": jax.jit(sa._attend_kernel_bwd)}
+    times = {name: {"call": ms(fn, *operands),
+                    "kernel": _kernel_ms(fn, *operands)}
+             for name, fn in passes.items()}
+    got = {name: [np.asarray(x, np.float32) for x in fn(*operands)]
+           for name, fn in passes.items()}
+    got["plain"] = [np.asarray(x, np.float32) for x in
+                    jax.jit(sa._attend_plain_bwd)(*operands)]
+    errors = {"%s_against_%s" % (a, b): [_rel(x, y) for x, y in
+                                         zip(got[a], got[b])]
+              for a, b in (("selected", "plain"),
+                           ("library_dynamic_mask", "plain"),
+                           ("selected", "library_dynamic_mask"))}
+    return times, errors
+
+
 def _three_forwards(ms, attend, qs, k, v, mask):
     """ms a layer of three forward attend passes at the cell's shape,
     ``{name: {"call": the jitted call, "kernel": its splash_mha*
@@ -253,11 +331,8 @@ def _three_forwards(ms, attend, qs, k, v, mask):
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm,
         splash_attention_mask_info as mi)
-    from mxnet_tpu.ops import sparse_attention as sa
-    sys.path.insert(0, os.path.join(ROOT, "benchmark"))
-    import trace_reduce
     t, h = qs.shape[:2]
-    tile, sizes = sa._kernel_sizes(t)
+    tile, sizes = _library_sizes(t)
 
     def heads_major(fn, *operands):
         with jax.default_matmul_precision("default"):
@@ -277,16 +352,6 @@ def _three_forwards(ms, attend, qs, k, v, mask):
         sm.MultiHeadMask([sm.CausalMask((t, t))] * h), block_sizes=sizes,
         save_residuals=True)
 
-    def kernel_ms(fn, *args):
-        with tempfile.TemporaryDirectory() as d:
-            with jax.profiler.trace(d):
-                for _ in range(3):
-                    out = fn(*args)
-                jax.block_until_ready(out)
-            devices, _ = trace_reduce.load_xplane(trace_reduce.find_xplane(d))
-        return sum(ns for name, _, ns in sorted(devices.items())[0][1]
-                   if name.startswith("splash_mha")) / 3e6
-
     forwards = {}
     for name, fn, args in (
             ("library_dynamic_mask", jax.jit(dynamic), (qs, k, v, mask)),
@@ -295,7 +360,7 @@ def _three_forwards(ms, attend, qs, k, v, mask):
              (qs, k, v)),
             ("selected", attend, (qs, k, v, mask))):
         forwards[name] = {"call": ms(fn, *args),
-                          "kernel": kernel_ms(fn, *args)}
+                          "kernel": _kernel_ms(fn, *args)}
     return forwards
 
 
@@ -335,7 +400,13 @@ def test_the_op_alone_both_lowerings_and_the_passes_times():
     kernel, plain = both_passes(True, 512), both_passes(False, 512)
     text = kernel.lower(*short).compile().as_text()
     assert "tpu_custom_call" in text and "splash_mha_fwd_selected" in text
-    assert "splash_mha_dkv" in text and "splash_mha_fwd_residuals" not in text
+    assert "splash_mha_dkv_selected" in text
+    assert "splash_mha_fwd_residuals" not in text
+    # the backward attend pass is this repo's kernel too (PR 62): none of
+    # the library's, no int32 tiling of the selection, no partial dq planes
+    assert "splash_mha_dkv_no_residuals" not in text
+    assert not re.search(r"s32\[[\d,]*1024,1024\]", text)
+    assert not re.search(r"bf16\[\d+,%d,2048,%d\]" % (h, dh), text)
     # the target pass: this repo's kernel (no ``splash_mha`` in its name:
     # dsa_attn_roofline's reader goes by that prefix) and none of the plain
     # blocks' float32 head products ``(Hkv, H / Hkv, 256, keys)``
@@ -405,9 +476,10 @@ def test_the_op_alone_both_lowerings_and_the_passes_times():
     times["attend_kernel_forward"] = ms(attend, qs, one[1], one[2], mask)
     report["forward_ms_a_layer"] = _three_forwards(
         ms, attend, qs, one[1], one[2], mask)
-    times["attend_kernel_backward"] = ms(
-        jax.jit(sa._attend_kernel_bwd), qs, one[1], one[2], mask, out, lse,
-        g[0].astype(jnp.bfloat16))
+    backwards, report["backward_8192_l2_err"] = _two_backwards(
+        ms, (qs, one[1], one[2], mask, out, lse, g[0].astype(jnp.bfloat16)))
+    report["backward_ms_a_layer"] = backwards
+    times["attend_kernel_backward"] = backwards["selected"]["call"]
     # the target pass in isolation, both lowerings (PR 60)
     for grads in (False, True):
         name = "target_with_gradient" if grads else "target_loss_only"
@@ -439,6 +511,16 @@ def test_the_op_alone_both_lowerings_and_the_passes_times():
     for kind in ("call", "kernel"):
         assert forwards["selected"][kind] + 2.0 \
             <= forwards["library_dynamic_mask"][kind], forwards
+    # the op's own backward kernel: the plain blocks' cotangents as closely
+    # as the library's kernel gave them, in less time as a call (what the
+    # library's form put around its kernel is gone)
+    errors = report["backward_8192_l2_err"]
+    for n in ("selected_against_plain", "library_dynamic_mask_against_plain"):
+        assert max(errors[n]) <= ATTN_L2_ERR, errors
+    assert max(errors["selected_against_plain"]) \
+        <= 1.25 * max(errors["library_dynamic_mask_against_plain"]), errors
+    assert backwards["selected"]["call"] + 1.0 \
+        <= backwards["library_dynamic_mask"]["call"], backwards
     # the indexer's side reads the log-sum-exp of whichever lowering ran
     assert report["l2_err"]["index_loss"] <= 1e-3, report
     for n in ("d_qi", "d_ki", "d_w"):
