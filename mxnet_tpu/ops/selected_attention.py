@@ -1,7 +1,10 @@
 """The attend pass, forward and backward, and the target pass of
 ``IndexedSelfAttention`` as three Pallas kernels of this repo, none of
 the library's, all under a selection that is DATA (a ``(T, T)`` mask no
-function computes) over grouped queries.
+function computes) over grouped queries; and the attend pair once more
+under a mask that is a FUNCTION of row and key, ``causal_attention``'s
+TPU lowering for grouped 128-lane heads (``computed_attention_fwd`` /
+``computed_attention_bwd``, the last section below).
 
 **The forward attend pass** (``selected_attention_fwd``): softmax
 attention of one sequence under the selection.
@@ -82,6 +85,25 @@ call is one inner ``jax.jit``, so the layers and modules of a process
 share one traced kernel.  Its device names are ``dsa_target_grads`` and
 ``dsa_target_loss``: not ``splash_mha*``, which ``dsa_attn_roofline``'s
 work function does not count it under.
+
+**The attend pair under a computed mask** (``computed_attention_fwd`` /
+``computed_attention_bwd``, PR 64; ``splash_mha_fwd_computed`` /
+``splash_mha_dkv_computed`` on the device, the prefix every
+``*attn_roofline`` reader sums): the SAME two step bodies (``_kernel``,
+``_backward_step``), which take where a tile's allowed pairs come from as
+a parameter: ``_Loaded`` is the int8 tile above, ``_Computed`` evaluates
+``allowed(rows' entries, key ids)`` (``ops/transformer.py``
+``kernel_mask``: causal, ``sliding_window``, ``block_diffusion`` through
+its host-made row codes) in VMEM from the rows' int32 entries and an iota
+of the tile's keys, once for the heads of a step, where the library's
+kernels evaluate it once a head.  Nothing ``(T, T)`` exists.  Which key
+tiles a query tile visits is a table made on the host at trace time from
+the mask itself (``visit_plan``) and prefetched as scalars: only tiles
+that hold an allowed pair, a tile's empty pieces skipped, a query tile's
+``dq`` written at its last visit and a key tile's ``dk`` / ``dv`` at the
+last visit any query tile pays it (under a window or the block mask the
+last query tile does not read every key).  The operands are ``(B, T, H *
+Dh)`` and ``(B, T, Hkv * D)`` rows, the batch a grid axis.
 """
 from __future__ import annotations
 
@@ -95,7 +117,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["selected_attention_fwd", "forward_tiles",
            "selected_attention_bwd", "backward_tiles", "selected_target",
-           "target_tiles"]
+           "target_tiles", "computed_attention_fwd", "computed_attention_bwd",
+           "visit_plan"]
 
 LANES = 128
 # what masks a score: the library's value, so a row's log-sum-exp over a
@@ -142,13 +165,153 @@ def forward_tiles(t: int, group: int, lanes: int):
     return _attend_tiles(t, group, lanes, ROWS, BLOCK_KV, PIECE)
 
 
-def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_ref, l_ref,
-            acc_ref, *, heads, bq, bkv, piece, dh, dv):
-    i, j = pl.program_id(1), pl.program_id(2)
-    last_row = (i + 1) * bq - 1
-    last_tile = last_row // bkv
+class _Loaded:
+    """Where a step of the attend kernels gets its allowed pairs when the
+    mask is DATA: an int8 ``(bq, bkv)`` tile of the selection, loaded a
+    step, under the grid (heads' steps, query tiles, key tiles) of a
+    selection that is causal and nothing more: key tile ``j`` is the
+    grid's, the tiles behind a query tile's last row are never run and
+    the last query tile reads every key."""
 
-    @pl.when(j == 0)
+    def __init__(self, refs, bq, bkv, backward):
+        self.refs = refs
+        self.mask_ref = refs[6 if backward else 3]
+        if backward:
+            self.n = pl.program_id(0)
+        self.i, self.j = pl.program_id(1), pl.program_id(2)
+        self.last_row = (self.i + 1) * bq - 1
+        self.last_tile = self.last_row // bkv
+        self.tile, self.bkv, self.backward = self.j, bkv, backward
+
+    @property
+    def first(self):
+        return self.j == 0
+
+    @property
+    def last(self):
+        return self.j == self.last_tile
+
+    def runs(self, n, lo):
+        """Whether piece ``n``, keys ``lo`` on of the tile, holds a pair
+        at all: its first key is not beyond the last row."""
+        return self.j * self.bkv + lo <= self.last_row
+
+    def keep(self, lo, piece):
+        """The piece's allowed pairs ``(bq, piece)``; keys down in the
+        backward pass, where the tile is transposed in VMEM."""
+        tile = self.mask_ref[:, lo:lo + piece]
+        if self.backward:
+            return tile.astype(jnp.float32).T != 0.0
+        return tile.astype(jnp.int32) != 0
+
+    def closes(self, steps):
+        """Whether this step's key tile has its whole ``dk`` and ``dv``:
+        the last query tile of a key/value head's last step reads every
+        key tile."""
+        return (self.n % steps == steps - 1) \
+            & (self.i == pl.num_programs(1) - 1)
+
+
+class _Computed:
+    """Where a step gets its allowed pairs when the mask is a FUNCTION of
+    row and key: ``allowed(rows' entries, key ids)`` (``ops/transformer.py``
+    ``kernel_mask``) evaluated in VMEM on the rows' int32 entries, loaded
+    a query tile (a column in the forward pass, a row of lanes in the
+    backward one), and an iota of the tile's keys, once for the heads of
+    the step.  The grid is (batch, heads' steps, query tiles, VISITS): a
+    query tile's ``j``-th visit is to the key tile the prefetched
+    ``visit_plan`` names, and the plan says which of its pieces run, when
+    a query tile has seen its last key and when a key tile its last
+    query."""
+
+    def __init__(self, refs, bq, bkv, backward, allowed):
+        plan_ref, *self.refs = refs
+        self.codes_ref = self.refs[6 if backward else 3]
+        self.n, self.i, self.j = (pl.program_id(a) for a in (1, 2, 3))
+        self.tile, self.pieces, self.flags = (
+            plan_ref[field, self.i, self.j] for field in range(3))
+        self.bq, self.bkv, self.backward = bq, bkv, backward
+        self.allowed = allowed
+
+    @property
+    def first(self):
+        return self.j == 0
+
+    @property
+    def last(self):
+        return self.flags & LAST_VISIT != 0
+
+    def runs(self, n, lo):
+        return (self.pieces >> n) & 1 != 0
+
+    def keep(self, lo, piece):
+        first_key = self.tile * self.bkv + lo
+        if self.backward:
+            shape = (piece, self.bq)
+            codes = jnp.broadcast_to(self.codes_ref[:1, :], shape)
+        else:
+            shape = (self.bq, piece)
+            codes = jnp.tile(self.codes_ref[...], (1, piece // LANES))
+        return self.allowed(codes, first_key + jax.lax.broadcasted_iota(
+            jnp.int32, shape, 0 if self.backward else 1))
+
+    def closes(self, steps):
+        return (self.n % steps == steps - 1) & (self.flags & CLOSES != 0)
+
+
+# ``visit_plan``'s flags: the query tile's last visit, and the last visit
+# that the key tile gets from any query tile
+LAST_VISIT, CLOSES = 1, 2
+
+
+def visit_plan(rows, allowed, bq: int, bkv: int, piece: int):
+    """The visits of the computed-mask kernels, made on the host at trace
+    time from the mask itself: int32 ``(4, T / bq, visits)`` for ``rows``
+    (the mask's int32 entry a row, ``T`` of them) and ``allowed(rows'
+    entries, key ids)``.  Field 0: the key tile of a query tile's
+    ``j``-th visit, only tiles that hold an allowed pair, in order; the
+    visits behind the last repeat it, so nothing is fetched for them.
+    Field 1: bit ``n`` says piece ``n`` of that tile holds an allowed
+    pair (0 behind the last visit: nothing runs).  Field 2: LAST_VISIT on
+    a query tile's last visit, CLOSES on the visit after which no later
+    step of the grid (query tiles outermost) reads the key tile again.
+    Field 3: the key tile whose ``dk`` and ``dv`` blocks are the current
+    ones at a step: the last to have closed, or the first that will."""
+    t = len(rows)
+    nq, nk, pieces = t // bq, t // bkv, bkv // piece
+    keys = np.arange(t, dtype=np.int32)[None, :]
+    some = np.stack([
+        np.asarray(allowed(rows[at:at + bq, None], keys)).reshape(
+            bq, t // piece, piece).any(axis=(0, 2))
+        for at in range(0, t, bq)]).reshape(nq, nk, pieces)
+    if not (some.any(axis=(1, 2)).all() and some.any(axis=(0, 2)).all()):
+        raise ValueError("a query tile or a key tile without one allowed pair")
+    bits = (some.astype(np.int32) << np.arange(pieces, dtype=np.int32)).sum(2)
+    visits = [np.flatnonzero(row) for row in bits]
+    plan = np.zeros((4, nq, max(len(v) for v in visits)), np.int32)
+    for i, tiles in enumerate(visits):
+        plan[0, i] = tiles[-1]
+        plan[0, i, :len(tiles)] = tiles
+        plan[1, i, :len(tiles)] = bits[i, tiles]
+        plan[2, i, len(tiles) - 1] = LAST_VISIT
+    closed = []
+    for i, tiles in enumerate(visits):
+        for j, tile in enumerate(tiles):
+            if not bits[i + 1:, tile].any():
+                plan[2, i, j] |= CLOSES
+                closed.append((i, j, tile))
+    plan[3] = closed[0][2]
+    for i, j, tile in closed:
+        plan[3, i, j:] = tile
+        plan[3, i + 1:] = tile
+    return plan
+
+
+def _kernel(*refs, source, heads, bq, bkv, piece, dh, dv):
+    at = source(refs, bq, bkv, False)
+    q_ref, k_ref, v_ref, _, o_ref, lse_ref, m_ref, l_ref, acc_ref = at.refs
+
+    @pl.when(at.first)
     def _():
         m_ref[...] = jnp.full_like(m_ref, MASK_VALUE)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -161,8 +324,7 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_ref, l_ref,
         s = jax.lax.dot_general(q, k_ref[lo:lo + piece, :],
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        keep = mask_ref[:, lo:lo + piece].astype(jnp.int32) != 0
-        s = jnp.where(keep[None], s.reshape(heads, bq, piece),
+        s = jnp.where(at.keep(lo, piece)[None], s.reshape(heads, bq, piece),
                       MASK_VALUE).reshape(heads * bq, piece)
         m_prev, l_prev = m_ref[...], l_ref[...]
         m_next = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
@@ -175,13 +337,11 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_ref, l_ref,
         acc_ref[...] = jnp.tile(alpha, (1, dv // LANES)) * acc_ref[...] + pv
 
     for n in range(bkv // piece):
-        # a piece whose first key lies beyond the query tile's last row
-        # holds no causal pair: the diagonal tile runs only the pieces it
-        # needs, the tiles behind it none
-        pl.when(j * bkv + n * piece <= last_row)(
-            functools.partial(one_piece, n * piece))
+        # only the pieces that hold an allowed pair: the diagonal tile
+        # runs the pieces its rows reach, the tiles behind it none
+        pl.when(at.runs(n, n * piece))(functools.partial(one_piece, n * piece))
 
-    @pl.when(j == last_tile)
+    @pl.when(at.last)
     def _():
         l = l_ref[...]
         out = acc_ref[...] * jnp.tile(1.0 / l, (1, dv // LANES))
@@ -222,8 +382,8 @@ def selected_attention_fwd(q, k, v, mask, interpret: bool = False):
     # (tests/test_sparse_attention.py, tests/tpu/test_keye_tpu.py): not a
     # forward kernel behind the kernel search's bitwise gate
     out, lse = pl.pallas_call(
-        functools.partial(_kernel, heads=heads, bq=bq, bkv=bkv, piece=piece,
-                          dh=dh, dv=dv),
+        functools.partial(_kernel, source=_Loaded, heads=heads, bq=bq,
+                          bkv=bkv, piece=piece, dh=dh, dv=dv),
         grid=(h // heads, t // bq, t // bkv),
         in_specs=[
             pl.BlockSpec((bq, heads * dh), lambda n, i, j: (i, n)),
@@ -277,21 +437,19 @@ def backward_tiles(t: int, group: int, lanes: int):
                          BACKWARD_PIECE)
 
 
-def _backward_step(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, mask_ref,
-                   dq_ref, dk_ref, dv_ref, q_st, do_st, di_st, dq_acc,
-                   dk_acc, dv_acc, *, heads, steps, bq, bkv, piece, dh, dv):
-    n, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    last_row = (i + 1) * bq - 1
-    last_tile = last_row // bkv
+def _backward_step(*refs, source, heads, steps, bq, bkv, piece, dh, dv):
+    at = source(refs, bq, bkv, True)
+    (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, _, dq_ref, dk_ref, dv_ref,
+     q_st, do_st, di_st, dq_acc, dk_acc, dv_acc) = at.refs
     f32 = jnp.float32
     nt = (((1,), (1,)), ((), ()))
 
-    @pl.when((n % steps == 0) & (i == 0) & (j == 0))
+    @pl.when((at.n % steps == 0) & (at.i == 0) & (at.j == 0))
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(j == 0)
+    @pl.when(at.first)
     def _():
         # the query tile's operands as the key tiles read them: the heads'
         # rows one under another, and ``di = rowsum(out * d_out)`` a head
@@ -321,8 +479,8 @@ def _backward_step(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, mask_ref,
                                 preferred_element_type=f32)
         dp = jax.lax.dot_general(v_p, do_st[...], nt,
                                  preferred_element_type=f32)
-        # the forward's int8 tile, transposed in VMEM for the eight heads
-        keep = mask_ref[:, lo:lo + piece].astype(f32).T != 0.0
+        # the tile's pairs keys down, once for the group's heads
+        keep = at.keep(lo, piece)
         p, ds = [], []
         for g in range(heads):
             rows = slice(g * bq, (g + 1) * bq)
@@ -332,28 +490,27 @@ def _backward_step(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, mask_ref,
                 k_ref.dtype))
             p.append(p_g.astype(do_st.dtype))
         p, ds = jnp.concatenate(p, axis=1), jnp.concatenate(ds, axis=1)
-        keys = pl.ds(pl.multiple_of(j * bkv + lo, piece), piece)
+        keys = pl.ds(pl.multiple_of(at.tile * bkv + lo, piece), piece)
         dv_acc[keys, :] += jnp.dot(p, do_st[...], preferred_element_type=f32)
         dk_acc[keys, :] += jnp.dot(ds, q_st[...], preferred_element_type=f32)
         dq_acc[...] += jax.lax.dot_general(ds, k_p, (((0,), (0,)), ((), ())),
                                            preferred_element_type=f32)
 
-    for at in range(0, bkv, piece):
-        # as the forward: a piece whose first key lies beyond the query
-        # tile's last row holds no causal pair
-        pl.when(j * bkv + at <= last_row)(functools.partial(one_piece, at))
+    for n, lo in enumerate(range(0, bkv, piece)):
+        # as the forward: only the pieces that hold an allowed pair
+        pl.when(at.runs(n, lo))(functools.partial(one_piece, lo))
 
-    @pl.when(j == last_tile)
+    @pl.when(at.last)
     def _():
         for g in range(heads):
             dq_ref[:, g * dh:(g + 1) * dh] = \
                 dq_acc[g * bq:(g + 1) * bq].astype(dq_ref.dtype)
 
-    # the last query tile of a key/value head's last step reads every key
-    # tile: each leaves as its sum closes
-    @pl.when((n % steps == steps - 1) & (i == pl.num_programs(1) - 1))
+    # a key tile leaves as its sum closes: at the last visit it gets from
+    # a key/value head's last step
+    @pl.when(at.closes(steps))
     def _():
-        keys = pl.ds(pl.multiple_of(j * bkv, bkv), bkv)
+        keys = pl.ds(pl.multiple_of(at.tile * bkv, bkv), bkv)
         dk_ref[...] = dk_acc[keys, :].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[keys, :].astype(dv_ref.dtype)
 
@@ -408,8 +565,9 @@ def _selected_attention_bwd(q, k, v, mask, out, lse, d_out, *, interpret,
     # chosen by platform and held to the plain blocks by tolerance
     # (tests/test_sparse_attention.py, tests/tpu/test_keye_tpu.py)
     dq, dk, dv_ = pl.pallas_call(
-        functools.partial(_backward_step, heads=heads, steps=steps, bq=bq,
-                          bkv=bkv, piece=piece, dh=dh, dv=dv),
+        functools.partial(_backward_step, source=_Loaded, heads=heads,
+                          steps=steps, bq=bq, bkv=bkv, piece=piece, dh=dh,
+                          dv=dv),
         grid=(h // heads, t // bq, t // bkv),
         in_specs=[
             a_head(dh), a_key_tile(dh), a_key_tile(dv), a_head(dv),
@@ -434,6 +592,130 @@ def _selected_attention_bwd(q, k, v, mask, out, lse, d_out, *, interpret,
     )(q.reshape(t, h * dh), k.reshape(t, hkv * dh), v.reshape(t, hkv * dv),
       out.reshape(t, h * dv), d_out.reshape(t, h * dv),
       lse.reshape(h // heads, heads, t), mask.astype(jnp.int8))
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv_.reshape(v.shape)
+
+
+def _computed_specs(heads, steps, bq, bkv):
+    """The block specs of the computed-mask kernels over ``(B, T, lanes)``
+    rows: ``(a step's heads, the visited key tile of a key/value head,
+    the log-sum-exp of a step's heads)``, each by its lanes a head."""
+    def a_head(width):
+        return pl.BlockSpec((None, bq, heads * width),
+                            lambda b, n, i, j, plan: (b, i, n))
+
+    def a_key_tile(width):
+        return pl.BlockSpec(
+            (None, bkv, width),
+            lambda b, n, i, j, plan: (b, plan[0, i, j], n // steps))
+
+    lse = pl.BlockSpec((None, None, heads, bq),
+                       lambda b, n, i, j, plan: (b, n, 0, i))
+    return a_head, a_key_tile, lse
+
+
+def computed_attention_fwd(q, k, v, rows, allowed, interpret: bool = False):
+    """Softmax attention under a mask that is a function: ``(B, T, H, Dh)``
+    scaled queries, ``(B, T, Hkv, Dh)`` keys, ``(B, T, Hkv, Dv)`` values
+    (heads of whole 128 lanes, ``T`` of whole 128s), the mask's int32
+    entry a row ``rows`` (numpy, ``T``) and ``allowed(rows' entries, key
+    ids)`` (every row reads at least itself) -> ``(B, T, H, Dv)`` in the
+    inputs' dtype and the float32 log-sum-exp ``(B, H, T)``.  The kernel
+    is ``selected_attention_fwd``'s (``_kernel``) with the tile's pairs
+    from ``_Computed``; on the device ``splash_mha_fwd_computed``."""
+    b, t, h, dh = q.shape
+    hkv, dv = k.shape[2], v.shape[3]
+    heads, bq, bkv, piece = forward_tiles(t, h // hkv, max(dh, dv))
+    steps = h // hkv // heads
+    plan = visit_plan(rows, allowed, bq, bkv, piece)
+    a_head, a_key_tile, lse_spec = _computed_specs(heads, steps, bq, bkv)
+    codes = jnp.broadcast_to(jnp.asarray(rows)[:, None], (t, LANES))
+    # lint: allow(raw-pallas-call) — one lowering of causal_attention,
+    # chosen by platform and shape and held to the plain blocks by
+    # tolerance (tests/test_rows_attention.py, tests/tpu/test_sdar_tpu.py)
+    out, lse = pl.pallas_call(
+        functools.partial(
+            _kernel, source=functools.partial(_Computed, allowed=allowed),
+            heads=heads, bq=bq, bkv=bkv, piece=piece, dh=dh, dv=dv),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h // heads, t // bq, plan.shape[2]),
+            in_specs=[a_head(dh), a_key_tile(dh), a_key_tile(dv),
+                      pl.BlockSpec((bq, LANES),
+                                   lambda b, n, i, j, plan: (i, 0))],
+            out_specs=[a_head(dv), lse_spec],
+            scratch_shapes=[pltpu.VMEM((heads * bq, LANES), jnp.float32),
+                            pltpu.VMEM((heads * bq, LANES), jnp.float32),
+                            pltpu.VMEM((heads * bq, dv), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((b, t, h * dv), q.dtype),
+                   jax.ShapeDtypeStruct((b, h // heads, heads, t),
+                                        jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3 + ("arbitrary",)),
+        interpret=interpret,
+        name="splash_mha_fwd_computed",
+    )(jnp.asarray(plan), q.reshape(b, t, h * dh), k.reshape(b, t, hkv * dh),
+      v.reshape(b, t, hkv * dv), codes)
+    return out.reshape(b, t, h, dv), lse.reshape(b, h, t)
+
+
+def computed_attention_bwd(q, k, v, rows, allowed, out, lse, d_out,
+                           interpret: bool = False):
+    """The cotangents of ``computed_attention_fwd``'s scaled queries, keys
+    and values from its output ``(B, T, H, Dv)``, its log-sum-exp ``(B, H,
+    T)`` and the output's cotangent -> ``(dq, dk, dv)`` in their inputs'
+    shapes and dtypes: ``selected_attention_bwd``'s kernel
+    (``_backward_step``) with the tile's pairs from ``_Computed``, a key
+    tile leaving at the visit the plan says is its last; on the device
+    ``splash_mha_dkv_computed``.  ``backward_tiles`` must take ``T``."""
+    b, t, h, dh = q.shape
+    hkv, dv = k.shape[2], v.shape[3]
+    heads, bq, bkv, piece = backward_tiles(t, h // hkv, max(dh, dv))
+    steps = h // hkv // heads
+    plan = visit_plan(rows, allowed, bq, bkv, piece)
+    a_head, a_key_tile, lse_spec = _computed_specs(heads, steps, bq, bkv)
+    group_rows = heads * bq
+
+    def closed(width):
+        # the key tile that closed last, or before a key/value head's last
+        # step the first that will: unwritten and unfetched until it does
+        return pl.BlockSpec(
+            (None, bkv, width), lambda b, n, i, j, plan: (
+                b, jnp.where(n % steps == steps - 1, plan[3, i, j],
+                             plan[3, 0, 0]), n // steps))
+
+    codes = jnp.broadcast_to(jnp.asarray(rows)[None, :], (8, t))
+    # lint: allow(raw-pallas-call) — as computed_attention_fwd's
+    dq, dk, dv_ = pl.pallas_call(
+        functools.partial(
+            _backward_step,
+            source=functools.partial(_Computed, allowed=allowed),
+            heads=heads, steps=steps, bq=bq, bkv=bkv, piece=piece, dh=dh,
+            dv=dv),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h // heads, t // bq, plan.shape[2]),
+            in_specs=[a_head(dh), a_key_tile(dh), a_key_tile(dv), a_head(dv),
+                      a_head(dv), lse_spec,
+                      pl.BlockSpec((8, bq), lambda b, n, i, j, plan: (0, i))],
+            out_specs=[a_head(dh), closed(dh), closed(dv)],
+            scratch_shapes=[pltpu.VMEM((group_rows, dh), q.dtype),
+                            pltpu.VMEM((group_rows, dv), d_out.dtype),
+                            pltpu.VMEM((heads, bq), jnp.float32),
+                            pltpu.VMEM((group_rows, dh), jnp.float32),
+                            pltpu.VMEM((t, dh), jnp.float32),
+                            pltpu.VMEM((t, dv), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((b, t, h * dh), q.dtype),
+                   jax.ShapeDtypeStruct((b, t, hkv * dh), k.dtype),
+                   jax.ShapeDtypeStruct((b, t, hkv * dv), v.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 4,
+            vmem_limit_bytes=BACKWARD_VMEM),
+        interpret=interpret,
+        name="splash_mha_dkv_computed",
+    )(jnp.asarray(plan), q.reshape(b, t, h * dh), k.reshape(b, t, hkv * dh),
+      v.reshape(b, t, hkv * dv), out.reshape(b, t, h * dv),
+      d_out.reshape(b, t, h * dv), lse.reshape(b, h // heads, heads, t),
+      codes)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv_.reshape(v.shape)
 
 

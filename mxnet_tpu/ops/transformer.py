@@ -12,12 +12,12 @@ ONE inner function, ``causal_attention``, blockwise softmax attention
 that recomputes the scores in the backward pass.  It has two lowerings.
 The plain blocks walk the queries under ``lax.map`` of a
 ``jax.checkpoint``ed body and run on every platform.  Where the program
-is lowered for a TPU and the inputs are ones the kernel takes (bfloat16,
-heads of 64 or of 128 and more lanes, ``T`` whole tiles), Pallas splash
-attention under a causal mask (online softmax in VMEM, tiles above the
-diagonal skipped, a fused backward kernel) runs instead.  The code
-chooses from what it sees, no option does; ``attn:lowering`` records
-the choice.  Key and value may have fewer heads than the query (grouped
+is lowered for a TPU and the inputs are ones the kernels take (bfloat16,
+heads of 64 or of 128 and more lanes, ``T`` whole tiles), a Pallas pair
+runs instead (online softmax in VMEM, empty tiles skipped): this repo's
+own over ``(T, H * Dh)`` rows or the library's splash attention
+(``kernel_pair``).  The code chooses from what it sees, no option does;
+``attn:lowering`` records it.  Key and value may have fewer heads (grouped
 queries: query head ``j`` reads key/value head ``j // (H / Hkv)``), and
 the mask is one of ``MASKS``: ``causal``, ``block_diffusion`` over a
 doubled sequence ``[noised ; clean]`` (``block_diffusion_allowed``), or
@@ -220,25 +220,24 @@ def causal_attention(q, k, v, scale: float, mask: str = "causal",
                      block: int = 0, window: int = 0):
     """Masked self-attention of ``(B, T, H, Dh)`` q, ``(B, T, Hkv, Dh)``
     k and ``(B, T, Hkv, Dv)`` v -> ``(B, T, H, Dv)``; scores, softmax and
-    accumulation in float32.  The value heads may be narrower or wider
-    than the query's (latent attention); ``Hkv`` is ``H`` or a divisor of
-    it, and query head ``j`` then reads key/value head ``j // (H /
-    Hkv)``.  ``mask`` is ``causal`` (the name the function keeps),
-    ``block_diffusion`` with its ``block`` length, over ``T = 2 x`` the
-    clean length (``block_diffusion_allowed``), or ``sliding_window``
-    with its ``window`` >= 1 (``sliding_window_allowed``); a window of
-    ``T`` or more is the causal mask and lowers, and is counted, as it.
+    accumulation in float32.  ``Dv`` may differ from ``Dh`` (latent
+    attention); ``Hkv`` is ``H`` or a divisor of it, and query head ``j``
+    then reads key/value head ``j // (H / Hkv)``.  ``mask`` is ``causal``
+    (the name the function keeps), ``block_diffusion`` with its ``block``
+    length, over ``T = 2 x`` the clean length, or ``sliding_window`` with
+    its ``window`` >= 1 (``block_diffusion_allowed``, ``sliding_window_
+    allowed``); a window of ``T`` or more is, and is counted as, causal.
 
-    One algorithm, two lowerings.  Inputs the flash-attention kernel
-    takes (``_kernel_takes``) run it where the program is LOWERED for a
+    One algorithm, two lowerings.  Inputs the flash-attention kernels
+    take (``_kernel_takes``) run one where the program is LOWERED for a
     TPU and the plain blocks on any other platform; every other input
     runs the plain blocks everywhere.  Each trace records which, as the
     counter ``attn:lowering``: ``kernel`` 1 means this op's TPU lowering
-    is the kernel (the lowered text of a CPU program holds the plain
-    blocks all the same), ``plain`` 1 the plain blocks on every platform;
-    the track names dtype and shape, ``/kv<Hkv>`` where the keys have
-    fewer heads and ``/<mask><size>`` where the mask is not the causal
-    one; ``mask_form`` is ``kernel_mask``'s, ``none`` on the plain path."""
+    is a kernel pair (a CPU program's text holds the plain blocks all the
+    same) and ``pair`` which (``kernel_pair``: ``rows``, the repo's own,
+    or ``library``); ``plain`` 1 the plain blocks everywhere (``pair``
+    ``none``).  The track names dtype, shape, ``/kv<Hkv>`` under fewer key
+    heads and any other ``/<mask><size>``; ``mask_form``: ``kernel_mask``."""
     t, h, hkv = q.shape[1], q.shape[2], k.shape[2]
     if mask not in MASKS:
         raise MXNetError("attention mask %r is none of %s" % (mask, MASKS))
@@ -259,15 +258,16 @@ def causal_attention(q, k, v, scale: float, mask: str = "causal",
                          "of %d: the rows are two copies of whole blocks"
                          % (t, block))
     kernel = _kernel_takes(q, k, v)
+    pair = kernel_pair(q, k, v) if kernel else "none"
     trace.counter("attn:lowering", cat="ops", track="%s%s%s%s%s" % (
         q.dtype.name, list(q.shape),
         "" if v.shape[3] == q.shape[3] else "x%d" % v.shape[3],
         "" if hkv == h else "/kv%d" % hkv,
         "" if kind[0] == "causal" else "/%s%d" % kind),
-        kernel=int(kernel), plain=int(not kernel),
+        kernel=int(kernel), plain=int(not kernel), pair=pair,
         mask_form=kernel_mask(kind, t)[0] if kernel else "none")
-    if not kernel:
-        return _plain_attention(q, k, v, scale, kind)
+    if pair != "library":
+        return _plain_or_rows(q, k, v, scale, kind, pair)
     return _kernel_on_tpu(
         lambda q, k, v: _flash_attention(q, k, v, scale, kind),
         lambda q, k, v: _plain_attention(q, k, v, scale, kind), False,
@@ -544,13 +544,13 @@ class CausalSelfAttentionOp(OpDef):
 
     Which lowering runs is ``causal_attention``'s choice, from the
     platform the program is lowered for and the inputs: bfloat16 with
-    ``Dv % 64 == 0``, ``Dh`` 64 or >= 128 (both padded with zeros to whole
-    128 lanes inside the kernel's wrapper) and ``T`` a multiple of 128 and
-    of its tile (``min(1024, T)``, itself whole slices of 512, at every
-    head size: 256 / 256 fits the kernels' VMEM at that tile), lowered
-    for a TPU, is JAX's Pallas splash-attention kernel; float32, any
-    other shape and every other platform are the plain query blocks.
-    The counter ``attn:lowering`` records it per bind."""
+    ``Dv % 64 == 0``, ``Dh`` 64 or >= 128 and ``T`` a multiple of 128 and
+    of ``min(1024, T)``, lowered for a TPU, is a Pallas kernel pair: the
+    repo's own in row form where grouped query heads of 128 lanes share
+    key/value heads (``kernel_pair``), else JAX's splash attention (heads
+    padded to whole 128 lanes in its wrapper; 256 / 256 fits its VMEM at
+    a tile of 1024); float32, any other shape and every other platform
+    are the plain query blocks.  ``attn:lowering`` records it per bind."""
     params = [Param("scale", float, default=0.0),
               Param("layer", int, default=-1),
               Param("mask", str, default="causal", enum=MASKS),
@@ -643,6 +643,102 @@ def kernel_mask(kind, t: int):
             and not (half & (half - 1) or size & (size - 1)):
         return ("codes",) + block_diffusion_codes(half, size)
     return "function", np.arange(t, dtype=np.int32), _mask_function(kind, t)
+
+
+def kernel_pair(q, k, v) -> str:
+    """Which kernel pair the TPU lowering of inputs that ``_kernel_takes``
+    is, from what the inputs show: ``rows``, this repo's own
+    (``ops/selected_attention.py`` ``computed_attention_fwd`` / ``_bwd``:
+    the operands as the projections leave them, a key/value head's group
+    of query heads a grid step under ONE evaluation of the mask), where
+    the query heads share key/value heads (a group of 2 or more: equal
+    heads give a step's contraction over the group nothing), q, k and v
+    are all of 128 lanes (a 64-lane head is no whole lane block of a row,
+    a 256-lane one doubles a step's VMEM) and the backward kernel keeps a
+    key/value head's ``dk`` and ``dv`` in VMEM (``backward_tiles``);
+    ``library``, JAX's splash attention, everywhere else."""
+    from . import selected_attention
+    t, h, dh = q.shape[1:]
+    group = h // k.shape[2]
+    rows = (group >= 2 and dh == v.shape[3] == selected_attention.LANES
+            and selected_attention.backward_tiles(t, group, dh) is not None)
+    return "rows" if rows else "library"
+
+
+def _rows_mask(kind, t: int):
+    """``kernel_mask``'s ``(rows, allowed)`` for the repo's own pair: the
+    causal mask, the library's own object there, is the row ids under
+    its one compare here."""
+    _, rows, allowed = kernel_mask(kind, t)
+    if rows is None:
+        return np.arange(t, dtype=np.int32), _mask_function(kind, t)
+    return rows, allowed
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _rows_attention(q, k, v, scale: float, kind=("causal", 0),
+                    interpret: bool = False):
+    """The TPU lowering of ``kernel_pair`` ``rows``: this repo's kernel
+    pair under the mask ``kind`` evaluated in VMEM (``ops/
+    selected_attention.py``), forward and backward on one plan.  q, the
+    output, its cotangent and ``dq`` are ``(B, T, H * Dh)`` rows and k, v,
+    ``dk``, ``dv`` ``(B, T, Hkv * D)`` rows, what the projections give and
+    take but for a reshape; the batch is a grid axis; only key tiles that
+    hold an allowed pair are visited (a host-made table a ``(kind, T)``);
+    the forward's float32 log-sum-exp ``(B, H, T)`` is kept for the
+    backward pass, which forms ``di`` itself and sums ``dq`` in VMEM.  The
+    queries are scaled first, as for the library's pair."""
+    return _rows_fwd(q, k, v, scale, kind, interpret)[0]
+
+
+def _rows_fwd(q, k, v, scale, kind, interpret):
+    q = q * scale
+    out, lse = _rows_forward(q, k, v, kind=kind, interpret=interpret)
+    return out, (q, k, v, out, lse)
+
+
+def _rows_bwd(scale, kind, interpret, kept, g):
+    dq, dk, dv = _rows_backward(*kept, g, kind=kind, interpret=interpret)
+    return dq * scale, dk, dv
+
+
+_rows_attention.defvjp(_rows_fwd, _rows_bwd)
+
+
+def _plain_or_rows(q, k, v, scale, kind, pair):
+    """``causal_attention`` where ``pair`` is ``none`` (the plain blocks
+    on every platform) or ``rows`` (this repo's kernels where the program
+    is lowered for a TPU, the plain blocks elsewhere)."""
+    if pair == "none":
+        return _plain_attention(q, k, v, scale, kind)
+    return _kernel_on_tpu(
+        lambda q, k, v: _rows_attention(q, k, v, scale, kind),
+        lambda q, k, v: _plain_attention(q, k, v, scale, kind), False,
+        q, k, v)
+
+
+# lint: allow(raw-jit) — never dispatched on their own: jits inside the
+# step program, so that every layer and module of a process shares one
+# traced kernel and one visit plan a (shape, kind), as
+# ``selected_attention._selected_attention_bwd``
+@functools.partial(jax.jit, static_argnames=("kind", "interpret"))
+def _rows_forward(q, k, v, *, kind, interpret):
+    from .selected_attention import computed_attention_fwd
+    # bfloat16 products are exact at any precision, and Mosaic refuses
+    # bfloat16 operands under a "highest" default, as ``_flash_fwd`` says
+    with jax.default_matmul_precision("default"):
+        return computed_attention_fwd(
+            q, k, v, *_rows_mask(kind, q.shape[1]), interpret=interpret)
+
+
+# lint: allow(raw-jit) — as ``_rows_forward``
+@functools.partial(jax.jit, static_argnames=("kind", "interpret"))
+def _rows_backward(q, k, v, out, lse, d_out, *, kind, interpret):
+    from .selected_attention import computed_attention_bwd
+    with jax.default_matmul_precision("default"):
+        return computed_attention_bwd(
+            q, k, v, *_rows_mask(kind, q.shape[1]), out, lse, d_out,
+            interpret=interpret)
 
 
 def _softmax_ce(logits, label, ignore=None):

@@ -309,7 +309,7 @@ def test_the_attention_at_the_cells_shape_lowers_to_the_kernel_on_a_tpu():
     assert text.count("tpu_custom_call") == 2
     assert "splash_mha_fwd" in text and "splash_mha_dkv" in text
     assert "stablehlo.pad" not in text
-    assert events[0]["args"] == {"kernel": 1, "plain": 0,
+    assert events[0]["args"] == {"kernel": 1, "plain": 0, "pair": "rows",
                                  "mask_form": "function"}
     assert events[0]["id"] == \
         "bfloat16[1, 4096, 32, 128]/kv4/sliding_window2048"
@@ -688,5 +688,5 @@ def test_device_scopes_and_the_lowering_counter_name_both_kinds():
     assert [e["id"] for e in events] == \
         ["float32[2, 16, 4, 8]/kv2/sliding_window6"] * 3 \
         + ["float32[2, 16, 4, 8]/kv2"]
-    assert all(e["args"] == {"kernel": 0, "plain": 1, "mask_form": "none"}
-               for e in events)
+    assert all(e["args"] == {"kernel": 0, "plain": 1, "pair": "none",
+                            "mask_form": "none"} for e in events)

@@ -224,7 +224,7 @@ def test_attention_at_256_lanes_lowers_to_the_kernel_on_a_tpu(dv):
         mx.trace.set_enabled(was)
     assert text.count("tpu_custom_call") == 2
     assert "stablehlo.pad" not in text
-    assert events[0]["args"] == {"kernel": 1, "plain": 0,
+    assert events[0]["args"] == {"kernel": 1, "plain": 0, "pair": "library",
                                  "mask_form": "library"}
     assert events[0]["id"] == "bfloat16[1, 1024, 4, 256]" + (
         "" if dv == 256 else "x128")

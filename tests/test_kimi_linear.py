@@ -677,6 +677,7 @@ def test_latent_attention_lowering_on_a_tpu(dqk, kernel):
     assert text.count("tpu_custom_call") == (2 if kernel else 0)
     assert events[0]["args"] == {"kernel": int(kernel),
                                  "plain": int(not kernel),
+                                 "pair": "library" if kernel else "none",
                                  "mask_form": "library" if kernel else "none"}
     assert events[0]["id"] == "bfloat16%s%s" % (
         [1, 256, 2, dqk], "" if dqk == 128 else "x128")

@@ -381,8 +381,11 @@ def test_which_heads_lower_to_the_kernel_on_a_tpu(shape, hkv, dv, takes,
         events = mx.trace.counter_events(["attn:lowering"], since_ns=mark)
     finally:
         mx.trace.set_enabled(was)
+    # the repo's own pair takes grouped 128-lane heads; 64-lane heads and
+    # latent attention's widths are the library's
+    pair = "none" if not takes else "rows" if dh == dv == 128 else "library"
     assert events[0]["args"] == {
-        "kernel": int(takes), "plain": int(not takes),
+        "kernel": int(takes), "plain": int(not takes), "pair": pair,
         "mask_form": "library" if takes else "none"}
     assert events[0]["id"] == "bfloat16%s%s%s" % (
         list(shape), "" if dv == dh else "x%d" % dv,

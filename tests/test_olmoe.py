@@ -148,6 +148,7 @@ def test_attention_lowering_is_chosen_from_platform_and_inputs(
     assert len(events) == 1, "once a trace of the op"
     assert events[0]["args"] == {
         "kernel": int(kernel_inputs), "plain": int(not kernel_inputs),
+        "pair": "library" if kernel_inputs else "none",
         "mask_form": "library" if kernel_inputs else "none"}
     assert events[0]["id"] == "%s%s" % (np.dtype(dtype).name, list(shape))
 

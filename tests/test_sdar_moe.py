@@ -273,7 +273,8 @@ def test_the_lowering_counter_names_the_masks_form(mask, size, t, dtype,
         mx.trace.set_enabled(was)
     kernel = int(form != "none")
     assert [e["args"] for e in events] == [
-        {"kernel": kernel, "plain": 1 - kernel, "mask_form": form}]
+        {"kernel": kernel, "plain": 1 - kernel,
+         "pair": "rows" if kernel else "none", "mask_form": form}]
     assert events[0]["id"] == "%s[1, %d, 4, 128]/kv2%s" % (
         dtype, t, "" if mask == "causal" else "/%s%d" % (mask, size))
 
@@ -466,7 +467,7 @@ def test_the_attention_at_the_cells_shape_lowers_to_the_kernel_on_a_tpu():
     assert text.count("tpu_custom_call") == 2
     assert "splash_mha_fwd" in text and "splash_mha_dkv" in text
     assert "stablehlo.pad" not in text
-    assert events[0]["args"] == {"kernel": 1, "plain": 0,
+    assert events[0]["args"] == {"kernel": 1, "plain": 0, "pair": "rows",
                                  "mask_form": "codes"}
     assert events[0]["id"] == \
         "bfloat16[1, 8192, 32, 128]/kv4/block_diffusion4"

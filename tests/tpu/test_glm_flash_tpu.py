@@ -202,7 +202,7 @@ def test_published_width_step_matches_reference():
     # six attention calls, every one the kernel at 256 against 256
     assert len(bf16["attn_lowering"]) == 6, bf16["attn_lowering"]
     for track, args in bf16["attn_lowering"]:
-        assert args == {"kernel": 1, "plain": 0,
+        assert args == {"kernel": 1, "plain": 0, "pair": "library",
                         "mask_form": "library"}, (track, args)
         assert track == "bfloat16[1, 4096, 20, 256]", track
     # float8 weights are refused by at least one limit
@@ -244,7 +244,7 @@ def test_attention_kernel_matches_plain_blocks_at_256_against_256():
     assert "tpu_custom_call" in text
     assert "tpu_custom_call" not in plain.lower(q, k, v).compile().as_text()
     event = mx.trace.counter_events(["attn:lowering"], since_ns=mark)[-1]
-    assert event["args"] == {"kernel": 1, "plain": 0,
+    assert event["args"] == {"kernel": 1, "plain": 0, "pair": "library",
                              "mask_form": "library"}
     assert event["id"] == "bfloat16[1, 4096, 20, 256]"
     assert tf_ops._kernel_tiles(4096) == (1024, 512)

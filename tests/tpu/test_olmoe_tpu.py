@@ -272,7 +272,7 @@ def test_attention_kernel_matches_plain_blocks_at_the_cell_shape():
     assert "tpu_custom_call" in kernel.lower(q, k, v).compile().as_text()
     assert "tpu_custom_call" not in plain.lower(q, k, v).compile().as_text()
     event = mx.trace.counter_events(["attn:lowering"], since_ns=mark)[-1]
-    assert event["args"] == {"kernel": 1, "plain": 0,
+    assert event["args"] == {"kernel": 1, "plain": 0, "pair": "library",
                              "mask_form": "library"}
     assert event["id"] == "bfloat16[4, 4096, 16, 128]"
     got = [np.asarray(x, np.float32) for x in kernel(q, k, v)]
@@ -407,6 +407,6 @@ def test_the_cell_s_bound_module_runs_the_kernel():
     events = mx.trace.counter_events(["attn:lowering"], since_ns=mark)
     assert events, "the step traced no attention op"
     for e in events:
-        assert e["args"] == {"kernel": 1, "plain": 0,
+        assert e["args"] == {"kernel": 1, "plain": 0, "pair": "library",
                              "mask_form": "library"}, e
         assert e["id"] == "bfloat16[4, 4096, 16, 128]", e

@@ -244,7 +244,8 @@ def test_published_width_step_matches_reference():
                    and (a["key_heads"], a["value_heads"]) == (16, 32)
                    for _, a in bf16["gdn:lowering"])
         assert bf16["attn:lowering"] == [[ATTN_TRACK, {
-            "kernel": 1, "plain": 0, "mask_form": "library"}]]
+            "kernel": 1, "plain": 0, "pair": "library",
+            "mask_form": "library"}]]
         # the three mixers' convolutions read the projection where it lies
         assert bf16["conv:lowering"] == [[CONV_TRACK, {
             "kernel": 1, "plain": 0}]] * 3
@@ -454,7 +455,7 @@ def test_attention_kernel_matches_plain_blocks_at_256_over_two():
     assert "tpu_custom_call" in text and "splash_mha" in text
     assert "tpu_custom_call" not in plain.lower(q, k, v).compile().as_text()
     event = mx.trace.counter_events(["attn:lowering"], since_ns=mark)[-1]
-    assert event["args"] == {"kernel": 1, "plain": 0,
+    assert event["args"] == {"kernel": 1, "plain": 0, "pair": "library",
                              "mask_form": "library"}
     assert event["id"] == ATTN_TRACK
     got = [np.asarray(x, np.float32) for x in kernel(q, k, v)]

@@ -201,7 +201,8 @@ def test_published_width_step_matches_reference():
     # mask over 4 key/value heads
     assert len(bf16["attn_lowering"]) == kw["num_layers"]
     for track, args in bf16["attn_lowering"]:
-        assert args == {"kernel": 1, "plain": 0, "mask_form": "codes"}, \
+        assert args == {"kernel": 1, "plain": 0, "pair": "rows",
+                        "mask_form": "codes"}, \
             (track, args)
         assert track == "bfloat16[1, 8192, 32, 128]/kv4/block_diffusion4"
     # float8 weights are refused by at least one limit
@@ -244,9 +245,14 @@ def test_attention_kernel_matches_plain_blocks_under_the_block_mask():
     plain = both_passes(tf_ops._plain_attention, kind)
     text = kernel.lower(q, k, v).compile().as_text()
     assert "tpu_custom_call" in text and "splash_mha" in text
+    # the repo's own pair in row form (PR 64), none of the library's
+    assert "splash_mha_fwd_computed" in text
+    assert "splash_mha_dkv_computed" in text
+    assert "no_residuals" not in text and "fwd_residuals" not in text
     assert "tpu_custom_call" not in plain.lower(q, k, v).compile().as_text()
     event = mx.trace.counter_events(["attn:lowering"], since_ns=mark)[-1]
-    assert event["args"] == {"kernel": 1, "plain": 0, "mask_form": "codes"}
+    assert event["args"] == {"kernel": 1, "plain": 0, "pair": "rows",
+                             "mask_form": "codes"}
     assert event["id"] == "bfloat16[1, 8192, 32, 128]/kv4/block_diffusion4"
     got = [np.asarray(x, np.float32) for x in kernel(q, k, v)]
     want = [np.asarray(x, np.float32) for x in plain(q, k, v)]
@@ -264,14 +270,19 @@ def test_attention_kernel_matches_plain_blocks_under_the_block_mask():
         jax.block_until_ready(out)
         return (time.perf_counter() - t0) / 5 * 1e3
 
-    # one layer's kernel pair in isolation, with the op's transposes and
-    # the partial dq planes' sum: the figure to hold beside the traced
-    # cell's splash_mha* operations (PERF.md section 5)
+    # one layer's kernel pair in isolation, from head-major-free inputs
+    # as the test makes them (XLA lays them out for the kernels here; in
+    # the step the projections give that layout), and the library's pair
+    # with its transposes and partial dq planes beside it: the figures to
+    # hold beside the traced cell's splash_mha* operations (PERF.md
+    # section 5)
     forward = jax.jit(lambda q, k, v: tf_ops.causal_attention(
         q, k, v, scale, *kind))
     report["mask_form"] = event["args"]["mask_form"]
     report["ms_a_layer"] = {"kernel_forward": ms(forward, q, k, v),
                             "kernel_forward_backward": ms(kernel, q, k, v),
+                            "library_forward_backward": ms(both_passes(
+                                tf_ops._flash_attention, kind), q, k, v),
                             "plain_forward_backward": ms(plain, q, k, v)}
     out_dir = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)

@@ -242,7 +242,7 @@ def test_published_width_step_matches_reference():
         # layer under the causal mask, then three under the window
         assert [t for t, _ in bf16["attn_lowering"]] == TRACKS
         assert all(a["kernel"] == 1 and a["plain"] == 0
-                   for _, a in bf16["attn_lowering"])
+                   and a["pair"] == "rows" for _, a in bf16["attn_lowering"])
         # the lanes counted are the held rows', not the bound's 24 576
         for rows, (zeros, lanes) in zip(bf16["held_rows"],
                                         bf16["act_zeros"]):
@@ -305,6 +305,10 @@ def test_attention_kernel_matches_plain_blocks_under_the_window():
     assert "tpu_custom_call" in text and "splash_mha" in text
     event = mx.trace.counter_events(["attn:lowering"], since_ns=mark)[-1]
     assert event["args"]["kernel"] == 1 and event["id"] == TRACKS[1]
+    # groups of seven 128-lane heads: the repo's own pair (PR 64)
+    assert event["args"]["pair"] == "rows"
+    assert "splash_mha_fwd_computed" in text
+    assert "splash_mha_dkv_computed" in text and "no_residuals" not in text
     got = [np.asarray(x, np.float32) for x in kernel(q, k, v)]
     want = [np.asarray(x, np.float32) for x in plain(q, k, v)]
     report = {"max_err_share": [], "l2_err": []}
@@ -323,7 +327,9 @@ def test_attention_kernel_matches_plain_blocks_under_the_window():
 
     report["ms_a_layer"] = {
         "window_kernel_forward_backward": ms(kernel, q, k, v),
-        "causal_kernel_forward_backward": ms(causal, q, k, v)}
+        "causal_kernel_forward_backward": ms(causal, q, k, v),
+        "window_library_forward_backward": ms(
+            both_passes(tf_ops._flash_attention, kind), q, k, v)}
     out_dir = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, "smallthinker_attn_parity.json"),

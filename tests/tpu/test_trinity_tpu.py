@@ -231,8 +231,10 @@ def test_published_width_step_matches_reference():
         # the window, then the full layer under the causal mask
         assert [t for t, _ in bf16["attn_lowering"]] == TRACKS
         assert [a for _, a in bf16["attn_lowering"]] == \
-            [{"kernel": 1, "plain": 0, "mask_form": "function"}] * 4 \
-            + [{"kernel": 1, "plain": 0, "mask_form": "library"}]
+            [{"kernel": 1, "plain": 0, "pair": "rows",
+              "mask_form": "function"}] * 4 \
+            + [{"kernel": 1, "plain": 0, "pair": "rows",
+                "mask_form": "library"}]
     # float8 weights are refused by at least one limit
     assert fp8["loss_rel_err"] > limits["loss_rtol"] or any(
         fp8["adam_update_rel_err"][n] > limits["update_rtol"][n]
@@ -281,7 +283,7 @@ def test_attention_kernel_matches_plain_blocks_under_the_window():
     assert "tpu_custom_call" in text and "splash_mha" in text
     assert "tpu_custom_call" not in plain.lower(q, k, v).compile().as_text()
     event = mx.trace.counter_events(["attn:lowering"], since_ns=mark)[-1]
-    assert event["args"] == {"kernel": 1, "plain": 0,
+    assert event["args"] == {"kernel": 1, "plain": 0, "pair": "rows",
                              "mask_form": "function"}
     assert event["id"] == TRACKS[0]
     got = [np.asarray(x, np.float32) for x in kernel(q, k, v)]
