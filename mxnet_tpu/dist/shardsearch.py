@@ -191,8 +191,9 @@ class _Trial:
             compute_dtype=get_env("MXNET_COMPUTE_DTYPE") or None,
             global_dp=gdp, mesh=mesh,
             sharding=_to_partition_specs(specs))
-        self.state = self.fused.init_state(module._arg_params,
-                                           module._aux_params)
+        # a trial's state is a copy: the module keeps its arrays
+        self.state = self.fused.init_state(dict(module._arg_params),
+                                           dict(module._aux_params))
         batch = DataBatch(
             data=[zeros(shape) for _, shape in module._data_shapes],
             label=[zeros(shape)

@@ -87,6 +87,11 @@ class BucketingModule(BaseModule):
         return module
 
     def get_params(self):
+        """The current bucket's ``Module.get_params``.  The buckets'
+        modules share ONE pair of dicts, and ``Module``'s rule holds for
+        it once: only the default bucket's module can own a fused state
+        (the others borrow its optimizer), and binding a second bucket
+        ends it, reading the weights back into the dicts first."""
         assert self.binded and self.params_initialized
         return self._curr_module.get_params()
 

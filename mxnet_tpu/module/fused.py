@@ -34,8 +34,9 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..base import MXNetError, get_env
+from ..context import cpu
 from ..executor import _GraphProgram
-from ..ndarray import NDArray
+from ..ndarray import NDArray, _read_to_host
 from ..parallel.mesh import tracing_over
 from .. import trace as _trace
 from ..trace import heads as _heads, scopes as _scopes
@@ -379,41 +380,46 @@ class FusedTrainStep:
 
     def init_state(self, arg_params: Dict[str, NDArray],
                    aux_params: Dict[str, NDArray]):
-        """Build the device-resident train state from host param dicts.
-        Each leaf lands directly in its declared sharding (tensor-
-        parallel params never materialize replicated on the mesh)."""
-        rep = self._replicated()
-
-        def host(v):
-            a = v._get() if isinstance(v, NDArray) else v
-            return np.asarray(a)
-        tree = {
-            "params": {n: host(arg_params[n]) for n in self.train_names},
-            "fixed": {n: host(arg_params[n]) for n in self.fixed_names},
-            "aux": {n: host(aux_params[n]) for n in self.aux_names},
-        }
-        for group in tree.values():
-            for n, a in group.items():
+        """Build the device-resident train state from param dicts.  The
+        state owns storage of its own: each leaf is a copy, made on the
+        device where the source lies there, and lands directly in its
+        declared sharding (tensor-parallel params never materialize
+        replicated on the mesh).  The dicts give their arrays up, each
+        for its shape (a ``jax.ShapeDtypeStruct``) as the state takes its
+        copy, so that no device holds the weights twice (``Module``: one
+        home at a time); a caller that keeps its arrays hands over
+        shallow copies of its dicts (``dist/shardsearch.py``)."""
+        def leaves(source, names):
+            out = {}
+            for n in names:
+                v = source[n]
+                out[n] = a = v._get() if isinstance(v, NDArray) else v
                 self._check_divisible(n, a.shape)
+                source[n] = jax.ShapeDtypeStruct(a.shape, a.dtype)
+            return out
+        tree = {"params": leaves(arg_params, self.train_names),
+                "fixed": leaves(arg_params, self.fixed_names),
+                "aux": leaves(aux_params, self.aux_names)}
         if self._multiprocess():
             # dist init semantics: rank 0's value wins everywhere
             # (reference kvstore_dist init); a global device_put needs
             # identical host values on every process anyway.  ONE pytree
             # collective, not one per tensor.
             from jax.experimental import multihost_utils as mhu
-            tree = mhu.broadcast_one_to_all(tree)
+            tree = mhu.broadcast_one_to_all(
+                jax.tree_util.tree_map(np.asarray, tree))
 
-        def put(a, sh=rep):
-            # device_put may alias the caller's buffer when it already
-            # lives here; the state is donated every step, so it must own
-            # fresh storage or the source NDArrays get deleted under it
-            return jnp.copy(jax.device_put(a, sh))
-        params = {n: put(a, self._param_sharding(n))
-                  for n, a in tree["params"].items()}
-        fixed = {n: put(a, self._param_sharding(n))
-                 for n, a in tree["fixed"].items()}
-        aux = {n: put(a, self._param_sharding(n))
-               for n, a in tree["aux"].items()}
+        def put(group):
+            # device_put may alias the buffer it is given; the state is
+            # donated every step, so it must own fresh storage or an
+            # NDArray that somebody took from get_params() before the
+            # first step gets deleted under them.  A source nobody else
+            # holds goes as soon as its copy is made.
+            return {n: jnp.copy(jax.device_put(group.pop(n),
+                                               self._param_sharding(n)))
+                    for n in list(group)}
+        params, fixed, aux = put(tree["params"]), put(tree["fixed"]), \
+            put(tree["aux"])
         if self.shard_update or self.param_specs:
             # optimizer state lives SHARDED at rest: each replica holds
             # only its slice (the paper's memory saving) and the donated
@@ -440,7 +446,7 @@ class FusedTrainStep:
             opt = {n: self._opt_init(w) for n, w in params.items()}
         # the step counter lives on device and increments in-program: a
         # host-built scalar would cost one transfer per step
-        t = jax.device_put(jnp.zeros((), jnp.int32), rep)
+        t = jax.device_put(jnp.zeros((), jnp.int32), self._replicated())
         return {"params": params, "opt": opt, "aux": aux, "fixed": fixed,
                 "t": t}
 
@@ -922,6 +928,15 @@ class FusedTrainStep:
             stats.add_step(dt)
         return out
 
+    def _gathered(self, x):
+        """This process's whole copy of a leaf that lies in shards."""
+        # lint: allow(raw-jit) — trivial all-gather reshard with live
+        # out_shardings, built on the rare classic-fallback path; never a
+        # steady-state dispatch worth a disk entry
+        gathered = jax.jit(lambda a: a,
+                           out_shardings=self._replicated())(x)
+        return gathered.addressable_data(0)
+
     def gather_update_leaf(self, x):
         """One sharded-at-rest optimizer-state leaf -> replicated (and,
         multi-process, host-materializable).  The classic-updater
@@ -930,15 +945,10 @@ class FusedTrainStep:
         layout it cannot use."""
         if x is None:
             return None
-        # lint: allow(raw-jit) — trivial all-gather reshard with live
-        # out_shardings, built on the rare classic-fallback path; never a
-        # steady-state dispatch worth a disk entry
-        gathered = jax.jit(lambda a: a,
-                           out_shardings=self._replicated())(x)
         # materialize through host: the classic path mixes this with
         # per-device arrays, and a mesh-committed array would poison
         # every eager op it meets with a device mismatch
-        return jnp.asarray(np.asarray(gathered.addressable_data(0)))
+        return jnp.asarray(np.asarray(self._gathered(x)))
 
     def warm_step(self, state, batch, base_key) -> str:
         """Compile (or cache-load) the step program for these avals
@@ -1014,24 +1024,23 @@ class FusedTrainStep:
     # -- host sync -----------------------------------------------------------
     def read_params(self, state, arg_params: Dict[str, NDArray],
                     aux_params: Dict[str, NDArray]):
-        """Pull the live state back into host-side NDArray dicts. Copies:
-        the state buffers are donated to the next step, which would delete
-        the arrays under any NDArray handed out here."""
-        # Materialize through host in BOTH cases (the docstring's
-        # contract): a jnp.copy would stay committed to the fused mesh,
-        # and a mesh-committed weight leaking into the classic per-device
-        # path (kvstore re-seed on fallback, exec-group updates) poisons
-        # every eager op it meets with a device mismatch.  A tensor-
-        # parallel (specced) param is SHARDED at rest — addressable_data(0)
-        # would hand back one shard as if it were the whole weight, so
-        # non-replicated leaves gather first.
+        """Pull the live state back into host-side NDArray dicts: every
+        array handed out has the ``cpu`` context and is the caller's own.
+        It is a copy (the state buffers are donated to the next step,
+        which would delete the arrays under anything that aliased them)
+        and costs one device->host transfer; no device holds it."""
+        host_device = cpu().jax_device()
+
         def host(x):
-            sh = getattr(x, "sharding", None)
-            if sh is not None and not x.is_fully_replicated:
-                if x.is_fully_addressable:
-                    return NDArray(jnp.asarray(np.asarray(x)))
-                return NDArray(self.gather_update_leaf(x))
-            return NDArray(jnp.asarray(np.asarray(x.addressable_data(0))))
+            # a replicated leaf is read from one device; a leaf that lies
+            # in shards (a tensor-parallel weight) is whole only once
+            # assembled: by jax where this process addresses every shard,
+            # by a gather where it does not
+            if not x.is_fully_addressable:
+                x = x.addressable_data(0) if x.is_fully_replicated \
+                    else self._gathered(x)
+            return NDArray(jax.device_put(_read_to_host(x, False)[0],
+                                          host_device))
         for n in self.train_names:
             arg_params[n] = host(state["params"][n])
         for n in self.fixed_names:

@@ -24,7 +24,16 @@ __all__ = ["Module"]
 
 
 class Module(BaseModule):
-    """Module over a Symbol (reference module.py:18)."""
+    """Module over a Symbol (reference module.py:18).
+
+    The weights have one home at a time.  Before the fused train state
+    exists it is ``_arg_params`` / ``_aux_params`` (and the executor
+    group that is filled from them).  While a fused state exists it is
+    that state and nothing else on any device: ``_fused_ensure_state``
+    lets go of the dicts' arrays as the state takes its copies, the
+    dicts keep the shapes under ``_params_dirty``, and whatever
+    ``get_params`` hands out then is a host array (``cpu`` context)
+    that the caller owns."""
 
     def __init__(self, symbol, data_names=("data",), label_names=("softmax_label",),
                  logger=logging, context=None, work_load_list=None,
@@ -134,6 +143,11 @@ class Module(BaseModule):
 
     # -- params --------------------------------------------------------------
     def get_params(self):
+        """``(arg_params, aux_params)`` at their current values.  Once
+        training has begun they are read back from wherever the weights
+        live (the fused state, or the executor group) as ``cpu``-context
+        arrays that no later step writes or deletes; before that they
+        are the arrays ``init_params`` filled."""
         assert self.binded and self.params_initialized
         if self._params_dirty:
             self._sync_params_from_devices()
@@ -145,6 +159,10 @@ class Module(BaseModule):
         if self.params_initialized and not force_init:
             return
         assert self.binded, "call bind before initializing the parameters"
+        if self.params_initialized and self._params_dirty:
+            # the arrays written below are the current weights': a name
+            # this call leaves out keeps what training made of it
+            self._sync_params_from_devices()
 
         if self._arg_params is None:
             param_arrays = [nd_zeros(x[0].shape, dtype=x[0].dtype)
@@ -348,6 +366,10 @@ class Module(BaseModule):
             self.params_initialized = True
             self._arg_params = shared_module._arg_params
             self._aux_params = shared_module._aux_params
+        elif self._fused_state is not None and \
+                not self._fused._multiprocess():
+            # bound again in mid-training: the weights stay where they live
+            self._exec_group.release()
         elif self.params_initialized:
             self._exec_group.set_params(self._arg_params, self._aux_params)
 
@@ -355,6 +377,9 @@ class Module(BaseModule):
             self.borrow_optimizer(shared_module)
 
     def _reset_bind(self):
+        if self.binded and self._params_dirty and self._fused_state is None:
+            # the classic path's weights would go with the executor group
+            self._sync_params_from_devices()
         self.binded = False
         self._exec_group = None
         self._lent_exec_group = False
@@ -720,6 +745,11 @@ class Module(BaseModule):
         self.logger.info("fused train step disabled: %s", reason)
 
     def _fused_ensure_state(self):
+        """Build the fused state where there is none and move the
+        weights into it: the executor group's arrays go before, the
+        dicts' own tensor by tensor as the state takes its copies, so no
+        device ever holds the weights twice.  An array somebody took
+        from ``get_params()`` before lives on in their hands."""
         if self._fused_state is None:
             if self._params_dirty:
                 self._sync_params_from_devices()
@@ -732,8 +762,12 @@ class Module(BaseModule):
                 # (_drop_fused_state).  Multi-process eval runs the
                 # executor group every epoch and keeps them.
                 self._exec_group.release()
+            # what is left in the dicts is the shapes (dist/shardsearch.py
+            # reads them), in the state every reader meets after an
+            # update(): _sync_params_from_devices fills them again
             self._fused_state = self._fused.init_state(self._arg_params,
                                                        self._aux_params)
+            self._params_dirty = True
             self._fused_t = 0
             from .. import random as _random
             key = _random.new_key()

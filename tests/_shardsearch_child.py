@@ -40,7 +40,8 @@ def main():
              label_shapes=[("softmax_label", (8,))])
     mod.init_params()
     mesh = parallel.make_mesh([("dp", 2), ("mp", 2)])
-    shapes = {n: tuple(mod._arg_params[n].shape) for n in mod._param_names}
+    arg_params, _ = mod.get_params()
+    shapes = {n: tuple(arg_params[n].shape) for n in mod._param_names}
     key = fingerprint(mod._symbol, shapes, mesh)
     print("SHARD_PRE_HIT %d" % (1 if store.load_config(key) else 0))
     print("SHARD_KEY %s" % key)

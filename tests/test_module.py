@@ -1,5 +1,7 @@
 """Module + training convergence tests. Modeled on reference
 tests/python/train/test_mlp.py and module unit usage."""
+import os
+
 import numpy as np
 import pytest
 
@@ -102,40 +104,74 @@ def test_feedforward_fit_and_checkpoint(tmp_path):
     assert pred.shape == (400, 4)
 
 
-def test_bucketing_module():
+@pytest.mark.parametrize("keys", [(8, 4, 8, 4, 6), (8, 8, 8, 4, 6, 8),
+                                  (4, 8, 6, 8, 8)],
+                         ids=lambda keys: "-".join(map(str, keys)))
+def test_bucketing_module(keys):
     """Buckets of different sequence lengths share parameters
-    (reference bucketing flow)."""
-    np.random.seed(0)
-    mx.random.seed(0)
-
+    (reference bucketing flow): ONE dict of them, whichever bucket's
+    module trains.  The default bucket's steps before the first switch
+    run on the fused state, which then holds the weights alone; the
+    switch reads them back, and every later read sees every bucket's
+    steps, as on the classic path throughout."""
     def sym_gen(seq_len):
+        # params are seq-len independent (real bucketing's property)
         data = mx.sym.Variable("data")
-        net = mx.sym.FullyConnected(data, num_hidden=8, name="fc_shared")
+        emb = mx.sym.Embedding(data, input_dim=10, output_dim=8, name="emb")
+        net = mx.sym.FullyConnected(mx.sym.sum_axis(emb, axis=1),
+                                    num_hidden=8, name="fc_shared")
         net = mx.sym.FullyConnected(net, num_hidden=2, name="out")
         return mx.sym.SoftmaxOutput(net, name="softmax")
 
-    mod = mx.mod.BucketingModule(sym_gen, default_bucket_key=8,
-                                 context=mx.current_context())
     from mxnet_tpu.io import DataBatch
 
     def batch(key, bs=8):
-        X = np.random.randn(bs, key).astype(np.float32)
-        y = (X.sum(axis=1) > 0).astype(np.float32)
+        X = np.random.randint(0, 10, (bs, key)).astype(np.float32)
+        y = (X.sum(axis=1) > 4.5 * key).astype(np.float32)
         return DataBatch(data=[mx.nd.array(X)], label=[mx.nd.array(y)],
                          bucket_key=key, pad=0,
                          provide_data=[("data", (bs, key))],
                          provide_label=[("softmax_label", (bs,))])
 
-    mod.bind(data_shapes=[("data", (8, 8))],
-             label_shapes=[("softmax_label", (8,))])
-    mod.init_params()
-    mod.init_optimizer(optimizer_params={"learning_rate": 0.1})
-    for key in (8, 4, 8, 4, 6):
-        b = batch(key)
-        mod.forward(b, is_train=True)
-        mod.backward()
-        mod.update()
-    assert set(mod._buckets.keys()) == {8, 4, 6}
+    def run(fused):
+        os.environ["MXNET_FUSED_TRAIN"] = "1" if fused else "0"
+        try:
+            np.random.seed(0)
+            mx.random.seed(0)
+            mod = mx.mod.BucketingModule(sym_gen, default_bucket_key=8,
+                                         context=mx.current_context())
+            mod.bind(data_shapes=[("data", (8, 8))],
+                     label_shapes=[("softmax_label", (8,))])
+            mod.init_params()
+            mod.init_optimizer(optimizer_params={"learning_rate": 0.1,
+                                                 "momentum": 0.9})
+            default = mod._buckets[8]
+            reads = []
+            for key in keys:
+                b = batch(key)
+                mod.forward(b, is_train=True)
+                mod.backward()
+                mod.update()
+                # the state lives as long as the default bucket is alone
+                assert (default._fused_state is not None) == \
+                    (fused and set(mod._buckets) == {8})
+                for m in mod._buckets.values():
+                    assert m._arg_params is default._arg_params
+                arg, _ = mod.get_params()
+                assert all(v.context == mx.cpu(0) for v in arg.values())
+                reads.append({k: v.asnumpy() for k, v in arg.items()})
+            assert set(mod._buckets.keys()) == set(keys) | {8}
+            return reads
+        finally:
+            os.environ.pop("MXNET_FUSED_TRAIN", None)
+
+    fused, classic = run(True), run(False)
+    for got, want in zip(fused, classic):
+        for name in want:
+            assert np.abs(got[name] - want[name]).max() < 1e-5, name
+    # every step moved the shared weights
+    for a, b in zip(fused, fused[1:]):
+        assert np.abs(a["fc_shared_bias"] - b["fc_shared_bias"]).max() > 0
 
 
 def test_monitor_in_module():
