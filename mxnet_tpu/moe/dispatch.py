@@ -122,11 +122,11 @@ def held_rows_bound(rows: int, num_experts: int, experts_held: int) -> int:
     experts_held / num_experts`` rows under a balanced router, and
     ``R`` is ``HELD_ROWS_SLACK * B`` rounded up to whole row tiles of
     the grouped-matmul kernels.  Where that saves fewer than
-    ``BOUND_WORTH_ROWS`` of the ``rows`` it is ``rows``: no bound.  One
-    rule from what the code sees: the op (``_moe_share_ffn``) sizes its
-    passes by it, and ``FusedTrainStep.note_outputs`` reports it as
-    ``bound`` of the ``moe:load`` sample.  A rank that holds every expert
-    has ``rows``."""
+    ``BOUND_WORTH_ROWS`` of the ``rows`` it is ``rows``: no bound, and the
+    op's one window is every row, with no conditional.  One rule from what
+    the code sees: the op (``_moe_share_ffn``) sizes its passes by it, and
+    ``FusedTrainStep.note_outputs`` reports it as ``bound`` of the
+    ``moe:load`` sample.  A rank that holds every expert has ``rows``."""
     rows = int(rows)
     if not experts_held or experts_held >= num_experts:
         return rows

@@ -412,14 +412,13 @@ class MoEShareFFNOp(OpDef):
     step writes no ``T*k``-sized zeros for it.  Both passes of the first
     ``R`` rows run outside any conditional (XLA:TPU stops its fusions at
     one, and its code for a conditional with kernels in both branches is
-    ten times either branch's: PERF.md, PR 40).  A program over more
-    than one device, and a geometry whose ``R`` is ``T*k`` (the bound
-    would save too few rows to be worth a conditional), run the three
-    nodes' statements over all rows with no ``cond``.  Nothing ``T*k``-
-    sized is left in a window where ``moe.dispatch.held_sum``'s kernel
-    runs (the combine's forward, the row gradient's backward).  A
-    window's parts and the bounded node are jits of this module
-    (``_WINDOW_PARTS``, ``_share_bounded``): traced once a process.
+    ten times either branch's: PERF.md, PR 40).  Where ``R`` is ``T*k``
+    (a bound that would save too few rows to be worth a conditional) the
+    node is the one window ``(0, T*k)`` with no ``cond``; a program over
+    more than one device runs the three nodes' statements.  A window
+    leaves nothing ``T*k``-sized where ``moe.dispatch.held_sum``'s kernel
+    runs (the combine's forward, the row gradient's backward); its parts
+    and the bounded node are jits of this module, traced once a process.
 
     ``act_zeros`` adds a second output ``(2,)`` float32, no gradient:
     ``(zeros, lanes)`` of the activated lanes (``act(x Wg)``) of the rows
@@ -468,11 +467,12 @@ class MoEShareFFNOp(OpDef):
         from ..moe.dispatch import held_rows_bound
         from ..parallel.mesh import traced_devices
         from .transformer import scope_prefix
-        slot, counts = inputs[2], inputs[4]
-        every = slot.shape[0] * slot.shape[1]
-        bound = held_rows_bound(every, counts.shape[0], p.experts_held)
-        if bound == every or traced_devices() > 1:
+        every = inputs[2].size                  # slot's (T, k) choices
+        bound = held_rows_bound(every, inputs[4].shape[0], p.experts_held)
+        if traced_devices() > 1:
             out = _share_window(p)(*inputs)
+        elif bound == every:
+            out = _share_window(p, (0, every))(*inputs)
         else:
             out = _share_bounded(_static(p.items()), scope_prefix(), bound,
                                  *inputs)

@@ -51,9 +51,11 @@ BLOCKS = ["l1_moe_dispatch", "l2_moe_dispatch", "l3_moe_dispatch"]
 F32, BF16 = jnp.float32, jnp.bfloat16
 # sha256 of a tiny SDAR step's lowered text at the commit before the op
 # took a third mask (96d8256): under ``block_diffusion`` the mask's choice
-# and the plain blocks lower to what they did
+# and the plain blocks lower to what they did.  Taken again at PR 66 (at
+# whose parent it read what it did): the tiny rank's share has no row bound
+# and lowers as the one window ``(0, T*k)`` since
 SDAR_STEP_TEXT = \
-    "dcf93a5d01c154f4357e214817c0bc7e4e678da426ff73c34bec6e04712dfbc4"
+    "b51fa88041ba63d17a41a5345691f1cd7147054fbc76e26138d1c6c829bd2086"
 
 
 def _rel(got, want):

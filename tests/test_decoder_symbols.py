@@ -353,20 +353,23 @@ STEPS = {
                    dict(data=(2, 24), softmax_label=(2, 24))),
 }
 
-# sha256 of each step's lowered text at 945e6c8
+# sha256 of each step's lowered text at 945e6c8, taken again at PR 66 for
+# what that PR meant to move and nothing else (at its parent the five read
+# what they did): a rank's share with no row bound, as every tiny one is,
+# lowers as the one window ``(0, T*k)`` (``_moe_share_ffn``)
 STEP_WAS = {
     "kimi":
-        "78f89a019fa4f63160937f02bb44e0941124fad984ee247f58275f12920f1587",
+        "5b4a44980d9a739a66b20d8ebb79e2f4bc1a3e0325a6aedd3fe57786af3184cb",
     "glm":
-        "3a00c3fc2d2fd85cce626d1955a2174d85b0f52940d1f41a8edac6b24be37a9f",
+        "292685084b552e7e7ed132e853a86133ee00d17abc2c8962486f503997fd3cae",
     "afmoe":
-        "56a01f25a20c009757a2834695e48964ac2b23da9fcc48d26a5cf9c814f7356e",
+        "76ff959066a5b7e3909615e0613827be95b09e7afaa1c6227c7527dd644113c4",
     # taken at the commit that added the builder (ISSUE 47)
     "smallthinker":
-        "b1a83ee5679c16bad171411fc23ea7d6a0df99b255614ddcd14ccc724757f01c",
+        "95b031f02386400a58b5e0493e1d76a80030c0e9412c1cf011c168736e2f10dd",
     # taken at the commit that added the builder (ISSUE 50)
     "qwen3-next":
-        "933120a5a69823361198a22b5351ff73ff4276adbc2aa0a56e10def7701e9033",
+        "e79d49911605d777c1239610d6dbee01e9d33b23f649361f14499065ba5cb2c6",
 }
 
 

@@ -39,7 +39,8 @@ def _plan(held_rows, seed, among=ROWS):
     rng = np.random.RandomState(seed)
     mine = rng.permutation(among)[:held_rows]
     expert = rng.randint(0, HELD, held_rows)
-    expert[:2] = (0, HELD - 1)                  # the third may be empty
+    # the third may be empty
+    expert[:2] = (0, HELD - 1)[:held_rows]
     expert[expert == 2] = 1
     mine = mine[np.lexsort((mine, expert))]
     order = np.concatenate([mine, np.setdiff1d(np.arange(ROWS), mine)]
@@ -76,12 +77,19 @@ def any_dtype(monkeypatch):
 
 # held rows, window (lo, n): under the bound with the second token tile
 # empty; the last visited row tile half sentinel; exactly full; the rows
-# behind the bound of an overflowing step; all rows of a whole plan
+# behind the bound of an overflowing step; all rows of a whole plan; and
+# the window of a share with no bound, every row of the plan (``lo = 0, n =
+# T*k``), of which none, a few per cent, a quarter (the balanced share of
+# such a rank) and all are held
 WINDOWS = [("under_one_token_tile_empty", 400, 0, BOUND),
            ("half_a_row_tile_sentinel", 640, 0, BOUND),
            ("exactly_full", BOUND, 0, BOUND),
            ("behind_the_bound", 1600, BOUND, ROWS - BOUND),
-           ("nothing_held_in_the_window", 700, BOUND, ROWS - BOUND)]
+           ("nothing_held_in_the_window", 700, BOUND, ROWS - BOUND),
+           ("every_row_none_held", 0, 0, ROWS),
+           ("every_row_a_few_per_cent_held", 132, 0, ROWS),
+           ("every_row_a_quarter_held", ROWS // 4, 0, ROWS),
+           ("every_row_all_held", ROWS, 0, ROWS)]
 
 
 @pytest.mark.parametrize("weighted", [True, False],
@@ -136,9 +144,9 @@ class _Train:
     is_train = True
 
 
-def _ops(dtype):
+def _ops(dtype, held=HELD):
     get = mx.ops.get_op
-    share = dict(experts_held=HELD, first_expert=FIRST)
+    share = dict(experts_held=held, first_expert=FIRST)
     ffn = dict(num_hidden=H, output_dim=D, act_type="silu", no_bias=True,
                gated=True, layer=1, **share)
     dispatch = get("_moe_dispatch")
@@ -155,17 +163,17 @@ def _run(ops, which, *inputs):
     return out[0] if isinstance(out, tuple) else out
 
 
-def _inputs(held_rows, dtype):
+def _inputs(held_rows, dtype, held=HELD):
     """Logits under which exactly ``held_rows`` of the ``T*k`` choices
-    fall on the rank's four experts: the first ``held_rows / 4`` tokens
-    choose all four, the others none."""
+    fall on the rank's ``held`` experts: the first ``held_rows / k``
+    tokens choose among them alone, the others none of them."""
     rng = np.random.RandomState(held_rows)
     logits = rng.randn(T, E).astype(np.float32)
-    logits[:, FIRST:FIRST + HELD] *= 0.1
-    logits[:, FIRST:FIRST + HELD] -= 12.0
-    logits[:held_rows // HELD, FIRST:FIRST + HELD] += 24.0
+    logits[:, FIRST:FIRST + held] *= 0.1
+    logits[:, FIRST:FIRST + held] -= 12.0
+    logits[:held_rows // K, FIRST:FIRST + held] += 24.0
     x = rng.randn(T, D).astype(np.float32)
-    ws = [(rng.randn(HELD, *s) / 12).astype(np.float32)
+    ws = [(rng.randn(held, *s) / 12).astype(np.float32)
           for s in ((D, H), (D, H), (H, D))]
     ct = rng.randn(T, D).astype(np.float32)
     return [jnp.asarray(x, dtype), jnp.asarray(logits)] \
@@ -199,22 +207,30 @@ def _tsum_traces(since):
             if e["args"]["tsum"]]
 
 
-CASES = [("under", 400), ("exactly_full", BOUND), ("overflow", 1600)]
+# four held experts of 32: a bound of 1024 rows, the held rows under, at
+# and over it; eight: four balanced shares are all 2048 rows, no bound, and
+# the node's one window is every row
+CASES = [("under", 400, HELD), ("exactly_full", BOUND, HELD),
+         ("overflow", 1600, HELD),
+         ("no_bound_a_few_per_cent", 128, 2 * HELD),
+         ("no_bound_a_quarter", ROWS // 4, 2 * HELD)]
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bfloat16", "float32"])
-@pytest.mark.parametrize("case,held_rows", CASES, ids=[c for c, _ in CASES])
+@pytest.mark.parametrize("case,held_rows,held", CASES,
+                         ids=[c[0] for c in CASES])
 def test_the_share_on_the_sorted_side_is_the_share_through_slot(
-        case, held_rows, dtype, monkeypatch, any_dtype):
+        case, held_rows, held, dtype, monkeypatch, any_dtype):
     """``_moe_share_ffn`` with ``held_sum``'s kernel form against the same
     node with the gathers: the forward, the gradients of the data, of the
     router's logits (through ``weight``) and of the three stacked weights;
     an absent choice's weight gets a gradient of exactly 0 from both."""
     monkeypatch.setattr(layout, "BOUND_WORTH_ROWS", 0)
-    assert held_rows_bound(ROWS, E, HELD) == BOUND
-    ops = _ops(dtype)
-    args, ct = _inputs(held_rows, dtype)
+    bound = held_rows_bound(ROWS, E, held)
+    assert bound == (BOUND if held == HELD else ROWS)
+    ops = _ops(dtype, held)
+    args, ct = _inputs(held_rows, dtype, held)
 
     def plan(x, logits):
         return _run(ops, "dispatch", x, logits)
@@ -254,12 +270,13 @@ def test_the_share_on_the_sorted_side_is_the_share_through_slot(
             tracks = {e["id"] for e in _tsum_traces(since)}
     finally:
         mx.trace.set_enabled(was)
-    # the first window's sum, and the window's behind the bound
+    # the first window's sum, and the window's behind the bound; with no
+    # bound the one window's
     name = jnp.dtype(dtype).name
     assert tracks == {
         "tsum %s[%d, 256] x [%d, %d]" % (name, n, n, D)
-        for n in (BOUND, ROWS - BOUND)}, tracks
-    assert int(np.asarray(counts)[FIRST:FIRST + HELD].sum()) == held_rows
+        for n in (bound, ROWS - bound) if n}, tracks
+    assert int(np.asarray(counts)[FIRST:FIRST + held].sum()) == held_rows
 
     f32 = lambda a: np.asarray(a.astype(jnp.float32))       # noqa: E731
     # float32: the same sums in another order; bfloat16: k rows added in

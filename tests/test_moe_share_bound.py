@@ -2,9 +2,11 @@
 rows (``_moe_share_ffn``, ``moe.dispatch.held_rows_bound``): the one
 node against the three-node share it replaces, forward and every
 gradient, where the held rows lie under the bound, fill it exactly, and
-overflow it (the full-size fallback); the shares of all ranks still sum
-to the uncut layer; the rule's values at the benchmark's shapes; which
-graph ``MoEFeedForward`` builds; the ``moe:load`` sample's ``bound``."""
+overflow it (the full-size fallback), and where the geometry has no
+bound and the node is the window of every row; the shares of all ranks
+still sum to the uncut layer; the rule's values at the benchmark's
+shapes; which graph ``MoEFeedForward`` builds; the ``moe:load`` sample's
+``bound``."""
 import os
 import sys
 import time
@@ -42,9 +44,9 @@ def small_bounds(monkeypatch):
     monkeypatch.setattr(share_rule, "BOUND_WORTH_ROWS", 0)
 
 
-def _ops(score, bias):
+def _ops(score, bias, held=HELD):
     get = mx.ops.get_op
-    share = dict(experts_held=HELD, first_expert=FIRST)
+    share = dict(experts_held=held, first_expert=FIRST)
     ffn = dict(num_hidden=H, output_dim=D, act_type="silu", no_bias=True,
                gated=True, layer=1, **share)
     dispatch = get("_moe_dispatch")
@@ -67,38 +69,31 @@ def _run(ops, which, *inputs, aux=()):
     return out[0] if isinstance(out, tuple) else out
 
 
-def _inputs(held_rows, score, bias):
+def _inputs(held_rows, score, bias, held=HELD):
     """Logits under which exactly ``held_rows`` of the ``T*k`` choices
-    fall on the rank's four experts: the first ``held_rows / 4`` tokens
-    choose all four, the others none."""
+    fall on the rank's ``held`` experts: the first ``held_rows / k``
+    tokens choose among them alone, the others none of them."""
     rng = np.random.RandomState(held_rows)
     logits = rng.randn(T, E).astype(np.float32)
-    logits[:, FIRST:FIRST + HELD] *= 0.1
-    logits[:, FIRST:FIRST + HELD] -= 12.0
-    logits[:held_rows // HELD, FIRST:FIRST + HELD] += 24.0
+    logits[:, FIRST:FIRST + held] *= 0.1
+    logits[:, FIRST:FIRST + held] -= 12.0
+    logits[:held_rows // K, FIRST:FIRST + held] += 24.0
     x = rng.randn(T, D).astype(np.float32)
-    ws = [(rng.randn(HELD, *s) / 6).astype(np.float32)
+    ws = [(rng.randn(held, *s) / 6).astype(np.float32)
           for s in ((D, H), (D, H), (H, D))]
     ct = rng.randn(T, D).astype(np.float32)
     aux = [jnp.asarray(1e-3 * rng.randn(E), jnp.float32)] if bias else []
     return [jnp.asarray(a) for a in [x, logits] + ws], jnp.asarray(ct), aux
 
 
-CASES = [("under", 400), ("exactly_full", BOUND), ("overflow", 1600)]
-
-
-@pytest.mark.parametrize("bias", [False, True], ids=["no_bias", "bias"])
-@pytest.mark.parametrize("score", ["softmax", "sigmoid"])
-@pytest.mark.parametrize("case,held_rows", CASES,
-                         ids=[c for c, _ in CASES])
-def test_the_one_node_is_the_three_node_share(case, held_rows, score, bias,
-                                              small_bounds):
-    """Forward and the gradients of the data, of the router's logits
-    (through ``weight``) and of the three stacked weights; an absent
-    choice's weight gets a gradient of exactly 0 from both."""
-    assert held_rows_bound(ROWS, E, HELD) == BOUND
-    ops = _ops(score, bias)
-    args, ct, aux = _inputs(held_rows, score, bias)
+def _one_against_three(held_rows, score, bias, held):
+    """``_moe_share_ffn`` against ``_moe_expert_ffn`` between the dispatch
+    node and ``_moe_combine`` for a rank of ``held`` experts: forward and
+    the gradients of the data, of the router's logits (through
+    ``weight``) and of the three stacked weights; an absent choice's
+    weight gets a gradient of exactly 0 from both."""
+    ops = _ops(score, bias, held)
+    args, ct, aux = _inputs(held_rows, score, bias, held)
 
     def plan(x, logits):
         return _run(ops, "dispatch", x, logits, aux=aux)
@@ -120,7 +115,7 @@ def test_the_one_node_is_the_three_node_share(case, held_rows, score, bias,
 
     (_, (want, counts, slot)), want_grads = graded(three)(*args)
     (_, (got, _, _)), got_grads = graded(one)(*args)
-    assert int(np.asarray(counts)[FIRST:FIRST + HELD].sum()) == held_rows
+    assert int(np.asarray(counts)[FIRST:FIRST + held].sum()) == held_rows
     assert np.asarray(want).any()
     scale = float(np.abs(np.asarray(want)).max())
     assert np.abs(np.asarray(got) - np.asarray(want)).max() <= 1e-6 * scale
@@ -139,6 +134,39 @@ def test_the_one_node_is_the_three_node_share(case, held_rows, score, bias,
             lambda w: (layer(args[0], w, args[2:], d) * ct).sum()))(d[1]))
         assert not d_weight[absent].any()
         assert d_weight[~absent].all()
+
+
+CASES = [("under", 400), ("exactly_full", BOUND), ("overflow", 1600)]
+
+
+@pytest.mark.parametrize("bias", [False, True], ids=["no_bias", "bias"])
+@pytest.mark.parametrize("score", ["softmax", "sigmoid"])
+@pytest.mark.parametrize("case,held_rows", CASES,
+                         ids=[c for c, _ in CASES])
+def test_the_one_node_is_the_three_node_share(case, held_rows, score, bias,
+                                              small_bounds):
+    assert held_rows_bound(ROWS, E, HELD) == BOUND
+    _one_against_three(held_rows, score, bias, HELD)
+
+
+# the LFM2 cell's geometry scaled down (8 of 32 experts, top-4: four
+# balanced shares are all T*k rows) with the held rows a few per cent of
+# the choices, the balanced quarter, and every one
+NO_BOUND = [("a_few_per_cent", 128), ("a_quarter", ROWS // 4),
+            ("every_row", ROWS)]
+
+
+@pytest.mark.parametrize("score,bias", [("softmax", False),
+                                        ("sigmoid", True)],
+                         ids=["softmax", "sigmoid-bias"])
+@pytest.mark.parametrize("case,held_rows", NO_BOUND,
+                         ids=[c for c, _ in NO_BOUND])
+def test_the_one_node_with_no_bound_is_the_three_node_share(case, held_rows,
+                                                            score, bias):
+    """A quarter held is no bound, whatever a bound would have to save:
+    the node is the window ``(0, T*k)``."""
+    assert held_rows_bound(ROWS, E, 2 * HELD) == ROWS
+    _one_against_three(held_rows, score, bias, 2 * HELD)
 
 
 def _share_block(held, first, scale):
@@ -216,6 +244,8 @@ def test_the_shares_add_up_to_the_uncut_layer_under_the_bound(config, scale,
     ("kimi-linear-48b-a3b-train-4k", 4096, 8, 256, 8, 4096),
     # 8192 rows would be saved of 16 384: not worth a conditional
     ("glm-4.7-flash-train-4k", 4096, 4, 64, 8, 16384),
+    # four balanced shares ARE all 32 768 rows
+    ("lfm2-8b-a1b-train-8k", 8192, 4, 32, 8, 32768),
     ("a_quarter_held_is_all_rows", 4096, 8, 64, 16, 4096 * 8),
     ("rounded_up_to_a_tile", 16384, 2, 128, 3, 3072),
     ("too_few_rows_to_save", 512, 4, 32, 4, 2048),
@@ -223,9 +253,9 @@ def test_the_shares_add_up_to_the_uncut_layer_under_the_bound(config, scale,
 def test_the_bound_at_the_cells_shapes(cell, tokens, k, experts, held, want):
     """``HELD_ROWS_SLACK`` times the balanced share in whole row tiles of
     the grouped-matmul kernels, and all ``T*k`` rows where that is no
-    fewer.  The three
+    fewer.  The four
     cells' numbers are read from their configuration files."""
-    if cell.endswith("-train-4k"):
+    if "-train-" in cell:
         kw = manifest.Manifest().cell(cell).config["model"]["kwargs"]
         rows = 2 * kw["seq_len"] if cell.startswith("sdar") \
             else kw["seq_len"]
@@ -258,24 +288,61 @@ def test_every_expert_held_builds_the_three_nodes():
                 data=(T, D), share_weight=(T, K), share_slot=(T, K))
 
 
-def test_a_program_over_two_devices_runs_no_cond(small_bounds):
-    """The bound is one device's: under a mesh the body runs over all
-    rows, today's statements in today's order."""
-    from mxnet_tpu.parallel import mesh as _mesh
-    ops = _ops("softmax", False)
-    args, ct, aux = _inputs(200, "softmax", False)
-
+def _share_layer(ops):
+    """The dispatch node and the share node over its plan."""
     def layer(x, logits, *ws):
         d = _run(ops, "dispatch", x, logits)
         return _run(ops, "share", x, d[1], d[2], d[7], d[4], *ws)[0]
+    return layer
+
+
+def _two_devices():
+    from mxnet_tpu.parallel import mesh as _mesh
+    return _mesh.tracing_over(_mesh.make_mesh([("ep", 2)],
+                                              jax.devices()[:2]))
+
+
+def test_a_program_over_two_devices_runs_no_cond(small_bounds):
+    """The bound is one device's: under a mesh the body runs over all
+    rows, today's statements in today's order."""
+    layer = _share_layer(_ops("softmax", False))
+    args, ct, aux = _inputs(200, "softmax", False)
 
     def conds(f):
         return str(jax.make_jaxpr(f)(*args)).count(" cond[")
 
     assert conds(lambda *a: layer(*a)) == 1
-    with _mesh.tracing_over(_mesh.make_mesh([("ep", 2)],
-                                            jax.devices()[:2])):
+    with _two_devices():
         assert conds(lambda *a: layer(*a)) == 0
+
+
+def test_a_share_with_no_bound_is_one_window_and_no_cond():
+    """Where the bound is all ``T*k`` rows the one-device program is the
+    window of every row: the module's three jits, ``held_sum`` both ways
+    (``_combine_held``, not the picked rows' ``_combine_sorted``), no
+    conditional and no second pass.  A program over two devices keeps the
+    three nodes' statements."""
+    layer = _share_layer(_ops("softmax", False, 2 * HELD))
+    args, ct, aux = _inputs(ROWS // 4, "softmax", False, 2 * HELD)
+
+    def texts():
+        # new functions each time: jax.make_jaxpr caches by the function
+        return [str(jax.make_jaxpr(f)(*args)) for f in (
+            lambda *a: layer(*a),
+            jax.grad(lambda *a: (layer(*a) * ct).sum(), argnums=(0, 1, 2)))]
+
+    forward, both = texts()
+    assert " cond[" not in forward + both
+    for part in ("_window_rows", "_window_ffn", "_window_sum"):
+        assert "name=%s" % part in forward, part
+    assert "name=_combine_held" in forward
+    assert "name=_combine_sorted" not in forward
+    with _two_devices():
+        forward, both = texts()
+    assert " cond[" not in forward + both
+    assert "name=_window_" not in forward
+    assert "name=_combine_sorted" in forward
+    assert "name=_combine_held" not in forward
 
 
 def test_a_second_program_finds_the_bounded_body_traced(small_bounds,

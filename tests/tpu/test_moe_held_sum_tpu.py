@@ -1,6 +1,7 @@
-"""``moe.dispatch.held_sum`` on the chip at the four cells' shapes whose
-rank's share is bounded: the sorted-side kernel ``token-sum`` compiled by
-Mosaic against the token-side gathers it replaces.
+"""``moe.dispatch.held_sum`` on the chip at the shapes of the cells whose
+rank's share runs it, four bounded windows and the two windows of every row
+(no bound): the sorted-side kernel ``token-sum`` compiled by Mosaic against
+the token-side gathers it replaces.
 
     MXNET_TPU_TESTS=1 python -m pytest tests/tpu/test_moe_held_sum_tpu.py -s -q
 
@@ -32,7 +33,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 SHAPES = [("sdar-30b-a3b-train-4k", 8192, 8, 128, 16, 32768, 2048),
           ("smallthinker-21b-a3b-train-8k", 8192, 6, 64, 8, 24576, 2560),
           ("trinity-mini-train-4k", 4096, 8, 128, 8, 8192, 2048),
-          ("kimi-linear-48b-a3b-train-4k", 4096, 8, 256, 8, 4096, 2304)]
+          ("kimi-linear-48b-a3b-train-4k", 4096, 8, 256, 8, 4096, 2304),
+          # no bound: the window is all T*k rows
+          ("lfm2-8b-a1b-train-8k", 8192, 4, 32, 8, 32768, 2048),
+          ("glm-4.7-flash-train-4k", 4096, 4, 64, 8, 16384, 2048)]
 
 
 def _plan(tokens, k, experts, held_experts, seed):
