@@ -43,11 +43,12 @@ mx.symbol.Cast <- function(...) {
   mx.symbol.internal.create('Cast', list(...))
 }
 
-#' Depthwise causal convolution over time of ``(B, T, C)``: one
+#' Depthwise causal convolution over time of ``(B, T, C)``: a ``kernel``-tap filter a channel
 #' @param kernel kernel parameter (default 4)
 #' @param lanes lanes parameter
 #' @param act_type act_type parameter (one of relu, sigmoid, tanh, softrelu, silu)
 #' @param gated gated parameter
+#' @param no_bias no_bias parameter
 #' @param name string, node name (auto when omitted)
 #' @return MXSymbol
 mx.symbol.CausalConv1D <- function(...) {
@@ -371,6 +372,14 @@ mx.symbol.Reshape <- function(...) {
 #' @return MXSymbol
 mx.symbol.RotaryEmbedding <- function(...) {
   mx.symbol.internal.create('RotaryEmbedding', list(...))
+}
+
+#' The state-space scan of Mamba-2 over ``(B, T, H, P)`` data, ``(B,
+#' @param layer layer parameter (default -1)
+#' @param name string, node name (auto when omitted)
+#' @return MXSymbol
+mx.symbol.SSDScan <- function(...) {
+  mx.symbol.internal.create('SSDScan', list(...))
 }
 
 #' reference svm_output-inl.h: hinge-loss gradient layer.
@@ -1011,6 +1020,14 @@ mx.nd.RotaryEmbedding <- function(..., ctx = mx.cpu()) {
   a <- list(...)
   is.nd <- vapply(a, function(x) inherits(x, 'MXNDArray'), logical(1))
   mx.nd.internal.invoke('RotaryEmbedding', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
+}
+
+#' Imperative SSDScan: 7 NDArray input(s), 0 scalar(s)
+#' @return MXNDArray
+mx.nd.SSDScan <- function(..., ctx = mx.cpu()) {
+  a <- list(...)
+  is.nd <- vapply(a, function(x) inherits(x, 'MXNDArray'), logical(1))
+  mx.nd.internal.invoke('SSDScan', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
 }
 
 #' Imperative SVMOutput: 2 NDArray input(s), 2 scalar(s)

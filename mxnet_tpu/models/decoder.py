@@ -25,6 +25,13 @@ def proj(x, name, width, bias=False, **inputs):
                               name=name, **inputs)
 
 
+def cut(x, axis, *widths):
+    """``x`` cut along ``axis`` into consecutive parts of ``widths``."""
+    ends = [sum(widths[:i]) for i in range(len(widths) + 1)]
+    return [sym.slice_axis(x, axis=axis, begin=lo, end=hi)
+            for lo, hi in zip(ends, ends[1:])]
+
+
 def scoped(prefix, kind=None, layer=-1):
     """The ``__scope__`` attribute scope (``ops.transformer.node_scope``)
     of one block part, for the device trace.  A part made of plain ops is
@@ -170,16 +177,19 @@ def block(x, pre, eps, mixer, mlp, mixer_norm="attn_norm",
 
 
 def lm_head_loss(x, vocab_size, eps, label=None, head_weight=None,
-                 row_weight=None, **ignoring):
+                 row_weight=None, logits_divisor=None, **ignoring):
     """Rows ``(B*T, D)`` -> ``final_norm`` -> ``lm_head`` -> the per-row
     cross entropy -> the loss head ``lm``, whose gradient is 1 / rows.
     ``label`` None: ``softmax_label`` ``(B, T)``, flattened.
     ``head_weight`` shares the head's matrix, ``row_weight`` multiplies
-    the rows' losses, ``ignoring`` is ``SoftmaxCELoss``'s."""
+    the rows' losses, ``logits_divisor`` divides the logits before the
+    loss, ``ignoring`` is ``SoftmaxCELoss``'s."""
     if label is None:
         label = sym.Reshape(sym.Variable("softmax_label"), shape=(-1,))
     head = {} if head_weight is None else {"weight": head_weight}
     logits = proj(norm(x, "final_norm", eps), "lm_head", vocab_size, **head)
+    if logits_divisor is not None:
+        logits = logits / logits_divisor
     rows = sym.SoftmaxCELoss(logits, label, name="lm_loss", **ignoring)
     if row_weight is not None:
         rows = rows * row_weight

@@ -49,8 +49,8 @@ ends of ``ops/linear_attention.py`` run it), ``attn_proj.l<i>`` and
 """
 from .. import symbol as sym
 from ..moe.layer import with_aux_loss, with_load_heads
-from .decoder import (block, embed, gqa_attention, lm_head_loss, norm, proj,
-                      routed_experts, scoped)
+from .decoder import (block, cut, embed, gqa_attention, lm_head_loss, norm,
+                      proj, routed_experts, scoped)
 
 
 def qwen3_next_lm(num_layers, hidden_size, full_attention_interval,
@@ -70,12 +70,6 @@ def qwen3_next_lm(num_layers, hidden_size, full_attention_interval,
                          % (rotary_dim, head_dim))
     hk, hv, d = gdn_key_heads, gdn_value_heads, gdn_head_dim
     group = hv // hk
-
-    def cut(x, axis, *widths):
-        """``x`` cut along ``axis`` into consecutive parts."""
-        ends = [sum(widths[:i]) for i in range(len(widths) + 1)]
-        return [sym.slice_axis(x, axis=axis, begin=lo, end=hi)
-                for lo, hi in zip(ends, ends[1:])]
 
     def gdn(h, pre, l):
         with scoped("", "gdn_proj", l):

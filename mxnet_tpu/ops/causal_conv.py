@@ -65,7 +65,7 @@ from .. import trace
 from .nn import ACTIVATIONS
 from .pallas_kernels import _kernel_on_tpu, pl
 
-__all__ = ["causal_conv", "causal_conv1d", "gated_conv"]
+__all__ = ["biased_conv", "causal_conv", "causal_conv1d", "gated_conv"]
 
 # lanes a grid step takes: the largest that divides every part and the
 # group (and with them every part's place in its group)
@@ -86,8 +86,8 @@ SUBLANES = 8
 
 def causal_conv1d(x, w):
     """Depthwise causal convolution over time: ``(B, T, C)`` data, one
-    ``W``-tap filter a channel ``(C, W)``, no bias: ``y_t = sum_j w[:, j]
-    x_{t - (W - 1) + j}``, positions before 0 read as zero."""
+    ``W``-tap filter a channel ``(C, W)`` (a bias is its caller's): ``y_t = sum_j
+    w[:, j] x_{t - (W - 1) + j}``, positions before 0 read as zero."""
     width = w.shape[1]
     t = x.shape[1]
     xp = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
@@ -443,7 +443,7 @@ def causal_conv(x, w, act_type=None, lanes=None, interpret: bool = False):
     docstring); each trace records which as ``conv:lowering``:
     ``kernel`` 1 means the op's TPU lowering is the kernel pair (a CPU
     program holds the plain form all the same), ``plain`` 1 the plain
-    form on every platform."""
+    form on every platform.  ``biased_conv``, below, adds a bias."""
     grouped = x.ndim == 4
     if not grouped:
         x = x.reshape(x.shape[:2] + (1, -1))
@@ -631,3 +631,189 @@ def gated_conv(x, w, interpret: bool = False):
                   kernel=int(kernel), plain=int(not kernel))
     return _gated_lowerings(x, w, interpret) if kernel \
         else _plain_gated(x, w)
+
+
+# The biased form, ``act(conv(x) + b)`` with one number a channel (a
+# state-space mixer's convolution), below the two forms above for the
+# reason the gated form is: their kernels' payloads name those lines.
+# The same walk, blocks and passes (``_specs``, ``_passes``, ``_window``);
+# the bias rides the taps' operand, on the sublane behind the last tap,
+# and its cotangent leaves there, summed as the taps' are.
+def _taps_and_bias(w, bias):
+    """``_tap_rows`` of ``(C, W)`` w with ``(C,)`` bias as tap ``W``."""
+    return _tap_rows(jnp.concatenate(
+        [w.astype(jnp.float32), bias.astype(jnp.float32)[:, None]], axis=1))
+
+
+def _biased_fwd_kernel(x_ref, w_ref, y_ref, tail_ref, *, width):
+    """``_fwd_kernel`` with the bias, ``w_ref``'s row ``width``."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        tail_ref[...] = jnp.zeros_like(tail_ref)
+
+    def one(lanes, r0, n, _):
+        taps = _taps(w_ref, lanes, width + 1)
+        win = _window(x_ref, tail_ref, lanes, r0, n)
+        pre = taps[width] + sum(taps[j] * _shifted(win, width - 1 - j)
+                                for j in range(width))
+        y_ref[pl.ds(r0, n), lanes] = (
+            pre * jax.nn.sigmoid(pre)).astype(y_ref.dtype)
+
+    _passes(x_ref, one)
+    tail_ref[...] = x_ref[x_ref.shape[0] - HALO:, :]
+
+
+def _biased_bwd_kernel(x_ref, front_ref, w_ref, dy_ref, dx_ref, dw_ref,
+                       next_ref, acc_ref, *, width, tiles):
+    """``_bwd_kernel`` with the bias: ``pre`` holds it, and ``acc_ref``'s
+    row ``width`` sums ``g`` itself, the bias's cotangent."""
+    f32 = jnp.float32
+    b, m = pl.program_id(1), pl.program_id(2)
+
+    @pl.when((b == 0) & (m == 0))
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(m == 0)
+    def _():
+        next_ref[...] = jnp.zeros_like(next_ref)
+
+    front = front_ref[...]
+    front = jnp.where(m == tiles - 1, jnp.zeros_like(front), front)
+
+    def one(lanes, r0, n, after):
+        taps = _taps(w_ref, lanes, width + 1)
+        win = _window(x_ref, front, lanes, r0, n)
+        xs = [_shifted(win, width - 1 - j) for j in range(width)]
+        pre = taps[width] + sum(tap * x for tap, x in zip(taps, xs))
+        sig = jax.nn.sigmoid(pre)
+        g = (dy_ref[pl.ds(r0, n), lanes].astype(f32)
+             * (sig * (1.0 + pre * (1.0 - sig))))
+        for j, p in enumerate([g * x for x in xs] + [g]):
+            acc_ref[j, :, lanes] += sum(p[i:i + SUBLANES]
+                                        for i in range(0, n, SUBLANES))
+        gwin = jnp.concatenate([g, after], axis=0)
+        dx_ref[pl.ds(r0, n), lanes] = sum(
+            taps[j] * _lifted(gwin, width - 1 - j, n)
+            for j in range(width)).astype(dx_ref.dtype)
+        if isinstance(r0, int):
+            next_ref[:, lanes] = g[:SUBLANES]
+        return g[:SUBLANES]
+
+    _passes(x_ref, one, first=lambda lanes: next_ref[:, lanes], flip=True)
+
+    @pl.when((b == pl.num_programs(1) - 1) & (m == tiles - 1))
+    def _():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+        for j in range(width + 1):
+            dw_ref[j:j + 1, :] = jnp.sum(acc_ref[j], axis=0, keepdims=True)
+
+
+# lint: allow(raw-jit) — as _conv_fwd
+@functools.partial(jax.jit, static_argnames=("parts", "interpret"))
+def _biased_fwd(x, w, bias, *, parts, interpret):
+    """``causal_conv_bias_fwd``: ``_conv_fwd`` of ``conv(x) + bias``."""
+    b, t, g, dw = x.shape
+    c, width = w.shape
+    sp = _specs(x, parts, False)
+    # lint: allow(raw-pallas-call) — as _conv_fwd
+    return pl.pallas_call(
+        functools.partial(_biased_fwd_kernel, width=width),
+        grid=sp["grid"], in_specs=[sp["data"], sp["taps"]],
+        out_specs=sp["out"],
+        out_shape=jax.ShapeDtypeStruct((b, t, c), x.dtype),
+        scratch_shapes=[sp["scratch"]((HALO, sp["block"]), x.dtype)],
+        compiler_params=sp["params"], interpret=interpret,
+        name="causal_conv_bias_fwd",
+    )(x.reshape(b, t, g * dw), _taps_and_bias(w, bias))
+
+
+# lint: allow(raw-jit) — as _conv_fwd
+@functools.partial(jax.jit, static_argnames=("parts", "interpret"))
+def _biased_bwd(x, w, bias, dy, *, parts, interpret):
+    """``causal_conv_bias_bwd``: ``_conv_bwd``'s cotangents and the
+    bias's."""
+    b, t, g, dw = x.shape
+    c, width = w.shape
+    sp = _specs(x, parts, True)
+    flat = x.reshape(b, t, g * dw)
+    # lint: allow(raw-pallas-call) — as _conv_fwd
+    dx, dtaps = pl.pallas_call(
+        functools.partial(_biased_bwd_kernel, width=width,
+                          tiles=sp["tiles"]),
+        grid=sp["grid"],
+        in_specs=[sp["data"], sp["front"], sp["taps"], sp["out"]],
+        out_specs=[sp["data"], sp["taps"]],
+        out_shape=[jax.ShapeDtypeStruct(flat.shape, x.dtype),
+                   jax.ShapeDtypeStruct((SUBLANES, c), jnp.float32)],
+        scratch_shapes=[
+            sp["scratch"]((SUBLANES, sp["block"]), jnp.float32),
+            sp["scratch"]((width + 1, SUBLANES, sp["block"]), jnp.float32)],
+        compiler_params=sp["params"], interpret=interpret,
+        name="causal_conv_bias_bwd",
+    )(flat, flat, _taps_and_bias(w, bias), dy)
+    return (dx, dtaps[:width].T.astype(w.dtype),
+            dtaps[width].astype(bias.dtype))
+
+
+def _plain_biased(x, w, bias, parts, act_type):
+    """``biased_conv`` of ``(B, T, G, Dw)`` data by the plain form."""
+    y = causal_conv1d(_taken(x, parts), w) + bias.astype(x.dtype)
+    return (y if act_type is None else ACTIVATIONS[act_type](y),
+            _rest(x, parts))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _biased_lowerings(x, w, bias, parts, interpret: bool):
+    """``_two_lowerings`` with the bias: ``silu(conv(.) + bias)`` of x's
+    ``parts`` and the lanes behind them; the backward pass keeps x, w and
+    the bias."""
+    return _biased_lowerings_fwd(x, w, bias, parts, interpret)[0]
+
+
+def _biased_lowerings_fwd(x, w, bias, parts, interpret):
+    def kernels(x, w, bias):
+        return (_biased_fwd(x, w, bias, parts=parts, interpret=interpret),
+                _rest(x, parts))
+
+    out = _kernel_on_tpu(
+        kernels, lambda x, w, bias: _plain_biased(x, w, bias, parts, "silu"),
+        interpret, x, w, bias)
+    return out, (x, w, bias)
+
+
+def _biased_lowerings_bwd(parts, interpret, res, cts):
+    def kernels(x, w, bias, dy, drest):
+        dx, dw, db = _biased_bwd(x, w, bias, dy, parts=parts,
+                                 interpret=interpret)
+        return _put_rest(dx, drest, x.shape[2]).reshape(x.shape), dw, db
+
+    def plain(x, w, bias, dy, drest):
+        return jax.vjp(lambda x, w, bias: _plain_biased(
+            x, w, bias, parts, "silu"), x, w, bias)[1]((dy, drest))
+
+    return _kernel_on_tpu(kernels, plain, interpret, *res, *cts)
+
+
+_biased_lowerings.defvjp(_biased_lowerings_fwd, _biased_lowerings_bwd)
+
+
+def biased_conv(x, w, bias, act_type=None, lanes=None,
+                interpret: bool = False):
+    """``causal_conv`` with ``(C,)`` bias added before the activation:
+    the same inputs, outputs and choice of lowering (the kernel pair
+    takes one tap fewer: the bias has the sublane behind the last), the
+    same counter, its track ending ``+bias``."""
+    grouped = x.ndim == 4
+    if not grouped:
+        x = x.reshape(x.shape[:2] + (1, -1))
+    b, t, g, dw = x.shape
+    parts = tuple(lanes) if grouped else (dw,)
+    kernel = _kernel_takes(x, w, parts, act_type) and w.shape[1] < SUBLANES
+    trace.counter("conv:lowering", cat="ops",
+                  track="%s%s/%d+bias" % (x.dtype.name, [b, t, g * dw],
+                                          g * sum(parts)),
+                  kernel=int(kernel), plain=int(not kernel))
+    y, rest = (_biased_lowerings(x, w, bias, parts, interpret) if kernel
+               else _plain_biased(x, w, bias, parts, act_type))
+    return [y, rest] if grouped else [y]

@@ -130,7 +130,7 @@ from jax import lax
 
 from .. import trace
 from ..base import MXNetError
-from .causal_conv import causal_conv, causal_conv1d, gated_conv
+from .causal_conv import biased_conv, causal_conv, causal_conv1d, gated_conv
 from .nn import ACTIVATIONS
 from .pallas_kernels import _kernel_on_tpu, pl
 from .registry import OpDef, Param, register_op
@@ -974,18 +974,16 @@ def _kernel_takes(q, v) -> bool:
 
 @register_op("CausalConv1D", hint="causalconv1d")
 class CausalConv1DOp(OpDef):
-    """Depthwise causal convolution over time of ``(B, T, C)``: one
-    ``kernel``-tap filter a channel (``weight`` ``(C, kernel)``), no bias;
-    output ``t`` reads inputs ``t - kernel + 1 .. t``; then ``act_type``.
-    With ``lanes`` ``(w_0, ..)`` the data is ``(B, T, G, Dw)``: a group's
-    leading lanes are convolved where they lie, part by part, as
-    ``weight``'s rows; the lanes behind them are ``rest`` (``causal_conv``).
-    ``gated`` alone: ``[B | C | u]`` ``(B, T, 3 C)`` -> ``C * conv(B * u)``."""
+    """Depthwise causal convolution over time of ``(B, T, C)``: a ``kernel``-tap filter a channel
+    (``weight`` ``(C, kernel)``; output ``t`` reads inputs ``t - kernel + 1 .. t``), plus ``bias``
+    ``(C,)`` where ``no_bias`` is False (unset: no such input), then ``act_type``.  ``lanes``: of
+    ``(B, T, G, Dw)`` a group's leading lanes; ``gated``: ``C * conv(B * u)`` (``causal_conv``)."""
     params = [Param("kernel", int, default=4), Param("lanes", "shape"),
-              Param("act_type", str, enum=list(ACTIVATIONS)), Param("gated", bool)]
+              Param("act_type", str, enum=list(ACTIVATIONS)), Param("gated", bool),
+              Param("no_bias", bool)]
 
     def list_arguments(self, p):
-        return ["data", "weight"]
+        return ["data", "weight"] + ["bias"] * (p.no_bias is False)
 
     def list_outputs(self, p):
         return ["output", "rest"] if p.lanes else ["output"]
@@ -995,14 +993,16 @@ class CausalConv1DOp(OpDef):
         if d is None:
             return in_shapes, [None] * (2 if p.lanes else 1), []
         if len(d) != (4 if p.lanes else 3) or taken > d[-1] or d[2] % wide \
-                or p.gated and (p.lanes or p.act_type):
+                or p.gated and (p.lanes or p.act_type or p.no_bias is False):
             raise MXNetError("CausalConv1D: data (batch, seq, channels) or, "
                              "with lanes, (.., groups, width); got %r" % (d,))
         outs = [tuple(d[:2]) + (d[2] * n,) for n in (taken, d[3] - taken)] \
             if p.lanes else [tuple(d[:2]) + (d[2] // wide,)]
-        return [d, (outs[0][2], p.kernel)], outs, []
+        return [d, (outs[0][2], p.kernel)] + [outs[0][2:]] * (p.no_bias is False), outs, []
 
     def forward(self, p, inputs, aux, ctx):
+        if len(inputs) > 2:
+            return biased_conv(*inputs, p.act_type, p.lanes)
         return causal_conv(*inputs, act_type=p.act_type, lanes=p.lanes) \
             if not p.gated else [gated_conv(*inputs)]
 
