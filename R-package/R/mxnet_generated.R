@@ -201,6 +201,16 @@ mx.symbol.GatedDeltaNet <- function(...) {
   mx.symbol.internal.create('GatedDeltaNet', list(...))
 }
 
+#' ``RMSNorm`` over every ``head_dim`` lanes of ``(..., H * head_dim)``
+#' @param head_dim head_dim parameter (required)
+#' @param eps eps parameter (default 1e-05)
+#' @param act_type act_type parameter (default silu; one of silu, sigmoid)
+#' @param name string, node name (auto when omitted)
+#' @return MXSymbol
+mx.symbol.GatedRMSNorm <- function(...) {
+  mx.symbol.internal.create('GatedRMSNorm', list(...))
+}
+
 #' reference identity_attach_KL_sparse_reg-inl.h: identity forward with a
 #' @param sparseness_target sparseness_target parameter (default 0.1)
 #' @param penalty penalty parameter (default 0.001)
@@ -884,6 +894,14 @@ mx.nd.GatedDeltaNet <- function(..., ctx = mx.cpu()) {
   a <- list(...)
   is.nd <- vapply(a, function(x) inherits(x, 'MXNDArray'), logical(1))
   mx.nd.internal.invoke('GatedDeltaNet', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
+}
+
+#' Imperative GatedRMSNorm: 1 NDArray input(s), 1 scalar(s)
+#' @return MXNDArray
+mx.nd.GatedRMSNorm <- function(..., ctx = mx.cpu()) {
+  a <- list(...)
+  is.nd <- vapply(a, function(x) inherits(x, 'MXNDArray'), logical(1))
+  mx.nd.internal.invoke('GatedRMSNorm', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
 }
 
 #' Imperative IndexedSelfAttention: 1 NDArray input(s), 1 scalar(s)

@@ -14,7 +14,8 @@ depthwise causal convolution of ``conv_kernel`` taps and SiLU; a
 low-rank decay gate (``hidden -> kda_head_dim -> heads * kda_head_dim``)
 and a per-head write gate; the output through a per-head RMSNorm gated
 by ``sigmoid`` of a second low-rank projection (the one bias of the
-model is on its up-projection), then ``o_proj``.  MLA: queries of
+model is on its up-projection; ``GatedRMSNorm``, on the rows as the op
+writes them), then ``o_proj``.  MLA: queries of
 ``qk_nope_dim + qk_rope_dim`` a head; keys and values decompressed from
 one ``kv_lora_rank`` latent (RMSNorm'ed) plus one ``qk_rope_dim`` key
 part shared by all heads; values of ``v_head_dim``; no rotary embedding
@@ -38,7 +39,7 @@ load-balance loss (the selection bias balances).
 """
 from .. import symbol as sym
 from ..moe.layer import with_load_heads
-from .decoder import (block, embed, lm_head_loss, norm, proj,
+from .decoder import (block, embed, lm_head_loss, proj,
                       routed_experts, swiglu)
 from .latent_attention import latent_attention
 
@@ -77,14 +78,12 @@ def kimi_linear_lm(num_layers, hidden_size, full_attn_layers, dense_layers,
                            shape=(-1, seq_len, kda_heads))
         o = sym.KimiDeltaAttention(q, k, v, decay, beta, layer=l,
                                    name=pre + "kda")
-        o = norm(sym.Reshape(o, shape=(-1, kda_head_dim)), pre + "o_norm",
-                 rms_eps)
         gate = proj(proj(h, pre + "g_down", kda_head_dim),
                     pre + "g_up", kda_width, bias=True)
-        gate = sym.Activation(sym.Reshape(gate, shape=(-1, kda_head_dim)),
-                              act_type="sigmoid")
-        return proj(sym.Reshape(o * gate, shape=(-1, kda_width)),
-                    pre + "o_proj", hidden_size)
+        o = sym.GatedRMSNorm(sym.Reshape(o, shape=(-1, kda_width)),
+                             gate=gate, head_dim=kda_head_dim, eps=rms_eps,
+                             act_type="sigmoid", name=pre + "o_norm")
+        return proj(o, pre + "o_proj", hidden_size)
 
     def mla(h, pre, l):
         return latent_attention(h, pre, seq_len, hidden_size, mla_heads,

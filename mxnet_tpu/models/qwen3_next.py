@@ -19,7 +19,8 @@ published filter's order, ``[q heads | k heads | v heads]``, and beside
 them the z lanes as they are); the op (q and k L2-normalized, a key head
 repeated for its value heads, ``beta = sigmoid(b)``, ``g = -exp(A_log)
 softplus(a + dt_bias)``); the output through a per-head RMSNorm times
-``silu(z)``, then ``o_proj``.
+``silu(z)`` (``GatedRMSNorm``, on the rows as the op writes them and the
+z lanes as the convolution hands them on), then ``o_proj``.
 
 Gated attention: ``num_heads`` query heads over ``num_kv_heads``
 key/value heads of ``head_dim``; ``q_proj`` is twice as wide as the
@@ -49,8 +50,8 @@ ends of ``ops/linear_attention.py`` run it), ``attn_proj.l<i>`` and
 """
 from .. import symbol as sym
 from ..moe.layer import with_aux_loss, with_load_heads
-from .decoder import (block, cut, embed, gqa_attention, lm_head_loss, norm,
-                      proj, routed_experts, scoped)
+from .decoder import (block, cut, embed, gqa_attention, lm_head_loss, proj,
+                      routed_experts, scoped)
 
 
 def qwen3_next_lm(num_layers, hidden_size, full_attention_interval,
@@ -89,9 +90,10 @@ def qwen3_next_lm(num_layers, hidden_size, full_attention_interval,
                                            hv * d), (hk, hk, hv)))
         o = sym.GatedDeltaNet(q, k, v, a, b, layer=l, name=pre + "gdn")
         with scoped("", "gdn_proj", l):
-            o = norm(sym.Reshape(o, shape=(-1, d)), pre + "o_norm", rms_eps)
-            o = o * sym.Activation(sym.Reshape(mixed[1], shape=(-1, d)),
-                                   act_type="silu")
+            o = sym.GatedRMSNorm(
+                sym.Reshape(o, shape=(-1, seq_len, hv * d)), gate=mixed[1],
+                head_dim=d, eps=rms_eps, act_type="silu",
+                name=pre + "o_norm")
             return proj(sym.Reshape(o, shape=(-1, hv * d)), pre + "o_proj",
                         hidden_size)
 

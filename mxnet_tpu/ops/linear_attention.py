@@ -894,7 +894,7 @@ def _two_lowerings_bwd(interpret, res, do):
     # as jax.checkpoint ties what it computes again to the cotangent's
     # arrival: without it XLA is free to form every layer's float32 q, k
     # and g at the start of the backward pass and hold them
-    (args, kept), do = lax.optimization_barrier((res, do))
+    (args, kept), do = lax.optimization_barrier((res, do.reshape(*do.shape[:2], -1)))
 
     def kernels(do, kept, *args):
         v = args[2]
@@ -906,7 +906,7 @@ def _two_lowerings_bwd(interpret, res, do):
         return dq, dk, dv, ddecay, dbeta, da_log, ddt_bias
 
     def plain(do, kept, *args):
-        return jax.vjp(_plain_attention, *args)[1](do)
+        return jax.vjp(_plain_attention, *args)[1](do.reshape(args[2].shape))
 
     return _kernel_on_tpu(kernels, plain, interpret, do, kept, *args)
 

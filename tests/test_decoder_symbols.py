@@ -18,7 +18,11 @@ the commit that adds it; a hash is never re-taken to make a refactor
 pass.  ISSUE 53 meant to move two builders' graphs and took theirs
 again (every ``kimi*`` and ``qwen3-next*`` symbol and the two steps:
 ``CausalConv1D`` carries its SiLU, and Qwen3-Next's reads the fused
-projection where it lies); every other builder's stand."""
+projection where it lies), and so did ISSUE 68 (the same symbols and
+steps: the mixers' output stage is the one node ``GatedRMSNorm`` on the
+rows as the rule writes them, where ``RMSNorm``, ``Activation`` and a
+product stood between three ``Reshape``s); every other builder's
+stand."""
 import hashlib
 import importlib
 import json
@@ -215,7 +219,7 @@ SYMBOL_WAS = {
     "olmoe-1b-7b":
         "3010508f9af6d214d25b4ea18ba84994901ecdb99e011aa1a16b0b8e402fb4b7",
     "kimi-linear-48b-a3b":
-        "4d129c2816b2423686a37318e20ecdca9ab8ef75a9238dc94ee9757f0dc3ca44",
+        "dff53698a00ec2819599b157cfe8cda630ea56f97e2efbb13412fa15d568f87f",
     "glm-4.7-flash":
         "787b1b2114b39c79c8efd33a4dd949f218c07bf94a39b900e6b0333c00100433",
     "sdar-30b-a3b":
@@ -231,19 +235,20 @@ SYMBOL_WAS = {
         "4dd9b03064d193eab8d657784f597d6d779c00b8cae09e19fdec3613e90ebdfd",
     "smallthinker-whole":
         "b7b3aee331521264f4ec20024a1d4ea0c553144c1c2666ef2e413f8688f07b58",
-    # taken at the commit that added the builder (ISSUE 50)
+    # taken at the commit that added the builder (ISSUE 50), again at
+    # ISSUE 53 and at ISSUE 68, which meant to move them
     "qwen3-next-80b-a3b":
-        "d02bcecd33cf7bf01e6ba648bf134c1deb802f3417edfe61d89e53096f4b282b",
+        "4f741640321eef15e5496d3a410e1463afa70bf4ad2b94aaa8975cf4f8f8dd1d",
     "qwen3-next-share":
-        "7e4b15905697ab30e84574f4e94f0ae242f0f24e61355ddef6c3bc2fcf37f265",
+        "6e0104974da04939fcf104c7935a85575b3b8ed12cea2e7894585b04bc1fd616",
     "qwen3-next-whole":
-        "f5ea23d967030b0d5d1073124b9fe25fd33bf3be207cf4dc73048b4e90478691",
+        "cf277e4ccb52f1e3c15a167127e43ff82e20133c715add8722c35e6e17407614",
     "qwen3-next-aux-0":
-        "6a208c40c6852fe06e098356e0601caf8024de83eb0a9b23fd18fb185a5e8465",
+        "fadda8e584d0522584fd2f87270fa13ab51bb2a2c42b09922c14465fe4faac86",
     "qwen3-next-all-rotated":
-        "e79bc102d9f2dfe90f1753da0792f9c2eb791a1ea9d55cc6f7a0cbacba7a88ce",
+        "42a6201dd4a9f54d1b7352de868b075bab670d3d9cc7ce648c3df4d9073178c3",
     "qwen3-next-every-second":
-        "4eb005aa5b1a6fde901cfc10ccc42d4aa449021e7fc03b8dca4e6a541a61dbc6",
+        "3ffc25bad70427762a66ee4f5e08204614979f97f8c7370daa865822233e474d",
     # taken at the commit that added the builder (ISSUE 54)
     "ouro-2.6b":
         "a6237f2acd13d5d18d5a2fe516b57a08d4d8b58d51e529a1552893f9c88f15f3",
@@ -288,11 +293,11 @@ SYMBOL_WAS = {
     "olmoe-aux-0":
         "56de2d26c2c517cb4762f53be03544ae0560a3f024c25b59df3c4986f064eddb",
     "kimi-share":
-        "b086532bd9522f3b1bfb59261fd5720841fffb673164a7398b95fdca6601aaf7",
+        "f13a444520f419ed1046d68efe2cdec276313c32adbe7771aba2b93f9b185fbd",
     "kimi-whole":
-        "c8b5eac50af6cffbbbf6090d643b0231acec060ce9f0590e972dd7bd6dd23ee3",
+        "cf827c6317ed0d1673ff6ae133ca492a141cf8198bc69cee42a991da90198446",
     "kimi-mixed":
-        "ca9a76ea70b1c574b0f9d5ec231ac2a2c7b173770a9c669b736e58e8997c23da",
+        "4aa4c2c3e28b649b673bb631f7e0d50530edb8b21f09f18239213b7f99f19196",
     "glm-share":
         "8fcc0fbc997e1497b3671ef97ad37ddaf922eea12d3c38139aebc9553073a9a8",
     "glm-whole":
@@ -359,7 +364,7 @@ STEPS = {
 # lowers as the one window ``(0, T*k)`` (``_moe_share_ffn``)
 STEP_WAS = {
     "kimi":
-        "5b4a44980d9a739a66b20d8ebb79e2f4bc1a3e0325a6aedd3fe57786af3184cb",
+        "46bf3071246cac5e0a7e69a64243f23fb52194a50198bf1f19c73fa851117150",
     "glm":
         "292685084b552e7e7ed132e853a86133ee00d17abc2c8962486f503997fd3cae",
     "afmoe":
@@ -367,9 +372,10 @@ STEP_WAS = {
     # taken at the commit that added the builder (ISSUE 47)
     "smallthinker":
         "95b031f02386400a58b5e0493e1d76a80030c0e9412c1cf011c168736e2f10dd",
-    # taken at the commit that added the builder (ISSUE 50)
+    # taken at the commit that added the builder (ISSUE 50), again at
+    # ISSUE 53 and at ISSUE 68
     "qwen3-next":
-        "e79d49911605d777c1239610d6dbee01e9d33b23f649361f14499065ba5cb2c6",
+        "d65ce1bfaf36f97e584e8f105def2d48f7de15010a41520c3bca866f96575c3f",
 }
 
 

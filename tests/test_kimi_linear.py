@@ -31,6 +31,7 @@ from mxnet_tpu.ops import transformer as tf_ops           # noqa: E402
 from check_utils import check_symbolic_forward, jaxpr_eqns  # noqa: E402
 
 import manifest                                           # noqa: E402
+from symbol_signature import nodes, signature             # noqa: E402
 
 REF = manifest.load_module("reference", "kimi-linear-48b-a3b")
 
@@ -1028,6 +1029,48 @@ def test_fit_feeds_the_held_share_to_moe_load():
         assert 0 < e["args"]["held"] < routed
     assert [n for n in mod._aux_names] == [b + "_select_bias"
                                            for b in blocks]
+
+
+# sha256 of the symbol's arguments, outputs and states (names and shapes,
+# in order: ``common/symbol_signature.py``) at the parent of ISSUE 68,
+# which made the mixers' output stage one node and meant to move nothing
+# a checkpoint or the reference's weights map by
+SIGNATURE_WAS = {
+    "cell": "61b87bedb5279d790b41c5ea1c3ba0ea93dbc6c5e89e19da501bce92d5b67302",
+    "tiny": "0681ed4ac795d882aa07dd954774479f8ad19594adf9997ca629f1452f5b731a",
+}
+
+
+@pytest.mark.parametrize("case", sorted(SIGNATURE_WAS))
+def test_the_output_stage_is_one_node_under_the_names_it_had(case):
+    """Every KDA layer ends in ONE ``GatedRMSNorm`` with a sigmoid gate,
+    fed the gate's up-projection as it comes; its weight is still
+    ``l<i>_o_norm_gamma`` of a head's width, and the symbol's arguments,
+    outputs and states are the parent's, name for name and shape for
+    shape."""
+    import json
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "kimi-linear-48b-a3b.json")) as f:
+        cell = json.load(f)["model"]["kwargs"]
+    kwargs, batch = (cell, 1) if case == "cell" else (TINY, BATCH)
+    net = kimi_linear_lm(**kwargs)
+    shape = (batch, kwargs["seq_len"])
+    assert signature(net, data=shape, softmax_label=shape) \
+        == SIGNATURE_WAS[case]
+    shapes = dict(zip(net.list_arguments(), net.infer_shape(
+        data=shape, softmax_label=shape)[0]))
+    mixers = [l for l in range(1, kwargs["num_layers"] + 1)
+              if l not in kwargs["full_attn_layers"]]
+    stages = nodes(net, "GatedRMSNorm")
+    assert [n.name for n in stages] == ["l%d_o_norm" % l for l in mixers]
+    for l, node in zip(mixers, stages):
+        assert shapes["l%d_o_norm_gamma" % l] == (kwargs["kda_head_dim"],)
+        assert node.params["act_type"] == "sigmoid"
+        assert [i[0].name for i in node.inputs][1:] == [
+            "l%d_o_norm_gamma" % l, "l%d_g_up" % l]
+    assert not [n for n in nodes(net, "RMSNorm") if "o_norm" in n.name]
+    assert not [n for n in nodes(net, "Activation")
+                if n.params["act_type"] == "sigmoid"]
 
 
 def test_a_checkpoint_written_before_pr36_still_loads():

@@ -37,6 +37,7 @@ from mxnet_tpu.ops import linear_attention as kda_ops     # noqa: E402
 
 from check_utils import jaxpr_eqns                        # noqa: E402
 import manifest                                           # noqa: E402
+from symbol_signature import nodes, signature             # noqa: E402
 
 REF = manifest.load_module("reference", "qwen3-next-80b-a3b")
 
@@ -665,6 +666,50 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer():
 
 
 # -- scopes and counters -------------------------------------------------------
+# sha256 of the symbol's arguments, outputs and states (names and shapes,
+# in order: ``common/symbol_signature.py``) at the parent of ISSUE 68,
+# which made the mixers' output stage one node and meant to move nothing
+# a checkpoint or the reference's weights map by
+SIGNATURE_WAS = {
+    "cell": "1bc64c778f1eab7245996e200419e75d8649e9ab79f02817d79b1d34242f8b68",
+    "tiny": "364dc18e3c94c45b4f4d1f523528f78419dba0c23beb332c04f7b8b7d15745c5",
+}
+
+
+@pytest.mark.parametrize("case", sorted(SIGNATURE_WAS))
+def test_the_output_stage_is_one_node_under_the_names_it_had(case):
+    """Every Gated DeltaNet layer ends in ONE ``GatedRMSNorm`` on the
+    rows as the rule writes them, under ``gdn_proj.l<i>``, fed the z
+    lanes as the convolution hands them on; its weight is still
+    ``l<i>_o_norm_gamma`` of a head's width, and the symbol's arguments,
+    outputs and states are the parent's, name for name and shape for
+    shape."""
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "qwen3-next-80b-a3b.json")) as f:
+        cell = json.load(f)["model"]["kwargs"]
+    kwargs, batch = (cell, 1) if case == "cell" else (TINY, BATCH)
+    net = qwen3_next_lm(**kwargs)
+    shape = (batch, kwargs["seq_len"])
+    assert signature(net, data=shape, softmax_label=shape) \
+        == SIGNATURE_WAS[case]
+    shapes = dict(zip(net.list_arguments(), net.infer_shape(
+        data=shape, softmax_label=shape)[0]))
+    mixers = [l for l in range(kwargs["num_layers"])
+              if (l + 1) % kwargs["full_attention_interval"]]
+    stages = nodes(net, "GatedRMSNorm")
+    assert [n.name for n in stages] == ["l%d_o_norm" % l for l in mixers]
+    for l, node in zip(mixers, stages):
+        assert shapes["l%d_o_norm_gamma" % l] == (kwargs["gdn_head_dim"],)
+        assert node.attrs["__scope__"] == "gdn_proj.l%d" % l
+        assert node.params["act_type"] == "silu"
+        assert [i[0].name for i in node.inputs][1:] == [
+            "l%d_o_norm_gamma" % l, "l%d_conv" % l]
+    # no other norm or activation is left of the stage
+    assert not [n for n in nodes(net, "RMSNorm") if "o_norm" in n.name]
+    assert not [n for n in nodes(net, "Activation")
+                if n.attrs.get("__scope__", "").startswith("gdn_proj")]
+
+
 def test_device_scopes_name_the_new_parts():
     net, kwargs, params, tokens, labels = _tiny(seed=1, num_layers=4)
     scopes = set()

@@ -3,13 +3,18 @@
 read without a chip: a plain ``jnp`` replica of one mixer block of
 Qwen3-Next at the published widths (bfloat16 compute, float32
 parameters cast, forward and ``jax.vjp``, the rule ``gated_delta_net``
-itself, on a TPU its kernels) compiled for a described v5e, in two
+itself, on a TPU its kernels) compiled for a described v5e, in three
 forms:
 
   chain  the block as it was built before PR 53: cut q, k, v, z ->
-         ``Concat`` -> ``causal_conv1d`` -> SiLU -> cut
-  op     the block as ``models/qwen3_next.py`` builds it: ``causal_conv``
-         on the projection where it lies (on a TPU the kernel pair)
+         ``Concat`` -> ``causal_conv1d`` -> SiLU -> cut; the output
+         stage ``RMSNorm`` x ``silu(z)`` over ``(rows * heads, 128)``
+  op     the block as it was built from PR 53 to PR 67: ``causal_conv``
+         on the projection where it lies (on a TPU the kernel pair),
+         the output stage as in ``chain``
+  norm   the block as ``models/qwen3_next.py`` builds it: ``op`` with
+         the output stage as ``gated_rms_norm`` on the rows the rule
+         writes (on a TPU the kernel pair ``gated_norm_fwd`` / ``_bwd``)
 
 and prints, a form, XLA's ``bytes accessed`` and the entry computation's
 ``copy`` operations and fusions by kind with the bytes of their results
@@ -33,7 +38,10 @@ import jax.numpy as jnp  # noqa: E402
 from jax import lax  # noqa: E402
 
 from mxnet_tpu.ops.causal_conv import causal_conv, causal_conv1d  # noqa: E402
+from mxnet_tpu.ops.gated_norm import gated_rms_norm  # noqa: E402
 from mxnet_tpu.ops.linear_attention import gated_delta_net  # noqa: E402
+
+FORMS = ("chain", "op", "norm")
 
 HIDDEN, HK, HV, D, TAPS = 2048, 16, 32, 128, 4
 GROUP = HV // HK
@@ -71,8 +79,11 @@ def block(form, params, h, dtype):
     ba = (h @ p["ba"].T).reshape(1, t, HK, 2 * GROUP)
     b, a = (x.reshape(1, t, HV) for x in (ba[..., :GROUP], ba[..., GROUP:]))
     o = gated_delta_net(q, k, v, a, b, p["a_log"], p["dt_bias"])
-    o = rms(o.reshape(-1, D), p["norm"])
-    o = o * jax.nn.silu(z.reshape(-1, D))
+    if form == "norm":
+        o = gated_rms_norm(o.reshape(1, t, HV * D), p["norm"], z, 1e-6)
+    else:
+        o = rms(o.reshape(-1, D), p["norm"])
+        o = o * jax.nn.silu(z.reshape(-1, D))
     return o.reshape(t, HV * D) @ p["o"].T
 
 
@@ -132,7 +143,7 @@ def main():
 
     params = {n: arr(s, jnp.float32) for n, s in param_shapes().items()}
     rows = arr((args.seq, HIDDEN), jnp.bfloat16)
-    for form in ("chain", "op"):
+    for form in FORMS:
         compiled = jax.jit(step, static_argnums=0).lower(
             form, params, rows, rows).compile()
         text = compiled.as_text()
