@@ -45,7 +45,7 @@ product) beside the ops' own ``attn.l<i>``, ``moe_*.l<i>`` and
 from .. import symbol as sym
 from ..moe.layer import with_load_heads
 from .decoder import (block, embed, kind_attention, layer_kinds, lm_head_loss,
-                      routed_experts, swiglu)
+                      routed_experts, scoped, swiglu)
 
 
 def afmoe_lm(num_layers, hidden_size, layer_types, dense_layers, num_heads,
@@ -61,7 +61,7 @@ def afmoe_lm(num_layers, hidden_size, layer_types, dense_layers, num_heads,
 
     def mlp(h, pre, layer):
         if layer < dense_layers:
-            return swiglu(h, pre, dense_width, hidden_size)
+            return swiglu(h, pre, dense_width, hidden_size, layer)
         return routed_experts(
             h, pre, layer, num_experts, experts_per_tok, expert_width,
             hidden_size, renormalize=True, score="sigmoid", scale=route_scale,
@@ -70,7 +70,8 @@ def afmoe_lm(num_layers, hidden_size, layer_types, dense_layers, num_heads,
 
     x = embed(sym.Variable("data"), vocab_size, hidden_size)
     if embed_scale != 1.0:
-        x = x * float(embed_scale)
+        with scoped("", "embed"):
+            x = x * float(embed_scale)
     for l, kind in enumerate(layer_types):
         pre = "l%d_" % l
         x = block(x, pre, rms_eps,
@@ -79,5 +80,5 @@ def afmoe_lm(num_layers, hidden_size, layer_types, dense_layers, num_heads,
                       num_heads, num_kv_heads, head_dim, hidden_size,
                       rms_eps, gated=True),
                   lambda h: mlp(h, pre, l),
-                  post_norms=("attn_post_norm", "ffn_post_norm"))
+                  post_norms=("attn_post_norm", "ffn_post_norm"), layer=l)
     return with_load_heads(lm_head_loss(x, vocab_size, rms_eps))

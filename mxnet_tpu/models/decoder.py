@@ -8,6 +8,15 @@ a reference and its tests.  Unnamed nodes are numbered in the order they
 are made and a node takes the attribute scope it is made in, so the order
 of the statements here is part of every builder's symbol, which
 ``tests/test_decoder_symbols.py`` holds by its hash.
+
+Every part made of plain ops carries a declared device scope
+(``scoped``; ``trace/scopes.py``), so that a device trace tells a block's
+parts apart: ``embed``, ``block_norm.l<i>``, ``residual.l<i>``,
+``mlp.l<i>``, ``attn_proj.l<i>`` / ``attn_gate.l<i>``, ``lm_head``, beside
+the ops' own (``attn``, ``moe_*``, ``lm_loss``).  A scope stands beside a
+node that names itself, never around it: of nested declared scopes the
+outermost wins.  ``tests/test_block_scopes.py`` fails a builder that
+leaves a projection, a norm or a sum generic.
 """
 import contextlib
 
@@ -48,18 +57,24 @@ def scoped(prefix, kind=None, layer=-1):
                      + ("" if layer < 0 else ".l%d" % layer))
 
 
-def embed(tokens, vocab_size, hidden_size, name="embed", **inputs):
-    """Ids ``(B, T)`` -> rows ``(B*T, D)``; ``weight=`` shares a table."""
-    x = sym.Embedding(tokens, input_dim=vocab_size, output_dim=hidden_size,
-                      name=name, **inputs)
-    return sym.Reshape(x, shape=(-1, hidden_size))
+def embed(tokens, vocab_size, hidden_size, name="embed", scope="", **inputs):
+    """Ids ``(B, T)`` -> rows ``(B*T, D)``; ``weight=`` shares a table.
+    Scope: ``scope + "embed"`` (the table's gradient with it); ``scope``
+    None sets none, for a caller whose own scope is around the call."""
+    with scoped(scope, "embed"):
+        x = sym.Embedding(tokens, input_dim=vocab_size,
+                          output_dim=hidden_size, name=name, **inputs)
+        return sym.Reshape(x, shape=(-1, hidden_size))
 
 
-def swiglu(h, pre, width, hidden_size):
-    """The dense MLP: ``(silu(h Wg) * (h Wu)) Wd``."""
-    gate = sym.Activation(proj(h, pre + "gate_proj", width), act_type="silu")
-    return proj(gate * proj(h, pre + "up_proj", width), pre + "down_proj",
-                hidden_size)
+def swiglu(h, pre, width, hidden_size, layer=-1, scope=""):
+    """The dense MLP: ``(silu(h Wg) * (h Wu)) Wd``.  Scope: ``scope +
+    "mlp"``, ``.l<layer>`` behind it."""
+    with scoped(scope, "mlp", layer):
+        gate = sym.Activation(proj(h, pre + "gate_proj", width),
+                              act_type="silu")
+        return proj(gate * proj(h, pre + "up_proj", width),
+                    pre + "down_proj", hidden_size)
 
 
 def routed_experts(h, pre, layer, num_experts, experts_per_tok, expert_width,
@@ -154,7 +169,7 @@ def kind_attention(h, pre, layer, kind, window, rope_theta, *sizes, **how):
 
 def block(x, pre, eps, mixer, mlp, mixer_norm="attn_norm",
           post_norms=(None, None), sum_scopes=(None, None),
-          mlp_sees_mixer_rows=False):
+          mlp_sees_mixer_rows=False, layer=-1):
     """One residual block: ``x + [post](mixer(norm(x)))``, then
     ``x + [post](mlp(norm(x)))``.  ``mixer`` and ``mlp`` are functions of
     the normed rows; ``post_norms`` names the two norms inside the
@@ -162,16 +177,20 @@ def block(x, pre, eps, mixer, mlp, mixer_norm="attn_norm",
     made in (``scoped``), where a builder's symbol has one there.
     ``mlp_sees_mixer_rows``: ``mlp`` is a function of its own normed rows
     and, second, of the rows the mixer read (a router placed before the
-    mixer)."""
+    mixer).  ``layer``: the block's index in its model, for the scopes of
+    its own nodes: ``block_norm.l<layer>`` (every norm here) and
+    ``residual.l<layer>`` (a sum ``sum_scopes`` names no scope for)."""
     normed = []
     for branch, pre_norm, post_norm, scope in zip(
             (mixer, mlp), (mixer_norm, "ffn_norm"), post_norms, sum_scopes):
-        normed.append(norm(x, pre + pre_norm, eps))
+        with scoped("", "block_norm", layer):
+            normed.append(norm(x, pre + pre_norm, eps))
         y = branch(*reversed(normed)) \
             if mlp_sees_mixer_rows and branch is mlp else branch(normed[-1])
         if post_norm:
-            y = norm(y, pre + post_norm, eps)
-        with scope or contextlib.nullcontext():
+            with scoped("", "block_norm", layer):
+                y = norm(y, pre + post_norm, eps)
+        with scope or scoped("", "residual", layer):
             x = x + y
     return x
 
@@ -183,13 +202,18 @@ def lm_head_loss(x, vocab_size, eps, label=None, head_weight=None,
     ``label`` None: ``softmax_label`` ``(B, T)``, flattened.
     ``head_weight`` shares the head's matrix, ``row_weight`` multiplies
     the rows' losses, ``logits_divisor`` divides the logits before the
-    loss, ``ignoring`` is ``SoftmaxCELoss``'s."""
+    loss, ``ignoring`` is ``SoftmaxCELoss``'s.  Scopes: ``lm_head`` (the
+    norm, the projection, the division), the loss node's own ``lm_loss``."""
     if label is None:
         label = sym.Reshape(sym.Variable("softmax_label"), shape=(-1,))
     head = {} if head_weight is None else {"weight": head_weight}
-    logits = proj(norm(x, "final_norm", eps), "lm_head", vocab_size, **head)
-    if logits_divisor is not None:
-        logits = logits / logits_divisor
+    # not around the loss node: the outermost declared scope wins, and
+    # the loss's operations are ``lm_loss``'s
+    with scoped("", "lm_head"):
+        logits = proj(norm(x, "final_norm", eps), "lm_head", vocab_size,
+                      **head)
+        if logits_divisor is not None:
+            logits = logits / logits_divisor
     rows = sym.SoftmaxCELoss(logits, label, name="lm_loss", **ignoring)
     if row_weight is not None:
         rows = rows * row_weight

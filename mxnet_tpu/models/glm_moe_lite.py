@@ -43,7 +43,11 @@ Device scopes (``__scope__`` attributes, ``ops.transformer.node_scope``):
 ``mla_q.l<i>``, ``mla_kv.l<i>``, ``rope.l<i>`` beside the ops' own
 ``attn.l<i>`` and ``moe_*.l<i>``; the module's under ``mtp.``
 (``mtp.eh_proj``, ``mtp.mla_q``, ``mtp.attn``, ``mtp.moe_experts``,
-``mtp.lm_loss``, ...).
+``mtp.lm_head``, ``mtp.residual``, ``mtp.lm_loss``, ...).  The shared
+skeleton names the rest (``embed``, ``block_norm.l<i>``,
+``residual.l<i>``, ``mlp.l<i>``, ``attn_proj.l<i>``, ``lm_head``); the
+module's block norms, first sum and output projection take those names
+with no index and no prefix: they were never under ``mtp.``.
 """
 from .. import symbol as sym
 from ..moe.layer import with_load_heads
@@ -75,7 +79,7 @@ def glm_moe_lite_lm(num_layers, hidden_size, dense_layers, heads,
 
         def mlp(h):
             if dense:
-                return swiglu(h, pre, dense_width, hidden_size)
+                return swiglu(h, pre, dense_width, hidden_size, layer, scope)
             with scoped(scope):
                 return routed_experts(
                     h, pre, layer, num_experts, experts_per_tok,
@@ -85,7 +89,9 @@ def glm_moe_lite_lm(num_layers, hidden_size, dense_layers, heads,
                     experts_held=experts_held, first_expert=first_expert)
 
         return block(x, pre, rms_eps, mla, mlp, mixer_norm="mixer_norm",
-                     sum_scopes=(None, None if dense else scoped(scope)))
+                     sum_scopes=(None, scoped(scope, "residual")
+                                 if scope and not dense else None),
+                     layer=layer)
 
     def flat(label):
         return sym.Reshape(label, shape=(-1,))
@@ -103,7 +109,8 @@ def glm_moe_lite_lm(num_layers, hidden_size, dense_layers, heads,
         with scoped("mtp.", "eh_proj"):
             u = proj(sym.Concat(
                 norm(embed(label, vocab_size, hidden_size, "mtp_embed",
-                           weight=embed_weight), "mtp_enorm", rms_eps),
+                           scope=None, weight=embed_weight), "mtp_enorm",
+                     rms_eps),
                 norm(x, "mtp_hnorm", rms_eps), dim=1),
                 "mtp_eh_proj", hidden_size)
         u = layer_block(u, "mtp_", -1, False, "mtp.")
@@ -112,9 +119,10 @@ def glm_moe_lite_lm(num_layers, hidden_size, dense_layers, heads,
         last = sym.slice_axis(label, axis=1, begin=0, end=1) * 0 - 1
         target = sym.Concat(sym.slice_axis(label, axis=1, begin=1,
                                            end=seq_len), last, dim=1)
-        with scoped("mtp."):
+        with scoped("mtp.", "lm_head"):
             logits = proj(norm(u, "mtp_final_norm", rms_eps), "mtp_lm_head",
                           vocab_size, weight=lm_head)
+        with scoped("mtp."):        # the loss names itself: mtp.lm_loss
             loss = sym.SoftmaxCELoss(logits, flat(target), name="mtp_loss",
                                      use_ignore=True, ignore_label=-1)
         heads_out.append(sym.MakeLoss(loss, grad_scale=float(mtp_weight),

@@ -102,20 +102,24 @@ def granite_hybrid_lm(num_layers, hidden_size, layer_types, ssm_heads,
                 h, pre, l, seq_len, num_heads, num_kv_heads, head_dim,
                 hidden_size, rms_eps, head_norms=False,
                 scale=attention_multiplier)
-        return y * residual_multiplier
+        with scoped("", "residual", l):
+            return y * residual_multiplier
 
-    def mlp(h, pre):
-        gate, up = cut(proj(h, pre + "input_linear", 2 * mlp_width), 1,
-                       mlp_width, mlp_width)
-        return proj(sym.Activation(gate, act_type="silu") * up,
-                    pre + "output_linear", hidden_size) * residual_multiplier
+    def mlp(h, pre, l):
+        with scoped("", "mlp", l):
+            gate, up = cut(proj(h, pre + "input_linear", 2 * mlp_width), 1,
+                           mlp_width, mlp_width)
+            return proj(sym.Activation(gate, act_type="silu") * up,
+                        pre + "output_linear", hidden_size) \
+                * residual_multiplier
 
     table = sym.Variable("embed_weight")
-    x = embed(sym.Variable("data"), vocab_size, hidden_size, weight=table) \
-        * embedding_multiplier
+    x = embed(sym.Variable("data"), vocab_size, hidden_size, weight=table)
+    with scoped("", "embed"):
+        x = x * embedding_multiplier
     for l, kind in enumerate(layer_types):
         pre = "l%d_" % l
         x = block(x, pre, rms_eps, lambda h: mixer(h, pre, l, kind),
-                  lambda h: mlp(h, pre), mixer_norm="mixer_norm")
+                  lambda h: mlp(h, pre, l), mixer_norm="mixer_norm", layer=l)
     return lm_head_loss(x, vocab_size, rms_eps, head_weight=table,
                         logits_divisor=logits_scaling)

@@ -133,7 +133,7 @@ def keye_lm(num_layers, hidden_size, num_heads, num_kv_heads, head_dim,
                 rotate=lambda t: rotate(t, tuple(mrope_sections)),
                 core=lambda q, k, v: indexed(h, pre, l, q, k, v)),
             lambda h: experts(h, pre, l),
-            sum_scopes=(scoped("", "attn_proj", l), None))
+            sum_scopes=(scoped("", "attn_proj", l), None), layer=l)
     net = lm_head_loss(x, vocab_size, rms_eps)
     if aux_coef:
         net = with_aux_loss(net, grad_scale=aux_coef)

@@ -40,7 +40,7 @@ load-balance loss (the selection bias balances).
 from .. import symbol as sym
 from ..moe.layer import with_load_heads
 from .decoder import (block, embed, lm_head_loss, proj,
-                      routed_experts, swiglu)
+                      routed_experts, scoped, swiglu)
 from .latent_attention import latent_attention
 
 
@@ -68,22 +68,27 @@ def kimi_linear_lm(num_layers, hidden_size, full_attn_layers, dense_layers,
             return sym.Reshape(x, shape=(-1, seq_len, kda_heads,
                                          kda_head_dim))
 
-        q, k, v = (conv(proj(h, pre + s + "_proj", kda_width),
-                        pre + s + "_conv") for s in "qkv")
-        decay = proj(proj(h, pre + "f_down", kda_head_dim),
-                     pre + "f_up", kda_width)
-        decay = sym.Reshape(decay, shape=(-1, seq_len, kda_heads,
-                                          kda_head_dim))
-        beta = sym.Reshape(proj(h, pre + "beta_proj", kda_heads),
-                           shape=(-1, seq_len, kda_heads))
+        # ``kda_proj.l<l>`` around the op's own ``kda.l<l>``, never around
+        # the op: the outermost declared scope wins
+        with scoped("", "kda_proj", l):
+            q, k, v = (conv(proj(h, pre + s + "_proj", kda_width),
+                            pre + s + "_conv") for s in "qkv")
+            decay = proj(proj(h, pre + "f_down", kda_head_dim),
+                         pre + "f_up", kda_width)
+            decay = sym.Reshape(decay, shape=(-1, seq_len, kda_heads,
+                                              kda_head_dim))
+            beta = sym.Reshape(proj(h, pre + "beta_proj", kda_heads),
+                               shape=(-1, seq_len, kda_heads))
         o = sym.KimiDeltaAttention(q, k, v, decay, beta, layer=l,
                                    name=pre + "kda")
-        gate = proj(proj(h, pre + "g_down", kda_head_dim),
-                    pre + "g_up", kda_width, bias=True)
-        o = sym.GatedRMSNorm(sym.Reshape(o, shape=(-1, kda_width)),
-                             gate=gate, head_dim=kda_head_dim, eps=rms_eps,
-                             act_type="sigmoid", name=pre + "o_norm")
-        return proj(o, pre + "o_proj", hidden_size)
+        with scoped("", "kda_proj", l):
+            gate = proj(proj(h, pre + "g_down", kda_head_dim),
+                        pre + "g_up", kda_width, bias=True)
+            o = sym.GatedRMSNorm(sym.Reshape(o, shape=(-1, kda_width)),
+                                 gate=gate, head_dim=kda_head_dim,
+                                 eps=rms_eps, act_type="sigmoid",
+                                 name=pre + "o_norm")
+            return proj(o, pre + "o_proj", hidden_size)
 
     def mla(h, pre, l):
         return latent_attention(h, pre, seq_len, hidden_size, mla_heads,
@@ -92,7 +97,7 @@ def kimi_linear_lm(num_layers, hidden_size, full_attn_layers, dense_layers,
 
     def mlp(h, pre, l):
         if l <= dense_layers:
-            return swiglu(h, pre, dense_width, hidden_size)
+            return swiglu(h, pre, dense_width, hidden_size, l)
         return routed_experts(
             h, pre, l, num_experts, experts_per_tok, expert_width,
             hidden_size, renormalize=True, score="sigmoid", scale=routed_scale,
@@ -104,5 +109,6 @@ def kimi_linear_lm(num_layers, hidden_size, full_attn_layers, dense_layers,
         pre = "l%d_" % l
         mixer = mla if l in full_attn_layers else kda
         x = block(x, pre, rms_eps, lambda h: mixer(h, pre, l),
-                  lambda h: mlp(h, pre, l), mixer_norm="mixer_norm")
+                  lambda h: mlp(h, pre, l), mixer_norm="mixer_norm",
+                  layer=l)
     return with_load_heads(lm_head_loss(x, vocab_size, rms_eps))

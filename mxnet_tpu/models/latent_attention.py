@@ -27,12 +27,16 @@ def latent_attention(h, pre, seq_len, hidden_size, heads, kv_lora_rank,
     names the attention op's trace scope.  ``scope`` is the prefix of
     the device scopes of the parts made of plain ops (``scoped``:
     ``mla_q``, ``mla_kv``, ``rope``; ``""`` for a trunk's block,
-    ``"mtp."`` inside a prediction module); None sets no attribute and
-    leaves the symbol as it was before scopes."""
+    ``"mtp."`` inside a prediction module; the rotated queries' cut and
+    the output projection are ``attn_proj`` under either); None sets no
+    attribute and leaves the symbol as it was before scopes."""
     def rotate(x):
         with scoped(scope, "rope", layer):
             return sym.RotaryEmbedding(x, theta=rope_theta)
 
+    # with no prefix: what a prediction module made outside its ``mtp.``
+    # scopes stays outside the kind, and ``scope_mtp_ms`` reads what it did
+    outside = None if scope is None else ""
     qk_dim = qk_nope_dim + qk_rope_dim
     with scoped(scope, "mla_q", layer):
         q = proj(norm(proj(h, pre + "q_a_proj", q_lora_rank),
@@ -41,10 +45,12 @@ def latent_attention(h, pre, seq_len, hidden_size, heads, kv_lora_rank,
             if q_lora_rank else proj(h, pre + "q_proj", heads * qk_dim)
         q = sym.Reshape(q, shape=(-1, seq_len, heads, qk_dim))
     if rope_theta:
-        q = sym.Concat(
-            sym.slice_axis(q, axis=3, begin=0, end=qk_nope_dim),
-            rotate(sym.slice_axis(q, axis=3, begin=qk_nope_dim, end=qk_dim)),
-            dim=3)
+        with scoped(outside, "attn_proj", layer):
+            q = sym.Concat(
+                sym.slice_axis(q, axis=3, begin=0, end=qk_nope_dim),
+                rotate(sym.slice_axis(q, axis=3, begin=qk_nope_dim,
+                                      end=qk_dim)),   # its own, inside this
+                dim=3)
     with scoped(scope, "mla_kv", layer):
         kv_a = proj(h, pre + "kv_a_proj", kv_lora_rank + qk_rope_dim)
         latent = norm(sym.slice_axis(kv_a, axis=1, begin=0,
@@ -67,5 +73,6 @@ def latent_attention(h, pre, seq_len, hidden_size, heads, kv_lora_rank,
                            end=qk_nope_dim + v_head_dim)
     with scoped(scope):
         a = sym.CausalSelfAttention(q, k, v, layer=layer, name=pre + "attn")
-    return proj(sym.Reshape(a, shape=(-1, heads * v_head_dim)),
-                pre + "o_proj", hidden_size)
+    with scoped(outside, "attn_proj", layer):
+        return proj(sym.Reshape(a, shape=(-1, heads * v_head_dim)),
+                    pre + "o_proj", hidden_size)

@@ -83,7 +83,7 @@ def lfm2_moe_lm(num_layers, hidden_size, layer_types, dense_layers,
 
     def mlp(h, pre, l):
         if l < dense_layers:
-            return swiglu(h, pre, dense_width, hidden_size)
+            return swiglu(h, pre, dense_width, hidden_size, l)
         return routed_experts(
             h, pre, l, num_experts, experts_per_tok, expert_width,
             hidden_size, renormalize=True, score="sigmoid",
@@ -95,6 +95,7 @@ def lfm2_moe_lm(num_layers, hidden_size, layer_types, dense_layers,
     for l, kind in enumerate(layer_types):
         pre = "l%d_" % l
         x = block(x, pre, rms_eps, lambda h: mixer(h, pre, l, kind),
-                  lambda h: mlp(h, pre, l), mixer_norm="operator_norm")
+                  lambda h: mlp(h, pre, l), mixer_norm="operator_norm",
+                  layer=l)
     return with_load_heads(lm_head_loss(x, vocab_size, rms_eps,
                                         head_weight=table))

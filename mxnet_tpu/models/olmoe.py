@@ -20,7 +20,7 @@ sum(load balance)``.
 from .. import symbol as sym
 from ..moe.layer import with_aux_loss, with_load_heads
 from .decoder import (block, embed, lm_head_loss, norm, proj,
-                      routed_experts)
+                      routed_experts, scoped)
 
 
 def olmoe_lm(num_layers, hidden_size, num_heads, num_experts,
@@ -36,19 +36,23 @@ def olmoe_lm(num_layers, hidden_size, num_heads, num_experts,
         return sym.Reshape(x, shape=(-1, seq_len, num_heads, head_dim))
 
     def attention(h, pre, l):
-        q, k = (sym.RotaryEmbedding(heads(norm(
-            proj(h, pre + s + "_proj", hidden_size), pre + s + "_norm",
-            rms_eps)), theta=rope_theta) for s in "qk")
-        v = heads(proj(h, pre + "v_proj", hidden_size))
+        # ``attn_proj.l<l>`` around the op's own ``attn.l<l>``
+        with scoped("", "attn_proj", l):
+            q, k = (sym.RotaryEmbedding(heads(norm(
+                proj(h, pre + s + "_proj", hidden_size), pre + s + "_norm",
+                rms_eps)), theta=rope_theta) for s in "qk")
+            v = heads(proj(h, pre + "v_proj", hidden_size))
         a = sym.CausalSelfAttention(q, k, v, layer=l, name=pre + "attn")
-        return proj(sym.Reshape(a, shape=(-1, hidden_size)),
-                    pre + "o_proj", hidden_size)
+        with scoped("", "attn_proj", l):
+            return proj(sym.Reshape(a, shape=(-1, hidden_size)),
+                        pre + "o_proj", hidden_size)
 
     x = embed(sym.Variable("data"), vocab_size, hidden_size)
     for l in range(num_layers):
         pre = "l%d_" % l
         x = block(x, pre, rms_eps, lambda h: attention(h, pre, l),
                   lambda h: routed_experts(h, pre, l, num_experts,
-                                           experts_per_tok, expert_width))
+                                           experts_per_tok, expert_width),
+                  layer=l)
     net = lm_head_loss(x, vocab_size, rms_eps)
     return with_load_heads(with_aux_loss(net, grad_scale=aux_coef))

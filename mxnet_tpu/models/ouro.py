@@ -55,7 +55,8 @@ from .. import symbol as sym
 from ..attribute import AttrScope
 from ..initializer import Normal
 from ..trace.heads import LOOP_EXIT
-from .decoder import block, embed, gqa_attention, norm, proj, swiglu
+from .decoder import (block, embed, gqa_attention, norm, proj, scoped,
+                      swiglu)
 
 
 def exit_objective(ce, gate, num_steps, exit_beta):
@@ -102,9 +103,10 @@ def ouro_lm(num_layers, hidden_size, num_heads, num_kv_heads, head_dim,
                   lambda r: gqa_attention(
                       r, pre, l, seq_len, num_heads, num_kv_heads, head_dim,
                       hidden_size, rms_eps, rotate=rotate, head_norms=False),
-                  lambda r: swiglu(r, pre, mlp_width, hidden_size),
-                  post_norms=("attn_post_norm", "ffn_post_norm"))
-    h = norm(h, "final_norm", rms_eps)
+                  lambda r: swiglu(r, pre, mlp_width, hidden_size, l),
+                  post_norms=("attn_post_norm", "ffn_post_norm"), layer=l)
+    with scoped("", "lm_head"):
+        h = norm(h, "final_norm", rms_eps)
     with AttrScope(__scope__="loop_head"):
         logits = proj(h, "lm_head", vocab_size)
         gate = proj(h, "exit_gate", 1, bias=True)

@@ -121,7 +121,7 @@ def qwen3_next_lm(num_layers, hidden_size, full_attention_interval,
                       hidden_size, renormalize=True, score="softmax",
                       shared_hidden=shared_width, shared_gate=True,
                       experts_held=experts_held, first_expert=first_expert),
-                  mixer_norm="mixer_norm")
+                  mixer_norm="mixer_norm", layer=l)
     net = lm_head_loss(x, vocab_size, rms_eps)
     if aux_coef:
         net = with_aux_loss(net, grad_scale=aux_coef)

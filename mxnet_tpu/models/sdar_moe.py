@@ -75,11 +75,13 @@ def sdar_moe_lm(num_layers, hidden_size, num_heads, num_kv_heads, head_dim,
                 h, pre, l, num_experts, experts_per_tok, expert_width,
                 hidden_size, renormalize=True, score="softmax",
                 experts_held=experts_held, first_expert=first_expert),
-            sum_scopes=(scoped("", "attn_proj", l), None))
+            sum_scopes=(scoped("", "attn_proj", l), None), layer=l)
     # the head reads the noised half: rows 0..T-1 of each sequence
-    noised = sym.slice_axis(sym.Reshape(x, shape=(-1, rows, hidden_size)),
-                            axis=1, begin=0, end=seq_len)
-    noised = sym.Reshape(noised, shape=(-1, hidden_size))
+    with scoped("", "lm_head"):
+        noised = sym.slice_axis(
+            sym.Reshape(x, shape=(-1, rows, hidden_size)), axis=1, begin=0,
+            end=seq_len)
+        noised = sym.Reshape(noised, shape=(-1, hidden_size))
     label = sym.Variable("softmax_label")                  # (B, 2, T)
     target, weight = (sym.Reshape(sym.slice_axis(label, axis=1, begin=i,
                                                  end=i + 1), shape=(-1,))

@@ -73,6 +73,6 @@ def smallthinker_lm(num_layers, hidden_size, layer_types, num_heads,
                       score="softmax", router_data=h,
                       experts_held=experts_held, first_expert=first_expert,
                       act_zeros=act_zeros),
-                  mlp_sees_mixer_rows=True)
+                  mlp_sees_mixer_rows=True, layer=l)
     return with_act_zeros_head(with_load_heads(
         lm_head_loss(x, vocab_size, rms_eps)))

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 from typing import List, Optional
 
+from ..attribute import AttrScope
 from ..base import get_env
 from .. import symbol as _sym
 from ..trace.heads import MOE_ACT_ZEROS, MOE_LOAD
@@ -70,7 +71,8 @@ def MoEFeedForward(data, num_hidden: int, num_experts: int, k: int = 2,
     ``FullyConnected``, in the experts' gated or plain form) to every
     token's output, with ``shared_gate`` times ``sigmoid(x w_sg)``, one
     number a token from a fourth, bias-free ``FullyConnected`` of width 1
-    (``<name>_shared_gate``).  ``experts_held`` > 0 makes this one
+    (``<name>_shared_gate``); its nodes and the sum that adds it carry the
+    device scope ``mlp.l<layer>`` (``_shared_scope``).  ``experts_held`` > 0 makes this one
     expert-parallel rank's share: the router, its weights'
     renormalization and the load
     head stay ``num_experts`` wide, the stacked weights hold experts
@@ -150,16 +152,29 @@ def MoEFeedForward(data, num_hidden: int, num_experts: int, k: int = 2,
         return x if act_type == "identity" else \
             _sym.Activation(x, act_type=act_type)
 
-    hidden = fc(data, "i2h", shared_hidden)
-    hidden = act(fc(data, "i2h_gate", shared_hidden)) * hidden if gated \
-        else act(hidden)
-    shared = fc(hidden, "h2o", output_dim)
-    if shared_gate:
-        shared = _sym.broadcast_mul(shared, _sym.Activation(
-            _sym.FullyConnected(data, num_hidden=1, no_bias=True,
-                                name=name + "_shared_gate"),
-            act_type="sigmoid"))
-    return out + shared
+    with _shared_scope(layer):
+        hidden = fc(data, "i2h", shared_hidden)
+        hidden = act(fc(data, "i2h_gate", shared_hidden)) * hidden if gated \
+            else act(hidden)
+        shared = fc(hidden, "h2o", output_dim)
+        if shared_gate:
+            shared = _sym.broadcast_mul(shared, _sym.Activation(
+                _sym.FullyConnected(data, num_hidden=1, no_bias=True,
+                                    name=name + "_shared_gate"),
+                act_type="sigmoid"))
+        return out + shared
+
+
+def _shared_scope(layer: Optional[int]) -> AttrScope:
+    """The ``__scope__`` of a shared expert's nodes (``ops.transformer.
+    node_scope``): a dense MLP, so ``mlp``, ``.l<layer>`` behind it,
+    behind the prefix the caller's attribute scope holds (``mtp.``).  A
+    caller that named the whole layer itself keeps its name."""
+    outer = AttrScope.current().get(None).get("__scope__", "")
+    if outer and not outer.endswith("."):
+        return AttrScope()
+    return AttrScope(__scope__="%smlp%s" % (
+        outer, "" if layer is None else ".l%d" % layer))
 
 
 def _dispatch_heads(symbol, out_idx: int) -> List:

@@ -29,7 +29,8 @@ capacity (``MoEServeParityPass``) need touch nothing else.
 Each body runs under a ``jax.named_scope`` (``moe_route``,
 ``moe_experts``, ``moe_combine``, with ``.l<layer>``);
 ``_moe_share_ffn`` enters the three around its gather, its experts and
-its combine.
+its combine, and ``moe_share`` around what a row bound adds beside them
+(``_share_bounded``).
 """
 from __future__ import annotations
 
@@ -549,12 +550,20 @@ def _share_bounded(params, prefix, bound, *inputs):
     out = _share_window(p, (0, bound))(*inputs)
     # the rows behind the bound, where any is held: the same body over
     # them, added.  The branch that runs nothing is all a common step
-    # pays for it (zeros out and, backward, zero gradients)
-    overflows = _held_sizes(p, counts).sum() > bound
-    return jax.tree.map(jnp.add, out, jax.lax.cond(
-        overflows,
-        jax.checkpoint(_share_window(p, (bound, every - bound))),
-        lambda *_: jax.tree.map(jnp.zeros_like, out), *inputs))
+    # pays for it (zeros out and, backward, zero gradients).  The scope
+    # ``moe_share`` is around the bound's test and the sum, never around
+    # the ``cond``: the outermost declared scope wins, and the second
+    # pass's parts keep their own.  The ``conditional``, its branch of
+    # zeros and the backward pass's sums of the two passes' gradients
+    # (JAX's transpose makes those, in no scope of this function) stay
+    # under the node's generic scope
+    with _scope("moe_share", p):
+        overflows = _held_sizes(p, counts).sum() > bound
+    behind = jax.lax.cond(
+        overflows, jax.checkpoint(_share_window(p, (bound, every - bound))),
+        lambda *_: jax.tree.map(jnp.zeros_like, out), *inputs)
+    with _scope("moe_share", p):
+        return jax.tree.map(jnp.add, out, behind)
 
 
 @register_op("_moe_combine", hint="moe_combine")

@@ -2,7 +2,7 @@
 
 Every scope the program enters while it traces a step is a
 ``jax.named_scope``, so its name lands in the ``op_name`` of the HLO
-operations made under it (``jit(step_s1)/transpose(jvp(attn.l0))/
+operations made under it (``jit(step_s2)/transpose(jvp(attn.l0))/
 dot_general``; JAX writes the ``jvp`` / ``transpose`` wrappers of the
 backward pass itself).  A name reads ``<kind>.<instance>``: the kind is
 what a reader sums by (``kind_of``).  There are two sorts:
@@ -54,10 +54,12 @@ import time
 from typing import Dict, Optional, Set
 
 __all__ = ["SCHEME", "module_name", "declared", "generic", "enclosing", "adopt",
-           "resolve", "kind_of", "op_names_of", "table_of", "register_program",
-           "program_scopes", "program_op_names"]
+           "resolve", "kind_of", "sort_of", "op_names_of", "table_of",
+           "register_program", "program_scopes", "program_op_names"]
 
-SCHEME = 1
+# 2 (PR 69): the builders name the rest of a decoder block (``mlp``,
+# ``block_norm``, ``residual``, ``lm_head``, ``embed``, ``kda_proj``, ...)
+SCHEME = 2
 TABLE_SPAN = "trace:scope_table"
 
 _declared: Set[str] = set()
@@ -72,8 +74,8 @@ _op_names: Dict[str, tuple] = {}
 
 
 def module_name(base: str) -> str:
-    """``step`` -> ``step_s1``: the name a scoped program's function
-    takes, and with it its HLO module (``jit_step_s1``)."""
+    """``step`` -> ``step_s2``: the name a scoped program's function
+    takes, and with it its HLO module (``jit_step_s2``)."""
     return "%s_s%d" % (base, SCHEME)
 
 
@@ -112,6 +114,24 @@ def kind_of(scope: str) -> str:
     """What is before the first dot: ``attn.l0`` -> ``attn``,
     ``mtp.attn`` -> ``mtp``, ``lm_loss`` -> ``lm_loss``."""
     return scope.partition(".")[0]
+
+
+def sort_of(scope: str) -> Optional[str]:
+    """Which sort of scope this process entered ``scope`` as:
+    ``"declared"``, ``"generic"``, ``"enclosing"``, ``"adopted"`` (a
+    scope some compiler-written kernel was given to and nothing entered)
+    or None (no scope of this process).  In ``resolve``'s order, for a
+    name entered as more than one sort.  What lets a reader of a table
+    say how much of a step nobody has named without a list of kinds."""
+    if scope in _declared:
+        return "declared"
+    if scope in _generic:
+        return "generic"
+    if scope in _enclosing:
+        return "enclosing"
+    if scope in _adopted.values():
+        return "adopted"
+    return None
 
 
 _WRAPPED = re.compile(r"^(\w+)\((.*)\)$")

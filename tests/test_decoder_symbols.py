@@ -22,7 +22,14 @@ projection where it lies), and so did ISSUE 68 (the same symbols and
 steps: the mixers' output stage is the one node ``GatedRMSNorm`` on the
 rows as the rule writes them, where ``RMSNorm``, ``Activation`` and a
 product stood between three ``Reshape``s); every other builder's
-stand."""
+stood.  ISSUE 69 moved every symbol's ``__scope__`` attributes and
+nothing else (the skeleton names the rest of a block for the device
+trace: ``mlp``, ``block_norm``, ``residual``, ``lm_head``, ``embed``,
+...): all the symbol hashes were taken again, and ``UNSCOPED_WAS`` holds
+that the op nodes, their order, their keywords and their inputs are the
+parent's: each graph less its ``__scope__`` attributes hashes to what
+the parent's (cc9ea8d) did, by this file's own ``_unscoped``.  The
+lowered steps carry no scope and stand."""
 import hashlib
 import importlib
 import json
@@ -213,123 +220,237 @@ SYMBOLS = {
     "mla-mtp": _mla(q_lora_rank=12, rope_theta=1e6, scope="mtp."),
 }
 
-# sha256 of each symbol's JSON at 945e6c8; the first five are ISSUE 46's
-# table, the four behind them the sixth builder's
+# sha256 of each symbol's JSON, all taken again at ISSUE 69 (the scopes
+# moved, ``UNSCOPED_WAS`` holds the rest); first the five cells of ISSUE
+# 46's table, the four behind them the sixth builder's
 SYMBOL_WAS = {
     "olmoe-1b-7b":
-        "3010508f9af6d214d25b4ea18ba84994901ecdb99e011aa1a16b0b8e402fb4b7",
+        "292ca4805a7d2c60c90cfa7c66f804e6494628cd78e82f8e42cedc598fd161c8",
     "kimi-linear-48b-a3b":
-        "dff53698a00ec2819599b157cfe8cda630ea56f97e2efbb13412fa15d568f87f",
+        "31e18ea5af2aacdb914cafed3b52c3e6a40acf02f1c47201a2a062601d3172e6",
     "glm-4.7-flash":
-        "787b1b2114b39c79c8efd33a4dd949f218c07bf94a39b900e6b0333c00100433",
+        "a8c96d68fb7e7165301540a48986d2f38c6bd94010b92b18b75894be1df75bb0",
     "sdar-30b-a3b":
-        "c0ee067be3ca69380c71a9e30645329c7717f3e867b201153c5744dd812f7d83",
+        "beca2ba133113521e71048d1b2c2e6ae3d62c8dbe772a9348fcfc09dc012ce4e",
     "trinity-mini":
-        "3e5e7ce8c564e558445076d7db9f435b29431733b2063a6ccb1fbe3797ed571f",
+        "c99dcd1e44dd3fb8356f3d9f19d572d609ff2aab54a2e0087bb4c62d917cf4be",
     # taken at the commit that added the builder (ISSUE 47)
     "smallthinker-21b-a3b":
-        "d610d2522f3a17701ea206b16b6426c8054b081f462b43aebb18f96e733d2bcd",
+        "033c467024b1c9fe645173aceb507ad22cf1607bac2643b21271ced499aa97ee",
     "smallthinker-share":
-        "28eb66fcbf3cedccff08db0eeee123e1a831d4492d975664c1273ee8b6952324",
+        "3e28061f486216de8f7065ab09e036fcfa2e7756d8d4689e2a311e3f72452de3",
     "smallthinker-counted":
-        "4dd9b03064d193eab8d657784f597d6d779c00b8cae09e19fdec3613e90ebdfd",
+        "1baeb038ce2614acb37b9f86701f3a5ea72e30a2274d89a14cb44d944bfd32c2",
     "smallthinker-whole":
-        "b7b3aee331521264f4ec20024a1d4ea0c553144c1c2666ef2e413f8688f07b58",
+        "2fc6e7c24ba8fba01fcd4d7cbecae108cb4d4425bac9eb1483971a898d2b4ae9",
     # taken at the commit that added the builder (ISSUE 50), again at
     # ISSUE 53 and at ISSUE 68, which meant to move them
     "qwen3-next-80b-a3b":
-        "4f741640321eef15e5496d3a410e1463afa70bf4ad2b94aaa8975cf4f8f8dd1d",
+        "e48c8c7eefbd71e36a5aff657b8d5601459c7bf71c425dde3739d03f2dbbea1c",
     "qwen3-next-share":
-        "6e0104974da04939fcf104c7935a85575b3b8ed12cea2e7894585b04bc1fd616",
+        "9f25a1cdd04695f638a35c93c978c27fa187062ce3e9da9b6d80be76d245d638",
     "qwen3-next-whole":
-        "cf277e4ccb52f1e3c15a167127e43ff82e20133c715add8722c35e6e17407614",
+        "2f6738445844c419db1b90157539813266d60082a04cdfa99d92b0ef46df2edf",
     "qwen3-next-aux-0":
-        "fadda8e584d0522584fd2f87270fa13ab51bb2a2c42b09922c14465fe4faac86",
+        "0df6fca437447172ee67ecf1d41eaa0f4b06cee7827d72e13cb26b1b56063abe",
     "qwen3-next-all-rotated":
-        "42a6201dd4a9f54d1b7352de868b075bab670d3d9cc7ce648c3df4d9073178c3",
+        "84637bc8ba5387763746b96959fc0c92629c860c834abdb741f7bb0ff615401b",
     "qwen3-next-every-second":
-        "3ffc25bad70427762a66ee4f5e08204614979f97f8c7370daa865822233e474d",
+        "de0bcf6f0437f57cf9479d08883fa361145883eb91303b4dba8673a9f6a9834f",
     # taken at the commit that added the builder (ISSUE 54)
     "ouro-2.6b":
-        "a6237f2acd13d5d18d5a2fe516b57a08d4d8b58d51e529a1552893f9c88f15f3",
+        "fa238b0166d330fa195af38ee897c171d283b100092f937136a271d8dd9ec827",
     "ouro-tiny":
-        "8a7127bf3a21a28c0c279c69149be96d239a3ae5f2b5f37214cefe815e313acb",
+        "d2235ef6fa90eff6f7972105d6ae09983343887b63b482a8a6c66f5c5e553533",
     "ouro-one-pass":
-        "eb9d2058606bdfc102781e3c565a1364fc79778f24df8e43c2ca7e303f1223fd",
+        "47f5e6e4fdf76cf370c25abd2f0f22a7e96bcc6e2d931e6ff6bd7fdf707fafaf",
     "ouro-kept":
-        "8ea406b517cee9237e488a330e5aa8c741db56f5ca2bf347f33c69a28ea97b19",
+        "6c4ba28714b5e6655de1341196a26d94cca263eaa3724fcbc0e0952c79ecafef",
     "ouro-grouped":
-        "d25c2955f4329377c144aff6f552bcb7cf7ae56f513f95b566bd62bbf88e150d",
+        "db06b56e0ff5aa99cc032c9ff00d564b931431d1ce51b5220fb96b1e36658993",
     "ouro-wide-embedding":
-        "1e32bfb3be57e690901828997a7b534b564ad51ad7752defadbcae93627279b1",
+        "c2deeac264e2acd4ece3aae4381ebdb3a9fcbae71978067e29dab4c4e541096f",
     # taken at the commit that added the builder (ISSUE 57)
     "keye-aux-0":
-        "3f7802952bb019a6771000414b3ef2f3634497074a95fa27e4ae84ce3a94d45f",
+        "be41c905547f39db66d13d9d811bc1954751f1d68efc5cf4b452604bc4d038d5",
     "keye-positions":
-        "4037d94f8faded7ae89a1a73980939db69b25ec97e94ffec749da899209dba4f",
+        "a6b8c84aab36f520d4298230fd5dcac8b656051d27a499cfa4f842ebdb6ac79d",
     "keye-share":
-        "32539ecb44ecda162787cfb26a3d358093ae442744e32337438cdc5bd867d91f",
+        "aeeef10379d28f175ac22fecef5ffce387b09d98d37523cd3e927be426d2de7f",
     "keye-vl-2.0-30b-a3b":
-        "99be1309ec9a0b073e9146315671dc85e58b65d2fb5edd0195a5fa2bf2df08e1",
+        "23de62d96a80016657f1b6b580f0d80be5a9d2689cb245145136ed0a6abc7417",
     "keye-whole":
-        "e01e43dbaa2a055c54b06713a3b97c17c95a6be4a113fe94d976fa0a9a1e0232",
+        "5bfb3997be65d6137780372bad0278621ae72c80335ba926dd9d70e507ee72d0",
     "keye-wide-embedding":
-        "eb834439a3fe90b9840ee600f5fba26d34a9ff58d2c29f71042b45c519c297d7",
+        "da58b11a60f9d2ec808b4e8b0ce0ddb43564f4920f21d0024a8aeb78d4b2e665",
     # taken at the commit that added the builder (ISSUE 61)
     "lfm2-8b-a1b":
-        "15f583703e2dc8409244e0723eb87bb853fd278540264834e2282b762c4080b0",
+        "ee113a9952156390ff1ebf4cd74d4ac16fecffbdb96e63b983cc3cf7e3ac926c",
     "lfm2-every-second":
-        "dc8a1c9ffb890736cef38d94045e5c2b597b8c9d734b4c35ed00d85f5755107c",
+        "59489e5182b542f656ff457b8ea0a02cd280b87644224fe712c4744d529dcdc7",
     "lfm2-last-rank":
-        "ab255caf1c0e0763c47ca9469312e496f0cb654dd079a8fd5f92351a49e41406",
+        "a4937d56484b125e47a8f67dbf347189b750fb28803649a837588e291e0622e8",
     "lfm2-share":
-        "039659a5f095be401af2baddca332e5b490894fbbeb5333646fd4b13f8a76288",
+        "fd1a98ba3e1faf2c2cbf5ef4e80f941f6507be4f457050a9ed10ce10228fcb2b",
     "lfm2-two-dense":
-        "00d398f0d54af22e8b993c9d3e873abefa1411c0aeb4896c5750be8fcb59c5bc",
+        "6ea9498e004cf5ce47ff96dc63f4404f1ad693ed4167382b9d8b1eccc44fd9ba",
     "lfm2-whole":
-        "3df3c390ba50dcd9af29227efe2351edbfc4e35f0d22a930fe0bae2d4ae496e2",
+        "de5737612e1498efecb6dab3a8103c0f053fd9f21bd3c277259eb650048a125e",
     "olmoe-tiny":
-        "af8dc705e9e7aeb26b2806967a870d607de9f4892070d6b09552c77d2034efc0",
+        "550564dc2380c65c09baacccb0a3323def4451a8a52d0a4fe506bf1bdd73dd0c",
     "olmoe-aux-0":
-        "56de2d26c2c517cb4762f53be03544ae0560a3f024c25b59df3c4986f064eddb",
+        "2b05c74c3698dfa6307c665cccc1fdcd3b7bb60dc8375ae29489a851b606bcb0",
     "kimi-share":
-        "f13a444520f419ed1046d68efe2cdec276313c32adbe7771aba2b93f9b185fbd",
+        "456625b096a4323e8f5a7e8dd8c337802eecd87f4ead69a5bf97f5207618007c",
     "kimi-whole":
-        "cf827c6317ed0d1673ff6ae133ca492a141cf8198bc69cee42a991da90198446",
+        "fae443b0f6fe0b1e740eba09c09050272b16ab1fb1586f9e95d3a6d573468418",
     "kimi-mixed":
-        "4aa4c2c3e28b649b673bb631f7e0d50530edb8b21f09f18239213b7f99f19196",
+        "f8bdf76465e163604882f13c41385c621a9b04bc61d7d324c49f31b84718ce7e",
     "glm-share":
-        "8fcc0fbc997e1497b3671ef97ad37ddaf922eea12d3c38139aebc9553073a9a8",
+        "353b4b06fdea4895c8e3f919c4db986411210924ca1fbbabdf790061fe6f0c3a",
     "glm-whole":
-        "0701683aeeffa8ac821097b06c39f3f81e4255258222735c908a2ef4a3ee6e36",
+        "cb7c43f5675506fccafaa9b7c0d9f19b895b5115b378d6db97918050f8049575",
     "glm-no-mtp":
-        "177747956dddc152220380fad55c4483f1af51aec890e7265bf15a9b62719104",
+        "10fd309892f748fe34695de78c5686ab614e657ed4f94e2bc57725debce237d5",
     "glm-plain-q":
-        "6388f2f59006d9c6031358443a95311a8d690772ed624c4b3c4c673f5fb69b79",
+        "5483431b3de208fe7a57c4af34cc942d902678b2d03f05b924002558cf58ccbc",
     "sdar-share":
-        "2d83d30a65d2bcf58ab95c9077e927f7e8a2326837e7cfc66c47ea0a67bccad0",
+        "b1f93dcb9e2b8f23fcb169a37423439c6d9eb30e16df544f40eaf60cce9e3e03",
     "sdar-whole":
-        "13409b7766155319f82afed3b1c2536b86b9993abbcfc854f68494f61894e479",
+        "73334241c7ac2e9f6af95c9f19d1bab4349ad973d1928396bc895ddd0b03a83f",
     "sdar-aux-0":
-        "6adca4ee43a9aa21f1fd67750ea029348468f27f65a5fd7de4fe0477c26136ac",
+        "81022914c84b3d0e67486eebcc54da82e6b0c02707bb225b61806bea31297b50",
     "afmoe-share":
-        "132bec7ac759c3c3632747c8cde3f428bc6442ad740b9d5f0d007dab36be2c18",
+        "d3048c79a329b3cddd62a3fc5a44a530bc6696f9e05feac05ea8449f0c9cbe2f",
     "afmoe-whole":
-        "5e07e8ae61be77e12a75d79aa493dd2170f420e491d96ae13df5f51563f80d67",
+        "b70fbf703e1bc2af6e8c95644da855ef574f64255aeeafad864ee6091d9ae9ed",
     "afmoe-unscaled":
-        "1c81e3650bab94348e9d11383067421b02ae37f743c703507066b76e61495fa2",
+        "0d154d8c18056c732877f73d37df477deab8e1465cfbc2bea4724e6cd3a1340d",
     "afmoe-sliding":
-        "9113a9b5c92a50d0a10a74e9a8cc5b9dba602b1142e62b48de2c96ff94f424cd",
+        "a454f85b09da41a91d7ffe2b0df84d4b8ecf459a448c05f712f1969c23b8e1c8",
     "afmoe-full":
-        "f8d42f162b8401c7ab71763eef66a3bee5833dbe2e091b0bef39ff04e6ef447e",
+        "a51d096bb9f42f7c4222829edd92ff3632adbc40c18cc19bc4424c4737991fac",
     "afmoe-no-dense":
-        "24a718720548b59203a2426cc1ec455419000c8b861142763e21da7c6e09ff57",
+        "6b0e2f4ade81b4fd2582590886cff8d22b9a3bb71424ee1cdfecd8f4e1432299",
     "afmoe-all-dense":
-        "cf725ecb8b1155d7d3e7943bc4c8360d8ae70a52464164bc47b0a2693269c410",
+        "ec87c53c1e4f91691b03fa6ec0d639c3d84af6d7405f75371515c6748880ff43",
     "mla-unscoped":
         "abfabac33e4456b26e6087fd726edff992019de2edcfabf201ea739a7275c832",
     "mla-mtp":
-        "cf9dd247a23b054411934d6c18ae766ffc59b532cb7a19ce457da1040d143806",
+        "e730f9c676502fc0221c889b2e5b176cd466f0818030584758550e36dfd05e63",
+}
+
+# sha256 of each symbol's JSON less every ``__scope__`` attribute
+# (``_unscoped``), taken at cc9ea8d, the parent of ISSUE 69
+UNSCOPED_WAS = {
+    "afmoe-all-dense":
+        "72a00872405886c2e409dbe251702b5df869600ee4e6b2833f4d561a5f921e2e",
+    "afmoe-full":
+        "f2931962e02cee8898ad4be16e550d436ac4a7c78ed7a5530bd7227dd6219b99",
+    "afmoe-no-dense":
+        "bcc8812b46742559b808640f895636b71e7ae65e83235ecb4f8faebfcba80b3b",
+    "afmoe-share":
+        "2f263df8d1bd873c3f6b111176dc8d031f573939a8a04e30cc06bdd95c9d01f1",
+    "afmoe-sliding":
+        "52df43b942bab0f3235b2a2007a41aed0b92b8f72ffee3b4b36c0b760b1094f4",
+    "afmoe-unscaled":
+        "3b08edf58505c7e814637e0e58d4e74ec11d23f14db2ba471badccb051f6e452",
+    "afmoe-whole":
+        "b7b6e04c42c89538f92051bfd2eddaf68da28523a222e7c46282fd9249069c7a",
+    "glm-4.7-flash":
+        "d4d19b79e11053cb45695e9bbb582b36c60201c351ab5f7741953be45509566f",
+    "glm-no-mtp":
+        "1109d2f659648df5682fec0e89f7dbcfea6a4d661aa134ac951d2ead81b1b8bb",
+    "glm-plain-q":
+        "1c34c6b2a6c7e3a794ddd8d783731c7cfcab15f36be28ef91adbde36c7f48f5a",
+    "glm-share":
+        "ef9d60b0726a5c95c13f084e61a7bc05f620877588abb6a7bd62250749652e93",
+    "glm-whole":
+        "11baf419ec2c196826dd7a669fafdeb8f5b2742146d81b554c338a9f6817e7bc",
+    "keye-aux-0":
+        "3554b453b80ea7cc495c7adbb7f471c563470812c7445459c8438dc3e9a44007",
+    "keye-positions":
+        "eb9bf2ebc11ba4d888800dd09e44ceb51b9184b2e751a034307e69d1b22111da",
+    "keye-share":
+        "990c009be7c548e78758471b25b0446cf797f2c7f648c1f6c63f5fcad29b6128",
+    "keye-vl-2.0-30b-a3b":
+        "81f94cc78f5c8da8a1aa3c09c81164046e49be65494e1626e84318c34bc5e275",
+    "keye-whole":
+        "104f2856af3bfefd9d38a0e545f55378c00579eaad70fabccec8aee8fa6d92a7",
+    "keye-wide-embedding":
+        "16e2d441a39f01621dc28ce2127a371a710ce0891d23c8a89d5f552a99f2886e",
+    "kimi-linear-48b-a3b":
+        "a0bc397151a9e2432cb4628de918ce465b06bb7dc11a0644878de4d0dea1f4c9",
+    "kimi-mixed":
+        "a13502b45a4537a43b9e314a406aed7ce54094fdee49f79b6dce388503fe65dc",
+    "kimi-share":
+        "60314449c363688af0cf92b6fd437bc81ad387a43210dd692208e5f9ad0cc4a3",
+    "kimi-whole":
+        "8451e99ddb128f59fe8130e638cdf3d468e973990826a1d542a00163affa517e",
+    "lfm2-8b-a1b":
+        "9714d6ef4cacdd901a3776ae9ae19743cc5873faeb99c45b731fe86487852df0",
+    "lfm2-every-second":
+        "0c18d7caedd02e6f4233f6db37f0443b45ce77a04eab97cd85373613a86dca0c",
+    "lfm2-last-rank":
+        "89f97d5d1f479b950d678ee853e0962cee90df81ce77c85613bbbc93290039a8",
+    "lfm2-share":
+        "44529f45c013355f9f824362be92d03182224e5c969d2c8d17369b2487be6ec9",
+    "lfm2-two-dense":
+        "029d48c3d763a986d05534ef5cb17ba5c1052abb4ac1682fb7c0f40cf166b5f2",
+    "lfm2-whole":
+        "3a032dbfecdfc32b2d2833527422f3387425342a8a4bf7bb3139013b1b7e6f34",
+    "mla-mtp":
+        "5f9d1e6d7026e44df8c7d950f84acb81976a36a66d09edb3a2483b2b2c03ceb6",
+    "mla-unscoped":
+        "3bf38fa8129599dd1ae0db2acd856fe414f0241d17e5b72c680aa38e28bf99b4",
+    "olmoe-1b-7b":
+        "5de1d98f9c66eec063517b973039b1362de643cc6ff7dc172b5561a1539441c5",
+    "olmoe-aux-0":
+        "25dc6e59ca59a7d2f7666b352ff591f9fcd33b6ae0e7efed0367c25ffa4c4b00",
+    "olmoe-tiny":
+        "65cb6a5d95d38a1d1e74170cfecaefd3849bb0f7cceeb90b6b8f4016f58290b5",
+    "ouro-2.6b":
+        "55b196ec8fbb109cb9033ddb5cab7065f0eb5a542dbf0ec0f4f3d089855b1939",
+    "ouro-grouped":
+        "723796663e50d769735a7bc2c47d49c5fe356b58ef9991b0a9fb06a4d5dfa6ee",
+    "ouro-kept":
+        "df509a51a2d415ee3cd01a8717973a1ab9eda204edd76d447d221bae897ee495",
+    "ouro-one-pass":
+        "5b9173c160db0189203db1531270c924cf1ff5546c93a8170ffb0466cfd6aa56",
+    "ouro-tiny":
+        "2e9fccf47068f65306078425671ea30a8f20392539eef83cd70190a2c45555ad",
+    "ouro-wide-embedding":
+        "cc063a7e36accf69cfd047cef59e758edf4fdd8cf066d429b160f905ccef040a",
+    "qwen3-next-80b-a3b":
+        "6eb31cdde1fd23b82eef8e3219373acc48000e860cc8c46d4751dec8391ec210",
+    "qwen3-next-all-rotated":
+        "39a248ba624661e1fb87fc387f6105a48f6f3747283bf07c315567882243be81",
+    "qwen3-next-aux-0":
+        "e702ccb5401f4c75079f849633c67bb784d213ad870b34ecf82af3f2de044570",
+    "qwen3-next-every-second":
+        "04ae8ab1a898d049508c5242789eb29d1cffa80508ae52ec287fa57c47e3a64a",
+    "qwen3-next-share":
+        "ef0d0d150af5c666e1cbde207c9324d5a7d8f5f3bef836ab31626941a7243612",
+    "qwen3-next-whole":
+        "ad0a64b92badfb232b450af8cc7ee19dd4c1f5a45b2df4690c724b22391b308d",
+    "sdar-30b-a3b":
+        "1c18b15682d1cc6a8efb9b7c3cdfd695c02449c91faaed1e52d0e974ecad898d",
+    "sdar-aux-0":
+        "b0c6c9539383dcd9b6747288bd4766d59e26f7bc9b92e68cdcdcb91aa8c07703",
+    "sdar-share":
+        "e566cb082f3a7cdc3b43777928c5ec38e7720efd6f70f26e5141a358bf638567",
+    "sdar-whole":
+        "a2a3afe53fc1b1824b5f28374025516600dd0a95eea2f903e383cd6cff07d1d2",
+    "smallthinker-21b-a3b":
+        "e337b60ca0ebe787781e86164756b416041ca8a432935bfea6c37eb9145a4e1d",
+    "smallthinker-counted":
+        "4e2205e39943e94b2e474a96d78ef249ce03a455651d5821636de831d9286d90",
+    "smallthinker-share":
+        "0d1be0c22132d8f57fb200c642e4e01500088637c62aed17d726c84bb6c2c10c",
+    "smallthinker-whole":
+        "3ea5154542d6d9151528a95e0a0220454b603d182108b46110be20803b63cba4",
+    "trinity-mini":
+        "c592cd30d5124c19e6ae03ee0db2aba798d08437e26e169d0460a4ce4f57e65e",
 }
 
 
@@ -338,10 +459,30 @@ def _build(builder, kwargs):
         return builder(**kwargs)
 
 
+def _unscoped(text):
+    """A symbol's JSON with no node's ``__scope__``, a loop node's body
+    (a symbol's JSON in its ``body`` keyword) included."""
+    doc = json.loads(text)
+    for node in doc["nodes"]:
+        node.get("attr", {}).pop("__scope__", None)
+        body = node.get("param", {}).get("body")
+        if body is not None:
+            node["param"]["body"] = _unscoped(body)
+    return json.dumps(doc, sort_keys=True)
+
+
 @pytest.mark.parametrize("case", sorted(SYMBOLS))
 def test_the_symbol_is_node_for_node_what_it_was(case):
     text = _build(*SYMBOLS[case]).tojson()
     assert hashlib.sha256(text.encode()).hexdigest() == SYMBOL_WAS[case]
+
+
+@pytest.mark.parametrize("case", sorted(SYMBOLS))
+def test_less_its_scopes_the_symbol_is_the_parents(case):
+    """A scope is an attribute: naming a block's parts moved no op node,
+    no keyword and no input."""
+    text = _unscoped(_build(*SYMBOLS[case]).tojson())
+    assert hashlib.sha256(text.encode()).hexdigest() == UNSCOPED_WAS[case]
 
 
 # id -> (builder, its arguments), the step's inputs

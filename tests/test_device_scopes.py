@@ -128,15 +128,37 @@ def test_precedence_declared_over_generic_and_outermost_declared(
     assert getattr(tf_ops._scope, "prefix", "") == ""
 
 
+def test_sort_of_says_which_sort_a_scope_was_entered_as(monkeypatch):
+    for name in ("_declared", "_generic", "_enclosing"):
+        monkeypatch.setattr(scopes, name, set())
+    monkeypatch.setattr(scopes, "_adopted", {})
+    with scopes.declared("mlp.l0"), scopes.generic("rmsnorm.n"), \
+            scopes.enclosing("loop"):
+        pass
+    scopes.adopt("ragged-dot", "moe_experts")
+    assert [scopes.sort_of(s) for s in (
+        "mlp.l0", "rmsnorm.n", "loop", "moe_experts")] == [
+            "declared", "generic", "enclosing", "adopted"]
+    # a kind is no scope, and neither is a name nothing entered
+    assert scopes.sort_of("mlp") is None
+    assert scopes.sort_of("jit(step_s2)") is None
+    # entered as two sorts: the one ``resolve`` would take it for
+    with scopes.generic("mlp.l0"), scopes.declared("moe_experts"):
+        pass
+    assert scopes.sort_of("mlp.l0") == "declared"
+    assert scopes.sort_of("moe_experts") == "declared"
+    assert "sort_of" in scopes.__all__
+
+
 def test_what_is_no_scope_resolves_to_nothing():
     with scopes.declared("attn.l7"), scopes.generic("rmsnorm.n"):
         pass
-    assert scopes.resolve("jit(step_s1)/jit(main)/add") is None
-    assert scopes.resolve("jit(step_s1)/jvp()/reduce_sum") is None
+    assert scopes.resolve("jit(step_s2)/jit(main)/add") is None
+    assert scopes.resolve("jit(step_s2)/jvp()/reduce_sum") is None
     assert scopes.resolve("jit(attn.l7)/mul") is None      # a function's name
-    assert scopes.resolve("jit(step_s1)/while/body/closed_call/mul") is None
+    assert scopes.resolve("jit(step_s2)/while/body/closed_call/mul") is None
     assert scopes.resolve(
-        "jit(step_s1)/transpose(jvp(rmsnorm.n))/while/body/attn.l7/mul") \
+        "jit(step_s2)/transpose(jvp(rmsnorm.n))/while/body/attn.l7/mul") \
         == "attn.l7"
     text = ('  %fusion.7 = f32[8]{0} fusion(%p), kind=kLoop, calls=%f, '
             'metadata={op_name="jit(s)/jvp(rmsnorm.n)/mul" source_line=3}\n'

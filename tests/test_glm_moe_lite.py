@@ -180,8 +180,9 @@ def test_compressed_queries_and_rotated_parts_against_a_per_head_reference():
     # sequence the reference moves
     scopes = {a["__scope__"] for a in net.attr_dict().values()
               if "__scope__" in a}
-    # the attention op names its own scope (attn.l2) and takes none
-    assert scopes == {"mla_q.l2", "mla_kv.l2", "rope.l2"}
+    # the attention op names its own scope (attn.l2) and takes none; the
+    # rotated queries' cut and the output projection are attn_proj's
+    assert scopes == {"mla_q.l2", "mla_kv.l2", "rope.l2", "attn_proj.l2"}
     assert "__scope__" not in net.attr_dict().get("a_attn", {})
     theta0 = dict(m, rope_theta=1.0)        # every angle = the position
     with jax.default_matmul_precision("highest"):

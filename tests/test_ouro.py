@@ -151,7 +151,9 @@ def test_the_embeddings_own_initializer_wins_over_the_modules():
     (``Variable(init=)``, the attribute ``__init__``) and
     ``Module.init_params`` uses it for that variable alone; without it
     the symbol has no such attribute."""
-    assert "embed_weight" not in ouro_lm(**TINY).attr_dict()
+    # (its device scope apart: ``embed`` names the table it makes)
+    assert ouro_lm(**TINY).attr_dict()["embed_weight"] == {
+        "__scope__": "embed"}
     net = ouro_lm(**dict(TINY, embed_sigma=4.0))
     assert net.attr_dict()["embed_weight"] == {
         "__init__": mx.init.Normal(4.0).dumps()}
