@@ -211,6 +211,21 @@ mx.symbol.GatedRMSNorm <- function(...) {
   mx.symbol.internal.create('GatedRMSNorm', list(...))
 }
 
+#' Between a q or k projection and attention, on the ``(B * T, heads
+#' @param head_dim head_dim parameter (required)
+#' @param norm norm parameter (default True)
+#' @param eps eps parameter (default 1e-06)
+#' @param seq_len seq_len parameter (default 0)
+#' @param theta theta parameter (default 10000.0)
+#' @param period period parameter (default 0)
+#' @param sections sections parameter
+#' @param with_positions with_positions parameter
+#' @param name string, node name (auto when omitted)
+#' @return MXSymbol
+mx.symbol.HeadNormRotary <- function(...) {
+  mx.symbol.internal.create('HeadNormRotary', list(...))
+}
+
 #' reference identity_attach_KL_sparse_reg-inl.h: identity forward with a
 #' @param sparseness_target sparseness_target parameter (default 0.1)
 #' @param penalty penalty parameter (default 0.001)
@@ -902,6 +917,14 @@ mx.nd.GatedRMSNorm <- function(..., ctx = mx.cpu()) {
   a <- list(...)
   is.nd <- vapply(a, function(x) inherits(x, 'MXNDArray'), logical(1))
   mx.nd.internal.invoke('GatedRMSNorm', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
+}
+
+#' Imperative HeadNormRotary: 1 NDArray input(s), 2 scalar(s)
+#' @return MXNDArray
+mx.nd.HeadNormRotary <- function(..., ctx = mx.cpu()) {
+  a <- list(...)
+  is.nd <- vapply(a, function(x) inherits(x, 'MXNDArray'), logical(1))
+  mx.nd.internal.invoke('HeadNormRotary', a[is.nd], as.double(unlist(a[!is.nd])), ctx)
 }
 
 #' Imperative IndexedSelfAttention: 1 NDArray input(s), 1 scalar(s)

@@ -94,37 +94,64 @@ def routed_experts(h, pre, layer, num_experts, experts_per_tok, expert_width,
 
 
 def gqa_attention(h, pre, layer, rows, num_heads, num_kv_heads, head_dim,
-                  hidden_size, eps, rotate=lambda x: x, gated=False,
+                  hidden_size, eps, rotate=None, gated=False,
                   head_norms=True, core=None, **mask):
     """Grouped-query attention with an RMSNorm over each head's lanes of
     q and of k (none with ``head_norms`` off), ``(B*rows, D)`` ->
-    ``(B*rows, D)``.  ``rotate`` places q
-    and k (default: no positions but the order), ``mask`` is
+    ``(B*rows, D)``.  ``rotate`` places q and k (default: no positions
+    but the order): ``RotaryEmbedding``'s keywords (``theta``, ``period``,
+    ``sections``, ``positions`` with ``with_positions``), and norm and
+    rotation are ONE node ``HeadNormRotary`` on the rows as the
+    projection writes them, reshaped to heads only in front of the core
+    op (``ops/head_rotary.py``); or a function of the ``(B, rows, H,
+    head_dim)`` heads, which then stand behind a ``Reshape`` and an
+    ``RMSNorm`` of their own.  ``mask`` is
     ``CausalSelfAttention``'s (default: causal), ``gated`` multiplies the
     heads' outputs by the sigmoid of a gate before ``o_proj``: True, the
     gate is its own projection ``h Wg``; ``"query"``, it is the second
-    half of a doubled ``q_proj``, head by head ``[q | gate]``.  ``core``:
+    half of a doubled ``q_proj``, head by head ``[q | gate]`` (its halves
+    are cut from the heads, so q and k keep the nodes over the heads).
+    ``core``:
     another core op than ``CausalSelfAttention``, a function of the
     placed ``(q, k, v)`` that gives the heads' outputs.  Scopes:
     ``attn_proj.l<i>``, ``attn_gate.l<i>``."""
     width = num_heads * head_dim
+    on_rows = not callable(rotate) and gated != "query" \
+        and bool(head_norms or rotate)
 
-    def heads(name, n, lanes=head_dim):
-        return sym.Reshape(proj(h, pre + name + "_proj", n * lanes),
-                           shape=(-1, rows, n, lanes))
+    def heads(x, n, lanes=head_dim):
+        return sym.Reshape(x, shape=(-1, rows, n, lanes))
 
-    def placed(x, name):
+    def projected(name, n, lanes=head_dim):
+        """A projection's rows where q and k are placed on them, else
+        its heads."""
+        x = proj(h, pre + name + "_proj", n * lanes)
+        return x if on_rows else heads(x, n, lanes)
+
+    def placed(x, name, n):
+        if on_rows:
+            how = dict(rotate, seq_len=rows) if rotate else {}
+            return heads(sym.HeadNormRotary(
+                x, head_dim=head_dim, norm=head_norms, eps=eps,
+                name=pre + name + ("_norm" if head_norms else "_rotary"),
+                **how), n)
         if head_norms:
             x = norm(x, pre + name + "_norm", eps)
-        return rotate(x)
+        if callable(rotate):
+            return rotate(x)
+        return sym.RotaryEmbedding(x, **rotate) if rotate else x
 
     with scoped("", "attn_proj", layer):
-        q = heads("q", num_heads, head_dim * (2 if gated == "query" else 1))
+        q = projected("q", num_heads,
+                      head_dim * (2 if gated == "query" else 1))
         if gated == "query":
             q, gate = (sym.slice_axis(q, axis=3, begin=lo, end=lo + head_dim)
                        for lo in (0, head_dim))
-        q, k = placed(q, "q"), placed(heads("k", num_kv_heads), "k")
-        v = heads("v", num_kv_heads)
+        q = placed(q, "q", num_heads)
+        k = placed(projected("k", num_kv_heads), "k", num_kv_heads)
+        v = projected("v", num_kv_heads)
+        if on_rows:
+            v = heads(v, num_kv_heads)
     a = core(q, k, v) if core else sym.CausalSelfAttention(
         q, k, v, layer=layer, name=pre + "attn", **mask)
     with scoped("", "attn_gate" if gated else "attn_proj", layer):
@@ -163,7 +190,7 @@ def kind_attention(h, pre, layer, kind, window, rope_theta, *sizes, **how):
     ``sizes`` and ``how`` are ``gqa_attention``'s, from ``rows`` on."""
     if kind == "sliding":
         how = dict(how, mask="sliding_window", window=window,
-                   rotate=lambda t: sym.RotaryEmbedding(t, theta=rope_theta))
+                   rotate=dict(theta=rope_theta))
     return gqa_attention(h, pre, layer, *sizes, **how)
 
 

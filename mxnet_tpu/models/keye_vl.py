@@ -83,15 +83,18 @@ def keye_lm(num_layers, hidden_size, num_heads, num_kv_heads, head_dim,
                          % (tuple(mrope_sections), head_dim // 2, index_dim))
     where = sym.Variable("positions") if positions else None
 
-    def rotate(x, sections):
-        """Every axis for the heads' sections, the temporal one for the
-        indexer's single section."""
+    def rotation(sections):
+        """``RotaryEmbedding``'s keywords: every axis for the heads'
+        sections, the temporal one for the indexer's single section."""
+        how = dict(theta=rope_theta, sections=sections)
         if where is None:
-            return sym.RotaryEmbedding(x, theta=rope_theta, sections=sections)
+            return how
         axes = where if len(sections) == 3 else sym.slice_axis(
             where, axis=1, begin=0, end=1)
-        return sym.RotaryEmbedding(x, positions=axes, with_positions=True,
-                                   theta=rope_theta, sections=sections)
+        return dict(how, positions=axes, with_positions=True)
+
+    def rotate(x, sections):
+        return sym.RotaryEmbedding(x, **rotation(sections))
 
     selected = []        # (index loss, selection) a block
 
@@ -130,7 +133,7 @@ def keye_lm(num_layers, hidden_size, num_heads, num_kv_heads, head_dim,
             lambda h: gqa_attention(
                 h, pre, l, seq_len, num_heads, num_kv_heads, head_dim,
                 hidden_size, rms_eps,
-                rotate=lambda t: rotate(t, tuple(mrope_sections)),
+                rotate=rotation(tuple(mrope_sections)),
                 core=lambda q, k, v: indexed(h, pre, l, q, k, v)),
             lambda h: experts(h, pre, l),
             sum_scopes=(scoped("", "attn_proj", l), None), layer=l)

@@ -79,7 +79,7 @@ def lfm2_moe_lm(num_layers, hidden_size, layer_types, dense_layers,
         return gqa_attention(
             h, pre, l, seq_len, num_heads, num_kv_heads, head_dim,
             hidden_size, rms_eps,
-            rotate=lambda t: sym.RotaryEmbedding(t, theta=rope_theta))
+            rotate=dict(theta=rope_theta))
 
     def mlp(h, pre, l):
         if l < dense_layers:

@@ -92,9 +92,6 @@ def ouro_lm(num_layers, hidden_size, num_heads, num_kv_heads, head_dim,
                          % (num_heads, num_kv_heads))
     steps = int(total_ut_steps)
 
-    def rotate(t):
-        return sym.RotaryEmbedding(t, theta=rope_theta)
-
     # one pass: the stack, the norm that closes it, the head and the gate
     h = sym.Variable("loop_rows")
     for l in range(num_layers):
@@ -102,7 +99,8 @@ def ouro_lm(num_layers, hidden_size, num_heads, num_kv_heads, head_dim,
         h = block(h, pre, rms_eps,
                   lambda r: gqa_attention(
                       r, pre, l, seq_len, num_heads, num_kv_heads, head_dim,
-                      hidden_size, rms_eps, rotate=rotate, head_norms=False),
+                      hidden_size, rms_eps, rotate=dict(theta=rope_theta),
+                      head_norms=False),
                   lambda r: swiglu(r, pre, mlp_width, hidden_size, l),
                   post_norms=("attn_post_norm", "ffn_post_norm"), layer=l)
     with scoped("", "lm_head"):

@@ -69,8 +69,7 @@ def sdar_moe_lm(num_layers, hidden_size, num_heads, num_kv_heads, head_dim,
             lambda h: gqa_attention(
                 h, pre, l, rows, num_heads, num_kv_heads, head_dim,
                 hidden_size, rms_eps, mask="block_diffusion", block=block_len,
-                rotate=lambda t: sym.RotaryEmbedding(t, theta=rope_theta,
-                                                     period=seq_len)),
+                rotate=dict(theta=rope_theta, period=seq_len)),
             lambda h: routed_experts(
                 h, pre, l, num_experts, experts_per_tok, expert_width,
                 hidden_size, renormalize=True, score="softmax",

@@ -17,6 +17,7 @@ from . import transformer  # noqa: F401 (registers RMSNorm/RoPE/attention/loss h
 from . import linear_attention  # noqa: F401 (registers the delta-rule ops and CausalConv1D)
 from . import ssd  # noqa: F401 (registers SSDScan, the state-space scan)
 from . import gated_norm  # noqa: F401 (registers GatedRMSNorm, the mixers' output stage)
+from . import head_rotary  # noqa: F401 (registers HeadNormRotary, q's and k's norm and rotation)
 from . import control_flow  # noqa: F401 (registers Repeat, the loop node)
 from . import sparse_attention  # noqa: F401 (registers IndexedSelfAttention)
 

@@ -20,3 +20,22 @@ def nodes(net, op_name):
     import mxnet_tpu as mx
     return [n for n in mx.symbol._topo(net._heads)
             if not n.is_variable and n.op.name == op_name]
+
+
+def placed_on_rows(net):
+    """What stands between q's and k's projections and attention since
+    ISSUE 70: every ``HeadNormRotary`` node as ``(name, scope, the
+    keywords it was made with, its inputs' names)``, in the graph's
+    order, a loop node's body included."""
+    import mxnet_tpu as mx
+    found = []
+    for node in mx.symbol._topo(net._heads):
+        if node.is_variable:
+            continue
+        if node.op.name == "Repeat":
+            found += placed_on_rows(node.params["body"])
+        if node.op.name == "HeadNormRotary":
+            found.append((node.name, node.attrs.get("__scope__"),
+                          dict(node.params),
+                          [i[0].name for i in node.inputs]))
+    return found

@@ -33,6 +33,7 @@ from mxnet_tpu.moe.layer import MoEFeedForward            # noqa: E402
 from mxnet_tpu.ops import transformer as tf_ops           # noqa: E402
 
 import manifest                                           # noqa: E402
+from symbol_signature import nodes, placed_on_rows      # noqa: E402
 
 REF = manifest.load_module("reference", "trinity-mini")
 
@@ -53,9 +54,11 @@ F32, BF16 = jnp.float32, jnp.bfloat16
 # took a third mask (96d8256): under ``block_diffusion`` the mask's choice
 # and the plain blocks lower to what they did.  Taken again at PR 66 (at
 # whose parent it read what it did): the tiny rank's share has no row bound
-# and lowers as the one window ``(0, T*k)`` since
+# and lowers as the one window ``(0, T*k)`` since.  And at ISSUE 70, which
+# meant to move it: q and k pass ``HeadNormRotary``, at these 8-lane heads
+# the old statements between two more reshapes
 SDAR_STEP_TEXT = \
-    "b51fa88041ba63d17a41a5345691f1cd7147054fbc76e26138d1c6c829bd2086"
+    "6d75c6c781731597ec0d91120f5704b2a671389a1ca8191b6a047890fe93c226"
 
 
 def _rel(got, want):
@@ -692,3 +695,27 @@ def test_device_scopes_and_the_lowering_counter_name_both_kinds():
         + ["float32[2, 16, 4, 8]/kv2"]
     assert all(e["args"] == {"kernel": 0, "plain": 1, "pair": "none",
                             "mask_form": "none"} for e in events)
+
+
+# -- ISSUE 70: q's and k's norm and rotation, one node on the rows ---------
+def test_q_and_k_are_placed_by_one_node_on_the_rows():
+    """A sliding layer's q and k are normed and rotated, a full layer's
+    normed and nothing else, each by ONE ``HeadNormRotary`` under
+    ``attn_proj.l<i>`` on the rows as the projection writes them, under
+    the weights' old names."""
+    net = afmoe_lm(**TINY)
+    placed = placed_on_rows(net)
+    assert [(name, scope, ins) for name, scope, _, ins in placed] == [
+        ("l%d_%s_norm" % (l, x), "attn_proj.l%d" % l,
+         ["l%d_%s_proj" % (l, x), "l%d_%s_norm_gamma" % (l, x)])
+        for l in range(TINY["num_layers"]) for x in "qk"]
+    for name, _, how, _ in placed:
+        sliding = TINY["layer_types"][int(name[1])] == "sliding"
+        assert (how["head_dim"], how["norm"], how["seq_len"],
+                how["eps"]) == (TINY["head_dim"], True,
+                                TINY["seq_len"] if sliding else 0,
+                                TINY["rms_eps"])
+        assert not sliding or how["theta"] == TINY["rope_theta"]
+    assert not nodes(net, "RotaryEmbedding")
+    assert not [n for n in nodes(net, "RMSNorm")
+                if n.name[3:] in ("q_norm", "k_norm")]

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "common"))
 sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 
 import jax                                                # noqa: E402
@@ -26,6 +27,7 @@ from mxnet_tpu.ops import transformer as tf_ops           # noqa: E402
 from mxnet_tpu.trace.heads import DSA_SELECT              # noqa: E402
 
 import manifest                                           # noqa: E402
+from symbol_signature import nodes, placed_on_rows      # noqa: E402
 
 REF = manifest.load_module("reference", "keye-vl-2.0-30b-a3b")
 
@@ -390,3 +392,28 @@ def test_reference_flops_count_what_the_selection_keeps():
     whole = {"model": {"kwargs": dict(TINY, topk=64)}}
     assert REF.train_flops_per_sample(whole) > REF.train_flops_per_sample(cfg)
     assert REF.selected_pairs(8192, 2048) == 14_681_088
+
+
+# -- ISSUE 70: q's and k's norm and rotation, one node on the rows ---------
+@pytest.mark.parametrize("positions", [False, True])
+def test_q_and_k_are_placed_by_one_node_on_the_rows(positions):
+    """Every layer's q and k are normed and turned by the three position
+    axes' sections by ONE ``HeadNormRotary`` under ``attn_proj.l<i>``,
+    the ``positions`` input its third where the builder has one; the
+    indexer's 4-D rotations keep their nodes."""
+    net = keye_lm(**dict(TINY, positions=positions))
+    where = ["positions"] * positions
+    placed = placed_on_rows(net)
+    assert [(name, scope, ins) for name, scope, _, ins in placed] == [
+        ("l%d_%s_norm" % (l, x), "attn_proj.l%d" % l,
+         ["l%d_%s_proj" % (l, x), "l%d_%s_norm_gamma" % (l, x)] + where)
+        for l in range(TINY["num_layers"]) for x in "qk"]
+    for _, _, how, _ in placed:
+        assert (how["head_dim"], how["norm"], how["seq_len"], how["theta"],
+                tuple(how["sections"]), bool(how["with_positions"])) == (
+            TINY["head_dim"], True, TINY["seq_len"], TINY["rope_theta"],
+            tuple(TINY["mrope_sections"]), positions)
+    turned = nodes(net, "RotaryEmbedding")
+    assert len(turned) == 2 * TINY["num_layers"]
+    assert {n.attrs["__scope__"].split(".")[0] for n in turned} \
+        == {"dsa_index"}

@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "common"))
 sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 
 import jax                                                # noqa: E402
@@ -29,6 +30,7 @@ from mxnet_tpu.moe.layer import MoEFeedForward            # noqa: E402
 from mxnet_tpu.ops import transformer as tf_ops           # noqa: E402
 
 import manifest                                           # noqa: E402
+from symbol_signature import nodes, placed_on_rows      # noqa: E402
 
 REF = manifest.load_module("reference", "lfm2-8b-a1b")
 
@@ -467,3 +469,41 @@ def test_device_scopes_and_the_lowering_counters_name_both_mixers():
     assert [e["id"] for e in attn] == ["float32[2, 16, 4, 8]/kv2"]
     assert [e["id"] for e in conv] == ["float32[2, 16, 96]/gated32"] * 4
     assert all(e["args"] == {"kernel": 0, "plain": 1} for e in conv)
+
+
+# -- ISSUE 70: q's and k's norm and rotation, one node on the rows ---------
+def test_q_and_k_are_placed_by_one_node_on_the_rows():
+    """The attention layers' q and k are normed and rotated by ONE
+    ``HeadNormRotary`` under ``attn_proj.l<i>`` (at the cell's 64-lane
+    heads the op's plain lowering: ``rotary:lowering`` reads ``plain``
+    for any head that is not 128 lanes)."""
+    net = lfm2_moe_lm(**TINY)
+    attends = [l for l, kind in enumerate(TINY["layer_types"])
+               if kind == "full_attention"]
+    placed = placed_on_rows(net)
+    assert [(name, scope, ins) for name, scope, _, ins in placed] == [
+        ("l%d_%s_norm" % (l, x), "attn_proj.l%d" % l,
+         ["l%d_%s_proj" % (l, x), "l%d_%s_norm_gamma" % (l, x)])
+        for l in attends for x in "qk"]
+    for _, _, how, _ in placed:
+        assert (how["head_dim"], how["norm"], how["seq_len"], how["theta"],
+                how["eps"]) == (TINY["head_dim"], True, TINY["seq_len"],
+                                TINY["rope_theta"], TINY["rms_eps"])
+    assert not nodes(net, "RotaryEmbedding")
+    was = mx.trace.enabled()
+    mx.trace.set_enabled(True)
+    try:
+        mark = time.perf_counter_ns()
+        shape = (BATCH, TINY["seq_len"])
+        args, _, _ = net.infer_shape(data=shape, softmax_label=shape)
+        net.simple_bind(mx.cpu(), grad_req="null", data=shape,
+                        softmax_label=shape).forward()
+        chosen = mx.trace.counter_events(["rotary:lowering"], since_ns=mark)
+    finally:
+        mx.trace.set_enabled(was)
+    rows = BATCH * TINY["seq_len"]
+    assert [(e["id"], e["args"]) for e in chosen[:2]] == [
+        ("float32[%d, %d]/%d" % (rows, n * TINY["head_dim"],
+                                 TINY["head_dim"]),
+         {"kernel": 0, "plain": 1})
+        for n in (TINY["num_heads"], TINY["num_kv_heads"])]

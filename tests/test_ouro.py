@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "common"))
 sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 
 import jax                                                # noqa: E402
@@ -24,6 +25,7 @@ from mxnet_tpu.models.ouro import exit_objective          # noqa: E402
 from mxnet_tpu.symbol import _topo                        # noqa: E402
 
 import manifest                                           # noqa: E402
+from symbol_signature import nodes, placed_on_rows      # noqa: E402
 
 REF = manifest.load_module("reference", "ouro-2.6b")
 
@@ -362,3 +364,26 @@ def test_fit_feeds_loop_exit_once_a_step_and_the_bind_loop_body():
         for b in bodies)
     # the body's nodes: what one pass is made of, not four
     assert bodies[0]["args"]["nodes"] < 60
+
+
+# -- ISSUE 70: q's and k's rotation, one node on the rows ------------------
+@pytest.mark.parametrize("kv_heads", [4, 2])
+def test_q_and_k_are_placed_by_one_node_on_the_rows(kv_heads):
+    """This model has no head norms: every layer of the loop's body
+    rotates q and k by ONE ``HeadNormRotary`` (``norm`` off, no weight)
+    under ``attn_proj.l<i>``, with equal heads as with grouped ones (one
+    rule, by the head's width: the traced pair of ``PERF.md`` section 6,
+    PR 70, reads the cell +4.1 % with the node)."""
+    net = ouro_lm(**dict(TINY, num_kv_heads=kv_heads))
+    placed = placed_on_rows(net)
+    assert [(name, scope, ins) for name, scope, _, ins in placed] == [
+        ("l%d_%s_rotary" % (l, x), "attn_proj.l%d" % l,
+         ["l%d_%s_proj" % (l, x)])
+        for l in range(TINY["num_layers"]) for x in "qk"]
+    for _, _, how, _ in placed:
+        assert (how["head_dim"], how["norm"], how["seq_len"],
+                how["theta"]) == (TINY["head_dim"], False, TINY["seq_len"],
+                                  TINY["rope_theta"])
+    body = [n for n in _topo(net._heads)
+            if not n.is_variable and n.op.name == "Repeat"][0].params["body"]
+    assert not nodes(body, "RotaryEmbedding")

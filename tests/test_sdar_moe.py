@@ -31,6 +31,7 @@ from mxnet_tpu.trace.heads import DIFFUSION_NOISE, MTP_LOSS   # noqa: E402
 from mxnet_tpu.ops import transformer as tf_ops           # noqa: E402
 
 import manifest                                           # noqa: E402
+from symbol_signature import nodes, placed_on_rows      # noqa: E402
 
 REF = manifest.load_module("reference", "sdar-30b-a3b")
 GEN = manifest.load_module("generators", "token_block_noised")
@@ -742,3 +743,31 @@ def test_device_scopes_name_the_blocks_parts():
     for scope in ("attn_proj.l0", "attn.l1", "moe_experts.l1",
                   "moe_route.l0", "moe_combine.l1", "lm_loss"):
         assert scope + "/" in text or scope + '"' in text, scope
+
+
+# -- ISSUE 70: q's and k's norm and rotation, one node on the rows ---------
+def test_q_and_k_are_placed_by_one_node_on_the_rows():
+    """Every layer's q and k leave their projections through ONE
+    ``HeadNormRotary`` under ``attn_proj.l<i>``, the rows of both copies
+    at positions ``n mod T``; the weights are still ``l<i>_{q,k}_norm_gamma``
+    of a head's width and no ``RMSNorm`` or ``RotaryEmbedding`` stands
+    over the heads."""
+    net = sdar_moe_lm(**TINY)
+    rows = 2 * TINY["seq_len"]
+    placed = placed_on_rows(net)
+    assert [(name, scope, ins) for name, scope, _, ins in placed] == [
+        ("l%d_%s_norm" % (l, x), "attn_proj.l%d" % l,
+         ["l%d_%s_proj" % (l, x), "l%d_%s_norm_gamma" % (l, x)])
+        for l in range(TINY["num_layers"]) for x in "qk"]
+    for _, _, how, _ in placed:
+        assert (how["head_dim"], how["norm"], how["seq_len"], how["period"],
+                how["theta"], how["eps"]) == (
+            TINY["head_dim"], True, rows, TINY["seq_len"],
+            TINY["rope_theta"], TINY["rms_eps"])
+    shapes = dict(zip(net.list_arguments(), net.infer_shape(
+        data=(BATCH, rows), softmax_label=(BATCH, 2, TINY["seq_len"]))[0]))
+    assert shapes["l1_q_norm_gamma"] == shapes["l1_k_norm_gamma"] \
+        == (TINY["head_dim"],)
+    assert not nodes(net, "RotaryEmbedding")
+    assert not [n for n in nodes(net, "RMSNorm") if "_norm" in n.name
+                and n.name[3:] in ("q_norm", "k_norm")]

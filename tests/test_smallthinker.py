@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "common"))
 sys.path.insert(0, os.path.join(ROOT, "benchmark"))
 
 import jax                                                # noqa: E402
@@ -33,6 +34,7 @@ from mxnet_tpu.moe.dispatch import held_rows_bound        # noqa: E402
 from mxnet_tpu.trace.heads import MOE_ACT_ZEROS           # noqa: E402
 
 import manifest                                           # noqa: E402
+from symbol_signature import nodes, placed_on_rows      # noqa: E402
 
 REF = manifest.load_module("reference", "smallthinker-21b-a3b")
 share_rule = sys.modules["mxnet_tpu.moe.dispatch"]
@@ -58,8 +60,10 @@ F32 = jnp.float32
 STEP_TEXT_WAS = {
     "olmoe":
         "0eeb7a8c80320f85d5aeb07cc83d53e328f9fa006d09ca4ae1083936719a3524",
+    # taken again at ISSUE 70, as tests/test_decoder_symbols.py's: q and
+    # k pass ``HeadNormRotary``
     "afmoe":
-        "76ff959066a5b7e3909615e0613827be95b09e7afaa1c6227c7527dd644113c4"}
+        "9983d99f54b79b1d772042fc37085d6a0b2a292a58ad0f098d0f62407f09a0f4"}
 
 
 def _rel(got, want):
@@ -783,3 +787,25 @@ def test_reference_flops_are_the_hand_count():
         {"model": {"kwargs": dict(kwargs, seq_len=4096)}}) \
         == REF.train_flops_per_sample({"model": {"kwargs": dict(
             kwargs, seq_len=4096, layer_types=["full"] * 4)}})
+
+
+# -- ISSUE 70: q's and k's norm and rotation, one node on the rows ---------
+def test_q_and_k_are_placed_by_one_node_on_the_rows():
+    """This model has no head norms: a sliding layer's q and k are
+    rotated by ONE ``HeadNormRotary`` (``norm`` off, no weight) under
+    ``attn_proj.l<i>``; the full layer, which has no positions either,
+    has no node between its projections and attention."""
+    net = smallthinker_lm(**TINY)
+    sliding = [l for l, kind in enumerate(TINY["layer_types"])
+               if kind == "sliding"]
+    placed = placed_on_rows(net)
+    assert [(name, scope, ins) for name, scope, _, ins in placed] == [
+        ("l%d_%s_rotary" % (l, x), "attn_proj.l%d" % l,
+         ["l%d_%s_proj" % (l, x)]) for l in sliding for x in "qk"]
+    for _, _, how, _ in placed:
+        assert (how["head_dim"], how["norm"], how["seq_len"],
+                how["theta"]) == (TINY["head_dim"], False, TINY["seq_len"],
+                                  TINY["rope_theta"])
+    assert not nodes(net, "RotaryEmbedding")
+    assert not [a for a in net.list_arguments() if a.endswith("norm_gamma")
+                and a[3:] in ("q_norm_gamma", "k_norm_gamma")]

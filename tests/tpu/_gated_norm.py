@@ -105,10 +105,12 @@ def check_pair(report):
     assert kernel["bwd_ms"] < plain["bwd_ms"]
 
 
-def compiled_step_text(config):
+def compiled_step_text(config, inputs=None):
     """The text of a cell's fused step (its configuration's builder,
     arguments, optimizer and compute dtype, one sequence a step) compiled
-    for the chip from shapes alone: nothing is bound or held."""
+    for the chip from shapes alone: nothing is bound or held.
+    ``inputs``: the shapes of ``data`` and ``softmax_label`` where they
+    are not ``(1, seq_len)``."""
     import jax
     import jax.numpy as jnp
     import mxnet_tpu as mx
@@ -120,7 +122,7 @@ def compiled_step_text(config):
     kwargs = cfg["model"]["kwargs"]
     net = getattr(importlib.import_module(module), name)(**kwargs)
     shape = (1, kwargs["seq_len"])
-    inputs = {"data": shape, "softmax_label": shape}
+    inputs = inputs or {"data": shape, "softmax_label": shape}
     arg_shapes, _, aux_shapes = net.infer_shape(**inputs)
     shapes = dict(zip(net.list_arguments(), arg_shapes))
     params = [n for n in shapes if n not in inputs]
@@ -128,7 +130,7 @@ def compiled_step_text(config):
         net, [mx.tpu(0)], ["data"], ["softmax_label"], params, [],
         mx.optimizer.create(cfg["optimizer"]["name"],
                             **cfg["optimizer"]["params"]),
-        label_shapes=[("softmax_label", shape)],
+        label_shapes=[("softmax_label", inputs["softmax_label"])],
         compute_dtype=cfg["compute_dtype"])
 
     def arr(s, dtype=jnp.float32):
@@ -147,11 +149,13 @@ def compiled_step_text(config):
         state, batch, arr(()), key).compile().as_text()
 
 
-def head_layout_copies(text, dtype="f32"):
+def head_layout_copies(text, dtype="f32", heads=32, scope=""):
     """The entry computation's ``copy`` and ``reshape`` operations that
-    write a ``<dtype>[.., 32, 128]`` array: a relayout between rows with
-    the tokens on the sublanes and heads on the sublanes."""
+    write a ``<dtype>[.., heads, 128]`` array: a relayout between rows
+    with the tokens on the sublanes and heads on the sublanes.
+    ``scope``: only those whose ``op_name`` holds it."""
     entry = text[text.index("ENTRY "):]
     return [line.strip()[:200] for line in entry.splitlines() if re.match(
         r"\s*(?:ROOT )?%?[\w.-]+ = " + dtype
-        + r"\[[0-9,]*32,128\]\S* (copy|reshape)\(", line)]
+        + r"\[[0-9,]*%d,128\]\S* (copy|reshape)\(" % heads, line)
+        and scope in line]

@@ -20,6 +20,14 @@ anything is asserted.
 The second holds ``causal_attention``'s TPU kernel under the block mask
 against its plain blocks at the cell's shape, ``(1, 8192, 32, 128)``
 over 4 key/value heads in blocks of 4, with both lowerings' times.
+
+The third (ISSUE 70) holds q's and k's norm and rotation as the kernel
+pair ``head_rotary_fwd`` / ``head_rotary_bwd`` alone at this cell's
+``(8192, 4096)`` and ``(8192, 512)`` rows, both copies at positions ``n
+mod 4096``, against the plain form and against its bytes, and the cell's
+step compiled for the chip, whose ``attn_proj`` part holds no relayout
+of a ``[.., 32, 128]`` or ``[.., 4, 128]`` array
+(``chiprun_out/sdar_rotary_parity.json``).
 """
 import gc
 import json
@@ -29,6 +37,7 @@ import time
 
 import numpy as np
 
+import _head_rotary
 from _mirror import tpu_gate
 
 pytestmark = [tpu_gate()]
@@ -301,3 +310,38 @@ def test_attention_kernel_matches_plain_blocks_under_the_block_mask():
     assert np.array_equal(moved[:, :12], got[0][:, :12])
     assert not np.array_equal(moved[:, 12:16], got[0][:, 12:16])
     assert not np.array_equal(moved[:, at], got[0][:, at])
+
+
+def test_head_rotary_kernels_at_the_cells_rows_and_the_steps_relayouts():
+    """``head_norm_rotary`` at the cell's q and k rows, ``(8192, 4096)``
+    and ``(8192, 512)`` bfloat16, the noised and the clean copy at the
+    same positions: the kernel pair compiled by Mosaic is no further from
+    the plain form in float32 than the plain form in bfloat16 is, each
+    pass under ``BYTES_TIMES`` its bytes' time and under the plain
+    form's.  Then the cell's step compiled for the chip: four layers' q
+    and k are eight calls of each kernel, and no ``copy`` or ``reshape``
+    under ``attn_proj`` writes a head-form array, nor does any float32
+    result of the entry computation have q's head form (23 of ``[1, 8192,
+    32, 128]`` in the parent's step compiled for a described v5e)."""
+    import jax
+    report = {"device": jax.devices()[0].device_kind, "pairs": [
+        _head_rotary.pair_against_the_plain_form(
+            width, 1e-6, theta=1e6, period=4096)
+        for width in _head_rotary.WIDTHS]}
+    print("\nROTARY_KERNEL_PARITY " + json.dumps(report["pairs"]),
+          flush=True)
+    report["step"] = _head_rotary.head_form_in_attn_proj(
+        "sdar-30b-a3b", {"data": (1, 8192), "softmax_label": (1, 2, 4096)})
+    print("ROTARY_STEP " + json.dumps(report["step"]), flush=True)
+    out_dir = os.path.join(ROOT, "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, "sdar_rotary_parity.json"), "w") as f:
+        json.dump(report, f, indent=1)
+    for pair in report["pairs"]:
+        _head_rotary.check_pair(pair)
+    assert report["step"]["calls"] == [8, 8]
+    assert report["step"]["lowering"] == [
+        ["bfloat16[8192, %d]/128" % width, 1]
+        for width in _head_rotary.WIDTHS] * 4
+    assert not report["step"]["head_layout_copies"]
+    assert not report["step"]["float32_head_form"]

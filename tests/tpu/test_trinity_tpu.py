@@ -24,6 +24,14 @@ The second holds ``causal_attention``'s TPU kernel under the window
 against its plain blocks at the cell's shape, ``(1, 4096, 32, 128)`` over
 4 key/value heads under a window of 2048, with both lowerings' times and
 the kernel's at tiles of 512.
+
+The third (ISSUE 70) holds q's and k's norm and rotation as the kernel
+pair ``head_rotary_fwd`` / ``head_rotary_bwd`` alone at ``(8192, 4096)``
+and ``(8192, 512)`` against the plain form and against its bytes (the
+norm with this cell's rotation, and the full layer's norm alone), and the
+cell's step compiled for the chip, whose ``attn_proj`` part holds no
+relayout of a ``[.., 32, 128]`` or ``[.., 4, 128]`` array
+(``chiprun_out/trinity_rotary_parity.json``).
 """
 import gc
 import json
@@ -33,6 +41,7 @@ import time
 
 import numpy as np
 
+import _head_rotary
 from _mirror import tpu_gate
 
 pytestmark = [tpu_gate()]
@@ -336,3 +345,36 @@ def test_attention_kernel_matches_plain_blocks_under_the_window():
     assert np.array_equal(moved[:, :100], got[0][:, :100])
     assert np.array_equal(moved[:, 2151:], got[0][:, 2151:])
     assert not np.array_equal(moved[:, 100:2151], got[0][:, 100:2151])
+
+
+def test_head_rotary_kernels_at_the_cells_widths_and_the_steps_relayouts():
+    """``head_norm_rotary`` at 32 and 4 heads of 128 over 8192 bfloat16
+    rows, the sliding layers' norm and rotation and the full layer's norm
+    alone: the kernel pair compiled by Mosaic is no further from the
+    plain form in float32 than the plain form in bfloat16 is, output and
+    both cotangents, each pass under ``BYTES_TIMES`` its bytes' time and
+    under the plain form's.  Then the cell's step compiled for the chip:
+    five layers' q and k are ten calls of each kernel, and no ``copy`` or
+    ``reshape`` under ``attn_proj`` writes a head-form array, nor has any
+    float32 result of the entry computation q's head form."""
+    import jax
+    report = {"device": jax.devices()[0].device_kind, "pairs": [
+        _head_rotary.pair_against_the_plain_form(width, 1e-5, **rotation)
+        for width in _head_rotary.WIDTHS
+        for rotation in (dict(theta=1e4), {})]}
+    print("\nROTARY_KERNEL_PARITY " + json.dumps(report["pairs"]),
+          flush=True)
+    report["step"] = _head_rotary.head_form_in_attn_proj("trinity-mini")
+    print("ROTARY_STEP " + json.dumps(report["step"]), flush=True)
+    out_dir = os.path.join(ROOT, "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, "trinity_rotary_parity.json"), "w") as f:
+        json.dump(report, f, indent=1)
+    for pair in report["pairs"]:
+        _head_rotary.check_pair(pair)
+    assert report["step"]["calls"] == [10, 10]
+    assert report["step"]["lowering"] == [
+        ["bfloat16[4096, %d]/128" % width, 1]
+        for width in _head_rotary.WIDTHS] * 5
+    assert not report["step"]["head_layout_copies"]
+    assert not report["step"]["float32_head_form"]
