@@ -10,7 +10,7 @@
 # ---- symbol operators (registry) ----
 
 #' reference activation-inl.h:182.
-#' @param act_type act_type parameter (required; one of relu, sigmoid, tanh, softrelu, silu)
+#' @param act_type act_type parameter (required; one of relu, sigmoid, tanh, softrelu, silu, relu2)
 #' @param name string, node name (auto when omitted)
 #' @return MXSymbol
 mx.symbol.Activation <- function(...) {
@@ -46,7 +46,7 @@ mx.symbol.Cast <- function(...) {
 #' Depthwise causal convolution over time of ``(B, T, C)``: a ``kernel``-tap filter a channel
 #' @param kernel kernel parameter (default 4)
 #' @param lanes lanes parameter
-#' @param act_type act_type parameter (one of relu, sigmoid, tanh, softrelu, silu)
+#' @param act_type act_type parameter (one of relu, sigmoid, tanh, softrelu, silu, relu2)
 #' @param gated gated parameter
 #' @param no_bias no_bias parameter
 #' @param name string, node name (auto when omitted)
@@ -340,6 +340,7 @@ mx.symbol.Pooling <- function(...) {
 
 #' Root-mean-square LayerNorm over the last axis (Zhang & Sennrich
 #' @param eps eps parameter (default 1e-05)
+#' @param groups groups parameter
 #' @param name string, node name (auto when omitted)
 #' @return MXSymbol
 mx.symbol.RMSNorm <- function(...) {

@@ -25,6 +25,7 @@ from .ouro import ouro_lm
 from .keye_vl import keye_lm
 from .lfm2_moe import lfm2_moe_lm
 from .granite_hybrid import granite_hybrid_lm
+from .nemotron_h import nemotron_h_lm
 from .gru import gru_unroll, gru_cell, rnn_unroll, rnn_cell, GRUState, \
     GRUParam, RNNState, RNNParam
 
@@ -37,5 +38,6 @@ __all__ = ["get_mlp", "get_lenet", "get_resnet", "get_resnet50",
            "get_rpn", "olmoe_lm", "kimi_linear_lm", "glm_moe_lite_lm",
            "sdar_moe_lm", "afmoe_lm", "smallthinker_lm", "qwen3_next_lm",
            "ouro_lm", "keye_lm", "lfm2_moe_lm", "granite_hybrid_lm",
+           "nemotron_h_lm",
            "gru_unroll", "gru_cell", "rnn_unroll",
            "rnn_cell", "GRUState", "GRUParam", "RNNState", "RNNParam"]

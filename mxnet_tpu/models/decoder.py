@@ -1,10 +1,12 @@
 """The decoder skeleton: what the LM builders of this package share,
 written once.  A decoder is ``embed`` -> L x ``block`` -> ``lm_head_loss``
 over ``(B*T, D)`` rows; its builder says which mixer (``gqa_attention``,
-``latent_attention``, its own) and which MLP (``swiglu``,
-``routed_experts``) a layer gets, as functions of the normed rows, and its
-head: a further decoder is one builder file over this one, a configuration,
-a reference and its tests.  Unnamed nodes are numbered in the order they
+``mamba_mixer``, ``latent_attention``, its own) and which MLP (``swiglu``,
+``routed_experts``, gated or plain) a layer gets, as functions of the
+normed rows, and its head; a model whose layers are ONE branch each (a
+norm, a mixer OR an expert layer, a sum: Nemotron-H) stacks
+``one_branch_block``.  A further decoder is one builder file over this one,
+a configuration, a reference and its tests.  Unnamed nodes are numbered in the order they
 are made and a node takes the attribute scope it is made in, so the order
 of the statements here is part of every builder's symbol, which
 ``tests/test_decoder_symbols.py`` holds by its hash.
@@ -25,8 +27,12 @@ from ..attribute import AttrScope
 from ..moe.layer import MoEFeedForward
 
 
-def norm(x, name, eps):
-    return sym.RMSNorm(x, eps=eps, name=name)
+def norm(x, name, eps, groups=0):
+    """``RMSNorm`` with a gain; ``groups`` > 0: the statistic over each of
+    that many equal parts of the lanes (said only where it is so: an unset
+    parameter is not in a node's JSON)."""
+    return sym.RMSNorm(x, eps=eps, name=name,
+                       **({"groups": groups} if groups else {}))
 
 
 def proj(x, name, width, bias=False, **inputs):
@@ -78,9 +84,11 @@ def swiglu(h, pre, width, hidden_size, layer=-1, scope=""):
 
 
 def routed_experts(h, pre, layer, num_experts, experts_per_tok, expert_width,
-                   hidden_size=0, act_type="silu", **router):
+                   hidden_size=0, act_type="silu", gated=True, **router):
     """The routed expert layer ``pre + "moe"``: gated experts (SwiGLU;
-    ReGLU with ``act_type="relu"``), no bias, no token-choice dropped.
+    ReGLU with ``act_type="relu"``) or, ``gated`` off, plain ones of two
+    matrices (``act(x W1) W2``; the squared ReLU is ``act_type="relu2"``),
+    a shared expert in the same form; no bias, no token-choice dropped.
     ``layer`` < 0: no trace index.  ``hidden_size``: the output's width,
     which a shared expert needs said (0: the input's, left to the op).
     ``router``: the router's kind, the rows it reads where they are not
@@ -89,7 +97,7 @@ def routed_experts(h, pre, layer, num_experts, experts_per_tok, expert_width,
     return MoEFeedForward(
         h, num_hidden=expert_width, num_experts=num_experts,
         k=experts_per_tok, capacity_factor=0.0, name=pre + "moe",
-        act_type=act_type, gated=True, no_bias=True,
+        act_type=act_type, gated=gated, no_bias=True,
         layer=None if layer < 0 else layer, output_dim=hidden_size, **router)
 
 
@@ -164,6 +172,43 @@ def gqa_attention(h, pre, layer, rows, num_heads, num_kv_heads, head_dim,
         return proj(a, pre + "o_proj", hidden_size)
 
 
+def mamba_mixer(h, pre, layer, rows, hidden_size, heads, head_dim, state,
+                groups, conv_kernel, eps, norm_groups=0):
+    """The Mamba-2 mixer (arXiv:2405.21060), ``(B*rows, D)`` -> ``(B*rows,
+    D)``: ``[z | xBC | dt] = h W_in`` (``heads * head_dim`` | that + ``2
+    groups state`` | ``heads`` wide, in that order); ``xBC = silu(conv(xBC)
+    + b)``, a depthwise causal convolution of ``conv_kernel`` taps with a
+    bias; ``[x | B | C] = xBC``, ``B`` and ``C`` in ``groups`` groups of
+    ``state`` lanes, head ``j`` reading group ``j // (heads / groups)``;
+    the scan ``SSDScan`` (``ops/ssd.py``); ``y = RMSNorm(y * silu(z))``
+    with one gain over all lanes, the statistic over all of them or, with
+    ``norm_groups``, over each of that many equal parts; ``y W_out``.  No
+    projection bias.  Scopes: ``ssm_proj.l<i>`` (both projections),
+    ``ssm_conv.l<i>``, the scan's own ``ssm_scan.l<i>``, ``ssm_norm.l<i>``
+    (the gate and the norm)."""
+    inner, bc = heads * head_dim, groups * state
+    with scoped("", "ssm_proj", layer):
+        z, xbc, dt = cut(proj(h, pre + "in_proj", 2 * inner + 2 * bc + heads),
+                         1, inner, inner + 2 * bc, heads)
+    with scoped("", "ssm_conv", layer):
+        xbc = sym.CausalConv1D(
+            sym.Reshape(xbc, shape=(-1, rows, inner + 2 * bc)),
+            kernel=conv_kernel, act_type="silu", no_bias=False,
+            name=pre + "conv")
+        x, b, c = (sym.Reshape(part, shape=(-1, rows, n, lanes))
+                   for part, n, lanes in zip(
+                       cut(xbc, 2, inner, bc, bc), (heads, groups, groups),
+                       (head_dim, state, state)))
+    y = sym.SSDScan(x, b, c, sym.Reshape(dt, shape=(-1, rows, heads)),
+                    layer=layer, name=pre + "ssm")
+    with scoped("", "ssm_norm", layer):
+        y = norm(sym.Reshape(y, shape=(-1, inner))
+                 * sym.Activation(z, act_type="silu"),
+                 pre + "ssm_norm", eps, norm_groups)
+    with scoped("", "ssm_proj", layer):
+        return proj(y, pre + "out_proj", hidden_size)
+
+
 LAYER_KINDS = ("sliding", "full")
 
 
@@ -220,6 +265,18 @@ def block(x, pre, eps, mixer, mlp, mixer_norm="attn_norm",
         with scope or scoped("", "residual", layer):
             x = x + y
     return x
+
+
+def one_branch_block(x, pre, eps, branch, layer=-1):
+    """One residual layer of ONE branch: ``x + branch(norm(x))``, the norm
+    ``pre + "norm"`` under ``block_norm.l<layer>``, the sum under
+    ``residual.l<layer>``.  ``branch`` is a function of the normed rows: a
+    mixer or an MLP, whichever the layer is."""
+    with scoped("", "block_norm", layer):
+        h = norm(x, pre + "norm", eps)
+    y = branch(h)
+    with scoped("", "residual", layer):
+        return x + y
 
 
 def lm_head_loss(x, vocab_size, eps, label=None, head_weight=None,

@@ -51,7 +51,7 @@ Device scopes: ``ssm_proj.l<i>`` (a mixer's in- and out-projection),
 """
 from .. import symbol as sym
 from .decoder import (block, cut, embed, gqa_attention, layer_kinds,
-                      lm_head_loss, norm, proj, scoped)
+                      lm_head_loss, mamba_mixer, proj, scoped)
 
 MIXER_KINDS = ("mamba", "attention")
 
@@ -67,36 +67,12 @@ def granite_hybrid_lm(num_layers, hidden_size, layer_types, ssm_heads,
         raise ValueError("%d query heads over %d key/value heads, %d "
                          "state-space heads over %d groups"
                          % (num_heads, num_kv_heads, ssm_heads, ssm_groups))
-    inner, bc = ssm_heads * ssm_head_dim, ssm_groups * ssm_state
-
-    def ssm(h, pre, l):
-        with scoped("", "ssm_proj", l):
-            z, xbc, dt = cut(proj(h, pre + "in_proj",
-                                  2 * inner + 2 * bc + ssm_heads), 1,
-                             inner, inner + 2 * bc, ssm_heads)
-        with scoped("", "ssm_conv", l):
-            xbc = sym.CausalConv1D(
-                sym.Reshape(xbc, shape=(-1, seq_len, inner + 2 * bc)),
-                kernel=conv_kernel, act_type="silu", no_bias=False,
-                name=pre + "conv")
-            x, b, c = (sym.Reshape(part, shape=(-1, seq_len, n, lanes))
-                       for part, n, lanes in zip(
-                           cut(xbc, 2, inner, bc, bc),
-                           (ssm_heads, ssm_groups, ssm_groups),
-                           (ssm_head_dim, ssm_state, ssm_state)))
-        y = sym.SSDScan(x, b, c,
-                        sym.Reshape(dt, shape=(-1, seq_len, ssm_heads)),
-                        layer=l, name=pre + "ssm")
-        with scoped("", "ssm_norm", l):
-            y = norm(sym.Reshape(y, shape=(-1, inner))
-                     * sym.Activation(z, act_type="silu"),
-                     pre + "ssm_norm", rms_eps)
-        with scoped("", "ssm_proj", l):
-            return proj(y, pre + "out_proj", hidden_size)
 
     def mixer(h, pre, l, kind):
         if kind == "mamba":
-            y = ssm(h, pre, l)
+            y = mamba_mixer(h, pre, l, seq_len, hidden_size, ssm_heads,
+                            ssm_head_dim, ssm_state, ssm_groups, conv_kernel,
+                            rms_eps)
         else:
             y = gqa_attention(
                 h, pre, l, seq_len, num_heads, num_kv_heads, head_dim,

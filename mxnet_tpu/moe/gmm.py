@@ -38,7 +38,9 @@ backward pass are two module-level ``jax.jit`` functions free of
 per-call objects (``_forward``, ``_backward``): a process traces each
 once a signature, choice and kernels with it, and a program holds each
 once for any number of layers.  The counter ``moe:gmm_trace`` fires
-where a kernel is traced.  A third kernel, ``token-sum``: the file's end.
+where a kernel is traced, ``moe:gmm_lowering`` once a traced product with
+the choice made for it (``tiled_matmul``).  A third kernel,
+``token-sum``: the file's end.
 """
 from __future__ import annotations
 
@@ -92,13 +94,36 @@ def _divisor(x: int, most: int) -> int:
                  if x % t == 0), 0)
 
 
+def _blocks(x: int, most: int, size: int):
+    """The tiles ``tiles_for`` tries along a ``K`` or ``N`` of ``x``
+    elements of ``size`` bytes: ``_divisor``'s alone where that is one or
+    two steps (every shape tuned so far), and in float32, which only
+    parity tests run on a chip and which keeps the tiles it had; else
+    all of ``x`` as ONE block, which Pallas takes for any multiple of 64
+    lanes (a block equal to the array's dimension is legal), and
+    ``_divisor``'s three steps or more behind it."""
+    t = _divisor(x, most)
+    if t and (x // t <= 2 or size > 2):
+        return [t]
+    return [x] * (x % 64 == 0) + [t] * bool(t)
+
+
+def _tgmm_vmem_bytes(tk: int, tn: int, size: int) -> int:
+    """What ``tgmm``, the fuller kernel, holds in VMEM at tiles
+    ``(ROW_TILE, tk, tn)`` of ``size``-byte elements: both inputs' row
+    tiles and the output's ``(tk, tn)`` block twice (Pallas
+    double-buffers), and the float32 accumulator."""
+    return 2 * ROW_TILE * (tk + tn) * size + tk * tn * (2 * size + 4)
+
+
 def tiles_for(m: int, k: int, n: int, e: int, dtype
               ) -> Optional[Tuple[int, int, int]]:
     """``(tm, tk, tn)`` for ``(M, K)`` rows against ``E`` matrices
     ``(K, N)`` (for ``tgmm``: the ``(K, N)`` of its output), or None
     where the kernels do not run and ``ragged_dot`` stays: rows that are
     no whole number of row tiles, a ``K`` or ``N`` that is no whole
-    number of 128-lane tiles, any dtype but bfloat16 and float32.
+    number of 64-lane half tiles or whose blocks no VMEM holds, any dtype
+    but bfloat16 and float32.
 
     ``tm`` depends on nothing but ``M``, so that one ``GroupTiles``
     serves every product of a layer.  The chip numbers behind each
@@ -125,18 +150,38 @@ def tiles_for(m: int, k: int, n: int, e: int, dtype
       ``gmm`` and 40 in ``tgmm``: hence ``VMEM_LIMIT``.  The library's
       kernels at 512 x 1024 x 1024 (the most Mosaic's 16 MiB allow
       them): 3.95 / 3.73 / 4.00.
+    * **A dimension ``_divisor`` cannot cut, or cuts in three steps or
+      more, is ONE block** (``_blocks``; PR 71, Nemotron-H's plain
+      experts: ``N`` = 1856 = 14.5 x 128 had no tile at all and fell to
+      ``ragged_dot``, ``K`` = 2688 = 3 x 896 would fetch a group's
+      weights again at every visit) where ``tgmm``'s blocks, the fuller
+      kernel's, stay under ``VMEM_LIMIT`` less an eighth
+      (``_tgmm_vmem_bytes``; bfloat16 2688 x 1856: 42.5 MiB).  Of the
+      pairs that fit, the one with the fewest grid steps along ``K`` and
+      ``N`` together: 2688 x 3712, which fits in no one block, is 896 x
+      3712 (3 steps) and not 2688 x 128 (29).  On the chip (my chip runs,
+      PR 71; 8 groups of 150-230 rows in a window of 6144, ms): the up
+      projection's three products 1.51 (forward alone 0.60) against
+      ``ragged_dot``'s 4.59 (1.60), the down projection's 0.90 (0.31)
+      against 4.42 (1.34), outputs and both gradients ``ragged_dot``'s
+      bit for bit (``tests/tpu/test_nemotron_h_tpu.py``).  That is the
+      kernels alone: round them, the state holds such a weight with its
+      OTHER dimension on the lanes and the step pays 24 transposing
+      copies, 12.2 ms, for it (``docs/moe.md``).
     * ``E`` enters no choice yet: one expert-parallel rank's share
       (Kimi: 1 024 of 32 768 rows in 8 groups; GLM: 2 800 of 16 384) is
       a launch and the weights' fetch whatever ``tm``, as
       ``ragged_dot``'s is, and both cells gain with OLMoE's tiles.
     """
-    if dtype not in (jnp.bfloat16, jnp.float32):
+    if dtype not in (jnp.bfloat16, jnp.float32) or m % ROW_TILE:
         return None
-    most = 2048 if dtype == jnp.bfloat16 else 1024
-    tk, tn = _divisor(k, most), _divisor(n, most)
-    if m % ROW_TILE or not tk or not tn:
+    most, size = (2048, 2) if dtype == jnp.bfloat16 else (1024, 4)
+    fits = [(tk, tn) for tk in _blocks(k, most, size)
+            for tn in _blocks(n, most, size)
+            if _tgmm_vmem_bytes(tk, tn, size) <= VMEM_LIMIT // 8 * 7]
+    if not fits:
         return None
-    return ROW_TILE, tk, tn
+    return (ROW_TILE,) + min(fits, key=lambda t: (k // t[0]) * (n // t[1]))
 
 
 # lint: allow(raw-jit) — as _forward below: a jit inside the step program,
@@ -420,6 +465,18 @@ def _two_lowerings_bwd(interpret, res, g):
 _two_lowerings.defvjp(_two_lowerings_fwd, _two_lowerings_bwd)
 
 
+def _note_lowering(rows, w, kernel: bool):
+    """``moe:gmm_lowering``: one sample a traced grouped product, track
+    ``<dtype>[rows] x [E, K, N]``; ``kernel`` 1 where its TPU lowering is
+    the kernel pair (a CPU program holds ``ragged_dot`` all the same),
+    ``plain`` 1 where ``ragged_dot`` stays on every platform: a dtype or
+    width ``tiles_for`` refuses, rows that are no whole tiles, a mesh."""
+    trace.counter("moe:gmm_lowering", cat="ops",
+                  track="%s[%d] x %s" % (rows.dtype.name, rows.shape[0],
+                                         list(w.shape)),
+                  kernel=int(kernel), plain=int(not kernel))
+
+
 def tiled_matmul(rows, w, group_sizes, interpret: bool = False):
     """``dispatch.grouped_matmul``'s body.  ``group_sizes`` is a layer's
     ``GroupTiles`` or plain sizes (then the visits are made here, for
@@ -430,8 +487,10 @@ def tiled_matmul(rows, w, group_sizes, interpret: bool = False):
     e, k, n = w.shape
     # what tiles_for refuses it refuses with K and N swapped too, so one
     # question covers the backward-data product
-    if isinstance(tiles, GroupTiles) and rows.dtype == w.dtype \
-            and tiles_for(rows.shape[0], k, n, e, rows.dtype):
+    kernel = isinstance(tiles, GroupTiles) and rows.dtype == w.dtype \
+        and bool(tiles_for(rows.shape[0], k, n, e, rows.dtype))
+    _note_lowering(rows, w, kernel)
+    if kernel:
         return _two_lowerings(rows, w, tiles, interpret)
     return ragged_matmul(rows, w, getattr(tiles, "sizes", tiles))
 

@@ -46,7 +46,7 @@ from ..base import MXNetError, _AttrDict
 from ..moe.router import drop_free
 from .registry import OpDef, Param, register_op
 
-_ACTS = ["relu", "tanh", "sigmoid", "softrelu", "identity", "silu"]
+_ACTS = ["relu", "tanh", "sigmoid", "softrelu", "identity", "silu", "relu2"]
 
 
 def _act(name):

@@ -30,10 +30,16 @@ def _conv_out(x, k, s, p, d=1):
     return (x + 2 * p - eff) // s + 1
 
 
+def _relu2(x):
+    """The squared ReLU, ``relu(x) ** 2``: an exact zero, with a zero
+    gradient, wherever the ReLU has one."""
+    return jnp.square(jax.nn.relu(x))
+
+
 # act_type -> function, for Activation and the routed experts (ops/moe.py)
 ACTIVATIONS = {"relu": jax.nn.relu, "sigmoid": jax.nn.sigmoid,
                "tanh": jnp.tanh, "softrelu": jax.nn.softplus,
-               "silu": jax.nn.silu}
+               "silu": jax.nn.silu, "relu2": _relu2}
 
 
 @register_op("Activation", hint="activation")

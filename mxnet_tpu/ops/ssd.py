@@ -24,18 +24,23 @@ in plain ``jnp`` (every platform, float32, autodiff: the parity twin):
 once a group and not once a head, ``exp(G) C S`` from the entry state,
 ``S' = exp(G_c) S + B^T (exp(G_c - G) dt x)``.  Where the program is
 LOWERED for a TPU and the inputs are ones the kernels take
-(``_kernel_takes``: bfloat16, whole chunks, one group, an even number of
-64-lane heads, a 128-lane state), two Pallas kernels run the rule,
+(``_kernel_takes``: bfloat16, whole chunks, an even number of 64-lane
+heads a group, a 128-lane state), two Pallas kernels run the rule,
 ``ssd_chunk_fwd`` and ``ssd_chunk_bwd``, over a grid of (batch, a few
-heads, chunk).  They read x as ``(T, H * P)`` rows, where the
+heads of ONE group, chunk).  They read x as ``(T, H * P)`` rows, where the
 projection's matmul left it: TWO 64-lane heads fill a 128-lane tile and
 are handled as one, each under its own decay matrix, the other's lanes
 zeroed in the operand (the MXU passes are the ones a 128-lane head would
 take; nothing is cut or shifted along the lanes), and a tile's two
 states lie side by side in one ``(N, 128)`` float32 tile, carried along
 the sequential chunk axis in scratch.  A step's heads share the chunk's
-``C B^T``.  The decay and the step enter as ``ops/linear_attention.py``'s
-head form takes a head's decay, a chunk a row ``(B, H, T / C, 1, C)``,
+``C B^T``: they are heads of one group (``_step_heads`` cuts a step to a
+group's heads), and the step's ``B`` and ``C`` blocks are that group's
+``N`` lanes of the ``(B, T, G * N)`` rows, found by the block index
+alone, so ``G`` groups (Nemotron-H's eight under 64 heads, PR 71) run
+the kernel bodies one group runs (Granite's), which hold no group.  The
+decay and the step enter as ``ops/linear_attention.py``'s head form
+takes a head's decay, a chunk a row ``(B, H, T / C, 1, C)``,
 and that file's statements turn rows into running sums and decay
 matrices (``_chunk_sums``, ``_head_decays``, ``_as_row``, ``_as_col``).
 The forward kernel writes every chunk's entry states (``f32[B, H / 2, T
@@ -279,30 +284,35 @@ def _bwd_kernel(x_ref, b_ref, c_ref, g_ref, dt_ref, d_ref, s_ref, dy_ref,
     ddt_ref[...] = jnp.stack([stepped[h] for h in heads])
 
 
-def _step_heads(h: int) -> int:
-    """Heads a grid step takes of ``h``: the most up to
-    ``SSD_KERNEL_HEADS``, in whole tiles of two."""
-    return next(x for x in range(min(SSD_KERNEL_HEADS, h), 0, -2)
-                if h % x == 0)
+def _step_heads(h: int, groups: int = 1) -> int:
+    """Heads a grid step takes of ``h`` in ``groups`` groups: the most
+    up to ``SSD_KERNEL_HEADS`` that divide a group's, in whole tiles of
+    two, so that a step's heads read one ``B`` and one ``C``."""
+    k = h // groups
+    return next(x for x in range(min(SSD_KERNEL_HEADS, k), 0, -2)
+                if k % x == 0)
 
 
-def _kernel_specs(b, t, h, p, n, flip):
+def _kernel_specs(b, t, h, p, n, flip, groups=1):
     """The grid over (batch, heads, chunk) and the blocks of one step:
     ``(C, heads * P)`` of a ``(B, T, H * P)`` array, ``(C, N)`` of the
-    group's ``(B, T, N)`` (``part``: of a step's share ``(B, H / heads,
-    T, N)``), ``(heads, 1, C)`` of a head's rows ``(B, H, T / C, 1,
-    C)``, ``(1, heads * P)`` of the skip's lanes, ``(heads / 2, N, 2 P)``
-    states of ``(B, H / 2, T / C, N, 2 P)``.  ``flip`` walks the chunks
-    from the last."""
+    ``(B, T, G * N)`` rows of ``B`` or ``C``, the lanes of the step's
+    group (``part``: of a step's share ``(B, H / heads, T, N)``),
+    ``(heads, 1, C)`` of a head's rows ``(B, H, T / C, 1, C)``, ``(1,
+    heads * P)`` of the skip's lanes, ``(heads / 2, N, 2 P)`` states of
+    ``(B, H / 2, T / C, N, 2 P)``.  ``flip`` walks the chunks from the
+    last."""
     from jax.experimental.pallas import tpu as pltpu
     chunk, z = SSD_CHUNK, t // SSD_CHUNK
-    heads = _step_heads(h)
+    heads = _step_heads(h, groups)
     at = (lambda i: z - 1 - i) if flip else (lambda i: i)
+    steps_a_group = h // groups // heads
     return dict(
         grid=(b, h // heads, z), heads=heads,
         seq=pl.BlockSpec((None, chunk, heads * p),
                          lambda i, j, m: (i, at(m), j)),
-        group=pl.BlockSpec((None, chunk, n), lambda i, j, m: (i, at(m), 0)),
+        group=pl.BlockSpec((None, chunk, n),
+                           lambda i, j, m: (i, at(m), j // steps_a_group)),
         part=pl.BlockSpec((None, None, chunk, n),
                           lambda i, j, m: (i, j, at(m), 0)),
         rows=pl.BlockSpec((None, heads, None, 1, chunk),
@@ -322,30 +332,32 @@ def _states_shape(b, t, h, p, n):
                                 jnp.float32)
 
 
-def _note_trace(x, rows, n, **which):
+def _note_trace(x, rows, groups, n, **which):
     b, t, hp = x.shape
     h = rows.shape[1]
     trace.counter("ssd:kernel_trace", cat="ops",
-                  track="%s%s/n%d" % (x.dtype.name, [b, t, h, hp // h], n),
-                  chunk=SSD_CHUNK, heads_a_tile=2, heads_a_step=_step_heads(h),
-                  lowering="kernel", **which)
+                  track="%s%s/g%dn%d" % (x.dtype.name, [b, t, h, hp // h],
+                                         groups, n),
+                  chunk=SSD_CHUNK, heads_a_tile=2,
+                  heads_a_step=_step_heads(h, groups), lowering="kernel",
+                  **which)
 
 
 # lint: allow(raw-jit) — never dispatched on its own: a jit inside the step
 # program, there so that every layer's call shares one traced jaxpr and one
 # lowered function; the step that holds it goes through the cache
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _ssd_fwd(x, bm, cm, g, dt, d, *, interpret):
-    """``ssd_chunk_fwd`` over ``(B, T, H * P)`` x, ``(B, T, N)`` bm and
-    cm, the log-decay and the step a chunk a row ``(B, H, T / C, 1, C)``
-    and the skip over x's lanes ``(1, H * P)`` -> y in x's layout and
-    dtype and every chunk's entry states (``_states_shape``), which
+@functools.partial(jax.jit, static_argnames=("groups", "interpret"))
+def _ssd_fwd(x, bm, cm, g, dt, d, *, groups, interpret):
+    """``ssd_chunk_fwd`` over ``(B, T, H * P)`` x, ``(B, T, G * N)`` bm
+    and cm, the log-decay and the step a chunk a row ``(B, H, T / C, 1,
+    C)`` and the skip over x's lanes ``(1, H * P)`` -> y in x's layout
+    and dtype and every chunk's entry states (``_states_shape``), which
     ``_ssd_bwd`` wants back."""
     b, t, hp = x.shape
-    h, n = g.shape[1], bm.shape[2]
+    h, n = g.shape[1], bm.shape[2] // groups
     p = hp // h
-    _note_trace(x, g, n, fwd=1, bwd=0)
-    sp = _kernel_specs(b, t, h, p, n, False)
+    _note_trace(x, g, groups, n, fwd=1, bwd=0)
+    sp = _kernel_specs(b, t, h, p, n, False, groups)
     # lint: allow(raw-pallas-call) — one lowering of this op, a pair with
     # its own vjp, chosen by platform and held to the plain chunks by
     # tolerance (tests/test_granite_hybrid.py, tests/tpu): not a forward
@@ -363,18 +375,18 @@ def _ssd_fwd(x, bm, cm, g, dt, d, *, interpret):
 
 
 # lint: allow(raw-jit) — as _ssd_fwd
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _ssd_bwd(x, bm, cm, g, dt, d, states, dy, *, interpret):
+@functools.partial(jax.jit, static_argnames=("groups", "interpret"))
+def _ssd_bwd(x, bm, cm, g, dt, d, states, dy, *, groups, interpret):
     """``ssd_chunk_bwd``: the cotangents of x (its dtype), of bm and cm
     (float32, a grid step's heads' share each: ``(B, H / heads, T, N)``,
-    summed by the caller), of the log-decay and of the step (float32, a
-    chunk a row) from ``_ssd_fwd``'s inputs, its entry states and the
-    output's cotangent."""
+    summed a group by the caller, ``_group_sums``), of the log-decay and
+    of the step (float32, a chunk a row) from ``_ssd_fwd``'s inputs, its
+    entry states and the output's cotangent."""
     b, t, hp = x.shape
-    h, n = g.shape[1], bm.shape[2]
+    h, n = g.shape[1], bm.shape[2] // groups
     p = hp // h
-    _note_trace(x, g, n, fwd=0, bwd=1)
-    sp = _kernel_specs(b, t, h, p, n, True)
+    _note_trace(x, g, groups, n, fwd=0, bwd=1)
+    sp = _kernel_specs(b, t, h, p, n, True, groups)
     share = jax.ShapeDtypeStruct((b, h // sp["heads"], t, n), jnp.float32)
     rows = jax.ShapeDtypeStruct(g.shape, jnp.float32)
     # lint: allow(raw-pallas-call) — as _ssd_fwd
@@ -392,8 +404,8 @@ def _ssd_bwd(x, bm, cm, g, dt, d, states, dy, *, interpret):
 
 
 def _kernel_layout(x, bm, cm, dt, g, d):
-    """``(B, T, H, P)`` -> ``(B, T, H * P)`` and the one group's ``(B,
-    T, 1, N)`` -> ``(B, T, N)`` (free); a head's rows ``(B, T, H)`` ->
+    """``(B, T, H, P)`` -> ``(B, T, H * P)`` and the groups' ``(B, T,
+    G, N)`` -> ``(B, T, G * N)`` (free); a head's rows ``(B, T, H)`` ->
     ``(B, H, T / C, 1, C)``, a chunk a row of lanes, as
     ``ops/linear_attention.py`` hands a head's decay over; the skip
     ``(H,)`` over its head's lanes ``(1, H * P)``."""
@@ -415,6 +427,15 @@ def _from_rows(a):
     return a.reshape(b, h, -1).transpose(0, 2, 1)
 
 
+def _group_sums(share, like):
+    """The backward kernel's shares of ``B``'s or ``C``'s cotangent ``(B,
+    H / heads, T, N)``, a grid step's heads each, summed over the steps
+    of a group -> ``like``'s ``(B, T, G, N)`` and dtype."""
+    b, t, groups, n = like.shape
+    return share.reshape(b, groups, -1, t, n).sum(2).transpose(0, 2, 1, 3) \
+        .astype(like.dtype)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
 def _two_lowerings(x, bm, cm, dt, a_log, dt_bias, d, interpret: bool):
     """The op's body for inputs the kernels take: the kernels where the
@@ -434,7 +455,7 @@ def _two_lowerings_fwd(x, bm, cm, dt, a_log, dt_bias, d, interpret):
     def kernels(x, bm, cm, dt, a_log, dt_bias, d):
         dt, g = ssd_gates(dt, a_log, dt_bias)
         y, states = _ssd_fwd(*_kernel_layout(x, bm, cm, dt, g, d),
-                             interpret=interpret)
+                             groups=bm.shape[2], interpret=interpret)
         return y.reshape(x.shape), states
 
     def plain(*args):
@@ -456,13 +477,12 @@ def _two_lowerings_bwd(interpret, res, dy):
         (dtf, g), before = jax.vjp(ssd_gates, dt, a_log, dt_bias)
         dx, dbm, dcm, dg, ddt = _ssd_bwd(
             *_kernel_layout(x, bm, cm, dtf, g, d), states,
-            dy.reshape(b, t, -1), interpret=interpret)
+            dy.reshape(b, t, -1), groups=bm.shape[2], interpret=interpret)
         ddt, da_log, ddt_bias = before((_from_rows(ddt), _from_rows(dg)))
         dd = jnp.sum(dy.astype(jnp.float32) * x.astype(jnp.float32),
                      axis=(0, 1, 3))
-        return (dx.reshape(x.shape), dbm.sum(1).reshape(bm.shape)
-                .astype(bm.dtype), dcm.sum(1).reshape(cm.shape)
-                .astype(cm.dtype), ddt, da_log, ddt_bias,
+        return (dx.reshape(x.shape), _group_sums(dbm, bm),
+                _group_sums(dcm, cm), ddt, da_log, ddt_bias,
                 dd.astype(d.dtype))
 
     def plain(dy, states, *args):
@@ -476,12 +496,14 @@ _two_lowerings.defvjp(_two_lowerings_fwd, _two_lowerings_bwd)
 
 def _kernel_takes(x, bm) -> bool:
     """What the kernels compute and tile: bfloat16 (float32 runs the
-    plain chunks, which keep its digits), sequences of whole chunks, ONE
-    group's ``B`` and ``C`` of one 128-lane row under an even number of
-    64-lane heads."""
+    plain chunks, which keep its digits), sequences of whole chunks,
+    ``B`` and ``C`` of one 128-lane row a group, each group under an even
+    number of 64-lane heads (a grid step's heads lie in one group:
+    ``_step_heads``)."""
+    groups = bm.shape[2]
     return (x.dtype == jnp.bfloat16 and x.shape[1] % SSD_CHUNK == 0
-            and x.shape[2] % 2 == 0 and x.shape[3] == SSD_HEAD_DIM
-            and bm.shape[2:] == (1, SSD_STATE))
+            and x.shape[2] % (2 * groups) == 0
+            and x.shape[3] == SSD_HEAD_DIM and bm.shape[3] == SSD_STATE)
 
 
 def ssd_scan(x, bm, cm, dt, a_log, dt_bias, d, interpret: bool = False):

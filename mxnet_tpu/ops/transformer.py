@@ -120,6 +120,20 @@ def rms_norm(x, gamma, eps: float):
     return (x32 * lax.rsqrt(var + eps)).astype(x.dtype) * gamma.astype(x.dtype)
 
 
+def grouped_rms_norm(x, gamma, eps: float, groups=None):
+    """``rms_norm`` with the statistic over each of ``groups`` equal parts
+    of the last axis (None or 0: over all of it) and ONE gain over the
+    whole axis: Mamba-2's gated norm over ``n_groups`` > 1."""
+    if not groups:
+        return rms_norm(x, gamma, eps)
+    if x.shape[-1] % groups:
+        raise MXNetError("RMSNorm: %d lanes in %d groups"
+                         % (x.shape[-1], groups))
+    parts = x.shape[:-1] + (groups, x.shape[-1] // groups)
+    return rms_norm(x.reshape(parts), gamma.reshape(parts[-2:]),
+                    eps).reshape(x.shape)
+
+
 def rotary_embedding(x, theta: float, period: int = 0):
     """Rotary position embedding of ``(B, T, H, Dh)`` at positions
     ``0..T-1``, or ``n mod period`` for row ``n`` where ``period`` is
@@ -432,8 +446,10 @@ def _plain_attention(q, k, v, scale: float, kind=("causal", 0)):
 @register_op("RMSNorm", hint="rmsnorm")
 class RMSNormOp(OpDef):
     """Root-mean-square LayerNorm over the last axis (Zhang & Sennrich
-    2019): no mean subtraction, no bias; float32 statistics."""
-    params = [Param("eps", float, default=1e-5)]
+    2019): no mean subtraction, no bias; float32 statistics.  With
+    ``groups`` the statistic is over each of that many equal parts of the
+    axis under ONE gain (``grouped_rms_norm``)."""
+    params = [Param("eps", float, default=1e-5), Param("groups", int)]
 
     def list_arguments(self, p):
         return ["data", "gamma"]
@@ -445,7 +461,7 @@ class RMSNormOp(OpDef):
         return [d, (d[-1],)], [d], []
 
     def forward(self, p, inputs, aux, ctx):
-        return [rms_norm(inputs[0], inputs[1], p.eps)]
+        return [grouped_rms_norm(inputs[0], inputs[1], p.eps, p.groups)]
 
 
 @register_op("LayerNorm", hint="layernorm")

@@ -16,8 +16,9 @@ import mxnet_tpu as mx
 from mxnet_tpu import models
 from mxnet_tpu.trace import scopes
 
-from test_decoder_symbols import (AFMOE, GLM, KEYE, KIMI, LFM2, OLMOE, OURO,
-                                  QWEN3_NEXT, SDAR, SMALLTHINKER, _unscoped)
+from test_decoder_symbols import (AFMOE, GLM, KEYE, KIMI, LFM2, NEMOTRON,
+                                  OLMOE, OURO, QWEN3_NEXT, SDAR, SMALLTHINKER,
+                                  _unscoped)
 
 GRANITE = dict(num_layers=3, hidden_size=32,
                layer_types=["mamba", "attention", "mamba"], ssm_heads=4,
@@ -58,6 +59,8 @@ BUILDERS = {
     "keye_lm": (KEYE, {"attn_proj"}),
     "lfm2_moe_lm": (LFM2, {"mlp", "attn_proj"}),
     "granite_hybrid_lm": (GRANITE, {"mlp", "attn_proj"}),
+    # one-branch layers: ``mlp`` is the shared expert's
+    "nemotron_h_lm": (NEMOTRON, {"mlp", "attn_proj", "ssm_proj", "ssm_norm"}),
 }
 
 

@@ -124,6 +124,13 @@ GRANITE = dict(num_layers=3, hidden_size=32,
                vocab_size=50, seq_len=24, embedding_multiplier=12.0,
                residual_multiplier=0.22, attention_multiplier=0.125,
                logits_scaling=8.0, rms_eps=1e-5)
+NEMOTRON = dict(num_layers=5, hidden_size=32,
+                layer_types=["mamba", "moe", "attention", "moe", "mamba"],
+                ssm_heads=4, ssm_head_dim=8, ssm_state=12, ssm_groups=2,
+                conv_kernel=4, num_heads=4, num_kv_heads=2, head_dim=8,
+                num_experts=8, experts_per_tok=3, expert_width=24,
+                shared_width=40, route_scale=2.5, vocab_size=50, seq_len=24,
+                rms_eps=1e-5, bias_rate=1e-3, experts_held=4, first_expert=0)
 WHOLE = dict(experts_held=0, first_expert=0)
 # latent attention by itself: seq_len, hidden_size, heads, kv_lora_rank,
 # qk_nope_dim, qk_rope_dim, v_head_dim, rms_eps
@@ -214,6 +221,11 @@ SYMBOLS = {
     # one
     "granite-4.0-h-micro": _cell("granite-4.0-h-micro"),
     "granite-tiny": _tiny("granite_hybrid_lm", GRANITE),
+    # the twelfth builder, from the commit that added it (ISSUE 71):
+    # one-branch layers; its cell, a rank's share, the whole layer
+    "nemotron-3-nano-30b-a3b": _cell("nemotron-3-nano-30b-a3b"),
+    "nemotron-share": _tiny("nemotron_h_lm", NEMOTRON),
+    "nemotron-whole": _tiny("nemotron_h_lm", NEMOTRON, **WHOLE),
     # OLMoE: its load-balance heads stay on at coefficient 0
     "olmoe-tiny": _tiny("olmoe_lm", OLMOE),
     "olmoe-aux-0": _tiny("olmoe_lm", OLMOE, aux_coef=0.0),
@@ -252,6 +264,13 @@ SYMBOLS = {
 # at that issue; first the five cells of ISSUE
 # 46's table, the four behind them the sixth builder's
 SYMBOL_WAS = {
+    # the twelfth builder's, at the commit that added it (ISSUE 71)
+    "nemotron-3-nano-30b-a3b":
+        "035bca8d3252dec3e47e76eca065c0a4126c25bafc6b2b06470e5c82ccbe8e3e",
+    "nemotron-share":
+        "7ca4333e3df14197b537eccdadf201faa56f2b166e1ceb68a905def537b5f364",
+    "nemotron-whole":
+        "206931b10d7727090418ea56ee0008c52dbc3d131d8f3b2c7ec185dcd9ca49c0",
     "granite-4.0-h-micro":
         "4ac25b1380f9eac9c32b04777c960ad89be7dda40738cf164ff1c9e476d6df00",
     "granite-tiny":
@@ -376,6 +395,13 @@ SYMBOL_WAS = {
 # (``_unscoped``), taken at cc9ea8d, the parent of ISSUE 69 (the six
 # builders ISSUE 70 meant to move: at that issue)
 UNSCOPED_WAS = {
+    # the twelfth builder's, at the commit that added it (ISSUE 71)
+    "nemotron-3-nano-30b-a3b":
+        "3cd8a0a55b56590c96ad065a2dec7ede419307bb95bdeac6bf98f3634fccc8ef",
+    "nemotron-share":
+        "e63c016d482b7f57277e312bfb25470a9897833f1bb39cb485783c987daa8b01",
+    "nemotron-whole":
+        "de87ce80f8bfe9e20d4bf480ff9686d7f8d1a1b883155a047e517af031701bd1",
     "granite-4.0-h-micro":
         "571ed1dab116db74869733e5f1e6ed6babacc71ee4945c0b122975974abcd384",
     "granite-tiny":
@@ -695,6 +721,13 @@ def test_a_training_step_of_the_block_is_the_old_nodes(case, dtype):
 # 70, which moved q's and k's norm and rotation into one node and meant
 # to move nothing a checkpoint or a reference's weights map by
 SIGNATURE_WAS = {
+    # the twelfth builder's, at the commit that added it (ISSUE 71)
+    "nemotron-3-nano-30b-a3b":
+        "9157e895b7e02c3bae9a025ee249454e4d38911b0fb6f36b7a6b7d2368bdec9e",
+    "nemotron-share":
+        "8a35990f2b15cd43b03cf6f206dcb81013c7fbad2e5c27164a7e07d337b2c46d",
+    "nemotron-whole":
+        "8cf9b92beef0e0007ce897f456ea993c1c792b76ec7e32cb0f43e7dc39a3086b",
     "granite-4.0-h-micro":
         "54a8611fba569b409ff54b118ebdca5cd793000123b8c0476ab8c5320a4d1dc1",
     "granite-tiny":

@@ -129,7 +129,8 @@ def test_the_kernel_pair_interpreted_is_the_token_by_token_scan(dtype, heads,
     ((1, 4096, 64, 64, 1, 128), BF16, True),      # the cell's
     ((1, 4096, 64, 64, 1, 128), F32, False),      # float32: the plain chunks
     ((1, 4000, 64, 64, 1, 128), BF16, False),     # no whole chunks
-    ((1, 4096, 64, 64, 2, 128), BF16, False),     # two groups
+    ((1, 4096, 64, 64, 2, 128), BF16, True),      # two groups (ISSUE 71)
+    ((1, 4096, 64, 64, 64, 128), BF16, False),    # half a lane tile a group
     ((1, 4096, 63, 64, 1, 128), BF16, False),     # half a lane tile left
     ((1, 4096, 32, 128, 1, 128), BF16, False),    # another head
     ((1, 4096, 64, 64, 1, 64), BF16, False),      # another state
@@ -169,7 +170,7 @@ def test_a_tpu_program_holds_the_kernels_and_the_counters_say_so():
     assert [(e["args"]["fwd"], e["args"]["bwd"]) for e in traced] \
         == [(1, 0), (0, 1)]
     for e in traced:
-        assert e["id"] == "bfloat16[1, 384, 6, 64]/n128"
+        assert e["id"] == "bfloat16[1, 384, 6, 64]/g1n128"
         assert (e["args"]["chunk"], e["args"]["heads_a_tile"],
                 e["args"]["heads_a_step"], e["args"]["lowering"]) \
             == (128, 2, 6, "kernel")

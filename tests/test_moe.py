@@ -758,3 +758,84 @@ def test_combine_node_takes_order_from_the_dispatch_node():
     with pytest.raises(mx.base.MXNetError, match="give order"):
         mx.sym._moe_combine(mx.sym.Variable("rows"), mx.sym.Variable("w"),
                             mx.sym.Variable("s"), name="c")
+
+
+# -- the plain (ungated) form of one rank's share (ISSUE 71) ------------------
+
+def _share_ops(act_type, gated, held, first, experts, k, hidden, width):
+    get = mx.ops.get_op
+    share = dict(experts_held=held, first_expert=first)
+    ffn = dict(num_hidden=width, output_dim=hidden, act_type=act_type,
+               no_bias=True, gated=gated, layer=1, **share)
+    return {name: (get(op), get(op).parse_params(params)) for name, op, params
+            in (("dispatch", "_moe_dispatch", dict(
+                    num_experts=experts, k=k, capacity_factor=0.0,
+                    renormalize=True, score="sigmoid", scale=2.5,
+                    bias_rate=1e-3, layer=1, **share)),
+                ("experts", "_moe_expert_ffn", ffn),
+                ("combine", "_moe_combine", dict(layer=1)),
+                ("share", "_moe_share_ffn", ffn))}
+
+
+@pytest.mark.parametrize("bounded", [False, True], ids=["window", "bound"])
+@pytest.mark.parametrize("act_type", ["relu2", "silu"])
+def test_the_plain_share_node_is_the_three_node_layer(act_type, bounded,
+                                                      monkeypatch):
+    """``_moe_share_ffn`` with ``gated`` off (two stacked matrices,
+    ``act(x W1) W2``: Nemotron-H's experts under the squared ReLU)
+    against ``_moe_expert_ffn`` between the dispatch node and
+    ``_moe_combine`` for a rank that holds 4 of 32 experts: forward and
+    the gradients of the data, of the router's logits and of both
+    stacked weights; as the one window of every row, and under a static
+    row bound with its conditional."""
+    share_rule = sys.modules["mxnet_tpu.moe.dispatch"]
+    T, k, experts, held, first, D, H = 256, 4, 32, 4, 8, 24, 40
+    if bounded:
+        monkeypatch.setattr(share_rule, "BOUND_WORTH_ROWS", 0)
+    assert (share_rule.held_rows_bound(T * k, experts, held) < T * k) \
+        is bounded
+    ops = _share_ops(act_type, False, held, first, experts, k, D, H)
+    assert ops["share"][0].list_arguments(ops["share"][1])[-2:] \
+        == ["i2h_weight", "h2o_weight"]
+
+    class Train:
+        is_train = True
+
+    def run(which, *inputs, aux=()):
+        op, p = ops[which]
+        return op.forward(p, list(inputs), list(aux), Train)
+
+    rng = np.random.RandomState(len(act_type) + bounded)
+    x = jnp.asarray(rng.randn(T, D), jnp.float32)
+    logits = jnp.asarray(rng.randn(T, experts), jnp.float32)
+    ws = [jnp.asarray(rng.randn(held, *s) / 5, jnp.float32)
+          for s in ((D, H), (H, D))]
+    ct = jnp.asarray(rng.randn(T, D), jnp.float32)
+    bias = [jnp.asarray(0.1 * rng.randn(experts), jnp.float32)]
+
+    def three(x, ws, d):
+        rows = run("experts", d[0], *ws, d[4])[0]
+        return run("combine", rows, d[1], d[2], d[7])[0]
+
+    def one(x, ws, d):
+        return run("share", x, d[1], d[2], d[7], d[4], *ws)[0]
+
+    def graded(layer):
+        def loss(x, logits, *ws):
+            d = run("dispatch", x, logits, aux=bias)[0]
+            out = layer(x, ws, d)
+            return (out * ct).sum(), (out, d[4])
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3),
+                                          has_aux=True))
+
+    (_, (want, counts)), want_grads = graded(three)(x, logits, *ws)
+    (_, (got, _)), got_grads = graded(one)(x, logits, *ws)
+    mine = int(np.asarray(counts)[first:first + held].sum())
+    assert 0 < mine < T * k and np.asarray(want).any()
+    scale = float(np.abs(np.asarray(want)).max())
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() <= 2e-6 * scale
+    for name, a, b in zip(("data", "logits", "up", "down"), got_grads,
+                          want_grads):
+        a, b = np.asarray(a), np.asarray(b)
+        assert b.any(), name
+        assert np.abs(a - b).max() <= 5e-6 * np.abs(b).max(), name

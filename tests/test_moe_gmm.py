@@ -41,14 +41,14 @@ TOLERANCE = {"float32": 2e-6, "bfloat16": 1e-2}
 
 
 @functools.lru_cache(maxsize=None)
-def _both_ways(layout, dtype_name, wide=False):
+def _both_ways(layout, dtype_name, wide=False, kn=None):
     """(kernels, ragged_dot) each as {product: array}: the output and
     both gradients of ``own(grouped(own(rows), w))`` against a fixed
     cotangent, ``own`` being ``_moe_expert_ffn``'s select of the rows
-    that belong to a group."""
+    that belong to a group.  ``kn``: another ``(K, N)``."""
     dtype = jnp.dtype(dtype_name)
     sizes = jnp.asarray(LAYOUTS[layout], jnp.int32)
-    k, n = (1280, 1280) if wide else (K, N)
+    k, n = kn or ((1280, 1280) if wide else (K, N))
     rng = np.random.RandomState(len(layout))
     rows = jnp.asarray(rng.randn(ROWS, k), dtype)
     w = jnp.asarray(rng.randn(len(sizes), k, n) / np.sqrt(k), dtype)
@@ -101,6 +101,83 @@ def test_kernels_match_ragged_dot_over_several_k_and_n_tiles(product):
     assert gmm.tiles_for(ROWS, 1280, 1280, 8, jnp.float32) == (
         gmm.ROW_TILE, 640, 640)
     assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+
+
+# (cell, M, K, N, E) of the two stacked shapes (gate / up ``D x W``, down
+# ``W x D``) of every expert cell: M the rows its grouped products run over
+# (``T k``, or a rank's static bound ``held_rows_bound``), E the experts the
+# stacked weights hold; and the tiles the rule gave them before ISSUE 71
+# took widths of 64 x odd and ``K`` = 2688, in bfloat16 (what the cells
+# compute in) and in float32 (what their parity tests do)
+CELL_SHAPES = [
+    ("olmoe-1b-7b", 131072, 2048, 1024, 64, (256, 2048, 1024), (256, 1024, 1024)),
+    ("olmoe-1b-7b", 131072, 1024, 2048, 64, (256, 1024, 2048), (256, 1024, 1024)),
+    ("kimi-linear-48b-a3b", 4096, 2304, 1024, 8, (256, 1152, 1024), (256, 768, 1024)),
+    ("kimi-linear-48b-a3b", 4096, 1024, 2304, 8, (256, 1024, 1152), (256, 1024, 768)),
+    ("glm-4.7-flash", 16384, 2048, 1536, 8, (256, 2048, 1536), (256, 1024, 768)),
+    ("glm-4.7-flash", 16384, 1536, 2048, 8, (256, 1536, 2048), (256, 768, 1024)),
+    ("sdar-30b-a3b", 32768, 2048, 768, 16, (256, 2048, 768), (256, 1024, 768)),
+    ("sdar-30b-a3b", 32768, 768, 2048, 16, (256, 768, 2048), (256, 768, 1024)),
+    ("trinity-mini", 8192, 2048, 1024, 8, (256, 2048, 1024), (256, 1024, 1024)),
+    ("trinity-mini", 8192, 1024, 2048, 8, (256, 1024, 2048), (256, 1024, 1024)),
+    ("smallthinker-21b-a3b", 24576, 2560, 768, 8, (256, 1280, 768), (256, 640, 768)),
+    ("smallthinker-21b-a3b", 24576, 768, 2560, 8, (256, 768, 1280), (256, 768, 640)),
+    ("qwen3-next-80b-a3b", 10240, 2048, 512, 32, (256, 2048, 512), (256, 1024, 512)),
+    ("qwen3-next-80b-a3b", 10240, 512, 2048, 32, (256, 512, 2048), (256, 512, 1024)),
+    ("keye-vl-2.0-30b-a3b", 32768, 2048, 768, 16, (256, 2048, 768), (256, 1024, 768)),
+    ("keye-vl-2.0-30b-a3b", 32768, 768, 2048, 16, (256, 768, 2048), (256, 768, 1024)),
+    ("lfm2-8b-a1b", 32768, 2048, 1792, 8, (256, 2048, 1792), (256, 1024, 896)),
+    ("lfm2-8b-a1b", 32768, 1792, 2048, 8, (256, 1792, 2048), (256, 896, 1024)),
+]
+
+
+@pytest.mark.parametrize("cell,m,k,n,e,bf16,f32", CELL_SHAPES, ids=[
+    "%s-%dx%d" % (c[0], c[2], c[3]) for c in CELL_SHAPES])
+def test_the_tile_rule_gives_the_nine_expert_cells_the_tiles_they_had(
+        cell, m, k, n, e, bf16, f32):
+    """A later change of the rule for one width cannot move another cell's
+    tiles unseen.  The shapes are read back from the configuration files:
+    ``M`` is the rows the cell's grouped products run over."""
+    import json
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", cell + ".json")) as f:
+        kw = json.load(f)["model"]["kwargs"]
+    rows = kw["seq_len"] * (2 if cell.startswith("sdar") else 1) \
+        * kw["experts_per_tok"] * (4 if cell.startswith("olmoe") else 1)
+    held = kw.get("experts_held", 0)
+    assert m == dispatch.held_rows_bound(rows, kw["num_experts"], held)
+    assert e == (held or kw["num_experts"])
+    assert {k, n} == {kw["hidden_size"], kw["expert_width"]}
+    assert gmm.tiles_for(m, k, n, e, jnp.bfloat16) == bf16
+    assert gmm.tiles_for(m, k, n, e, jnp.float32) == f32
+    # what every cell's tiles hold: at most two steps along K and along N
+    assert k // bf16[1] <= 2 and n // bf16[2] <= 2
+
+
+@pytest.mark.parametrize("m,k,n,dtype,want", [
+    # Nemotron-H's experts: 2688 = 3 x 896 and 1856 = 14.5 x 128, ONE block
+    # each (42.5 MiB of VMEM in tgmm, under the limit less an eighth)
+    (6144, 2688, 1856, "bfloat16", (256, 2688, 1856)),
+    (6144, 1856, 2688, "bfloat16", (256, 1856, 2688)),
+    # float32's tiles are twice the bytes: 66 MiB do not fit, K keeps 896
+    (6144, 2688, 1856, "float32", (256, 896, 1856)),
+    # a width of 64 x odd is one block at any size that fits
+    (1024, 192, 320, "float32", (256, 192, 320)),
+    # 32 lanes over a half tile: no block
+    (1024, 160, 128, "bfloat16", None),
+    # 29 x 128 against 2688 fits in no one block: of the pairs that fit the
+    # fewest steps, 3 along K and not 29 along N
+    (1024, 2688, 3712, "bfloat16", (256, 896, 3712)),
+    (1024, 3712, 2688, "bfloat16", (256, 3712, 896)),
+    (1000, 2688, 1856, "bfloat16", None),
+])
+def test_the_tile_rule_at_widths_that_are_no_whole_lane_tiles(m, k, n, dtype,
+                                                              want):
+    assert gmm.tiles_for(m, k, n, 8, jnp.dtype(dtype)) == want
+    # the backward-data product asks with K and N swapped: refused alike
+    assert (gmm.tiles_for(m, n, k, 8, jnp.dtype(dtype)) is None) \
+        == (want is None)
 
 
 @pytest.mark.parametrize("experts,tiles", [(1, 1), (4, 2), (8, 16), (64, 4)])
@@ -201,8 +278,8 @@ def test_a_ranks_share_reads_zero_behind_its_groups(interpreted):
 
 @pytest.mark.parametrize("why,rows,k,n,dtype,devices", [
     ("rows_not_whole_tiles", 640, K, N, "float32", 1),
-    ("k_not_whole_lanes", ROWS, 192, N, "float32", 1),
-    ("n_not_whole_lanes", ROWS, K, 64, "float32", 1),
+    ("k_not_whole_half_tiles", ROWS, 160, N, "float32", 1),
+    ("n_not_whole_half_tiles", ROWS, K, 96, "float32", 1),
     ("a_dtype_without_a_kernel", ROWS, K, N, "float16", 1),
     ("a_program_over_two_devices", ROWS, K, N, "float32", 2),
     ("the_kernels", ROWS, K, N, "float32", 1),
