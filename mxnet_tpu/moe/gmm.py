@@ -40,7 +40,7 @@ once a signature, choice and kernels with it, and a program holds each
 once for any number of layers.  The counter ``moe:gmm_trace`` fires
 where a kernel is traced, ``moe:gmm_lowering`` once a traced product with
 the choice made for it (``tiled_matmul``).  A third kernel,
-``token-sum``: the file's end.
+``token-sum``, and a weight read through its transpose: the file's end.
 """
 from __future__ import annotations
 
@@ -164,10 +164,10 @@ def tiles_for(m: int, k: int, n: int, e: int, dtype
       projection's three products 1.51 (forward alone 0.60) against
       ``ragged_dot``'s 4.59 (1.60), the down projection's 0.90 (0.31)
       against 4.42 (1.34), outputs and both gradients ``ragged_dot``'s
-      bit for bit (``tests/tpu/test_nemotron_h_tpu.py``).  That is the
-      kernels alone: round them, the state holds such a weight with its
-      OTHER dimension on the lanes and the step pays 24 transposing
-      copies, 12.2 ms, for it (``docs/moe.md``).
+      bit for bit (``tests/tpu/test_nemotron_h_tpu.py``).  The state
+      holds such a weight with its OTHER dimension on the lanes (24
+      transposing copies a step, 12.2 ms), so since PR 72 the pair reads
+      it through its transpose: ``reads_turned``, the file's end.
     * ``E`` enters no choice yet: one expert-parallel rank's share
       (Kimi: 1 024 of 32 768 rows in 8 groups; GLM: 2 800 of 16 384) is
       a launch and the weights' fetch whatever ``tm``, as
@@ -465,45 +465,45 @@ def _two_lowerings_bwd(interpret, res, g):
 _two_lowerings.defvjp(_two_lowerings_fwd, _two_lowerings_bwd)
 
 
-def _note_lowering(rows, w, kernel: bool):
+def _note_lowering(rows, w, kernel: bool, turned: bool):
     """``moe:gmm_lowering``: one sample a traced grouped product, track
     ``<dtype>[rows] x [E, K, N]``; ``kernel`` 1 where its TPU lowering is
     the kernel pair (a CPU program holds ``ragged_dot`` all the same),
-    ``plain`` 1 where ``ragged_dot`` stays on every platform: a dtype or
-    width ``tiles_for`` refuses, rows that are no whole tiles, a mesh."""
+    ``plain`` 1 where ``ragged_dot`` stays on every platform, ``turned``
+    1, on that sample alone, where the pair reads ``w`` transposed."""
     trace.counter("moe:gmm_lowering", cat="ops",
                   track="%s[%d] x %s" % (rows.dtype.name, rows.shape[0],
-                                         list(w.shape)),
-                  kernel=int(kernel), plain=int(not kernel))
+                                         list(w.shape)), kernel=int(kernel),
+                  plain=int(not kernel), **({"turned": 1} if turned else {}))
 
 
 def tiled_matmul(rows, w, group_sizes, interpret: bool = False):
-    """``dispatch.grouped_matmul``'s body.  ``group_sizes`` is a layer's
-    ``GroupTiles`` or plain sizes (then the visits are made here, for
-    this one product).  ``interpret`` runs the kernels in the Pallas
-    interpreter on any platform: the tests."""
+    """``dispatch.grouped_matmul``'s body.  ``group_sizes``: a layer's
+    ``GroupTiles``, or plain sizes whose visits are then made here.
+    ``interpret``: the kernels in the Pallas interpreter, the tests."""
     tiles = group_sizes if isinstance(group_sizes, GroupTiles) \
         else group_tiles(group_sizes, rows.shape[0])
     e, k, n = w.shape
-    # what tiles_for refuses it refuses with K and N swapped too, so one
-    # question covers the backward-data product
+    # ragged_dot stays for a mesh, rows that are no whole tiles, a dtype or
+    # width tiles_for refuses: with K and N swapped too, so ONE question
     kernel = isinstance(tiles, GroupTiles) and rows.dtype == w.dtype \
         and bool(tiles_for(rows.shape[0], k, n, e, rows.dtype))
-    _note_lowering(rows, w, kernel)
-    if kernel:
+    turned = kernel and reads_turned(k, n)
+    _note_lowering(rows, w, kernel, turned)
+    if kernel and not turned:
         return _two_lowerings(rows, w, tiles, interpret)
+    if turned:
+        return _turned(rows, jnp.swapaxes(w, 1, 2), tiles, interpret)
     return ragged_matmul(rows, w, getattr(tiles, "sizes", tiles))
 
 
 # -- a rank's window summed a token (``dispatch.held_sum``) -------------------
 #
-# ``token-sum`` (PR 49): no grouped matmul of an expert FFN but the same walk
-# turned round, for one expert-parallel rank's window of the sorted rows:
-# ``(M, N)`` rows whose groups are each in token order -> ``(T, N)``, every
-# token's rows summed.  Its name does not begin ``ragged-dot``: it is none of
-# the nine products ``moe_gmm_roofline`` counts work for.  It stands at the
-# end of the file so that no line above it moves: a Mosaic kernel's payload
-# names its source lines and is part of JAX's cache key.
+# ``token-sum`` (PR 49): no grouped matmul of an expert FFN (its name does not
+# begin ``ragged-dot``) but the same walk turned round, for one rank's window
+# of the sorted rows: ``(M, N)`` rows whose groups are each in token order ->
+# ``(T, N)``, every token's rows summed.  Later code stands BELOW it and the
+# lines above keep their count: a Mosaic payload names its source lines.
 
 # rows a visit of ``token-sum`` reads: a run (one expert's rows of one tile
 # of ROW_TILE tokens) is ~20-40 rows in the cells that run it.  A v5e, SDAR's
@@ -650,3 +650,82 @@ def token_sums(rows, token, sizes, lo, tokens, weight=None, *, interpret):
             bytes_accessed=rows.dtype.itemsize * (m + tokens) * n),
         interpret=interpret, name="token-sum",
     )(*visits[:4], *operands)
+
+
+# -- a stacked weight read through its transpose (``tiled_matmul``) -----------
+#
+# The TPU holds a float32 ``(E, K, N)`` whose ``N`` is no whole number of
+# 128-lane tiles while ``K`` is with ``K`` on the lanes (layout ``{1,2,0}``:
+# nothing padded), the kernels above want ``N`` there, and so does every
+# elementwise neighbour XLA lays out after them: Nemotron-H's step turned
+# the up projection ``(8, 2688, 1856)``, Adam's two moments and their three
+# updates round the kernels, 24 float32 copies a step (PR 71, docs/moe.md).
+# The logical transpose ``(E, N, K)`` of an array held ``{1,2,0}`` IS the
+# row-major array of that shape, a bitcast, so such a weight is read as
+# ``wt = swapaxes(w, 1, 2)`` by the same kernels with their ``transposed``
+# flags turned round: shape for shape the three products of a down
+# projection ``(E, N, K)``.  The cotangent of ``wt`` goes back through
+# ``swapaxes``' own transpose, a bitcast again.
+
+
+def reads_turned(k: int, n: int) -> bool:
+    """Whether the kernel pair reads a stacked ``(E, K, N)`` through its
+    transpose: ``N`` is no whole number of lane tiles and ``K`` is."""
+    return bool(n % 128) and not k % 128
+
+
+# lint: allow(raw-jit) — as _forward
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _forward_turned(rows, wt, tiles: GroupTiles, *, interpret):
+    """``_forward`` for ``wt`` ``(E, N, K)``.  Traced once a signature."""
+    e, n, k = wt.shape
+    shape = tiles_for(rows.shape[0], k, n, e, rows.dtype)
+
+    def kernels(rows, wt, sizes, *visits):
+        return _gmm(rows, wt, *visits, tiles=shape, transposed=True,
+                    interpret=interpret)
+
+    def plain(rows, wt, sizes, *visits):
+        return ragged_matmul(rows, jnp.swapaxes(wt, 1, 2), sizes)
+
+    return _kernel_on_tpu(kernels, plain, interpret, rows, wt, *tiles)
+
+
+# lint: allow(raw-jit) — as _forward
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _backward_turned(g, rows, wt, tiles: GroupTiles, *, interpret):
+    """``_backward`` for ``wt`` ``(E, N, K)``: the cotangents of the rows
+    and of ``wt``.  Traced once a signature."""
+    e, n, k = wt.shape
+    shape = tiles_for(rows.shape[0], n, k, e, g.dtype)
+
+    def kernels(g, rows, wt, sizes, *visits):
+        d_rows = _gmm(g, wt, *visits, tiles=shape, transposed=False,
+                      interpret=interpret)
+        d_wt = _tgmm(g, rows, *visits, groups=e, dtype=wt.dtype,
+                     tiles=shape, interpret=interpret)
+        return d_rows, d_wt
+
+    def plain(g, rows, wt, sizes, *visits):
+        return jax.vjp(lambda rows, wt: ragged_matmul(
+            rows, jnp.swapaxes(wt, 1, 2), sizes), rows, wt)[1](g)
+
+    return _kernel_on_tpu(kernels, plain, interpret, g, rows, wt, *tiles)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _turned(rows, wt, tiles: GroupTiles, interpret: bool):
+    """``_two_lowerings`` for a weight handed over transposed."""
+    return _forward_turned(rows, wt, tiles, interpret=interpret)
+
+
+def _turned_fwd(rows, wt, tiles, interpret):
+    return _forward_turned(rows, wt, tiles, interpret=interpret), (
+        rows, wt, tiles)
+
+
+def _turned_bwd(interpret, res, g):
+    return _backward_turned(g, *res, interpret=interpret) + (None,)
+
+
+_turned.defvjp(_turned_fwd, _turned_bwd)

@@ -11,6 +11,8 @@ exported text and the counter ``moe:gmm_trace``.  Times and the chip's
 own numerics: ``tests/tpu/test_olmoe_tpu.py``."""
 import functools
 import importlib
+import json
+import os
 import re
 import time
 
@@ -41,14 +43,12 @@ TOLERANCE = {"float32": 2e-6, "bfloat16": 1e-2}
 
 
 @functools.lru_cache(maxsize=None)
-def _both_ways(layout, dtype_name, wide=False, kn=None):
-    """(kernels, ragged_dot) each as {product: array}: the output and
-    both gradients of ``own(grouped(own(rows), w))`` against a fixed
-    cotangent, ``own`` being ``_moe_expert_ffn``'s select of the rows
-    that belong to a group.  ``kn``: another ``(K, N)``."""
+def _products(matmul, layout, dtype_name, k, n):
+    """{product: array}: the output and both gradients of
+    ``own(matmul(own(rows), w, sizes))`` against a fixed cotangent, ``own``
+    being ``_moe_expert_ffn``'s select of the rows that belong to a group."""
     dtype = jnp.dtype(dtype_name)
     sizes = jnp.asarray(LAYOUTS[layout], jnp.int32)
-    k, n = kn or ((1280, 1280) if wide else (K, N))
     rng = np.random.RandomState(len(layout))
     rows = jnp.asarray(rng.randn(ROWS, k), dtype)
     w = jnp.asarray(rng.randn(len(sizes), k, n) / np.sqrt(k), dtype)
@@ -58,18 +58,23 @@ def _both_ways(layout, dtype_name, wide=False, kn=None):
     def own(x):
         return jnp.where(mine, x, jnp.zeros((), x.dtype))
 
-    def run(matmul):
-        def loss(rows, w):
-            out = own(matmul(own(rows), w, sizes))
-            return (out.astype(jnp.float32)
-                    * ct.astype(jnp.float32)).sum(), out
-        (_, out), (d_rows, d_w) = jax.jit(jax.value_and_grad(
-            loss, argnums=(0, 1), has_aux=True))(rows, w)
-        return {"forward": out, "backward_data": d_rows,
-                "backward_weight": d_w}
+    def loss(rows, w):
+        out = own(matmul(own(rows), w, sizes))
+        return (out.astype(jnp.float32) * ct.astype(jnp.float32)).sum(), out
+    (_, out), (d_rows, d_w) = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1), has_aux=True))(rows, w)
+    return {"forward": out, "backward_data": d_rows, "backward_weight": d_w}
 
-    return (run(functools.partial(gmm.tiled_matmul, interpret=True)),
-            run(gmm.ragged_matmul))
+
+_interpreted = functools.partial(gmm.tiled_matmul, interpret=True)
+
+
+def _both_ways(layout, dtype_name, wide=False, kn=None):
+    """(kernels, ragged_dot) each as ``_products`` gives them.  ``kn``:
+    another ``(K, N)``."""
+    k, n = kn or ((1280, 1280) if wide else (K, N))
+    return (_products(_interpreted, layout, dtype_name, k, n),
+            _products(gmm.ragged_matmul, layout, dtype_name, k, n))
 
 
 @pytest.mark.parametrize("layout", sorted(LAYOUTS))
@@ -131,6 +136,13 @@ CELL_SHAPES = [
 ]
 
 
+def _cell_kwargs(cell):
+    """The builder's arguments in ``benchmark/configs/<cell>.json``."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", cell + ".json")) as f:
+        return json.load(f)["model"]["kwargs"]
+
+
 @pytest.mark.parametrize("cell,m,k,n,e,bf16,f32", CELL_SHAPES, ids=[
     "%s-%dx%d" % (c[0], c[2], c[3]) for c in CELL_SHAPES])
 def test_the_tile_rule_gives_the_nine_expert_cells_the_tiles_they_had(
@@ -138,11 +150,7 @@ def test_the_tile_rule_gives_the_nine_expert_cells_the_tiles_they_had(
     """A later change of the rule for one width cannot move another cell's
     tiles unseen.  The shapes are read back from the configuration files:
     ``M`` is the rows the cell's grouped products run over."""
-    import json
-    import os
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs", cell + ".json")) as f:
-        kw = json.load(f)["model"]["kwargs"]
+    kw = _cell_kwargs(cell)
     rows = kw["seq_len"] * (2 if cell.startswith("sdar") else 1) \
         * kw["experts_per_tok"] * (4 if cell.startswith("olmoe") else 1)
     held = kw.get("experts_held", 0)
@@ -178,6 +186,89 @@ def test_the_tile_rule_at_widths_that_are_no_whole_lane_tiles(m, k, n, dtype,
     # the backward-data product asks with K and N swapped: refused alike
     assert (gmm.tiles_for(m, n, k, 8, jnp.dtype(dtype)) is None) \
         == (want is None)
+
+
+TURNED_K, TURNED_N = 256, 192            # two lane tiles against one and a half
+
+
+def _kernels_as_the_weight_lies(rows, w, sizes):
+    """The kernel pair in the plain orientation, whatever ``reads_turned``
+    says: what ``tiled_matmul`` ran at such a shape until ISSUE 72."""
+    return gmm._two_lowerings(rows, w, gmm.group_tiles(sizes, rows.shape[0]),
+                              True)
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("product", ["forward", "backward_data",
+                                     "backward_weight"])
+def test_a_weight_read_through_its_transpose_gives_the_same_products(
+        product, dtype, layout):
+    """``N`` = 192 is no whole number of lane tiles and ``K`` = 256 is:
+    ``tiled_matmul`` hands the kernels ``swapaxes(w, 1, 2)``.  Output,
+    the rows' gradient and the weight's, in ``w``'s own ``(E, K, N)``, are
+    the plain orientation's bit for bit (the same float32 sums over the
+    same tiles) and ``ragged_dot``'s within its tolerance, over empty
+    groups and a window whose groups end before its rows do (rows behind
+    the last group are selected away: the kernels leave them unwritten)."""
+    assert gmm.reads_turned(TURNED_K, TURNED_N)
+    assert not gmm.reads_turned(TURNED_N, TURNED_K)
+    got, want = (x[product] for x in _both_ways(
+        layout, dtype, kn=(TURNED_K, TURNED_N)))
+    plain = _products(_kernels_as_the_weight_lies, layout, dtype,
+                      TURNED_K, TURNED_N)[product]
+    assert got.dtype == want.dtype == plain.dtype
+    assert got.shape == want.shape == plain.shape
+    got, want, plain = (np.asarray(x, np.float32)
+                        for x in (got, want, plain))
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, plain)
+    assert np.abs(got - want).max() <= TOLERANCE[dtype] * max(
+        np.abs(want).max(), 1.0)
+    if product == "backward_weight":
+        empty = [i for i, s in enumerate(LAYOUTS[layout]) if s == 0]
+        assert not got[empty].any(), "an expert without rows writes zeros"
+    elif layout == "fewer_rows_than_m":
+        assert not got[sum(LAYOUTS[layout]):].any()
+
+
+def _lowerings(fn, *args):
+    """``moe:gmm_lowering``'s samples of one trace of ``fn``:
+    ``[(track, fields)]``; a sample that is not turned carries no
+    ``turned`` field."""
+    return [(e["id"], e["args"]) for e in _counter_events(
+        "moe:gmm_lowering", lambda: jax.make_jaxpr(fn)(*args))]
+
+
+@pytest.mark.parametrize("cell", sorted(
+    {c[0] for c in CELL_SHAPES} | {"nemotron-3-nano-30b-a3b"}))
+def test_of_the_ten_expert_cells_only_nemotrons_up_projection_turns(cell):
+    """The orientation is chosen from the stacked weight's shape alone.
+    Of the ten configurations' ``(E, D, W)`` (gate / up) and ``(E, W, D)``
+    (down), read from the configuration files, ``(8, 2688, 1856)`` is the
+    only one whose last dimension is no whole number of lane tiles; the
+    counter says ``turned`` there and for no other product, a kernel's or
+    not."""
+    kw = _cell_kwargs(cell)
+    e = kw.get("experts_held") or kw["num_experts"]
+    d, w = kw["hidden_size"], kw["expert_width"]
+    turns = {(e, d, w): gmm.reads_turned(d, w),
+             (e, w, d): gmm.reads_turned(w, d)}
+    assert [s for s, t in turns.items() if t] == (
+        [(8, 2688, 1856)] if cell.startswith("nemotron") else [])
+    sizes = jnp.zeros((e,), jnp.int32)
+    for (e, k, n), turned in turns.items():
+        for rows in (gmm.ROW_TILE, 100):      # the kernels; no whole row tile
+            got = _lowerings(
+                lambda x, w: dispatch.grouped_matmul(x, w, sizes),
+                jax.ShapeDtypeStruct((rows, k), jnp.bfloat16),
+                jax.ShapeDtypeStruct((e, k, n), jnp.bfloat16))
+            kernel = rows == gmm.ROW_TILE
+            assert got == [("bfloat16[%d] x [%d, %d, %d]" % (rows, e, k, n),
+                            dict({"kernel": int(kernel),
+                                  "plain": int(not kernel)},
+                                 **({"turned": 1} if turned and kernel
+                                    else {})))]
 
 
 @pytest.mark.parametrize("experts,tiles", [(1, 1), (4, 2), (8, 16), (64, 4)])
@@ -283,6 +374,9 @@ def test_a_ranks_share_reads_zero_behind_its_groups(interpreted):
     ("a_dtype_without_a_kernel", ROWS, K, N, "float16", 1),
     ("a_program_over_two_devices", ROWS, K, N, "float32", 2),
     ("the_kernels", ROWS, K, N, "float32", 1),
+    ("a_turned_weight_over_two_devices", ROWS, TURNED_K, TURNED_N,
+     "float32", 2),
+    ("the_kernels_turned", ROWS, TURNED_K, TURNED_N, "float32", 1),
 ])
 def test_what_the_kernels_refuse_keeps_ragged_dot(why, rows, k, n, dtype,
                                                   devices):
@@ -302,19 +396,22 @@ def test_what_the_kernels_refuse_keeps_ragged_dot(why, rows, k, n, dtype,
                 x, w, groups).astype(jnp.float32).sum(), argnums=(0, 1))
             return grad(x, w)
 
-    want = (3, 4) if why == "the_kernels" else (0, 3)
+    want = (3, 4) if why.startswith("the_kernels") else (0, 3)
     assert _primitives(both, x, w) == want
 
 
+@pytest.mark.parametrize("k,n", [(K, N), (TURNED_K, TURNED_N)],
+                         ids=["as_it_lies", "turned"])
 @pytest.mark.parametrize("platform,custom_calls", [("cpu", 0), ("tpu", 3)])
 def test_the_platform_chooses_when_the_program_is_lowered(platform,
-                                                           custom_calls):
+                                                           custom_calls, k, n):
     """Shapes the kernels take, exported for a CPU: no Mosaic call
     (``ragged_dot``, which that platform spells out in plain operations);
     for a TPU: the three kernels under the names the benchmark's
-    roofline reader sums (``ragged-dot*``)."""
-    x = jnp.zeros((ROWS, K), jnp.bfloat16)
-    w = jnp.zeros((4, K, N), jnp.bfloat16)
+    roofline reader sums (``ragged-dot*``), whichever way they read the
+    weight."""
+    x = jnp.zeros((ROWS, k), jnp.bfloat16)
+    w = jnp.zeros((4, k, n), jnp.bfloat16)
     sizes = jnp.asarray([256] * 4, jnp.int32)
     text = jax.export.export(jax.jit(jax.grad(
         lambda x, w: jnp.square(dispatch.grouped_matmul(x, w, sizes).astype(
@@ -354,15 +451,19 @@ def _one_fused_step(net, tokens, dim, seed):
     assert np.isfinite(mod.get_outputs()[0].asnumpy()).all()
 
 
-def _gmm_traces(run):
+def _counter_events(counter, run):
+    """The samples of ``counter`` that ``run()`` leaves on the trace ring."""
     was = mx.trace.enabled()
     mx.trace.set_enabled(True)
     try:
         mark = time.perf_counter_ns()
         run()
-        return mx.trace.counter_events(["moe:gmm_trace"], since_ns=mark)
+        return mx.trace.counter_events([counter], since_ns=mark)
     finally:
         mx.trace.set_enabled(was)
+
+
+_gmm_traces = functools.partial(_counter_events, "moe:gmm_trace")
 
 
 def test_kernels_are_traced_once_a_signature_whatever_the_layers(
@@ -383,6 +484,27 @@ def test_kernels_are_traced_once_a_signature_whatever_the_layers(
             for e in traces} == {(gmm.ROW_TILE, 640, 384),
                                  (gmm.ROW_TILE, 384, 640)}
     assert len({e["id"] for e in traces}) == 6
+
+
+def test_turned_kernels_are_traced_once_a_signature_too(interpreted):
+    """Three routed layers whose gate and up projections ``(4, 640, 320)``
+    are read turned and whose down projection ``(4, 320, 640)`` is not, in
+    two fused modules: again six kernel traces.  The turned forward is
+    ``gmm_t`` and its backward-data product ``gmm``: shape for shape the
+    down projection's three products, so every track's name comes twice."""
+    net = _routed_net(3, 128, 640, 320, 4, 4)
+    traces = _gmm_traces(lambda: [_one_fused_step(net, 128, 640, seed)
+                                  for seed in (3, 4)])
+    assert sorted(e["id"] for e in traces) == sorted(
+        ["gmm_t float32[512, 640] x [4, 320, 640]",       # up, forward
+         "gmm float32[512, 320] x [4, 320, 640]",         # up, backward-data
+         "tgmm float32[512, 320] x [512, 640]",           # up, backward-weight
+         "gmm float32[512, 320] x [4, 320, 640]",         # down, forward
+         "gmm_t float32[512, 640] x [4, 320, 640]",       # down, backward-data
+         "tgmm float32[512, 320] x [512, 640]"]), [e["id"] for e in traces]
+    assert {(e["args"]["tm"], e["args"]["tk"], e["args"]["tn"])
+            for e in traces} == {(gmm.ROW_TILE, 640, 320),
+                                 (gmm.ROW_TILE, 320, 640)}
 
 
 def test_a_graph_without_routed_experts_traces_no_kernel():

@@ -32,7 +32,8 @@ from mxnet_tpu.ops.transformer import grouped_rms_norm    # noqa: E402
 
 import manifest                                           # noqa: E402
 from test_granite_hybrid import scan_errors, scan_inputs  # noqa: E402
-from test_moe_gmm import ROWS as GMM_ROWS, _both_ways     # noqa: E402
+from test_moe_gmm import (ROWS as GMM_ROWS, _both_ways,    # noqa: E402
+                          _lowerings)
 
 REF = manifest.load_module("reference", "nemotron-3-nano-30b-a3b")
 
@@ -198,27 +199,66 @@ def test_the_kernels_at_a_width_of_64_times_odd_match_ragged_dot(dtype, limit):
 
 def test_every_traced_grouped_product_says_its_lowering():
     """``moe:gmm_lowering``: ``kernel`` 1 where the tile rule takes the
-    product, ``plain`` 1 where ``ragged_dot`` stays on every platform."""
+    product, ``turned`` 1 where the kernels read the weight through its
+    transpose (a last dimension of one and a half lane tiles under a first
+    of two) and on no other sample (the benchmark's tests hold a plain
+    sample's fields to the two it had), ``plain`` 1 where ``ragged_dot``
+    stays on every platform: a shape the rule would turn too, over rows
+    that are no whole tile."""
     sizes = jnp.asarray([128, 128], jnp.int32)
 
-    def both(rows, w, narrow):
-        return gmm.tiled_matmul(rows, w, sizes).sum() \
-            + gmm.tiled_matmul(rows[:, :96], narrow, sizes).sum()
+    def all_four(rows, w, narrow, odd):
+        return gmm.tiled_matmul(rows[:, :192], w, sizes).sum() \
+            + gmm.tiled_matmul(rows[:, :96], narrow, sizes).sum() \
+            + gmm.tiled_matmul(rows, odd, sizes).sum() \
+            + gmm.tiled_matmul(rows[:100], odd, sizes).sum()
 
-    was = mx.trace.enabled()
-    mx.trace.set_enabled(True)
-    try:
-        mark = time.perf_counter_ns()
-        jax.make_jaxpr(both)(jnp.zeros((256, 192), BF16),
-                             jnp.zeros((2, 192, 64), BF16),
-                             jnp.zeros((2, 96, 64), BF16))
-        events = mx.trace.counter_events(["moe:gmm_lowering"], since_ns=mark)
-    finally:
-        mx.trace.set_enabled(was)
-    assert [(e["id"], e["args"]["kernel"], e["args"]["plain"])
-            for e in events] == [
-        ("bfloat16[256] x [2, 192, 64]", 1, 0),
-        ("bfloat16[256] x [2, 96, 64]", 0, 1)]
+    assert _lowerings(all_four, jnp.zeros((256, 256), BF16),
+                      jnp.zeros((2, 192, 64), BF16),
+                      jnp.zeros((2, 96, 64), BF16),
+                      jnp.zeros((2, 256, 192), BF16)) == [
+        ("bfloat16[256] x [2, 192, 64]", {"kernel": 1, "plain": 0}),
+        ("bfloat16[256] x [2, 96, 64]", {"kernel": 0, "plain": 1}),
+        ("bfloat16[256] x [2, 256, 192]",
+         {"kernel": 1, "plain": 0, "turned": 1}),
+        ("bfloat16[100] x [2, 256, 192]", {"kernel": 0, "plain": 1})]
+
+
+@pytest.mark.parametrize("name, held", [("_moe_expert_ffn", 0),
+                                        ("_moe_expert_ffn", 8),
+                                        ("_moe_share_ffn", 8)])
+def test_the_stacked_weights_are_declared_as_the_reference_reads_them(
+        name, held):
+    """Reading the up projection through its transpose is the kernels'
+    business: the two nodes declare ``(E, D, W)`` and ``(E, W, D)`` at the
+    cell's widths as before (the reference computes ``x @ w_up[e]``,
+    checkpoints and initializers see these shapes)."""
+    op = mx.ops.get_op(name)
+    p = op.parse_params({"num_hidden": 1856, "act_type": "relu2",
+                         "no_bias": True, "experts_held": held})
+    known = {"data": (4096, 2688), "weight": (4096, 6), "counts": (128,)}
+    shapes = op.infer_shape(p, [known.get(n) for n in op.list_arguments(p)])[0]
+    assert [s for n, s in zip(op.list_arguments(p), shapes)
+            if n.endswith("_weight") and n != "weight"] == [
+        (held or 128, 2688, 1856), (held or 128, 1856, 2688)]
+
+
+def test_the_cell_declares_its_expert_weights_in_the_shapes_it_had():
+    import json
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "nemotron-3-nano-30b-a3b.json")) as f:
+        kwargs = json.load(f)["model"]["kwargs"]
+    net = nemotron_h_lm(**kwargs)
+    inputs = {n: (1, kwargs["seq_len"]) for n in ("data", "softmax_label")}
+    shapes = dict(zip(net.list_arguments(), net.infer_shape(**inputs)[0]))
+    up = {n: s for n, s in shapes.items() if n.endswith("experts_i2h_weight")}
+    down = {n: s for n, s in shapes.items()
+            if n.endswith("experts_h2o_weight")}
+    assert len(up) == len(down) == 4
+    assert set(up.values()) == {(8, 2688, 1856)}
+    assert set(down.values()) == {(8, 1856, 2688)}
+    assert all(gmm.reads_turned(*s[1:]) for s in up.values())
+    assert not any(gmm.reads_turned(*s[1:]) for s in down.values())
 
 
 # -- the builder -----------------------------------------------------------------

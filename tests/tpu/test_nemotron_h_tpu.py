@@ -28,6 +28,14 @@ of 128, and the grouped-matmul kernels at ``K, N`` = 2688, 1856 (one block
 each) over 8 groups of ~190 rows in a window of 6144, the up and the down
 projection, against ``lax.ragged_dot``;
 ``chiprun_out/nemotron_kernel_parity.json``.
+
+The third (PR 72) holds the up projection where the state holds it: the
+kernel pair reads ``(8, 2688, 1856)`` through its transpose
+(``gmm.reads_turned``), output and both gradients ``ragged_dot``'s bit for
+bit with its time beside the orientation it had, and the cell's step
+compiled for the chip from shapes alone holds no ``copy`` that writes an
+``f32[8,2688,1856]`` in any layout (PR 71's held 24: weight, ``m``, ``v``
+and their updates, four layers); ``chiprun_out/nemotron_turned.json``.
 """
 import gc
 import json
@@ -53,6 +61,7 @@ CONV_TRACK = "bfloat16[1, 4096, 6144]/6144+bias"
 ATTN_TRACK = "bfloat16[1, 4096, 32, 128]/kv2"
 GMM_TRACKS = {"bfloat16[6144] x [8, 2688, 1856]",
               "bfloat16[6144] x [8, 1856, 2688]"}
+TURNED = "x [8, 2688, 1856]"
 
 
 def _rel(a, b):
@@ -238,6 +247,9 @@ def test_published_width_step_matches_reference():
         # window of 6144 rows, and the overflow pass's rows behind it)
         assert all(a["kernel"] == 1 and a["plain"] == 0
                    for kind in low.values() for _, a in kind)
+        # the up projection, and nothing else, is read through its transpose
+        assert all(a.get("turned", 0) == int(t.endswith(TURNED))
+                   for t, a in low["moe:gmm_"])
     first = report["adam_bf16"][str(SEED)]["lowering"]["moe:gmm_"]
     assert {t for t, _ in first} >= GMM_TRACKS
     # float8 weights are refused by at least one update limit on every
@@ -388,3 +400,99 @@ def test_the_grouped_lowerings_match_their_plain_forms_at_the_cells_shapes():
         for mine_err, theirs in zip(side["l2_err_of_the_kernels"],
                                     side["l2_err_of_ragged_dot"]):
             assert mine_err <= max(GMM_L2_ERR, 1.5 * theirs), side
+
+
+def test_the_up_projection_is_read_where_the_state_holds_it():
+    """``(8, 2688, 1856)``: 1856 is 14.5 lane tiles, so the chip holds the
+    float32 weight and Adam's moments with 2688 on the lanes, and since
+    PR 72 the kernels read it so.  The three products over 8 groups of
+    150-230 rows in a window of 6144 are ``ragged_dot``'s bit for bit and
+    the orientation the weight lies in is no slower than the one PR 71
+    ran (1.51 ms; the down projection 0.90); the cell's compiled step
+    turns no float32 array of that shape."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    import mxnet_tpu as mx
+    from mxnet_tpu.moe import gmm
+    import _gated_norm
+    rng = np.random.RandomState(72)
+    bf16 = jnp.bfloat16
+    m, e, k, n = 6144, 8, 2688, 1856
+    out_dir = os.path.join(ROOT, "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    report = {}
+
+    def save():
+        with open(os.path.join(out_dir, "nemotron_turned.json"), "w") as f:
+            json.dump(report, f, indent=1)
+
+    assert gmm.reads_turned(k, n) and not gmm.reads_turned(n, k)
+    sizes = jnp.asarray([192, 170, 230, 188, 201, 150, 214, 191], jnp.int32)
+    mine = (jnp.arange(m) < sizes.sum())[:, None]
+    rows = jnp.where(mine, jnp.asarray(rng.standard_normal((m, k)), bf16), 0)
+    w = jnp.asarray(rng.standard_normal((e, k, n)) / np.sqrt(k), bf16)
+    ct = jnp.where(mine, jnp.asarray(rng.standard_normal((m, n)), bf16), 0)
+
+    def as_it_lay(rows, w, sizes):
+        return gmm._two_lowerings(rows, w, gmm.group_tiles(sizes, m), False)
+
+    def passes(matmul):
+        def run(rows, w, ct):
+            out, vjp = jax.vjp(lambda r, w: matmul(r, w, sizes), rows, w)
+            d_rows, d_w = vjp(ct)
+            keep = lambda a: jnp.where(mine, a, 0)
+            return keep(out), keep(d_rows), d_w
+        return jax.jit(run)
+
+    mx.trace.set_enabled(True)
+    mark = time.perf_counter_ns()
+    turned, lay, plain = (passes(f) for f in (
+        gmm.tiled_matmul, as_it_lay, gmm.ragged_matmul))
+    text = turned.lower(rows, w, ct).compile().as_text()
+    assert text.count("ragged-dot-gmm") and "ragged-dot-tgmm" in text
+    which = [e_["id"].split()[0] for e_ in mx.trace.counter_events(
+        ["moe:gmm_trace"], since_ns=mark)]
+    want = [np.asarray(a, np.float32) for a in plain(rows, w, ct)]
+    report["products"] = {
+        "traced": which,
+        "max_abs_diff_from_ragged_dot": [
+            float(np.abs(np.asarray(a, np.float32) - b).max())
+            for a, b in zip(turned(rows, w, ct), want)],
+        "ms_three_products": {
+            "turned": _ms(turned, rows, w, ct),
+            "as_the_weight_lay": _ms(lay, rows, w, ct),
+            "ragged_dot": _ms(plain, rows, w, ct)}}
+    save()
+    print("\nNEMOTRON_TURNED products " + json.dumps(report["products"]),
+          flush=True)
+    del rows, w, ct, want
+    gc.collect()
+
+    # -- the cell's step, compiled for the chip from shapes alone ---------------
+    mark = time.perf_counter_ns()
+    text = _gated_norm.compiled_step_text("nemotron-3-nano-30b-a3b")
+    lowered = [[e_["id"], e_["args"]] for e_ in mx.trace.counter_events(
+        ["moe:gmm_lowering"], since_ns=mark)]
+    copies = re.findall(r"= (\w+\[8,2688,1856\]\{[0-9,]*)[^ ]* copy\(", text)
+    report["step"] = {
+        "copies": text.count(" copy("),
+        "copies_of_the_up_projection": sorted(copies),
+        "layouts_of_f32_8_2688_1856": sorted(set(re.findall(
+            r"f32\[8,2688,1856\]\{([0-9,]*)", text))),
+        "mosaic_kernels": text.count("tpu_custom_call"),
+        "gmm_lowering": lowered}
+    save()
+    print("\nNEMOTRON_TURNED step " + json.dumps(report["step"]), flush=True)
+
+    products = report["products"]
+    assert sorted(which) == ["gmm", "gmm_t", "tgmm"], which
+    assert products["max_abs_diff_from_ragged_dot"] == [0.0, 0.0, 0.0]
+    ms = products["ms_three_products"]
+    assert ms["turned"] <= 1.1 * ms["as_the_weight_lay"] < ms["ragged_dot"]
+    assert not [c for c in copies if c.startswith("f32")], copies
+    # the share node's jits are traced once a process: samples only where
+    # no earlier test of this process bound the model
+    assert all(a["kernel"] == 1
+               and a.get("turned", 0) == int(t.endswith(TURNED))
+               for t, a in lowered), lowered

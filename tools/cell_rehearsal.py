@@ -13,7 +13,8 @@ kernel's tiling, too much fast memory, a program that does not fit the
 chip's 15.75 GiB) it refuses here.  Printed: arguments (the state: 12 B a
 parameter under Adam), temporaries (the float32 gradients and the
 activations the backward pass keeps) and their sum in GiB, and how many
-Mosaic kernels the program holds.  ``--text`` writes the compiled program.
+Mosaic kernels and ``copy`` operations (relayouts and same-layout copies
+the compiler adds) the program holds.  ``--text`` writes the compiled program.
 A compile that passes is not a chip run.
 """
 import argparse
@@ -98,6 +99,7 @@ def main():
         "argument_plus_temporaries_gib": round(
             (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / gib, 3),
         "mosaic_kernels": text.count("tpu_custom_call"),
+        "copies": text.count(" copy("),
         "compile_s": round(time.time() - t0, 1)}))
 
 
