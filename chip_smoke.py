@@ -338,10 +338,6 @@ def main(argv=None):
     eval_phase(mod, X, y, it)
     phase("kernels: compiled by Mosaic vs jnp twins")
     kernel_phase()
-    if os.environ.get("MXNET_COMPILE_CACHE"):
-        from mxnet_tpu import profiler
-        bypasses = profiler.compile_report()["totals"]["bypasses"]
-        check(bypasses == 0, "MXNET_COMPILE_CACHE: no program bypassed it")
     phase("done: %d compile requests in the train phase, %d compiled, %d "
           "from the persistent cache"
           % (counter.count, counter.compiled, counter.cache_hits))

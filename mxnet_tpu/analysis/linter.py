@@ -12,8 +12,8 @@ Rules
 donated-aliasing   ``jax.device_put`` of a host buffer flowing into
                    donated state without ``jnp.copy`` (PR 2 / PR 7r2:
                    nondeterministic result corruption on CPU zero-copy)
-raw-jit            ``jax.jit`` outside ``compile_cache`` — bypasses the
-                   persistent executable cache (PR 5's whole point)
+raw-jit            ``jax.jit`` outside ``compile_cache`` — a program with
+                   no name, no AOT handle and no compile counters
 raw-dist-init      ``jax.distributed.initialize`` outside
                    ``mxnet_tpu/dist/`` — the process-group boot is
                    single-owner (gloo selection, pre-backend ordering,
@@ -262,8 +262,8 @@ class _Ctx:
 # rules
 
 def _rule_raw_jit(ctx: _Ctx) -> Iterable[Finding]:
-    """jax.jit outside compile_cache: bypasses the persistent executable
-    cache — every restart pays the full XLA compile (CHANGES PR 5)."""
+    """jax.jit outside compile_cache: a program the compile counters,
+    the scope table and the AOT warm-up cannot see."""
     if ctx.rel.startswith("mxnet_tpu/compile_cache/"):
         return
     for node in ast.walk(ctx.tree):
@@ -273,8 +273,8 @@ def _rule_raw_jit(ctx: _Ctx) -> Iterable[Finding]:
             yield ctx.finding(
                 "raw-jit", node,
                 "jax.jit bypasses compile_cache.cached_jit — route through "
-                "the persistent executable cache, or suppress with the "
-                "serialization reason (donation layout / pallas)")
+                "the one wrapper (name, AOT handle, counters), or suppress "
+                "with the reason (donation layout / pallas)")
 
 
 _PALLAS_CALLS = ("pl.pallas_call", "pallas.pallas_call",

@@ -1,97 +1,45 @@
-"""cached_jit: jax.jit with a persistent, cross-process executable cache.
+"""cached_jit: the one wrapper of ``jax.jit``.
 
-``cached_jit(fn)`` behaves exactly like ``jax.jit(fn)`` until a cache is
-active (``MXNET_COMPILE_CACHE=<dir>`` or ``configure()``); the serving
-and training entry points route every program through it.  With a cache:
+``cached_jit(fn, name=...)`` behaves exactly like ``jax.jit(fn)`` until
+something warms it; every program the training and serving entry points
+build goes through it.  What it adds:
 
-* first call lowers the function (``jit(...).lower(args)``), keys the
-  lowered StableHLO text + environment (fingerprint.py), and looks the
-  key up on disk;
-* a **hit** deserializes the PJRT executable — milliseconds instead of
-  the XLA optimization pipeline — and wraps it in a
-  ``_CachedExecutable`` that replays it through
-  ``LoadedExecutable.execute`` with the recorded input pruning
-  (jit drops unused args from the executable), device placement, and
-  output pytree;
-* a **miss** compiles via the AOT path (``lowered.compile()``),
-  serializes the executable, and publishes it atomically;
-* anything the fast path cannot express — multi-process meshes, input
-  shardings without a recipe, a backend whose PJRT client cannot
-  serialize — **bypasses**: the program compiles exactly as before (and
-  a serialize-incapable backend flips the cache to JAX's built-in
-  persistent compilation cache so later compiles still persist).
+* a **name**: the key of the compile counters
+  (``mx.profiler.compile_report()``) and of the scope table
+  (``trace.scopes.register_program``);
+* an **AOT handle**: ``warm(*args)`` / ``compile_for(*args)`` lower and
+  compile the program for these arguments without running it, which is
+  what ``Module.prepare``, ``BucketingModule.precompile``,
+  ``FusedTrainStep.aot_compile`` and the serve warm-up grid ride;
+* a **dispatch** for what was warmed: the compiled executable is wrapped
+  in a ``_CachedExecutable`` that replays it through
+  ``LoadedExecutable.execute`` with the recorded input pruning (jit drops
+  unused args from the executable), device placement, and output pytree.
+  The first call of a wrapped executable is validated (arity, avals,
+  placement); one that refuses it is replaced by a fresh ``Compiled``.
 
-A cache entry can only ever fail toward a recompile: checksums are
-verified before PJRT sees the blob, the first call of a deserialized
-executable is validated (arity, avals, placement) and any failure drops
-the entry, warns once, and compiles fresh.
+Nothing is persisted here: what survives a restart is JAX's persistent
+compilation cache, which ``place_jax_cache`` (`jaxcache.py`) places.
 """
 from __future__ import annotations
 
-import contextlib
-import threading
+import logging
 import time
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .. import trace as _trace
-from ..base import get_env, make_lock
-from .fingerprint import (environment_fingerprint,
-                          fast_key as _fast_key_of, program_key)
+from ..base import make_lock
 from .stats import get_stats
-from .store import CacheStore, warn_once
 
-__all__ = ["CachedFunction", "CompileCache", "cached_jit", "get_cache",
-           "configure", "reset"]
+__all__ = ["CachedFunction", "cached_jit"]
 
-DEFAULT_SIZE_MB = 2048.0
+logger = logging.getLogger(__name__)
 
 
 class _CacheEntryInvalid(Exception):
-    """Raised when a deserialized entry cannot serve the call; always
+    """Raised when a wrapped executable cannot serve the call; always
     handled by falling back to a fresh compile."""
-
-
-_nocache_lock = make_lock("compile_cache.nocache")
-_nocache_depth = 0
-_nocache_prev = True
-
-
-@contextlib.contextmanager
-def _fresh_compile_ctx():
-    """Compile OUTSIDE jax's builtin persistent compilation cache.
-
-    An executable that jax served from ITS disk cache re-serializes into
-    a blob missing its jitted kernel symbols — deserializing that later
-    fails with "Symbols not found" (measured on CPU PJRT), so every
-    executable WE intend to serialize must come from a fresh backend
-    compile.  The thread-local ``enable_compilation_cache(False)``
-    context is NOT enough: ``compilation_cache.is_cache_used`` memoizes
-    its verdict once per process, so after any ordinary compile the
-    flag is ignored.  Instead the cache is disabled process-wide for
-    the duration (refcounted — overlapping warmup-pool compiles share
-    one window) with ``reset_cache()`` dropping the memo on the way in
-    AND out; an unrelated compile racing the window merely skips the
-    jax cache once."""
-    global _nocache_depth, _nocache_prev
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache as jax_cc
-    with _nocache_lock:
-        if _nocache_depth == 0:
-            _nocache_prev = bool(jax.config.jax_enable_compilation_cache)
-            jax_cc.reset_cache()
-            jax.config.update("jax_enable_compilation_cache", False)
-        _nocache_depth += 1
-    try:
-        yield
-    finally:
-        with _nocache_lock:
-            _nocache_depth -= 1
-            if _nocache_depth == 0:
-                jax.config.update("jax_enable_compilation_cache",
-                                  _nocache_prev)
-                jax_cc.reset_cache()
 
 
 # -- leaf plumbing -----------------------------------------------------------
@@ -127,60 +75,11 @@ def _sig_leaf(x):
     return _leaf_aval(x)
 
 
-def _sharding_recipe(s):
-    """Reconstructable description of an input sharding, or None when it
-    has no recipe (such a program is compiled but not cached)."""
-    from jax.sharding import NamedSharding, SingleDeviceSharding
-    if isinstance(s, SingleDeviceSharding):
-        (dev,) = tuple(s.device_set)
-        return ("dev", int(dev.id))
-    if isinstance(s, NamedSharding):
-        mesh = s.mesh
-        spec = tuple(tuple(e) if isinstance(e, (list, tuple)) else e
-                     for e in tuple(s.spec))
-        return ("named", tuple(int(n) for n in mesh.devices.shape),
-                tuple(mesh.axis_names), spec,
-                tuple(int(d.id) for d in mesh.devices.ravel()))
-    return None
-
-
-def _placement_extras(args) -> str:
-    """Ordered device placement of every argument leaf — the part of a
-    program's identity its HLO text does not carry.  The platform rides
-    along: device ids repeat across platforms, and the same StableHLO
-    placed on cpu(0) and on tpu(0) of one process are two programs."""
-    import jax
-    parts = []
-    for x in jax.tree_util.tree_flatten(args)[0]:
-        sh = getattr(x, "sharding", None)
-        parts.append(None if sh is None else
-                     (next(iter(sh.device_set)).platform,
-                      _sharding_recipe(sh)))
-    return repr(parts)
-
-
-def _recipe_to_sharding(r, by_id):
-    """``by_id``: the executable's OWN client's devices.  Device ids
-    repeat across platforms (TFRT_CPU_0 and TPU_0 are both id 0), so a
-    cpu-context program in a TPU process must not resolve its recipe
-    against the default backend."""
-    from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
-                              SingleDeviceSharding)
-    if r[0] == "dev":
-        return SingleDeviceSharding(by_id[r[1]])
-    if r[0] == "named":
-        _tag, shape, axes, spec, ids = r
-        devs = np.array([by_id[i] for i in ids]).reshape(shape)
-        return NamedSharding(Mesh(devs, tuple(axes)),
-                             PartitionSpec(*spec))
-    raise ValueError("unknown sharding recipe %r" % (r[0],))
-
-
-# -- the deserialized-executable callable ------------------------------------
+# -- the raw-execute callable -------------------------------------------------
 
 class _CachedExecutable:
-    """Callable over the original args pytree, backed by a deserialized
-    PJRT executable.
+    """Callable over the original args pytree, backed by a compiled
+    PJRT executable (``_wrap_live`` builds it).
 
     Input shardings are the EXECUTABLE's (``Compiled.input_shardings``),
     not the call args': jit repositions uncommitted arguments (an
@@ -197,7 +96,7 @@ class _CachedExecutable:
                  avals: Sequence[Tuple[Tuple[int, ...], str]],
                  shardings: Sequence[Any],
                  out_avals: Sequence[Tuple[Tuple[int, ...], str]],
-                 out_shardings: Sequence[Any], name: str, key: str):
+                 out_shardings: Sequence[Any], name: str):
         self._loaded = loaded
         self._out_tree = out_tree
         self._kept = tuple(kept)
@@ -208,7 +107,6 @@ class _CachedExecutable:
         self._multi = any(s is not None and len(s.device_set) > 1
                           for s in tuple(shardings) + tuple(out_shardings))
         self.name = name
-        self.key = key
         self._validated = False
 
     def _place(self, i: int, x):
@@ -265,12 +163,12 @@ class _CachedExecutable:
 
 
 def _wrap_live(compiled, lowered, args, name: str):
-    """Wrap a FRESHLY compiled executable in the same raw-execute path
-    deserialized entries use, or None when it cannot be expressed.
+    """Wrap a compiled executable in the raw-execute path, or None when
+    it cannot be expressed.
 
-    This is a steady-state dispatch optimization, not just a cache
-    concern: per call on a 150-leaf train state this host measured raw
-    ``execute`` at 1.8ms vs 2.2ms through jit dispatch and 3.4ms through
+    This is a steady-state dispatch optimization: per call on a
+    150-leaf train state a CPU host measured raw ``execute`` at 1.8ms
+    vs 2.2ms through jit dispatch and 3.4ms through
     ``Compiled.__call__`` — without it, every warmed program (serve
     construction warms ALL buckets by default) would pay the slowest
     path forever."""
@@ -298,7 +196,7 @@ def _wrap_live(compiled, lowered, args, name: str):
             compiled.runtime_executable(), lowered.out_tree, kept,
             [_leaf_aval(flat[i]) for i in kept], in_sh,
             [(tuple(i.shape), str(i.dtype)) for i in out_info], out_sh,
-            name, key=None)
+            name)
     except Exception:
         return None
 
@@ -335,262 +233,6 @@ def _arg_specs(args):
     return jax.tree_util.tree_map(spec, args)
 
 
-# -- the disk-backed cache ---------------------------------------------------
-
-class CompileCache:
-    """Persistent executable cache over one directory (see module
-    docstring).  Thread-safe; shared by every CachedFunction in the
-    process via ``get_cache()``."""
-
-    def __init__(self, directory: str, size_mb: Optional[float] = None):
-        if size_mb is None:
-            size_mb = get_env("MXNET_COMPILE_CACHE_SIZE_MB",
-                              DEFAULT_SIZE_MB, float)
-        self.store = CacheStore(directory, size_mb)
-        self.mode = "serialize"
-
-    # -- keying ------------------------------------------------------------
-    def key_for(self, lowered, args) -> str:
-        """HLO text alone is NOT the whole program: the device
-        assignment is a compile parameter that never appears in it (the
-        same step lowered for a mesh over devices (1,2) vs (2,3) — or
-        (1,2) vs (2,1) — is textually identical but placed differently),
-        so the args' ordered placement recipes join the key."""
-        return program_key(lowered.as_text(),
-                           extras=(_placement_extras(args),),
-                           env_fp=environment_fingerprint())
-
-    def bypass_reason(self) -> Optional[str]:
-        if self.mode != "serialize":
-            return "builtin-fallback"
-        import jax
-        if jax.process_count() > 1:
-            return "multi-process"
-        return None
-
-    # -- load / store ------------------------------------------------------
-    def load_entry(self, key: str, name: str):
-        """-> validated-on-first-call _CachedExecutable, or None.  Fully
-        self-contained: the sidecar carries the output pytree, input
-        pruning, avals and placement, so a hit needs NO lowering."""
-        res = self.store.load(key)
-        if res is None:
-            return None
-        blob, meta = res
-        import jax
-        t0 = time.perf_counter()
-        try:
-            platform = meta.get("platform")
-            if platform:
-                client = jax.local_devices(backend=platform)[0].client
-            else:
-                client = jax.devices()[0].client
-            by_id = {d.id: d for d in client.devices()}
-            loaded = client.deserialize_executable(
-                blob, [by_id[i] for i in meta["devices"]])
-            shardings = [_recipe_to_sharding(r, by_id)
-                         for r in meta["shardings"]]
-            out_shardings = [_recipe_to_sharding(r, by_id)
-                             for r in meta["out_shardings"]]
-            entry = _CachedExecutable(
-                loaded, meta["out_tree"], meta["kept"], meta["avals"],
-                shardings, meta["out_avals"], out_shardings, name, key)
-        except Exception as e:
-            warn_once(
-                "deserialize",
-                "compile cache entry %s would not deserialize on this "
-                "backend (%s: %s); recompiling"
-                % (key[:12], type(e).__name__, e))
-            self.store.invalidate(key)
-            return None
-        dt = time.perf_counter() - t0
-        get_stats().note_hit(name, dt)
-        _trace.complete("compile:deserialize", t0, dt, cat="compile",
-                        program=name)
-        return entry
-
-    def load_fast(self, fkey: str, name: str):
-        """Trace-free lookup: fast key -> index -> entry.  A dangling
-        index (its target evicted or corrupt) is dropped and reads as a
-        miss — the HLO-keyed path then takes over after one lowering."""
-        key = self.store.load_index(fkey)
-        if key is None:
-            return None
-        entry = self.load_entry(key, name)
-        if entry is None:
-            self.store.drop_index(fkey)
-        return entry
-
-    def store_entry(self, key: str, compiled, lowered, args, name: str,
-                    fkey: Optional[str] = None) -> None:
-        """Serialize + publish one freshly compiled executable; every
-        failure degrades to running uncached."""
-        import jax
-        stats = get_stats()
-        out_tree = lowered.out_tree
-        flat = jax.tree_util.tree_flatten(args)[0]
-        flat = [_canon_leaf(x) for x in flat]
-        try:
-            kept = sorted(compiled._executable._kept_var_idx)
-        except Exception:
-            kept = list(range(len(flat)))
-        if kept and kept[-1] >= len(flat):
-            stats.note_bypass(name, "arg-pruning-opaque")
-            return
-
-        def is_sharding(x):
-            return hasattr(x, "device_set")
-
-        # placement from the EXECUTABLE, not the args: jit repositions
-        # uncommitted inputs (e.g. an unpinned RNG key lands replicated
-        # on the mesh) and replay must reproduce that
-        try:
-            in_sh = jax.tree_util.tree_leaves(compiled.input_shardings[0],
-                                              is_leaf=is_sharding)
-            out_sh = jax.tree_util.tree_leaves(compiled.output_shardings,
-                                               is_leaf=is_sharding)
-            out_info = jax.tree_util.tree_leaves(lowered.out_info)
-        except Exception:
-            stats.note_bypass(name, "shardings-opaque")
-            return
-        if len(in_sh) != len(kept) or len(out_sh) != len(out_info):
-            stats.note_bypass(name, "shardings-opaque")
-            return
-        avals, recipes = [], []
-        for i, sh in zip(kept, in_sh):
-            r = _sharding_recipe(sh)
-            if r is None:
-                stats.note_bypass(name, "unserializable-sharding")
-                return
-            avals.append(_leaf_aval(flat[i]))
-            recipes.append(r)
-        out_avals, out_recipes = [], []
-        for info, sh in zip(out_info, out_sh):
-            r = _sharding_recipe(sh)
-            if r is None:
-                stats.note_bypass(name, "unserializable-sharding")
-                return
-            out_avals.append((tuple(info.shape), str(info.dtype)))
-            out_recipes.append(r)
-        try:
-            rex = compiled.runtime_executable()
-            # the executable's OWN client (a cpu-ctx program in a process
-            # whose default backend is the TPU must not serialize
-            # through the TPU client)
-            client = getattr(rex, "client", None) or jax.devices()[0].client
-            platform = client.platform
-            devices = list(rex.local_devices())
-            blob = client.serialize_executable(rex)
-        except Exception as e:
-            self._serialize_unavailable(e)
-            stats.note_bypass(name, "serialize-unavailable")
-            return
-        # verify before publishing: CPU PJRT has produced blobs that
-        # reference unexported kernel symbols (executables served from
-        # jax's own cache, among others) — a blob that cannot load NOW
-        # will never load, and publishing it would cost every later
-        # process a failed deserialize
-        try:
-            client.deserialize_executable(blob, devices)
-        except Exception as e:
-            warn_once(
-                "blob-verify",
-                "freshly serialized executable for %s would not "
-                "deserialize (%s: %s); not caching this program"
-                % (name, type(e).__name__, e))
-            stats.note_bypass(name, "unserializable-blob")
-            return
-        import jaxlib
-        meta = {"name": name, "kept": kept, "avals": avals,
-                "shardings": recipes, "platform": platform,
-                "devices": [int(d.id) for d in devices],
-                "out_tree": out_tree, "out_avals": out_avals,
-                "out_shardings": out_recipes,
-                "jax": (jax.__version__, jaxlib.__version__)}
-        nbytes = self.store.save(key, blob, meta)
-        stats.note_store(nbytes)
-        # index only a PUBLISHED entry: a failed save already invalidated
-        # the key, and a dangling index would defeat the trace-free path
-        # with one wasted lookup per warm start until it self-healed
-        if fkey is not None and nbytes > 0:
-            self.store.save_index(fkey, key)
-
-    # -- builtin-cache fallback --------------------------------------------
-    def _serialize_unavailable(self, exc) -> None:
-        """PJRT executable serialization missing on this backend: this
-        cache stands down (every program bypasses from here on) and
-        JAX's own persistent compilation cache, wherever the entry point
-        placed it (``place_jax_cache``), is what persists compiles."""
-        if self.mode != "serialize":
-            return
-        self.mode = "builtin"
-        import jax
-        warn_once("serialize-unavailable",
-                  "PJRT executable serialization unavailable on this "
-                  "backend (%s: %s); JAX's persistent compilation cache "
-                  "at %r stays in charge"
-                  % (type(exc).__name__, exc,
-                     jax.config.jax_compilation_cache_dir))
-
-    def describe(self) -> dict:
-        return {"directory": self.store.directory, "mode": self.mode,
-                "entries": self.store.entry_count(),
-                "disk_bytes": self.store.disk_bytes(),
-                "size_mb": self.store.size_bytes / 2 ** 20}
-
-
-# -- process-global cache handle ---------------------------------------------
-
-_cache: Optional[CompileCache] = None
-_cache_resolved = False
-_cache_lock = make_lock("compile_cache.configure")
-
-
-def get_cache() -> Optional[CompileCache]:
-    """The active cache, or None (default: ``MXNET_COMPILE_CACHE`` env
-    var names the directory; empty/unset = off)."""
-    global _cache, _cache_resolved
-    if _cache_resolved:
-        return _cache
-    with _cache_lock:
-        if _cache_resolved:
-            return _cache
-        d = (get_env("MXNET_COMPILE_CACHE") or "").strip()
-        cache = None
-        if d:
-            try:
-                cache = CompileCache(d)
-            except Exception as e:
-                warn_once("cache-init",
-                          "MXNET_COMPILE_CACHE=%r unusable (%s: %s); "
-                          "running uncached" % (d, type(e).__name__, e))
-        _cache = cache
-        _cache_resolved = True
-    return _cache
-
-
-def configure(directory: Optional[str],
-              size_mb: Optional[float] = None) -> Optional[CompileCache]:
-    """Programmatic cache setup (None disables).  Re-reads the
-    environment fingerprint so a test that monkeypatched flags keys
-    correctly."""
-    global _cache, _cache_resolved
-    with _cache_lock:
-        environment_fingerprint(refresh=True)
-        _cache = CompileCache(directory, size_mb) if directory else None
-        _cache_resolved = True
-    return _cache
-
-
-def reset() -> None:
-    """Forget the configured cache (next get_cache() re-reads the env)."""
-    global _cache, _cache_resolved
-    with _cache_lock:
-        _cache = None
-        _cache_resolved = False
-        environment_fingerprint(refresh=True)
-
-
 # -- the jit wrapper ---------------------------------------------------------
 
 def _signature(args) -> Tuple:
@@ -599,29 +241,20 @@ def _signature(args) -> Tuple:
     return (treedef, tuple(_sig_leaf(x) for x in flat))
 
 
-def _sig_string(sig: Tuple) -> str:
-    """Deterministic text form of a signature (treedef and ShapedArray
-    reprs are stable for a given structure) — the aval half of a fast
-    key."""
-    treedef, avals = sig
-    return "%s|%s" % (treedef, avals)
-
 
 class CachedFunction:
-    """Drop-in jax.jit wrapper with cache-aware AOT dispatch.
+    """Drop-in jax.jit wrapper with AOT dispatch.
 
-    With no cache configured and no ``warm()`` call, ``__call__``
-    delegates straight to the wrapped ``jax.jit`` function — the default
-    path is byte-for-byte the old behavior.  Otherwise calls dispatch on
-    the args' aval signature to a per-signature entry: a deserialized
-    ``_CachedExecutable`` (cache hit) or the AOT-compiled ``Compiled``
-    (miss/bypass — also what ``warm()`` installs so a pre-compiled
-    program is found by the later identical call instead of recompiling
-    inside jit's own cache)."""
+    With no ``warm()`` call, ``__call__`` delegates straight to the
+    wrapped ``jax.jit`` function.  Otherwise calls dispatch on the args'
+    aval signature to a per-signature entry: the AOT-compiled program as
+    a ``_CachedExecutable`` (or the ``Compiled`` itself where raw
+    execute cannot express it), which is what ``warm()`` installs so a
+    pre-compiled program is found by the later identical call instead
+    of recompiling inside jit's own cache."""
 
     def __init__(self, fn, name: Optional[str] = None,
-                 donate_argnums=None, fast_key: Optional[str] = None,
-                 **jit_kwargs):
+                 donate_argnums=None, **jit_kwargs):
         import jax
         if "static_argnums" in jit_kwargs:
             raise ValueError("cached_jit supports dynamic args only; "
@@ -631,35 +264,26 @@ class CachedFunction:
         if donate_argnums is not None:
             jit_kwargs["donate_argnums"] = donate_argnums
         self._jit = jax.jit(fn, **jit_kwargs)
-        # fast_key: caller-supplied description of everything the traced
-        # program depends on beyond the input avals (symbol-graph hash,
-        # optimizer hparams, flags).  Lets a warm start skip tracing
-        # entirely: fast_key + aval signature + env/code fingerprints
-        # index straight into the disk entry.  The HLO-text key stays
-        # the ground truth — a fast-key miss (or any code change, via
-        # code_fingerprint) falls back to lower-then-lookup.
-        self._fast_desc = fast_key
         self._entries: Dict[Tuple, Any] = {}
         self._last: Optional[Tuple[Tuple, Any]] = None
         self._called = False
-        # the first dispatch's avals, and whether this process traced
-        # the function: what ``optimized_hlo`` needs afterwards
+        # the first dispatch's avals: what ``optimized_hlo`` needs
+        # afterwards
         self._specs = None
-        self._traced = False
         self._lock = make_lock("compile_cache.cached_fn")
 
     @property
     def has_compiled(self) -> bool:
-        """Whether any program exists yet (compiled, warmed, or loaded)."""
+        """Whether any program exists yet (compiled or warmed)."""
         return self._called or bool(self._entries)
 
     # -- public ------------------------------------------------------------
     def __call__(self, *args):
-        if not self._entries and get_cache() is None:
+        if not self._entries:
             # cold default path: plain jit, zero added machinery
             if not self._called:
                 self._specs = _arg_specs(args)
-                self._called = self._traced = True
+                self._called = True
             return self._jit(*args)
         sig = _signature(args)
         last = self._last
@@ -676,21 +300,18 @@ class CachedFunction:
         return entry(*args)
 
     def warm(self, *args) -> str:
-        """Compile (or load) the program for these args WITHOUT running
-        it — no outputs materialize, no donation happens, no aux state
-        moves.  Returns 'present' | 'hit' | 'compiled'."""
+        """Compile the program for these args WITHOUT running it — no
+        outputs materialize, no donation happens, no aux state moves.
+        Returns 'present' | 'compiled'."""
         sig = _signature(args)
         if sig in self._entries:
             return "present"
-        entry = self._acquire(sig, args)
-        # disk-backed entries carry their store key; a live wrapper
-        # (fresh compile re-dispatched through raw execute) does not
-        return "hit" if isinstance(entry, _CachedExecutable) \
-            and entry.key is not None else "compiled"
+        self._acquire(sig, args)
+        return "compiled"
 
     def compile_for(self, *args):
         """The entry (Compiled or _CachedExecutable) for these args,
-        compiling/loading if needed — the AOT handle bench and
+        compiling if needed — the AOT handle bench and
         ``FusedTrainStep.aot_compile`` install directly."""
         sig = _signature(args)
         entry = self._entries.get(sig)
@@ -707,37 +328,26 @@ class CachedFunction:
         persistent cache is on, reads it from there.  None before the
         first dispatch.  Never called on the step's path: it is what
         ``trace.scopes.program_scopes`` builds its table from, on
-        request.  A program that the fast key served without tracing is
-        traced once here, for the scopes it enters."""
+        request."""
         if self._last is not None:
             entry = self._last[1]
         else:
             entry = next(reversed(self._entries.values()), None)
-        if self._specs is not None and (entry is None or not self._traced):
-            lowered = self._jit.lower(*self._specs)
-            self._traced = True
-            if entry is None:
-                entry = lowered.compile()
+        if entry is None and self._specs is not None:
+            entry = self._jit.lower(*self._specs).compile()
         return optimized_hlo_text(entry) if entry is not None else None
 
     # -- internals ---------------------------------------------------------
     def _first_call(self, sig, entry, args):
-        """Validated first execution of a deserialized entry; any
-        failure drops the entry and compiles fresh (the corruption /
-        stale-entry tolerance contract)."""
+        """Validated first execution of a wrapped executable; any
+        failure drops the entry and compiles fresh."""
         try:
             out = entry(*args)
         except Exception as e:
-            warn_once(
-                "entry-exec",
-                "cached executable for %s failed on first use (%s: %s); "
-                "recompiling" % (self.name, type(e).__name__, e))
-            cache = get_cache()
-            if cache is not None and entry.key is not None:
-                cache.store.invalidate(entry.key)
-            # republish: the bad entry was invalidated above, so the
-            # fresh executable takes its slot for the next process
-            fresh = self._compile(args, store=True)
+            logger.warning(
+                "wrapped executable for %s failed on first use (%s: %s); "
+                "recompiling", self.name, type(e).__name__, e)
+            fresh = self._compile(args)[1]
             with self._lock:
                 self._entries[sig] = fresh
                 self._last = (sig, fresh)
@@ -754,91 +364,31 @@ class CachedFunction:
             # a second signature on an already-compiled program is a
             # RETRACE — in a steady loop that's the silent-10x bug the
             # recompile guard exists to catch
-            retrace = self.has_compiled
-            stats = get_stats()
-            cache = get_cache()
-            reason = cache.bypass_reason() if cache is not None else None
-            fkey = None
-            if cache is not None and reason is None and \
-                    self._fast_desc is not None:
-                # trace-free path: no jit.lower, no graph walk — the
-                # whole warm start is one deserialize
-                fkey = _fast_key_of(self._fast_desc, _sig_string(sig))
-                entry = cache.load_fast(fkey, self.name)
-                if entry is not None:
-                    self._entries[sig] = entry
-                    return entry
-            t0 = time.perf_counter()
-            lowered = self._jit.lower(*args)
-            self._traced = True
-            dt0 = time.perf_counter() - t0
-            stats.note_trace_lower(self.name, dt0)
-            entry = None
-            key = None
-            if cache is not None:
-                if reason is None:
-                    key = cache.key_for(lowered, args)
-                    entry = cache.load_entry(key, self.name)
-                    if entry is None:
-                        stats.note_miss(self.name)
-                    elif fkey is not None:
-                        # heal the index: the entry existed but the fast
-                        # key didn't point at it yet
-                        cache.store.save_index(fkey, key)
-                else:
-                    stats.note_bypass(self.name, reason)
-            if entry is None:
-                t1 = time.perf_counter()
-                if key is not None:
-                    with _fresh_compile_ctx():
-                        compiled = lowered.compile()
-                else:
-                    compiled = lowered.compile()
-                dt1 = time.perf_counter() - t1
-                stats.note_compile(self.name, dt1, retrace=retrace)
-                if key is not None:
-                    cache.store_entry(key, compiled, lowered, args,
-                                      self.name, fkey=fkey)
-                # dispatch future calls through the raw-execute path
-                # (measured faster than both jit and Compiled.__call__);
-                # anything it can't express keeps the Compiled handle
-                entry = _wrap_live(compiled, lowered, args,
-                                   self.name) or compiled
+            lowered, compiled = self._compile(args,
+                                              retrace=self.has_compiled)
+            # dispatch future calls through the raw-execute path
+            # (measured faster than both jit and Compiled.__call__);
+            # anything it can't express keeps the Compiled handle
+            entry = _wrap_live(compiled, lowered, args,
+                               self.name) or compiled
             self._entries[sig] = entry
             return entry
 
-    def _compile(self, args, store: bool = True):
-        """Plain AOT compile (no lookup) — the bad-entry fallback."""
+    def _compile(self, args, retrace: bool = False):
+        """Plain AOT lower + compile, counted -> (lowered, compiled)."""
         stats = get_stats()
-        cache = get_cache()
-        will_store = (store and cache is not None
-                      and cache.bypass_reason() is None)
         t0 = time.perf_counter()
         lowered = self._jit.lower(*args)
-        self._traced = True
-        dt0 = time.perf_counter() - t0
-        stats.note_trace_lower(self.name, dt0)
+        stats.note_trace_lower(self.name, time.perf_counter() - t0)
         t1 = time.perf_counter()
-        if will_store:
-            with _fresh_compile_ctx():
-                compiled = lowered.compile()
-        else:
-            compiled = lowered.compile()
-        dt1 = time.perf_counter() - t1
-        stats.note_compile(self.name, dt1)
-        if will_store:
-            fkey = None
-            if self._fast_desc is not None:
-                fkey = _fast_key_of(self._fast_desc,
-                                    _sig_string(_signature(args)))
-            cache.store_entry(cache.key_for(lowered, args), compiled,
-                              lowered, args, self.name, fkey=fkey)
-        return compiled
+        compiled = lowered.compile()
+        stats.note_compile(self.name, time.perf_counter() - t1,
+                           retrace=retrace)
+        return lowered, compiled
 
 
 def cached_jit(fn, name: Optional[str] = None, donate_argnums=None,
-               fast_key: Optional[str] = None, **jit_kwargs) -> CachedFunction:
-    """jax.jit through the persistent executable cache (see
-    CachedFunction)."""
+               **jit_kwargs) -> CachedFunction:
+    """jax.jit under a name, with an AOT handle (see CachedFunction)."""
     return CachedFunction(fn, name=name, donate_argnums=donate_argnums,
-                          fast_key=fast_key, **jit_kwargs)
+                          **jit_kwargs)

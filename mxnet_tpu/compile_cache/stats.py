@@ -1,52 +1,37 @@
 """Compilation observability: where cold-start time goes, per program.
 
 One process-global ``CompileStats`` (compilation is process-global: the
-jit caches, the disk cache, and the XLA compiler are all shared), fed by
-every ``cached_jit`` wrapper and surfaced through
+jit caches and the XLA compiler are shared), fed by every ``cached_jit``
+wrapper's AOT path and surfaced through
 ``mx.profiler.compile_report()/_str()``.
 
-Per program name: trace+lower seconds, backend-compile seconds,
-deserialize seconds, cache hits/misses/bypasses (with the bypass
-reason), and a ``steady_retraces`` counter — the number of times a
-program object that had ALREADY compiled once compiled again for a new
-input signature.  A nonzero steady retrace count is the silent-10x
+Per program name: trace+lower seconds, backend-compile seconds, the
+number of compiles, and a ``steady_retraces`` counter — the number of
+times a program object that had ALREADY compiled once compiled again for
+a new input signature.  A nonzero steady retrace count is the silent-10x
 regression (a shape/dtype wobble re-entering XLA every step) that the
 tier-1 recompile guard turns into a test failure.
 """
 from __future__ import annotations
 
-import threading
 from typing import Dict, Optional
 
 from ..base import make_lock
 
 
 class _ProgramStats:
-    __slots__ = ("trace_lower_s", "compile_s", "deserialize_s", "hits",
-                 "misses", "bypasses", "compiles", "retraces",
-                 "bypass_reasons")
+    __slots__ = ("trace_lower_s", "compile_s", "compiles", "retraces")
 
     def __init__(self):
         self.trace_lower_s = 0.0
         self.compile_s = 0.0
-        self.deserialize_s = 0.0
-        self.hits = 0
-        self.misses = 0
-        self.bypasses = 0
         self.compiles = 0
         self.retraces = 0
-        self.bypass_reasons: Dict[str, int] = {}
 
     def report(self) -> dict:
-        out = {"trace_lower_s": self.trace_lower_s,
-               "compile_s": self.compile_s,
-               "deserialize_s": self.deserialize_s,
-               "hits": self.hits, "misses": self.misses,
-               "bypasses": self.bypasses, "compiles": self.compiles,
-               "steady_retraces": self.retraces}
-        if self.bypass_reasons:
-            out["bypass_reasons"] = dict(self.bypass_reasons)
-        return out
+        return {"trace_lower_s": self.trace_lower_s,
+                "compile_s": self.compile_s, "compiles": self.compiles,
+                "steady_retraces": self.retraces}
 
 
 class CompileStats:
@@ -57,8 +42,6 @@ class CompileStats:
         self.name = name
         self._lock = make_lock("compile_cache.stats")
         self._programs: Dict[str, _ProgramStats] = {}
-        self.bytes_written = 0
-        self.entries_written = 0
 
     def _prog(self, name: str) -> _ProgramStats:
         ps = self._programs.get(name)
@@ -80,79 +63,27 @@ class CompileStats:
             if retrace:
                 ps.retraces += 1
 
-    def note_hit(self, name: str, seconds: float) -> None:
-        with self._lock:
-            ps = self._prog(name)
-            ps.deserialize_s += seconds
-            ps.hits += 1
-
-    def note_miss(self, name: str) -> None:
-        with self._lock:
-            self._prog(name).misses += 1
-
-    def note_bypass(self, name: str, reason: str) -> None:
-        with self._lock:
-            ps = self._prog(name)
-            ps.bypasses += 1
-            ps.bypass_reasons[reason] = ps.bypass_reasons.get(reason, 0) + 1
-
-    def note_store(self, nbytes: int) -> None:
-        with self._lock:
-            if nbytes > 0:
-                self.bytes_written += nbytes
-                self.entries_written += 1
-
     # -- reporting ---------------------------------------------------------
-    def totals(self) -> dict:
-        with self._lock:
-            progs = {n: p.report() for n, p in self._programs.items()}
-        tot = {"programs": len(progs),
-               "trace_lower_s": sum(p["trace_lower_s"] for p in progs.values()),
-               "compile_s": sum(p["compile_s"] for p in progs.values()),
-               "deserialize_s": sum(p["deserialize_s"] for p in progs.values()),
-               "hits": sum(p["hits"] for p in progs.values()),
-               "misses": sum(p["misses"] for p in progs.values()),
-               "bypasses": sum(p["bypasses"] for p in progs.values()),
-               "compiles": sum(p["compiles"] for p in progs.values()),
-               "steady_retraces": sum(p["steady_retraces"]
-                                      for p in progs.values()),
-               "bytes_written": self.bytes_written,
-               "entries_written": self.entries_written}
-        lookups = tot["hits"] + tot["misses"]
-        tot["hit_rate"] = (tot["hits"] / lookups) if lookups else None
-        return tot
-
-    def report(self, cache=None) -> dict:
-        """Full report; ``cache`` (a CompileCache) contributes the disk
-        view (dir, entries, bytes, mode)."""
+    def report(self) -> dict:
         with self._lock:
             progs = {n: p.report() for n, p in sorted(self._programs.items())}
-        out = {"totals": self.totals(), "per_program": progs}
-        if cache is not None:
-            out["cache"] = cache.describe()
-        return out
+        tot = {"programs": len(progs)}
+        for field in ("trace_lower_s", "compile_s", "compiles",
+                      "steady_retraces"):
+            tot[field] = sum(p[field] for p in progs.values())
+        return {"totals": tot, "per_program": progs}
 
-    def report_str(self, cache=None) -> str:
-        r = self.report(cache=cache)
+    def report_str(self) -> str:
+        r = self.report()
         t = r["totals"]
-        lines = ["%s: %d programs, %d compiles (%.2fs), %d hits (%.2fs "
-                 "deserialize), %d misses, %d bypasses, %d steady retraces"
+        lines = ["%s: %d programs, %d compiles (%.2fs), %d steady retraces"
                  % (self.name, t["programs"], t["compiles"], t["compile_s"],
-                    t["hits"], t["deserialize_s"], t["misses"],
-                    t["bypasses"], t["steady_retraces"])]
-        if t["hit_rate"] is not None:
-            lines.append("  hit_rate %.2f" % t["hit_rate"])
-        c = r.get("cache")
-        if c:
-            lines.append("  cache %s: mode=%s, %d entries, %.1f MB on disk"
-                         % (c["directory"], c["mode"], c["entries"],
-                            c["disk_bytes"] / 2 ** 20))
+                    t["steady_retraces"])]
         for name, p in r["per_program"].items():
             lines.append(
-                "  %-40s lower %6.2fs  compile %6.2fs  hit/miss/byp "
-                "%d/%d/%d" % (name[:40], p["trace_lower_s"],
-                              p["compile_s"], p["hits"], p["misses"],
-                              p["bypasses"]))
+                "  %-40s lower %6.2fs  compile %6.2fs  x%d"
+                % (name[:40], p["trace_lower_s"], p["compile_s"],
+                   p["compiles"]))
         return "\n".join(lines)
 
 

@@ -39,10 +39,6 @@ class SparseEmbedSpec:
         self.dim = int(dim)
         self.cap = int(cap) if cap else None
 
-    def describe(self):
-        """Stable tuple for compile-cache fast keys."""
-        return (self.ids_name, self.vocab, self.dim, self.cap)
-
     def __repr__(self):
         return "SparseEmbedSpec(ids=%r, vocab=%d, dim=%d, cap=%r)" % (
             self.ids_name, self.vocab, self.dim, self.cap)

@@ -211,31 +211,6 @@ class EmbeddingTable:
                 % (self.name, n_distinct, self.unique_cap, cap))
         return cap
 
-    def _desc(self, tag: str, extra=()) -> str:
-        """Trace-free fast-key description: the table geometry, sharding
-        layout, and every optimizer scalar the traced update closes
-        over (the ``fused_hparams`` contract from module/fused.py)."""
-        import hashlib
-        from ..parallel.mesh import mesh_axes
-        opt = self.optimizer
-        hparams = None
-        if opt is not None:
-            hparams = (type(opt).__name__, float(opt.wd),
-                       tuple((k, getattr(opt, k, None))
-                             for k in sorted(
-                                 getattr(opt, "fused_hparams", ()))))
-        h = hashlib.sha256()
-        parts = (tag, self.vocab, self.dim, str(self.dtype),
-                 self.unique_cap,
-                 mesh_axes(self.mesh) if self.mesh is not None else None,
-                 tuple(self.row_spec) if self._sharding is not None
-                 else None,
-                 hparams) + tuple(extra)
-        for p in parts:
-            h.update(repr(p).encode())
-            h.update(b"\x00")
-        return "embed|%s" % h.hexdigest()
-
     def _lookup_prog(self, cap: int, combiner: Optional[str]):
         key = ("lookup", cap, combiner)
         prog = self._progs.get(key)
@@ -260,8 +235,7 @@ class EmbeddingTable:
             return pooled / jnp.maximum(n, 1)[..., None]
 
         from ..compile_cache import cached_jit
-        prog = cached_jit(fn, name="embed:lookup",
-                          fast_key=self._desc("lookup", (cap, combiner)))
+        prog = cached_jit(fn, name="embed:lookup")
         self._progs[key] = prog
         return prog
 
@@ -288,8 +262,7 @@ class EmbeddingTable:
                                      opt_update, lr, wd, t)
 
         from ..compile_cache import cached_jit
-        prog = cached_jit(fn, name="embed:update", donate_argnums=(0, 1),
-                          fast_key=self._desc("update", (cap,)))
+        prog = cached_jit(fn, name="embed:update", donate_argnums=(0, 1))
         self._progs[key] = prog
         return prog
 
@@ -308,8 +281,7 @@ class EmbeddingTable:
             return table.at[uniq].add(vrows, mode="drop")
 
         from ..compile_cache import cached_jit
-        prog = cached_jit(fn, name="embed:accumulate", donate_argnums=(0,),
-                          fast_key=self._desc("accumulate", (cap,)))
+        prog = cached_jit(fn, name="embed:accumulate", donate_argnums=(0,))
         self._progs[key] = prog
         return prog
 

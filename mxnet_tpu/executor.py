@@ -188,7 +188,6 @@ class Executor:
                           for n, a in sorted(arg_dict.items()))
         self._prog_tag = "%s@%08x" % (outs[0] if outs else "exec",
                                       zlib.crc32(shapes.encode()))
-        self._prog_desc = None      # lazy: see _program_desc()
 
         # names of args that receive gradients
         self._grad_names = [n for n in symbol.list_arguments()
@@ -199,7 +198,6 @@ class Executor:
         # sharding for the RNG operand; None = classic single-device
         self._mesh = None
         self._mesh_rep = None
-        self._mesh_desc = ""
 
     # -- multichip placement -------------------------------------------------
     def set_mesh(self, mesh, param_specs=None, input_specs=None) -> None:
@@ -236,15 +234,7 @@ class Executor:
             nd._place(NamedSharding(mesh, sp))
         self._mesh = mesh
         self._mesh_rep = NamedSharding(mesh, PartitionSpec())
-        # mesh axes + specs join the program identity: the same graph
-        # placed on dp=8 vs dp=4 x tp=2 partitions differently while the
-        # device-id list stays identical
-        from .parallel.mesh import mesh_axes
-        self._mesh_desc = "mesh:%r;specs:%r" % (
-            mesh_axes(mesh),
-            sorted((n, tuple(s)) for n, s in specs.items()))
-        self._prog_desc = None      # recompute with the mesh in it
-        self._jit_cache.clear()     # programs re-key under the mesh
+        self._jit_cache.clear()     # programs are traced over the mesh
 
     # -- helpers ------------------------------------------------------------
     @property
@@ -282,14 +272,13 @@ class Executor:
 
     def _get_jit(self, kind: str):
         """kind: 'fwd_train' | 'fwd_eval' | 'fwdbwd'.  Every whole-graph
-        program goes through compile_cache.cached_jit: with
-        MXNET_COMPILE_CACHE set, a process restart deserializes the
-        executable instead of re-running XLA."""
+        program goes through compile_cache.cached_jit: a process restart
+        traces and lowers it again and reads the executable from JAX's
+        persistent cache, where an entry point placed one."""
         if kind in self._jit_cache:
             return self._jit_cache[kind]
         from .compile_cache import cached_jit
         name = "exec:%s:%s" % (kind, self._prog_tag)
-        fast_key = "exec|%s|%s" % (kind, self._program_desc())
         prog = self._prog
         if kind in ("fwdbwd", "fwdbwd_ones"):
             with_head = (kind == "fwdbwd")
@@ -306,11 +295,11 @@ class Executor:
                 grads = vjp_fn(list(head_grads))[0]
                 return outs, grads, new_aux
             if with_head:
-                jfn = cached_jit(fn, name=name, fast_key=fast_key)
+                jfn = cached_jit(fn, name=name)
             else:
                 jfn = cached_jit(lambda gargs, sargs, aux, rng:
                                  fn(gargs, sargs, aux, rng, None),
-                                 name=name, fast_key=fast_key)
+                                 name=name)
         else:
             is_train = (kind == "fwd_train")
 
@@ -319,33 +308,9 @@ class Executor:
                 # lowering has to see that (parallel.mesh.traced_devices)
                 with tracing_over(self._mesh):
                     return prog.eval(args, aux, rng, _t)
-            jfn = cached_jit(fn, name=name, fast_key=fast_key)
+            jfn = cached_jit(fn, name=name)
         self._jit_cache[kind] = jfn
         return jfn
-
-    def _program_desc(self) -> str:
-        """Everything this executor's traced programs depend on beyond
-        the input avals: the symbol graph (ops, topology, attrs — all in
-        the json), the bound dtypes, grad request layout, the device,
-        and the bulk-exec/mirror modes.  Feeds the compile cache's
-        trace-free fast key; sound alongside code_fingerprint (op
-        IMPLEMENTATIONS live in source files, not the json)."""
-        if self._prog_desc is None:
-            import hashlib
-            h = hashlib.sha256()
-            h.update(self._symbol.tojson().encode())
-            h.update(repr(sorted(
-                (n, str(a.dtype)) for n, a in self.arg_dict.items())).encode())
-            h.update(repr(sorted(
-                (n, str(a.dtype)) for n, a in self.aux_dict.items())).encode())
-            h.update(repr(sorted(self._grad_req.items())).encode())
-            h.update(repr(sorted(self._grad_names)).encode())
-            h.update(str(self._ctx).encode())
-            h.update(str(self._prog.do_mirror).encode())
-            h.update(str(self._fused_train).encode())
-            h.update(self._mesh_desc.encode())
-            self._prog_desc = h.hexdigest()
-        return self._prog_desc
 
     def default_program_kinds(self) -> Tuple[str, ...]:
         """The jit program(s) this executor's hot loop will request:
@@ -357,8 +322,7 @@ class Executor:
 
     def precompile(self, kinds: Optional[Sequence[str]] = None) -> Tuple[str, ...]:
         """AOT-compile whole-graph programs WITHOUT executing them (no
-        output buffers, no aux updates, no donation) — through the
-        persistent compile cache when one is active.  Safe to run from a
+        output buffers, no aux updates, no donation).  Safe to run from a
         warmup thread pool: tracing/compilation touch no executor state
         beyond the jit-program cache entry being built.  Eager-mode
         executors (ctx_group placement, monitors) have no whole-graph
@@ -396,8 +360,8 @@ class Executor:
         return tuple(done)
 
     def has_compiled(self) -> bool:
-        """Whether any whole-graph program has been built (compiled,
-        cache-loaded, or executed) for this executor."""
+        """Whether any whole-graph program has been built (compiled or
+        executed) for this executor."""
         return any(getattr(f, "has_compiled", True)
                    for f in self._jit_cache.values())
 

@@ -241,8 +241,8 @@ class BucketingModule(BaseModule):
         ``prepare()``: nothing executes, so no aux state moves, no
         shared gradient arrays are clobbered, and N buckets compile in
         max(compile) wall time instead of sum (XLA releases the GIL).
-        With ``MXNET_COMPILE_CACHE`` set, a restarted process loads each
-        bucket's executable from disk here instead of compiling at all.
+        A restarted process traces each bucket again here and reads its
+        executable from JAX's persistent cache, where one is placed.
 
         Parameters
         ----------

@@ -760,62 +760,16 @@ class FusedTrainStep:
         step.__name__ = _scopes.module_name("step")
         return step
 
-    def _program_desc(self, tag: str) -> str:
-        """Trace-free fast-key description for this step's programs:
-        the symbol graph plus every closed-over ingredient of the trace
-        — optimizer class + baked hparams + per-name schedule factors,
-        remat, compute dtype, sharded-update mode, mesh layout, and the
-        train/fixed/label name split.  Op and optimizer IMPLEMENTATIONS
-        are covered by the cache's code_fingerprint."""
-        import hashlib
-        from ..parallel.mesh import mesh_axes as _mesh_axes
-        h = hashlib.sha256()
-        h.update(self._prog.symbol.tojson().encode())
-        for part in (tag, type(self.optimizer).__name__,
-                     repr(self.hparam_signature()),
-                     repr(sorted(self._lr_mult.items())),
-                     repr(sorted(self._wd.items())),
-                     str(self.compute_dtype), str(self._remat),
-                     repr(self.device_augment.signature()
-                          if self.device_augment is not None else None),
-                     str(self.shard_update), str(self.global_dp),
-                     # mesh AXES, not just devices: dp=8 and dp=4 x tp=2
-                     # over the same chips partition differently but list
-                     # identical device ids — without the axis shape the
-                     # fast key would alias the two programs
-                     repr(_mesh_axes(self.mesh)),
-                     repr(sorted((n, tuple(s))
-                                 for n, s in self.param_specs.items())),
-                     # sparse-embed geometry: a cap change or a table
-                     # entering/leaving the sparse path is a different
-                     # program
-                     repr(sorted((n, sp.describe())
-                                 for n, sp in self.sparse_embeds.items())),
-                     # MoE routing geometry: belt-and-braces with the
-                     # symbol json, same as the embed specs
-                     repr(sorted((n, sp.describe())
-                                 for n, sp in self.moe_blocks.items())),
-                     # platform with the ids: cpu(0) and tpu(0) are both
-                     # device id 0 in a process that has a chip
-                     repr([(d.platform, int(d.id))
-                           for d in self.mesh.devices.ravel()]),
-                     repr(self.train_names), repr(self.fixed_names),
-                     repr(sorted(self.label_shapes.items()))):
-            h.update(str(part).encode())
-            h.update(b"\x00")
-        return "fused|%s" % h.hexdigest()
-
     def _build_step(self):
         from ..compile_cache import cached_jit
         self._step = cached_jit(self._make_step_fn(), name="fused:step",
-                                donate_argnums=(0,),
-                                fast_key=self._program_desc("step"))
+                                donate_argnums=(0,))
         _scopes.register_program("fused:step", self._step)
         return self._step
 
     def _build_fwd(self):
-        # one cached program per mode (is_train closed over rather than
-        # a static argnum: the compile cache keys concrete programs)
+        # one program per mode (is_train closed over: cached_jit takes
+        # no static argnum)
         from ..compile_cache import cached_jit
         prog = self._prog
 
@@ -830,8 +784,7 @@ class FusedTrainStep:
                     outs, _ = prog.eval(args, state["aux"], rng, is_train)
                 return outs
             mode = "train" if is_train else "eval"
-            return cached_jit(fwd, name="fused:fwd_%s" % mode,
-                              fast_key=self._program_desc("fwd_%s" % mode))
+            return cached_jit(fwd, name="fused:fwd_%s" % mode)
 
         self._fwd = {True: make(True), False: make(False)}
         return self._fwd
@@ -875,21 +828,8 @@ class FusedTrainStep:
 
         superstep.__name__ = _scopes.module_name("superstep")
         from ..compile_cache import cached_jit
-        # the traced metric reducer is part of the program; identify it
-        # by owner class + qualname — process-stable, unlike a repr with
-        # an object address (implementation changes ride code_fingerprint)
-        if metric_update is None:
-            mtag = "none"
-        else:
-            owner = getattr(metric_update, "__self__", None)
-            mtag = "%s:%s" % (
-                type(owner).__name__ if owner is not None else "",
-                getattr(metric_update, "__qualname__",
-                        type(metric_update).__name__))
         program = cached_jit(superstep, name="fused:superstep:k%d" % k,
-                             donate_argnums=(0,),
-                             fast_key=self._program_desc(
-                                 "superstep:k%d:u%d:%s" % (k, unroll, mtag)))
+                             donate_argnums=(0,))
         _scopes.register_program(program.name, program)
         return program
 
@@ -932,7 +872,7 @@ class FusedTrainStep:
         """This process's whole copy of a leaf that lies in shards."""
         # lint: allow(raw-jit) — trivial all-gather reshard with live
         # out_shardings, built on the rare classic-fallback path; never a
-        # steady-state dispatch worth a disk entry
+        # steady-state dispatch worth a name or a warm-up
         gathered = jax.jit(lambda a: a,
                            out_shardings=self._replicated())(x)
         return gathered.addressable_data(0)
@@ -975,9 +915,8 @@ class FusedTrainStep:
         executed-FLOP count from XLA cost analysis (0.0 when the backend
         cannot report one).  Keeps the (state, batch, lr, key) calling
         contract in one place; bench.py uses this so its utilization
-        numerator is the very program its loop runs.  Routed through the
-        compile cache: a warm process start installs the deserialized
-        executable without compiling."""
+        numerator is the very program its loop runs.  A warm process
+        start reads the executable from JAX's persistent cache."""
         if self._step is None:
             self._build_step()
         lr = jnp.asarray(self.optimizer.base_lr(), jnp.float32)

@@ -802,10 +802,10 @@ class Module(BaseModule):
     @_recorded("module:prepare")
     def prepare(self, data_batch=None, threads=None):
         """AOT-compile this module's hot-loop program(s) before the loop
-        runs them — through the persistent compile cache when
-        ``MXNET_COMPILE_CACHE`` is set, so a restarted process loads
-        executables instead of paying XLA again.  Compile-only: nothing
-        executes, no aux state moves, no gradients land.
+        runs them.  A restarted process traces and lowers them again and
+        reads the executables from JAX's persistent cache, where its
+        entry point placed one (``place_jax_cache``).  Compile-only:
+        nothing executes, no aux state moves, no gradients land.
 
         With the fused train step engaged this warms the one donated
         step program (``data_batch`` supplies the batch avals; default a

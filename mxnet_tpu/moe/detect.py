@@ -34,12 +34,6 @@ class MoEBlockSpec:
         self.held = slice(int(first_expert), int(first_expert)
                           + int(experts_held or num_experts))
 
-    def describe(self):
-        """Stable tuple for compile-cache fast keys."""
-        return (self.name, self.num_experts, self.k,
-                self.capacity_factor, self.renormalize,
-                self.held.start, self.held.stop)
-
     def __repr__(self):
         return ("MoEBlockSpec(name=%r, E=%d, k=%d, cf=%g, renorm=%r)"
                 % (self.name, self.num_experts, self.k,

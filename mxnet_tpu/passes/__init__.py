@@ -20,8 +20,7 @@ rewrites the graph BEFORE the compiler sees it —
 with per-pass trace spans and ``mx.profiler.passes_report()``, a
 round-trip + attr-preservation verifier after every pass, and a pipeline
 fingerprint stamped into the transformed symbol (``__passes__`` graph
-attr) that joins the compile-cache fast key — quantized and f32
-programs can never alias.
+attr), which the report and a saved graph's JSON carry.
 
 Typical serving flow (what ``ServeEngine(quantize=...)`` runs)::
 

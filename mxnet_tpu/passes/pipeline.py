@@ -18,10 +18,9 @@ with, per pass:
 The pipeline **fingerprint** — a digest of the pass list and each pass's
 config (for quantization: the calibration table digest and every baked
 scale) — is stamped into the transformed symbol's graph attrs
-(``__passes__``).  ``Symbol.tojson`` serializes graph attrs and
-``Executor._program_desc`` hashes the json, so the fingerprint joins the
-compile cache's trace-free fast key automatically: a quantized program
-and its f32 twin can never alias, even before lowering.
+(``__passes__``).  ``Symbol.tojson`` serializes graph attrs, so a saved
+graph says which pipeline made it, and ``passes_report`` shows the
+fingerprint of the last run.
 """
 from __future__ import annotations
 

@@ -121,10 +121,8 @@ class Predictor:
         else:
             params = load_ndarray_file(param_bytes_or_path)
         # graph-optimization hook (mxnet_tpu.passes): run the pipeline on
-        # the checkpointed f32 graph, bind the TRANSFORMED symbol.  The
-        # pipeline fingerprint lands in the symbol's graph attrs, which
-        # Executor._program_desc hashes into the compile-cache fast key —
-        # a quantized program can never alias its f32 twin.  set_params
+        # the checkpointed f32 graph, bind the TRANSFORMED symbol (its
+        # graph attrs carry the pipeline fingerprint).  set_params
         # replays the params-side transform (re-quantize/cast) so hot
         # weight reload keeps working against the rewritten graph.
         self._pipeline = pipeline
@@ -270,8 +268,8 @@ class Predictor:
     def precompile(self, shape_sets, threads=None):
         """Bind every shape set and AOT-compile its inference program
         through a bounded thread pool (see compile_cache.parallel_warm);
-        with a persistent cache active, a warm process start deserializes
-        instead of compiling."""
+        with JAX's persistent cache placed, a warm process start reads
+        the executables instead of compiling."""
         from .compile_cache import parallel_warm
         execs = [(dict(s), self.ensure_bound(s)) for s in shape_sets]
         return parallel_warm(

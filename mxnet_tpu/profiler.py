@@ -739,22 +739,21 @@ def online_report_str() -> str:
 
 
 # -- compilation instrumentation (mxnet_tpu.compile_cache) -------------------
-# Compilation is process-global (one XLA compiler, one jit cache, one disk
-# cache), so unlike the per-instance registries above there is exactly one
-# CompileStats, owned by the compile_cache subsystem; these are thin views.
+# Compilation is process-global (one XLA compiler, one jit cache), so unlike
+# the per-instance registries above there is exactly one CompileStats, owned
+# by the compile_cache subsystem; these are thin views.
 
 def compile_report() -> dict:
-    """Per-program trace/lower/compile seconds, cache hits / misses /
-    bypasses, steady-state retrace count, plus the disk cache's mode,
-    entry count and bytes (totals + per_program + cache keys)."""
-    from .compile_cache import get_cache, get_stats
-    return get_stats().report(cache=get_cache())
+    """Per-program trace/lower/compile seconds, compile count and
+    steady-state retrace count (totals + per_program keys)."""
+    from .compile_cache import get_stats
+    return get_stats().report()
 
 
 def compile_report_str() -> str:
     """Human-readable compile/cold-start table (see compile_report)."""
-    from .compile_cache import get_cache, get_stats
-    return get_stats().report_str(cache=get_cache())
+    from .compile_cache import get_stats
+    return get_stats().report_str()
 
 
 # -- the unified view --------------------------------------------------------

@@ -315,7 +315,7 @@ class DecodeEngine:
             from ..compile_cache import cached_jit
             self._argmax_jit = cached_jit(
                 lambda x: jnp.argmax(x, axis=-1).astype(jnp.int32),
-                name="serve:decode_argmax", fast_key="serve|decode_argmax")
+                name="serve:decode_argmax")
         return np.asarray(self._argmax_jit(logits_jax))
 
     def _zero_state_row(self, slot_idx: int) -> None:
@@ -327,8 +327,7 @@ class DecodeEngine:
             from ..compile_cache import cached_jit
             self._reset_jit = cached_jit(
                 lambda s, i: s.at[i].set(0),
-                name="serve:decode_slot_reset",
-                fast_key="serve|decode_slot_reset")
+                name="serve:decode_slot_reset")
         i = np.int32(slot_idx)
         for sname in self._state_shapes:
             arr = self._exec.arg_dict[sname]
@@ -341,12 +340,12 @@ class DecodeEngine:
             arr._set(jnp.zeros(arr.shape, arr._get().dtype))
 
     def _warmup(self) -> None:
-        """Compile + run every steady-loop program once, through the
-        persistent compile cache: the decode-step forward (one
-        ``fwd_eval`` executable at the fixed slot shapes), the slot-join
-        row reset, and the argmax sampler.  With ``MXNET_COMPILE_CACHE``
-        set a restart deserializes all three instead of compiling — the
-        decode loop itself never sees the XLA compiler."""
+        """Compile + run every steady-loop program once: the decode-step
+        forward (one ``fwd_eval`` executable at the fixed slot shapes),
+        the slot-join row reset, and the argmax sampler.  A restart
+        traces all three again and reads their executables from JAX's
+        persistent cache where one is placed — the decode loop itself
+        never sees the XLA compiler."""
         try:
             self._exec.precompile(("fwd_eval",))
         except Exception as e:

@@ -373,9 +373,9 @@ class ServeEngine:
         instead of sum; ``MXNET_SERVE_WARMUP_THREADS`` bounds the pool
         (default: one thread per bucket up to the host's cores) — and
         (3) run each bucket once, serially (cheap after compilation:
-        buffers allocate, the executable loads).  With
-        ``MXNET_COMPILE_CACHE`` set, phase 2 deserializes executables
-        from disk on a restart instead of compiling at all.
+        buffers allocate, the executable loads).  On a restart phase 2
+        traces and lowers the grid again and reads the executables from
+        JAX's persistent cache, where the entry point placed one.
 
         Any failure is re-raised as a ServeError naming the offending
         bucket and its shapes — a mid-grid compile error must not

@@ -15,10 +15,11 @@ budgets:
 When admitting a model would burst a budget, the **least-recently-used
 idle** live model is evicted: its engine drains (it has no outstanding
 requests — busy models are never evicted) and its device buffers are
-released.  Swap-in builds the engine again through the factory; with
-``MXNET_COMPILE_CACHE`` set, construction is a warm fast-key hit —
-executables deserialize instead of recompiling, so multiplexing churn
-costs buffer H2D, not XLA.  Checkpoint hot-reload composes: a factory
+released.  Swap-in builds the engine again through the factory: its
+programs are traced again and, in a process whose entry point placed
+JAX's persistent cache, their executables are read from it, so
+multiplexing churn costs a trace and buffer H2D, not XLA's backend
+compile.  Checkpoint hot-reload composes: a factory
 that reads the newest committed step makes every swap-in a deploy.
 
 ::

@@ -253,7 +253,7 @@ class PagedDecodeEngine:
         self._step_jit = cached_jit(
             functools.partial(_paged_step, cfg=cfg,
                               use_kernel=self._use_kernel),
-            name="serve:paged_step", fast_key="serve|paged_step")
+            name="serve:paged_step")
 
         self.stats = PagedStats(name, self.num_slots,
                                 self._pool.num_blocks)

@@ -62,8 +62,7 @@ class SpecDecoder:
         self._jit = cached_jit(
             functools.partial(_paged_step, cfg=draft_cfg,
                               use_kernel=use_kernel),
-            name="serve:paged_draft_step",
-            fast_key="serve|paged_draft_step")
+            name="serve:paged_draft_step")
 
     def run(self, tokens, positions, n_valid, lengths) -> np.ndarray:
         """One draft step over a (S, C) window against the draft KV

@@ -3,8 +3,8 @@
 One runtime unifies the timeline the six ``mx.profiler.*_report()``
 counter families could only summarize: every hot path (feed stages,
 reader worker decode loops, fused dispatch, superstep windows,
-checkpoint save/commit, serve request lifecycle, XLA lower/compile/
-deserialize) records spans into per-thread ring buffers, and one
+checkpoint save/commit, serve request lifecycle, XLA trace/lower/
+compile) records spans into per-thread ring buffers, and one
 ``mx.profiler.dump_trace(path)`` writes a Chrome/Perfetto-loadable
 timeline with a lane per process and thread — including the spans of
 ``feed.ParallelReader`` worker *processes*, which spill to per-worker

@@ -1,22 +1,19 @@
-"""mxnet_tpu.compile_cache: persistent executable cache + AOT warmup.
+"""mxnet_tpu.compile_cache: the one jit wrapper, its AOT warm-up, and
+JAX's persistent cache behind it.
 
-Covers the ISSUE-5 acceptance battery:
-* same program + same topology hits; any aval/flag/version change misses
-* truncated / bit-flipped / stale entries are skipped with a warning and
-  recompiled — a corrupted cache entry never fails a run
-* concurrent processes racing on one cache dir don't corrupt it
-* LRU eviction respects the size bound
+* a second instance of anything (a function, a training module, a
+  bucketing module's grid, a serve engine) in a process whose persistent
+  cache holds its programs compiles nothing on the backend
+* what ``warm()`` compiled is what the next call dispatches to, over one
+  device or a mesh; a wrapped executable that refuses its first call is
+  replaced by a fresh compile
 * parallel AOT warmup: ServeEngine grid, BucketingModule.precompile,
   Module.prepare, Executor.precompile
 * steady-state recompile guard on fit (K=1 fused and superstep K>1),
   score(), and warmed bucket/serve loops
 """
-import glob
 import os
-import pickle
-import subprocess
 import sys
-import time
 
 import numpy as np
 import pytest
@@ -25,356 +22,153 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "common"))
 
 import mxnet_tpu as mx                                    # noqa: E402
 from mxnet_tpu import compile_cache as cc                 # noqa: E402
-from mxnet_tpu.compile_cache.fingerprint import (         # noqa: E402
-    environment_fingerprint, program_key)
 from mxnet_tpu.compile_cache.stats import _reset_stats    # noqa: E402
-from mxnet_tpu.compile_cache.store import _reset_warnings  # noqa: E402
 from compile_guard import assert_no_compiles, count_backend_compiles  # noqa: E402
+from jax_cache import jax_cache_dir                       # noqa: E402,F401
 
 import jax                                                # noqa: E402
 import jax.numpy as jnp                                   # noqa: E402
 
 
-@pytest.fixture
-def cache_dir(tmp_path):
-    """Fresh cache at a tmp dir; global cache/stats state restored."""
-    d = str(tmp_path / "cc")
+@pytest.fixture(autouse=True)
+def fresh_stats():
+    """The process-global compile counters start and end empty."""
     _reset_stats()
-    _reset_warnings()
-    cc.configure(d, 64)
-    yield d
-    cc.reset()
-    _reset_stats()
-    _reset_warnings()
-
-
-@pytest.fixture
-def no_cache():
-    """Explicitly no cache (undo any ambient MXNET_COMPILE_CACHE)."""
-    _reset_stats()
-    cc.configure(None)
     yield
-    cc.reset()
     _reset_stats()
 
 
 def _totals():
-    return cc.get_stats().totals()
+    return mx.profiler.compile_report()["totals"]
 
 
 # ---------------------------------------------------------------------------
-# cache core: hit/miss keying
+# the one cache: a fresh wrapper models a process restart (jit's own
+# cache cannot help, only JAX's persistent cache can)
 
 
-def test_same_program_same_topology_hits(cache_dir):
+def test_same_program_second_instance_compiles_nothing(jax_cache_dir):
     def make():
         return cc.cached_jit(lambda x, y: jnp.tanh(x) @ y + 1.0,
                              name="t:mm")
     x = jnp.ones((16, 16))
-    r1 = make()(x, x)
-    t = _totals()
-    assert (t["hits"], t["misses"]) == (0, 1)
-    # a fresh wrapper instance models a process restart: jit's own cache
-    # cannot help, only the disk entry can
-    r2 = make()(x, x)
-    t = _totals()
-    assert (t["hits"], t["misses"]) == (1, 1)
+    with count_backend_compiles() as c:
+        r1 = make()(x, x)
+        assert (c.count, c.compiled) == (1, 1)
+        r2 = make()(x, x)
+        assert (c.count, c.compiled) == (2, 1)
+        # the AOT handle reads the same cache
+        assert make().warm(x, x) == "compiled"
+        assert (c.count, c.compiled) == (3, 1)
     assert np.allclose(np.asarray(r1), np.asarray(r2))
-    assert cc.get_cache().describe()["entries"] == 1
+    assert os.listdir(jax_cache_dir)
 
 
-def test_aval_changes_miss(cache_dir):
-    def fn(x):
-        return x * 2 + 1
+def test_aval_changes_compile_their_own_program(jax_cache_dir):
+    def make():
+        # a new function object each time: jit's own cache goes by it
+        return cc.cached_jit(lambda x: x * 2 + 1, name="t:a")
 
-    cc.cached_jit(fn, name="t:a")(jnp.ones((4, 4), jnp.float32))
-    # shape change
-    cc.cached_jit(fn, name="t:a")(jnp.ones((8, 4), jnp.float32))
-    # dtype change
-    cc.cached_jit(fn, name="t:a")(jnp.ones((4, 4), jnp.bfloat16))
-    t = _totals()
-    assert t["hits"] == 0 and t["misses"] == 3
-    assert cc.get_cache().describe()["entries"] == 3
-    # and each variant now hits
-    cc.cached_jit(fn, name="t:a")(jnp.ones((8, 4), jnp.float32))
-    assert _totals()["hits"] == 1
-
-
-def test_program_key_covers_environment():
-    """jax/jaxlib version, platform, topology, and compile flags all key
-    the entry (unit-level: the env fingerprint string feeds the hash)."""
-    text = "module @jit_f { }"
-    base = program_key(text, env_fp="jax=1;platform=cpu;XLA_FLAGS=")
-    assert base == program_key(text, env_fp="jax=1;platform=cpu;XLA_FLAGS=")
-    assert base != program_key(text, env_fp="jax=2;platform=cpu;XLA_FLAGS=")
-    assert base != program_key(text, env_fp="jax=1;platform=tpu;XLA_FLAGS=")
-    assert base != program_key(
-        text, env_fp="jax=1;platform=cpu;XLA_FLAGS=--xla_foo")
-    assert base != program_key(text + " ",
-                               env_fp="jax=1;platform=cpu;XLA_FLAGS=")
-
-
-def test_fingerprint_tracks_compile_flags(monkeypatch):
-    fp0 = environment_fingerprint(refresh=True)
-    monkeypatch.setenv("MXNET_COMPUTE_DTYPE", "bfloat16")
-    fp1 = environment_fingerprint(refresh=True)
-    assert fp0 != fp1
-    monkeypatch.delenv("MXNET_COMPUTE_DTYPE")
-    assert environment_fingerprint(refresh=True) == fp0
-
-
-def test_compute_dtype_and_remat_key_differently(cache_dir, monkeypatch):
-    """The knobs that steer program construction produce distinct
-    entries even for the same python function and avals."""
-    def run():
-        def fn(x):
-            return (x * 3).sum()
-        return cc.cached_jit(fn, name="t:flags")(jnp.ones((4,)))
-
-    run()
-    monkeypatch.setenv("MXNET_COMPUTE_DTYPE", "bfloat16")
-    environment_fingerprint(refresh=True)
-    run()
-    t = _totals()
-    assert t["hits"] == 0 and t["misses"] == 2
-    environment_fingerprint(refresh=True)
+    variants = [jnp.ones((4, 4), jnp.float32), jnp.ones((8, 4), jnp.float32),
+                jnp.ones((4, 4), jnp.bfloat16)]
+    with count_backend_compiles() as c:
+        for v in variants:
+            make()(v)
+        assert (c.count, c.compiled) == (3, 3)
+        # and each variant is now served from the cache
+        for v in variants:
+            make()(v)
+        assert (c.count, c.compiled) == (6, 3)
+    # one wrapper, three warmed signatures: three entries, each call
+    # dispatched to its own
+    f = make()
+    for v in variants:
+        f.warm(v)
+    assert len(f._entries) == 3
+    for v in variants:
+        out = f(v)
+        assert out.shape == v.shape and out.dtype == v.dtype
 
 
 # ---------------------------------------------------------------------------
-# corruption tolerance
+# the dispatch of a warmed program
 
 
-def _entry_files(cache_dir):
-    exes = sorted(glob.glob(os.path.join(cache_dir, "*.exe")))
-    metas = sorted(glob.glob(os.path.join(cache_dir, "*.meta")))
-    return exes, metas
-
-
-def test_truncated_entry_recompiles(cache_dir, caplog):
-    def make():
-        return cc.cached_jit(lambda x: jnp.sin(x) @ x, name="t:tr")
-    x = jnp.ones((8, 8))
-    want = np.asarray(make()(x))
-    exes, _ = _entry_files(cache_dir)
-    with open(exes[0], "r+b") as f:
-        f.truncate(32)
-    with caplog.at_level("WARNING"):
-        got = np.asarray(make()(x))
-    assert np.allclose(got, want)
-    assert any("recompil" in r.message for r in caplog.records)
-    t = _totals()
-    assert t["hits"] == 0 and t["misses"] == 2
-    # the republished entry is healthy again
-    _reset_warnings()
-    assert np.allclose(np.asarray(make()(x)), want)
-    assert _totals()["hits"] == 1
-
-
-def test_bitflipped_entry_recompiles(cache_dir, caplog):
-    def make():
-        return cc.cached_jit(lambda x: jnp.cos(x) @ x, name="t:flip")
-    x = jnp.ones((8, 8))
-    want = np.asarray(make()(x))
-    exes, _ = _entry_files(cache_dir)
-    with open(exes[0], "r+b") as f:
-        f.seek(os.path.getsize(exes[0]) // 2)
-        b = f.read(1)
-        f.seek(-1, os.SEEK_CUR)
-        f.write(bytes([b[0] ^ 0xFF]))
-    with caplog.at_level("WARNING"):
-        got = np.asarray(make()(x))
-    assert np.allclose(got, want)
-    assert any("checksum" in r.message for r in caplog.records)
-
-
-def test_corrupt_meta_recompiles(cache_dir):
-    def make():
-        return cc.cached_jit(lambda x: x - 7.0, name="t:meta")
+def test_wrapped_executable_refusing_first_call_falls_back(caplog):
+    """A wrapped executable that cannot serve its first call (here: a
+    pruning record naming an argument that does not exist) is dropped,
+    the program compiles fresh, and the call still succeeds."""
+    f = cc.cached_jit(lambda x: x * 5.0, name="t:stale")
     x = jnp.ones((4,))
-    want = np.asarray(make()(x))
-    _, metas = _entry_files(cache_dir)
-    with open(metas[0], "wb") as f:
-        f.write(b"not a pickle at all")
-    assert np.allclose(np.asarray(make()(x)), want)
-    assert _totals()["hits"] == 0 and _totals()["misses"] == 2
-
-
-def test_stale_entry_first_call_falls_back(cache_dir, caplog):
-    """An entry that deserializes but cannot serve the call (here: a
-    sidecar claiming an argument index that does not exist — the shape a
-    stale/mismatched entry takes) is dropped on first use, recompiled,
-    and the run still succeeds."""
-    def make():
-        return cc.cached_jit(lambda x: x * 5.0, name="t:stale")
-    x = jnp.ones((4,))
-    want = np.asarray(make()(x))
-    _, metas = _entry_files(cache_dir)
-    with open(metas[0], "rb") as f:
-        meta = pickle.load(f)
-    meta["kept"] = [7]      # nonsense pruning record
-    store = cc.get_cache().store
-    key = os.path.splitext(os.path.basename(metas[0]))[0]
-    with open(store._exe_path(key), "rb") as f:
-        blob = f.read()
-    store.save(key, blob, meta)
+    f.warm(x)
+    (sig, entry), = f._entries.items()
+    assert type(entry).__name__ == "_CachedExecutable"
+    entry._kept = (7,)      # nonsense pruning record
     with caplog.at_level("WARNING"):
-        got = np.asarray(make()(x))
-    assert np.allclose(got, want)
+        got = np.asarray(f(x))
+    assert np.allclose(got, 5.0)
     assert any("failed on first use" in r.message for r in caplog.records)
+    assert f._entries[sig] is not entry
+    assert np.allclose(np.asarray(f(x)), 5.0)
 
 
-# ---------------------------------------------------------------------------
-# LRU size bound
+def test_warm_is_compile_only_and_reports_what_it_did():
+    calls = []
 
+    def fn(x):
+        calls.append(1)             # runs at trace time only
+        return x + 1
 
-def test_lru_eviction_respects_size_bound(tmp_path):
-    d = str(tmp_path / "lru")
-    _reset_stats()
-    _reset_warnings()
-    cache = cc.configure(d, 0.01)      # 10 KB: fits only a few tiny entries
-    try:
-        def prog(i):
-            f = cc.cached_jit(lambda x: x * (i + 1), name="t:lru%d" % i)
-            f(jnp.ones((i + 2,)))
-        for i in range(8):
-            prog(i)
-            time.sleep(0.02)           # distinct mtimes for LRU order
-        assert cache.store.disk_bytes() <= cache.store.size_bytes
-        exes, metas = _entry_files(d)
-        assert 0 < len(exes) < 8       # something survived, something left
-        # survivors are the newest: the last program must still hit
-        before = _totals()["hits"]
-        prog(7)
-        assert _totals()["hits"] == before + 1
-    finally:
-        cc.reset()
-        _reset_stats()
-
-
-def test_hit_refreshes_recency(tmp_path):
-    d = str(tmp_path / "touch")
-    _reset_stats()
-    _reset_warnings()
-    cc.configure(d, 64)
-    try:
-        def prog(i):
-            f = cc.cached_jit(lambda x: x + i, name="t:touch%d" % i)
-            f(jnp.ones((3,)))
-        prog(0)
-        time.sleep(0.05)
-        prog(1)
-        time.sleep(0.05)
-        prog(0)                        # fresh wrapper -> disk hit -> touch
-        entries = cc.get_cache().store._entries()
-        assert len(entries) == 2
-        # oldest-by-mtime is now program 1's entry, not program 0's
-        exes, _ = _entry_files(d)
-        oldest_key = entries[0][1]
-        newest_key = entries[-1][1]
-        assert oldest_key != newest_key
-    finally:
-        cc.reset()
-        _reset_stats()
-
-
-# ---------------------------------------------------------------------------
-# concurrent processes racing on one directory
-
-_RACE_CHILD = r"""
-import os, sys
-import numpy as np
-sys.path.insert(0, %(repo)r)
-os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["MXNET_COMPILE_CACHE"] = %(dir)r
-import jax.numpy as jnp
-from mxnet_tpu import compile_cache as cc
-f = cc.cached_jit(lambda x: jnp.tanh(x) @ x + 3.0, name="race")
-out = np.asarray(f(jnp.ones((24, 24))))
-print("CHILD_OK %%.6f" %% float(out[0, 0]))
-"""
-
-
-def test_concurrent_processes_do_not_corrupt(tmp_path):
-    """N processes compile the same program into one empty cache dir at
-    once: every process succeeds, and the published entry is loadable
-    (atomic publish means last-writer-wins, never a torn entry)."""
-    d = str(tmp_path / "race")
-    os.makedirs(d)
-    code = _RACE_CHILD % {"repo": os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "dir": d}
-    procs = [subprocess.Popen([sys.executable, "-c", code],
-                              stdout=subprocess.PIPE,
-                              stderr=subprocess.PIPE, text=True)
-             for _ in range(3)]
-    outs = []
-    for p in procs:
-        # generous bound: three jax imports racing on a loaded 2-core
-        # tier-1 host have been observed near the minute mark
-        out, err = p.communicate(timeout=420)
-        assert p.returncode == 0, "child failed: %s" % err[-800:]
-        outs.append(out)
-    vals = [float(o.split("CHILD_OK")[1]) for o in outs]
-    assert max(vals) - min(vals) < 1e-6
-    # no temp turds, exactly one complete entry, and it loads
-    exes = glob.glob(os.path.join(d, "*.exe"))
-    metas = glob.glob(os.path.join(d, "*.meta"))
-    assert len(exes) == 1 and len(metas) == 1
-    _reset_stats()
-    _reset_warnings()
-    cc.configure(d, 64)
-    try:
-        f = cc.cached_jit(lambda x: jnp.tanh(x) @ x + 3.0, name="race")
-        np.asarray(f(jnp.ones((24, 24))))
-        assert _totals()["hits"] == 1
-    finally:
-        cc.reset()
-        _reset_stats()
-
-
-def test_fast_key_hit_skips_tracing(cache_dir):
-    """A wrapper built with a fast_key loads its executable WITHOUT
-    lowering: the warm path's trace_lower_s stays zero."""
-    def make():
-        return cc.cached_jit(lambda x: jnp.tanh(x) @ x, name="t:fast",
-                             fast_key="unit-test-fast-key-1")
-    x = jnp.ones((16, 16))
-    want = np.asarray(make()(x))
+    f = cc.cached_jit(fn, name="t:warm")
+    assert not f.has_compiled
+    x = jnp.ones((3,))
+    assert f.warm(x) == "compiled" and f.has_compiled
+    assert f.warm(x) == "present"
+    assert f.compile_for(x) is f._entries[next(iter(f._entries))]
+    assert len(calls) == 1
+    with assert_no_compiles("the call after warm()"):
+        assert np.allclose(np.asarray(f(x)), 2.0)
+    assert len(calls) == 1
     t = _totals()
-    assert t["misses"] == 1
-    base_trace = t["trace_lower_s"]
-    got = np.asarray(make()(x))
-    t = _totals()
-    assert np.allclose(got, want)
-    assert t["hits"] == 1
-    assert t["trace_lower_s"] == base_trace, \
-        "fast-key hit still traced/lowered the program"
-    # index + entry pair on disk
-    assert glob.glob(os.path.join(cache_dir, "*.idx"))
+    assert (t["programs"], t["compiles"]) == (1, 1)
 
 
-def test_fast_key_dangling_index_heals(cache_dir):
-    f1 = cc.cached_jit(lambda x: x * 9.0, name="t:heal",
-                       fast_key="unit-test-heal")
-    want = np.asarray(f1(jnp.ones((4,))))
-    # evict the entry but leave the index dangling
-    for p in _entry_files(cache_dir)[0] + _entry_files(cache_dir)[1]:
-        os.unlink(p)
-    f2 = cc.cached_jit(lambda x: x * 9.0, name="t:heal",
-                       fast_key="unit-test-heal")
-    got = np.asarray(f2(jnp.ones((4,))))
-    assert np.allclose(got, want)
-    # dangling index was dropped and republished with the fresh entry
-    f3 = cc.cached_jit(lambda x: x * 9.0, name="t:heal",
-                       fast_key="unit-test-heal")
-    base_trace = _totals()["trace_lower_s"]
-    np.asarray(f3(jnp.ones((4,))))
-    assert _totals()["trace_lower_s"] == base_trace
+def test_unwarmed_function_is_plain_jit():
+    """Nothing warmed: the call goes to ``jax.jit`` and the wrapper
+    holds no entry; ``optimized_hlo`` still finds the program."""
+    f = cc.cached_jit(lambda x: jnp.tanh(x) * 2, name="t:plain")
+    assert f.optimized_hlo() is None
+    x = jnp.ones((5,))
+    f(x)
+    f(x)
+    assert f.has_compiled and not f._entries
+    assert _totals()["programs"] == 0
+    assert "tanh" in f.optimized_hlo()
 
 
-def test_multi_device_program_roundtrips(cache_dir):
-    """An 8-device NamedSharding program (the fused mesh shape) caches
-    and replays: deserialized executables accept sharded inputs and
-    produce the same values."""
+def test_cached_jit_takes_no_static_argnums():
+    with pytest.raises(ValueError, match="dynamic args only"):
+        cc.cached_jit(lambda x, n: x * n, name="t:static",
+                      static_argnums=(1,))
+
+
+def test_donated_argument_is_consumed_by_a_warmed_program():
+    f = cc.cached_jit(lambda s, x: s + x, name="t:donate",
+                      donate_argnums=(0,))
+    s, x = jnp.ones((256,)), jnp.ones((256,))
+    f.warm(s, x)
+    out = f(s, x)
+    assert np.allclose(np.asarray(out), 2.0)
+    assert s.is_deleted() and not x.is_deleted()
+    # the specs kept for ``optimized_hlo`` hold no buffer
+    assert not any(isinstance(leaf, jax.Array)
+                   for leaf in jax.tree_util.tree_leaves(f._specs))
+
+
+def test_multi_device_program_roundtrips():
+    """An 8-device NamedSharding program (the fused mesh shape) warms
+    and replays: the wrapped executable accepts sharded inputs and
+    produces jit's values."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     mesh = Mesh(np.array(jax.devices()), ("dp",))
     sh = NamedSharding(mesh, P("dp"))
@@ -382,14 +176,17 @@ def test_multi_device_program_roundtrips(cache_dir):
 
     def make():
         return cc.cached_jit(lambda a: (a * 2).sum(0), name="t:mesh")
-    want = np.asarray(make()(x))
-    got = np.asarray(make()(x))
-    t = _totals()
-    assert (t["hits"], t["misses"]) == (1, 1)
+    want = np.asarray(make()(x))          # plain jit
+    warmed = make()
+    assert warmed.warm(x) == "compiled"
+    entry, = warmed._entries.values()
+    assert type(entry).__name__ == "_CachedExecutable" and entry._multi
+    got = np.asarray(warmed(x))
     assert np.allclose(got, want)
+    assert np.allclose(np.asarray(warmed(x)), want)
 
 
-def test_multi_device_sharded_outputs_and_uncommitted_args(cache_dir):
+def test_multi_device_sharded_outputs_and_uncommitted_args():
     """The two multi-device traps: (a) a PARTITIONED output must come
     back whole, not as shard 0 (replay reassembles from
     execute_sharded); (b) an uncommitted argument (the unpinned RNG key
@@ -409,19 +206,21 @@ def test_multi_device_sharded_outputs_and_uncommitted_args(cache_dir):
 
     def make():
         return cc.cached_jit(fn, name="t:meshout")
-    w = make()(a, key)
-    g = make()(a, key)
-    assert _totals()["hits"] == 1
+    w = make()(a, key)                    # plain jit
+    warmed = make()
+    warmed.warm(a, key)
+    entry, = warmed._entries.values()
+    assert type(entry).__name__ == "_CachedExecutable" and entry._multi
+    g = warmed(a, key)
     assert np.asarray(g["rows"]).shape == (8, 4), \
         "partitioned output came back as a single shard"
     assert np.allclose(np.asarray(g["rows"]), np.asarray(w["rows"]))
     assert np.allclose(float(g["total"]), float(w["total"]))
     # steady-state calls keep working (per-call placement of the
     # uncommitted key)
-    g2 = make()
-    g2(a, key)
-    assert np.allclose(np.asarray(g2(a, key)["rows"]),
+    assert np.allclose(np.asarray(warmed(a, key)["rows"]),
                        np.asarray(w["rows"]))
+
 
 
 # ---------------------------------------------------------------------------
@@ -443,7 +242,7 @@ def _mlp(dim=8, classes=2):
     return mx.sym.SoftmaxOutput(net, name="softmax")
 
 
-def test_executor_precompile_is_compile_only(no_cache):
+def test_executor_precompile_is_compile_only():
     """precompile builds the program without executing: outputs stay
     unset, and the later forward() finds the program already built
     (zero backend compiles) even with NO disk cache — warm() primes the
@@ -465,7 +264,7 @@ def test_executor_precompile_is_compile_only(no_cache):
     assert ex.outputs[0].shape == (2, 4)
 
 
-def test_executor_fwdbwd_precompile_covers_train_loop(no_cache):
+def test_executor_fwdbwd_precompile_covers_train_loop():
     X, y = _blobs()
     it = mx.io.NDArrayIter(X, y, batch_size=16)
     mod = mx.mod.Module(_mlp(), context=mx.cpu())
@@ -482,7 +281,7 @@ def test_executor_fwdbwd_precompile_covers_train_loop(no_cache):
         mod.backward()
 
 
-def test_module_prepare_then_fit_no_compiles(no_cache):
+def test_module_prepare_then_fit_no_compiles():
     """Module.prepare AOT-compiles the fused step; the fit loop then
     runs with zero XLA compiles from the very first batch (modulo the
     tiny eager host ops, which are primed by one throwaway batch)."""
@@ -503,7 +302,7 @@ def test_module_prepare_then_fit_no_compiles(no_cache):
     assert c.count <= 2, "fused step recompiled after prepare()"
 
 
-def test_fit_steady_state_no_compiles(no_cache):
+def test_fit_steady_state_no_compiles():
     """K=1 fused fit: after the first epoch built its programs, later
     epochs compile NOTHING (generalized from test_serve's
     no-compiles-in-loop into the shared compile_guard helper)."""
@@ -517,7 +316,7 @@ def test_fit_steady_state_no_compiles(no_cache):
                 optimizer_params={"learning_rate": 0.1})
 
 
-def test_superstep_steady_state_no_compiles(no_cache):
+def test_superstep_steady_state_no_compiles():
     """K>1 superstep fit: the scan-of-K program compiles once; later
     epochs (same K, same metric reducer) compile nothing."""
     X, y = _blobs(n=128)
@@ -530,7 +329,7 @@ def test_superstep_steady_state_no_compiles(no_cache):
                 superstep=2, optimizer_params={"learning_rate": 0.1})
 
 
-def test_score_steady_state_no_compiles(no_cache):
+def test_score_steady_state_no_compiles():
     X, y = _blobs(n=128)
     it = mx.io.NDArrayIter(X, y, batch_size=32)
     mod = mx.mod.Module(_mlp(), context=mx.cpu())
@@ -539,38 +338,6 @@ def test_score_steady_state_no_compiles(no_cache):
     mod.score(it, "acc")        # builds the eval program
     with assert_no_compiles("second score()"):
         mod.score(it, "acc")
-
-
-def test_fused_step_cache_hit_across_instances(cache_dir):
-    """Two same-shaped training modules: the second's donated fused step
-    loads from the persistent cache instead of compiling (the restart
-    story for training jobs), and training through the deserialized
-    executable matches the compiled one bitwise."""
-    X, y = _blobs(n=64)
-
-    def train():
-        it = mx.io.NDArrayIter(X, y, batch_size=32, shuffle=False)
-        mx.random.seed(7)
-        mod = mx.mod.Module(_mlp(), context=mx.cpu())
-        mod.bind(it.provide_data, it.provide_label)
-        mod.init_params(mx.init.Xavier(rnd_type="gaussian", factor_type="in",
-                                       magnitude=2))
-        mod.init_optimizer(optimizer_params={"learning_rate": 0.1})
-        for batch in it:
-            mod.forward(batch, is_train=True)
-            mod.update()
-        args, _ = mod.get_params()
-        return {k: v.asnumpy() for k, v in args.items()}
-
-    p1 = train()
-    before = _totals()
-    p2 = train()
-    after = _totals()
-    assert after["hits"] > before["hits"], \
-        "second module's programs did not hit the cache"
-    for k in p1:
-        assert np.array_equal(p1[k], p2[k]), \
-            "deserialized step diverged from compiled step on %s" % k
 
 
 # ---------------------------------------------------------------------------
@@ -598,7 +365,7 @@ def _bucketing_module():
                                   context=mx.cpu())
 
 
-def test_bucketing_precompile_then_loop_no_compiles(no_cache):
+def test_bucketing_precompile_then_loop_no_compiles():
     """precompile binds + compiles the whole bucket grid (through the
     warmup pool); a training sweep over every bucket then triggers no
     XLA compiles — the generalized no-compiles-in-loop guard applied to
@@ -635,24 +402,6 @@ def test_bucketing_precompile_then_loop_no_compiles(no_cache):
     assert set(mod._buckets.keys()) == {4, 6, 8}
 
 
-def test_bucketing_precompile_cache_hits_across_instances(cache_dir):
-    """A rebuilt bucketing module's grid loads from disk: zero backend
-    compiles the second time around."""
-    def build():
-        mod = _bucketing_module()
-        mod.bind(data_shapes=[("data", (8, 8))],
-                 label_shapes=[("softmax_label", (8,))])
-        mod.init_params()
-        mod.init_optimizer(optimizer_params={"learning_rate": 0.1})
-        mod.precompile({k: ([("data", (8, k))], [("softmax_label", (8,))])
-                        for k in (4, 8)})
-        return mod
-    build()
-    with count_backend_compiles() as c:
-        build()
-    assert c.count == 0, \
-        "warm bucket-grid precompile still hit the XLA compiler"
-
 
 # ---------------------------------------------------------------------------
 # serve engine warmup
@@ -677,35 +426,75 @@ def _engine(prefix, **kw):
     return mx.serve.ServeEngine.from_checkpoint(prefix, 0, **kw)
 
 
-def test_serve_engine_warm_restart_no_compiles(cache_dir, tmp_path):
-    """The acceptance shape: a second ('restarted') engine constructs
-    its whole bucket grid from the cache — zero XLA compiles, 100% hit
-    rate for its programs — and serves the same answers."""
-    prefix, X = _save_pair(tmp_path)
-    eng1 = _engine(prefix)
+
+# ---------------------------------------------------------------------------
+# a second instance / a restart compiles nothing on the backend
+
+
+def _train_once():
+    X, y = _blobs(n=64)
+    it = mx.io.NDArrayIter(X, y, batch_size=32, shuffle=False)
+    mx.random.seed(7)
+    mod = mx.mod.Module(_mlp(), context=mx.cpu())
+    mod.bind(it.provide_data, it.provide_label)
+    mod.init_params(mx.init.Xavier(rnd_type="gaussian", factor_type="in",
+                                   magnitude=2))
+    mod.init_optimizer(optimizer_params={"learning_rate": 0.1})
+    for batch in it:
+        mod.forward(batch, is_train=True)
+        mod.update()
+    args, _ = mod.get_params()
+    return {k: v.asnumpy() for k, v in args.items()}
+
+
+def _precompile_grid():
+    mod = _bucketing_module()
+    mod.bind(data_shapes=[("data", (8, 8))],
+             label_shapes=[("softmax_label", (8,))])
+    mod.init_params()
+    mod.init_optimizer(optimizer_params={"learning_rate": 0.1})
+    mod.precompile({k: ([("data", (8, k))], [("softmax_label", (8,))])
+                    for k in (4, 8)})
+    return sorted(mod._buckets)
+
+
+def _serve_once(prefix, X):
+    eng = _engine(prefix)
     try:
-        want = eng1.predict(X[0], timeout=30)
+        return np.asarray(eng.predict(X[0], timeout=30))
     finally:
-        eng1.close()
-    before = _totals()
+        eng.close()
+
+
+@pytest.mark.parametrize("what", ["fused_step", "bucketing_precompile",
+                                  "serve_engine"])
+def test_second_instance_compiles_nothing(what, jax_cache_dir, tmp_path):
+    """The restart story: a second same-shaped training module's donated
+    fused step, a rebuilt bucketing module's grid, a second serve
+    engine's whole bucket grid are read from JAX's persistent cache
+    (compile requests, none of them compiled) and give the first one's
+    answers bit for bit."""
+    if what == "serve_engine":
+        prefix, X = _save_pair(tmp_path)
+        build = lambda: _serve_once(prefix, X)            # noqa: E731
+    else:
+        build = {"fused_step": _train_once,
+                 "bucketing_precompile": _precompile_grid}[what]
+    first = build()
     with count_backend_compiles() as c:
-        eng2 = _engine(prefix)
-    try:
-        assert c.count == 0, \
-            "warm serve-grid construction still compiled"
-        after = _totals()
-        lookups = (after["hits"] - before["hits"]) + \
-            (after["misses"] - before["misses"])
-        assert lookups > 0
-        assert after["misses"] == before["misses"], \
-            "warm engine missed the cache"
-        got = eng2.predict(X[0], timeout=30)
-        assert np.allclose(got, want, atol=1e-5)
-    finally:
-        eng2.close()
+        second = build()
+    assert c.count > 0, "the second instance asked for no program"
+    assert c.compiled == 0, \
+        "%d of the second instance's %d programs were compiled" \
+        % (c.compiled, c.count)
+    if isinstance(first, dict):
+        for k in first:
+            assert np.array_equal(first[k], second[k]), k
+    else:
+        assert np.array_equal(first, second)
 
 
-def test_serve_warmup_failure_names_bucket(tmp_path, monkeypatch, no_cache):
+def test_serve_warmup_failure_names_bucket(tmp_path, monkeypatch):
     """A mid-grid warmup failure surfaces the offending bucket and its
     shapes, not a bare jax traceback."""
     prefix, _X = _save_pair(tmp_path)
@@ -725,7 +514,7 @@ def test_serve_warmup_failure_names_bucket(tmp_path, monkeypatch, no_cache):
     assert "XLA exploded" in msg
 
 
-def test_serve_warmup_thread_env(tmp_path, monkeypatch, no_cache):
+def test_serve_warmup_thread_env(tmp_path, monkeypatch):
     prefix, X = _save_pair(tmp_path)
     monkeypatch.setenv("MXNET_SERVE_WARMUP_THREADS", "2")
     eng = _engine(prefix)
@@ -736,7 +525,7 @@ def test_serve_warmup_thread_env(tmp_path, monkeypatch, no_cache):
         eng.close()
 
 
-def test_predictor_precompile(no_cache):
+def test_predictor_precompile():
     X, _y = _blobs()
     net = _mlp()
     it = mx.io.NDArrayIter(X, np.zeros(len(X), np.float32), batch_size=8)
@@ -759,30 +548,29 @@ def test_predictor_precompile(no_cache):
             p.get_output(0)
 
 
+
 # ---------------------------------------------------------------------------
 # observability
 
 
-def test_compile_report_surfaces_cache(cache_dir):
+def test_compile_report_surfaces_programs():
     f = cc.cached_jit(lambda x: x * 2, name="t:report")
-    f(jnp.ones((4,)))
+    f.warm(jnp.ones((4,)))
     rep = mx.profiler.compile_report()
-    assert rep["cache"]["directory"] == cc.get_cache().store.directory
-    assert rep["cache"]["mode"] == "serialize"
-    assert rep["cache"]["entries"] >= 1
+    assert set(rep) == {"totals", "per_program"}
     assert rep["totals"]["compiles"] >= 1
     assert "t:report" in rep["per_program"]
     per = rep["per_program"]["t:report"]
+    assert set(per) == {"trace_lower_s", "compile_s", "compiles",
+                        "steady_retraces"}
     assert per["compile_s"] > 0 and per["trace_lower_s"] > 0
     s = mx.profiler.compile_report_str()
-    assert "t:report" in s and "hit_rate" in s
+    assert "t:report" in s and "steady retraces" in s
 
 
-def test_steady_retrace_counter(no_cache):
+def test_steady_retrace_counter():
     """A program object compiling a SECOND signature is a retrace — the
     regression the counter exists to expose."""
-    _reset_stats()
-    cc.configure(None)
     f = cc.cached_jit(lambda x: x + 1, name="t:retrace")
     f.warm(jnp.ones((2,)))
     assert _totals()["steady_retraces"] == 0
@@ -790,91 +578,11 @@ def test_steady_retrace_counter(no_cache):
     assert _totals()["steady_retraces"] == 1
 
 
-# -- mesh-shape keying (ISSUE 7) ---------------------------------------------
-
-def _mesh_sharded_arg(axes):
-    """One (8, 4) array sharded P(<first axis>) over a mesh of `axes`
-    covering all 8 devices."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    sizes = [s for _, s in axes]
-    devs = np.array(jax.devices()).reshape(sizes)
-    mesh = Mesh(devs, tuple(a for a, _ in axes))
-    sh = NamedSharding(mesh, P(axes[0][0]))
-    return jax.device_put(jnp.arange(32.0).reshape(8, 4), sh)
-
-
-def test_mesh_shape_changes_cache_key(cache_dir):
-    """The same program placed on dp=8 vs dp=4 x tp=2 partitions
-    differently while listing identical device ids: the two placements
-    must key DISTINCT cache entries, and a warm restart on the same
-    mesh must hit."""
-    def make():
-        return cc.cached_jit(lambda a: (a * 2).sum(0), name="t:meshkey")
-    x_dp8 = _mesh_sharded_arg([("dp", 8)])
-    x_dp4tp2 = _mesh_sharded_arg([("dp", 4), ("tp", 2)])
-    want = np.asarray(make()(x_dp8))
-    assert _totals()["misses"] == 1
-    got = np.asarray(make()(x_dp4tp2))
-    t = _totals()
-    # dp=4 x tp=2 must MISS (fresh compile), never load the dp=8 entry
-    assert t["misses"] == 2 and t["hits"] == 0
-    assert np.allclose(got, want)
-    # warm restart on the same mesh shape: both placements hit
-    np.asarray(make()(x_dp8))
-    np.asarray(make()(x_dp4tp2))
-    assert _totals()["hits"] == 2
-
-
-def test_fused_fast_key_includes_mesh_axes():
-    """The trace-free fast key is built from _program_desc, which must
-    distinguish mesh AXES (dp=8 vs dp=4 x tp=2 list the same device
-    ids) and the per-param sharding specs."""
-    from jax.sharding import PartitionSpec as P
-    from mxnet_tpu.module.fused import FusedTrainStep
-    from mxnet_tpu.parallel import make_mesh
-
-    data = mx.sym.Variable("data")
-    h = mx.sym.Activation(
-        mx.sym.FullyConnected(data, num_hidden=8, name="fc1"),
-        act_type="relu", name="act1")
-    net = mx.sym.SoftmaxOutput(
-        mx.sym.FullyConnected(h, num_hidden=2, name="fc2"),
-        name="softmax")
-
-    def desc(mesh_axes, sharding=None):
-        opt = mx.optimizer.create("sgd", learning_rate=0.1)
-        f = FusedTrainStep(net, [mx.cpu(0)], ("data",),
-                           ("softmax_label",),
-                           ["fc1_weight", "fc1_bias", "fc2_weight",
-                            "fc2_bias"], [], opt,
-                           label_shapes=[("softmax_label", (16,))],
-                           mesh=make_mesh(mesh_axes), sharding=sharding)
-        return f._program_desc("step")
-
-    d_dp8 = desc([("dp", 8)])
-    d_dp4tp2 = desc([("dp", 4), ("tp", 2)])
-    d_spec = desc([("dp", 4), ("tp", 2)],
-                  sharding={"fc1_weight": P(None, "tp")})
-    assert d_dp8 != d_dp4tp2, "mesh axes not in the fast-key description"
-    assert d_dp4tp2 != d_spec, "sharding specs not in the fast-key " \
-        "description"
-    assert desc([("dp", 8)]) == d_dp8, "description is not deterministic"
-
-
-def test_executor_mesh_placement_keys_program_desc():
-    """Executor.set_mesh (the tp-sharded serve path) must re-key the
-    executor's fast-key description by mesh axes + specs."""
-    from jax.sharding import PartitionSpec as P
-    from mxnet_tpu.parallel import make_mesh
-    net = mx.sym.SoftmaxOutput(
-        mx.sym.FullyConnected(mx.sym.Variable("data"), num_hidden=8,
-                              name="fc1"), name="softmax")
-
-    def bound():
-        return net.simple_bind(mx.cpu(0), grad_req="null",
-                               data=(4, 6), softmax_label=(4,))
-    base = bound()._program_desc()
-    ex = bound()
-    ex.set_mesh(make_mesh([("tp", 2)]),
-                param_specs={"fc1_weight": P("tp", None)})
-    assert ex._program_desc() != base
+def test_the_package_exports_what_the_entry_points_import():
+    """``benchmark/run.py``, ``chip_smoke.py`` and the bench mains import
+    these by name."""
+    for name in ("cached_jit", "CachedFunction", "place_jax_cache",
+                 "jax_cache_dir", "count_backend_compiles",
+                 "record_compile_spans", "parallel_warm", "WarmupError",
+                 "get_stats"):
+        assert name in cc.__all__ and hasattr(cc, name), name

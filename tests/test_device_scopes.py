@@ -2,7 +2,9 @@
 fused step carries the name of the graph node or step part that made it,
 the program's own table says which, it is built on request only, and it
 never reads names older than the code that traced the step."""
+import os
 import re
+import sys
 
 import numpy as np
 import pytest
@@ -15,6 +17,9 @@ from mxnet_tpu.compile_cache import cached_jit
 from mxnet_tpu.compile_cache.jaxcache import count_backend_compiles
 from mxnet_tpu.ops import transformer as tf_ops
 from mxnet_tpu.trace import scopes
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "common"))
+from jax_cache import jax_cache_dir  # noqa: E402,F401
 
 INSTRUCTION = re.compile(
     r'^\s*(?:ROOT\s+)?%?([^\s=]+)\s*=\s.*?\bop_name="([^"]*)"', re.M)
@@ -240,24 +245,6 @@ def _make_step(scoped, name):
     return step
 
 
-@pytest.fixture
-def jax_cache_dir(tmp_path):
-    """JAX's persistent cache in a directory of the test's own."""
-    from jax.experimental.compilation_cache import compilation_cache as cc
-    keys = ("jax_compilation_cache_dir",
-            "jax_persistent_cache_min_compile_time_secs",
-            "jax_persistent_cache_min_entry_size_bytes")
-    was = {k: getattr(jax.config, k) for k in keys}
-    cc.reset_cache()
-    jax.config.update(keys[0], str(tmp_path))
-    jax.config.update(keys[1], 0.0)
-    jax.config.update(keys[2], -1)
-    yield str(tmp_path)
-    cc.reset_cache()
-    for k, v in was.items():
-        jax.config.update(k, v)
-
-
 def test_a_cache_filled_without_scopes_does_not_serve_its_names(
         jax_cache_dir):
     """The key of JAX's persistent cache strips the scopes, so the same
@@ -288,31 +275,19 @@ def test_a_cache_filled_without_scopes_does_not_serve_its_names(
         assert scopes.table_of(warm.optimized_hlo()) == table
 
 
-def test_a_program_the_fast_key_served_is_traced_once_for_its_scopes(
-        tmp_path):
-    """A warm start through the executable cache's fast key traces
-    nothing; the table's request then traces the function once (the
-    scopes it enters are what names resolve against) and reads the text
-    of the entry that runs, compiling nothing."""
-    from mxnet_tpu import compile_cache
+def test_a_warmed_programs_table_reads_the_entry_that_runs():
+    """A program that ``warm()`` compiled was traced by this process: the
+    table's request lowers nothing and compiles nothing, it reads the
+    text of the entry the next call dispatches to."""
     w, x = jnp.ones((16, 16)), jnp.ones((4, 16))
-    compile_cache.configure(str(tmp_path))
-    try:
-        cold = cached_jit(_make_step(True, "fk_step"), name="t:fk",
-                          fast_key="t|fk")
-        cold(w, x)
-        assert cold._traced
-        warm = cached_jit(_make_step(True, "fk_step"), name="t:fk",
-                          fast_key="t|fk")
-        warm(w, x)
-        assert warm._entries and not warm._traced
-        lowerings = []
-        warm._jit = _CountingJit(warm._jit, lowerings)
-        with count_backend_compiles() as counter:
-            table = scopes.table_of(warm.optimized_hlo())
-            assert scopes.table_of(warm.optimized_hlo()) == table
-        assert len(lowerings) == 1 and warm._traced and counter.count == 0
-        assert table and set(table.values()) == {"attn.l0"}
-        assert table == scopes.table_of(cold.optimized_hlo())
-    finally:
-        compile_cache.reset()
+    warmed = cached_jit(_make_step(True, "warmed_step"), name="t:warmed")
+    assert warmed.warm(w, x) == "compiled"
+    assert warmed.warm(w, x) == "present"
+    lowerings = []
+    warmed._jit = _CountingJit(warmed._jit, lowerings)
+    with count_backend_compiles() as counter:
+        table = scopes.table_of(warmed.optimized_hlo())
+        warmed(w, x)
+        assert scopes.table_of(warmed.optimized_hlo()) == table
+    assert lowerings == [] and counter.count == 0
+    assert table and set(table.values()) == {"attn.l0"}

@@ -295,7 +295,7 @@ def test_dp_ep_mesh_matches_single_device_and_shards():
     assert "dp=2 x ep=2" in mx.profiler.multichip_report_str()
 
 
-def test_moe_geometry_in_program_desc_and_report():
+def test_moe_geometry_and_report():
     mod, _ = _fit(cf=0.5, num_epoch=1)
     f = mod._fused
     assert f.moe_blocks and f.moe_stats is not None

@@ -23,6 +23,9 @@ from mxnet_tpu import trace
 from mxnet_tpu.compile_cache import count_backend_compiles, jaxcache
 from mxnet_tpu.io import DataIter
 
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "common"))
+from jax_cache import jax_cache_dir  # noqa: E402,F401
+
 from test_fit_spans import (IN_DIM, _BucketIter, _bucket_sym, _data_iter,
                             _end, _mlp)
 
@@ -216,26 +219,6 @@ def test_a_function_traced_inside_anothers_trace_lies_inside_its_span():
     assert all(_inside(e, traces[0], slack=1e3) for e in traces[1:])
     assert len(_spans(["compile:lower"])) == 1
     assert len(_spans(["compile:backend"])) == 1
-
-
-@pytest.fixture
-def jax_cache_dir(tmp_path):
-    """JAX's persistent cache in a directory of the test's own, keeping
-    every program (tier-1's keeps compiles over half a second)."""
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache as cc
-    keys = ("jax_compilation_cache_dir",
-            "jax_persistent_cache_min_compile_time_secs",
-            "jax_persistent_cache_min_entry_size_bytes")
-    was = {k: getattr(jax.config, k) for k in keys}
-    cc.reset_cache()
-    jax.config.update(keys[0], str(tmp_path))
-    jax.config.update(keys[1], 0.0)
-    jax.config.update(keys[2], -1)
-    yield str(tmp_path)
-    cc.reset_cache()
-    for k, v in was.items():
-        jax.config.update(k, v)
 
 
 def test_the_persistent_caches_answer_reads_as_a_hit_with_its_load_time(

@@ -27,7 +27,7 @@ from test_module import test_module_predict_and_params       # noqa: F401,E402
 from test_optimizer import test_sgd_plain_and_momentum       # noqa: F401,E402
 from test_random import test_seed_determinism                # noqa: F401,E402
 from test_rnn_op import test_rnn_op_state_outputs            # noqa: F401,E402
-from test_compile_cache import cache_dir                     # noqa: F401,E402
+from jax_cache import jax_cache_dir                          # noqa: F401,E402
 
 
 def test_smoke_unary_grad():
@@ -46,15 +46,14 @@ def test_smoke_fused_matches_classic():
         assert np.abs(pf[k] - pc[k]).max() < 1e-4, k
 
 
-def test_smoke_warmed_serve_grid_roundtrips_executable_cache(cache_dir,  # noqa: F811
-                                                             tmp_path):
+def test_smoke_warmed_serve_grid_restarts_from_the_persistent_cache(
+        jax_cache_dir, tmp_path):                            # noqa: F811
     """A ServeEngine bound to the chip (``dev_type="tpu"`` — the engine's
     default is the host): every bucket warmed and dispatched through the
-    raw ``LoadedExecutable.execute`` path, its executables serialized
-    into ``MXNET_COMPILE_CACHE``; then a second engine built from that
-    cache alone — zero compiles, same answers as the first and as a
-    host-bound engine, and not one program bypassing the cache (a PJRT
-    blob that cannot round-trip would show here as a bypass)."""
+    raw ``LoadedExecutable.execute`` path; then a second engine whose
+    grid is read from JAX's persistent cache alone — compile requests,
+    none compiled, same answers as the first and as a host-bound
+    engine."""
     import numpy as np
     from compile_guard import count_backend_compiles
     import test_compile_cache as t
@@ -64,11 +63,12 @@ def test_smoke_warmed_serve_grid_roundtrips_executable_cache(cache_dir,  # noqa:
         want = eng1.predict(X[0], timeout=60)
     finally:
         eng1.close()
-    assert t._totals()["entries_written"] > 0, t._totals()
     with count_backend_compiles() as c:
         eng2 = t._engine(prefix, dev_type="tpu")
     try:
-        assert c.count == 0, "warm serve-grid construction still compiled"
+        assert c.count > 0 and c.compiled == 0, \
+            "warm serve-grid construction compiled %d of %d programs" \
+            % (c.compiled, c.count)
         got = eng2.predict(X[0], timeout=60)
     finally:
         eng2.close()
@@ -79,5 +79,3 @@ def test_smoke_warmed_serve_grid_roundtrips_executable_cache(cache_dir,  # noqa:
     finally:
         host.close()
     assert np.allclose(got, ref, atol=1e-4)
-    totals = t._totals()
-    assert totals["bypasses"] == 0 and totals["hits"] > 0, totals
